@@ -1,0 +1,548 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, no arguments.  Drives the two main paths once through the entry
+points a user calls — `easydist_compile` over the GPT-2 small train step, and
+`GenerationSession.for_gpt` in its three KV layouts — at the model's published
+width and depth, with random weights made from a seed, and checks every result
+against a reference computed beside it.  Phases, in order:
+
+  clock    a chained bf16 matmul timed with plain `jax.block_until_ready` must
+           land under the chip's datasheet peak (so the wait is real)
+  kernels  the five Pallas kernels of ops/flash_attention.py, compiled
+           (`interpret=False`), against the float32 XLA paths in that file
+  trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
+           state threaded and donated; loss trajectory against a plain
+           `jax.jit` of the einsum-attention step
+  server   `submit` / `step` / `run_until_drained`: bucketed, paged, paged
+           int8; every token teacher-forced against one full `gpt_apply`
+
+It needs a TPU: without one it exits 2 before compiling anything.  No phase's
+exception is caught — a failure anywhere is a traceback and a nonzero exit.
+The last line of stdout is the one JSON object the driver reads,
+`{"ok": true, "device": {"platform", "kind", "count"}}`; the line before it
+and `chiprun_out/chip_smoke/summary.json` carry the run's set-up notes (no
+number in them is a performance claim).  It writes the XLA compile cache
+(`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`), the discovery
+rule store under `<checkout>/.easydist_cache`, and `chiprun_out/chip_smoke/`.
+"""
+
+import collections
+import importlib
+import json
+import os
+import shutil
+import sys
+import time
+
+_T0 = time.perf_counter()
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_OUT = os.path.join(_HERE, "chiprun_out", "chip_smoke")
+
+# GPT-2 small as published (vocab padded to a multiple of 128): the shape
+# bench.py measures
+GPT2_SMALL = dict(vocab=50304, seq=1024, dim=768, heads=12, layers=12,
+                  dtype="bfloat16")
+TRAIN_BATCH = 8
+TRAIN_STEPS = 6
+# flash-attention trajectory vs the einsum-attention jit: both compute in
+# bf16 and differ in summation order only
+LOSS_RTOL = 2e-2
+# kernel vs float32 reference, relative to the reference's largest element:
+# the kernels read bf16 inputs, accumulate in f32 and round the result to
+# bf16 (2^-8 relative) — 2e-2 leaves room for the backward's three matmuls
+KERNEL_RTOL = 2e-2
+# how far below the float32 reference's best logit a served token's reference
+# logit may sit.  Random weights give flat logits (std ~0.55 over 50k tokens),
+# so an exact argmax match is not the contract: a tie inside the margin is
+# bf16 noise, a token read from a corrupted cache lands ~2.5 below the top.
+# First run on a v5e: deficit 0.000 with 175/175 exact on the bf16 caches,
+# 0.008 with 174/175 on int8.
+LOGIT_MARGIN = {"bucketed": 0.05, "paged": 0.05, "paged_int8": 0.1}
+MIN_EXACT_MATCH = 0.9
+
+
+def log(msg: str) -> None:
+    print(f"[chip_smoke +{time.perf_counter() - _T0:6.1f}s] {msg}", flush=True)
+
+
+# ---------------------------------------------------------------- clock
+
+
+def phase_clock(peak_flops: float, n: int = 4096, chain: int = 64):
+    """`chain` dependent n x n bf16 matmuls in one program; the achieved
+    rate must sit under the datasheet peak, or `block_until_ready` returned
+    before the device finished."""
+    import jax
+    import jax.numpy as jnp
+
+    from easydist_tpu.utils.timer import time_per_call
+
+    @jax.jit
+    def chained(a, b):
+        def body(_, x):
+            return jnp.dot(x, b, preferred_element_type=jnp.bfloat16)
+        return jax.lax.fori_loop(0, chain, body, a)
+
+    key = jax.random.PRNGKey(0)
+    a = jax.random.normal(key, (n, n), jnp.bfloat16)
+    b = (jax.random.normal(jax.random.fold_in(key, 1), (n, n), jnp.float32)
+         / jnp.sqrt(n)).astype(jnp.bfloat16)
+    secs = time_per_call(chained, (a, b), iters=5, warmup=2)
+    rate = 2.0 * n ** 3 * chain / secs
+    out = chained(a, b)
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all()), \
+        "clock: chained matmul produced non-finite values"
+    assert rate < peak_flops, (
+        f"clock: {rate / 1e12:.1f} TFLOP/s is over the {peak_flops / 1e12:.0f}"
+        f" TFLOP/s peak — block_until_ready did not wait for the device")
+    assert rate > 0.02 * peak_flops, (
+        f"clock: {rate / 1e12:.2f} TFLOP/s is under 2% of peak — the matmul "
+        f"did not run on the MXU")
+    log(f"PASS clock: {rate / 1e12:.1f} TFLOP/s bf16 on a {chain}-deep "
+        f"{n}^3 chain, under the {peak_flops / 1e12:.0f} TFLOP/s peak")
+    return {"tflops": round(rate / 1e12, 1)}
+
+
+# -------------------------------------------------------------- kernels
+
+
+def _close(name, got, want, rtol=KERNEL_RTOL):
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    assert got.shape == want.shape, (name, got.shape, want.shape)
+    assert np.isfinite(got).all(), f"{name}: non-finite kernel output"
+    err = float(np.abs(got - want).max())
+    ref = float(np.abs(want).max())
+    assert err <= rtol * ref, \
+        f"{name}: max |kernel - reference| {err:.3e} > {rtol} * {ref:.3e}"
+    return err / ref
+
+
+def phase_kernels(batch=TRAIN_BATCH, heads=12, d=64, seq=1024, slots=8,
+                  page_tokens=64, interpret=False):
+    """Each Pallas entry point at the shapes the trainer and the server use,
+    on ragged lengths, against the float32 XLA path beside it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    scale = 1.0 / float(np.sqrt(d))
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(11), 8)
+    errs = {}
+
+    def reference(f):
+        # a float32 reference: on a TPU the default matmul precision is
+        # one bf16 pass, which is no reference at all
+        def run(*a):
+            with jax.default_matmul_precision("highest"):
+                return jax.jit(f)(*a)
+        return run
+
+    def f32(*xs):
+        return [x.astype(jnp.float32) for x in xs]
+
+    # ---- training forward + fused backward (causal)
+    q, k, v = (jax.random.normal(keys[i], (batch, heads, seq, d), bf)
+               for i in range(3))
+    g = jax.random.normal(keys[3], (batch, heads, seq, d), bf)
+
+    def flash_loss(q, k, v):
+        out = fa.flash_attention(q, k, v, True, interpret=interpret)
+        return (out.astype(jnp.float32) * g.astype(jnp.float32)).sum(), out
+
+    def ref_loss(q, k, v):
+        out = fa._reference_attention(q, k, v, True, scale)
+        return (out * g.astype(jnp.float32)).sum(), out
+
+    (_, out_k), grads_k = jax.jit(
+        jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True))(
+        q, k, v)
+    (_, out_r), grads_r = reference(
+        jax.value_and_grad(ref_loss, argnums=(0, 1, 2), has_aux=True))(
+        *f32(q, k, v))
+    errs["forward"] = _close("flash forward", out_k, out_r)
+    errs["backward"] = max(
+        _close(f"flash backward d{n}", a, b)
+        for n, a, b in zip("qkv", grads_k, grads_r))
+    del q, k, v, g, out_k, out_r, grads_k, grads_r
+
+    # ---- bucketed decode: one query row per slot, ragged cache lengths
+    # (1 = a fresh slot, seq = a full bucket, the rest mid-block)
+    lengths = jnp.asarray(
+        ([1, seq, seq // 13, seq // 4, seq // 4 + 44, 5, seq - 1,
+          seq // 2 + 1] * slots)[:slots], jnp.int32)
+    qd = jax.random.normal(keys[4], (slots, heads, d), bf)
+    kc = jax.random.normal(keys[5], (slots, heads, seq, d), bf)
+    vc = jax.random.normal(keys[6], (slots, heads, seq, d), bf)
+    got = jax.jit(lambda *a: fa.flash_decode_attention(
+        *a, interpret=interpret))(qd, kc, vc, lengths)
+    want = reference(lambda q, k, v, l: fa._decode_attention_xla(
+        q, k, v, l, scale))(*f32(qd, kc, vc), lengths)
+    errs["decode"] = _close("bucketed decode", got, want)
+
+    # ---- paged decode: the same rows scattered over an arena through a
+    # shuffled page table, dead windows on the sentinel
+    max_pages = seq // page_tokens
+    n_pages = slots * max_pages + 3
+    perm = np.random.RandomState(0).permutation(n_pages)
+    table = np.full((slots, max_pages), n_pages, np.int32)
+    kp = np.zeros((n_pages, heads, page_tokens, d), np.float32)
+    vp = np.zeros_like(kp)
+    kc_h, vc_h = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
+    for row in range(slots):
+        for j in range(-(-int(lengths[row]) // page_tokens)):
+            pid = int(perm[row * max_pages + j])
+            table[row, j] = pid
+            win = slice(j * page_tokens, (j + 1) * page_tokens)
+            kp[pid] = kc_h[row, :, win]
+            vp[pid] = vc_h[row, :, win]
+    table = jnp.asarray(table)
+    kp, vp = jnp.asarray(kp, bf), jnp.asarray(vp, bf)
+    got = jax.jit(lambda *a: fa.flash_paged_decode_attention(
+        *a, interpret=interpret))(qd, kp, vp, table, lengths)
+    want_p = reference(lambda q, k, v, t, l: fa._paged_decode_attention_xla(
+        q, k, v, t, l, scale))(*f32(qd, kp, vp), table, lengths)
+    errs["paged_decode"] = _close("paged decode", got, want_p)
+    # the page table is an indirection, not arithmetic: the paged reference
+    # equals the contiguous one
+    _close("paged reference vs contiguous", want_p, want, rtol=1e-5)
+
+    # ---- int8 paged decode, one and two scale blocks per row
+    for nb in (1, 2):
+        kq, ks = fa.kv_quantize(kp.astype(jnp.float32), nb)
+        vq, vs = fa.kv_quantize(vp.astype(jnp.float32), nb)
+        got = jax.jit(lambda *a: fa.flash_paged_decode_quant_attention(
+            *a, interpret=interpret))(qd, kq, vq, ks, vs, table, lengths)
+        want_q = reference(
+            lambda q, k, v, a, b, t, l: fa._paged_decode_attention_quant_xla(
+                q, k, v, a, b, t, l, scale))(
+            qd.astype(jnp.float32), kq, vq, ks, vs, table, lengths)
+        errs[f"paged_decode_int8_nb{nb}"] = _close(
+            f"int8 paged decode nb={nb}", got, want_q)
+
+    log("PASS kernels (interpret=%s): max error / max |reference| — %s"
+        % (interpret, ", ".join(f"{k} {v:.1e}" for k, v in errs.items())))
+    return {k: float(f"{v:.2e}") for k, v in errs.items()}
+
+
+# -------------------------------------------------------------- trainer
+
+
+def _leaf_bytes_on(leaf, device) -> int:
+    return sum(s.data.nbytes for s in leaf.addressable_shards
+               if s.device == device)
+
+
+def phase_trainer(cfg_kw=None, batch=TRAIN_BATCH, steps=TRAIN_STEPS,
+                  devices=None, large_leaf=2 ** 20):
+    """GPT-2 small through `easydist_compile` on all local chips, beside a
+    plain `jax.jit` of the einsum-attention step."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from easydist_tpu.jaxfront import easydist_compile, make_device_mesh
+    from easydist_tpu.models import GPTConfig, make_gpt_train_step
+
+    devices = list(devices or jax.devices())
+    n = len(devices)
+    cfg = GPTConfig(**(cfg_kw or GPT2_SMALL), attention="flash")
+    if n >= 4:
+        devices = devices[:4]
+        mesh = make_device_mesh((2, 2), ("dp", "tp"), devices=devices)
+    else:
+        devices = devices[:1]
+        mesh = make_device_mesh((1,), ("d",), devices=devices)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, cfg.seq), 0,
+                                cfg.vocab)
+    targets = jax.random.randint(jax.random.PRNGKey(2), (batch, cfg.seq), 0,
+                                 cfg.vocab)
+
+    # ---- the reference beside it: plain jit, einsum attention, one device
+    ref_step, ref_init = make_gpt_train_step(
+        dataclasses.replace(cfg, attention="einsum"))
+    ref_jit = jax.jit(ref_step, donate_argnums=(0,))
+    state = ref_init(jax.random.PRNGKey(0))
+    ref_losses = []
+    for _ in range(steps):
+        state, loss = ref_jit(state, tokens, targets)
+        ref_losses.append(float(loss))
+    del state
+
+    # ---- the system: trace -> discovery -> solve -> emission -> jit
+    step, init_state = make_gpt_train_step(cfg)
+    compiled = easydist_compile(step, mesh=mesh)
+    state = init_state(jax.random.PRNGKey(0))
+    t0 = time.perf_counter()
+    result = compiled.get_compiled(state, tokens, targets)
+    plan_s = time.perf_counter() - t0
+    losses, step_s, sharding_notes = [], [], None
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        state, loss = compiled(state, tokens, targets)
+        losses.append(float(loss))  # waits for the step
+        step_s.append(time.perf_counter() - t0)
+        if len(losses) == 1 and n >= 4:
+            sharding_notes = _assert_plan_is_real(state, devices, large_leaf)
+    log(f"trainer losses   {[round(x, 4) for x in losses]}")
+    log(f"reference losses {[round(x, 4) for x in ref_losses]}")
+
+    assert all(np.isfinite(losses)), f"trainer: non-finite loss {losses}"
+    assert all(b < a for a, b in zip(losses, losses[1:])), \
+        f"trainer: loss not decreasing {losses}"
+    np.testing.assert_allclose(
+        losses, ref_losses, rtol=LOSS_RTOL,
+        err_msg="trainer: flash/easydist trajectory left the einsum jit's")
+
+    max_dev = max(abs(a - b) / abs(b) for a, b in zip(losses, ref_losses))
+    notes = {
+        "mesh": dict(mesh.shape),
+        "losses": [round(x, 4) for x in losses],
+        "max_rel_dev_vs_einsum_jit": float(f"{max_dev:.2e}"),
+        "compile_s": {k: round(v, 2)
+                      for k, v in result.phase_seconds.items()},
+        "plan_s": round(plan_s, 2),
+        "first_step_s_incl_xla": round(step_s[0], 2),
+        "later_step_s": round(min(step_s[1:]), 4),
+        "replicated_flops_share": round(result.replicated_flops_fraction, 4),
+        "peak_bytes_in_use": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices],
+    }
+    if sharding_notes:
+        notes["sharding"] = sharding_notes
+    log(f"PASS trainer: {cfg.layers} layers x {cfg.dim} wide on mesh "
+        f"{dict(mesh.shape)}, {steps} steps, loss {losses[0]:.4f} -> "
+        f"{losses[-1]:.4f}, within {LOSS_RTOL} of the einsum jit; "
+        f"notes {json.dumps(notes)}")
+    return notes
+
+
+def _assert_plan_is_real(state, devices, large_leaf):
+    """After step 1 on the (2, 2) mesh: the large parameter and Adam leaves
+    are not fully replicated, and no device holds the whole state."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(state)
+    large = [x for x in leaves if x.size >= large_leaf]
+    replicated = [x.shape for x in large if x.sharding.is_fully_replicated]
+    assert large and not replicated, (
+        f"trainer: {len(replicated)} of {len(large)} large state leaves are "
+        f"fully replicated on the mesh: {replicated[:6]}")
+    total = sum(x.nbytes for x in leaves)
+    held = [sum(_leaf_bytes_on(x, d) for x in leaves) for d in devices]
+    assert max(held) < total, (
+        f"trainer: a device holds the whole state ({max(held)} of {total} "
+        f"bytes)")
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use") for d in devices]
+    assert all(b < total for b in in_use if b is not None), (
+        f"trainer: a device has {max(in_use)} bytes in use, the whole state "
+        f"is {total}")
+    return {"state_bytes": total, "state_bytes_per_device": held,
+            "large_leaves": len(large), "bytes_in_use": in_use}
+
+
+# --------------------------------------------------------------- server
+
+
+def _requests(vocab, bucket, chunk):
+    """(prompt, max_new) in two waves.  Mixed lengths: shorter than one
+    prefill chunk, longer than one, longer than two; one that runs into the
+    end of the bucket; and in the second wave a prompt sharing its first two
+    chunks with one of the first wave, so the trie restores them."""
+    import numpy as np
+
+    rng = np.random.RandomState(7)
+
+    def toks(n):
+        return [int(t) for t in rng.randint(1, vocab, size=n)]
+
+    shared = toks(2 * chunk)
+    wave1 = [(toks(5), 24), (toks(chunk + 6), 32),
+             (shared + toks(9), 28), (toks(2 * chunk + 22), 24),
+             (toks(bucket - 10), 24)]
+    wave2 = [(shared + toks(13), 24), (toks(chunk // 2), 32)]
+    return wave1, wave2
+
+
+def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
+    """`GenerationSession.for_gpt` on one chip in one KV layout; every
+    served token teacher-forced against one full float32 `gpt_apply`."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from easydist_tpu.jaxfront import make_device_mesh
+    from easydist_tpu.models import GPTConfig
+    from easydist_tpu.models.gpt import gpt_apply
+    from easydist_tpu.serve import GenerationSession, ServeConfig
+
+    cfg = GPTConfig(**(cfg_kw or GPT2_SMALL))
+    device = device or jax.devices()[0]
+    mesh = make_device_mesh((1,), ("d",), devices=[device])
+    layout_kw = {"bucketed": {},
+                 "paged": {"kv_layout": "paged"},
+                 "paged_int8": {"kv_layout": "paged",
+                                "kv_quant_dtype": "int8"}}[layout]
+    config = ServeConfig(decode_buckets=(cfg.seq,), **layout_kw,
+                         **(config_kw or {}))
+    bucket, chunk = cfg.seq, min(config.prefill_chunk, cfg.seq)
+    sess = GenerationSession.for_gpt(params, cfg, config=config, mesh=mesh)
+
+    t0 = time.perf_counter()
+    served = []
+    for wave in _requests(cfg.vocab, bucket, chunk):
+        futs = [sess.submit(p, max_new_tokens=m) for p, m in wave]
+        sess.step()
+        sess.run_until_drained(max_steps=4 * bucket)
+        served += [(p, m, f.result(timeout=0)) for (p, m), f in
+                   zip(wave, futs)]
+    wall = time.perf_counter() - t0
+
+    # ---- every future resolved with the right count and reason
+    for prompt, max_new, res in served:
+        room = bucket - len(prompt) + 1
+        want_n, want_why = (max_new, "length") if max_new <= room \
+            else (room, "bucket_full")
+        assert (len(res["ids"]), res["finish_reason"]) == (want_n, want_why), (
+            f"server[{layout}]: prompt of {len(prompt)} finished with "
+            f"{len(res['ids'])} tokens / {res['finish_reason']!r}, expected "
+            f"{want_n} / {want_why!r}")
+    reused = sess.metrics.counter("prefix_tokens_reused")
+    assert reused >= 2 * chunk, (
+        f"server[{layout}]: the trie restored {reused} prompt tokens, "
+        f"expected the {2 * chunk}-token shared prefix")
+
+    # ---- teacher forcing: one full forward over prompt + served ids in
+    # float32; token i must be (within the margin) the reference's pick at
+    # position len(prompt) + i - 1
+    ref_cfg = GPTConfig(**{**(cfg_kw or GPT2_SMALL), "dtype": "float32"})
+
+    # the weights are an argument, not a closure: closed over, they would be
+    # baked into the executable as ~500 MB of constants (slow to compile, and
+    # too large a file for the compile cache)
+    @jax.jit
+    def ref_logits(params, tokens):
+        with jax.default_matmul_precision("highest"):
+            return gpt_apply(params, ref_cfg, tokens)[0]
+
+    worst, exact, total = 0.0, 0, 0
+    for prompt, _, res in served:
+        ids = res["ids"]
+        seq = np.zeros((1, bucket), np.int32)
+        full = (prompt + ids)[:bucket]
+        seq[0, :len(full)] = full
+        logits = np.asarray(ref_logits(params, jnp.asarray(seq)))
+        for i, tok in enumerate(ids):
+            row = logits[len(prompt) + i - 1]
+            worst = max(worst, float(row.max() - row[tok]))
+            exact += int(row.argmax() == tok)
+            total += 1
+    margin = LOGIT_MARGIN[layout]
+    assert worst <= margin, (
+        f"server[{layout}]: a served token sits {worst:.3f} below the "
+        f"float32 reference's best logit (margin {margin}) — the cached "
+        f"path and the full forward disagree")
+    assert exact >= MIN_EXACT_MATCH * total, (
+        f"server[{layout}]: only {exact}/{total} served tokens are the "
+        f"reference's argmax")
+
+    counters = sess.metrics.snapshot()["counters"]
+    notes = {"requests": len(served), "tokens": total,
+             "exact_argmax": f"{exact}/{total}",
+             "worst_logit_deficit": round(worst, 4),
+             "prefix_tokens_reused": reused,
+             "prefill_chunks": counters.get("prefill_chunks"),
+             "decode_steps": counters.get("decode_steps"),
+             "wall_s_incl_compile": round(wall, 1)}
+    sess.close()
+    log(f"PASS server[{layout}]: {len(served)} requests, {total} tokens, "
+        f"counts and finish reasons right, {exact}/{total} tokens are the "
+        f"float32 argmax, worst deficit {worst:.3f} <= {margin}; notes "
+        f"{json.dumps(notes)}")
+    return notes
+
+
+# ----------------------------------------------------------------- main
+
+
+def _versions():
+    from importlib import metadata
+
+    out = {}
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[pkg] = metadata.version(pkg)
+        except metadata.PackageNotFoundError:
+            out[pkg] = None
+    return out
+
+
+def main() -> int:
+    import jax
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.utils.jax_cache import configure_jax_cache
+
+    cache_dir = configure_jax_cache()
+    cache_events = collections.Counter()
+    jax.monitoring.register_event_listener(
+        lambda event, **_: cache_events.update([event.rsplit("/", 1)[-1]]))
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU; JAX reports platform="
+              f"{dev.platform!r} ({dev.device_kind!r} x{len(devices)})",
+              file=sys.stderr)
+        return 2
+
+    # a PerfDB left by another program changes solver decisions from outside
+    # the tree: this run reads none and keeps its own beside its outputs
+    shutil.rmtree(_OUT, ignore_errors=True)
+    os.makedirs(_OUT)
+    edconfig.prof_db_path = os.path.join(_OUT, "perf.db")
+
+    from easydist_tpu import native
+    from easydist_tpu.models import GPTConfig, gpt_init
+    from easydist_tpu.runtime.calibrate import detect_device_constants
+
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices)}
+    versions = _versions()
+    log(f"platform={dev.platform} device_kind={dev.device_kind!r} "
+        f"count={len(devices)} " +
+        " ".join(f"{k}={v}" for k, v in versions.items()) +
+        f" native={'loaded' if native.available() else 'python fallback'} "
+        f"jax_cache={cache_dir}")
+    peak = detect_device_constants(dev.device_kind)["peak_flops"]
+
+    notes = {"clock": phase_clock(peak)}
+    notes["kernels"] = phase_kernels()
+    notes["trainer"] = phase_trainer()
+    params = gpt_init(GPTConfig(**GPT2_SMALL), jax.random.PRNGKey(0))
+    for layout in ("bucketed", "paged", "paged_int8"):
+        notes[f"server_{layout}"] = phase_server(layout, params)
+
+    summary = {
+        "ok": True, "device": device, "versions": versions,
+        "native": native.available(),
+        "jax_cache": {"dir": cache_dir,
+                      "hits": cache_events["cache_hits"],
+                      "misses": cache_events["cache_misses"]},
+        "wall_s": round(time.perf_counter() - _T0, 1),
+        "phases": notes, "claim": None}
+    with open(os.path.join(_OUT, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    log("summary " + json.dumps(summary))
+    # the driver's contract: the last line is this object and nothing more
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -200,26 +200,18 @@ def verify_memory_plan(graph: MetaGraph, plan, per_axis: Sequence[Dict],
 def resolve_hbm_budget(mesh=None) -> int:
     """Per-device HBM capacity the MEM004 gate verifies against.
     `edconfig.analyze_hbm_budget` wins when set (>0); 0 disables; the
-    default (-1) asks the real device's memory_stats and falls back to the
-    platform default (`hbm_capacity_default`, v5e 16 GiB) on backends that
-    do not report one (CPU virtual meshes)."""
+    default (-1) asks the real device
+    (`runtime.calibrate.device_memory_limit`) and falls back to
+    `hbm_capacity_default` only on backends that report none (CPU virtual
+    meshes)."""
     from easydist_tpu import config as edconfig
+    from easydist_tpu.runtime.calibrate import device_memory_limit
 
     cap = edconfig.analyze_hbm_budget
     if cap >= 0:
         return int(cap)
-    if mesh is not None:
-        try:
-            dev = np.asarray(mesh.devices).flat[0]
-            stats = dev.memory_stats()
-            if stats:
-                limit = stats.get("bytes_limit") or stats.get(
-                    "bytes_reservable_limit")
-                if limit:
-                    return int(limit)
-        except Exception:
-            pass
-    return int(edconfig.hbm_capacity_default)
+    limit = device_memory_limit(mesh) if mesh is not None else None
+    return int(limit or edconfig.hbm_capacity_default)
 
 
 def _node_recompute_seconds(node) -> float:

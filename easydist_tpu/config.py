@@ -35,8 +35,12 @@ dump_graphviz = _env_bool("EASYDIST_DUMP_GRAPHVIZ", True)
 dump_hlo = _env_bool("EASYDIST_DUMP_HLO", False)
 
 # ---------------- compile cache ----------------
+# caches are anchored to the checkout, never to the working directory: a
+# cache that moves with `cd` is a cold cache nobody asked for
+checkout_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 enable_compile_cache = _env_bool("EASYDIST_COMPILE_CACHE", False)
-compile_cache_dir = os.environ.get("EASYDIST_COMPILE_CACHE_DIR", "./.easydist_cache")
+compile_cache_dir = os.environ.get(
+    "EASYDIST_COMPILE_CACHE_DIR", os.path.join(checkout_dir, ".easydist_cache"))
 
 # ---------------- ShardCombine discovery ----------------
 # number of shards used when executing candidate shardings (reference
@@ -124,9 +128,10 @@ comm_overlap_ratio_source = os.environ.get("EASYDIST_COMM_OVERLAP_SOURCE", "auto
 # set by runtime.calibrate (calibrate_overlap / apply_calibration), never
 # by hand: achieved overlap fraction measured on THIS backend, or None
 comm_overlap_ratio_measured = None
-# device peak FLOP/s for overlap bounding (v5e bf16 ~197e12; f32 ~49e12);
-# auto-replaced with the real device kind's datasheet value at compile time
-# (runtime.calibrate.apply_device_constants) unless the env var is set
+# device peak FLOP/s for overlap bounding.  This default prices CPU virtual
+# meshes only: on a TPU it is replaced with the device kind's datasheet
+# value at compile time (runtime.calibrate.apply_device_constants; a kind
+# the table does not know raises) unless the env var is set
 peak_flops = _env_float("EASYDIST_PEAK_FLOPS", 4.9e13)
 # (mem_cost_weight was removed: the solver derives the memory tie-break
 # weight from the comm-cost scale so it can order comm-equal solutions but
@@ -217,7 +222,8 @@ comm_overlap = _env_bool("EASYDIST_COMM_OVERLAP", False)
 # step); per-call kwargs on ddp_step/zero2_step/zero3_step override.
 grad_accum_microbatches = _env_int("EASYDIST_GRAD_ACCUM_MICROBATCHES", 0)
 # replace peak_flops/hbm_bandwidth defaults with the real device kind's
-# datasheet constants at compile time (unknown backends keep the defaults)
+# datasheet constants at compile time (a CPU host keeps the defaults; an
+# unknown TPU kind raises)
 auto_device_constants = _env_bool("EASYDIST_AUTO_DEVICE_CONSTANTS", True)
 # load measured alpha/beta/HBM values from the PerfDB when present
 # (runtime.calibrate.calibrate() records them on the target hardware)
@@ -233,11 +239,12 @@ enable_analyze = _env_bool("EASYDIST_ANALYZE", True)
 # (the escape hatch for shipping past a false positive while it is triaged)
 analyze_raise = _env_bool("EASYDIST_ANALYZE_RAISE", True)
 # MEM004 HBM budget gate (bytes/device): -1 = auto (ask the real device's
-# memory_stats; unknown backends fall back to hbm_capacity_default), 0 =
+# memory_stats; CPU virtual meshes fall back to hbm_capacity_default), 0 =
 # gate off, >0 = explicit budget.  Unlike per_device_memory_cap (which
 # DRIVES remat), this only verifies — it never changes the program.
 analyze_hbm_budget = _env_int("EASYDIST_ANALYZE_HBM_BUDGET", -1)
-# platform HBM capacity assumed when no real device answers (v5e: 16 GiB)
+# HBM capacity assumed for CPU virtual meshes, which report none (a TPU
+# that reports none raises instead)
 hbm_capacity_default = _env_int("EASYDIST_HBM_CAPACITY", 16 * 2**30)
 # SCHED003: warn when a pipeline tick schedule's static bubble fraction
 # (idle fwd/bwd slots over total slots) exceeds this

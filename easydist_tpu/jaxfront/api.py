@@ -244,6 +244,10 @@ def emit_sharded_fn(closed_jaxpr, names: VarNames,
 
             out = _emit_attention_variant(eqn, strategies, axis_names, mesh,
                                           invals)
+            if out is None and eqn.primitive.name == "pallas_call" \
+                    and mesh.size > 1:
+                out = _bind_whole_per_device(eqn, subfuns, bind_params,
+                                             invals, mesh)
             if out is None:
                 out = eqn.primitive.bind(*subfuns, *invals, **bind_params)
                 if not eqn.primitive.multiple_results:
@@ -256,6 +260,27 @@ def emit_sharded_fn(closed_jaxpr, names: VarNames,
         return [read(v) for v in jaxpr.outvars]
 
     return sharded_fn
+
+
+def _bind_whole_per_device(eqn, subfuns, bind_params, invals, mesh):
+    """Bind a Pallas kernel so that every device of `mesh` runs it whole.
+
+    The solver pins `pallas_call` replicated (presets.py), but replicated
+    is not enough for the TPU lowering: inside a jit that GSPMD partitions
+    it refuses a Mosaic custom call outright ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map").  So
+    the kernel goes under a shard_map over every mesh axis with all
+    operands and results replicated — GSPMD gathers what the neighbours
+    hold sharded, each device computes the full result."""
+    from jax.sharding import PartitionSpec
+
+    def whole(*xs):
+        return eqn.primitive.bind(*subfuns, *xs, **bind_params)
+
+    return jax.shard_map(
+        whole, mesh=mesh, in_specs=tuple(PartitionSpec() for _ in invals),
+        out_specs=[PartitionSpec() for _ in eqn.outvars],
+        check_vma=False)(*invals)
 
 
 def _compile_cache_key(closed_jaxpr, axis_specs) -> str:
@@ -432,6 +457,8 @@ class CompileResult:
         self.remat_plan = None
         self.memory_plan = None  # cached MemoryPlan from the last analyze()
         self.predicted_peak_bytes: Optional[int] = None
+        # set-up seconds {"trace", "discovery", "solve"} (set by compile_step)
+        self.phase_seconds: Dict[str, float] = {}
 
     def analyze(self, include_program: bool = True,
                 include_memory: bool = True):
@@ -737,8 +764,12 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
 
     closed_jaxpr = inline_calls(closed_jaxpr)
     jaxpr = closed_jaxpr.jaxpr
+    # set-up seconds by phase, left on the CompileResult (XLA's own compile
+    # happens later, at the jit's first call, and is the caller's to time)
+    phase_seconds = {"trace": time.perf_counter() - t0, "discovery": 0.0,
+                     "solve": 0.0}
     logger.info("[trace] %d eqns in %.2fs", len(jaxpr.eqns),
-                time.perf_counter() - t0)
+                phase_seconds["trace"])
 
     # measured hardware constants beat datasheet defaults when available
     # (EASYDIST_AUTO_CALIBRATION=0 opts out; run runtime.calibrate() once
@@ -765,6 +796,14 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
         state_pairs = state_io
     out_leaves, out_tree = jax.tree_util.tree_flatten(out_shape)
 
+    def finish(names, per_axis, graph, **findings):
+        result = _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph,
+                                 axis_specs, mesh, args, kwargs, flat_args,
+                                 in_tree, out_tree, state_pairs, donate_state,
+                                 **findings)
+        result.phase_seconds = phase_seconds
+        return result
+
     if cached is not None:
         # names must match the analyzer's assignment order exactly
         names = VarNames()
@@ -784,17 +823,17 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
                 f"compile-cache hit {cache_key}: layer-1 strategy findings "
                 f"were produced by the solving compile; only the emitted-"
                 f"program lint runs here"))
-        return _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph,
-                               axis_specs, mesh, args, kwargs, flat_args,
-                               in_tree, out_tree, state_pairs, donate_state,
-                               analysis_findings=cache_findings)
+        return finish(names, per_axis, graph,
+                      analysis_findings=cache_findings)
 
     # gate shardability on the SMALLEST axis: per-axis pools re-check
     # divisibility, so a dim only shardable on a small axis must not be
     # filtered out by a larger one
     world = min((s.size for s in axis_specs), default=1)
     analyzer = ShardingAnalyzer(closed_jaxpr, world_size=world)
+    t0 = time.perf_counter()
     rules, shape_info = analyzer.run()  # logs its own one-line summary
+    phase_seconds["discovery"] = time.perf_counter() - t0
     names = analyzer.names
     if edconfig.use_op_cost_db:
         from easydist_tpu.runtime.perfdb import record_discovery
@@ -814,10 +853,12 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
     # solver-layer findings
     analysis_findings: List[object] = list(analyzer.findings)
     solver_audits: List[Dict[str, float]] = []
+    t0 = time.perf_counter()
     per_axis, graph = solve_axes(closed_jaxpr, axis_specs, world, rules,
                                  shape_info, names, state_io_names,
                                  findings=analysis_findings,
                                  audits=solver_audits)
+    phase_seconds["solve"] = time.perf_counter() - t0
 
     if edconfig.dump_dir:
         _dump_strategies(graph, [c if c is not None else {} for c in per_axis],
@@ -826,11 +867,9 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
         _strategy_cache_store(cache_key,
                               [c if c is not None else {} for c in per_axis])
 
-    return _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph,
-                           axis_specs, mesh, args, kwargs, flat_args,
-                           in_tree, out_tree, state_pairs, donate_state,
-                           analysis_findings=analysis_findings,
-                           solver_audits=solver_audits)
+    return finish(names, per_axis, graph,
+                  analysis_findings=analysis_findings,
+                  solver_audits=solver_audits)
 
 
 def _replicated_flops_fraction(jaxpr, per_axis_final, axis_specs) -> float:

@@ -319,7 +319,7 @@ def emit_region(region: PartialRegion, jaxpr, env, mesh):
     semantics) back into `env`.  Every mesh axis is manual — in/out specs
     come from the solved placements, so GSPMD cannot re-layout inside."""
     import jax
-    from easydist_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.extend import core as jex_core
     from jax.sharding import PartitionSpec
 

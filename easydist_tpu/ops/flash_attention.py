@@ -31,9 +31,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from easydist_tpu.utils.jax_compat import tpu_compiler_params
-
 _NEG_INF = -1e30
+
+
+def _default_interpret() -> bool:
+    """The Pallas interpreter everywhere but on a TPU, where the kernels
+    compile natively (and a kernel Mosaic refuses fails the call)."""
+    return jax.default_backend() != "tpu"
 
 
 def _pick_block(block: int, t: int) -> int:
@@ -150,8 +154,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
@@ -274,8 +277,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse3, delta3)
@@ -307,8 +309,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse3, delta3)
@@ -361,7 +362,7 @@ def flash_attention_lse(q, k, v, causal: bool = True,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     return _flash_forward(q, k, v, causal, scale, block_q, block_k,
                           interpret)
 
@@ -378,7 +379,7 @@ def _bwd_lse(causal, scale, block_q, block_k, interpret, res, cts):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     # dL/ds_ij = p_ij * (dp_ij - delta_i) + g_lse_i * p_ij: the lse
     # cotangent folds into delta (delta' = delta - g_lse), so the same
     # kernels serve both outputs
@@ -406,43 +407,65 @@ def flash_attention(q, k, v, causal: bool = True,
 # ------------------------------------------------- single-query decode
 
 
+def _decode_block_update(q, k_blk, v_blk, k0, length, o_scr, m_scr, l_scr):
+    """Online-softmax update of one query row against one K/V block, shared
+    by the three decode kernels: q [1, d] (pre-scaled), k_blk/v_blk
+    [bk, d], all f32; `k0` is the block's first cache position and
+    positions >= `length` are masked (unwritten slots, not future
+    tokens)."""
+    s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [1, bk]
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos < length, s, _NEG_INF)
+    m_prev = m_scr[...]                                 # [1, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_scr[...] = m_new
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    o_scr[...] = o_scr[...] * alpha + jnp.dot(
+        p, v_blk, preferred_element_type=jnp.float32)
+
+
+def _decode_init(o_scr, m_scr, l_scr):
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    o_scr[...] = jnp.zeros_like(o_scr)
+
+
+def _decode_result(o_scr, l_scr):
+    return o_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+
+
+def _decode_scratch(d: int):
+    return [pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32)]
+
+
 def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, o_scr, m_scr,
-                         l_scr, *, scale: float, block_k: int, n_k: int):
+                         l_scr, *, scale: float, block_k: int, n_k: int,
+                         heads: int):
     """One query row against a streamed K/V cache: the forward kernel with
-    bq=1 and the causal mask replaced by a per-row length mask (cache
-    positions >= length are unwritten slots, not future tokens)."""
+    bq=1 and the causal mask replaced by a per-row length mask."""
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        o_scr[...] = jnp.zeros_like(o_scr)
+        _decode_init(o_scr, m_scr, l_scr)
 
-    length = len_ref[0, 0]
+    length = len_ref[jax.lax.div(pl.program_id(0), heads)]
 
     @pl.when(ki * block_k < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale        # [1, d]
-        k_blk = k_ref[0].astype(jnp.float32)            # [bk, d]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = q @ k_blk.T                                 # [1, bk]
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, _NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[...] = m_new
-        l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_scr[...] = o_scr[...] * alpha + p @ v_blk
+        _decode_block_update(q_ref[0].astype(jnp.float32) * scale,
+                             k_ref[0].astype(jnp.float32),
+                             v_ref[0].astype(jnp.float32),
+                             ki * block_k, length, o_scr, m_scr, l_scr)
 
     @pl.when(ki == n_k - 1)
     def _write():
-        o_ref[0] = (o_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
-            o_ref.dtype)
+        o_ref[0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
 
 
 def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
@@ -454,7 +477,9 @@ def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
     heads, max_len, head_dim] cache buffers; lengths: int32 [batch] valid
     prefix length per row (positions >= length are masked).  Returns
     [batch, heads, head_dim].  VMEM residency is O(block_k), independent
-    of the cache length.
+    of the cache length.  The lengths ride scalar prefetch (SMEM-resident
+    before the grid runs — a blocked SMEM operand is not a legal TPU
+    block).
     """
     from easydist_tpu import config as edconfig
 
@@ -463,7 +488,7 @@ def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
     if block_k is None:
         block_k = edconfig.decode_block_k
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     b, h, d = q.shape
     t_k = k.shape[2]
     bk = _pick_block(block_k, t_k)
@@ -472,33 +497,29 @@ def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
     qf = q.reshape(b * h, 1, d)
     kf = k.reshape(b * h, t_k, d)
     vf = v.reshape(b * h, t_k, d)
-    # one scalar length per (b, h) row, SMEM-resident for the mask compare
-    lenf = jnp.broadcast_to(
-        lengths.astype(jnp.int32)[:, None], (b, h)).reshape(b * h, 1)
 
     kernel = functools.partial(_flash_decode_kernel, scale=scale,
-                               block_k=bk, n_k=n_k)
-    out = pl.pallas_call(
-        kernel,
+                               block_k=bk, n_k=n_k, heads=h)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b * h, n_k),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, ki: (bh, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, 1, d), lambda bh, ki, len_ref: (bh, 0, 0)),
+            pl.BlockSpec((1, bk, d), lambda bh, ki, len_ref: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, d), lambda bh, ki, len_ref: (bh, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bh, ki: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, d),
+                               lambda bh, ki, len_ref: (bh, 0, 0)),
+        scratch_shapes=_decode_scratch(d),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lenf, qf, kf, vf)
+    )(jnp.asarray(lengths, jnp.int32), qf, kf, vf)
     return out.reshape(b, h, d)
 
 
@@ -641,47 +662,84 @@ def _paged_decode_attention_quant_xla(q, k_pages, v_pages, k_scale,
     return _decode_attention_xla(q, kf, vf, lengths, scale)
 
 
+def _paged_kv_index_map(page_tokens: int, n_pages: int, rep: int):
+    """K/V (and scale) block index for grid (bi, hi, pi): window pi of row
+    bi resolves through the scalar-prefetched page table.  Dead windows
+    (pi past the row's live pages) clamp to the last live one — a repeated
+    index, so Pallas skips the DMA and the kernel's @pl.when skips the
+    compute.  GQA maps query head hi to kv head hi // rep."""
+    def kv_map(bi, hi, pi, tbl_ref, len_ref):
+        last_live = jnp.maximum(
+            jax.lax.div(len_ref[bi] + page_tokens - 1, page_tokens) - 1, 0)
+        page = tbl_ref[bi, jnp.minimum(pi, last_live)]
+        return (jnp.clip(page, 0, n_pages - 1), hi // rep, 0, 0)
+
+    return kv_map
+
+
+def _q_map(bi, hi, pi, tbl_ref, len_ref):
+    return (bi, hi, 0, 0)
+
+
+def _paged_decode_call(kernel, q, pages, table, lengths, interpret: bool):
+    """The pallas_call shared by the exact and the int8 paged kernels:
+    grid (batch, heads, max_pages), table and lengths scalar-prefetched,
+    every `pages` operand ([n_pages, kv_heads, page_tokens, *]) streamed
+    one page per step through the same table index map.  q and the output
+    ride a [batch, heads, 1, head_dim] view so each head's single row is
+    a block whose trailing dims equal the array's — the (1, head_dim)
+    block of a [batch, heads, head_dim] array is not a legal TPU block."""
+    b, h, d = q.shape
+    n_pages, kvh, pt, _ = pages[0].shape
+    mp = table.shape[1]
+    if h % kvh:
+        raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
+    kv_map = _paged_kv_index_map(pt, n_pages, h // kvh)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, h, mp),
+        in_specs=[pl.BlockSpec((1, 1, 1, d), _q_map)] + [
+            pl.BlockSpec((1, 1, pt, a.shape[-1]), kv_map) for a in pages],
+        out_specs=pl.BlockSpec((1, 1, 1, d), _q_map),
+        scratch_shapes=_decode_scratch(d),
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, page_tokens=pt, n_pages_max=mp),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
+      q.reshape(b, h, 1, d), *pages)
+    return out.reshape(b, h, d)
+
+
 def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
                                o_ref, o_scr, m_scr, l_scr, *, scale: float,
                                page_tokens: int, n_pages_max: int):
     """The single-query decode kernel with the K/V stream indirected
     through the page table: grid step pi wants the page holding tokens
     [pi*pt, (pi+1)*pt), and the BlockSpec index map (not the kernel body)
-    resolves it via the scalar-prefetched table, so dead windows clamp to
-    a repeated index and Pallas skips their DMA entirely."""
-    bi = pl.program_id(0)
+    resolves it via the scalar-prefetched table."""
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        o_scr[...] = jnp.zeros_like(o_scr)
+        _decode_init(o_scr, m_scr, l_scr)
 
-    length = len_ref[bi]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(pi * page_tokens < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale        # [1, d]
-        k_blk = k_ref[0, 0].astype(jnp.float32)         # [pt, d]
-        v_blk = v_ref[0, 0].astype(jnp.float32)
-        s = q @ k_blk.T                                 # [1, pt]
-        k_pos = pi * page_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, _NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[...] = m_new
-        l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_scr[...] = o_scr[...] * alpha + p @ v_blk
+        _decode_block_update(q_ref[0, 0].astype(jnp.float32) * scale,
+                             k_ref[0, 0].astype(jnp.float32),
+                             v_ref[0, 0].astype(jnp.float32),
+                             pi * page_tokens, length, o_scr, m_scr, l_scr)
 
     @pl.when(pi == n_pages_max - 1)
     def _write():
-        o_ref[0] = (o_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
-            o_ref.dtype)
+        o_ref[0, 0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
 
 
 def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
@@ -702,53 +760,32 @@ def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, h, d = q.shape
-    n_pages, kvh, pt, _ = k_pages.shape
-    mp = table.shape[1]
-    if h % kvh:
-        raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
-    rep = h // kvh
-    tbl = jnp.asarray(table, jnp.int32)
-    lens = jnp.asarray(lengths, jnp.int32)
+        interpret = _default_interpret()
+    kernel = functools.partial(_flash_paged_decode_kernel, scale=scale)
+    return _paged_decode_call(kernel, q, (k_pages, v_pages), table, lengths,
+                              interpret)
 
-    def kv_map(bi, hi, pi, tbl_ref, len_ref):
-        # dead windows (pi past the row's live pages) clamp to the last
-        # live one: repeated index -> no DMA; @pl.when skips the compute
-        last_live = jnp.maximum(
-            jax.lax.div(len_ref[bi] + pt - 1, pt) - 1, 0)
-        page = tbl_ref[bi, jnp.minimum(pi, last_live)]
-        return (jnp.clip(page, 0, n_pages - 1), hi // rep, 0, 0)
 
-    kernel = functools.partial(_flash_paged_decode_kernel, scale=scale,
-                               page_tokens=pt, n_pages_max=mp)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, d),
-                         lambda bi, hi, pi, tbl_ref, len_ref: (bi, hi, 0)),
-            pl.BlockSpec((1, 1, pt, d), kv_map),
-            pl.BlockSpec((1, 1, pt, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, d), lambda bi, hi, pi, tbl_ref, len_ref: (bi, hi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=tpu_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(tbl, lens, q, k_pages, v_pages)
-    return out
+def _dequant_block(blk_ref, s_ref):
+    """One int8 page [pt, d] times its per-row block scales [pt, nb], in
+    VMEM.  Each scale column is pulled out by a masked lane reduction and
+    selected onto its d // nb payload columns, so everything stays 2-D:
+    Mosaic refuses the lane-splitting reshape(pt, nb, d // nb)
+    ("infer-vector-layout: unsupported shape cast", v5e, libtpu 0.0.34)."""
+    blk = blk_ref[0, 0].astype(jnp.float32)             # [pt, d]
+    sc = s_ref[0, 0]                                    # [pt, nb]
+    nb = sc.shape[1]
+    if nb == 1:
+        return blk * sc
+    col = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    scale = None
+    for j in range(nb):
+        sc_j = jnp.sum(jnp.where(lane == j, sc, 0.0), axis=-1,
+                       keepdims=True)                   # [pt, 1]
+        scale = sc_j if scale is None else jnp.where(
+            col >= j * (blk.shape[1] // nb), sc_j, scale)
+    return blk * scale
 
 
 def _flash_paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
@@ -760,50 +797,24 @@ def _flash_paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
     page-table index map, and dequantization happens in VMEM inside the
     online-softmax loop — the arena stream stays int8 all the way from
     HBM, which is the whole 2-4x bytes/seq win."""
-    bi = pl.program_id(0)
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        o_scr[...] = jnp.zeros_like(o_scr)
+        _decode_init(o_scr, m_scr, l_scr)
 
-    length = len_ref[bi]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(pi * page_tokens < length)
     def _compute():
-        pt, d = k_ref.shape[2], k_ref.shape[3]
-        nb = ks_ref.shape[3]
-        q = q_ref[0].astype(jnp.float32) * scale        # [1, d]
-
-        def dq(blk_ref, s_ref):
-            blk = blk_ref[0, 0].astype(jnp.float32)     # [pt, d]
-            sc = s_ref[0, 0]                            # [pt, nb]
-            if nb == 1:
-                return blk * sc
-            return (blk.reshape(pt, nb, d // nb)
-                    * sc[:, :, None]).reshape(pt, d)
-
-        k_blk = dq(k_ref, ks_ref)
-        v_blk = dq(v_ref, vs_ref)
-        s = q @ k_blk.T                                 # [1, pt]
-        k_pos = pi * page_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, _NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[...] = m_new
-        l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_scr[...] = o_scr[...] * alpha + p @ v_blk
+        _decode_block_update(q_ref[0, 0].astype(jnp.float32) * scale,
+                             _dequant_block(k_ref, ks_ref),
+                             _dequant_block(v_ref, vs_ref),
+                             pi * page_tokens, length, o_scr, m_scr, l_scr)
 
     @pl.when(pi == n_pages_max - 1)
     def _write():
-        o_ref[0] = (o_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
-            o_ref.dtype)
+        o_ref[0, 0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
 
 
 def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
@@ -821,54 +832,11 @@ def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, h, d = q.shape
-    n_pages, kvh, pt, _ = k_pages.shape
-    nb = k_scale.shape[-1]
-    mp = table.shape[1]
-    if h % kvh:
-        raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
-    rep = h // kvh
-    tbl = jnp.asarray(table, jnp.int32)
-    lens = jnp.asarray(lengths, jnp.int32)
-
-    def kv_map(bi, hi, pi, tbl_ref, len_ref):
-        last_live = jnp.maximum(
-            jax.lax.div(len_ref[bi] + pt - 1, pt) - 1, 0)
-        page = tbl_ref[bi, jnp.minimum(pi, last_live)]
-        return (jnp.clip(page, 0, n_pages - 1), hi // rep, 0, 0)
-
-    kernel = functools.partial(_flash_paged_decode_quant_kernel,
-                               scale=scale, page_tokens=pt, n_pages_max=mp)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, d),
-                         lambda bi, hi, pi, tbl_ref, len_ref: (bi, hi, 0)),
-            pl.BlockSpec((1, 1, pt, d), kv_map),
-            pl.BlockSpec((1, 1, pt, d), kv_map),
-            pl.BlockSpec((1, 1, pt, nb), kv_map),
-            pl.BlockSpec((1, 1, pt, nb), kv_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, d), lambda bi, hi, pi, tbl_ref, len_ref: (bi, hi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=tpu_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(tbl, lens, q, k_pages, v_pages, k_scale, v_scale)
-    return out
+        interpret = _default_interpret()
+    kernel = functools.partial(_flash_paged_decode_quant_kernel, scale=scale)
+    return _paged_decode_call(kernel, q,
+                              (k_pages, v_pages, k_scale, v_scale), table,
+                              lengths, interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, table, lengths,

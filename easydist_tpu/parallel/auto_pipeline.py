@@ -38,7 +38,7 @@ from typing import Callable, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.extend import core as jex_core
 from jax.sharding import PartitionSpec as P
 

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from easydist_tpu import comm
 from easydist_tpu import config as edconfig
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 

@@ -15,7 +15,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

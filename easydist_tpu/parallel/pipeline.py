@@ -25,7 +25,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

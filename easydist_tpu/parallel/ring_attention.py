@@ -19,7 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -1,6 +1,6 @@
 """Circuit breaker for the serving engine.
 
-When the executor starts failing persistently (device wedged, tunnel down,
+When the executor starts failing persistently (device wedged, host link down,
 every batch timing out), retrying each request individually multiplies the
 damage: every queued request burns a full watchdog timeout before failing,
 latency explodes, and the queue stays pinned at capacity.  The breaker

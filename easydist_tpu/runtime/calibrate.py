@@ -9,9 +9,10 @@ the achieved comm/compute overlap fraction (what the backward-ordered
 flush in `comm.overlap` actually hides) and persists it alongside.
 `apply_calibration()` loads the stored fit into the solver's config so
 strategy costs reflect measured hardware instead of datasheet defaults;
-`apply_device_constants()` swaps the hardcoded v5e `peak_flops`/
+`apply_device_constants()` swaps the configured `peak_flops`/
 `hbm_bandwidth` defaults for the REAL device kind's datasheet values
-(prefix-matched, unknown backends keep the configured constants).
+(prefix-matched; a CPU host keeps the configured constants, a TPU kind the
+table does not know is an error).
 """
 
 from __future__ import annotations
@@ -37,7 +38,10 @@ _device_applied = None
 # per-chip datasheet constants by device-kind prefix (lowercased; first
 # match wins, so more specific prefixes come first).  peak_flops is the
 # bf16 MXU peak — the bound on how fast independent compute can hide a
-# collective; hbm_bandwidth in bytes/s.
+# collective; hbm_bandwidth in bytes/s.  The ONE table of peaks: bench.py
+# and chip_smoke.py read it through `detect_device_constants`.  Source:
+# Google Cloud TPU documentation, per-generation system architecture
+# pages (v5e: 197 TFLOP/s bf16, 819 GB/s HBM).
 _DEVICE_DATASHEET = (
     ("tpu v6 lite", {"peak_flops": 918e12, "hbm_bandwidth": 1.6e12}),
     ("tpu v5 lite", {"peak_flops": 197e12, "hbm_bandwidth": 8.1e11}),
@@ -55,17 +59,38 @@ def _backend_key() -> str:
 def detect_device_constants(device_kind: Optional[str] = None
                             ) -> Optional[Dict[str, float]]:
     """Datasheet constants for `device_kind` (default: the first visible
-    device), or None when the kind is unknown — CPU hosts and future TPU
-    generations keep the configured defaults."""
+    device).  None for a host that is not a TPU (CPU meshes keep the
+    configured defaults); a TPU kind missing from the table raises — a
+    cost model or an MFU priced against another chip's peak is wrong
+    without saying so."""
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # pragma: no cover - no backend at all
-            return None
+        device_kind = jax.devices()[0].device_kind
     kind = str(device_kind).lower()
     for prefix, consts in _DEVICE_DATASHEET:
         if kind.startswith(prefix):
             return dict(consts)
+    if kind.startswith("tpu"):
+        raise ValueError(
+            f"unknown TPU device kind {device_kind!r}: add its datasheet "
+            f"peaks to runtime/calibrate.py::_DEVICE_DATASHEET")
+    return None
+
+
+def device_memory_limit(mesh) -> Optional[int]:
+    """Per-device memory limit in bytes as the mesh's first device of this
+    process reports it (`memory_stats()["bytes_limit"]`; another host's
+    devices cannot be asked), or None on a backend that reports none (CPU
+    virtual meshes).  A TPU that reports none raises: the HBM cap drives
+    remat and the MEM004 gate, and an assumed capacity there plans for a
+    chip that is not the one in the machine."""
+    dev = mesh.local_devices[0]
+    stats = dev.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if limit:
+        return int(limit)
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev} reports no memory limit (memory_stats() = {stats!r})")
     return None
 
 
@@ -99,12 +124,6 @@ def apply_device_constants(force: bool = False) -> bool:
     return applied
 
 
-def _time_fn(fn, *args, iters=12):
-    from easydist_tpu.utils.timer import two_point_time
-
-    return two_point_time(fn, args, n1=max(2, iters // 4), n2=iters)
-
-
 def calibrate(mesh=None, axis: Optional[str] = None,
               persist: bool = True) -> Dict[str, float]:
     """Measure and (optionally) persist cost-model parameters.
@@ -112,14 +131,16 @@ def calibrate(mesh=None, axis: Optional[str] = None,
     Returns {"hbm_bandwidth", "ici_bandwidth", "ici_latency"} in the
     solver's units (bytes/s, seconds/launch).
     """
-    from easydist_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from easydist_tpu.utils.timer import time_per_call
 
     # HBM-bound bandwidth: big elementwise op, bytes moved = read + write
     n = 1 << 24  # 64 MiB f32
     x = jnp.ones((n,), jnp.float32)
     mul = jax.jit(lambda a: a * 1.000001)
-    t = _time_fn(mul, x)
+    t = time_per_call(mul, (x,))
     hbm = 2 * 4 * n / max(t, 1e-9)
 
     result = {"hbm_bandwidth": float(hbm)}
@@ -128,7 +149,7 @@ def calibrate(mesh=None, axis: Optional[str] = None,
         axis = axis or mesh.axis_names[0]
         world = mesh.shape[axis]
 
-        # build ONE jitted collective; _time_fn warms each shape before
+        # build ONE jitted collective; time_per_call warms each shape before
         # timing, so the loop measures dispatch+collective, never retracing
         ar = jax.jit(shard_map(lambda v: jax.lax.psum(v, axis), mesh=mesh,
                                in_specs=P(axis), out_specs=P(),
@@ -136,8 +157,8 @@ def calibrate(mesh=None, axis: Optional[str] = None,
 
         big_elems = 1 << 22  # 16 MiB f32 global
         small_elems = world  # one element per shard
-        t_big = _time_fn(ar, jnp.ones((big_elems,), jnp.float32))
-        t_small = _time_fn(ar, jnp.ones((small_elems,), jnp.float32))
+        t_big = time_per_call(ar, (jnp.ones((big_elems,), jnp.float32),))
+        t_small = time_per_call(ar, (jnp.ones((small_elems,), jnp.float32),))
         # alpha-beta fit: t = alpha + bytes_wire / bw, with all_reduce wire
         # bytes = 2 * size * (n-1)/n
         alpha = max(t_small, 1e-9)

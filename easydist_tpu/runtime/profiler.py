@@ -86,7 +86,7 @@ def measure_collective_overlap(mesh, axis: Optional[str] = None,
     overlap_discount_ratio`); `runtime.calibrate.calibrate_overlap`
     persists it per backend.
 
-    Each timing is the MIN over ``repeats`` independent two-point samples:
+    Each timing is the MIN over ``repeats`` independent samples:
     scheduler noise only inflates wall time, and a transient spike on
     t_both alone would otherwise read as negative overlap.  The default
     sizes put t_comm and t_compute within ~2x of each other on both the
@@ -97,8 +97,8 @@ def measure_collective_overlap(mesh, axis: Optional[str] = None,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from easydist_tpu.utils.jax_compat import shard_map
-    from easydist_tpu.utils.timer import two_point_time
+    from jax import shard_map
+    from easydist_tpu.utils.timer import time_per_call
 
     axis = axis or mesh.axis_names[0]
     world = mesh.shape[axis]
@@ -125,15 +125,13 @@ def measure_collective_overlap(mesh, axis: Optional[str] = None,
 
     v = jnp.ones((n_elems,), jnp.float32)
     a = jnp.ones((compute_dim, compute_dim), jnp.float32) * 1e-2
-    n1, n2 = max(2, iters // 4), iters
     repeats = max(1, repeats)
     # interleaved rounds so slow machine-load drift hits all three alike
     t_comm = t_compute = t_both = float("inf")
     for _ in range(repeats):
-        t_comm = min(t_comm, two_point_time(comm_fn, (v,), n1=n1, n2=n2))
-        t_compute = min(t_compute,
-                        two_point_time(comp_fn, (a,), n1=n1, n2=n2))
-        t_both = min(t_both, two_point_time(both_fn, (v, a), n1=n1, n2=n2))
+        t_comm = min(t_comm, time_per_call(comm_fn, (v,), iters=iters))
+        t_compute = min(t_compute, time_per_call(comp_fn, (a,), iters=iters))
+        t_both = min(t_both, time_per_call(both_fn, (v, a), iters=iters))
 
     hidden = t_comm + t_compute - t_both
     frac = hidden / max(min(t_comm, t_compute), 1e-12)

@@ -448,19 +448,13 @@ def resolve_memory_cap(mesh) -> int:
     applied uniformly (the solver's liveness constraint scales the same
     way — an explicit cap without the ratio would ship programs with none
     of the allocator headroom the ratio exists to provide).  Config wins
-    when set (>0); 0 disables; the default (-1) asks the real device (TPU
-    memory_stats bytes_limit).  Unknown (CPU virtual meshes) -> uncapped."""
+    when set (>0); 0 disables; the default (-1) asks the real device
+    (`runtime.calibrate.device_memory_limit`).  A backend that reports no
+    limit (CPU virtual meshes) -> uncapped."""
+    from easydist_tpu.runtime.calibrate import device_memory_limit
+
     cap = edconfig.per_device_memory_cap
     if cap >= 0:
         return int(cap * edconfig.memory_ratio) if cap > 0 else 0
-    try:
-        dev = np.asarray(mesh.devices).flat[0]
-        stats = dev.memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit") or stats.get(
-                "bytes_reservable_limit")
-            if limit:
-                return int(limit * edconfig.memory_ratio)
-    except Exception:
-        pass
-    return 0
+    limit = device_memory_limit(mesh)
+    return int(limit * edconfig.memory_ratio) if limit else 0

@@ -1,7 +1,11 @@
 """GPT training with automatic parallelization + elastic checkpointing
 (reference: examples/jax/test_gpt.py and benchmark/torch/pp/gpt/).
 
-python examples/jax/train_gpt.py [--steps 20] [--tiny]
+python examples/jax/train_gpt.py [--steps 20] [--no-tiny]
+
+Runs on the devices JAX reports.  For a virtual mesh take it from the
+environment, as the test suite does:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 """
 
 import argparse
@@ -10,18 +14,15 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-
-if not os.environ.get("EASYDIST_REAL_DEVICES"):
-    from easydist_tpu.utils.testing import force_cpu_devices
-
-    force_cpu_devices(8)
 import jax  # noqa: E402
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=10)
-    ap.add_argument("--tiny", action="store_true", default=True)
+    ap.add_argument("--tiny", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the tiny smoke config; --no-tiny for GPT-2 small")
     ap.add_argument("--ckpt", default="/tmp/easydist_gpt_ckpt")
     args = ap.parse_args()
 

@@ -11,7 +11,7 @@ from easydist_tpu import config as edconfig
 from easydist_tpu.analyze import (AnalysisError, check_bucket_plan, lint_fn,
                                   lint_bucket_plan, lint_jaxpr)
 from easydist_tpu.comm.bucketer import plan_buckets
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def dp_mesh(devices):

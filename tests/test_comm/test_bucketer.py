@@ -11,7 +11,7 @@ from easydist_tpu import config as edconfig
 from easydist_tpu.comm import (comm_counters, pack, plan_buckets,
                                reduce_gradients, unpack)
 from easydist_tpu.jaxfront import make_device_mesh
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _leaves():

@@ -185,7 +185,7 @@ multihost_setup(coordinator=coordinator, num_processes=2, process_id=rank)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from easydist_tpu.jaxfront import make_device_mesh
 from easydist_tpu.comm import quantized_psum
 mesh = make_device_mesh((2, 2), ("dcn", "ici"), dcn_axes=("dcn",))

@@ -188,7 +188,7 @@ def test_accum_grads_bitwise_with_zero_style_reducer(mesh_dp, exact_comm,
     from jax.sharding import PartitionSpec as P
 
     from easydist_tpu import comm
-    from easydist_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     params, x, y = _data(20)
     n = 8

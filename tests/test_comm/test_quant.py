@@ -14,7 +14,7 @@ from easydist_tpu.comm import (bf16_psum, comm_counters, dequantize_blockwise,
                                quantized_psum, quantized_psum_scatter,
                                reduce_gradients)
 from easydist_tpu.jaxfront import make_device_mesh
-from easydist_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(scope="module")

@@ -1,0 +1,132 @@
+"""Cross-lower every Pallas entry point of ops/flash_attention.py for TPU from
+the CPU (`lowering_platforms=("tpu",)`), so a BlockSpec the TPU lowering
+refuses fails tier-1 instead of waiting for a chip.  This runs Pallas' own
+jaxpr -> Mosaic lowering; Mosaic's compile is chip_smoke.py's job.
+
+Shapes: the ones chip_smoke.py uses (GPT-2 small: 12 heads of 64, bf16,
+64-token pages) and a GQA shape (32 query heads over 8 kv heads of 128).
+Also a kernel inside a program emitted for a (2, 2) mesh, and the int8
+paged kernel against its XLA reference under the interpreter, which no
+other test compares."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from easydist_tpu.ops.flash_attention import (
+    _paged_decode_attention_quant_xla, flash_attention,
+    flash_decode_attention, flash_paged_decode_attention,
+    flash_paged_decode_quant_attention, kv_quantize)
+
+BF16 = jnp.bfloat16
+SEQ, PAGE_TOKENS, N_PAGES = 1024, 64, 48
+# (batch, heads, kv_heads, head_dim)
+SHAPES = [pytest.param(8, 12, 12, 64, id="gpt2-small"),
+          pytest.param(4, 32, 8, 128, id="gqa-32-8-128")]
+
+
+def _lower_for_tpu(fn, *avals):
+    return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+def _aval(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
+def test_flash_forward_and_backward_lower(b, h, kvh, d):
+    qkv = _aval((b, h, SEQ, d), BF16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, interpret=False).astype(
+            jnp.float32).sum()
+
+    _lower_for_tpu(lambda q, k, v: flash_attention(
+        q, k, v, True, interpret=False), qkv, qkv, qkv)
+    _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
+def test_bucketed_decode_lowers(b, h, kvh, d):
+    cache = _aval((b, h, SEQ, d), BF16)
+    _lower_for_tpu(
+        lambda q, k, v, n: flash_decode_attention(q, k, v, n,
+                                                  interpret=False),
+        _aval((b, h, d), BF16), cache, cache, _aval((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
+def test_paged_decode_lowers(b, h, kvh, d):
+    pages = _aval((N_PAGES, kvh, PAGE_TOKENS, d), BF16)
+    _lower_for_tpu(
+        lambda q, k, v, t, n: flash_paged_decode_attention(
+            q, k, v, t, n, interpret=False),
+        _aval((b, h, d), BF16), pages, pages,
+        _aval((b, SEQ // PAGE_TOKENS), jnp.int32), _aval((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("n_blocks", [1, 2])
+@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
+def test_int8_paged_decode_lowers(b, h, kvh, d, n_blocks):
+    pages = _aval((N_PAGES, kvh, PAGE_TOKENS, d), jnp.int8)
+    scales = _aval((N_PAGES, kvh, PAGE_TOKENS, n_blocks), jnp.float32)
+    _lower_for_tpu(
+        lambda q, k, v, ks, vs, t, n: flash_paged_decode_quant_attention(
+            q, k, v, ks, vs, t, n, interpret=False),
+        _aval((b, h, d), BF16), pages, pages, scales, scales,
+        _aval((b, SEQ // PAGE_TOKENS), jnp.int32), _aval((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("n_blocks", [1, 2])
+@pytest.mark.parametrize("h,kvh", [(4, 4), (4, 2)])
+def test_int8_paged_kernel_matches_xla_reference(h, kvh, n_blocks):
+    pt, max_pages, n_pages, d = 8, 4, 16, 16
+    lengths = [32, 17, 1]
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(keys[0], (len(lengths), h, d), jnp.float32)
+    kq, ks = kv_quantize(
+        jax.random.normal(keys[1], (n_pages, kvh, pt, d)), n_blocks)
+    vq, vs = kv_quantize(
+        jax.random.normal(keys[2], (n_pages, kvh, pt, d)), n_blocks)
+    table = np.full((len(lengths), max_pages), n_pages, np.int32)
+    for row, n in enumerate(lengths):
+        live = -(-n // pt)
+        table[row, :live] = np.arange(live) * len(lengths) + row
+    table = jnp.asarray(table)
+    lens = jnp.asarray(lengths, jnp.int32)
+    scale = 1.0 / np.sqrt(d)
+
+    got = flash_paged_decode_quant_attention(q, kq, vq, ks, vs, table, lens,
+                                             interpret=True)
+    want = _paged_decode_attention_quant_xla(q, kq, vq, ks, vs, table, lens,
+                                             scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_kernel_inside_a_sharded_program_lowers(monkeypatch, cpu_devices):
+    """On a multi-device mesh the emitted program must carry its Pallas
+    kernels under a shard_map: inside a jit that GSPMD partitions, the TPU
+    lowering refuses a Mosaic custom call outright ("Mosaic kernels cannot
+    be automatically partitioned") — which is how the flash-attention train
+    step first failed on the four-chip host."""
+    import importlib
+
+    from easydist_tpu.jaxfront import make_device_mesh
+    from easydist_tpu.jaxfront.api import compile_step
+    from easydist_tpu.models import GPTConfig, make_gpt_train_step
+
+    # trace the kernels as a TPU would: compiled, not interpreted
+    monkeypatch.setattr(
+        importlib.import_module("easydist_tpu.ops.flash_attention"),
+        "_default_interpret", lambda: False)
+    mesh = make_device_mesh((2, 2), ("dp", "tp"), devices=cpu_devices[:4])
+    cfg = GPTConfig(vocab=512, seq=128, dim=128, heads=2, layers=1,
+                    dtype="bfloat16", attention="flash")
+    step, init_state = make_gpt_train_step(cfg)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = _aval((4, cfg.seq), jnp.int32)
+    result = compile_step(step, (state, tokens, tokens), {}, mesh=mesh)
+    assert "pallas_call" in str(result.closed_jaxpr)
+    result.jitted.trace(*result.in_avals).lower(lowering_platforms=("tpu",))
