@@ -367,7 +367,6 @@ _validate_resilience()
 
 # ---------------- profiling / perf db ----------------
 prof_db_path = os.environ.get("EASYDIST_PERF_DB", os.path.expanduser("~/.easydist_tpu/perf.db"))
-enable_runtime_prof = _env_bool("EASYDIST_RUNTIME_PROF", False)
 # price solver compute-redundancy with measured per-op seconds from the
 # PerfDB when available (runtime/op_profile.py); proxy otherwise
 use_op_cost_db = _env_bool("EASYDIST_OP_COST_DB", True)

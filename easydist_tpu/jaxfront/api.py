@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from easydist_tpu import config as edconfig
 from easydist_tpu.autoflow import SpmdSolver
 from easydist_tpu.metashard.metair import NodeStrategy, Placement
+from easydist_tpu.runtime import spans
 from .bridge import _eqn_flops, jaxpr_to_metagraph
 from .interpreter import ShardingAnalyzer, VarNames
 from .mesh import get_axis_specs, get_device_mesh, make_device_mesh
@@ -459,6 +460,27 @@ class CompileResult:
         self.predicted_peak_bytes: Optional[int] = None
         # set-up seconds {"trace", "discovery", "solve"} (set by compile_step)
         self.phase_seconds: Dict[str, float] = {}
+        self.name = "step"  # the wrapped function's (set by _finish_compile)
+
+    def dispatch(self, args, kwargs):
+        """`tree_jitted(*args, **kwargs)` inside an `easydist.step.call`
+        span, with no fence.
+        A dispatch during which the jit's executable cache grew
+        (`_cache_size()`) made XLA compile the program or load it from the
+        persistent cache — for new shapes, or for the same shapes under
+        other input shardings, which `CompiledFunction`'s own cache cannot
+        see: it is counted as `xla_compiles{fn=<name>}` and its interval
+        recorded as `easydist.step.compile`."""
+        jitted = self.tree_jitted
+        before = jitted._cache_size()
+        with spans.span("easydist.step.call", fn=self.name) as sp:
+            out = jitted(*args, **kwargs)
+            if jitted._cache_size() > before:
+                spans.count("xla_compiles", fn=self.name)
+                spans.record_span("easydist.step.compile", sp.t0_ns,
+                                  time.perf_counter_ns(), parent_id=sp.id,
+                                  fn=self.name)
+        return out
 
     def analyze(self, include_program: bool = True,
                 include_memory: bool = True):
@@ -754,20 +776,20 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
         mesh = make_device_mesh()
     axis_specs = get_axis_specs(mesh)
 
-    t0 = time.perf_counter()
+    from .inline import inline_calls
     from .scope import _compile_mesh_ctx
 
-    with _compile_mesh_ctx(mesh):
-        closed_jaxpr, out_shape = jax.make_jaxpr(func, return_shape=True)(
-            *args, **kwargs)
-    from .inline import inline_calls
-
-    closed_jaxpr = inline_calls(closed_jaxpr)
+    fn_name = getattr(func, "__name__", "step")
+    with spans.span("easydist.compile.trace", fn=fn_name) as sp:
+        with _compile_mesh_ctx(mesh):
+            closed_jaxpr, out_shape = jax.make_jaxpr(
+                func, return_shape=True)(*args, **kwargs)
+        closed_jaxpr = inline_calls(closed_jaxpr)
     jaxpr = closed_jaxpr.jaxpr
-    # set-up seconds by phase, left on the CompileResult (XLA's own compile
-    # happens later, at the jit's first call, and is the caller's to time)
-    phase_seconds = {"trace": time.perf_counter() - t0, "discovery": 0.0,
-                     "solve": 0.0}
+    # set-up seconds by phase, left on the CompileResult and taken from the
+    # `easydist.compile.*` spans (XLA's own compile happens later, at the
+    # jit's first call: `easydist.step.compile`)
+    phase_seconds = {"trace": sp.seconds, "discovery": 0.0, "solve": 0.0}
     logger.info("[trace] %d eqns in %.2fs", len(jaxpr.eqns),
                 phase_seconds["trace"])
 
@@ -797,10 +819,11 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
     out_leaves, out_tree = jax.tree_util.tree_flatten(out_shape)
 
     def finish(names, per_axis, graph, **findings):
-        result = _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph,
-                                 axis_specs, mesh, args, kwargs, flat_args,
-                                 in_tree, out_tree, state_pairs, donate_state,
-                                 **findings)
+        with spans.span("easydist.compile.emit", fn=fn_name):
+            result = _finish_compile(
+                closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
+                mesh, args, kwargs, flat_args, in_tree, out_tree,
+                state_pairs, donate_state, name=fn_name, **findings)
         result.phase_seconds = phase_seconds
         return result
 
@@ -831,9 +854,9 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
     # filtered out by a larger one
     world = min((s.size for s in axis_specs), default=1)
     analyzer = ShardingAnalyzer(closed_jaxpr, world_size=world)
-    t0 = time.perf_counter()
-    rules, shape_info = analyzer.run()  # logs its own one-line summary
-    phase_seconds["discovery"] = time.perf_counter() - t0
+    with spans.span("easydist.compile.discovery", fn=fn_name) as sp:
+        rules, shape_info = analyzer.run()  # logs its own one-line summary
+    phase_seconds["discovery"] = sp.seconds
     names = analyzer.names
     if edconfig.use_op_cost_db:
         from easydist_tpu.runtime.perfdb import record_discovery
@@ -853,12 +876,12 @@ def compile_step(func, args, kwargs, mesh=None, state_io="auto",
     # solver-layer findings
     analysis_findings: List[object] = list(analyzer.findings)
     solver_audits: List[Dict[str, float]] = []
-    t0 = time.perf_counter()
-    per_axis, graph = solve_axes(closed_jaxpr, axis_specs, world, rules,
-                                 shape_info, names, state_io_names,
-                                 findings=analysis_findings,
-                                 audits=solver_audits)
-    phase_seconds["solve"] = time.perf_counter() - t0
+    with spans.span("easydist.compile.solve", fn=fn_name) as sp:
+        per_axis, graph = solve_axes(closed_jaxpr, axis_specs, world, rules,
+                                     shape_info, names, state_io_names,
+                                     findings=analysis_findings,
+                                     audits=solver_audits)
+    phase_seconds["solve"] = sp.seconds
 
     if edconfig.dump_dir:
         _dump_strategies(graph, [c if c is not None else {} for c in per_axis],
@@ -937,9 +960,12 @@ def _xla_peak_bytes(closed_jaxpr, names, per_axis_final, axis_specs, mesh,
 def _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
                     mesh, args, kwargs, flat_args, in_tree, out_tree,
                     state_pairs, donate_state, analysis_findings=None,
-                    solver_audits=None):
+                    solver_audits=None, name="step"):
     """Emission + jit from solved strategies (shared by the fresh-solve and
-    compile-cache paths)."""
+    compile-cache paths).  `name` is the wrapped function's: both jits take
+    it, so that the device trace's `XLA Modules` line reads
+    `jit_train_step(...)`, `jit__decode_paged(...)` and not `jit_tree_fn`
+    for every program."""
     axis_names = [s.name for s in axis_specs]
     per_axis_final = [c if c is not None else {} for c in per_axis]
 
@@ -1063,6 +1089,7 @@ def _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
         donate_state = edconfig.enable_donation
     donate = tuple(sorted(set(state_pairs.values()))) if donate_state else ()
 
+    sharded_fn.__name__ = sharded_fn.__qualname__ = name + "_flat"
     jitted = jax.jit(sharded_fn, in_shardings=in_shardings,
                      donate_argnums=donate)
 
@@ -1102,6 +1129,7 @@ def _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
             if n and all(base + k in donated for k in range(n)):
                 donate_args.append(i)
             base += n
+    tree_fn.__name__ = tree_fn.__qualname__ = name
     tree_jitted = jax.jit(tree_fn, donate_argnums=tuple(donate_args))
 
     in_avals = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
@@ -1109,6 +1137,7 @@ def _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
     result = CompileResult(jitted, tree_jitted, in_shardings, per_axis_final,
                            graph, mesh, in_tree, out_tree, len(flat_args),
                            in_avals=in_avals)
+    result.name = name
     result.remat_plan = remat_plan
     result.closed_jaxpr = closed_jaxpr
     # donation audit surface (analyze.audit_decode_donation / SERVE001):
@@ -1139,8 +1168,6 @@ class CompiledFunction:
         self.compile_only = compile_only
         self._cache: Dict[object, CompileResult] = {}
         self._last: Optional[CompileResult] = None
-        self._perfdb = None
-        self._warmed: set = set()
         self._cache_hits = 0
         self._cache_misses = 0
         functools.update_wrapper(self, func)
@@ -1233,12 +1260,10 @@ class CompiledFunction:
 
     def __call__(self, *args, **kwargs):
         if not self.compile_only and self._last is not None:
-            # hot path: zero Python beyond jit dispatch; a shape/tree change
+            # hot path: one span round the jit dispatch; a shape/tree change
             # raises SignatureMismatch during retrace and falls through
             try:
-                if edconfig.enable_runtime_prof:
-                    return self._profiled_call(args, kwargs)
-                return self._last.tree_jitted(*args, **kwargs)
+                return self._last.dispatch(args, kwargs)
             except SignatureMismatch:
                 pass
         flat_args, treedef = jax.tree_util.tree_flatten((args, kwargs))
@@ -1246,35 +1271,7 @@ class CompiledFunction:
         self._last = result
         if self.compile_only:
             return result
-        if edconfig.enable_runtime_prof:
-            return self._profiled_call(args, kwargs)
-        return result.tree_jitted(*args, **kwargs)
-
-    def _profiled_call(self, args, kwargs):
-        """Fenced per-step timing recorded into the persistent PerfDB
-        (EASYDIST_RUNTIME_PROF; reference graph_profile_db)."""
-        from easydist_tpu.runtime.perfdb import PerfDB
-
-        t0 = time.perf_counter()
-        out = self._last.tree_jitted(*args, **kwargs)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        if id(self._last) not in self._warmed:
-            # first call pays trace + XLA compile; recording it would put a
-            # 100-1000x outlier into the persistent step-time history
-            self._warmed.add(id(self._last))
-            return out
-        if self._perfdb is None:
-            self._perfdb = PerfDB()
-        key = getattr(self.func, "__name__", "step")
-        hist = self._perfdb.get_op_perf("step_times", key) or []
-        hist = (hist + [dt])[-64:]
-        self._perfdb.record_op_perf("step_times", key, hist)
-        try:
-            self._perfdb.persist()
-        except Exception:
-            pass
-        return out
+        return result.dispatch(args, kwargs)
 
 
 def easydist_compile(func=None, mesh=None, state_io="auto",

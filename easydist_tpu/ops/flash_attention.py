@@ -133,31 +133,33 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                block_q=bq, block_k=bk, n_k=n_k)
     kv_map = _kv_index_map(causal, bq, bk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(b * h, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
+                jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_fwd",
+        )(qf, kf, vf)
     return out.reshape(b, h, t_q, d), lse.reshape(b * h, t_q)
 
 
@@ -263,56 +265,60 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
                                   scale=scale, block_q=bq, block_k=bk,
                                   n_k=n_k)
     kv_map = _kv_index_map(causal, bq, bk)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse3, delta3)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=(b * h, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+            out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(qf, kf, vf, dof, lse3, delta3)
 
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                                    scale=scale, block_q=bq, block_k=bk,
                                    n_q=n_q)
     q_map = _q_index_map(causal, bq, bk)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b * h, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-            pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bq, 1), q_map),
-            pl.BlockSpec((1, bq, 1), q_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse3, delta3)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            dkv_kernel,
+            grid=(b * h, n_k, n_q),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
+                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, bq, 1), q_map),
+                pl.BlockSpec((1, bq, 1), q_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
+                jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_bwd_dkv",
+        )(qf, kf, vf, dof, lse3, delta3)
 
     return (dq.reshape(b, h, t_q, d), dk.reshape(b, h, t_k, d),
             dv.reshape(b, h, t_k, d))
@@ -512,14 +518,16 @@ def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
                                lambda bh, ki, len_ref: (bh, 0, 0)),
         scratch_shapes=_decode_scratch(d),
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(lengths, jnp.int32), qf, kf, vf)
+    with jax.named_scope("flash_decode"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_decode",
+        )(jnp.asarray(lengths, jnp.int32), qf, kf, vf)
     return out.reshape(b, h, d)
 
 
@@ -681,8 +689,10 @@ def _q_map(bi, hi, pi, tbl_ref, len_ref):
     return (bi, hi, 0, 0)
 
 
-def _paged_decode_call(kernel, q, pages, table, lengths, interpret: bool):
-    """The pallas_call shared by the exact and the int8 paged kernels:
+def _paged_decode_call(kernel, name: str, q, pages, table, lengths,
+                       interpret: bool):
+    """The pallas_call shared by the exact and the int8 paged kernels
+    (`name`: `paged_decode` or `paged_decode_int8`, as the trace shows it):
     grid (batch, heads, max_pages), table and lengths scalar-prefetched,
     every `pages` operand ([n_pages, kv_heads, page_tokens, *]) streamed
     one page per step through the same table index map.  q and the output
@@ -703,15 +713,17 @@ def _paged_decode_call(kernel, q, pages, table, lengths, interpret: bool):
         out_specs=pl.BlockSpec((1, 1, 1, d), _q_map),
         scratch_shapes=_decode_scratch(d),
     )
-    out = pl.pallas_call(
-        functools.partial(kernel, page_tokens=pt, n_pages_max=mp),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      q.reshape(b, h, 1, d), *pages)
+    with jax.named_scope(name):
+        out = pl.pallas_call(
+            functools.partial(kernel, page_tokens=pt, n_pages_max=mp),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name=name,
+        )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
+          q.reshape(b, h, 1, d), *pages)
     return out.reshape(b, h, d)
 
 
@@ -762,8 +774,8 @@ def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
     if interpret is None:
         interpret = _default_interpret()
     kernel = functools.partial(_flash_paged_decode_kernel, scale=scale)
-    return _paged_decode_call(kernel, q, (k_pages, v_pages), table, lengths,
-                              interpret)
+    return _paged_decode_call(kernel, "paged_decode", q, (k_pages, v_pages),
+                              table, lengths, interpret)
 
 
 def _dequant_block(blk_ref, s_ref):
@@ -834,7 +846,7 @@ def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
     if interpret is None:
         interpret = _default_interpret()
     kernel = functools.partial(_flash_paged_decode_quant_kernel, scale=scale)
-    return _paged_decode_call(kernel, q,
+    return _paged_decode_call(kernel, "paged_decode_int8", q,
                               (k_pages, v_pages, k_scale, v_scale), table,
                               lengths, interpret)
 

@@ -8,9 +8,8 @@ pickle DB keeps its shape.
 
 from .checkpoint import save_checkpoint, load_checkpoint, latest_step  # noqa: F401
 from .perfdb import PerfDB  # noqa: F401
-from .profiler import (profile_compiled, op_cost_analysis,  # noqa: F401
-                       memory_analysis, serving_history,
-                       measure_collective_overlap)
+from .profiler import (op_cost_analysis, memory_analysis,  # noqa: F401
+                       serving_history, measure_collective_overlap)
 from .elastic import run_training, multihost_setup  # noqa: F401
 from .data import TokenLoader  # noqa: F401
 from .calibrate import (calibrate, apply_calibration,  # noqa: F401

@@ -1,8 +1,8 @@
 """Profiling & cost analysis on compiled programs.
 
 TPU replacement for the reference's profiling stack: per-op runtime
-benchmarking (passes/runtime_prof.py) becomes XLA cost analysis + wall-clock
-timing of the compiled program; the CUPTI C++ stream tracer
+benchmarking (passes/runtime_prof.py) becomes XLA cost analysis + the
+`easydist.step.call` spans of `runtime/spans.py`; the CUPTI C++ stream tracer
 (csrc/stream_tracer.cpp) becomes `jax.profiler` traces (XLA already exposes
 per-op scheduling); allocator profiling becomes `memory_analysis()` on the
 compiled executable.
@@ -10,7 +10,6 @@ compiled executable.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, Optional
 
 import jax
@@ -56,9 +55,8 @@ def serving_history(sub_key: str = "engine",
     """Recorded serving-metrics snapshots for one engine (the export
     target of `easydist_tpu.serve.ServeMetrics.export`): bounded history
     of {counters, gauges, latency percentiles, batch_occupancy,
-    compile_cache_hit_rate} dicts, oldest first.  Serving history lives in
-    the same PerfDB as step-time history (EASYDIST_RUNTIME_PROF), so one
-    store answers both "how fast is the step" and "how is it serving"."""
+    compile_cache_hit_rate} dicts, oldest first.  Step and phase times are
+    not kept here: they are spans (`runtime/spans.py`)."""
     if db is None:
         db = PerfDB()
     return db.get_op_perf("serving", sub_key) or []
@@ -138,30 +136,3 @@ def measure_collective_overlap(mesh, axis: Optional[str] = None,
     return {"t_comm": float(t_comm), "t_compute": float(t_compute),
             "t_both": float(t_both),
             "overlap_fraction": float(min(max(frac, 0.0), 1.0))}
-
-
-def profile_compiled(fn, args, key: Optional[str] = None,
-                     trials: int = 5, warmup: int = 2,
-                     db: Optional[PerfDB] = None,
-                     trace_dir: Optional[str] = None) -> float:
-    """Wall-clock seconds/call of `fn(*args)`, optionally recorded into the
-    perf DB and captured as a `jax.profiler` trace for xprof."""
-    out = None
-    for _ in range(warmup):
-        out = fn(*args)
-    jax.block_until_ready(out)
-
-    if trace_dir:
-        with jax.profiler.trace(trace_dir):
-            out = fn(*args)
-            jax.block_until_ready(out)
-
-    start = time.perf_counter()
-    for _ in range(trials):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    elapsed = (time.perf_counter() - start) / trials
-
-    if db is not None and key is not None:
-        db.record_op_perf("compiled", key, elapsed)
-    return elapsed

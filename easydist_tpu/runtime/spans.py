@@ -1,0 +1,166 @@
+"""The program's one span recorder: host spans, counters, request timelines.
+
+`span(name, **attrs)` is a context manager that (a) enters
+`jax.profiler.TraceAnnotation(name, **attrs)`, so that whenever a
+`jax.profiler` session is capturing the span lands in the trace's host
+plane, on the device trace's clock, and (b) appends one record to a
+process-wide bounded ring.  Always on: no switch, no exporter, no thread, no
+file.  With no profiler session a span costs two clock reads and a deque
+append (`TraceMe` is inert when nothing captures).  `attrs` hold only values
+already at hand (a count, an index, a name): never a walk over tokens, never
+a device read.
+
+Every time is `time.perf_counter_ns()`.  `snapshot()` gives plain lists and
+dicts; the rings drop their oldest entries when full, and `clear()` empties
+them (tests, or a benchmark between phases).
+
+The names, each opened in one place (`PERF.md` section 3 says which metric
+reads which):
+
+    easydist.compile.trace | .discovery | .solve | .emit
+        jaxfront/api.py::compile_step; `CompileResult.phase_seconds` is
+        filled from the first three
+    easydist.step.call            attrs: fn
+        jaxfront/api.py::CompileResult.dispatch, round every call of a
+        compiled step (no fence: the device may still be running when it
+        ends)
+    easydist.step.compile         attrs: fn
+        the interval of a dispatch that made XLA compile the step, or load
+        it from the persistent cache; counted as `xla_compiles{fn=...}`
+    easydist.serve.step           attrs: step, live, queued
+    easydist.serve.admit          attrs: admitted, deferred
+    easydist.serve.prefill.build | .call | .finish
+    easydist.serve.decode.build | .call | .harvest
+        serve/generation.py::GenerationSession.step and what it calls; a
+        `.call` runs from the dispatch of one compiled program to the
+        return of its readback, so a step's duration less its `.call`
+        descendants is the host's share of the step, with the device idle;
+        `.finish` carries its request's `request_id`
+
+Counters: `xla_compiles{fn=<name>}`.  Serving counts stay in `ServeMetrics`.
+Requests: the timeline `GenerationSession` gives every finished request
+(`docs/SERVING.md`, "Observability").
+"""
+
+from __future__ import annotations
+
+import collections
+import itertools
+import threading
+import time
+from typing import Dict, List
+
+import jax
+
+SPAN_RING = 65536      # ~120 spans a second of serving: minutes of history
+REQUEST_RING = 4096
+
+_spans: collections.deque = collections.deque(maxlen=SPAN_RING)
+_requests: collections.deque = collections.deque(maxlen=REQUEST_RING)
+_counters: Dict[str, int] = {}
+_counters_lock = threading.Lock()
+_ids = itertools.count(1)
+_open = threading.local()      # .stack: ids of this thread's open spans
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+class span:
+    """`with span("easydist.layer.what", n=3) as sp:` — afterwards
+    `sp.seconds` is the duration, for the caller that also feeds it to a
+    histogram.  `sp.set(k=v)` adds to the ring's record what is only known
+    inside (the profiler's annotation keeps the attrs given at entry)."""
+
+    __slots__ = ("name", "attrs", "id", "parent_id", "t0_ns", "t1_ns",
+                 "_annotation")
+
+    def __init__(self, name: str, **attrs):
+        self.name, self.attrs = name, attrs
+        self._annotation = jax.profiler.TraceAnnotation(name, **attrs)
+
+    def __enter__(self):
+        stack = _stack()
+        self.parent_id = stack[-1] if stack else 0
+        self.id = next(_ids)
+        stack.append(self.id)
+        self._annotation.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1_ns = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        _stack().pop()
+        _spans.append((self.name, self.id, self.parent_id, self.t0_ns,
+                       self.t1_ns, self.attrs))
+        return False
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1_ns - self.t0_ns) / 1e9
+
+
+def record_span(name: str, t0_ns: int, t1_ns: int, parent_id: int = 0,
+                **attrs) -> int:
+    """A span whose interval is only known afterwards (a dispatch that
+    turned out to compile).  Returns its id."""
+    span_id = next(_ids)
+    _spans.append((name, span_id, parent_id, t0_ns, t1_ns, attrs))
+    return span_id
+
+
+def count(name: str, n: int = 1, **key) -> None:
+    """Process-wide counter `name{k=v,...}` for events that have no
+    `ServeMetrics` to live in."""
+    if key:
+        name += "{" + ",".join(f"{k}={v}" for k, v in sorted(key.items())) \
+            + "}"
+    with _counters_lock:
+        _counters[name] = _counters.get(name, 0) + n
+
+
+def request(timeline: dict) -> None:
+    """One finished request's timeline into the bounded request ring."""
+    _requests.append(timeline)
+
+
+def snapshot() -> dict:
+    """{"spans": [{name, id, parent_id, t0_ns, t1_ns, attrs}], "counters":
+    {name: n}, "requests": [timeline]} — copies, oldest first."""
+    with _counters_lock:
+        counters = dict(_counters)
+    return {"spans": [{"name": n, "id": i, "parent_id": p, "t0_ns": t0,
+                       "t1_ns": t1, "attrs": dict(a)}
+                      for n, i, p, t0, t1, a in tuple(_spans)],
+            "counters": counters,
+            "requests": [dict(r) for r in tuple(_requests)]}
+
+
+def clear() -> None:
+    _spans.clear()
+    _requests.clear()
+    with _counters_lock:
+        _counters.clear()
+
+
+def self_ns(record: dict, records: List[dict]) -> int:
+    """A span's duration less what its children cover: the nanoseconds the
+    layer spent in its own code."""
+    t0, t1 = record["t0_ns"], record["t1_ns"]
+    covered, end = 0, t0
+    for c0, c1 in sorted((max(r["t0_ns"], t0), min(r["t1_ns"], t1))
+                         for r in records
+                         if r["parent_id"] == record["id"]):
+        if c1 > end:
+            covered += c1 - max(c0, end)
+            end = c1
+    return (t1 - t0) - covered

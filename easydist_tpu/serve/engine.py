@@ -674,8 +674,7 @@ class ServeEngine:
         }
 
     def export_metrics(self, db=None, sub_key: Optional[str] = None):
-        """Push the snapshot into the runtime PerfDB (serving history lands
-        next to EASYDIST_RUNTIME_PROF step times)."""
+        """Push the snapshot into the runtime PerfDB's serving history."""
         name = sub_key or getattr(self._fn, "__name__", "engine")
         return self.metrics.export(db=db, sub_key=name)
 

@@ -64,6 +64,7 @@ import numpy as np
 from easydist_tpu.kv import PagePool, PageTable, is_host_ref, is_page_ref
 from easydist_tpu.kv.tier import HostTier, TierError
 from easydist_tpu.resilience import faultinject
+from easydist_tpu.runtime import spans
 
 from .admission import ReplicaDrainingError, RequestTooLargeError
 from .batcher import select_bucket
@@ -125,6 +126,7 @@ class _Slot:
     generated: List[int] = field(default_factory=list)
     pinned: List[object] = field(default_factory=list)  # trie nodes held
     prompt: List[int] = field(default_factory=list)  # for evacuation
+    timing: dict = field(default_factory=dict)  # see `_new_timing`
 
 
 @dataclass
@@ -140,7 +142,27 @@ class _PrefillJob:
     slot_idx: int                 # reserved pool slot
     start: int                    # next chunk start (multiple of chunk)
     prefix_nodes: List[object]    # trie nodes restored (pinned)
-    t_submit: float
+    timing: dict                  # see `_new_timing`
+
+
+def _new_timing(prompt_len: int) -> dict:
+    """A request's timeline, on the clock of `runtime/spans.py`
+    (`time.perf_counter_ns`): stamped in place as the request moves, given
+    back under the result's `timing` key and kept in the recorder's request
+    ring.  `token_ns` has one stamp per generated id: the first is
+    `first_token_ns`; the tokens of one decode round share the stamp taken
+    when that round's readback returned."""
+    return {"request_id": None, "prompt_len": prompt_len, "prefix_len": 0,
+            "submit_ns": time.perf_counter_ns(), "admit_ns": None,
+            "first_token_ns": None, "token_ns": [], "finish_ns": None,
+            "finish_reason": None}
+
+
+def _finish_timing(timing: dict, reason: str) -> dict:
+    timing["finish_ns"] = time.perf_counter_ns()
+    timing["finish_reason"] = reason
+    spans.request(timing)
+    return timing
 
 
 class _BucketPool:
@@ -330,8 +352,10 @@ class GenerationSession:
     Greedy decoding (argmax inside the compiled step, so only int32 token
     ids cross the host boundary per token).  `submit` returns a Future
     resolving to {"ids": [...generated ids...], "finish_reason":
-    "eos"|"length"|"bucket_full"}; drive with `step()` (admit + bounded
-    prefill chunks + decode + harvest) or `run_until_drained()`.
+    "eos"|"length"|"bucket_full", "timing": the request's timeline (see
+    `_new_timing`)}; drive with `step()` (admit + bounded prefill chunks +
+    decode + harvest, each an `easydist.serve.*` span of
+    `runtime/spans.py`) or `run_until_drained()`.
 
     `compile_key` (any hashable; `for_gpt`/`for_llama` derive one from the
     model config) opts the session into the process-level compiled-program
@@ -387,6 +411,7 @@ class GenerationSession:
         self._pending: collections.deque = collections.deque()
         self._pools: Dict[int, _BucketPool] = {}
         self._next_request_id = 0
+        self._step_index = 0
         self._audited: set = set()
         self._audited_prefill: set = set()
         self._audited_verify: set = set()
@@ -587,6 +612,8 @@ class GenerationSession:
                         (layers, 1, heads, chunk_len, hd))[:, 0]
                 return out
 
+            # one program per chunk length: each under a name of its own
+            _extract.__name__ = f"_extract_{chunk_len}"
             fn = easydist_compile(_extract, mesh=self.mesh)
             self._extract_cs[chunk_len] = fn
         return fn
@@ -641,7 +668,7 @@ class GenerationSession:
         self._pending.append(
             (prompt, max_new_tokens,
              self.eos_id if eos_id is None else eos_id, fut,
-             time.perf_counter()))
+             _new_timing(len(prompt))))
         self.metrics.inc("requests_submitted")
         self.metrics.set_gauge("queue_depth", self.queue_depth)
         return fut
@@ -732,6 +759,33 @@ class GenerationSession:
             t *= 2
         return min(t, bucket)
 
+    def _admitted(self, timing: dict, prefix_len: int) -> int:
+        """A request left the queue: stamp it, feed `queue_wait`, and
+        give it its id."""
+        timing["admit_ns"] = time.perf_counter_ns()
+        timing["prefix_len"] = prefix_len
+        timing["request_id"] = self._next_request_id
+        self._next_request_id += 1
+        self.metrics.observe(
+            "queue_wait", (timing["admit_ns"] - timing["submit_ns"]) / 1e9)
+        return timing["request_id"]
+
+    def _first_token(self, timing: dict) -> None:
+        now = time.perf_counter_ns()
+        timing["first_token_ns"] = now
+        timing["token_ns"].append(now)
+        self.metrics.observe("ttft", (now - timing["submit_ns"]) / 1e9)
+
+    def _run(self, span_name: str, result, args, **attrs):
+        """One compiled program dispatched and its int32 readback awaited,
+        inside a `.call` span: (new state, readback, the closed span)."""
+        import jax
+
+        with spans.span(span_name, fn=result.name, **attrs) as sp:
+            state, out = result.dispatch(args, {})
+            out = np.asarray(jax.block_until_ready(out))
+        return state, out, sp
+
     def _admit_one(self) -> bool:
         """Pop one pending request toward generation.  Chunked path:
         reserve a pool slot + staging row, restore the longest cached
@@ -742,7 +796,7 @@ class GenerationSession:
 
         if not self._pending:
             return False
-        prompt, max_new, eos, fut, t_submit = self._pending[0]
+        prompt, max_new, eos, fut, timing = self._pending[0]
         bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
         pool = self._pool_for(bucket)
         if not pool.free:
@@ -772,11 +826,10 @@ class GenerationSession:
                 pool.trie.pin(nodes)
             self.metrics.record_admission(len(prompt), prefix_len)
             pool.jobs[row] = _PrefillJob(
-                request_id=self._next_request_id, future=fut,
+                request_id=self._admitted(timing, prefix_len), future=fut,
                 prompt=prompt, max_new=max_new, eos_id=eos, row=row,
                 slot_idx=slot_idx, start=prefix_len,
-                prefix_nodes=nodes, t_submit=t_submit)
-            self._next_request_id += 1
+                prefix_nodes=nodes, timing=timing)
             return True
 
         t_pad = self._prefill_pad(len(prompt), bucket)
@@ -790,12 +843,11 @@ class GenerationSession:
                                      jnp.asarray(0, jnp.int32),
                                      jnp.asarray(slot_idx, jnp.int32))
         self.metrics.record_admission(len(prompt), 0)
-        self.metrics.observe("ttft", time.perf_counter() - t_submit)
-
-        slot = _Slot(request_id=self._next_request_id, future=fut,
+        slot = _Slot(request_id=self._admitted(timing, 0), future=fut,
                      pos=len(prompt), token=int(np.asarray(first)[0]),
-                     max_new=max_new, eos_id=eos, prompt=prompt)
-        self._next_request_id += 1
+                     max_new=max_new, eos_id=eos, prompt=prompt,
+                     timing=timing)
+        self._first_token(timing)
         slot.generated.append(slot.token)
         pool.slots[slot_idx] = slot
         self._maybe_retire(pool, slot_idx)
@@ -808,7 +860,7 @@ class GenerationSession:
         token's K/V), mapping the trie's committed prefix pages in place
         of the bucketed layout's restore copies.  Defers (returns False,
         request stays queued) when the arena cannot make room."""
-        prompt, max_new, eos, fut, t_submit = self._pending[0]
+        prompt, max_new, eos, fut, timing = self._pending[0]
         prefix_len, nodes = 0, []
         if pool.trie is not None:
             # cap below len(prompt): at least one real token must run
@@ -848,10 +900,10 @@ class GenerationSession:
                 len(nodes) * pool.page_bytes)
         self.metrics.record_admission(len(prompt), prefix_len)
         pool.jobs[row] = _PrefillJob(
-            request_id=self._next_request_id, future=fut, prompt=prompt,
-            max_new=max_new, eos_id=eos, row=row, slot_idx=slot_idx,
-            start=prefix_len, prefix_nodes=nodes, t_submit=t_submit)
-        self._next_request_id += 1
+            request_id=self._admitted(timing, prefix_len), future=fut,
+            prompt=prompt, max_new=max_new, eos_id=eos, row=row,
+            slot_idx=slot_idx, start=prefix_len, prefix_nodes=nodes,
+            timing=timing)
         return True
 
     def _promote_path(self, pool: _PagedPool, nodes):
@@ -892,7 +944,6 @@ class GenerationSession:
         """Run up to `max_chunks` batched chunk calls on `pool`'s staging
         rows; finished jobs commit to the trie, migrate to their slot, and
         free their row.  Returns the number of chunk calls executed."""
-        import jax
         import jax.numpy as jnp
 
         if self._paged:
@@ -900,33 +951,41 @@ class GenerationSession:
         calls = 0
         c_len = pool.chunk
         while pool.jobs and calls < max_chunks:
-            tokens = np.full((pool.n_rows, c_len),
-                             int(self.config.pad_value), np.int32)
-            start = np.zeros((pool.n_rows,), np.int32)
-            lengths = np.ones((pool.n_rows,), np.int32)
-            for row, job in pool.jobs.items():
-                seg = job.prompt[job.start:job.start + c_len]
-                tokens[row, :len(seg)] = seg
-                start[row] = job.start
-                lengths[row] = len(job.prompt)
-            args = (pool.staging, self.params, jnp.asarray(tokens),
-                    jnp.asarray(start), jnp.asarray(lengths))
-            result = self._prefill_chunk_c.get_compiled(*args)
-            if pool.bucket not in self._audited_prefill:
-                self._audited_prefill.add(pool.bucket)
-                self._audit_chunked_prefill(result, pool.bucket)
-            t0 = time.perf_counter()
-            pool.staging, first = result.tree_jitted(*args)
-            first = np.asarray(jax.block_until_ready(first))
-            self.metrics.record_prefill_chunk(
-                pool.n_rows, c_len, time.perf_counter() - t0)
+            with spans.span("easydist.serve.prefill.build"):
+                tokens = np.full((pool.n_rows, c_len),
+                                 int(self.config.pad_value), np.int32)
+                start = np.zeros((pool.n_rows,), np.int32)
+                lengths = np.ones((pool.n_rows,), np.int32)
+                for row, job in pool.jobs.items():
+                    seg = job.prompt[job.start:job.start + c_len]
+                    tokens[row, :len(seg)] = seg
+                    start[row] = job.start
+                    lengths[row] = len(job.prompt)
+                args = (pool.staging, self.params, jnp.asarray(tokens),
+                        jnp.asarray(start), jnp.asarray(lengths))
+                result = self._prefill_chunk_c.get_compiled(*args)
+                if pool.bucket not in self._audited_prefill:
+                    self._audited_prefill.add(pool.bucket)
+                    self._audit_chunked_prefill(result, pool.bucket)
+            pool.staging, first, sp = self._run(
+                "easydist.serve.prefill.call", result, args,
+                rows=pool.n_rows, chunk=c_len)
+            self.metrics.record_prefill_chunk(pool.n_rows, c_len,
+                                              sp.seconds)
             calls += 1
-            for row in list(pool.jobs):
-                job = pool.jobs[row]
-                job.start += c_len
-                if job.start >= len(job.prompt):
-                    self._finish_prefill(pool, row, int(first[row]))
+            self._advance_jobs(pool, first, self._finish_prefill)
         return calls
+
+    def _advance_jobs(self, pool, first, finish: Callable) -> None:
+        """After a chunk call: every job moves one chunk on; the jobs whose
+        last chunk this was are finished, each in its own span."""
+        for row in list(pool.jobs):
+            job = pool.jobs[row]
+            job.start += pool.chunk
+            if job.start >= len(job.prompt):
+                with spans.span("easydist.serve.prefill.finish",
+                                request_id=job.request_id):
+                    finish(pool, row, int(first[row]))
 
     def _prefill_round_paged(self, pool: _PagedPool,
                              max_chunks: int) -> int:
@@ -936,54 +995,50 @@ class GenerationSession:
         all-sentinel table row so their writes drop and their logits are
         garbage nobody reads — one compiled signature regardless of
         which rows are live."""
-        import jax
         import jax.numpy as jnp
 
         calls = 0
         c_len = pool.chunk
         while pool.jobs and calls < max_chunks:
-            tokens = np.full((pool.n_rows, c_len),
-                             int(self.config.pad_value), np.int32)
-            start = np.zeros((pool.n_rows,), np.int32)
-            lengths = np.ones((pool.n_rows,), np.int32)
-            tbl = np.full((pool.n_rows, pool.max_pages),
-                          pool.pool.sentinel, np.int32)
-            for row, job in pool.jobs.items():
-                seg = job.prompt[job.start:job.start + c_len]
-                tokens[row, :len(seg)] = seg
-                start[row] = job.start
-                lengths[row] = len(job.prompt)
-                tbl[row] = pool.table.array[job.slot_idx]
-            args = (pool.arena, self.params, jnp.asarray(tbl),
-                    jnp.asarray(tokens), jnp.asarray(start),
-                    jnp.asarray(lengths))
-            result = self._paged_c("chunk").get_compiled(*args)
-            if pool.bucket not in self._audited_prefill:
-                self._audited_prefill.add(pool.bucket)
-                # SERVE002's jaxpr walk asserts the bucketed staging
-                # idiom (dynamic_update_slice restore); the paged
-                # program replaces it with table writes, audited
-                # host-side by KV001 — only the donation half applies
-                try:
-                    from easydist_tpu.analyze import check_decode_donation
+            with spans.span("easydist.serve.prefill.build"):
+                tokens = np.full((pool.n_rows, c_len),
+                                 int(self.config.pad_value), np.int32)
+                start = np.zeros((pool.n_rows,), np.int32)
+                lengths = np.ones((pool.n_rows,), np.int32)
+                tbl = np.full((pool.n_rows, pool.max_pages),
+                              pool.pool.sentinel, np.int32)
+                for row, job in pool.jobs.items():
+                    seg = job.prompt[job.start:job.start + c_len]
+                    tokens[row, :len(seg)] = seg
+                    start[row] = job.start
+                    lengths[row] = len(job.prompt)
+                    tbl[row] = pool.table.array[job.slot_idx]
+                args = (pool.arena, self.params, jnp.asarray(tbl),
+                        jnp.asarray(tokens), jnp.asarray(start),
+                        jnp.asarray(lengths))
+                result = self._paged_c("chunk").get_compiled(*args)
+                if pool.bucket not in self._audited_prefill:
+                    self._audited_prefill.add(pool.bucket)
+                    # SERVE002's jaxpr walk asserts the bucketed staging
+                    # idiom (dynamic_update_slice restore); the paged
+                    # program replaces it with table writes, audited
+                    # host-side by KV001 — only the donation half applies
+                    try:
+                        from easydist_tpu.analyze import \
+                            check_decode_donation
 
-                    check_decode_donation(
-                        result,
-                        node=f"prefill_chunk_paged[cap={pool.bucket}]")
-                except ImportError:
-                    pass
-            t0 = time.perf_counter()
-            pool.arena, first = result.tree_jitted(*args)
-            first = np.asarray(jax.block_until_ready(first))
-            self.metrics.record_prefill_chunk(
-                pool.n_rows, c_len, time.perf_counter() - t0)
+                        check_decode_donation(
+                            result,
+                            node=f"prefill_chunk_paged[cap={pool.bucket}]")
+                    except ImportError:
+                        pass
+            pool.arena, first, sp = self._run(
+                "easydist.serve.prefill.call", result, args,
+                rows=pool.n_rows, chunk=c_len)
+            self.metrics.record_prefill_chunk(pool.n_rows, c_len,
+                                              sp.seconds)
             calls += 1
-            for row in list(pool.jobs):
-                job = pool.jobs[row]
-                job.start += c_len
-                if job.start >= len(job.prompt):
-                    self._finish_prefill_paged(pool, row,
-                                               int(first[row]))
+            self._advance_jobs(pool, first, self._finish_prefill_paged)
         return calls
 
     def _finish_prefill_paged(self, pool: _PagedPool, row: int,
@@ -1026,12 +1081,12 @@ class GenerationSession:
             pinned = nodes
             self._audit_prefix_cache(pool)
         pool.free_rows.append(row)
-        self.metrics.observe("ttft", time.perf_counter() - job.t_submit)
+        self._first_token(job.timing)
 
         slot = _Slot(request_id=job.request_id, future=job.future,
                      pos=len(job.prompt), token=first_token,
                      max_new=job.max_new, eos_id=job.eos_id,
-                     pinned=pinned, prompt=job.prompt)
+                     pinned=pinned, prompt=job.prompt, timing=job.timing)
         slot.generated.append(slot.token)
         pool.slots[job.slot_idx] = slot
         self._maybe_retire(pool, job.slot_idx)
@@ -1067,12 +1122,12 @@ class GenerationSession:
                                      jnp.asarray(row, jnp.int32),
                                      jnp.asarray(job.slot_idx, jnp.int32))
         pool.free_rows.append(row)
-        self.metrics.observe("ttft", time.perf_counter() - job.t_submit)
+        self._first_token(job.timing)
 
         slot = _Slot(request_id=job.request_id, future=job.future,
                      pos=len(job.prompt), token=first_token,
                      max_new=job.max_new, eos_id=job.eos_id,
-                     pinned=pinned, prompt=job.prompt)
+                     pinned=pinned, prompt=job.prompt, timing=job.timing)
         slot.generated.append(slot.token)
         pool.slots[job.slot_idx] = slot
         self._maybe_retire(pool, job.slot_idx)
@@ -1093,7 +1148,9 @@ class GenerationSession:
         if self._paged:
             self._audit_kv(pool, f"retire[{reason}]")
         slot.future.set_result({"ids": list(slot.generated),
-                                "finish_reason": reason})
+                                "finish_reason": reason,
+                                "timing": _finish_timing(slot.timing,
+                                                         reason)})
         self.metrics.inc("requests_completed")
 
     def _maybe_retire(self, pool: _BucketPool, slot_idx: int) -> bool:
@@ -1120,60 +1177,66 @@ class GenerationSession:
         excluded bucketed slot would take a garbage write at row 0).
         The speculative scheduler uses it to plain-decode the slots a
         verify round could not carry."""
-        import jax
         import jax.numpy as jnp
 
-        live = [i for i in pool.slots if only is None or i in only]
-        token = np.zeros((pool.n_slots,), np.int32)
-        pos = np.zeros((pool.n_slots,), np.int32)
-        for idx in live:
-            token[idx] = pool.slots[idx].token
-            pos[idx] = pool.slots[idx].pos
-        if self._paged:
-            # only actively-decoding rows expose their table row: a
-            # reserved-but-still-prefilling slot's pages (possibly
-            # SHARED prefix pages) must not take the dead-row write this
-            # step lands at pos 0 — sentinel rows drop it instead
-            tbl = np.full((pool.n_slots, pool.max_pages),
-                          pool.pool.sentinel, np.int32)
+        with spans.span("easydist.serve.decode.build"):
+            live = [i for i in pool.slots if only is None or i in only]
+            token = np.zeros((pool.n_slots,), np.int32)
+            pos = np.zeros((pool.n_slots,), np.int32)
             for idx in live:
-                tbl[idx] = pool.table.array[idx]
-            args = (pool.arena, self.params, jnp.asarray(tbl),
-                    jnp.asarray(token), jnp.asarray(pos))
-            compiled = self._paged_c("decode")
-        else:
-            args = (pool.cache, self.params, jnp.asarray(token),
-                    jnp.asarray(pos))
-            compiled = self._decode_c
-        result = compiled.get_compiled(*args)
-        if pool.bucket not in self._audited:
-            self._audited.add(pool.bucket)
-            self._audit_donation(result, pool.bucket)
-            self._audit_host_aliases(pool)
+                token[idx] = pool.slots[idx].token
+                pos[idx] = pool.slots[idx].pos
             if self._paged:
-                self._audit_kv(pool, "first_decode")
-                if "k_scale" in pool.arena:
-                    self._audit_quant_program(result, "first_decode")
-        t0 = time.perf_counter()
+                # only actively-decoding rows expose their table row: a
+                # reserved-but-still-prefilling slot's pages (possibly
+                # SHARED prefix pages) must not take the dead-row write
+                # this step lands at pos 0 — sentinel rows drop it instead
+                tbl = np.full((pool.n_slots, pool.max_pages),
+                              pool.pool.sentinel, np.int32)
+                for idx in live:
+                    tbl[idx] = pool.table.array[idx]
+                args = (pool.arena, self.params, jnp.asarray(tbl),
+                        jnp.asarray(token), jnp.asarray(pos))
+                compiled = self._paged_c("decode")
+            else:
+                args = (pool.cache, self.params, jnp.asarray(token),
+                        jnp.asarray(pos))
+                compiled = self._decode_c
+            result = compiled.get_compiled(*args)
+            if pool.bucket not in self._audited:
+                self._audited.add(pool.bucket)
+                self._audit_donation(result, pool.bucket)
+                self._audit_host_aliases(pool)
+                if self._paged:
+                    self._audit_kv(pool, "first_decode")
+                    if "k_scale" in pool.arena:
+                        self._audit_quant_program(result, "first_decode")
+        state, nxt, sp = self._run("easydist.serve.decode.call", result,
+                                   args, rows=len(live))
         if self._paged:
-            pool.arena, nxt = result.tree_jitted(*args)
+            pool.arena = state
         else:
-            pool.cache, nxt = result.tree_jitted(*args)
-        nxt = np.asarray(jax.block_until_ready(nxt))
-        dt = time.perf_counter() - t0
-        for idx in live:
-            slot = pool.slots[idx]
-            slot.token = int(nxt[idx])
-            slot.pos += 1
-            slot.generated.append(slot.token)
-            self._maybe_retire(pool, idx)
-        self.metrics.record_decode_step(len(live), pool.n_slots, dt)
-        if self._paged:
-            in_use, held = pool.occupancy()
-            self.metrics.record_kv_pool(
-                in_use, held, pool.chunk,
-                quant_bytes_saved=(pool.model_page_bytes
-                                   - pool.page_bytes) * in_use)
+            pool.cache = state
+        with spans.span("easydist.serve.decode.harvest"):
+            for idx in live:
+                slot = pool.slots[idx]
+                slot.token = int(nxt[idx])
+                slot.pos += 1
+                slot.generated.append(slot.token)
+                # the round's one stamp: when its readback returned
+                slot.timing["token_ns"].append(sp.t1_ns)
+                self._maybe_retire(pool, idx)
+            self.metrics.record_decode_step(len(live), pool.n_slots,
+                                            sp.seconds)
+            if self._paged:
+                self._record_kv_pool(pool)
+
+    def _record_kv_pool(self, pool: _PagedPool) -> None:
+        in_use, held = pool.occupancy()
+        self.metrics.record_kv_pool(
+            in_use, held, pool.chunk,
+            quant_bytes_saved=(pool.model_page_bytes
+                               - pool.page_bytes) * in_use)
 
     # ------------------------------------------------ speculative decoding
     def _spec_round(self, pool) -> bool:
@@ -1227,123 +1290,121 @@ class GenerationSession:
         return self._verify_round_bucketed(pool, drafts)
 
     def _verify_round_bucketed(self, pool: _BucketPool, drafts) -> bool:
-        import jax
         import jax.numpy as jnp
 
         k = self._spec_k
-        tokens = np.zeros((pool.n_slots, k + 1), np.int32)
-        pos = np.zeros((pool.n_slots,), np.int32)
-        for idx, slot in pool.slots.items():
-            tokens[idx, 0] = slot.token
-            tokens[idx, 1:] = drafts.get(idx, [0] * k)
-            pos[idx] = slot.pos
-        args = (pool.cache, self.params, jnp.asarray(tokens),
-                jnp.asarray(pos))
-        result = self._verify_c().get_compiled(*args)
-        if ("bucketed", pool.bucket) not in self._audited_verify:
-            self._audited_verify.add(("bucketed", pool.bucket))
-            self._audit_verify(result, f"verify[bucket={pool.bucket}]")
-        t0 = time.perf_counter()
-        pool.cache, nxt = result.tree_jitted(*args)
-        nxt = np.asarray(jax.block_until_ready(nxt))
-        dt = time.perf_counter() - t0
+        with spans.span("easydist.serve.decode.build"):
+            tokens = np.zeros((pool.n_slots, k + 1), np.int32)
+            pos = np.zeros((pool.n_slots,), np.int32)
+            for idx, slot in pool.slots.items():
+                tokens[idx, 0] = slot.token
+                tokens[idx, 1:] = drafts.get(idx, [0] * k)
+                pos[idx] = slot.pos
+            args = (pool.cache, self.params, jnp.asarray(tokens),
+                    jnp.asarray(pos))
+            result = self._verify_c().get_compiled(*args)
+            if ("bucketed", pool.bucket) not in self._audited_verify:
+                self._audited_verify.add(("bucketed", pool.bucket))
+                self._audit_verify(result, f"verify[bucket={pool.bucket}]")
+        pool.cache, nxt, sp = self._run("easydist.serve.decode.call",
+                                        result, args, rows=len(pool.slots))
         # rejected rows need no explicit cleanup in the bucketed layout:
         # the pos cursor simply does not advance past the accepted
         # prefix, the next write at pos overwrites the stale row, and
         # the length mask hides everything past the query position
-        proposed, accepted, committed = self._commit_verify(
-            pool, drafts, tokens, nxt, list(pool.slots))
-        self.metrics.record_speculation(
-            proposed, accepted, committed, len(drafts), pool.n_slots, dt)
+        with spans.span("easydist.serve.decode.harvest"):
+            proposed, accepted, committed = self._commit_verify(
+                pool, drafts, tokens, nxt, list(pool.slots), sp.t1_ns)
+            self.metrics.record_speculation(
+                proposed, accepted, committed, len(drafts), pool.n_slots,
+                sp.seconds)
         return True
 
     def _verify_round_paged(self, pool: _PagedPool, drafts) -> bool:
-        import jax
         import jax.numpy as jnp
 
         k = self._spec_k
-        eligible: Dict[int, List[int]] = {}
-        for idx, d in drafts.items():
-            slot = pool.slots[idx]
-            if slot.pos + k + 1 > pool.bucket:
-                continue
-            # speculative rows may spill past the slot's up-front page
-            # reservation; map the spill windows now (the rollback below
-            # unconditionally truncates the row back to the reservation,
-            # so outside a verify round the invariant "live slots map
-            # exactly their reservation" always holds)
-            n_need = (slot.pos + k) // pool.chunk + 1
-            n_have = pool.table.n_mapped(idx)
-            if n_need > n_have:
-                if not pool.make_room(n_need - n_have):
+        with spans.span("easydist.serve.decode.build"):
+            eligible: Dict[int, List[int]] = {}
+            for idx, d in drafts.items():
+                slot = pool.slots[idx]
+                if slot.pos + k + 1 > pool.bucket:
                     continue
-                for j in range(n_have, n_need):
-                    pool.table.map(idx, j, pool.pool.alloc())
-            eligible[idx] = d
-        if not eligible:
-            return False
-        tokens = np.zeros((pool.n_slots, k + 1), np.int32)
-        pos = np.zeros((pool.n_slots,), np.int32)
-        tbl = np.full((pool.n_slots, pool.max_pages),
-                      pool.pool.sentinel, np.int32)
-        for idx, d in eligible.items():
-            slot = pool.slots[idx]
-            tokens[idx, 0] = slot.token
-            tokens[idx, 1:] = d
-            pos[idx] = slot.pos
-            tbl[idx] = pool.table.array[idx]
-        args = (pool.arena, self.params, jnp.asarray(tbl),
-                jnp.asarray(tokens), jnp.asarray(pos))
-        result = self._paged_c("verify").get_compiled(*args)
-        if ("paged", pool.bucket) not in self._audited_verify:
-            self._audited_verify.add(("paged", pool.bucket))
-            self._audit_verify(result, f"verify[paged cap={pool.bucket}]")
-        t0 = time.perf_counter()
-        pool.arena, nxt = result.tree_jitted(*args)
-        nxt = np.asarray(jax.block_until_ready(nxt))
-        dt = time.perf_counter() - t0
-        # reservation sizes BEFORE the commit walk can retire the slots
-        reserved = {idx: pool.pages_needed(len(pool.slots[idx].prompt),
-                                           pool.slots[idx].max_new)
-                    for idx in eligible}
-        rest = [i for i in pool.slots if i not in eligible]
-        proposed, accepted, committed = self._commit_verify(
-            pool, drafts, tokens, nxt, list(eligible))
-        # rollback: spill windows past the reservation only ever hold
-        # rejected/uncommitted draft rows (committed positions provably
-        # fit the reservation — pages_needed covers prompt + max_new),
-        # so truncating the table tail releases them.  Retired slots
-        # were already fully unmapped by _retire.
-        released = 0
-        for idx in eligible:
-            if idx not in pool.slots:
-                continue
-            for pid in pool.table.unmap_tail(idx, reserved[idx]):
-                pool.pool.release(pid)
-                released += 1
-        if released:
-            self._audit_spec_rollback(pool)
-        self.metrics.record_speculation(
-            proposed, accepted, committed, len(eligible), pool.n_slots,
-            dt, pages_released=released)
-        in_use, held = pool.occupancy()
-        self.metrics.record_kv_pool(
-            in_use, held, pool.chunk,
-            quant_bytes_saved=(pool.model_page_bytes
-                               - pool.page_bytes) * in_use)
+                # speculative rows may spill past the slot's up-front page
+                # reservation; map the spill windows now (the rollback
+                # below unconditionally truncates the row back to the
+                # reservation, so outside a verify round the invariant
+                # "live slots map exactly their reservation" always holds)
+                n_need = (slot.pos + k) // pool.chunk + 1
+                n_have = pool.table.n_mapped(idx)
+                if n_need > n_have:
+                    if not pool.make_room(n_need - n_have):
+                        continue
+                    for j in range(n_have, n_need):
+                        pool.table.map(idx, j, pool.pool.alloc())
+                eligible[idx] = d
+            if not eligible:
+                return False
+            tokens = np.zeros((pool.n_slots, k + 1), np.int32)
+            pos = np.zeros((pool.n_slots,), np.int32)
+            tbl = np.full((pool.n_slots, pool.max_pages),
+                          pool.pool.sentinel, np.int32)
+            for idx, d in eligible.items():
+                slot = pool.slots[idx]
+                tokens[idx, 0] = slot.token
+                tokens[idx, 1:] = d
+                pos[idx] = slot.pos
+                tbl[idx] = pool.table.array[idx]
+            args = (pool.arena, self.params, jnp.asarray(tbl),
+                    jnp.asarray(tokens), jnp.asarray(pos))
+            result = self._paged_c("verify").get_compiled(*args)
+            if ("paged", pool.bucket) not in self._audited_verify:
+                self._audited_verify.add(("paged", pool.bucket))
+                self._audit_verify(result,
+                                   f"verify[paged cap={pool.bucket}]")
+        pool.arena, nxt, sp = self._run("easydist.serve.decode.call",
+                                        result, args, rows=len(eligible))
+        with spans.span("easydist.serve.decode.harvest"):
+            # reservation sizes BEFORE the commit walk can retire the slots
+            reserved = {idx: pool.pages_needed(len(pool.slots[idx].prompt),
+                                               pool.slots[idx].max_new)
+                        for idx in eligible}
+            rest = [i for i in pool.slots if i not in eligible]
+            proposed, accepted, committed = self._commit_verify(
+                pool, drafts, tokens, nxt, list(eligible), sp.t1_ns)
+            # rollback: spill windows past the reservation only ever hold
+            # rejected/uncommitted draft rows (committed positions provably
+            # fit the reservation — pages_needed covers prompt + max_new),
+            # so truncating the table tail releases them.  Retired slots
+            # were already fully unmapped by _retire.
+            released = 0
+            for idx in eligible:
+                if idx not in pool.slots:
+                    continue
+                for pid in pool.table.unmap_tail(idx, reserved[idx]):
+                    pool.pool.release(pid)
+                    released += 1
+            if released:
+                self._audit_spec_rollback(pool)
+            self.metrics.record_speculation(
+                proposed, accepted, committed, len(eligible), pool.n_slots,
+                sp.seconds, pages_released=released)
+            self._record_kv_pool(pool)
         if rest:
             self._decode_round(pool, only=set(rest))
         return True
 
-    def _commit_verify(self, pool, drafts, tokens, nxt, idxs):
+    def _commit_verify(self, pool, drafts, tokens, nxt, idxs, t_tok: int):
         """Commit walk for the slots that rode a verify step: accept the
         longest draft prefix the target's own greedy picks ratify, plus
         the target's correction/bonus token.  Every committed token is
         the exact plain-greedy token (the draft row only decides how
         many commit per round), so retire semantics (eos/length/
         bucket_full) are checked token-by-token exactly as a sequence
-        of plain decode rounds would.  Returns (proposed, accepted,
-        committed) counts for the speculation metrics."""
+        of plain decode rounds would.  `t_tok` (when the round's readback
+        returned) stamps every token the round commits.  Returns
+        (proposed, accepted, committed) counts for the speculation
+        metrics."""
         k = self._spec_k
         proposed = accepted = committed = 0
         expect = 0.0
@@ -1369,6 +1430,7 @@ class GenerationSession:
                 slot.token = int(g_row[i])
                 slot.pos += 1
                 slot.generated.append(slot.token)
+                slot.timing["token_ns"].append(t_tok)
                 committed += 1
                 if self._maybe_retire(pool, idx):
                     break
@@ -1504,23 +1566,32 @@ class GenerationSession:
         # from completed steps were already streamed/synced, this step's
         # are lost — exactly the state a real mid-decode crash leaves
         faultinject.crash_point("fleet.replica.crash")
-        while self._admit_one():
-            pass
-        if self._chunked or self._paged:
-            budget = self.config.prefill_chunks_per_step
+        self._step_index += 1
+        with spans.span("easydist.serve.step", step=self._step_index,
+                        live=sum(p.n_active for p in self._pools.values()),
+                        queued=len(self._pending)):
+            with spans.span("easydist.serve.admit") as sp:
+                queued = len(self._pending)
+                while self._admit_one():
+                    pass
+                sp.set(admitted=queued - len(self._pending),
+                       deferred=len(self._pending))
+            if self._chunked or self._paged:
+                budget = self.config.prefill_chunks_per_step
+                for pool in self._pools.values():
+                    if budget <= 0:
+                        break
+                    if pool.jobs:
+                        budget -= self._prefill_round(pool, budget)
+            before = self.metrics.counter("tokens_generated")
             for pool in self._pools.values():
-                if budget <= 0:
-                    break
-                if pool.jobs:
-                    budget -= self._prefill_round(pool, budget)
-        before = self.metrics.counter("tokens_generated")
-        for pool in self._pools.values():
-            if pool.slots:
-                if self._drafter is not None and self._spec_round(pool):
-                    continue
-                self._decode_round(pool)
-        self.metrics.set_gauge("queue_depth", self.queue_depth)
-        return self.metrics.counter("tokens_generated") - before
+                if pool.slots:
+                    if self._drafter is not None \
+                            and self._spec_round(pool):
+                        continue
+                    self._decode_round(pool)
+            self.metrics.set_gauge("queue_depth", self.queue_depth)
+            return self.metrics.counter("tokens_generated") - before
 
     def run_until_drained(self, max_steps: int = 100000) -> None:
         """Drive `step()` until no request is live or queued."""
@@ -1736,10 +1807,11 @@ class GenerationSession:
         self._draining = True
         out: List[Dict[str, object]] = []
         while self._pending:
-            prompt, max_new, eos, fut, _ = self._pending.popleft()
+            prompt, max_new, eos, fut, timing = self._pending.popleft()
             if fut.set_running_or_notify_cancel() is False:
                 continue
-            fut.set_result({"ids": [], "finish_reason": "evacuated"})
+            fut.set_result({"ids": [], "finish_reason": "evacuated",
+                            "timing": _finish_timing(timing, "evacuated")})
             out.append({"prompt": list(prompt), "ids": [],
                         "max_new": max_new, "eos_id": eos})
         for pool in self._pools.values():
@@ -1753,7 +1825,8 @@ class GenerationSession:
                 if pool.trie is not None:
                     pool.trie.unpin(job.prefix_nodes)
                 job.future.set_result(
-                    {"ids": [], "finish_reason": "evacuated"})
+                    {"ids": [], "finish_reason": "evacuated",
+                     "timing": _finish_timing(job.timing, "evacuated")})
                 out.append({"prompt": list(job.prompt), "ids": [],
                             "max_new": job.max_new, "eos_id": job.eos_id})
             for idx in list(pool.slots):

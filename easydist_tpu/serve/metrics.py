@@ -3,8 +3,8 @@
 Everything a dashboard needs to judge a serving deployment — queue depth,
 batch occupancy (real rows / bucket rows), executable-cache hit rate,
 p50/p95/p99 latency — collected lock-cheap in-process and exported through
-the existing runtime plumbing (`runtime.perfdb.PerfDB`), so serving history
-lands next to the step-time history `EASYDIST_RUNTIME_PROF` already keeps.
+the existing runtime plumbing (`runtime.perfdb.PerfDB`).  Where a step's time
+went, and each finished request's timeline, are in `runtime/spans.py`.
 """
 
 from __future__ import annotations

@@ -1,4 +1,4 @@
 """Utility layer (reference: easydist/utils/)."""
 
-from .timer import EDTimer  # noqa: F401
+from .timer import time_per_call  # noqa: F401
 from .testing import cpu_mesh  # noqa: F401

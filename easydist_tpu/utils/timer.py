@@ -30,17 +30,3 @@ def time_per_call(fn: Callable, args=(), iters: int = 12,
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters
-
-
-class EDTimer:
-
-    def __init__(self, func: Callable, trials: int = 12,
-                 warmup_trials: int = 3):
-        self.func = func
-        self.trials = trials
-        self.warmup_trials = warmup_trials
-
-    def time(self) -> float:
-        """Seconds per call."""
-        return time_per_call(self.func, iters=self.trials,
-                             warmup=self.warmup_trials)

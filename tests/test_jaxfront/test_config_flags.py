@@ -93,24 +93,6 @@ def test_dump_flags_write_files(flag, tmp_path, cpu_devices):
 
 
 @pytest.mark.world_8
-def test_runtime_prof_records_step_times(flag, tmp_path, cpu_devices):
-    flag("enable_runtime_prof", True)
-    flag("prof_db_path", str(tmp_path / "perf.db"))
-    params, x, y = _case()
-    mesh = make_device_mesh((8,), ("d",))
-    compiled = easydist_compile(_step, mesh=mesh, donate_state=False)
-    compiled(params, x, y)  # cold call: compile time, not recorded
-    compiled(params, x, y)
-    compiled(params, x, y)
-
-    from easydist_tpu.runtime.perfdb import PerfDB
-
-    db = PerfDB(str(tmp_path / "perf.db"))
-    times = db.get_op_perf("step_times", "_step")
-    assert times and len(times) == 2 and all(t > 0 for t in times)
-
-
-@pytest.mark.world_8
 def test_remat_policy_recomputes_in_backward(flag, cpu_devices):
     """remat_policy='all' must make differentiation through a compiled
     forward recompute it (more dots in the grad jaxpr) instead of saving

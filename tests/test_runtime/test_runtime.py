@@ -1,4 +1,4 @@
-"""Checkpoint/perfdb/profiler/timer tests."""
+"""Checkpoint/perfdb/profiler tests."""
 
 import os
 
@@ -9,8 +9,7 @@ import pytest
 
 from easydist_tpu.runtime import (PerfDB, latest_step, load_checkpoint,
                                   memory_analysis, op_cost_analysis,
-                                  profile_compiled, save_checkpoint)
-from easydist_tpu.utils import EDTimer
+                                  save_checkpoint)
 
 
 def test_checkpoint_roundtrip(tmp_path):
@@ -134,21 +133,6 @@ def test_cost_and_memory_analysis():
     assert cost.get("flops", 0) > 0
     mem = memory_analysis(compiled)
     assert mem  # non-empty dict
-
-
-def test_profile_compiled(tmp_path):
-    fn = jax.jit(lambda x: jnp.tanh(x).sum())
-    x = jnp.ones((256,))
-    db = PerfDB(path=str(tmp_path / "perf.db"))
-    t = profile_compiled(fn, (x,), key="tanh_sum", db=db, trials=3)
-    assert t > 0
-    assert db.get_op_perf("compiled", "tanh_sum") == t
-
-
-def test_edtimer():
-    fn = jax.jit(lambda: jnp.ones((64,)).sum())
-    t = EDTimer(lambda: fn(), trials=3, warmup_trials=1).time()
-    assert t > 0
 
 
 def test_elastic_resume(tmp_path):

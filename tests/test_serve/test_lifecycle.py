@@ -102,8 +102,12 @@ class TestEvacuate:
         sess = _mk(model)
         fut = sess.submit([1, 2, 3], max_new_tokens=3)
         descs = sess.evacuate()  # never admitted
-        assert fut.result(timeout=5) == {"ids": [],
-                                         "finish_reason": "evacuated"}
+        res = fut.result(timeout=5)
+        assert (res["ids"], res["finish_reason"]) == ([], "evacuated")
+        # never admitted: the timeline has a submit and a finish, no more
+        timing = res["timing"]
+        assert timing["admit_ns"] is None and timing["token_ns"] == []
+        assert timing["submit_ns"] <= timing["finish_ns"]
         assert descs[0]["ids"] == []
 
     def test_evacuate_trie_has_no_orphaned_pins(self, model):

@@ -240,8 +240,9 @@ class TestGreedyParityGPT:
             params, cfg, config=_config(spec_k=3), eos_id=eos)
         sf = spec.submit(prompt, max_new_tokens=12)
         spec.run_until_drained()
-        assert sf.result(timeout=5) == pf.result(timeout=5)
+        assert sf.result(timeout=5)["ids"] == pf.result(timeout=5)["ids"]
         assert sf.result(timeout=5)["finish_reason"] == "eos"
+        assert pf.result(timeout=5)["finish_reason"] == "eos"
 
     def test_tp2_spec_parity(self, gpt_model):
         cfg, params = gpt_model
