@@ -9,7 +9,8 @@ against a reference computed beside it.  Phases, in order:
   clock    a chained bf16 matmul timed with plain `jax.block_until_ready` must
            land under the chip's datasheet peak (so the wait is real)
   kernels  the five Pallas kernels of ops/flash_attention.py, compiled
-           (`interpret=False`), against the float32 XLA paths in that file
+           (`interpret=False`), against the float32 XLA paths in that file;
+           the paged ones at GPT-2 small's heads and at a GQA shape
   trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
            state threaded and donated; loss trajectory against a plain
            `jax.jit` of the einsum-attention step
@@ -121,7 +122,8 @@ def _close(name, got, want, rtol=KERNEL_RTOL):
 
 
 def phase_kernels(batch=TRAIN_BATCH, heads=12, d=64, seq=1024, slots=8,
-                  page_tokens=64, interpret=False):
+                  page_tokens=64, gqa_kv_heads=8, gqa_head_dim=128,
+                  interpret=False):
     """Each Pallas entry point at the shapes the trainer and the server use,
     on ragged lengths, against the float32 XLA path beside it."""
     import jax
@@ -185,44 +187,69 @@ def phase_kernels(batch=TRAIN_BATCH, heads=12, d=64, seq=1024, slots=8,
     errs["decode"] = _close("bucketed decode", got, want)
 
     # ---- paged decode: the same rows scattered over an arena through a
-    # shuffled page table, dead windows on the sentinel
-    max_pages = seq // page_tokens
-    n_pages = slots * max_pages + 3
-    perm = np.random.RandomState(0).permutation(n_pages)
-    table = np.full((slots, max_pages), n_pages, np.int32)
-    kp = np.zeros((n_pages, heads, page_tokens, d), np.float32)
-    vp = np.zeros_like(kp)
-    kc_h, vc_h = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
-    for row in range(slots):
-        for j in range(-(-int(lengths[row]) // page_tokens)):
-            pid = int(perm[row * max_pages + j])
-            table[row, j] = pid
-            win = slice(j * page_tokens, (j + 1) * page_tokens)
-            kp[pid] = kc_h[row, :, win]
-            vp[pid] = vc_h[row, :, win]
-    table = jnp.asarray(table)
-    kp, vp = jnp.asarray(kp, bf), jnp.asarray(vp, bf)
-    got = jax.jit(lambda *a: fa.flash_paged_decode_attention(
-        *a, interpret=interpret))(qd, kp, vp, table, lengths)
-    want_p = reference(lambda q, k, v, t, l: fa._paged_decode_attention_xla(
-        q, k, v, t, l, scale))(*f32(qd, kp, vp), table, lengths)
-    errs["paged_decode"] = _close("paged decode", got, want_p)
+    # shuffled page table, dead windows on the sentinel; then int8 pages
+    # with one and two scale blocks per row
+    def paged(tag, qd, kc, vc, lengths):
+        slots, kv_heads, _, hd = kc.shape
+        max_pages = seq // page_tokens
+        n_pages = slots * max_pages + 3
+        perm = np.random.RandomState(0).permutation(n_pages)
+        table = np.full((slots, max_pages), n_pages, np.int32)
+        kp = np.zeros((n_pages, kv_heads, page_tokens, hd), np.float32)
+        vp = np.zeros_like(kp)
+        kc_h, vc_h = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
+        for row in range(slots):
+            for j in range(-(-int(lengths[row]) // page_tokens)):
+                pid = int(perm[row * max_pages + j])
+                table[row, j] = pid
+                win = slice(j * page_tokens, (j + 1) * page_tokens)
+                kp[pid] = kc_h[row, :, win]
+                vp[pid] = vc_h[row, :, win]
+        table = jnp.asarray(table)
+        kp, vp = jnp.asarray(kp, bf), jnp.asarray(vp, bf)
+        scale = 1.0 / float(np.sqrt(hd))
+        got = jax.jit(lambda *a: fa.flash_paged_decode_attention(
+            *a, interpret=interpret))(qd, kp, vp, table, lengths)
+        want_p = reference(
+            lambda q, k, v, t, l: fa._paged_decode_attention_xla(
+                q, k, v, t, l, scale))(*f32(qd, kp, vp), table, lengths)
+        errs[f"paged_decode{tag}"] = _close(f"paged decode{tag}", got, want_p)
+        for nb in (1, 2):
+            kq, ks = fa.kv_quantize(kp.astype(jnp.float32), nb)
+            vq, vs = fa.kv_quantize(vp.astype(jnp.float32), nb)
+            got = jax.jit(lambda *a: fa.flash_paged_decode_quant_attention(
+                *a, interpret=interpret))(qd, kq, vq, ks, vs, table, lengths)
+            want_q = reference(
+                lambda q, k, v, a, b, t, l:
+                fa._paged_decode_attention_quant_xla(
+                    q, k, v, a, b, t, l, scale))(
+                qd.astype(jnp.float32), kq, vq, ks, vs, table, lengths)
+            errs[f"paged_decode_int8_nb{nb}{tag}"] = _close(
+                f"int8 paged decode nb={nb}{tag}", got, want_q)
+        return want_p
+
+    want_p = paged("", qd, kc, vc, lengths)
     # the page table is an indirection, not arithmetic: the paged reference
     # equals the contiguous one
     _close("paged reference vs contiguous", want_p, want, rtol=1e-5)
+    del kc, vc
 
-    # ---- int8 paged decode, one and two scale blocks per row
-    for nb in (1, 2):
-        kq, ks = fa.kv_quantize(kp.astype(jnp.float32), nb)
-        vq, vs = fa.kv_quantize(vp.astype(jnp.float32), nb)
-        got = jax.jit(lambda *a: fa.flash_paged_decode_quant_attention(
-            *a, interpret=interpret))(qd, kq, vq, ks, vs, table, lengths)
-        want_q = reference(
-            lambda q, k, v, a, b, t, l: fa._paged_decode_attention_quant_xla(
-                q, k, v, a, b, t, l, scale))(
-            qd.astype(jnp.float32), kq, vq, ks, vs, table, lengths)
-        errs[f"paged_decode_int8_nb{nb}"] = _close(
-            f"int8 paged decode nb={nb}", got, want_q)
+    # ---- the same at a GQA shape (four query heads a KV head, as the chat
+    # cell's Mistral widths), lengths on both sides of the boundaries of a
+    # grid step's block of pages
+    kv_heads, gd = gqa_kv_heads, gqa_head_dim
+    block = fa._paged_step_shape(
+        seq // page_tokens,
+        (jax.ShapeDtypeStruct((1, kv_heads, page_tokens, gd), bf),) * 2
+    )[1] * page_tokens
+    lengths = jnp.asarray(np.clip(
+        ([1, block - 1, block, block + 1, 2 * block - 1, 2 * block + 1,
+          seq - block + 7, seq] * slots)[:slots], 1, seq), jnp.int32)
+    qg = jax.random.normal(keys[7], (slots, 4 * kv_heads, gd), bf)
+    kg, vg = (jax.random.normal(jax.random.fold_in(keys[7], i),
+                                (slots, kv_heads, seq, gd), bf)
+              for i in (1, 2))
+    paged("_gqa", qg, kg, vg, lengths)
 
     log("PASS kernels (interpret=%s): max error / max |reference| — %s"
         % (interpret, ", ".join(f"{k} {v:.1e}" for k, v in errs.items())))

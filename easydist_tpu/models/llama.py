@@ -599,9 +599,10 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, pages, table, token,
                             pos):
     """`llama_decode_step` against the page arena: the new roped K/V row
     lands at window `pos // page_tokens`, offset `pos % page_tokens`, and
-    attention runs through `ops.paged_decode_attention` (the kernel maps
-    query head -> kv head in its index maps; the fallback gathers then
-    GQA-repeats, bitwise-matching the bucketed repeat-then-attend)."""
+    attention runs through `ops.paged_decode_attention` (the kernel holds
+    whole pages and lets every query head of a GQA group attend its kv
+    head's rows, read once; the fallback gathers then GQA-repeats,
+    bitwise-matching the bucketed repeat-then-attend)."""
     from easydist_tpu.ops import kv_quantize, paged_decode_attention
 
     dtype = jnp.dtype(cfg.dtype)

@@ -413,24 +413,32 @@ def flash_attention(q, k, v, causal: bool = True,
 # ------------------------------------------------- single-query decode
 
 
-def _decode_block_update(q, k_blk, v_blk, k0, length, o_scr, m_scr, l_scr):
-    """Online-softmax update of one query row against one K/V block, shared
-    by the three decode kernels: q [1, d] (pre-scaled), k_blk/v_blk
-    [bk, d], all f32; `k0` is the block's first cache position and
-    positions >= `length` are masked (unwritten slots, not future
-    tokens)."""
-    s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [1, bk]
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _decode_block_update(q, k_blk, v_blk, k0, length, scale, o_scr, m_scr,
+                         l_scr):
+    """Online-softmax update of `r` query rows per KV head against one K/V
+    block, shared by the three decode kernels: q [g, r, d], k_blk/v_blk
+    [g, t, d] (g KV heads, r = the GQA group; 1, 1 for the contiguous
+    kernel), each still in the dtype it was stored in.  `k0` is the block's
+    first cache position and positions >= `length` are masked (unwritten
+    slots, not future tokens).  q . K^T goes to the MXU in the operands' own
+    dtype when they share one (bf16 products are exact in the f32
+    accumulator) and in f32 otherwise; scores, statistics, p and the
+    accumulator are f32, and p . V runs in f32."""
+    if q.dtype != k_blk.dtype:
+        q, k_blk = q.astype(jnp.float32), k_blk.astype(jnp.float32)
+    s = jnp.einsum("grd,gtd->grt", q, k_blk,
+                   preferred_element_type=jnp.float32) * scale
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     s = jnp.where(k_pos < length, s, _NEG_INF)
-    m_prev = m_scr[...]                                 # [1, 1]
+    m_prev = m_scr[...]                                 # [g, r, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     m_scr[...] = m_new
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    o_scr[...] = o_scr[...] * alpha + jnp.dot(
-        p, v_blk, preferred_element_type=jnp.float32)
+    o_scr[...] = o_scr[...] * alpha + jnp.einsum(
+        "grt,gtd->grd", p, v_blk.astype(jnp.float32),
+        preferred_element_type=jnp.float32)
 
 
 def _decode_init(o_scr, m_scr, l_scr):
@@ -443,10 +451,10 @@ def _decode_result(o_scr, l_scr):
     return o_scr[...] / jnp.maximum(l_scr[...], 1e-30)
 
 
-def _decode_scratch(d: int):
-    return [pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32)]
+def _decode_scratch(g: int, r: int, d: int):
+    return [pltpu.VMEM((g, r, d), jnp.float32),
+            pltpu.VMEM((g, r, 1), jnp.float32),
+            pltpu.VMEM((g, r, 1), jnp.float32)]
 
 
 def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, o_scr, m_scr,
@@ -464,14 +472,12 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, o_scr, m_scr,
 
     @pl.when(ki * block_k < length)
     def _compute():
-        _decode_block_update(q_ref[0].astype(jnp.float32) * scale,
-                             k_ref[0].astype(jnp.float32),
-                             v_ref[0].astype(jnp.float32),
-                             ki * block_k, length, o_scr, m_scr, l_scr)
+        _decode_block_update(q_ref[...], k_ref[...], v_ref[...],
+                             ki * block_k, length, scale, o_scr, m_scr, l_scr)
 
     @pl.when(ki == n_k - 1)
     def _write():
-        o_ref[0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
+        o_ref[...] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
 
 
 def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
@@ -516,7 +522,7 @@ def flash_decode_attention(q, k, v, lengths, scale: Optional[float] = None,
         ],
         out_specs=pl.BlockSpec((1, 1, d),
                                lambda bh, ki, len_ref: (bh, 0, 0)),
-        scratch_shapes=_decode_scratch(d),
+        scratch_shapes=_decode_scratch(1, 1, d),
     )
     with jax.named_scope("flash_decode"):
         out = pl.pallas_call(
@@ -670,70 +676,138 @@ def _paged_decode_attention_quant_xla(q, k_pages, v_pages, k_scale,
     return _decode_attention_xla(q, kf, vf, lengths, scale)
 
 
-def _paged_kv_index_map(page_tokens: int, n_pages: int, rep: int):
-    """K/V (and scale) block index for grid (bi, hi, pi): window pi of row
-    bi resolves through the scalar-prefetched page table.  Dead windows
-    (pi past the row's live pages) clamp to the last live one — a repeated
-    index, so Pallas skips the DMA and the kernel's @pl.when skips the
-    compute.  GQA maps query head hi to kv head hi // rep."""
-    def kv_map(bi, hi, pi, tbl_ref, len_ref):
+# One grid step of the paged kernels covers up to this many tokens of a
+# row (swept on a v5e at 32 slots, 8 KV heads of 128, 64-token pages:
+# PERF.md section 6, PR 25) ...
+_PAGED_STEP_TOKENS = 256
+# ... as long as what the step holds in VMEM stays under this: half of the
+# 16 MiB a v5e kernel may scope
+_PAGED_VMEM_BUDGET = 8 * 2 ** 20
+
+
+def _vmem_block_bytes(shape, dtype) -> int:
+    """Bytes a block takes in VMEM: the minor dim padded to 128 lanes, the
+    one before it to the dtype's sublane tile (8 rows of 32 bits)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sublanes = 8 * max(4 // itemsize, 1)
+    return (math.prod(lead) * -(-rows // sublanes) * sublanes
+            * -(-lanes // 128) * 128 * itemsize)
+
+
+def _largest_divisor(n: int, fits) -> int:
+    return next((m for m in range(n, 1, -1) if n % m == 0 and fits(m)), 1)
+
+
+def _paged_step_bytes(pages, g: int, n: int) -> int:
+    """VMEM one grid step of the paged kernels takes with g KV heads of n
+    pages: every `pages` operand's block double-buffered, and the f32
+    working copies of one page's K and V."""
+    _, _, pt, d = pages[0].shape
+    return (2 * n * sum(_vmem_block_bytes((g, pt, a.shape[-1]), a.dtype)
+                        for a in pages)
+            + 4 * _vmem_block_bytes((g, pt, d), jnp.float32))
+
+
+def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None):
+    """(KV heads, pages) one grid step of the paged kernels holds, from the
+    shapes and dtypes of the `pages` operands ([n_pages, kv_heads,
+    page_tokens, *] each; K first) alone.  Heads: all of them — a page is
+    then one contiguous block — unless one such page is over
+    `_PAGED_VMEM_BUDGET` (then the largest divisor of kv_heads that fits).
+    Pages: the largest divisor of `max_pages` that is at most `want`
+    (default: `_PAGED_STEP_TOKENS` worth) and fits the budget."""
+    _, kvh, pt, _ = pages[0].shape
+    g = _largest_divisor(
+        kvh, lambda g: _paged_step_bytes(pages, g, 1) <= _PAGED_VMEM_BUDGET)
+    if want is None:
+        want = max(_PAGED_STEP_TOKENS // pt, 1)
+    n = _largest_divisor(
+        max_pages, lambda n: n <= want
+        and _paged_step_bytes(pages, g, n) <= _PAGED_VMEM_BUDGET)
+    return g, n
+
+
+def _paged_kv_index_map(j: int, n_step: int, page_tokens: int, n_pages: int):
+    """Block index of the j-th K/V (or scale) page operand for grid
+    (bi, gi, pi): window pi * n_step + j of row bi, resolved through the
+    scalar-prefetched page table; a block is the gi-th group of KV heads of
+    one arena page (as a rule all of them: the whole page).  Dead windows
+    (past the row's live pages) clamp to the last live one — from the
+    second dead step on a repeated index, so Pallas skips the DMA, and the
+    kernel's @pl.when skips the compute.  (Clamping each operand to ITS
+    last live window saves the copies of a row's first dead step too, and
+    measured 6% slower on the v5e: the maps run on the scalar core every
+    step.)  Sentinel entries clip to a real page, as `gather_pages` does."""
+    def kv_map(bi, gi, pi, tbl_ref, len_ref):
         last_live = jnp.maximum(
             jax.lax.div(len_ref[bi] + page_tokens - 1, page_tokens) - 1, 0)
-        page = tbl_ref[bi, jnp.minimum(pi, last_live)]
-        return (jnp.clip(page, 0, n_pages - 1), hi // rep, 0, 0)
+        page = tbl_ref[bi, jnp.minimum(pi * n_step + j, last_live)]
+        return (jnp.clip(page, 0, n_pages - 1), gi, 0, 0)
 
     return kv_map
 
 
-def _q_map(bi, hi, pi, tbl_ref, len_ref):
-    return (bi, hi, 0, 0)
+def _q_map(bi, gi, pi, tbl_ref, len_ref):
+    return (bi, gi, 0, 0)
 
 
 def _paged_decode_call(kernel, name: str, q, pages, table, lengths,
-                       interpret: bool):
+                       pages_per_step: Optional[int], interpret: bool):
     """The pallas_call shared by the exact and the int8 paged kernels
-    (`name`: `paged_decode` or `paged_decode_int8`, as the trace shows it):
-    grid (batch, heads, max_pages), table and lengths scalar-prefetched,
-    every `pages` operand ([n_pages, kv_heads, page_tokens, *]) streamed
-    one page per step through the same table index map.  q and the output
-    ride a [batch, heads, 1, head_dim] view so each head's single row is
-    a block whose trailing dims equal the array's — the (1, head_dim)
-    block of a [batch, heads, head_dim] array is not a legal TPU block."""
+    (`name`: `paged_decode` or `paged_decode_int8`, as the trace shows it).
+    Grid (batch, kv_heads / g, max_pages / n) with g and n from
+    `_paged_step_shape`: one step serves EVERY query head of g KV heads (as
+    a rule all heads of the row) over n consecutive windows of the row's
+    page table, so a live K/V row leaves HBM once, whatever the GQA group.
+    Pages are not contiguous in the arena, so every `pages` operand
+    ([n_pages, kv_heads, page_tokens, *]) is passed n times, the j-th copy
+    with the index map of window pi * n + j: BlockSpec pipelining issues
+    the n page copies of the next step while this one computes.  Table and
+    lengths are scalar-prefetched.  q and the output ride a
+    [batch, kv_heads, group, head_dim] view, whose blocks' trailing dims
+    equal the array's."""
     b, h, d = q.shape
     n_pages, kvh, pt, _ = pages[0].shape
     mp = table.shape[1]
     if h % kvh:
         raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
-    kv_map = _paged_kv_index_map(pt, n_pages, h // kvh)
+    rep = h // kvh
+    g, n_step = _paged_step_shape(mp, pages, pages_per_step)
+    qo_spec = pl.BlockSpec((1, g, rep, d), _q_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, mp),
-        in_specs=[pl.BlockSpec((1, 1, 1, d), _q_map)] + [
-            pl.BlockSpec((1, 1, pt, a.shape[-1]), kv_map) for a in pages],
-        out_specs=pl.BlockSpec((1, 1, 1, d), _q_map),
-        scratch_shapes=_decode_scratch(d),
+        grid=(b, kvh // g, mp // n_step),
+        in_specs=[qo_spec] + [
+            pl.BlockSpec((1, g, pt, a.shape[-1]),
+                         _paged_kv_index_map(j, n_step, pt, n_pages))
+            for a in pages for j in range(n_step)],
+        out_specs=qo_spec,
+        scratch_shapes=_decode_scratch(g, rep, d),
     )
     with jax.named_scope(name):
         out = pl.pallas_call(
-            functools.partial(kernel, page_tokens=pt, n_pages_max=mp),
+            functools.partial(kernel, page_tokens=pt, n_step=n_step),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name=name,
         )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-          q.reshape(b, h, 1, d), *pages)
+          q.reshape(b, kvh, rep, d),
+          *[a for a in pages for _ in range(n_step)])
     return out.reshape(b, h, d)
 
 
-def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
-                               o_ref, o_scr, m_scr, l_scr, *, scale: float,
-                               page_tokens: int, n_pages_max: int):
-    """The single-query decode kernel with the K/V stream indirected
-    through the page table: grid step pi wants the page holding tokens
-    [pi*pt, (pi+1)*pt), and the BlockSpec index map (not the kernel body)
-    resolves it via the scalar-prefetched table."""
+def _paged_decode_steps(len_ref, q_ref, o_ref, o_scr, m_scr, l_scr, load_page,
+                        *, scale: float, page_tokens: int, n_step: int):
+    """The body both paged kernels share.  Grid step (bi, gi, pi) holds the
+    pages of windows [pi * n_step, (pi + 1) * n_step) of row bi (the
+    BlockSpec index maps, not this body, chased the table); `load_page(j)`
+    gives the j-th one's K and V as [g, page_tokens, d].  A step that starts
+    past the row's length is skipped whole, a dead page inside a live step
+    one by one."""
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
@@ -742,20 +816,37 @@ def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
 
     length = len_ref[pl.program_id(0)]
 
-    @pl.when(pi * page_tokens < length)
-    def _compute():
-        _decode_block_update(q_ref[0, 0].astype(jnp.float32) * scale,
-                             k_ref[0, 0].astype(jnp.float32),
-                             v_ref[0, 0].astype(jnp.float32),
-                             pi * page_tokens, length, o_scr, m_scr, l_scr)
+    @pl.when(pi * n_step * page_tokens < length)
+    def _live_step():
+        q = q_ref[0]
+        for j in range(n_step):
+            k0 = (pi * n_step + j) * page_tokens
 
-    @pl.when(pi == n_pages_max - 1)
+            @pl.when(k0 < length)
+            def _page():
+                k_blk, v_blk = load_page(j)
+                _decode_block_update(q, k_blk, v_blk, k0, length, scale,
+                                     o_scr, m_scr, l_scr)
+
+    @pl.when(pi == pl.num_programs(2) - 1)
     def _write():
-        o_ref[0, 0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
+        o_ref[0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
+
+
+def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, n_step: int,
+                               **kw):
+    """The single-query decode kernel with the K/V stream indirected
+    through the page table: `refs` are n_step K pages, n_step V pages, the
+    output and the scratch."""
+    k_refs, v_refs = refs[:n_step], refs[n_step:2 * n_step]
+    _paged_decode_steps(
+        len_ref, q_ref, *refs[2 * n_step:],
+        lambda j: (k_refs[j][0], v_refs[j][0]), n_step=n_step, **kw)
 
 
 def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
                                  scale: Optional[float] = None,
+                                 pages_per_step: Optional[int] = None,
                                  interpret: Optional[bool] = None):
     """Single-query flash attention through a page table (paged decode).
 
@@ -766,78 +857,70 @@ def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
     grid runs, so the K/V BlockSpec index maps can chase the indirection
     and clamp dead windows (>= the row's live page count) to the last
     live page — a repeated index that Pallas serves without re-DMA, the
-    paged extension of the contiguous kernel's dead-block skip.  GQA maps
-    query head hi to kv head hi // (heads // kv_heads) in the same index
-    maps.  Returns [batch, heads, head_dim]."""
+    paged extension of the contiguous kernel's dead-block skip.  One grid step holds whole pages
+    (all KV heads: a page is one contiguous block of the arena) of several
+    consecutive windows, and every query head of a GQA group attends the
+    page of its KV head while it is in VMEM.  `pages_per_step` is for tests
+    and sweeps; left None, `_paged_step_shape` derives it from the shapes.
+    Returns [batch, heads, head_dim]."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = _default_interpret()
     kernel = functools.partial(_flash_paged_decode_kernel, scale=scale)
     return _paged_decode_call(kernel, "paged_decode", q, (k_pages, v_pages),
-                              table, lengths, interpret)
+                              table, lengths, pages_per_step, interpret)
 
 
 def _dequant_block(blk_ref, s_ref):
-    """One int8 page [pt, d] times its per-row block scales [pt, nb], in
-    VMEM.  Each scale column is pulled out by a masked lane reduction and
-    selected onto its d // nb payload columns, so everything stays 2-D:
-    Mosaic refuses the lane-splitting reshape(pt, nb, d // nb)
+    """One int8 page [g, pt, d] times its per-row block scales [g, pt, nb],
+    in VMEM.  Each scale column is pulled out by a masked lane reduction
+    and selected onto its d // nb payload columns, so the minor dims keep
+    their shape: Mosaic refuses the lane-splitting reshape(pt, nb, d // nb)
     ("infer-vector-layout: unsupported shape cast", v5e, libtpu 0.0.34)."""
-    blk = blk_ref[0, 0].astype(jnp.float32)             # [pt, d]
-    sc = s_ref[0, 0]                                    # [pt, nb]
-    nb = sc.shape[1]
+    blk = blk_ref[0].astype(jnp.float32)                # [g, pt, d]
+    sc = s_ref[0]                                       # [g, pt, nb]
+    nb = sc.shape[-1]
     if nb == 1:
         return blk * sc
-    col = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 2)
+    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
     scale = None
     for j in range(nb):
         sc_j = jnp.sum(jnp.where(lane == j, sc, 0.0), axis=-1,
-                       keepdims=True)                   # [pt, 1]
+                       keepdims=True)                   # [g, pt, 1]
         scale = sc_j if scale is None else jnp.where(
-            col >= j * (blk.shape[1] // nb), sc_j, scale)
+            col >= j * (blk.shape[-1] // nb), sc_j, scale)
     return blk * scale
 
 
-def _flash_paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
-                                     ks_ref, vs_ref, o_ref, o_scr, m_scr,
-                                     l_scr, *, scale: float,
-                                     page_tokens: int, n_pages_max: int):
+def _flash_paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, *refs,
+                                     n_step: int, **kw):
     """`_flash_paged_decode_kernel` over block-scaled int8 pages: the K/V
-    blocks arrive int8 with their per-block f32 scales riding the SAME
-    page-table index map, and dequantization happens in VMEM inside the
-    online-softmax loop — the arena stream stays int8 all the way from
-    HBM, which is the whole 2-4x bytes/seq win."""
-    pi = pl.program_id(2)
-
-    @pl.when(pi == 0)
-    def _init():
-        _decode_init(o_scr, m_scr, l_scr)
-
-    length = len_ref[pl.program_id(0)]
-
-    @pl.when(pi * page_tokens < length)
-    def _compute():
-        _decode_block_update(q_ref[0, 0].astype(jnp.float32) * scale,
-                             _dequant_block(k_ref, ks_ref),
-                             _dequant_block(v_ref, vs_ref),
-                             pi * page_tokens, length, o_scr, m_scr, l_scr)
-
-    @pl.when(pi == n_pages_max - 1)
-    def _write():
-        o_ref[0, 0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
+    pages arrive int8 with their per-block f32 scale pages riding the SAME
+    page-table index maps (`refs`: n_step each of K, V, K scales, V scales,
+    then the output and the scratch), and dequantization happens in VMEM
+    inside the online-softmax loop — the arena stream stays int8 all the
+    way from HBM, which is the whole 2-4x bytes/seq win."""
+    k_refs, v_refs, ks_refs, vs_refs = (
+        refs[i * n_step:(i + 1) * n_step] for i in range(4))
+    _paged_decode_steps(
+        len_ref, q_ref, *refs[4 * n_step:],
+        lambda j: (_dequant_block(k_refs[j], ks_refs[j]),
+                   _dequant_block(v_refs[j], vs_refs[j])),
+        n_step=n_step, **kw)
 
 
 def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
                                        v_scale, table, lengths,
                                        scale: Optional[float] = None,
+                                       pages_per_step: Optional[int] = None,
                                        interpret: Optional[bool] = None):
     """`flash_paged_decode_attention` over a block-scaled int8 arena.
 
     k_pages/v_pages: int8 [n_pages, kv_heads, page_tokens, head_dim];
     k_scale/v_scale: f32 [n_pages, kv_heads, page_tokens, n_blocks].  The
-    scale pages ride the same scalar-prefetched table index map as the
+    scale pages ride the same scalar-prefetched table index maps as the
     payload (one indirection, four streams), and the kernel dequantizes
     on-chip inside the online-softmax loop.  Returns [batch, heads,
     head_dim] in q.dtype."""
@@ -848,7 +931,7 @@ def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
     kernel = functools.partial(_flash_paged_decode_quant_kernel, scale=scale)
     return _paged_decode_call(kernel, "paged_decode_int8", q,
                               (k_pages, v_pages, k_scale, v_scale), table,
-                              lengths, interpret)
+                              lengths, pages_per_step, interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, table, lengths,

@@ -8,12 +8,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from easydist_tpu.ops.flash_attention import (_decode_attention_xla,
-                                              _paged_decode_attention_xla,
-                                              decode_attention,
-                                              flash_paged_decode_attention,
-                                              gather_pages,
-                                              paged_decode_attention)
+from easydist_tpu.ops.flash_attention import (
+    _decode_attention_xla, _paged_decode_attention_quant_xla,
+    _paged_decode_attention_xla, decode_attention,
+    flash_paged_decode_attention, flash_paged_decode_quant_attention,
+    gather_pages, kv_quantize, paged_decode_attention)
 
 PT = 8          # page_tokens
 MP = 4          # max_pages per row -> virtual cache length 32
@@ -146,6 +145,130 @@ class TestFlashPagedKernelInterpret:
             flash_paged_decode_attention(q[:, :3], kp, vp, table,
                                          jnp.asarray([8], jnp.int32),
                                          interpret=True)
+
+
+# ---- the blocked kernel: every head of a row and several pages a grid step
+
+BMP = 6         # max_pages: 2 and 3 divide it, 4 does not
+BNP = 28        # arena pages: at most 24 live below, the rest unmapped
+GARBAGE = 1e4   # large and finite, like stale KV
+
+
+def _boundary_lengths(pages_per_step):
+    """1, one short of / exactly at / one past a page and a block boundary,
+    and a full bucket."""
+    block = (pages_per_step or BMP) * PT
+    return sorted({1, PT - 1, PT, PT + 1, block - 1, block,
+                   min(block + 1, BMP * PT), BMP * PT})
+
+
+def _blocked_setup(lengths, rep, kvh=2, d=16, seed=0, garbage=False):
+    """Rows scattered over the arena through a permuted table.  With
+    `garbage`, every row the mask must hide holds GARBAGE: unmapped arena
+    pages, the tail of a row's last live page, and whole dead windows that
+    still map a (stale) page instead of the sentinel."""
+    b = len(lengths)
+    rs = np.random.RandomState(seed)
+    kp, vp = (rs.standard_normal((BNP, kvh, PT, d)).astype(np.float32)
+              for _ in range(2))
+    q = jnp.asarray(rs.standard_normal((b, kvh * rep, d)), jnp.float32)
+    perm = [int(p) for p in rs.permutation(BNP)]
+    table = np.full((b, BMP), BNP, np.int32)
+    for row, n in enumerate(lengths):
+        live = -(-n // PT)
+        table[row, :live] = [perm.pop() for _ in range(live)]
+        if garbage:
+            last = table[row, live - 1]
+            kp[last, :, n - (live - 1) * PT:] = GARBAGE
+            vp[last, :, n - (live - 1) * PT:] = -GARBAGE
+    if garbage:
+        for pid in perm:                     # never mapped live
+            kp[pid], vp[pid] = GARBAGE, -GARBAGE
+        for row, n in enumerate(lengths):    # stale mappings, dead windows
+            table[row, -(-n // PT):] = perm[row % len(perm)]
+    return (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),
+            jnp.asarray(lengths, jnp.int32))
+
+
+class TestBlockedKernelInterpret:
+    @pytest.mark.parametrize("pages_per_step", [2, 4, None],
+                             ids=["divides", "does-not-divide", "rule"])
+    @pytest.mark.parametrize("rep", [1, 4, 8])
+    def test_matches_fallback(self, rep, pages_per_step):
+        q, kp, vp, table, L = _blocked_setup(
+            _boundary_lengths(pages_per_step), rep, garbage=True)
+        ref = _paged_decode_attention_xla(q, kp, vp, table, L, 0.25)
+        out = flash_paged_decode_attention(
+            q, kp, vp, table, L, scale=0.25, pages_per_step=pages_per_step,
+            interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("n_blocks", [1, 2])
+    @pytest.mark.parametrize("pages_per_step", [2, 4],
+                             ids=["divides", "does-not-divide"])
+    @pytest.mark.parametrize("rep", [1, 4])
+    def test_int8_matches_fallback(self, rep, pages_per_step, n_blocks):
+        q, kp, vp, table, L = _blocked_setup(
+            _boundary_lengths(pages_per_step), rep, garbage=True)
+        kq, ks = kv_quantize(kp, n_blocks)
+        vq, vs = kv_quantize(vp, n_blocks)
+        ref = _paged_decode_attention_quant_xla(q, kq, vq, ks, vs, table, L,
+                                                0.25)
+        out = flash_paged_decode_quant_attention(
+            q, kq, vq, ks, vs, table, L, scale=0.25,
+            pages_per_step=pages_per_step, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("n_blocks", [0, 1, 2],
+                             ids=["exact", "int8-nb1", "int8-nb2"])
+    def test_garbage_pages_unobservable(self, n_blocks):
+        # the kernel twin of TestXlaFallbackParity's: what sits in dead
+        # windows, unmapped pages and a last page's tail cannot move a bit
+        lengths = _boundary_lengths(2)
+        outs = []
+        for garbage in (False, True):
+            q, kp, vp, table, L = _blocked_setup(lengths, 4, garbage=garbage)
+            if n_blocks:
+                kq, ks = kv_quantize(kp, n_blocks)
+                vq, vs = kv_quantize(vp, n_blocks)
+                outs.append(flash_paged_decode_quant_attention(
+                    q, kq, vq, ks, vs, table, L, scale=0.25,
+                    pages_per_step=2, interpret=True))
+            else:
+                outs.append(flash_paged_decode_attention(
+                    q, kp, vp, table, L, scale=0.25, pages_per_step=2,
+                    interpret=True))
+        np.testing.assert_array_equal(np.asarray(outs[0]),
+                                      np.asarray(outs[1]))
+
+    def test_kv_heads_split_when_a_page_is_over_the_vmem_budget(
+            self, monkeypatch):
+        import importlib
+        fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+        q, kp, vp, table, L = _blocked_setup(_boundary_lengths(2), 4,
+                                             kvh=4, garbage=True)
+        # room for two of the four KV heads of a page, one page a step
+        monkeypatch.setattr(fa, "_PAGED_VMEM_BUDGET", 2 * (2 * 2 + 4) * 4096)
+        assert fa._paged_step_shape(BMP, (kp, vp)) == (2, 1)
+        ref = _paged_decode_attention_xla(q, kp, vp, table, L, 0.25)
+        out = flash_paged_decode_attention(q, kp, vp, table, L, scale=0.25,
+                                           interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+
+    def test_bf16_arena_matches_fallback(self):
+        # the arena's own dtype goes to the first product: bf16 x bf16 is
+        # exact in the f32 accumulator, so only the summation order differs
+        q, kp, vp, table, L = _blocked_setup(_boundary_lengths(2), 4)
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+        ref = _paged_decode_attention_xla(q, kp, vp, table, L, 0.25)
+        out = flash_paged_decode_attention(q, kp, vp, table, L, scale=0.25,
+                                           pages_per_step=2, interpret=True)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), atol=2e-2)
 
 
 class TestDispatch:

@@ -4,7 +4,9 @@ refuses fails tier-1 instead of waiting for a chip.  This runs Pallas' own
 jaxpr -> Mosaic lowering; Mosaic's compile is chip_smoke.py's job.
 
 Shapes: the ones chip_smoke.py uses (GPT-2 small: 12 heads of 64, bf16,
-64-token pages) and a GQA shape (32 query heads over 8 kv heads of 128).
+64-token pages) and a GQA shape (32 query heads over 8 kv heads of 128);
+the paged kernels also at the chat cell's own shape (32 slots, 32 windows)
+and at a wide MHA shape whose pages crowd the VMEM budget.
 Also a kernel inside a program emitted for a (2, 2) mesh, and the int8
 paged kernel against its XLA reference under the interpreter, which no
 other test compares."""
@@ -15,7 +17,8 @@ import numpy as np
 import pytest
 
 from easydist_tpu.ops.flash_attention import (
-    _paged_decode_attention_quant_xla, flash_attention,
+    _PAGED_STEP_TOKENS, _PAGED_VMEM_BUDGET, _paged_decode_attention_quant_xla,
+    _paged_step_bytes, _paged_step_shape, _vmem_block_bytes, flash_attention,
     flash_decode_attention, flash_paged_decode_attention,
     flash_paged_decode_quant_attention, kv_quantize)
 
@@ -24,6 +27,13 @@ SEQ, PAGE_TOKENS, N_PAGES = 1024, 64, 48
 # (batch, heads, kv_heads, head_dim)
 SHAPES = [pytest.param(8, 12, 12, 64, id="gpt2-small"),
           pytest.param(4, 32, 8, 128, id="gqa-32-8-128")]
+# the paged kernels: (batch, heads, kv_heads, head_dim, max_pages)
+PAGED_SHAPES = [
+    pytest.param(8, 12, 12, 64, SEQ // PAGE_TOKENS, id="gpt2-small"),
+    pytest.param(4, 32, 8, 128, SEQ // PAGE_TOKENS, id="gqa-32-8-128"),
+    pytest.param(32, 32, 8, 128, 32, id="chat-cell"),
+    pytest.param(8, 32, 32, 128, 24, id="mha-32-128"),
+]
 
 
 def _lower_for_tpu(fn, *avals):
@@ -56,26 +66,75 @@ def test_bucketed_decode_lowers(b, h, kvh, d):
         _aval((b, h, d), BF16), cache, cache, _aval((b,), jnp.int32))
 
 
-@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
-def test_paged_decode_lowers(b, h, kvh, d):
+@pytest.mark.parametrize("b,h,kvh,d,max_pages", PAGED_SHAPES)
+def test_paged_decode_lowers(b, h, kvh, d, max_pages):
     pages = _aval((N_PAGES, kvh, PAGE_TOKENS, d), BF16)
     _lower_for_tpu(
         lambda q, k, v, t, n: flash_paged_decode_attention(
             q, k, v, t, n, interpret=False),
         _aval((b, h, d), BF16), pages, pages,
-        _aval((b, SEQ // PAGE_TOKENS), jnp.int32), _aval((b,), jnp.int32))
+        _aval((b, max_pages), jnp.int32), _aval((b,), jnp.int32))
 
 
 @pytest.mark.parametrize("n_blocks", [1, 2])
-@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
-def test_int8_paged_decode_lowers(b, h, kvh, d, n_blocks):
+@pytest.mark.parametrize("b,h,kvh,d,max_pages", PAGED_SHAPES)
+def test_int8_paged_decode_lowers(b, h, kvh, d, max_pages, n_blocks):
     pages = _aval((N_PAGES, kvh, PAGE_TOKENS, d), jnp.int8)
     scales = _aval((N_PAGES, kvh, PAGE_TOKENS, n_blocks), jnp.float32)
     _lower_for_tpu(
         lambda q, k, v, ks, vs, t, n: flash_paged_decode_quant_attention(
             q, k, v, ks, vs, t, n, interpret=False),
         _aval((b, h, d), BF16), pages, pages, scales, scales,
-        _aval((b, SEQ // PAGE_TOKENS), jnp.int32), _aval((b,), jnp.int32))
+        _aval((b, max_pages), jnp.int32), _aval((b,), jnp.int32))
+
+
+def _arena_avals(kvh, d, quant_blocks=0):
+    if not quant_blocks:
+        return (_aval((N_PAGES, kvh, PAGE_TOKENS, d), BF16),) * 2
+    return ((_aval((N_PAGES, kvh, PAGE_TOKENS, d), jnp.int8),) * 2
+            + (_aval((N_PAGES, kvh, PAGE_TOKENS, quant_blocks),
+                     jnp.float32),) * 2)
+
+
+@pytest.mark.parametrize("quant_blocks", [0, 1, 2])
+@pytest.mark.parametrize("b,h,kvh,d,max_pages", PAGED_SHAPES)
+def test_paged_step_shape_rule(b, h, kvh, d, max_pages, quant_blocks):
+    """The block of the paged kernels' grid: divisors of kv_heads and of
+    max_pages, at most `_PAGED_STEP_TOKENS` tokens, under the VMEM budget,
+    the largest such, and a function of the shapes alone."""
+    pages = _arena_avals(kvh, d, quant_blocks)
+    g, n = _paged_step_shape(max_pages, pages)
+    assert (g, n) == _paged_step_shape(max_pages,
+                                       _arena_avals(kvh, d, quant_blocks))
+    assert kvh % g == 0 and max_pages % n == 0
+    assert n * PAGE_TOKENS <= _PAGED_STEP_TOKENS
+    assert _paged_step_bytes(pages, g, n) <= _PAGED_VMEM_BUDGET
+    # whole pages, unless one page of all heads is over the budget
+    assert g == kvh or _paged_step_bytes(pages, 2 * g, 1) > _PAGED_VMEM_BUDGET
+    # the next divisor up would break one of the two limits
+    bigger = [m for m in range(n + 1, max_pages + 1) if max_pages % m == 0]
+    assert not bigger or bigger[0] * PAGE_TOKENS > _PAGED_STEP_TOKENS \
+        or _paged_step_bytes(pages, g, bigger[0]) > _PAGED_VMEM_BUDGET
+
+
+def test_paged_step_shape_by_hand():
+    cell = _arena_avals(8, 128)
+    # a bf16 page of 8 heads x 64 tokens x 128 is 128 KiB; K and V, double-
+    # buffered: 512 KiB a page of the block, beside 4 x 256 KiB of f32 work
+    assert _vmem_block_bytes(cell[0].shape[1:], BF16) == 128 * 1024
+    assert _paged_step_bytes(cell, 8, 4) == 3 * 2 ** 20
+    assert _paged_step_shape(32, cell) == (8, 4)            # 256 tokens
+    assert _paged_step_shape(32, cell, want=8) == (8, 8)    # a sweep's ask
+    assert _paged_step_shape(6, cell, want=4) == (8, 3)     # 4 divides no 6
+    assert _paged_step_shape(7, cell) == (8, 1)             # a prime
+    # minor dims pad to the (sublane, 128-lane) tile: an f32 scale page
+    # [32, 64, 1] takes as much VMEM as [32, 64, 128]
+    assert _vmem_block_bytes((32, 64, 1), jnp.float32) == 32 * 64 * 128 * 4
+    assert _vmem_block_bytes((12, 64, 64), BF16) == 12 * 64 * 128 * 2
+    # a page too large for the budget is split over groups of KV heads
+    assert _paged_step_shape(24, _arena_avals(32, 128, 1)) == (16, 2)
+    wide = (_aval((N_PAGES, 64, PAGE_TOKENS, 256), jnp.float32),) * 2
+    assert _paged_step_shape(8, wide) == (16, 1)
 
 
 @pytest.mark.parametrize("n_blocks", [1, 2])
