@@ -2984,7 +2984,8 @@ def kv_scale_main():
         exact_ids = run(exact, prompts)
         epool = next(iter(exact._pools.values()))
         exact_pure = sorted(epool.arena) == ["k", "v"] and not any(
-            np.dtype(epool.arena[k].dtype) == np.int8 for k in epool.arena)
+            np.dtype(leaf.dtype) == np.int8
+            for leaf in jax.tree_util.tree_leaves(epool.arena))
         exact_bitwise = exact_ids == want
 
         # ---- int8 arm: density + greedy agreement

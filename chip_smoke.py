@@ -16,6 +16,9 @@ against a reference computed beside it.  Phases, in order:
            `jax.jit` of the einsum-attention step
   server   `submit` / `step` / `run_until_drained`: bucketed, paged, paged
            int8; every token teacher-forced against one full `gpt_apply`
+  arena    the paged server again through `for_llama` at the chat cell's
+           widths, slots and 576-page arena but two layers: same checks, and
+           the compiled decode program holds no copy of an arena leaf
 
 It needs a TPU: without one it exits 2 before compiling anything.  No phase's
 exception is caught — a failure anywhere is a traceback and a nonzero exit.
@@ -43,6 +46,12 @@ _OUT = os.path.join(_HERE, "chiprun_out", "chip_smoke")
 # bench.py measures
 GPT2_SMALL = dict(vocab=50304, seq=1024, dim=768, heads=12, layers=12,
                   dtype="bfloat16")
+# the chat cell of BENCHMARK.json (chipbench/configs/mistral-7b-v0.3.json and
+# its serve_config), cut to two layers
+CELL_2_LAYERS = dict(vocab=32768, seq=2048, dim=4096, heads=32, kv_heads=8,
+                     layers=2, ffn_dim=14336, rope_theta=1e6,
+                     dtype="bfloat16")
+CELL_SERVE = dict(max_decode_slots=32, kv_arena_pages=576)
 TRAIN_BATCH = 8
 TRAIN_STEPS = 6
 # flash-attention trajectory vs the einsum-attention jit: both compute in
@@ -397,9 +406,29 @@ def _requests(vocab, bucket, chunk):
     return wave1, wave2
 
 
-def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
-    """`GenerationSession.for_gpt` on one chip in one KV layout; every
-    served token teacher-forced against one full float32 `gpt_apply`."""
+def _decode_temporaries(sess):
+    """(temporary bytes of the session's compiled paged decode program,
+    bytes of one arena leaf).  The arena is donated leaf by leaf and each
+    leaf written in place (kv/arena.py), so the program's temporaries must
+    stay under ONE leaf: a layer sliced out of a stacked arena, a stack, or
+    a write that changes a leaf's layout each cost at least that."""
+    import jax.numpy as jnp
+
+    pool = next(iter(sess._pools.values()))
+    rows = jnp.zeros((pool.n_slots,), jnp.int32)
+    table = jnp.zeros((pool.n_slots, pool.max_pages), jnp.int32)
+    exe = sess._paged_c("decode").executable_for(
+        pool.arena, sess.params, table, rows, rows)
+    leaf = min(int(x.nbytes) for x in pool.arena["k"])
+    return int(exe.memory_analysis().temp_size_in_bytes), leaf
+
+
+def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None,
+                 family="gpt", in_place=False):
+    """`GenerationSession.for_gpt` (or `.for_llama`) on one chip in one KV
+    layout; every served token teacher-forced against one full float32
+    forward of the model.  `in_place` also holds the compiled paged decode
+    program to `_decode_temporaries`' bound."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -407,9 +436,15 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
     from easydist_tpu.jaxfront import make_device_mesh
     from easydist_tpu.models import GPTConfig
     from easydist_tpu.models.gpt import gpt_apply
+    from easydist_tpu.models.llama import LlamaConfig, llama_apply
     from easydist_tpu.serve import GenerationSession, ServeConfig
 
-    cfg = GPTConfig(**(cfg_kw or GPT2_SMALL))
+    Config, apply, for_model = {
+        "gpt": (GPTConfig, gpt_apply, GenerationSession.for_gpt),
+        "llama": (LlamaConfig, llama_apply, GenerationSession.for_llama),
+    }[family]
+    cfg_kw = cfg_kw or GPT2_SMALL
+    cfg = Config(**cfg_kw)
     device = device or jax.devices()[0]
     mesh = make_device_mesh((1,), ("d",), devices=[device])
     layout_kw = {"bucketed": {},
@@ -419,7 +454,7 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
     config = ServeConfig(decode_buckets=(cfg.seq,), **layout_kw,
                          **(config_kw or {}))
     bucket, chunk = cfg.seq, min(config.prefill_chunk, cfg.seq)
-    sess = GenerationSession.for_gpt(params, cfg, config=config, mesh=mesh)
+    sess = for_model(params, cfg, config=config, mesh=mesh)
 
     t0 = time.perf_counter()
     served = []
@@ -448,7 +483,7 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
     # ---- teacher forcing: one full forward over prompt + served ids in
     # float32; token i must be (within the margin) the reference's pick at
     # position len(prompt) + i - 1
-    ref_cfg = GPTConfig(**{**(cfg_kw or GPT2_SMALL), "dtype": "float32"})
+    ref_cfg = Config(**{**cfg_kw, "dtype": "float32"})
 
     # the weights are an argument, not a closure: closed over, they would be
     # baked into the executable as ~500 MB of constants (slow to compile, and
@@ -456,7 +491,7 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
     @jax.jit
     def ref_logits(params, tokens):
         with jax.default_matmul_precision("highest"):
-            return gpt_apply(params, ref_cfg, tokens)[0]
+            return apply(params, ref_cfg, tokens)[0]
 
     worst, exact, total = 0.0, 0, 0
     for prompt, _, res in served:
@@ -487,6 +522,13 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None):
              "prefill_chunks": counters.get("prefill_chunks"),
              "decode_steps": counters.get("decode_steps"),
              "wall_s_incl_compile": round(wall, 1)}
+    if in_place:
+        temp, leaf = _decode_temporaries(sess)
+        assert temp < leaf, (
+            f"server[{layout}]: the compiled decode program holds {temp} "
+            f"bytes of temporaries, an arena leaf is {leaf}: some leaf is "
+            f"copied instead of written in place")
+        notes.update(decode_temp_bytes=temp, arena_leaf_bytes=leaf)
     sess.close()
     log(f"PASS server[{layout}]: {len(served)} requests, {total} tokens, "
         f"counts and finish reasons right, {exact}/{total} tokens are the "
@@ -554,6 +596,16 @@ def main() -> int:
     params = gpt_init(GPTConfig(**GPT2_SMALL), jax.random.PRNGKey(0))
     for layout in ("bucketed", "paged", "paged_int8"):
         notes[f"server_{layout}"] = phase_server(layout, params)
+    del params
+    from easydist_tpu.models.llama import LlamaConfig, llama_init
+
+    # bf16 weights as the cell holds them; the reference upcasts the same
+    cell_params = jax.jit(lambda key: jax.tree.map(
+        lambda x: x.astype("bfloat16"),
+        llama_init(LlamaConfig(**CELL_2_LAYERS), key)))(jax.random.PRNGKey(1))
+    notes["arena_cell_widths"] = phase_server(
+        "paged", cell_params, cfg_kw=CELL_2_LAYERS, config_kw=CELL_SERVE,
+        family="llama", in_place=True)
 
     summary = {
         "ok": True, "device": device, "versions": versions,

@@ -35,50 +35,60 @@ from typing import List
 from .findings import Finding, make_finding
 
 
-def audit_quant_arena(arena, node: str = "kv.quant") -> List[Finding]:
-    """KVQ001 over an arena pytree ({"k","v"[,"k_scale","v_scale"]})."""
+def _pair_problem(name: str, payload, scales):
+    """What is wrong between one layer's payload leaf and its scale leaf
+    (None where the arena carries no scales), or None."""
     import numpy as np
 
+    quantized = np.dtype(payload.dtype) == np.int8
+    if quantized and scales is None:
+        return (f"{name!r} payload is int8 but the arena carries no "
+                f"{name}_scale leaf — pages cannot be dequantized")
+    if not quantized and scales is not None:
+        return (f"arena carries {name}_scale over a "
+                f"{np.dtype(payload.dtype).name} payload — the exact path "
+                f"must stay scale-free (jaxpr-identical contract)")
+    if not quantized:
+        return None
+    if np.dtype(scales.dtype) != np.float32:
+        return (f"{name}_scale dtype is {np.dtype(scales.dtype).name}, "
+                f"expected float32")
+    d = int(payload.shape[-1])
+    nb = int(scales.shape[-1])
+    if tuple(scales.shape[:-1]) != tuple(payload.shape[:-1]) \
+            or nb < 1 or d % nb != 0:
+        return (f"{name}_scale shape {tuple(scales.shape)} does not "
+                f"block-partition payload shape {tuple(payload.shape)} "
+                f"(leading dims must match; head_dim {d} must divide into "
+                f"{nb} blocks) — dequant would broadcast scales onto the "
+                f"wrong pages")
+    return None
+
+
+def audit_quant_arena(arena, node: str = "kv.quant") -> List[Finding]:
+    """KVQ001 over an arena pytree ({"k","v"[,"k_scale","v_scale"]}, each
+    a tuple of one leaf per layer): every layer's payload leaf against the
+    scale leaf at the same position; one finding a key, naming the first
+    layer at fault."""
     findings: List[Finding] = []
     for name in ("k", "v"):
-        payload = arena.get(name)
-        if payload is None:
-            findings.append(make_finding(
-                "KVQ001", node, f"arena has no {name!r} payload leaf"))
-            continue
+        payloads = arena.get(name)
         scales = arena.get(f"{name}_scale")
-        quantized = np.dtype(payload.dtype) == np.int8
-        if quantized and scales is None:
-            findings.append(make_finding(
-                "KVQ001", node,
-                f"{name!r} payload is int8 but the arena carries no "
-                f"{name}_scale leaf — pages cannot be dequantized"))
-            continue
-        if not quantized and scales is not None:
-            findings.append(make_finding(
-                "KVQ001", node,
-                f"arena carries {name}_scale over a "
-                f"{np.dtype(payload.dtype).name} payload — the exact "
-                f"path must stay scale-free (jaxpr-identical contract)"))
-            continue
-        if not quantized:
-            continue
-        if np.dtype(scales.dtype) != np.float32:
-            findings.append(make_finding(
-                "KVQ001", node,
-                f"{name}_scale dtype is {np.dtype(scales.dtype).name}, "
-                f"expected float32"))
-        d = int(payload.shape[-1])
-        nb = int(scales.shape[-1])
-        if tuple(scales.shape[:-1]) != tuple(payload.shape[:-1]) \
-                or nb < 1 or d % nb != 0:
-            findings.append(make_finding(
-                "KVQ001", node,
-                f"{name}_scale shape {tuple(scales.shape)} does not "
-                f"block-partition payload shape {tuple(payload.shape)} "
-                f"(leading dims must match; head_dim {d} must divide "
-                f"into {nb} blocks) — dequant would broadcast scales "
-                f"onto the wrong pages"))
+        if not payloads:
+            problem = f"arena has no {name!r} payload leaf"
+        elif scales is not None and len(scales) != len(payloads):
+            problem = (f"{name}_scale has {len(scales)} layer leaves over "
+                       f"{len(payloads)} payload leaves")
+        else:
+            problem = None
+            for li, payload in enumerate(payloads):
+                problem = _pair_problem(
+                    name, payload, None if scales is None else scales[li])
+                if problem:
+                    problem = f"layer {li}: {problem}"
+                    break
+        if problem:
+            findings.append(make_finding("KVQ001", node, problem))
     return findings
 
 

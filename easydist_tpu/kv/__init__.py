@@ -23,9 +23,17 @@ storage too:
     sentinel drop (`mode="drop"`), gathers clip and the garbage row is
     masked to -inf before softmax.
 
-Arena layout matches the bucketed cache with pages replacing the batch
-axis — {"k","v"}: [layers, n_pages, (kv_)heads, page_tokens, head_dim] —
-so `kv_cache_specs` shards heads on "tp" identically for both layouts.
+  * `arena` — the device side: the arena is a pytree of per-layer leaves,
+    {"k": (leaf_0, ..., leaf_{L-1}), "v": (...)} (int8 arenas add
+    "k_scale" / "v_scale" the same way), each leaf [n_pages, (kv_)heads,
+    page_tokens, head_dim] in a buffer of its own.  A compiled step
+    donates the arena leaf by leaf and writes each layer's rows into that
+    layer's own input buffer — nothing the size of a leaf is ever copied,
+    sliced out or stacked back.  A page leaves the device in the format
+    it always had, {key: [layers, heads, page_tokens, *]} (one page of
+    every leaf, stacked), which is what the trie, the host tier and the
+    fleet transport hold, hash and ship.
+
 Analyze rule KV001 (`analyze/kv_rules.py`) audits the pool/table/trie
 bookkeeping; `check_invariants` here is the raw audit it wraps.
 """

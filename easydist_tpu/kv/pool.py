@@ -1,9 +1,10 @@
 """Refcounted page-pool allocator over one preallocated HBM arena.
 
-`PagePool` is pure host bookkeeping: the device arena (a {"k","v"} pytree
-of [layers, n_pages, heads, page_tokens, head_dim] arrays) is allocated
-once by the owner (GenerationSession via `models.init_kv_pages`) and
-threaded through compiled steps as a donated argument; the pool tracks
+`PagePool` is pure host bookkeeping: the device arena (`kv/arena.py`: a
+{"k","v"} pytree of one [n_pages, heads, page_tokens, head_dim] leaf per
+layer) is allocated once by the owner (GenerationSession via
+`models.init_kv_pages`) and threaded through compiled steps as an argument
+donated leaf by leaf; the pool tracks
 which of its `n_pages` page slots are free, how many holders reference
 each live page, and the utilization counters serving metrics report.
 

@@ -15,6 +15,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from easydist_tpu.kv.arena import (init_page_arena, write_chunk, write_row,
+                                   write_rows)
 from .optim import adam_init, adam_update
 
 
@@ -435,11 +437,14 @@ def gpt_decode_step(params, cfg: GPTConfig, cache, token, pos):
 # ------------------------------------------------------- paged KV decode
 #
 # Page-table variants of the serving forwards: K/V lives in one
-# preallocated page arena ({"k","v"}: [layers, n_pages, heads,
-# page_tokens, head_dim]) and each sequence's int32 page-table row says
-# which arena page holds each `page_tokens`-token window.  The arena is
-# threaded through (and donated) exactly like the contiguous cache; the
-# table is a few KiB of int32 pushed fresh each step.  Unmapped/dead
+# preallocated page arena ({"k","v"}: a tuple of one leaf per layer,
+# [n_pages, heads, page_tokens, head_dim]; `kv/arena.py`) and each
+# sequence's int32 page-table row says which arena page holds each
+# `page_tokens`-token window.  The arena is threaded through and donated
+# leaf by leaf: a layer's write lands in that layer's own input buffer and
+# the written leaf is returned as it is, never sliced out of a stacked
+# array and never stacked back.  The table is a few KiB of int32 pushed
+# fresh each step.  Unmapped/dead
 # entries hold the sentinel `n_pages`: writes through it scatter with
 # mode="drop" (deterministically discarded), reads clip to a real page
 # whose rows the length mask zeroes before softmax.
@@ -447,58 +452,20 @@ def gpt_decode_step(params, cfg: GPTConfig, cache, token, pos):
 
 def init_kv_pages(cfg: GPTConfig, n_pages: int, page_tokens: int,
                   dtype=None, quant_dtype=None, quant_block: int = 0):
-    """Zeroed page arena {"k", "v"}: [layers, n_pages, heads, page_tokens,
-    head_dim].  Pages replace the batch axis of `init_kv_cache` at the
-    same dim index, so `kv_cache_specs` shards heads (dim 2) on "tp"
-    identically for both layouts.
+    """Zeroed page arena (`kv/arena.py`): {"k", "v"}, each a tuple of one
+    leaf per layer, [n_pages, heads, page_tokens, head_dim] — a buffer of
+    its own, donated and written in place leaf by leaf.
 
-    `quant_dtype="int8"` stores the payload block-scaled int8 and adds a
-    parallel scale arena {"k_scale", "v_scale"}: [layers, n_pages, heads,
+    `quant_dtype="int8"` stores the payload block-scaled int8 and adds
+    parallel scale leaves {"k_scale", "v_scale"}: [n_pages, heads,
     page_tokens, head_dim // block] f32 (`quant_block` 0 = one block per
     row).  Presence of the scale keys is the quant signal every paged
     forward branches on — a {"k","v"}-only arena traces the exact
     pre-quant program."""
-    if n_pages < 1:
-        raise ValueError(f"n_pages must be >= 1, got {n_pages}")
-    if page_tokens < 1:
-        raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
-    hd = cfg.dim // cfg.heads
     dt = jnp.dtype(cfg.dtype if dtype in (None, "auto") else dtype)
-    shape = (cfg.layers, n_pages, cfg.heads, page_tokens, hd)
-    if quant_dtype in (None, "none"):
-        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    if quant_dtype != "int8":
-        raise ValueError(f"quant_dtype must be None/'none'/'int8', "
-                         f"got {quant_dtype!r}")
-    block = quant_block or hd
-    if hd % block:
-        raise ValueError(f"quant_block {block} must divide head_dim {hd}")
-    sshape = (cfg.layers, n_pages, cfg.heads, page_tokens, hd // block)
-    return {"k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(sshape, jnp.float32),
-            "v_scale": jnp.zeros(sshape, jnp.float32)}
-
-
-def _pages_write_row(pages_layer, new, write_page, offset):
-    """Write one new K or V row per sequence through the page table:
-    pages_layer [n_pages, h, pt, hd], new [b, h, hd], write_page int32 [b]
-    (the arena page holding each row's current window; sentinel n_pages
-    for dead rows), offset int32 [b] (position within the page).  The two
-    advanced indices put the batch dim in front of the update, and
-    mode="drop" discards sentinel writes — dead rows touch nothing."""
-    return pages_layer.at[write_page, :, offset, :].set(
-        new.astype(pages_layer.dtype), mode="drop")
-
-
-def _pages_write_chunk(pages_layer, new, write_page):
-    """Write one full page-sized chunk of K or V per sequence:
-    pages_layer [n_pages, h, pt, hd], new [b, h, pt, hd], write_page
-    int32 [b].  Chunked prefill is page-aligned by construction
-    (page_tokens == prefill chunk), so a chunk always fills exactly one
-    freshly-allocated page; sentinel rows drop."""
-    return pages_layer.at[write_page].set(
-        new.astype(pages_layer.dtype), mode="drop")
+    return init_page_arena(cfg.layers, n_pages, cfg.heads, page_tokens,
+                           cfg.dim // cfg.heads, dt, quant_dtype,
+                           quant_block)
 
 
 def gpt_prefill_chunk_paged(params, cfg: GPTConfig, pages, table, tokens,
@@ -520,8 +487,8 @@ def gpt_prefill_chunk_paged(params, cfg: GPTConfig, pages, table, tokens,
     dtype = jnp.dtype(cfg.dtype)
     heads = cfg.heads
     b, c_len = tokens.shape
-    pt = pages["k"].shape[3]
-    quant_nb = pages["k_scale"].shape[-1] if "k_scale" in pages else 0
+    pt = pages["k"][0].shape[2]
+    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
     if c_len != pt:
         raise ValueError(f"paged prefill chunk {c_len} != page_tokens {pt} "
                          f"(chunks must fill exactly one page)")
@@ -550,12 +517,12 @@ def gpt_prefill_chunk_paged(params, cfg: GPTConfig, pages, table, tokens,
             # scale page rides the same write/gather indices
             k, sk = kv_quantize(k, quant_nb)
             v, sv = kv_quantize(v, quant_nb)
-            psk = _pages_write_chunk(pages["k_scale"][li], sk, wp)
-            psv = _pages_write_chunk(pages["v_scale"][li], sv, wp)
+            psk = write_chunk(pages["k_scale"][li], sk, wp)
+            psv = write_chunk(pages["v_scale"][li], sv, wp)
             new_ks.append(psk)
             new_vs.append(psv)
-        pk = _pages_write_chunk(pages["k"][li], k, wp)
-        pv = _pages_write_chunk(pages["v"][li], v, wp)
+        pk = write_chunk(pages["k"][li], k, wp)
+        pv = write_chunk(pages["v"][li], v, wp)
         new_k.append(pk)
         new_v.append(pv)
         # gather AFTER the write so the chunk attends its own fresh page
@@ -577,25 +544,14 @@ def gpt_prefill_chunk_paged(params, cfg: GPTConfig, pages, table, tokens,
                         + blk["mlp"]["fc"]["b"].astype(dtype))
         x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
                  + blk["mlp"]["proj"]["b"].astype(dtype))
-    pages = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    pages = {"k": tuple(new_k), "v": tuple(new_v)}
     if quant_nb:
-        pages["k_scale"] = jnp.stack(new_ks)
-        pages["v_scale"] = jnp.stack(new_vs)
+        pages["k_scale"] = tuple(new_ks)
+        pages["v_scale"] = tuple(new_vs)
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
     rel_last = jnp.clip(lengths.astype(jnp.int32) - 1 - start, 0, c_len - 1)
     last = jnp.take_along_axis(x, rel_last[:, None, None], axis=1)[:, 0]
     return pages, last.astype(jnp.float32) @ params["wte"].T
-
-
-def _pages_write_rows(pages_layer, new, write_page, offset):
-    """Write `s` consecutive K or V rows per sequence through the page
-    table: pages_layer [n_pages, h, pt, hd], new [b, h, s, hd],
-    write_page/offset int32 [b, s] (per position — a run of s positions
-    may straddle a page boundary, so each resolves its own page).  The
-    advanced indices broadcast to [b, s] in front of the update, and
-    mode="drop" discards sentinel pages — dead rows touch nothing."""
-    return pages_layer.at[write_page, :, offset, :].set(
-        new.transpose(0, 2, 1, 3).astype(pages_layer.dtype), mode="drop")
 
 
 def gpt_verify_step_paged(params, cfg: GPTConfig, pages, table, tokens,
@@ -616,8 +572,8 @@ def gpt_verify_step_paged(params, cfg: GPTConfig, pages, table, tokens,
     dtype = jnp.dtype(cfg.dtype)
     heads = cfg.heads
     b, s = tokens.shape
-    pt = pages["k"].shape[3]
-    quant_nb = pages["k_scale"].shape[-1] if "k_scale" in pages else 0
+    pt = pages["k"][0].shape[2]
+    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
     hd = cfg.dim // heads
     start = pos.astype(jnp.int32)
     tbl = table.astype(jnp.int32)
@@ -642,12 +598,12 @@ def gpt_verify_step_paged(params, cfg: GPTConfig, pages, table, tokens,
         if quant_nb:
             k, sk = kv_quantize(k, quant_nb)
             v, sv = kv_quantize(v, quant_nb)
-            psk = _pages_write_rows(pages["k_scale"][li], sk, wp, off)
-            psv = _pages_write_rows(pages["v_scale"][li], sv, wp, off)
+            psk = write_rows(pages["k_scale"][li], sk, wp, off)
+            psv = write_rows(pages["v_scale"][li], sv, wp, off)
             new_ks.append(psk)
             new_vs.append(psv)
-        pk = _pages_write_rows(pages["k"][li], k, wp, off)
-        pv = _pages_write_rows(pages["v"][li], v, wp, off)
+        pk = write_rows(pages["k"][li], k, wp, off)
+        pv = write_rows(pages["v"][li], v, wp, off)
         new_k.append(pk)
         new_v.append(pv)
         if quant_nb:
@@ -668,10 +624,10 @@ def gpt_verify_step_paged(params, cfg: GPTConfig, pages, table, tokens,
                         + blk["mlp"]["fc"]["b"].astype(dtype))
         x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
                  + blk["mlp"]["proj"]["b"].astype(dtype))
-    pages = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    pages = {"k": tuple(new_k), "v": tuple(new_v)}
     if quant_nb:
-        pages["k_scale"] = jnp.stack(new_ks)
-        pages["v_scale"] = jnp.stack(new_vs)
+        pages["k_scale"] = tuple(new_ks)
+        pages["v_scale"] = tuple(new_vs)
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
     return pages, x.astype(jnp.float32) @ params["wte"].T
 
@@ -689,8 +645,8 @@ def gpt_decode_step_paged(params, cfg: GPTConfig, pages, table, token, pos):
     dtype = jnp.dtype(cfg.dtype)
     heads = cfg.heads
     b = token.shape[0]
-    pt = pages["k"].shape[3]
-    quant_nb = pages["k_scale"].shape[-1] if "k_scale" in pages else 0
+    pt = pages["k"][0].shape[2]
+    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
     hd = cfg.dim // heads
     pos = pos.astype(jnp.int32)
     tbl = table.astype(jnp.int32)
@@ -712,12 +668,12 @@ def gpt_decode_step_paged(params, cfg: GPTConfig, pages, table, token, pos):
         if quant_nb:
             k, sk = kv_quantize(k, quant_nb)
             v, sv = kv_quantize(v, quant_nb)
-            psk = _pages_write_row(pages["k_scale"][li], sk, wp, off)
-            psv = _pages_write_row(pages["v_scale"][li], sv, wp, off)
+            psk = write_row(pages["k_scale"][li], sk, wp, off)
+            psv = write_row(pages["v_scale"][li], sv, wp, off)
             new_ks.append(psk)
             new_vs.append(psv)
-        pk = _pages_write_row(pages["k"][li], k, wp, off)
-        pv = _pages_write_row(pages["v"][li], v, wp, off)
+        pk = write_row(pages["k"][li], k, wp, off)
+        pv = write_row(pages["v"][li], v, wp, off)
         new_k.append(pk)
         new_v.append(pv)
         if quant_nb:
@@ -736,10 +692,10 @@ def gpt_decode_step_paged(params, cfg: GPTConfig, pages, table, token, pos):
                         + blk["mlp"]["fc"]["b"].astype(dtype))
         x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
                  + blk["mlp"]["proj"]["b"].astype(dtype))
-    pages = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    pages = {"k": tuple(new_k), "v": tuple(new_v)}
     if quant_nb:
-        pages["k_scale"] = jnp.stack(new_ks)
-        pages["v_scale"] = jnp.stack(new_vs)
+        pages["k_scale"] = tuple(new_ks)
+        pages["v_scale"] = tuple(new_vs)
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
     return pages, x.astype(jnp.float32) @ params["wte"].T
 

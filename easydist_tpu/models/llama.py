@@ -11,6 +11,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from easydist_tpu.kv.arena import (init_page_arena, write_chunk, write_row,
+                                   write_rows)
 from .optim import adam_init, adam_update
 
 
@@ -385,54 +387,25 @@ def llama_decode_step(params, cfg: LlamaConfig, cache, token, pos):
 # ------------------------------------------------------- paged KV decode
 #
 # Page-table variants (the llama mirror of gpt.py's): the arena stores
-# ROPED keys at kv_heads granularity — [layers, n_pages, kv_heads,
-# page_tokens, head_dim] — so page HBM scales with kv_heads and the GQA
-# repeat happens at attention time, matching the bucketed path's
+# ROPED keys at kv_heads granularity — one leaf per layer, [n_pages,
+# kv_heads, page_tokens, head_dim] — so page HBM scales with kv_heads and
+# the GQA repeat happens at attention time, matching the bucketed path's
 # repeat-then-attend order bitwise.
 
 
 def init_kv_pages(cfg: LlamaConfig, n_pages: int, page_tokens: int,
                   dtype=None, quant_dtype=None, quant_block: int = 0):
-    """Zeroed page arena {"k", "v"}: [layers, n_pages, kv_heads,
-    page_tokens, head_dim].  `quant_dtype="int8"` stores the payload
-    block-scaled int8 plus a parallel {"k_scale", "v_scale"} f32 scale
-    arena ([..., head_dim // block] — `quant_block` 0 = one block per
-    row); presence of the scale keys is the quant signal the paged
-    forwards branch on."""
-    if n_pages < 1:
-        raise ValueError(f"n_pages must be >= 1, got {n_pages}")
-    if page_tokens < 1:
-        raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
-    hd = cfg.dim // cfg.heads
+    """Zeroed page arena (`kv/arena.py`): {"k", "v"}, each a tuple of one
+    leaf per layer, [n_pages, kv_heads, page_tokens, head_dim] — a buffer
+    of its own, donated and written in place leaf by leaf.
+    `quant_dtype="int8"` stores the payload block-scaled int8 plus
+    parallel {"k_scale", "v_scale"} f32 scale leaves ([..., head_dim //
+    block] — `quant_block` 0 = one block per row); presence of the scale
+    keys is the quant signal the paged forwards branch on."""
     dt = jnp.dtype(cfg.dtype if dtype in (None, "auto") else dtype)
-    shape = (cfg.layers, n_pages, cfg.kv_heads, page_tokens, hd)
-    if quant_dtype in (None, "none"):
-        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    if quant_dtype != "int8":
-        raise ValueError(f"quant_dtype must be None/'none'/'int8', "
-                         f"got {quant_dtype!r}")
-    block = quant_block or hd
-    if hd % block:
-        raise ValueError(f"quant_block {block} must divide head_dim {hd}")
-    sshape = (cfg.layers, n_pages, cfg.kv_heads, page_tokens, hd // block)
-    return {"k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(sshape, jnp.float32),
-            "v_scale": jnp.zeros(sshape, jnp.float32)}
-
-
-def _pages_write_row(pages_layer, new, write_page, offset):
-    """pages_layer [n_pages, n, pt, hd], new [b, n, hd], write_page/offset
-    int32 [b]; sentinel write_page entries drop (dead rows)."""
-    return pages_layer.at[write_page, :, offset, :].set(
-        new.astype(pages_layer.dtype), mode="drop")
-
-
-def _pages_write_chunk(pages_layer, new, write_page):
-    """pages_layer [n_pages, n, pt, hd], new [b, n, pt, hd], write_page
-    int32 [b] — one full page per chunk; sentinel rows drop."""
-    return pages_layer.at[write_page].set(
-        new.astype(pages_layer.dtype), mode="drop")
+    return init_page_arena(cfg.layers, n_pages, cfg.kv_heads, page_tokens,
+                           cfg.dim // cfg.heads, dt, quant_dtype,
+                           quant_block)
 
 
 def llama_prefill_chunk_paged(params, cfg: LlamaConfig, pages, table,
@@ -447,8 +420,8 @@ def llama_prefill_chunk_paged(params, cfg: LlamaConfig, pages, table,
 
     dtype = jnp.dtype(cfg.dtype)
     b, c_len = tokens.shape
-    pt = pages["k"].shape[3]
-    quant_nb = pages["k_scale"].shape[-1] if "k_scale" in pages else 0
+    pt = pages["k"][0].shape[2]
+    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
     if c_len != pt:
         raise ValueError(f"paged prefill chunk {c_len} != page_tokens {pt} "
                          f"(chunks must fill exactly one page)")
@@ -479,12 +452,12 @@ def llama_prefill_chunk_paged(params, cfg: LlamaConfig, pages, table,
             # gather on BOTH payload and scales, so dequant commutes
             k, sk = kv_quantize(k, quant_nb)
             v, sv = kv_quantize(v, quant_nb)
-            psk = _pages_write_chunk(pages["k_scale"][li], sk, wp)
-            psv = _pages_write_chunk(pages["v_scale"][li], sv, wp)
+            psk = write_chunk(pages["k_scale"][li], sk, wp)
+            psv = write_chunk(pages["v_scale"][li], sv, wp)
             new_ks.append(psk)
             new_vs.append(psv)
-        pk = _pages_write_chunk(pages["k"][li], k, wp)
-        pv = _pages_write_chunk(pages["v"][li], v, wp)
+        pk = write_chunk(pages["k"][li], k, wp)
+        pv = write_chunk(pages["v"][li], v, wp)
         new_k.append(pk)
         new_v.append(pv)
         if quant_nb:
@@ -504,22 +477,14 @@ def llama_prefill_chunk_paged(params, cfg: LlamaConfig, pages, table,
         gated = jax.nn.silu(hx @ blk["w_gate"].astype(dtype)) \
             * (hx @ blk["w_up"].astype(dtype))
         x = x + gated @ blk["w_down"].astype(dtype)
-    pages = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    pages = {"k": tuple(new_k), "v": tuple(new_v)}
     if quant_nb:
-        pages["k_scale"] = jnp.stack(new_ks)
-        pages["v_scale"] = jnp.stack(new_vs)
+        pages["k_scale"] = tuple(new_ks)
+        pages["v_scale"] = tuple(new_vs)
     x = _rmsnorm(x, params["norm_f"])
     rel_last = jnp.clip(lengths.astype(jnp.int32) - 1 - start, 0, c_len - 1)
     last = jnp.take_along_axis(x, rel_last[:, None, None], axis=1)[:, 0]
     return pages, last.astype(jnp.float32) @ params["wte"].T
-
-
-def _pages_write_rows(pages_layer, new, write_page, offset):
-    """pages_layer [n_pages, n, pt, hd], new [b, n, s, hd], write_page/
-    offset int32 [b, s] — per-position page writes (a verify window may
-    straddle a page boundary); sentinel pages drop (dead rows)."""
-    return pages_layer.at[write_page, :, offset, :].set(
-        new.transpose(0, 2, 1, 3).astype(pages_layer.dtype), mode="drop")
 
 
 def llama_verify_step_paged(params, cfg: LlamaConfig, pages, table, tokens,
@@ -535,8 +500,8 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, pages, table, tokens,
 
     dtype = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
-    pt = pages["k"].shape[3]
-    quant_nb = pages["k_scale"].shape[-1] if "k_scale" in pages else 0
+    pt = pages["k"][0].shape[2]
+    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
     hd = cfg.dim // cfg.heads
     start = pos.astype(jnp.int32)
     tbl = table.astype(jnp.int32)
@@ -562,12 +527,12 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, pages, table, tokens,
         if quant_nb:
             k, sk = kv_quantize(k, quant_nb)
             v, sv = kv_quantize(v, quant_nb)
-            psk = _pages_write_rows(pages["k_scale"][li], sk, wp, off)
-            psv = _pages_write_rows(pages["v_scale"][li], sv, wp, off)
+            psk = write_rows(pages["k_scale"][li], sk, wp, off)
+            psv = write_rows(pages["v_scale"][li], sv, wp, off)
             new_ks.append(psk)
             new_vs.append(psv)
-        pk = _pages_write_rows(pages["k"][li], k, wp, off)
-        pv = _pages_write_rows(pages["v"][li], v, wp, off)
+        pk = write_rows(pages["k"][li], k, wp, off)
+        pv = write_rows(pages["v"][li], v, wp, off)
         new_k.append(pk)
         new_v.append(pv)
         if quant_nb:
@@ -587,10 +552,10 @@ def llama_verify_step_paged(params, cfg: LlamaConfig, pages, table, tokens,
         gated = jax.nn.silu(hx @ blk["w_gate"].astype(dtype)) \
             * (hx @ blk["w_up"].astype(dtype))
         x = x + gated @ blk["w_down"].astype(dtype)
-    pages = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    pages = {"k": tuple(new_k), "v": tuple(new_v)}
     if quant_nb:
-        pages["k_scale"] = jnp.stack(new_ks)
-        pages["v_scale"] = jnp.stack(new_vs)
+        pages["k_scale"] = tuple(new_ks)
+        pages["v_scale"] = tuple(new_vs)
     x = _rmsnorm(x, params["norm_f"])
     return pages, x.astype(jnp.float32) @ params["wte"].T
 
@@ -607,8 +572,8 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, pages, table, token,
 
     dtype = jnp.dtype(cfg.dtype)
     b = token.shape[0]
-    pt = pages["k"].shape[3]
-    quant_nb = pages["k_scale"].shape[-1] if "k_scale" in pages else 0
+    pt = pages["k"][0].shape[2]
+    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
     hd = cfg.dim // cfg.heads
     pos = pos.astype(jnp.int32)
     tbl = table.astype(jnp.int32)
@@ -627,12 +592,12 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, pages, table, token,
         if quant_nb:
             k, sk = kv_quantize(k, quant_nb)
             v, sv = kv_quantize(v, quant_nb)
-            psk = _pages_write_row(pages["k_scale"][li], sk, wp, off)
-            psv = _pages_write_row(pages["v_scale"][li], sv, wp, off)
+            psk = write_row(pages["k_scale"][li], sk, wp, off)
+            psv = write_row(pages["v_scale"][li], sv, wp, off)
             new_ks.append(psk)
             new_vs.append(psv)
-        pk = _pages_write_row(pages["k"][li], k, wp, off)
-        pv = _pages_write_row(pages["v"][li], v, wp, off)
+        pk = write_row(pages["k"][li], k, wp, off)
+        pv = write_row(pages["v"][li], v, wp, off)
         new_k.append(pk)
         new_v.append(pv)
         if quant_nb:
@@ -646,10 +611,10 @@ def llama_decode_step_paged(params, cfg: LlamaConfig, pages, table, token,
         gated = jax.nn.silu(hx @ blk["w_gate"].astype(dtype)) \
             * (hx @ blk["w_up"].astype(dtype))
         x = x + gated @ blk["w_down"].astype(dtype)
-    pages = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    pages = {"k": tuple(new_k), "v": tuple(new_v)}
     if quant_nb:
-        pages["k_scale"] = jnp.stack(new_ks)
-        pages["v_scale"] = jnp.stack(new_vs)
+        pages["k_scale"] = tuple(new_ks)
+        pages["v_scale"] = tuple(new_vs)
     x = _rmsnorm(x, params["norm_f"])
     return pages, x.astype(jnp.float32) @ params["wte"].T
 

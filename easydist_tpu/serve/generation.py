@@ -27,7 +27,9 @@ the serving half of the cache-carrying model API
     always steps ALL slots, slots recycle through a free list;
   * **paged KV pool** (`ServeConfig.kv_layout="paged"`) — ALL buckets
     collapse into ONE page-granular pool over a preallocated arena
-    (kv/pool.py + kv/table.py): sequences of any length share one
+    (kv/pool.py + kv/table.py on the host; kv/arena.py on the device: a
+    pytree of one leaf per layer, each written in place in its own
+    donated buffer): sequences of any length share one
     compiled decode step (the int32 page table, fixed
     [max_slots, max_pages], is the only per-step state that varies), a
     restored prefix is table entries pointing at trie-committed pages
@@ -37,9 +39,10 @@ the serving half of the cache-carrying model API
     a sequence can ever touch up front, so the table row is static for
     the slot's life; analyze rule KV001 audits the refcount/table
     bookkeeping at first decode and every retire;
-  * **donated caches** — pool and staging are positional arg 0 and
-    output 0 of their compiled callables, so `infer_state_io` pairs and
-    donates them; XLA updates in place instead of copying.  `analyze`
+  * **donated caches** — pool, staging and the paged arena (leaf by
+    leaf) are positional arg 0 and output 0 of their compiled callables,
+    so `infer_state_io` pairs and donates them; XLA updates in place
+    instead of copying.  `analyze`
     rules SERVE001 (decode) and SERVE002 (chunked prefill: donation +
     length-masked attention + trie accounting) audit exactly this.
 
@@ -215,16 +218,19 @@ class _PagedPool:
                 f"kv_arena_pages {n_pages} cannot hold even one "
                 f"full-length sequence ({self.max_pages} pages)")
         self.n_rows = n_rows
+        # {"k": (one leaf per layer), "v": (...)[, "k_scale", "v_scale"]}
+        # — kv/arena.py; every leaf is donated to each compiled step
         self.arena = init_pages(n_pages, chunk)
         # size pages from the arena's STORAGE leaves — quantized arenas
         # charge int8 payload + f32 scales, not the model dtype, which is
         # exactly the density win the kv_quant_bytes_saved gauge reports
-        self.page_bytes = sum(int(self.arena[k].nbytes) // n_pages
-                              for k in self.arena)
+        self.page_bytes = sum(int(leaf.nbytes) // n_pages
+                              for leaves in self.arena.values()
+                              for leaf in leaves)
         # what one page's k/v payload would cost at model precision —
         # the baseline the quant-savings gauge subtracts from
-        payload_elems = sum(int(self.arena[k].size) // n_pages
-                            for k in ("k", "v"))
+        payload_elems = sum(int(leaf.size) // n_pages
+                            for k in ("k", "v") for leaf in self.arena[k])
         self.model_page_bytes = payload_elems * model_itemsize \
             if model_itemsize else self.page_bytes
         self.pool = PagePool(n_pages, chunk, page_bytes=self.page_bytes)
@@ -528,22 +534,19 @@ class GenerationSession:
         # export/import iterate ALL arena keys: a quantized arena ships
         # its scale leaves alongside the int8 payload, so fleet manifests
         # (and host-tier manifests) cover both — a scale/payload desync
-        # cannot pass a digest check.  Exact arenas have keys {"k","v"},
-        # so the quant-off jaxpr is unchanged.
+        # cannot pass a digest check.  Exact arenas have keys {"k","v"}.
+        # The wire format is one page stacked over layers, {key: [layers,
+        # heads, page_tokens, *]}, whatever the arena's layout on the
+        # device (kv/arena.py).
         def _page_export(arena, page):
-            import jax
+            from easydist_tpu.kv.arena import export_page
 
-            return {k: jax.lax.dynamic_index_in_dim(
-                        arena[k], page, axis=1, keepdims=False)
-                    for k in arena}
+            return export_page(arena, page)
 
         def _page_import(arena, chunk_kv, page):
-            import jax
+            from easydist_tpu.kv.arena import import_page
 
-            return {k: jax.lax.dynamic_update_index_in_dim(
-                        arena[k], chunk_kv[k].astype(arena[k].dtype),
-                        page, axis=1)
-                    for k in arena}
+            return import_page(arena, chunk_kv, page)
 
         def _verify_paged(arena, params, table, tokens, pos):
             import jax.numpy as jnp

@@ -1,0 +1,259 @@
+"""The paged arena is written in place: the static witness.
+
+A paged step (`*_decode_step_paged`, `*_prefill_chunk_paged`,
+`*_verify_step_paged`) takes the arena as a pytree of per-layer leaves
+(`kv/arena.py`) and must hand every leaf back through exactly one write
+whose operand is that input leaf — never a slice of a stacked array, never
+a `concatenate` back into one.  Two readings, neither of which needs a
+chip:
+
+  * the jaxpr: the only equations whose output is as large as a payload
+    leaf are those writes, and nothing copies, slices or concatenates a
+    leaf;
+  * the program XLA compiles with the arena donated (CPU backend): every
+    leaf is input/output-aliased and the temporaries stay under one leaf;
+  * the decode step compiled for a described TPU v5e at the chat cell's
+    widths (one layer): there a row write indexed `[page, :, offset, :]`
+    made XLA re-lay the whole leaf out before and after the scatter, which
+    no CPU compile shows.
+
+This is the guard that keeps a later model file from stacking again
+(PR 28: the stacked arena cost 57 % of a decode round on the chip).
+
+Also pinned here: the page wire format.  The session's `_page_export` gives
+bitwise the stack of one page across the leaves, `{key: [layers, heads,
+page_tokens, *]}`, and export -> import -> export is the identity, so fleet
+manifests, host-tier entries and trie restores do not depend on the
+arena's layout.
+"""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.extend import core as jex_core
+from jax.sharding import SingleDeviceSharding
+
+from easydist_tpu.models import gpt, llama
+from easydist_tpu.serve import GenerationSession, ServeConfig
+
+N_PAGES, PT, ROWS, MAX_PAGES, VERIFY_S = 256, 8, 2, 4, 3
+
+MODELS = {
+    "gpt": (gpt, gpt.GPTConfig.tiny, gpt.gpt_init, {
+        "decode": gpt.gpt_decode_step_paged,
+        "chunk": gpt.gpt_prefill_chunk_paged,
+        "verify": gpt.gpt_verify_step_paged}),
+    "llama": (llama, llama.LlamaConfig.tiny, llama.llama_init, {
+        "decode": llama.llama_decode_step_paged,
+        "chunk": llama.llama_prefill_chunk_paged,
+        "verify": llama.llama_verify_step_paged}),
+}
+
+# primitives that would move a whole leaf: the stacked arena's slice and
+# stack, and their relatives
+_MOVES_A_LEAF = {"concatenate", "slice", "dynamic_slice", "squeeze", "copy",
+                 "dynamic_update_slice", "convert_element_type", "transpose",
+                 "reshape", "broadcast_in_dim", "pad"}
+
+
+def _step(model, quant, program):
+    """(fn(arena, *data) -> (arena, picks), arena, data) for one paged
+    step on the tiny config, with the session's argmax on top."""
+    mod, tiny, init, steps = MODELS[model]
+    cfg = tiny()
+    params = init(cfg, jax.random.PRNGKey(0))
+    arena = mod.init_kv_pages(cfg, N_PAGES, PT,
+                              quant_dtype="int8" if quant else None)
+    # row 0 owns pages 0..3, row 1 is dead (sentinel): its writes drop
+    table = np.full((ROWS, MAX_PAGES), N_PAGES, np.int32)
+    table[0] = np.arange(MAX_PAGES)
+    table = jnp.asarray(table)
+    if program == "decode":
+        data = (table, jnp.asarray([5, 0]), jnp.asarray([9, 0]))
+    elif program == "chunk":
+        data = (table, jnp.ones((ROWS, PT), jnp.int32),
+                jnp.asarray([PT, 0]), jnp.asarray([2 * PT, 0]))
+    else:  # a verify window that straddles a page boundary
+        data = (table, jnp.ones((ROWS, VERIFY_S), jnp.int32),
+                jnp.asarray([PT - 1, 0]))
+
+    def fn(arena, *data):
+        arena, logits = steps[program](params, cfg, arena, *data)
+        return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    return fn, arena, data
+
+
+def _nbytes(aval) -> int:
+    return int(np.prod(aval.shape)) * np.dtype(aval.dtype).itemsize
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "verify"])
+@pytest.mark.parametrize("quant", [False, True], ids=["exact", "int8"])
+@pytest.mark.parametrize("model", ["llama", "gpt"])
+def test_paged_step_writes_every_leaf_in_place(model, quant, program):
+    fn, arena, data = _step(model, quant, program)
+    leaves = jax.tree_util.tree_leaves(arena)
+    n = len(leaves)
+    leaf_bytes = min(int(x.nbytes) for x in arena["k"])
+
+    # ---- the jaxpr
+    jaxpr = jax.make_jaxpr(fn)(arena, *data).jaxpr
+    arena_in = {v: i for i, v in enumerate(jaxpr.invars[:n])}
+    arena_out = {v: i for i, v in enumerate(jaxpr.outvars[:n])}
+    assert len(arena_out) == n, "two arena outputs are one value"
+    written = {}            # written leaf var -> flat leaf index
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        touched = [v for v in eqn.invars
+                   if not isinstance(v, jex_core.Literal)
+                   and (v in arena_in or v in written)]
+        if name == "scatter" and eqn.invars[0] in arena_in:
+            idx = arena_in[eqn.invars[0]]
+            out = eqn.outvars[0]
+            assert idx not in written.values(), \
+                f"leaf {idx} is written twice"
+            assert arena_out.get(out) == idx, \
+                f"the write of leaf {idx} is not returned as leaf {idx}"
+            written[out] = idx
+            continue
+        assert not (touched and name in _MOVES_A_LEAF), \
+            f"`{name}` moves an arena leaf: {eqn}"
+        assert not any(v in arena_in for v in touched), \
+            f"`{name}` reads an input leaf before its write: {eqn}"
+        for out in eqn.outvars:
+            assert _nbytes(out.aval) < leaf_bytes, \
+                (f"`{name}` produces {_nbytes(out.aval)} bytes "
+                 f"{out.aval.shape}, as large as a leaf ({leaf_bytes})")
+    assert sorted(written.values()) == list(range(n)), \
+        "a leaf comes back without passing through its write"
+
+    # ---- the compiled program, arena donated
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(arena, *data).compile()
+    header = compiled.as_text().split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         header)
+    assert sorted((int(o), int(i)) for o, i in aliases) == \
+        [(i, i) for i in range(n)], header
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """A described (not attached) v5e chip to compile for; described inside
+    the fixture so that only the worker given this file loads libtpu."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else it logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler here: nothing to compile for
+            pytest.skip(f"no v5e topology can be described here: {e}")
+        # an executable for a described chip cannot be read back from the
+        # persistent cache: keep it out
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+
+
+def test_decode_step_writes_in_place_on_tpu(v5e_chip, monkeypatch):
+    """The chat cell's decode step (BENCHMARK.json: Mistral-7B widths, 32
+    slots, 576 pages of 64 tokens), one layer, with the Pallas kernel."""
+    from easydist_tpu import config as edconfig
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    cfg = llama.LlamaConfig(vocab=32768, seq=2048, dim=4096, heads=32,
+                            kv_heads=8, layers=1, ffn_dim=14336,
+                            rope_theta=1e6, dtype="bfloat16")
+    slots, max_pages = 32, 32
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                                 llama.llama_init(cfg, key)),
+        jax.random.PRNGKey(0)))
+    arena = on_chip(jax.eval_shape(
+        lambda: llama.init_kv_pages(cfg, 576, 64)))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    table = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
+                                 sharding=v5e_chip)
+
+    def step(arena, params, table, token, pos):
+        return llama.llama_decode_step_paged(params, cfg, arena, table,
+                                             token, pos)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        arena, params, table, rows, rows).compile()
+    leaf_bytes = 576 * 8 * 64 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes, \
+        "a leaf is copied (re-laid out?) round its write"
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["exact", "int8"])
+@pytest.mark.parametrize("model", ["llama", "gpt"])
+def test_page_wire_format_is_the_stack_of_leaf_pages(model, quant):
+    """Through the session's own compiled `_page_export` / `_page_import`,
+    on an arena a served prompt has written."""
+    _, tiny, init, _ = MODELS[model]
+    cfg = tiny()
+    factory = (GenerationSession.for_gpt if model == "gpt"
+               else GenerationSession.for_llama)
+    sess = factory(init(cfg, jax.random.PRNGKey(0)), cfg, config=ServeConfig(
+        kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+        prefill_chunk=PT, prefill_batch=2,
+        kv_quant_dtype="int8" if quant else "none"))
+    fut = sess.submit(list(range(1, 2 * PT + 4)), max_new_tokens=3)
+    sess.run_until_drained()
+    assert len(fut.result(timeout=5)["ids"]) == 3
+    pool = next(iter(sess._pools.values()))
+    before = jax.tree.map(np.asarray, pool.arena)
+    assert sorted(before) == (["k", "k_scale", "v", "v_scale"] if quant
+                              else ["k", "v"])
+    used = [p for p in range(pool.pool.n_pages) if before["k"][0][p].any()]
+    empty = [p for p in range(pool.pool.n_pages)
+             if not any(leaf[p].any() for leaves in before.values()
+                        for leaf in leaves)]
+    src, dst = used[0], empty[0]
+
+    page = sess._paged_c("export")(pool.arena, jnp.asarray(src, jnp.int32))
+    assert sorted(page) == sorted(before)
+    for key, leaves in before.items():
+        assert page[key].shape == (cfg.layers,) + leaves[0].shape[1:]
+        assert page[key].dtype == leaves[0].dtype
+        np.testing.assert_array_equal(
+            np.asarray(page[key]), np.stack([leaf[src] for leaf in leaves]))
+
+    # export -> import (at another page) -> export is the identity
+    pool.arena = sess._paged_c("import")(pool.arena, page,
+                                         jnp.asarray(dst, jnp.int32))
+    again = sess._paged_c("export")(pool.arena, jnp.asarray(dst, jnp.int32))
+    for key in page:
+        np.testing.assert_array_equal(np.asarray(again[key]),
+                                      np.asarray(page[key]))
+    # and the import wrote that page alone
+    for key, leaves in pool.arena.items():
+        for li, leaf in enumerate(leaves):
+            np.testing.assert_array_equal(
+                np.delete(np.asarray(leaf), dst, axis=0),
+                np.delete(before[key][li], dst, axis=0))
+    sess.close()

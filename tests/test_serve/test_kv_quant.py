@@ -172,7 +172,9 @@ class TestQuantSession:
         assert got == want
         pool = next(iter(sess._pools.values()))
         assert sorted(pool.arena) == ["k", "v"]
-        assert pool.arena["k"].dtype == jnp.dtype(cfg.dtype)
+        assert len(pool.arena["k"]) == cfg.layers
+        assert all(leaf.dtype == jnp.dtype(cfg.dtype)
+                   for leaf in pool.arena["k"])
 
     def test_int8_session_arena_and_accounting(self, model):
         cfg, params = model
@@ -181,8 +183,9 @@ class TestQuantSession:
         pool = next(iter(sess._pools.values()))
         epool = next(iter(exact._pools.values()))
         assert sorted(pool.arena) == ["k", "k_scale", "v", "v_scale"]
-        assert pool.arena["k"].dtype == jnp.int8
-        assert pool.arena["k_scale"].dtype == jnp.float32
+        assert all(leaf.dtype == jnp.int8 for leaf in pool.arena["k"])
+        assert all(leaf.dtype == jnp.float32
+                   for leaf in pool.arena["k_scale"])
         assert audit_quant_arena(pool.arena) == []
         # satellite: bytes/seq accounting follows the STORAGE dtype
         assert pool.page_bytes < epool.page_bytes
@@ -254,8 +257,9 @@ class TestCacheDtypeParity:
         got, sess = _run(params, cfg, PROMPTS, n_new=6, layout=layout,
                          kv_cache_dtype="bfloat16")
         pool = next(iter(sess._pools.values()))
-        store = pool.arena if layout == "paged" else pool.cache
-        assert store["k"].dtype == jnp.bfloat16
+        k_leaves = pool.arena["k"] if layout == "paged" \
+            else (pool.cache["k"],)
+        assert all(leaf.dtype == jnp.bfloat16 for leaf in k_leaves)
         flat_w = [t for ids in want for t in ids]
         flat_g = [t for ids in got for t in ids]
         match = sum(a == b for a, b in zip(flat_w, flat_g)) / len(flat_w)
@@ -289,12 +293,17 @@ class TestConfigValidation:
 
 
 # ------------------------------------------------------ layer 13 goldens
+def _leaves(shape, dtype, fill=0, layers=2):
+    """One key of an arena: a tuple of `layers` per-layer leaves."""
+    return tuple(np.full(shape, fill, dtype) for _ in range(layers))
+
+
 def _quant_arena(nb=1, **override):
-    shape = (2, 4, 2, 8, 8)
-    arena = {"k": np.zeros(shape, np.int8),
-             "v": np.zeros(shape, np.int8),
-             "k_scale": np.ones(shape[:-1] + (nb,), np.float32),
-             "v_scale": np.ones(shape[:-1] + (nb,), np.float32)}
+    shape = (4, 2, 8, 8)
+    arena = {"k": _leaves(shape, np.int8),
+             "v": _leaves(shape, np.int8),
+             "k_scale": _leaves(shape[:-1] + (nb,), np.float32, 1),
+             "v_scale": _leaves(shape[:-1] + (nb,), np.float32, 1)}
     arena.update(override)
     return {k: v for k, v in arena.items() if v is not None}
 
@@ -305,19 +314,21 @@ class TestKVQ001:
         assert audit_quant_arena(_quant_arena(nb=4)) == []
 
     def test_clean_exact_arena(self):
-        arena = {"k": np.zeros((2, 4, 2, 8, 8), np.float32),
-                 "v": np.zeros((2, 4, 2, 8, 8), np.float32)}
+        arena = {"k": _leaves((4, 2, 8, 8), np.float32),
+                 "v": _leaves((4, 2, 8, 8), np.float32)}
         assert audit_quant_arena(arena) == []
 
     @pytest.mark.parametrize("override, needle", [
         (dict(v_scale=None), "no v_scale"),
         (dict(k=None), "no 'k' payload"),
-        (dict(k=np.zeros((2, 4, 2, 8, 8), np.float32)), "scale-free"),
-        (dict(k_scale=np.ones((2, 4, 2, 8, 1), np.float16)), "float32"),
-        (dict(k_scale=np.ones((2, 4, 2, 8, 3), np.float32)),
+        (dict(k=_leaves((4, 2, 8, 8), np.float32)), "scale-free"),
+        (dict(k_scale=_leaves((4, 2, 8, 1), np.float16, 1)), "float32"),
+        (dict(k_scale=_leaves((4, 2, 8, 3), np.float32, 1)),
          "block-partition"),
-        (dict(k_scale=np.ones((2, 4, 2, 4, 1), np.float32)),
+        (dict(k_scale=_leaves((4, 2, 4, 1), np.float32, 1)),
          "block-partition"),
+        (dict(k_scale=_leaves((4, 2, 8, 1), np.float32, 1, layers=1)),
+         "layer leaves"),
     ])
     def test_desync_fires(self, override, needle):
         findings = audit_quant_arena(_quant_arena(**override))
