@@ -11,6 +11,7 @@ from .gpt import (GPTConfig, gpt_init, gpt_apply,  # noqa: F401
                   make_gpt_train_step)
 from .gpt import (init_kv_cache as gpt_init_kv_cache,  # noqa: F401
                   gpt_prefill, gpt_prefill_chunk, gpt_decode_step)
+from .decoder import Decoder  # noqa: F401
 from .resnet import resnet_init, resnet_apply, make_resnet_train_step  # noqa: F401
 from .optim import adam_init, adam_update, sgd_update  # noqa: F401
 from .llama import (LlamaConfig, llama_init, llama_apply,  # noqa: F401

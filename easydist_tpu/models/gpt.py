@@ -15,8 +15,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from easydist_tpu.kv.arena import (init_page_arena, write_chunk, write_row,
-                                   write_rows)
+from .decoder import (Contiguous, Decoder, Paged, chunk, decode, split_heads,
+                      verify)
 from .optim import adam_init, adam_update
 
 
@@ -180,31 +180,14 @@ def gpt_apply(params, cfg: GPTConfig, tokens):
     return x.astype(jnp.float32) @ params["wte"].T
 
 
-# --------------------------------------------------------- KV-cache decode
+# ---------------------------------------------------------------- serving
 #
-# Autoregressive serving forward: `gpt_prefill` runs the prompt once and
-# fills a per-layer K/V cache; `gpt_decode_step` then attends ONE new token
-# against the cache — O(layers * len) per token instead of the O(len^2)
-# full re-forward.  Both are pure functions returning the updated cache, so
-# a jit of the step with the cache input donated updates it in place
-# (analyze rule SERVE001 audits exactly that).
-
-
-def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int, dtype=None):
-    """Zeroed KV cache {"k", "v"}: [layers, batch, heads, max_len,
-    head_dim].  Layer-stacked so the cache is two leaves regardless of
-    depth (donation and sharding specs stay O(1)); the heads axis (dim 2)
-    is the natural tensor-parallel shard dim, matching the solved qkv
-    column-parallel strategy.  `dtype=None`/"auto" stores at the compute
-    dtype; pass e.g. "bfloat16" to halve cache HBM."""
-    if max_len > cfg.seq:
-        raise ValueError(
-            f"max_len {max_len} exceeds the learned position table "
-            f"(cfg.seq={cfg.seq})")
-    hd = cfg.dim // cfg.heads
-    dt = jnp.dtype(cfg.dtype if dtype in (None, "auto") else dtype)
-    shape = (cfg.layers, batch, cfg.heads, max_len, hd)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+# The cache-carrying forwards `serve/generation.py` compiles.  The layer
+# loop, both KV layouts (the contiguous bucket cache and the page arena of
+# `kv/arena.py`, int8 included) and the three step kinds live in
+# `models/decoder.py`; this file supplies `decoder(cfg)`, the block's
+# arithmetic.  Every step is pure and returns the updated cache first, so a
+# jit with the cache donated updates it in place (analyze rule SERVE001).
 
 
 def _block_list(params, cfg):
@@ -217,14 +200,58 @@ def _block_list(params, cfg):
     return list(blocks)
 
 
-def _cache_write_row(cache_layer, new, pos):
-    """Write one new K or V row per sequence: cache_layer [b, h, T, hd],
-    new [b, h, hd], pos int32 [b] -> updated layer.  Per-row
-    dynamic_update_slice touches only each sequence's own position."""
-    return jax.vmap(
-        lambda c, n, p: jax.lax.dynamic_update_slice(
-            c, n[:, None, :].astype(c.dtype), (0, p, 0)))(
-        cache_layer, new, pos.astype(jnp.int32))
+def _mlp(blk, x, dtype):
+    h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
+    h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
+                    + blk["mlp"]["fc"]["b"].astype(dtype))
+    return x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
+                + blk["mlp"]["proj"]["b"].astype(dtype))
+
+
+def decoder(cfg: GPTConfig) -> Decoder:
+    """The model as `models/decoder.py` serves it: learned positions (so
+    no cache outgrows `cfg.seq`), LayerNorm, one fused QKV projection,
+    heads == kv_heads, GELU MLP, head tied to the embedding."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def embed(params, tokens, pos):
+        return params["wte"][tokens].astype(dtype) \
+            + params["wpe"][pos].astype(dtype)
+
+    def qkv(blk, x, pos):
+        p = blk["attn"]["qkv"]
+        h = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
+        q, k, v = jnp.split(h @ p["w"].astype(dtype) + p["b"].astype(dtype),
+                            3, axis=-1)
+        return (split_heads(q, cfg.heads), split_heads(k, cfg.heads),
+                split_heads(v, cfg.heads))
+
+    def attn_out(blk, x, att):
+        p = blk["attn"]["proj"]
+        return x + (att @ p["w"].astype(dtype) + p["b"].astype(dtype))
+
+    return Decoder(
+        layers=cfg.layers, heads=cfg.heads, kv_heads=cfg.heads,
+        head_dim=cfg.dim // cfg.heads, dtype=dtype, max_positions=cfg.seq,
+        blocks=lambda params: _block_list(params, cfg), embed=embed,
+        qkv=qkv, attn_out=attn_out, ffn=lambda blk, x: _mlp(blk, x, dtype),
+        final_norm=lambda params, x: _layernorm(
+            x, params["ln_f"]["g"], params["ln_f"]["b"]),
+        unembed=lambda params, x: x.astype(jnp.float32) @ params["wte"].T)
+
+
+def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int, dtype=None):
+    """Zeroed contiguous cache (`decoder.Contiguous.init`); `max_len` may
+    not exceed the learned position table, `cfg.seq`."""
+    return Contiguous.init(decoder(cfg), batch, max_len, dtype)
+
+
+def init_kv_pages(cfg: GPTConfig, n_pages: int, page_tokens: int,
+                  dtype=None, quant_dtype=None, quant_block: int = 0):
+    """Zeroed page arena (`decoder.Paged.init`); `quant_dtype="int8"`
+    stores block-scaled int8 with scale leaves beside the payload."""
+    return Paged.init(decoder(cfg), n_pages, page_tokens, dtype, quant_dtype,
+                      quant_block)
 
 
 def gpt_prefill(params, cfg: GPTConfig, cache, tokens, lengths):
@@ -248,11 +275,7 @@ def gpt_prefill(params, cfg: GPTConfig, cache, tokens, lengths):
         x = x + attn_out
         ks.append(k)
         vs.append(v)
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
+        x = _mlp(blk, x, dtype)
     cache = {
         "k": cache["k"].at[:, :, :, :t, :].set(
             jnp.stack(ks).astype(cache["k"].dtype)),
@@ -265,439 +288,39 @@ def gpt_prefill(params, cfg: GPTConfig, cache, tokens, lengths):
     return cache, last.astype(jnp.float32) @ params["wte"].T
 
 
-def _cache_write_chunk(cache_layer, new, start):
-    """Write a fixed-size chunk of K or V rows per sequence: cache_layer
-    [b, h, T, hd], new [b, h, c, hd], start int32 [b] -> updated layer.
-    Per-row dynamic_update_slice at a traced start keeps ONE compiled
-    signature across every chunk position."""
-    return jax.vmap(
-        lambda cl, n, s: jax.lax.dynamic_update_slice(
-            cl, n.astype(cl.dtype), (0, s, 0)))(
-        cache_layer, new, start.astype(jnp.int32))
-
-
 def gpt_prefill_chunk(params, cfg: GPTConfig, cache, tokens, start_pos,
                       lengths):
-    """One fixed-size prefill chunk: run `tokens` (int32 [batch, chunk])
-    at absolute positions `start_pos + [0..chunk)` (int32 [batch]), write
-    the chunk's K/V into `cache` at those positions, and return
-    (cache, logits [batch, vocab]) taken at each row's last real position
-    — valid for rows whose chunk contains `lengths - 1` (the finishing
-    chunk), garbage otherwise (the scheduler only reads finishing rows).
-
-    Unlike `gpt_prefill` this attends the FULL cache window [0, T) with a
-    `key_pos <= query_pos` mask, so the traced shape is independent of how
-    much prompt is already cached: one compiled signature per bucket
-    replaces the per-pow2-length set, and restored prefix chunks (written
-    by a previous request via the prefix trie) are consumed exactly as if
-    recomputed — softmax weights past a row's live positions underflow to
-    exact 0, the stale-row-leakage property analyze SERVE002 audits."""
-    from easydist_tpu.ops import chunk_attention
-
-    dtype = jnp.dtype(cfg.dtype)
-    heads = cfg.heads
-    b, c_len = tokens.shape
-    hd = cfg.dim // heads
-    start = start_pos.astype(jnp.int32)
-    # absolute positions of this chunk's queries, per row: [b, chunk]
-    abs_pos = start[:, None] + jnp.arange(c_len, dtype=jnp.int32)[None, :]
-    x = params["wte"][tokens].astype(dtype) \
-        + params["wpe"][abs_pos].astype(dtype)
-    new_k, new_v = [], []
-    for li, blk in enumerate(_block_list(params, cfg)):
-        p_at = blk["attn"]
-        h_in = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
-        qkv = h_in @ p_at["qkv"]["w"].astype(dtype) \
-            + p_at["qkv"]["b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, c_len, heads, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, c_len, heads, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, c_len, heads, hd).transpose(0, 2, 1, 3)
-        ck = _cache_write_chunk(cache["k"][li], k, start)
-        cv = _cache_write_chunk(cache["v"][li], v, start)
-        new_k.append(ck)
-        new_v.append(cv)
-        att = chunk_attention(q, ck.astype(dtype), cv.astype(dtype),
-                              abs_pos)
-        att = att.transpose(0, 2, 1, 3).reshape(b, c_len, cfg.dim)
-        x = x + (att @ p_at["proj"]["w"].astype(dtype)
-                 + p_at["proj"]["b"].astype(dtype))
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
-    cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    rel_last = jnp.clip(lengths.astype(jnp.int32) - 1 - start, 0, c_len - 1)
-    last = jnp.take_along_axis(x, rel_last[:, None, None], axis=1)[:, 0]
-    return cache, last.astype(jnp.float32) @ params["wte"].T
+    """`decoder.chunk` on the contiguous cache: (cache, logits [b, vocab])."""
+    return chunk(decoder(cfg), Contiguous(cache), params, tokens, start_pos,
+                 lengths)
 
 
 def gpt_verify_step(params, cfg: GPTConfig, cache, tokens, pos):
-    """Speculative-decoding verify step: score `tokens` (int32
-    [batch, s] — each row is [last committed token, draft_0, ...,
-    draft_{s-2}]) at absolute positions `pos + [0..s)` in ONE forward,
-    returning (cache, logits [batch, s, vocab]) for ALL s positions, so
-    the host can accept the longest greedily-matching draft prefix.
-
-    The trunk is `gpt_prefill_chunk` with s as the chunk length: K/V for
-    all s positions is written at the traced start `pos` (one compiled
-    signature per (bucket, s)) and attention over the full cache window
-    is masked to `key_pos <= query_pos`, so position i's logits equal
-    what `gpt_decode_step` would produce after sequentially feeding the
-    first i tokens — rejected-draft rows written past the accept
-    boundary are exactly the stale rows the mask keeps out of every
-    later step (analyze rule SERVE003 audits this mask).  Callers must
-    guarantee pos + s <= T (the write would otherwise be clamped onto
-    committed rows)."""
-    from easydist_tpu.ops import chunk_attention
-
-    dtype = jnp.dtype(cfg.dtype)
-    heads = cfg.heads
-    b, s = tokens.shape
-    hd = cfg.dim // heads
-    start = pos.astype(jnp.int32)
-    abs_pos = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    x = params["wte"][tokens].astype(dtype) \
-        + params["wpe"][abs_pos].astype(dtype)
-    new_k, new_v = [], []
-    for li, blk in enumerate(_block_list(params, cfg)):
-        p_at = blk["attn"]
-        h_in = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
-        qkv = h_in @ p_at["qkv"]["w"].astype(dtype) \
-            + p_at["qkv"]["b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-        ck = _cache_write_chunk(cache["k"][li], k, start)
-        cv = _cache_write_chunk(cache["v"][li], v, start)
-        new_k.append(ck)
-        new_v.append(cv)
-        att = chunk_attention(q, ck.astype(dtype), cv.astype(dtype),
-                              abs_pos)
-        att = att.transpose(0, 2, 1, 3).reshape(b, s, cfg.dim)
-        x = x + (att @ p_at["proj"]["w"].astype(dtype)
-                 + p_at["proj"]["b"].astype(dtype))
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
-    cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    return cache, x.astype(jnp.float32) @ params["wte"].T
+    """`decoder.verify` on the contiguous cache: logits [b, s, vocab]."""
+    return verify(decoder(cfg), Contiguous(cache), params, tokens, pos)
 
 
 def gpt_decode_step(params, cfg: GPTConfig, cache, token, pos):
-    """One cached decode step: feed `token` (int32 [batch]) at position
-    `pos` (int32 [batch], == current sequence length per row) and return
-    (cache, logits [batch, vocab]) for sampling the next token.
-
-    Per-token work is O(layers * pos) attention reads plus the O(1)
-    matmuls — independent of how many tokens were already generated.  The
-    attention backend is `ops.decode_attention` (Pallas single-query flash
-    kernel on TPU, masked dot_general elsewhere)."""
-    from easydist_tpu.ops import decode_attention
-
-    dtype = jnp.dtype(cfg.dtype)
-    heads = cfg.heads
-    b = token.shape[0]
-    hd = cfg.dim // heads
-    pos = pos.astype(jnp.int32)
-    x = params["wte"][token].astype(dtype) \
-        + params["wpe"][pos].astype(dtype)
-    new_k, new_v = [], []
-    for li, blk in enumerate(_block_list(params, cfg)):
-        p_at = blk["attn"]
-        h_in = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
-        qkv = h_in @ p_at["qkv"]["w"].astype(dtype) \
-            + p_at["qkv"]["b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, heads, hd)
-        ck = _cache_write_row(cache["k"][li], k.reshape(b, heads, hd), pos)
-        cv = _cache_write_row(cache["v"][li], v.reshape(b, heads, hd), pos)
-        new_k.append(ck)
-        new_v.append(cv)
-        att = decode_attention(q, ck.astype(dtype), cv.astype(dtype),
-                               pos + 1)
-        x = x + (att.reshape(b, cfg.dim) @ p_at["proj"]["w"].astype(dtype)
-                 + p_at["proj"]["b"].astype(dtype))
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
-    cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    return cache, x.astype(jnp.float32) @ params["wte"].T
-
-
-# ------------------------------------------------------- paged KV decode
-#
-# Page-table variants of the serving forwards: K/V lives in one
-# preallocated page arena ({"k","v"}: a tuple of one leaf per layer,
-# [n_pages, heads, page_tokens, head_dim]; `kv/arena.py`) and each
-# sequence's int32 page-table row says which arena page holds each
-# `page_tokens`-token window.  The arena is threaded through and donated
-# leaf by leaf: a layer's write lands in that layer's own input buffer and
-# the written leaf is returned as it is, never sliced out of a stacked
-# array and never stacked back.  The table is a few KiB of int32 pushed
-# fresh each step.  Unmapped/dead
-# entries hold the sentinel `n_pages`: writes through it scatter with
-# mode="drop" (deterministically discarded), reads clip to a real page
-# whose rows the length mask zeroes before softmax.
-
-
-def init_kv_pages(cfg: GPTConfig, n_pages: int, page_tokens: int,
-                  dtype=None, quant_dtype=None, quant_block: int = 0):
-    """Zeroed page arena (`kv/arena.py`): {"k", "v"}, each a tuple of one
-    leaf per layer, [n_pages, heads, page_tokens, head_dim] — a buffer of
-    its own, donated and written in place leaf by leaf.
-
-    `quant_dtype="int8"` stores the payload block-scaled int8 and adds
-    parallel scale leaves {"k_scale", "v_scale"}: [n_pages, heads,
-    page_tokens, head_dim // block] f32 (`quant_block` 0 = one block per
-    row).  Presence of the scale keys is the quant signal every paged
-    forward branches on — a {"k","v"}-only arena traces the exact
-    pre-quant program."""
-    dt = jnp.dtype(cfg.dtype if dtype in (None, "auto") else dtype)
-    return init_page_arena(cfg.layers, n_pages, cfg.heads, page_tokens,
-                           cfg.dim // cfg.heads, dt, quant_dtype,
-                           quant_block)
+    """`decoder.decode` on the contiguous cache: logits [b, vocab]."""
+    return decode(decoder(cfg), Contiguous(cache), params, token, pos)
 
 
 def gpt_prefill_chunk_paged(params, cfg: GPTConfig, pages, table, tokens,
                             start_pos, lengths):
-    """`gpt_prefill_chunk` with the cache indirected through a page table:
-    `pages` is the arena, `table` int32 [batch, max_pages] maps each row's
-    windows to arena pages (sentinel-padded), and the chunk's K/V is
-    written INTO the row's own page for window `start_pos // page_tokens`
-    — there is no staging cache and no migrate/restore copy on the paged
-    path; a restored prefix is just table entries pointing at the trie's
-    committed pages.  Attention gathers the virtual contiguous cache
-    [batch, heads, max_pages * page_tokens, head_dim] through the table,
-    so when that length equals the bucketed window the lowered program
-    matches `gpt_prefill_chunk` shape-for-shape and the logits are
-    bitwise identical.  Requires tokens.shape[1] == page_tokens."""
-    from easydist_tpu.ops import (chunk_attention, gather_pages,
-                                  kv_dequantize, kv_quantize)
-
-    dtype = jnp.dtype(cfg.dtype)
-    heads = cfg.heads
-    b, c_len = tokens.shape
-    pt = pages["k"][0].shape[2]
-    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
-    if c_len != pt:
-        raise ValueError(f"paged prefill chunk {c_len} != page_tokens {pt} "
-                         f"(chunks must fill exactly one page)")
-    hd = cfg.dim // heads
-    start = start_pos.astype(jnp.int32)
-    tbl = table.astype(jnp.int32)
-    # the page receiving this chunk: the row's window start // page_tokens
-    # (sentinel for inactive rows -> the writes drop)
-    wp = jnp.take_along_axis(tbl, (start // pt)[:, None], axis=1)[:, 0]
-    abs_pos = start[:, None] + jnp.arange(c_len, dtype=jnp.int32)[None, :]
-    x = params["wte"][tokens].astype(dtype) \
-        + params["wpe"][abs_pos].astype(dtype)
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-    for li, blk in enumerate(_block_list(params, cfg)):
-        p_at = blk["attn"]
-        h_in = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
-        qkv = h_in @ p_at["qkv"]["w"].astype(dtype) \
-            + p_at["qkv"]["b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, c_len, heads, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, c_len, heads, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, c_len, heads, hd).transpose(0, 2, 1, 3)
-        if quant_nb:
-            # quantize-on-commit: the page stores block-scaled int8, the
-            # scale page rides the same write/gather indices
-            k, sk = kv_quantize(k, quant_nb)
-            v, sv = kv_quantize(v, quant_nb)
-            psk = write_chunk(pages["k_scale"][li], sk, wp)
-            psv = write_chunk(pages["v_scale"][li], sv, wp)
-            new_ks.append(psk)
-            new_vs.append(psv)
-        pk = write_chunk(pages["k"][li], k, wp)
-        pv = write_chunk(pages["v"][li], v, wp)
-        new_k.append(pk)
-        new_v.append(pv)
-        # gather AFTER the write so the chunk attends its own fresh page
-        if quant_nb:
-            ck = kv_dequantize(gather_pages(pk, tbl),
-                               gather_pages(psk, tbl), dtype)
-            cv = kv_dequantize(gather_pages(pv, tbl),
-                               gather_pages(psv, tbl), dtype)
-        else:
-            ck = gather_pages(pk, tbl)
-            cv = gather_pages(pv, tbl)
-        att = chunk_attention(q, ck.astype(dtype), cv.astype(dtype),
-                              abs_pos)
-        att = att.transpose(0, 2, 1, 3).reshape(b, c_len, cfg.dim)
-        x = x + (att @ p_at["proj"]["w"].astype(dtype)
-                 + p_at["proj"]["b"].astype(dtype))
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
-    pages = {"k": tuple(new_k), "v": tuple(new_v)}
-    if quant_nb:
-        pages["k_scale"] = tuple(new_ks)
-        pages["v_scale"] = tuple(new_vs)
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    rel_last = jnp.clip(lengths.astype(jnp.int32) - 1 - start, 0, c_len - 1)
-    last = jnp.take_along_axis(x, rel_last[:, None, None], axis=1)[:, 0]
-    return pages, last.astype(jnp.float32) @ params["wte"].T
+    """`decoder.chunk` through a page table; chunk == page_tokens."""
+    return chunk(decoder(cfg), Paged(pages, table), params, tokens,
+                 start_pos, lengths)
 
 
 def gpt_verify_step_paged(params, cfg: GPTConfig, pages, table, tokens,
                           pos):
-    """`gpt_verify_step` against the page arena: the s positions'
-    K/V rows land through the table per position (windows
-    `(pos + i) // page_tokens`, offsets `(pos + i) % page_tokens` — a
-    verify window may straddle a page boundary, unlike page-aligned
-    prefill chunks), and attention gathers the virtual contiguous cache
-    through the table as the paged prefill chunk does.  Returns
-    (pages, logits [batch, s, vocab]) for all s positions.  Callers must
-    have every touched window mapped (or the whole row sentinel — dead
-    rows drop); rejected positions live in mapped pages until the host
-    truncates the table tail past the reservation."""
-    from easydist_tpu.ops import (chunk_attention, gather_pages,
-                                  kv_dequantize, kv_quantize)
-
-    dtype = jnp.dtype(cfg.dtype)
-    heads = cfg.heads
-    b, s = tokens.shape
-    pt = pages["k"][0].shape[2]
-    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
-    hd = cfg.dim // heads
-    start = pos.astype(jnp.int32)
-    tbl = table.astype(jnp.int32)
-    abs_pos = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    # per-position page + offset: [b, s] each (sentinel rows stay
-    # sentinel through the take -> every write drops)
-    wp = jnp.take_along_axis(tbl, abs_pos // pt, axis=1)
-    off = abs_pos % pt
-    x = params["wte"][tokens].astype(dtype) \
-        + params["wpe"][abs_pos].astype(dtype)
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-    for li, blk in enumerate(_block_list(params, cfg)):
-        p_at = blk["attn"]
-        h_in = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
-        qkv = h_in @ p_at["qkv"]["w"].astype(dtype) \
-            + p_at["qkv"]["b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-        if quant_nb:
-            k, sk = kv_quantize(k, quant_nb)
-            v, sv = kv_quantize(v, quant_nb)
-            psk = write_rows(pages["k_scale"][li], sk, wp, off)
-            psv = write_rows(pages["v_scale"][li], sv, wp, off)
-            new_ks.append(psk)
-            new_vs.append(psv)
-        pk = write_rows(pages["k"][li], k, wp, off)
-        pv = write_rows(pages["v"][li], v, wp, off)
-        new_k.append(pk)
-        new_v.append(pv)
-        if quant_nb:
-            ck = kv_dequantize(gather_pages(pk, tbl),
-                               gather_pages(psk, tbl), dtype)
-            cv = kv_dequantize(gather_pages(pv, tbl),
-                               gather_pages(psv, tbl), dtype)
-        else:
-            ck = gather_pages(pk, tbl)
-            cv = gather_pages(pv, tbl)
-        att = chunk_attention(q, ck.astype(dtype), cv.astype(dtype),
-                              abs_pos)
-        att = att.transpose(0, 2, 1, 3).reshape(b, s, cfg.dim)
-        x = x + (att @ p_at["proj"]["w"].astype(dtype)
-                 + p_at["proj"]["b"].astype(dtype))
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
-    pages = {"k": tuple(new_k), "v": tuple(new_v)}
-    if quant_nb:
-        pages["k_scale"] = tuple(new_ks)
-        pages["v_scale"] = tuple(new_vs)
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    return pages, x.astype(jnp.float32) @ params["wte"].T
+    """`decoder.verify` through a page table."""
+    return verify(decoder(cfg), Paged(pages, table), params, tokens, pos)
 
 
 def gpt_decode_step_paged(params, cfg: GPTConfig, pages, table, token, pos):
-    """`gpt_decode_step` against the page arena: the new token's K/V row
-    lands in the page holding window `pos // page_tokens` at offset
-    `pos % page_tokens`, and attention runs through
-    `ops.paged_decode_attention` (page-gathering Pallas kernel on TPU,
-    gather + masked dot_general elsewhere).  The table's fixed
-    [batch, max_pages] shape keeps ONE compiled signature across
-    arbitrary per-row lengths — the whole point of the paged pool."""
-    from easydist_tpu.ops import kv_quantize, paged_decode_attention
-
-    dtype = jnp.dtype(cfg.dtype)
-    heads = cfg.heads
-    b = token.shape[0]
-    pt = pages["k"][0].shape[2]
-    quant_nb = pages["k_scale"][0].shape[-1] if "k_scale" in pages else 0
-    hd = cfg.dim // heads
-    pos = pos.astype(jnp.int32)
-    tbl = table.astype(jnp.int32)
-    wp = jnp.take_along_axis(tbl, (pos // pt)[:, None], axis=1)[:, 0]
-    off = pos % pt
-    x = params["wte"][token].astype(dtype) \
-        + params["wpe"][pos].astype(dtype)
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []
-    for li, blk in enumerate(_block_list(params, cfg)):
-        p_at = blk["attn"]
-        h_in = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"]).astype(dtype)
-        qkv = h_in @ p_at["qkv"]["w"].astype(dtype) \
-            + p_at["qkv"]["b"].astype(dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, heads, hd)
-        k = k.reshape(b, heads, hd)
-        v = v.reshape(b, heads, hd)
-        if quant_nb:
-            k, sk = kv_quantize(k, quant_nb)
-            v, sv = kv_quantize(v, quant_nb)
-            psk = write_row(pages["k_scale"][li], sk, wp, off)
-            psv = write_row(pages["v_scale"][li], sv, wp, off)
-            new_ks.append(psk)
-            new_vs.append(psv)
-        pk = write_row(pages["k"][li], k, wp, off)
-        pv = write_row(pages["v"][li], v, wp, off)
-        new_k.append(pk)
-        new_v.append(pv)
-        if quant_nb:
-            # int8 pages stream to the kernel as-is; dequantization
-            # happens inside the online-softmax loop (or post-gather in
-            # the XLA fallback)
-            att = paged_decode_attention(q, pk, pv, tbl, pos + 1,
-                                         k_scale=psk, v_scale=psv)
-        else:
-            att = paged_decode_attention(q, pk.astype(dtype),
-                                         pv.astype(dtype), tbl, pos + 1)
-        x = x + (att.reshape(b, cfg.dim) @ p_at["proj"]["w"].astype(dtype)
-                 + p_at["proj"]["b"].astype(dtype))
-        h = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"]).astype(dtype)
-        h = jax.nn.gelu(h @ blk["mlp"]["fc"]["w"].astype(dtype)
-                        + blk["mlp"]["fc"]["b"].astype(dtype))
-        x = x + (h @ blk["mlp"]["proj"]["w"].astype(dtype)
-                 + blk["mlp"]["proj"]["b"].astype(dtype))
-    pages = {"k": tuple(new_k), "v": tuple(new_v)}
-    if quant_nb:
-        pages["k_scale"] = tuple(new_ks)
-        pages["v_scale"] = tuple(new_vs)
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    return pages, x.astype(jnp.float32) @ params["wte"].T
+    """`decoder.decode` through a page table."""
+    return decode(decoder(cfg), Paged(pages, table), params, token, pos)
 
 
 def gpt_loss(params, cfg: GPTConfig, tokens, targets):

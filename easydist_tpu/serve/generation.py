@@ -3,14 +3,15 @@
 `ServeEngine` serves request-shaped functions — every call re-runs the
 whole forward.  For autoregressive generation that is O(T^2) attention
 flops per sequence; the KV cache makes each token O(T).  This module is
-the serving half of the cache-carrying model API
-(models/gpt.py::gpt_prefill_chunk/gpt_decode_step and the llama mirror):
+the serving half of the cache-carrying model API (models/decoder.py: one
+`Decoder` record a model, the `chunk`/`verify`/`decode` steps, and the
+`Contiguous` and `Paged` cache adapters):
 
   * **chunked, batched prefill** — each admitted prompt is processed in
     fixed [prefill_batch, prefill_chunk] windows against a multi-row
     staging cache, so ONE compiled prefill signature per bucket serves
-    every prompt length (PR 9 compiled one per pow2-padded length), and
-    up to `prefill_batch` pending prompts share each chunk call;
+    every prompt length, and up to `prefill_batch` pending prompts share
+    each chunk call;
   * **prefix-reuse KV cache** — finished prefills commit their aligned
     KV chunks into a per-bucket token trie (serve/prefix_cache.py);
     admission restores the longest cached whole-chunk prefix with
@@ -22,9 +23,9 @@ the serving half of the cache-carrying model API
     `prefill_chunks_per_step` chunk calls before the decode rounds run,
     so a long prompt cannot stall in-flight decodes for its whole
     prefill (decode p99 stays bounded);
-  * **bucketed KV pool + one compiled decode step** — unchanged from
-    PR 9: one slot pool per `ServeConfig.decode_buckets` entry, decode
-    always steps ALL slots, slots recycle through a free list;
+  * **bucketed KV pool + one compiled decode step** — one slot pool per
+    `ServeConfig.decode_buckets` entry, decode always steps ALL slots,
+    slots recycle through a free list;
   * **paged KV pool** (`ServeConfig.kv_layout="paged"`) — ALL buckets
     collapse into ONE page-granular pool over a preallocated arena
     (kv/pool.py + kv/table.py on the host; kv/arena.py on the device: a
@@ -174,21 +175,19 @@ class _BucketPool:
     the bucket's prefix trie."""
 
     def __init__(self, bucket: int, n_slots: int, init_cache,
-                 n_rows: int = 1, chunk: int = 0,
-                 prefix_bytes: int = 0):
+                 n_rows: int, chunk: int, prefix_bytes: int):
         self.bucket = bucket
         self.n_slots = n_slots
         self.cache = init_cache(n_slots, bucket)
         self.n_rows = n_rows
         self.staging = init_cache(n_rows, bucket)
-        self.chunk = chunk                      # 0 = legacy one-shot path
+        self.chunk = chunk
         self.free: List[int] = list(range(n_slots))
         self.slots: Dict[int, _Slot] = {}          # slot index -> _Slot
         self.free_rows: List[int] = list(range(n_rows))
         self.jobs: Dict[int, _PrefillJob] = {}     # staging row -> job
         self.trie: Optional[PrefixCache] = \
-            PrefixCache(chunk, prefix_bytes) if chunk and prefix_bytes \
-            else None
+            PrefixCache(chunk, prefix_bytes) if prefix_bytes else None
 
     @property
     def n_active(self) -> int:
@@ -346,14 +345,10 @@ class _PagedPool:
 class GenerationSession:
     """Continuous-batching token generation over a cache-carrying model.
 
-    model_prefill(params, cache, tokens, lengths) -> (cache, logits)
-    model_decode(params, cache, token, pos) -> (cache, logits)
-    model_prefill_chunk(params, cache, tokens, start_pos, lengths)
-        -> (cache, logits) — fixed-chunk window at absolute positions;
-        enables the chunked/batched/prefix-reuse prefill scheduler (the
-        `for_gpt`/`for_llama` constructors wire it; without it the
-        session falls back to PR 9's one-shot pow2-padded prefill).
-    init_cache(batch, max_len, dtype=None) -> cache pytree
+    `model` is the model's `Decoder` record (models/decoder.py;
+    `gpt.decoder(cfg)`, `llama.decoder(cfg)`): the session builds every
+    program it runs from it — chunked prefill, decode and verify, against
+    the contiguous bucket cache or the page arena as `kv_layout` says.
 
     Greedy decoding (argmax inside the compiled step, so only int32 token
     ids cross the host boundary per token).  `submit` returns a Future
@@ -370,50 +365,35 @@ class GenerationSession:
     so only host-side state is per-session.
     """
 
-    def __init__(self, params, *, model_prefill: Callable,
-                 model_decode: Callable, init_cache: Callable,
-                 model_prefill_chunk: Optional[Callable] = None,
-                 model_prefill_chunk_paged: Optional[Callable] = None,
-                 model_decode_paged: Optional[Callable] = None,
-                 model_verify: Optional[Callable] = None,
-                 model_verify_paged: Optional[Callable] = None,
-                 init_pages: Optional[Callable] = None,
+    def __init__(self, params, *, model,
                  drafter: Optional[object] = None,
                  config: Optional[ServeConfig] = None, mesh=None,
                  eos_id: Optional[int] = None,
-                 max_prompt_len: Optional[int] = None,
                  metrics: Optional[ServeMetrics] = None,
                  replica_id: Optional[str] = None,
                  compile_key: Optional[object] = None):
         from easydist_tpu.jaxfront import easydist_compile
+        from easydist_tpu.models.decoder import (Contiguous, Paged, chunk,
+                                                 decode, verify)
 
         self.config = config or ServeConfig()
         self.replica_id = replica_id
-        if max_prompt_len is not None:
+        if model.max_positions is not None:
             bad = [b for b in self.config.decode_buckets
-                   if b > max_prompt_len]
+                   if b > model.max_positions]
             if bad:
                 raise ValueError(
                     f"decode_buckets {bad} exceed the model's maximum "
-                    f"sequence length {max_prompt_len}; set "
+                    f"sequence length {model.max_positions}; set "
                     f"ServeConfig(decode_buckets=...) within it")
+        self._model = model
         self.params = params
         self.mesh = mesh
         self.eos_id = eos_id
         self.metrics = metrics or ServeMetrics(replica_id=replica_id)
         self._draining = False
         self._closed = False
-        self._init_cache = init_cache
-        self._chunked = model_prefill_chunk is not None
         self._paged = self.config.kv_layout == "paged"
-        if self._paged and (model_prefill_chunk_paged is None
-                            or model_decode_paged is None
-                            or init_pages is None):
-            raise ValueError(
-                "kv_layout='paged' requires model_prefill_chunk_paged, "
-                "model_decode_paged, and init_pages (the for_gpt/"
-                "for_llama constructors wire all three)")
-        self._init_pages = init_pages
         self._pending: collections.deque = collections.deque()
         self._pools: Dict[int, _BucketPool] = {}
         self._next_request_id = 0
@@ -429,15 +409,6 @@ class GenerationSession:
         self._spec_k = int(self.config.speculate_k or 0)
         self._drafter = None
         if self._spec_k:
-            if self._paged and model_verify_paged is None:
-                raise ValueError(
-                    "speculate_k with kv_layout='paged' requires "
-                    "model_verify_paged (the for_gpt/for_llama "
-                    "constructors wire it)")
-            if not self._paged and model_verify is None:
-                raise ValueError(
-                    "speculate_k requires model_verify (the for_gpt/"
-                    "for_llama constructors wire it)")
             if drafter is not None:
                 self._drafter = drafter
             elif self.config.speculate_drafter == "ngram":
@@ -456,17 +427,11 @@ class GenerationSession:
         self._spec_idle: Dict[int, int] = {}
         self._spec_gate_idle = 0
 
-        def _prefill(cache, params, tokens, lengths):
-            import jax.numpy as jnp
-
-            cache, logits = model_prefill(params, cache, tokens, lengths)
-            return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
         def _prefill_chunk(staging, params, tokens, start, lengths):
             import jax.numpy as jnp
 
-            staging, logits = model_prefill_chunk(params, staging, tokens,
-                                                  start, lengths)
+            staging, logits = chunk(model, Contiguous(staging), params,
+                                    tokens, start, lengths)
             return staging, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def _restore(staging, chunk_kv, row, start):
@@ -496,7 +461,8 @@ class GenerationSession:
         def _decode(pool, params, token, pos):
             import jax.numpy as jnp
 
-            pool, logits = model_decode(params, pool, token, pos)
+            pool, logits = decode(model, Contiguous(pool), params, token,
+                                  pos)
             return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         # speculative verify: tokens is [slots, k+1] (committed token then
@@ -506,10 +472,11 @@ class GenerationSession:
         def _verify(pool, params, tokens, pos):
             import jax.numpy as jnp
 
-            pool, logits = model_verify(params, pool, tokens, pos)
+            pool, logits = verify(model, Contiguous(pool), params, tokens,
+                                  pos)
             return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        self._verify_def = _verify if model_verify is not None else None
+        self._verify_def = _verify
 
         # paged-layout programs: arena first for donation pairing, the
         # int32 page table crosses as data every call (fixed shape — the
@@ -520,15 +487,15 @@ class GenerationSession:
                                  lengths):
             import jax.numpy as jnp
 
-            arena, logits = model_prefill_chunk_paged(
-                params, arena, table, tokens, start, lengths)
+            arena, logits = chunk(model, Paged(arena, table), params,
+                                  tokens, start, lengths)
             return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def _decode_paged(arena, params, table, token, pos):
             import jax.numpy as jnp
 
-            arena, logits = model_decode_paged(params, arena, table,
-                                               token, pos)
+            arena, logits = decode(model, Paged(arena, table), params,
+                                   token, pos)
             return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         # export/import iterate ALL arena keys: a quantized arena ships
@@ -551,16 +518,14 @@ class GenerationSession:
         def _verify_paged(arena, params, table, tokens, pos):
             import jax.numpy as jnp
 
-            arena, logits = model_verify_paged(params, arena, table,
-                                               tokens, pos)
+            arena, logits = verify(model, Paged(arena, table), params,
+                                   tokens, pos)
             return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        self._paged_defs = (
-            {"chunk": _prefill_chunk_paged, "decode": _decode_paged,
-             "export": _page_export, "import": _page_import}
-            if model_prefill_chunk_paged is not None else {})
-        if model_verify_paged is not None:
-            self._paged_defs["verify"] = _verify_paged
+        self._paged_defs = {
+            "chunk": _prefill_chunk_paged, "decode": _decode_paged,
+            "export": _page_export, "import": _page_import,
+            "verify": _verify_paged}
 
         # pool/staging is arg 0 and output 0 of every mutating compiled
         # callable, so state_io="auto" pairs it and XLA gets the buffer
@@ -582,8 +547,7 @@ class GenerationSession:
             if compile_key is not None and mesh is not None else None
         shared = _COMPILED_MEMO.get(memo_key) if memo_key else None
         if shared is None:
-            shared = (easydist_compile(_prefill, mesh=mesh),
-                      easydist_compile(_prefill_chunk, mesh=mesh),
+            shared = (easydist_compile(_prefill_chunk, mesh=mesh),
                       easydist_compile(_restore, mesh=mesh),
                       easydist_compile(_migrate, mesh=mesh),
                       easydist_compile(_decode, mesh=mesh),
@@ -592,7 +556,7 @@ class GenerationSession:
                 while len(_COMPILED_MEMO) >= 32:  # live sessions keep refs
                     _COMPILED_MEMO.pop(next(iter(_COMPILED_MEMO)))
                 _COMPILED_MEMO[memo_key] = shared
-        (self._prefill_c, self._prefill_chunk_c, self._restore_c,
+        (self._prefill_chunk_c, self._restore_c,
          self._migrate_c, self._decode_c, self._extract_cs,
          self._paged_cs, self._verify_cs) = shared
 
@@ -708,34 +672,29 @@ class GenerationSession:
                     host_tier_bytes=cfg.kv_host_tier_bytes,
                     export_page=self._export_arena_page,
                     model_itemsize=self._model_itemsize())
-            elif self._chunked:
+            else:
                 pool = _BucketPool(
                     bucket, cfg.max_decode_slots, self._cache_factory,
                     n_rows=cfg.prefill_batch,
                     chunk=min(cfg.prefill_chunk, bucket),
                     prefix_bytes=(cfg.prefix_cache_bytes
                                   if cfg.enable_prefix_cache else 0))
-            else:
-                pool = _BucketPool(bucket, cfg.max_decode_slots,
-                                   self._cache_factory)
             self._pools[bucket] = pool
         return pool
 
     def _cache_factory(self, batch: int, max_len: int):
-        dtype = self.config.kv_cache_dtype
-        return self._init_cache(batch, max_len,
-                                None if dtype == "auto" else dtype)
+        from easydist_tpu.models.decoder import Contiguous
+
+        return Contiguous.init(self._model, batch, max_len,
+                               self.config.kv_cache_dtype)
 
     def _pages_factory(self, n_pages: int, page_tokens: int):
+        from easydist_tpu.models.decoder import Paged
+
         cfg = self.config
-        dtype = None if cfg.kv_cache_dtype == "auto" else cfg.kv_cache_dtype
-        if cfg.kv_quant_dtype != "none":
-            # quant kwargs only when armed, so custom init_pages lambdas
-            # predating the knob keep working for quant-off sessions
-            return self._init_pages(n_pages, page_tokens, dtype,
-                                    quant_dtype=cfg.kv_quant_dtype,
-                                    quant_block=cfg.kv_quant_block)
-        return self._init_pages(n_pages, page_tokens, dtype)
+        return Paged.init(self._model, n_pages, page_tokens,
+                          cfg.kv_cache_dtype, cfg.kv_quant_dtype,
+                          cfg.kv_quant_block)
 
     def _model_itemsize(self) -> int:
         """Bytes per element at model precision (first param leaf) — the
@@ -753,14 +712,6 @@ class GenerationSession:
 
         return self._paged_c("export")(pool.arena,
                                        jnp.asarray(int(pid), jnp.int32))
-
-    def _prefill_pad(self, plen: int, bucket: int) -> int:
-        """Legacy one-shot path: smallest power of two >= plen (floor 8),
-        capped at the decode bucket."""
-        t = 8
-        while t < plen:
-            t *= 2
-        return min(t, bucket)
 
     def _admitted(self, timing: dict, prefix_len: int) -> int:
         """A request left the queue: stamp it, feed `queue_wait`, and
@@ -790,11 +741,10 @@ class GenerationSession:
         return state, out, sp
 
     def _admit_one(self) -> bool:
-        """Pop one pending request toward generation.  Chunked path:
-        reserve a pool slot + staging row, restore the longest cached
-        prefix, and enqueue a prefill job (chunks run in `step()`).
-        Legacy path: one-shot prefill + migrate, as in PR 9.  Returns
-        False when nothing is admissible."""
+        """Pop one pending request toward generation: reserve a pool slot
+        + staging row, restore the longest cached prefix, and enqueue a
+        prefill job (chunks run in `step()`).  Returns False when nothing
+        is admissible."""
         import jax.numpy as jnp
 
         if not self._pending:
@@ -802,9 +752,7 @@ class GenerationSession:
         prompt, max_new, eos, fut, timing = self._pending[0]
         bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
         pool = self._pool_for(bucket)
-        if not pool.free:
-            return False
-        if (self._chunked or self._paged) and not pool.free_rows:
+        if not pool.free or not pool.free_rows:
             return False
         if self._paged:
             return self._admit_one_paged(pool)
@@ -812,48 +760,25 @@ class GenerationSession:
         if fut.set_running_or_notify_cancel() is False:
             return True  # cancelled while queued; slot stays free
         slot_idx = pool.free.pop()
-
-        if self._chunked:
-            row = pool.free_rows.pop()
-            prefix_len, nodes = 0, []
-            if pool.trie is not None:
-                # cap below len(prompt): at least one real token must run
-                # through prefill so the finishing chunk produces logits
-                prefix_len, nodes = pool.trie.match(
-                    prompt, max_tokens=len(prompt) - 1)
-                for j, node in enumerate(nodes):
-                    pool.staging = self._restore_c(
-                        pool.staging, node.kv,
-                        jnp.asarray(row, jnp.int32),
-                        jnp.asarray(j * pool.chunk, jnp.int32))
-                pool.trie.pin(nodes)
-            self.metrics.record_admission(len(prompt), prefix_len)
-            pool.jobs[row] = _PrefillJob(
-                request_id=self._admitted(timing, prefix_len), future=fut,
-                prompt=prompt, max_new=max_new, eos_id=eos, row=row,
-                slot_idx=slot_idx, start=prefix_len,
-                prefix_nodes=nodes, timing=timing)
-            return True
-
-        t_pad = self._prefill_pad(len(prompt), bucket)
-        tokens = np.full((1, t_pad), int(self.config.pad_value), np.int32)
-        tokens[0, :len(prompt)] = prompt
-        lengths = np.array([len(prompt)], np.int32)
-        pool.staging, first = self._prefill_c(
-            pool.staging, self.params, jnp.asarray(tokens),
-            jnp.asarray(lengths))
-        pool.cache = self._migrate_c(pool.cache, pool.staging,
-                                     jnp.asarray(0, jnp.int32),
-                                     jnp.asarray(slot_idx, jnp.int32))
-        self.metrics.record_admission(len(prompt), 0)
-        slot = _Slot(request_id=self._admitted(timing, 0), future=fut,
-                     pos=len(prompt), token=int(np.asarray(first)[0]),
-                     max_new=max_new, eos_id=eos, prompt=prompt,
-                     timing=timing)
-        self._first_token(timing)
-        slot.generated.append(slot.token)
-        pool.slots[slot_idx] = slot
-        self._maybe_retire(pool, slot_idx)
+        row = pool.free_rows.pop()
+        prefix_len, nodes = 0, []
+        if pool.trie is not None:
+            # cap below len(prompt): at least one real token must run
+            # through prefill so the finishing chunk produces logits
+            prefix_len, nodes = pool.trie.match(
+                prompt, max_tokens=len(prompt) - 1)
+            for j, node in enumerate(nodes):
+                pool.staging = self._restore_c(
+                    pool.staging, node.kv,
+                    jnp.asarray(row, jnp.int32),
+                    jnp.asarray(j * pool.chunk, jnp.int32))
+            pool.trie.pin(nodes)
+        self.metrics.record_admission(len(prompt), prefix_len)
+        pool.jobs[row] = _PrefillJob(
+            request_id=self._admitted(timing, prefix_len), future=fut,
+            prompt=prompt, max_new=max_new, eos_id=eos, row=row,
+            slot_idx=slot_idx, start=prefix_len,
+            prefix_nodes=nodes, timing=timing)
         return True
 
     def _admit_one_paged(self, pool: _PagedPool) -> bool:
@@ -1579,13 +1504,12 @@ class GenerationSession:
                     pass
                 sp.set(admitted=queued - len(self._pending),
                        deferred=len(self._pending))
-            if self._chunked or self._paged:
-                budget = self.config.prefill_chunks_per_step
-                for pool in self._pools.values():
-                    if budget <= 0:
-                        break
-                    if pool.jobs:
-                        budget -= self._prefill_round(pool, budget)
+            budget = self.config.prefill_chunks_per_step
+            for pool in self._pools.values():
+                if budget <= 0:
+                    break
+                if pool.jobs:
+                    budget -= self._prefill_round(pool, budget)
             before = self.metrics.counter("tokens_generated")
             for pool in self._pools.values():
                 if pool.slots:
@@ -1708,8 +1632,7 @@ class GenerationSession:
         """Trie page size (tokens) for the bucket `prompt` decodes in, or
         None when the prompt fits no bucket / prefix reuse is off."""
         bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
-        if bucket is None or not (self._chunked or self._paged) \
-                or not self.config.enable_prefix_cache \
+        if bucket is None or not self.config.enable_prefix_cache \
                 or not self.config.prefix_cache_bytes:
             return None
         return min(self.config.prefill_chunk, self._trie_bucket(bucket))
@@ -1874,8 +1797,7 @@ class GenerationSession:
             "prefill_signatures": (
                 self._paged_cs["chunk"].cache_stats()
                 if self._paged and "chunk" in self._paged_cs
-                else (self._prefill_chunk_c if self._chunked
-                      else self._prefill_c).cache_stats()),
+                else self._prefill_chunk_c.cache_stats()),
             "verify_signatures": (
                 self._paged_cs["verify"].cache_stats()
                 if self._paged and "verify" in self._paged_cs
@@ -1887,26 +1809,37 @@ class GenerationSession:
 
     # --------------------------------------------------------- constructors
     @classmethod
-    def _wire_draft_model(cls, kw, draft_model, decode_step, init_cache,
-                          seq_bound: Optional[int]) -> None:
-        """Turn a `draft_model=(params, cfg)` pair into a
-        `SmallModelDrafter` over the family's own decode step (in `kw`
-        as `drafter`, unless the caller passed one explicitly)."""
-        if draft_model is None or kw.get("drafter") is not None:
+    def _wire_draft_model(cls, kw, dparams, draft) -> None:
+        """Turn a draft model (its params and `Decoder`) into a
+        `SmallModelDrafter` over the contiguous decode step (in `kw` as
+        `drafter`, unless the caller passed one explicitly)."""
+        if kw.get("drafter") is not None:
             return
+        from easydist_tpu.models.decoder import Contiguous, decode
+
         from .speculate import SmallModelDrafter
 
-        dparams, dcfg = draft_model
         scfg = kw.get("config") or ServeConfig()
         max_len = max(scfg.decode_buckets)
-        if seq_bound is not None:
-            max_len = min(max_len, seq_bound)
+        if draft.max_positions is not None:
+            max_len = min(max_len, draft.max_positions)
         kw["drafter"] = SmallModelDrafter(
             dparams,
-            model_decode=lambda p, c, t, pos: decode_step(
-                p, dcfg, c, t, pos),
-            init_cache=lambda b, L: init_cache(dcfg, b, L),
+            model_decode=lambda p, c, t, pos: decode(
+                draft, Contiguous(c), p, t, pos),
+            init_cache=lambda b, L: Contiguous.init(draft, b, L),
             max_len=max_len, mesh=kw.get("mesh"))
+
+    @classmethod
+    def _for_family(cls, name, family, params, cfg, draft_model, kw):
+        """A session over `family`, a model module with `decoder(cfg)`."""
+        import dataclasses
+
+        kw.setdefault("compile_key", (name, dataclasses.astuple(cfg)))
+        if draft_model is not None:
+            cls._wire_draft_model(kw, draft_model[0],
+                                  family.decoder(draft_model[1]))
+        return cls(params, model=family.decoder(cfg), **kw)
 
     @classmethod
     def for_gpt(cls, params, cfg, *, draft_model=None, **kw):
@@ -1914,35 +1847,9 @@ class GenerationSession:
         (the learned-position-table bound).  `draft_model=(params, cfg)`
         wires a `SmallModelDrafter` over a second (smaller) gpt for
         `speculate_drafter="draft_model"`."""
-        import dataclasses
-
         from easydist_tpu.models import gpt
 
-        kw.setdefault("compile_key", ("gpt", dataclasses.astuple(cfg)))
-        if draft_model is not None:
-            cls._wire_draft_model(kw, draft_model, gpt.gpt_decode_step,
-                                  gpt.init_kv_cache,
-                                  seq_bound=draft_model[1].seq)
-        return cls(
-            params,
-            model_prefill=lambda p, c, t, l: gpt.gpt_prefill(p, cfg, c, t, l),
-            model_prefill_chunk=lambda p, c, t, s, l: gpt.gpt_prefill_chunk(
-                p, cfg, c, t, s, l),
-            model_decode=lambda p, c, t, pos: gpt.gpt_decode_step(
-                p, cfg, c, t, pos),
-            init_cache=lambda b, L, dt=None: gpt.init_kv_cache(
-                cfg, b, L, dtype=dt),
-            model_prefill_chunk_paged=lambda p, pg, tb, t, s, l:
-                gpt.gpt_prefill_chunk_paged(p, cfg, pg, tb, t, s, l),
-            model_decode_paged=lambda p, pg, tb, t, pos:
-                gpt.gpt_decode_step_paged(p, cfg, pg, tb, t, pos),
-            init_pages=lambda n, t, dt=None, **qkw: gpt.init_kv_pages(
-                cfg, n, t, dtype=dt, **qkw),
-            model_verify=lambda p, c, t, pos: gpt.gpt_verify_step(
-                p, cfg, c, t, pos),
-            model_verify_paged=lambda p, pg, tb, t, pos:
-                gpt.gpt_verify_step_paged(p, cfg, pg, tb, t, pos),
-            max_prompt_len=cfg.seq, **kw)
+        return cls._for_family("gpt", gpt, params, cfg, draft_model, kw)
 
     @classmethod
     def for_llama(cls, params, cfg, *, draft_model=None, **kw):
@@ -1950,33 +1857,6 @@ class GenerationSession:
         cfg.seq).  `draft_model=(params, cfg)` wires a
         `SmallModelDrafter` over a second (smaller) llama for
         `speculate_drafter="draft_model"`."""
-        import dataclasses
-
         from easydist_tpu.models import llama
 
-        kw.setdefault("compile_key", ("llama", dataclasses.astuple(cfg)))
-        if draft_model is not None:
-            cls._wire_draft_model(kw, draft_model,
-                                  llama.llama_decode_step,
-                                  llama.init_kv_cache, seq_bound=None)
-        return cls(
-            params,
-            model_prefill=lambda p, c, t, l: llama.llama_prefill(
-                p, cfg, c, t, l),
-            model_prefill_chunk=lambda p, c, t, s, l:
-                llama.llama_prefill_chunk(p, cfg, c, t, s, l),
-            model_decode=lambda p, c, t, pos: llama.llama_decode_step(
-                p, cfg, c, t, pos),
-            init_cache=lambda b, L, dt=None: llama.init_kv_cache(
-                cfg, b, L, dtype=dt),
-            model_prefill_chunk_paged=lambda p, pg, tb, t, s, l:
-                llama.llama_prefill_chunk_paged(p, cfg, pg, tb, t, s, l),
-            model_decode_paged=lambda p, pg, tb, t, pos:
-                llama.llama_decode_step_paged(p, cfg, pg, tb, t, pos),
-            init_pages=lambda n, t, dt=None, **qkw: llama.init_kv_pages(
-                cfg, n, t, dtype=dt, **qkw),
-            model_verify=lambda p, c, t, pos: llama.llama_verify_step(
-                p, cfg, c, t, pos),
-            model_verify_paged=lambda p, pg, tb, t, pos:
-                llama.llama_verify_step_paged(p, cfg, pg, tb, t, pos),
-            **kw)
+        return cls._for_family("llama", llama, params, cfg, draft_model, kw)

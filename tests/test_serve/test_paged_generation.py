@@ -316,13 +316,3 @@ class TestConfigValidation:
         with pytest.raises(ValueError, match="kv_arena_pages"):
             ServeConfig(decode_buckets=(32,), kv_layout="paged",
                         kv_arena_pages=-1)
-
-    def test_paged_requires_model_hooks(self, model):
-        cfg, params = model
-        sc = _config("paged")
-        with pytest.raises(ValueError, match="paged"):
-            GenerationSession(
-                model_prefill=lambda p, t: None,
-                model_decode=lambda p, c, t, pos: None,
-                init_cache=lambda b, T: {},
-                params=params, config=sc)

@@ -363,19 +363,6 @@ class TestKnobValidation:
                 config=_config(spec_k=2,
                                speculate_drafter="draft_model"))
 
-    def test_spec_k_requires_verify_step(self, gpt_model):
-        cfg, params = gpt_model
-        with pytest.raises(ValueError, match="model_verify"):
-            GenerationSession(
-                params,
-                model_prefill=lambda p, c, t, l: gpt.gpt_prefill(
-                    p, cfg, c, t, l),
-                model_decode=lambda p, c, t, pos: gpt.gpt_decode_step(
-                    p, cfg, c, t, pos),
-                init_cache=lambda b, L, dt=None: gpt.init_kv_cache(
-                    cfg, b, L, dtype=dt),
-                config=_config(spec_k=2))
-
 
 class TestSpeculationMetrics:
     def test_counters_and_gauges(self, gpt_model):

@@ -250,7 +250,7 @@ def test_session_programs_have_distinct_stable_names(model):
                                         max_decode_slots=2,
                                         prefill_chunk=CHUNK))
     names += [c.func.__name__ for c in (
-        flat._prefill_c, flat._prefill_chunk_c, flat._restore_c,
+        flat._prefill_chunk_c, flat._restore_c,
         flat._migrate_c, flat._decode_c, flat._extract_for(4),
         flat._extract_for(8))] + [flat._verify_def.__name__]
     assert len(set(names)) == len(names), names
