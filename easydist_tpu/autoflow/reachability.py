@@ -31,10 +31,12 @@ _HEAVY_OPS = {"dot_general", "conv_general_dilated", "matmul", "mm", "bmm",
 
 
 def _node_flops(node: MetaNode) -> float:
+    if node.flops is not None:
+        # recorded by the bridge: a dot's or conv's exact MACs, a Pallas
+        # kernel's own cost estimate
+        return node.flops
     if node.op_key not in _HEAVY_OPS:
         return 0.0
-    if node.flops is not None:
-        return node.flops  # exact MACs recorded by the bridge
     out_elems = sum(math.prod(v.shape) for v in node.outvars if v is not None)
     ins = [math.prod(v.shape) for v in node.invars if v is not None]
     if len(ins) >= 2 and out_elems > 0:

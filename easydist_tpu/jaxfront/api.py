@@ -167,6 +167,14 @@ def emit_sharded_fn(closed_jaxpr, names: VarNames,
     for r in (partial_regions or []):
         region_at[r.start] = r
         in_region.update(range(r.start, r.end + 1))
+    # eqn idx -> mesh axes over which that Pallas kernel's rows are sharded
+    pallas_rows = {}
+    if mesh.size > 1:
+        for idx, eqn in enumerate(jaxpr.eqns):
+            if eqn.primitive.name == "pallas_call" and idx not in in_region:
+                pallas_rows[idx] = _pallas_row_axes(
+                    eqn, [chosen.get(f"op{idx}") for chosen in per_axis],
+                    axis_names, mesh)
 
     def sharded_fn(*flat_args):
         from .partial_regions import emit_region
@@ -245,10 +253,9 @@ def emit_sharded_fn(closed_jaxpr, names: VarNames,
 
             out = _emit_attention_variant(eqn, strategies, axis_names, mesh,
                                           invals)
-            if out is None and eqn.primitive.name == "pallas_call" \
-                    and mesh.size > 1:
-                out = _bind_whole_per_device(eqn, subfuns, bind_params,
-                                             invals, mesh)
+            if out is None and idx in pallas_rows:
+                out = _bind_pallas_rows(eqn, subfuns, bind_params, invals,
+                                        mesh, pallas_rows[idx])
             if out is None:
                 out = eqn.primitive.bind(*subfuns, *invals, **bind_params)
                 if not eqn.primitive.multiple_results:
@@ -263,25 +270,74 @@ def emit_sharded_fn(closed_jaxpr, names: VarNames,
     return sharded_fn
 
 
-def _bind_whole_per_device(eqn, subfuns, bind_params, invals, mesh):
-    """Bind a Pallas kernel so that every device of `mesh` runs it whole.
+def _pallas_row_axes(eqn, strategies, axis_names, mesh) -> Tuple[str, ...]:
+    """The mesh axes, in `axis_names` order, on which the solved
+    `strategies` (one per axis) shard the rows of a row-parallel
+    `pallas_call` (presets.pallas_row_extent); `()` for a call that is not
+    row-parallel, or whose plan left it whole.  Counts the call as
+    `pallas_calls{kernel=<name>, row_shards=<k>}`, once per emission."""
+    from .presets import pallas_row_extent
 
-    The solver pins `pallas_call` replicated (presets.py), but replicated
-    is not enough for the TPU lowering: inside a jit that GSPMD partitions
-    it refuses a Mosaic custom call outright ("Mosaic kernels cannot be
-    automatically partitioned. Please wrap the call in a shard_map").  So
-    the kernel goes under a shard_map over every mesh axis with all
-    operands and results replicated — GSPMD gathers what the neighbours
-    hold sharded, each device computes the full result."""
-    from jax.sharding import PartitionSpec
+    n = pallas_row_extent(eqn)
+    axes, k = [], 1
+    for ax_name, s in zip(axis_names, strategies if n is not None else ()):
+        placements = [] if s is None else \
+            list(s.in_placements) + list(s.out_placements)
+        size = int(mesh.shape[ax_name])
+        if placements and all(p is not None and p.is_shard() and p.dim == 0
+                              for p in placements) \
+                and n % (k * size) == 0:
+            axes.append(ax_name)
+            k *= size
+    spans.count("pallas_calls", kernel=eqn.params.get("name") or "pallas_call",
+                row_shards=k)
+    return tuple(axes)
 
-    def whole(*xs):
+
+def _bind_pallas_rows(eqn, subfuns, bind_params, invals, mesh, axes):
+    """Bind a Pallas kernel under a `shard_map` over the whole mesh, its
+    rows (dimension 0 of every operand and result) split over `axes`.
+
+    The `shard_map` is there for every kernel: inside a jit that GSPMD
+    partitions, the TPU lowering refuses a bare Mosaic custom call
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+    call in a shard_map").  With `axes == ()` every device runs the call
+    whole: GSPMD gathers what the neighbours hold sharded and each device
+    computes the full result.  Otherwise a device runs the kernel on its
+    `n / k` rows only: the traced equation bakes the global row count into
+    the grid, the block mappings' array avals and `out_avals`, so the call
+    is re-bound with those three at the local extent (what Pallas' own
+    batching rule does in the other direction); kernel body, blocks and
+    index maps are untouched."""
+    import dataclasses
+
+    k = int(np.prod([mesh.shape[a] for a in axes], dtype=np.int64))
+    if k > 1:
+        def local(aval):
+            return aval.update(shape=(aval.shape[0] // k,) + aval.shape[1:])
+
+        gm = bind_params["grid_mapping"]
+        cost = bind_params.get("cost_estimate")
+        bind_params = dict(
+            bind_params,
+            grid_mapping=dataclasses.replace(
+                gm, grid=(gm.grid[0] // k,) + tuple(gm.grid[1:]),
+                block_mappings=tuple(
+                    dataclasses.replace(bm, array_aval=local(bm.array_aval))
+                    for bm in gm.block_mappings)),
+            out_avals=tuple(local(a) for a in bind_params["out_avals"]),
+            cost_estimate=cost and dataclasses.replace(
+                cost, flops=cost.flops // k,
+                transcendentals=cost.transcendentals // k,
+                bytes_accessed=cost.bytes_accessed // k))
+    spec = PartitionSpec(axes) if axes else PartitionSpec()
+
+    def kernel(*xs):
         return eqn.primitive.bind(*subfuns, *xs, **bind_params)
 
     return jax.shard_map(
-        whole, mesh=mesh, in_specs=tuple(PartitionSpec() for _ in invals),
-        out_specs=[PartitionSpec() for _ in eqn.outvars],
-        check_vma=False)(*invals)
+        kernel, mesh=mesh, in_specs=tuple(spec for _ in invals),
+        out_specs=[spec for _ in eqn.outvars], check_vma=False)(*invals)
 
 
 def _compile_cache_key(closed_jaxpr, axis_specs) -> str:
@@ -295,7 +351,7 @@ def _compile_cache_key(closed_jaxpr, axis_specs) -> str:
     # schema + cost-model salt: cached strategies are only valid for the
     # solver/cost-model that produced them; a version bump or a tuned
     # bandwidth/latency knob must miss, not silently serve stale plans
-    h.update(("v8|" + "|".join(
+    h.update(("v9|" + "|".join(
         f"{k}={getattr(edconfig, k)}" for k in
         ("ici_bandwidth", "dcn_bandwidth", "ici_latency", "dcn_latency",
          "hbm_bandwidth", "all_to_all_punish_factor",
@@ -693,7 +749,12 @@ def solve_axes(closed_jaxpr, axis_specs, world, rules, shape_info, names,
         _apply_user_pins(graph, closed_jaxpr, axis)
 
         def exclude_map(node, _prev=tuple(prev_chosen)):
-            if edconfig.allow_repeated_axis_strategy:
+            # a row-parallel kernel's one shard group is its rows
+            # (presets.py): a later axis can divide the kernel's work only
+            # by splitting them again, and the pool checks divisibility on
+            # the shape the earlier axes already shrank
+            if edconfig.allow_repeated_axis_strategy \
+                    or node.shard_where_valid:
                 return []
             out = []
             for chosen in _prev:

@@ -25,7 +25,8 @@ from .interpreter import VarNames, eqn_signature
 
 def _eqn_flops(eqn) -> float:
     """Rough FLOP estimate for replication accounting: exact-ish for
-    dot_general/conv, length x body for scan, output numel otherwise."""
+    dot_general/conv, a Pallas kernel's own `cost_estimate`, length x body
+    for scan, output numel otherwise."""
     import math
 
     prim = eqn.primitive.name
@@ -40,6 +41,8 @@ def _eqn_flops(eqn) -> float:
         rhs = eqn.invars[1].aval
         return 2.0 * math.prod(out.shape) * math.prod(rhs.shape[2:]) \
             * rhs.shape[1]
+    if eqn.params.get("cost_estimate") is not None:  # a pallas_call's
+        return float(eqn.params["cost_estimate"].flops)
     if prim == "scan":
         inner = eqn.params.get("jaxpr")
         length = eqn.params.get("length", 1)
@@ -129,11 +132,14 @@ def jaxpr_to_metagraph(closed_jaxpr, rules: Dict[str, dict],
                         invars=invars, outvars=outvars,
                         space=rule["space"], recombines=rule["recombines"],
                         arg_rows=arg_rows, sig=sig)
-        if eqn.primitive.name in ("dot_general", "conv_general_dilated"):
-            # exact MACs from dimension_numbers, recorded while we still
-            # have the eqn: shape-only recovery of the contraction length
-            # is ambiguous (square matmuls vs batched dots, r5 review #3)
+        if eqn.primitive.name in ("dot_general", "conv_general_dilated") \
+                or eqn.params.get("cost_estimate") is not None:
+            # exact MACs from dimension_numbers (a kernel's from its own
+            # cost estimate), recorded while we still have the eqn:
+            # shape-only recovery of the contraction length is ambiguous
+            # (square matmuls vs batched dots, r5 review #3)
             node.flops = _eqn_flops(eqn)
+        node.shard_where_valid = bool(rule.get("shard_where_valid"))
         if rule.get("compute") is not None:
             node.compute_proxy = float(rule["compute"])
         if rule.get("strategies") is not None:

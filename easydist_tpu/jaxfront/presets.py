@@ -698,23 +698,87 @@ def _create_rule(eqn, world_size):
     return {"space": ShardSpace([]), "recombines": {}}
 
 
+def _binds_program_id(jaxpr, axis: int) -> bool:
+    """Whether `jaxpr`, or any jaxpr nested in its equations' params (the
+    `pl.when` branches, loops), reads the grid position or extent of
+    `axis`."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("program_id", "num_programs") \
+                and eqn.params.get("axis") == axis:
+            return True
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns") and _binds_program_id(sub, axis):
+                    return True
+    return False
+
+
+def pallas_row_extent(eqn) -> Optional[int]:
+    """`n` when the `pallas_call` equation is ROW-PARALLEL over a leading
+    extent of `n`, else None.  Read from the equation alone, by the rule
+    below and by emission (api.py::_bind_pallas_rows) alike.
+
+    Row-parallel: the grid's leading axis has the static extent `n`; every
+    operand and every result has leading extent `n`, block size 1 along it,
+    and an index map whose first output IS its first input (grid position
+    `i` touches row `i` of everything and no other row); no scalar-prefetch
+    operands (their contents may name rows); and the kernel body never
+    reads the position or extent of grid axis 0.  Rows `[a, b)` of the
+    results are then the same call on rows `[a, b)` of the operands.  The
+    three flash training kernels qualify; the decode kernels carry
+    `lengths` / the page table as prefetched scalars and do not."""
+    from jax.experimental import pallas as pl
+
+    gm = eqn.params.get("grid_mapping")
+    if gm is None or gm.num_index_operands or not gm.grid \
+            or getattr(gm, "num_dynamic_grid_bounds", 0):
+        return None
+    n = gm.grid[0]
+    if not isinstance(n, int) or n < 1:
+        return None
+    avals = [v.aval for v in list(eqn.invars) + list(eqn.outvars)]
+    if len(avals) != len(gm.block_mappings):
+        return None
+    for aval, bm in zip(avals, gm.block_mappings):
+        shape = getattr(aval, "shape", ())
+        index_map = bm.index_map_jaxpr.jaxpr
+        if not shape or shape[0] != n or bm.array_aval.shape[0] != n \
+                or bm.block_shape[0] not in (pl.Blocked(1), pl.Squeezed()) \
+                or not index_map.invars \
+                or index_map.outvars[0] is not index_map.invars[0]:
+            return None
+    if _binds_program_id(eqn.params["jaxpr"], 0):
+        return None
+    return n
+
+
 @register_preset("pallas_call")
 def _pallas_call_rule(eqn, world_size):
-    """Pallas kernels stay REPLICATED under the auto-solver (for now).
+    """A row-parallel Pallas kernel (`pallas_row_extent`) is a shardable
+    op with ONE shard group: dimension 0 of every operand and every result
+    together, recombined by concatenation along 0.  Emission honours the
+    placement by re-binding the kernel at the shard's row count under a
+    `shard_map` (api.py::_bind_pallas_rows); an axis that does not divide
+    the rows drops out of the pool like any indivisible dim and the call
+    stays whole along it.  Nothing is padded.
 
-    Execution discovery cannot verify a sharded rebinding — the traced
-    eqn's grid_mapping bakes the full-shape grid, so binding shard-sized
-    operands is structurally invalid — and GSPMD cannot partition the
-    resulting Mosaic custom call either; honoring a SHARD placement would
-    need manual shard_map re-emission with a re-traced kernel (ROADMAP).
-    Declaring replicate analytically avoids nshards x candidates of doomed
-    eager executions and the failed-discovery warning per kernel.
-    Multi-device flash attention routes through parallel/ring_attention,
-    which composes the kernels per-shard explicitly."""
+    Any other `pallas_call` is replicated: its grid mapping bakes shapes
+    this rule cannot re-derive and GSPMD cannot partition a Mosaic custom
+    call, so emission runs it whole on every device.  Stating that
+    analytically also spares nshards x candidates eager executions that
+    could only fail.  Ring attention and the pipeline programs compose
+    their kernels per shard inside their own `shard_map`."""
     avals = _tensor_avals(eqn)
-    return {"space": ShardSpace([[DimSharding() for _ in a.shape]
-                                 for a in avals]),
-            "recombines": {}}
+    rows = [[DimSharding() for _ in a.shape] for a in avals]
+    if pallas_row_extent(eqn) is None:
+        return {"space": ShardSpace(rows), "recombines": {}}
+    for row in rows:
+        row[0] = DimSharding(group=1)
+    return {"space": ShardSpace(rows),
+            "recombines": {1: [_concat(0)] * len(eqn.outvars)},
+            "shard_where_valid": True}
 
 
 @register_preset("sharding_constraint")

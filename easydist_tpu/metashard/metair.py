@@ -189,6 +189,12 @@ class MetaNode:
         # set, the pool is exactly [pinned] — the solver prices neighbors
         # against the pin instead of fighting it at emission
         self.pinned: Optional[NodeStrategy] = None
+        # a node that shards on every axis where a shard strategy is valid
+        # (a row-parallel Pallas kernel: presets._pallas_call_rule): the
+        # pool offers replicate only where none is, the node is its own
+        # cluster (_solo_cluster), and a strategy chosen on one mesh axis
+        # stays in the pool for the next (api.solve_axes)
+        self.shard_where_valid = False
 
         for idx, v in enumerate(invars):
             if v is not None:
@@ -290,7 +296,9 @@ class MetaNode:
         # compute-redundancy cost (replicated compute runs full-size on
         # every device; sharded runs 1/n — see SpmdSolver._collect_edges).
         rep = self.replicate_strategy()
-        if all(s != rep for s in pool) and all(rep != e for e in exclude):
+        if not (self.shard_where_valid and pool) \
+                and all(s != rep for s in pool) \
+                and all(rep != e for e in exclude):
             pool.append(rep)
         if not pool:
             pool = [rep]
@@ -304,6 +312,15 @@ class MetaNode:
 # jax.checkpoint regions — both carry explicit priced strategies whose
 # many-input boundaries a cone back-build would sync-free-match away
 _SOLO_CLUSTER_OPS = {"scan", "while", "cond", "remat2", "remat", "checkpoint"}
+
+
+def _solo_cluster(node: "MetaNode") -> bool:
+    """A node solved as its own cluster.  Besides the composites: a node
+    that must shard where it can (a row-parallel Pallas kernel), because
+    on a later mesh axis its producers' pools have lost the placement they
+    chose on the earlier one, and a cone without a sync-free assignment
+    falls back to all-replicate — as an edge, the reshard is priced."""
+    return node.op_key in _SOLO_CLUSTER_OPS or node.shard_where_valid
 
 
 # ---------------------------------------------------------------- clusters
@@ -492,7 +509,7 @@ class MetaGraph:
         find_cone_roots, metair.py:852-892)."""
         roots = []
         for node in self.ops:
-            if node.op_key in _SOLO_CLUSTER_OPS:
+            if _solo_cluster(node):
                 # composites must never be grown into a downstream cone:
                 # back-build would sync-free-match their many-input boundary
                 # and silently drop strategies (a single-outvar scan passes
@@ -562,7 +579,7 @@ class MetaGraph:
 
         for root in roots:
             c = MetaNodeCluster(len(self.clusters))
-            if root.op_key in _SOLO_CLUSTER_OPS:
+            if _solo_cluster(root):
                 # composite ops price their internals via intrinsic_cost and
                 # have many-input boundaries; absorbing producers into their
                 # cone would DROP any strategy a producer can't serve

@@ -76,6 +76,23 @@ def _q_index_map(causal, block_q, block_k):
         bh, jnp.maximum(qb, (ki * block_k) // block_q), 0)
 
 
+def _flash_cost(arrays, n_q, n_k, bq, bk, causal, matmuls):
+    """`pl.CostEstimate` of one training call over `arrays` (its operands
+    and results, `[b*h, t, d]` first): what the kernel executes, advisory
+    to XLA and read by the auto-solver's cost model (jaxfront/bridge.py).
+    Every (Q block, K block) pair the grid runs — causal: those not
+    strictly above the diagonal — does `matmuls` products of 2 * bq * bk *
+    d FLOPs and one exp per score; every array crosses HBM once."""
+    bh, _, d = arrays[0].shape
+    pairs = sum(min(n_k, ((qi + 1) * bq - 1) // bk + 1) for qi in range(n_q)) \
+        if causal else n_q * n_k
+    scores = bh * pairs * bq * bk
+    return pl.CostEstimate(
+        flops=2 * matmuls * scores * d, transcendentals=scores,
+        bytes_accessed=sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+                           for a in arrays))
+
+
 # ---------------------------------------------------------------- forward
 
 
@@ -133,6 +150,10 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                block_q=bq, block_k=bk, n_k=n_k)
     kv_map = _kv_index_map(causal, bq, bk)
+    out_shape = [
+        jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
+        jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
+    ]
     with jax.named_scope("flash_fwd"):
         out, lse = pl.pallas_call(
             kernel,
@@ -146,10 +167,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                 pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
                 pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-                jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
-            ],
+            out_shape=out_shape,
             scratch_shapes=[
                 pltpu.VMEM((bq, d), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
@@ -157,6 +175,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
+            cost_estimate=_flash_cost([qf, kf, vf] + out_shape, n_q, n_k, bq,
+                                      bk, causal, matmuls=2),
             interpret=interpret,
             name="flash_fwd",
         )(qf, kf, vf)
@@ -261,6 +281,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
     lse3 = lse.reshape(b * h, t_q, 1)
     delta3 = delta.reshape(b * h, t_q, 1)
 
+    operands = [qf, kf, vf, dof, lse3, delta3]
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
                                   scale=scale, block_q=bq, block_k=bk,
                                   n_k=n_k)
@@ -282,9 +303,11 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
+            cost_estimate=_flash_cost(operands + [qf], n_q, n_k, bq, bk,
+                                      causal, matmuls=3),
             interpret=interpret,
             name="flash_bwd_dq",
-        )(qf, kf, vf, dof, lse3, delta3)
+        )(*operands)
 
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                                    scale=scale, block_q=bq, block_k=bk,
@@ -316,9 +339,11 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
+            cost_estimate=_flash_cost(operands + [kf, vf], n_q, n_k, bq, bk,
+                                      causal, matmuls=4),
             interpret=interpret,
             name="flash_bwd_dkv",
-        )(qf, kf, vf, dof, lse3, delta3)
+        )(*operands)
 
     return (dq.reshape(b, h, t_q, d), dk.reshape(b, h, t_k, d),
             dv.reshape(b, h, t_k, d))

@@ -37,7 +37,11 @@ reads which):
         descendants is the host's share of the step, with the device idle;
         `.finish` carries its request's `request_id`
 
-Counters: `xla_compiles{fn=<name>}`.  Serving counts stay in `ServeMetrics`.
+Counters: `xla_compiles{fn=<name>}`; `pallas_calls{kernel=<name>,
+row_shards=<k>}`, one per Pallas kernel call of a program emitted for a mesh
+(jaxfront/api.py::_pallas_row_axes): `k` is the number of shards its rows
+were split into, 1 for a call every device runs whole.  Serving counts stay
+in `ServeMetrics`.
 Requests: the timeline `GenerationSession` gives every finished request
 (`docs/SERVING.md`, "Observability").
 """

@@ -7,9 +7,9 @@ Shapes: the ones chip_smoke.py uses (GPT-2 small: 12 heads of 64, bf16,
 64-token pages) and a GQA shape (32 query heads over 8 kv heads of 128);
 the paged kernels also at the chat cell's own shape (32 slots, 32 windows)
 and at a wide MHA shape whose pages crowd the VMEM budget.
-Also a kernel inside a program emitted for a (2, 2) mesh, and the int8
-paged kernel against its XLA reference under the interpreter, which no
-other test compares."""
+Also a kernel inside a program emitted for a (2, 2) mesh, whole and with
+its rows sharded, and the int8 paged kernel against its XLA reference under
+the interpreter, which no other test compares."""
 
 import jax
 import jax.numpy as jnp
@@ -189,3 +189,39 @@ def test_kernel_inside_a_sharded_program_lowers(monkeypatch, cpu_devices):
     result = compile_step(step, (state, tokens, tokens), {}, mesh=mesh)
     assert "pallas_call" in str(result.closed_jaxpr)
     result.jitted.trace(*result.in_avals).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("batch,local_rows", [
+    pytest.param(4, 5, id="rows-over-both-axes"),
+    pytest.param(2, 5, id="rows-over-dp-only"),
+    pytest.param(1, 5, id="rows-whole")])
+def test_row_sharded_kernels_lower(monkeypatch, cpu_devices, batch,
+                                   local_rows):
+    """The flash kernels re-bound at their shard's row count (5 heads x
+    `batch` rows over a (2, 2) mesh: 20 / 4, 10 / 2, and 5 left whole,
+    which neither axis divides) pass Pallas' TPU lowering, and the lowered
+    module holds them at that extent."""
+    import importlib
+    import re
+
+    from easydist_tpu.jaxfront import make_device_mesh
+    from easydist_tpu.jaxfront.api import compile_step
+    from easydist_tpu.models import GPTConfig, make_gpt_train_step
+
+    monkeypatch.setattr(
+        importlib.import_module("easydist_tpu.ops.flash_attention"),
+        "_default_interpret", lambda: False)
+    mesh = make_device_mesh((2, 2), ("dp", "tp"), devices=cpu_devices[:4])
+    cfg = GPTConfig(vocab=512, seq=128, dim=320, heads=5, layers=1,
+                    dtype="bfloat16", attention="flash")
+    step, init_state = make_gpt_train_step(cfg)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    tokens = _aval((batch, cfg.seq), jnp.int32)
+    result = compile_step(step, (state, tokens, tokens), {}, mesh=mesh)
+    text = result.jitted.trace(*result.in_avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    for line in calls:
+        shapes = re.findall(r"tensor<(\d+)x128x(?:64|1)x", line)
+        assert shapes and set(shapes) == {str(local_rows)}, line
