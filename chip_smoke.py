@@ -537,6 +537,91 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None,
     return notes
 
 
+# a small Granite 4.0-H: one period [mamba, attention, mamba], 8 experts
+# top-2 of which 4 are held; widths the TPU kernels tile (d_state 128, expert
+# widths in 128s), far under the cell's
+HYBRID_SMALL = dict(
+    hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+    attention_multiplier=1.0 / 64, mamba_n_heads=8, mamba_d_head=64,
+    mamba_d_state=128, mamba_d_conv=4, mamba_expand=2, mamba_n_groups=1,
+    mamba_chunk_size=64, router_experts=8, experts_held=[0, 4],
+    num_local_experts=4, num_experts_per_tok=2, intermediate_size=128,
+    shared_intermediate_size=256,
+    layer_types=["mamba", "attention", "mamba"], num_hidden_layers=3,
+    vocab_size=512, embedding_multiplier=12, residual_multiplier=0.22,
+    logits_scaling=16, rms_norm_eps=1e-5)
+
+
+def phase_hybrid(sizes=None, device=None):
+    """A model with state layers through `GenerationSession`: chunked
+    prefill and decode through the page arena AND the state pool (the
+    state-update and grouped-matmul kernels compile natively here), every
+    served token teacher-forced against the benchmark's plain reference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import weights_granite
+    from chipbench.reference import granite_hybrid as reference
+    from chipbench.runners.serve_hybrid import model_config
+    from easydist_tpu.jaxfront import make_device_mesh
+    from easydist_tpu.models import granite_hybrid
+    from easydist_tpu.serve import GenerationSession, ServeConfig
+
+    sizes = sizes or HYBRID_SMALL
+    device = device or jax.devices()[0]
+    params = weights_granite.granite_params(sizes,
+                                            weights_granite.seed_key(5))
+    bucket, chunk = 256, 64
+    sess = GenerationSession(
+        params, model=granite_hybrid.decoder(model_config(sizes)),
+        config=ServeConfig(kv_layout="paged", decode_buckets=(bucket,),
+                           max_decode_slots=8, prefill_chunk=chunk,
+                           prefill_batch=2, enable_prefix_cache=False,
+                           speculate_k=0),
+        mesh=make_device_mesh((1,), ("d",), devices=[device]))
+    rng = np.random.default_rng(5)
+    reqs = [(rng.integers(1, sizes["vocab_size"], size=n).tolist(), m)
+            for n, m in ((5, 6), (chunk, 9), (chunk + 3, 12),
+                         (2 * chunk + 17, 8), (33, 5))]
+    t0 = time.perf_counter()
+    futs = [sess.submit(p, max_new_tokens=m) for p, m in reqs]
+    sess.run_until_drained(max_steps=4 * bucket)
+    wall = time.perf_counter() - t0
+    worst, spread, exact, total = 0.0, 0.0, 0, 0
+    for (prompt, max_new), fut in zip(reqs, futs):
+        res = fut.result(timeout=0)
+        assert (len(res["ids"]), res["finish_reason"]) == (max_new, "length")
+        seq = np.zeros((bucket,), np.int32)
+        seq[:len(prompt) + max_new] = prompt + res["ids"]
+        logits = np.asarray(reference.logits(params, sizes,
+                                             jnp.asarray(seq)))
+        for i, tok in enumerate(res["ids"]):
+            row = logits[len(prompt) + i - 1]
+            worst = max(worst, float(row.max() - row[tok]))
+            spread = max(spread, float(row.std()))
+            exact += int(row.argmax() == tok)
+            total += 1
+    pool = next(iter(sess._pools.values()))
+    assert pool.state.in_use == 0 and pool.pool.in_use == 0
+    counters = sess.metrics.snapshot()["counters"]
+    assert counters["moe_pairs_routed"] > 0
+    assert worst <= 0.5 * spread and exact >= MIN_EXACT_MATCH * total, (
+        f"hybrid: a served token sits {worst:.5f} below the float32 "
+        f"reference's best logit (a row's spread is {spread:.5f}); "
+        f"{exact}/{total} are its argmax")
+    sess.close()
+    notes = {"requests": len(reqs), "tokens": total,
+             "exact_argmax": f"{exact}/{total}",
+             "worst_logit_deficit": round(worst, 6),
+             "logit_spread": round(spread, 6),
+             "moe_pairs_routed": counters["moe_pairs_routed"],
+             "wall_s_incl_compile": round(wall, 1)}
+    log(f"PASS hybrid: state pool beside the arena, {exact}/{total} tokens "
+        f"are the float32 argmax; notes {json.dumps(notes)}")
+    return notes
+
+
 # ----------------------------------------------------------------- main
 
 
@@ -606,6 +691,8 @@ def main() -> int:
     notes["arena_cell_widths"] = phase_server(
         "paged", cell_params, cfg_kw=CELL_2_LAYERS, config_kw=CELL_SERVE,
         family="llama", in_place=True)
+
+    notes["hybrid"] = phase_hybrid()
 
     summary = {
         "ok": True, "device": device, "versions": versions,

@@ -1,0 +1,17 @@
+"""The state layers' decode update's share of the device's busy time in
+the traced part: chip 0's seconds in the state-update kernel (found by its
+result, the float32 state leaf: `hybrid_trace.py`) over its busy seconds.
+The chunked scan of a prefill call is XLA einsums with no name of their own
+in the reduced trace, and is not in this number (PERF.md section 7)."""
+
+from chipbench import hybrid_trace
+
+META = {"layer": "kernels", "unit": "%", "moves": "token_gap_p95_ms",
+        "source": "device_trace"}
+
+
+def read(run):
+    secs = hybrid_trace.seconds(run, hybrid_trace.STATE_UPDATE)
+    if secs is None or not run.get("busy"):
+        return None
+    return 100.0 * secs / run["busy"]["per_chip_s"][0]

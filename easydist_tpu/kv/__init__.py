@@ -34,6 +34,9 @@ storage too:
     every leaf, stacked), which is what the trie, the host tier and the
     fleet transport hold, hash and ship.
 
+  * `state.StatePool` — the slots of a model's recurrent-state layers
+    (one per live sequence, beside its pages in the same pool).
+
 Analyze rule KV001 (`analyze/kv_rules.py`) audits the pool/table/trie
 bookkeeping; `check_invariants` here is the raw audit it wraps.
 """
@@ -41,10 +44,11 @@ bookkeeping; `check_invariants` here is the raw audit it wraps.
 from __future__ import annotations
 
 from .pool import PagePool
+from .state import StatePool
 from .table import PageTable
 from .tier import HostTier, TierError
 
-__all__ = ["HostTier", "PagePool", "PageTable", "TierError",
+__all__ = ["HostTier", "PagePool", "PageTable", "StatePool", "TierError",
            "is_host_ref", "is_page_ref"]
 
 

@@ -12,8 +12,12 @@ through a page table; the int8 arena lives here and nowhere else).
 
 Every step is a pure function returning the updated cache first, so a jit
 with the cache as argument 0 donates it (`serve/generation.py`).  There is
-one kind of layer and no table of models: a model whose layers differ
-branches inside the functions it supplies, all of which receive the block.
+no table of models: a model whose layers differ branches inside the
+functions it supplies, all of which receive the block.  The loop knows two
+KINDS of layer, by what they cache: "attention" (K/V rows per position,
+through `Contiguous` or `Paged`) and "state" (a recurrent state per
+SEQUENCE, through the third adapter, `State`); a model with state layers
+says which is which in `kinds`.
 
 K and V are cached as the model's `qkv` returns them — positions already
 applied (roped keys), at kv_heads granularity; the GQA repeat happens at
@@ -23,7 +27,7 @@ attention time, so cache bytes scale with kv_heads.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +35,8 @@ import jax.numpy as jnp
 from easydist_tpu.kv.arena import (init_page_arena, write_chunk, write_row,
                                    write_rows)
 
-__all__ = ["Decoder", "Contiguous", "Paged", "chunk", "verify", "decode",
-           "split_heads"]
+__all__ = ["Decoder", "Contiguous", "Paged", "State", "chunk", "verify",
+           "decode", "split_heads"]
 
 
 @dataclass(frozen=True)
@@ -53,6 +57,24 @@ class Decoder:
     ffn: Callable         # (block, x) -> x
     final_norm: Callable  # (params, x) -> x
     unembed: Callable     # (params, x) -> float32 logits [..., vocab]
+    # A model with state layers fills in the three below; `valid` (bool,
+    # the leading shape of x) marks the rows and positions that are real,
+    # and such a model's `ffn` takes it too and returns (x, int32 counters
+    # [n]), which the loop sums over the layers (`State.counters`).
+    kinds: Optional[Tuple[str, ...]] = None   # "attention" | "state" a block
+    state: Optional[Callable] = None  # (block, x, carry, valid) -> x, carry
+    state_shapes: Optional[Dict[str, tuple]] = None  # name -> (shape, dtype)
+    #                                                  of ONE sequence's carry
+
+    @property
+    def kv_layers(self) -> int:
+        """The layers that cache K/V: the leaves of an arena."""
+        return self.layers if self.kinds is None \
+            else self.kinds.count("attention")
+
+    @property
+    def state_layers(self) -> int:
+        return 0 if self.kinds is None else self.kinds.count("state")
 
 
 def split_heads(y, n: int):
@@ -100,7 +122,7 @@ class Contiguous:
             raise ValueError(
                 f"max_len {max_len} exceeds the learned position table "
                 f"(cfg.seq={dec.max_positions})")
-        shape = (dec.layers, batch, dec.kv_heads, max_len, dec.head_dim)
+        shape = (dec.kv_layers, batch, dec.kv_heads, max_len, dec.head_dim)
         dt = _storage_dtype(dec, dtype)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
@@ -162,7 +184,7 @@ class Paged:
              quant_dtype=None, quant_block: int = 0):
         """Zeroed arena; `quant_dtype="int8"` adds the scale leaves
         (`quant_block` 0 = one block per row)."""
-        return init_page_arena(dec.layers, n_pages, dec.kv_heads,
+        return init_page_arena(dec.kv_layers, n_pages, dec.kv_heads,
                                page_tokens, dec.head_dim,
                                _storage_dtype(dec, dtype), quant_dtype,
                                quant_block)
@@ -245,21 +267,96 @@ class Paged:
         return {key: tuple(leaves) for key, leaves in self._new.items()}
 
 
+class State:
+    """The recurrent state of a model's state layers, {name: (a leaf per
+    STATE layer)} with each leaf [n_slots, *shape of one sequence's carry]
+    (`Decoder.state_shapes`): a slot per live sequence, whatever its
+    length.  Like an arena leaf, each is a buffer of its own, donated and
+    written in place.
+
+    `slots` (int32 [rows]) says which slot each row of the call is, and
+    `n_slots` marks a row that is none (reads clip, writes drop); `None`
+    means the rows ARE the slots, in order — a decode round — and then a
+    layer's carry is the leaf itself and the model's update of it must
+    leave dead rows as they were, which is what `valid` is for.  `live`
+    (bool [rows]) marks the rows that are sequences; `fresh` (bool [rows])
+    the rows that start from zero state (a prompt's first chunk)."""
+
+    @staticmethod
+    def init(dec: Decoder, n_slots: int):
+        return {name: tuple(jnp.zeros((n_slots,) + tuple(shape), dt)
+                            for _ in range(dec.state_layers))
+                for name, (shape, dt) in dec.state_shapes.items()}
+
+    @staticmethod
+    def split(dec: Decoder, cache):
+        """A stateful model's one donated pytree -> (the arena's keys, the
+        state's keys)."""
+        return ({k: v for k, v in cache.items()
+                 if k not in dec.state_shapes},
+                {k: cache[k] for k in dec.state_shapes})
+
+    def __init__(self, state, live, slots=None, fresh=None):
+        self._old, self.live = state, live
+        self._slots, self._fresh = slots, fresh
+        self._new = {name: [] for name in state}
+        self.counters = None      # the ffns' counters, summed over layers
+
+    def read(self):
+        li = len(next(iter(self._new.values())))
+        carry = {name: leaves[li] for name, leaves in self._old.items()}
+        if self._slots is None:
+            return carry
+        carry = {name: jnp.take(leaf, self._slots, axis=0, mode="clip")
+                 for name, leaf in carry.items()}
+        if self._fresh is not None:
+            carry = {name: jnp.where(
+                self._fresh.reshape((-1,) + (1,) * (c.ndim - 1)),
+                jnp.zeros_like(c), c) for name, c in carry.items()}
+        return carry
+
+    def write(self, carry):
+        for name, new in self._new.items():
+            li = len(new)
+            if self._slots is None:
+                new.append(carry[name])
+            else:
+                new.append(self._old[name][li].at[self._slots].set(
+                    carry[name], mode="drop"))
+
+    def cache(self):
+        return {name: tuple(leaves) for name, leaves in self._new.items()}
+
+
 # ------------------------------------------------------------------- steps
 
 
-def _forward(dec: Decoder, kv, params, tokens, pos):
-    """Embed, run every layer against `kv`, final norm: (cache, x)."""
+def _forward(dec: Decoder, kv, params, tokens, pos, st=None, valid=None):
+    """Embed, run every layer against `kv` (and `st`, the `State` of a
+    model with state layers), final norm: (cache, x)."""
     x = dec.embed(params, tokens, pos)
-    for blk in dec.blocks(params):
-        q, k, v = dec.qkv(blk, x, pos)
-        kv.write(k, v)
-        x = dec.attn_out(blk, x, _merge_heads(kv.attend(dec, q, pos)))
-        x = dec.ffn(blk, x)
-    return kv.cache(), dec.final_norm(params, x)
+    counters = []
+    for kind, blk in zip(dec.kinds or ("attention",) * dec.layers,
+                         dec.blocks(params)):
+        if kind == "state":
+            x, carry = dec.state(blk, x, st.read(), valid)
+            st.write(carry)
+        else:
+            q, k, v = dec.qkv(blk, x, pos)
+            kv.write(k, v)
+            x = dec.attn_out(blk, x, _merge_heads(kv.attend(dec, q, pos)))
+        if st is None:
+            x = dec.ffn(blk, x)
+        else:
+            x, c = dec.ffn(blk, x, valid)
+            counters.append(c)
+    if st is None:
+        return kv.cache(), dec.final_norm(params, x)
+    st.counters = sum(counters[1:], counters[0])
+    return {**kv.cache(), **st.cache()}, dec.final_norm(params, x)
 
 
-def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths):
+def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths, state=None):
     """One fixed-size prefill chunk: `tokens` (int32 [batch, chunk]) at
     absolute positions `start_pos + [0..chunk)`; attention covers the FULL
     cache window masked to `key_pos <= query_pos`, so the traced shape does
@@ -267,11 +364,14 @@ def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths):
     consumed as if recomputed.  Returns (cache, logits [batch, vocab]) at
     each row's last real position (`lengths - 1`): valid for rows whose
     chunk contains it, garbage nobody reads otherwise.  Against `Paged` a
-    chunk fills exactly one page."""
+    chunk fills exactly one page.  With `state` (a `State`), positions at
+    or past a row's length leave its state untouched."""
     c_len = tokens.shape[1]
     start = start_pos.astype(jnp.int32)
-    cache, x = _forward(dec, kv, params, tokens,
-                        kv.seek(start, c_len, aligned=True))
+    pos = kv.seek(start, c_len, aligned=True)
+    valid = None if state is None else \
+        state.live[:, None] & (pos < lengths.astype(jnp.int32)[:, None])
+    cache, x = _forward(dec, kv, params, tokens, pos, state, valid)
     rel_last = jnp.clip(lengths.astype(jnp.int32) - 1 - start, 0, c_len - 1)
     last = jnp.take_along_axis(x, rel_last[:, None, None], axis=1)[:, 0]
     return cache, dec.unembed(params, last)
@@ -284,16 +384,20 @@ def verify(dec: Decoder, kv, params, tokens, pos):
     Position i's logits equal what `decode` would give after feeding the
     first i tokens; rows written past the accepted prefix are the stale
     rows the position mask keeps out of every later step.  Callers
-    guarantee pos + s fits the cache (every touched page mapped)."""
+    guarantee pos + s fits the cache (every touched page mapped).  Not for
+    a model with state layers: a state has no position mask to hide a
+    rejected draft behind."""
     cache, x = _forward(dec, kv, params, tokens,
                         kv.seek(pos.astype(jnp.int32), tokens.shape[1]))
     return cache, dec.unembed(params, x)
 
 
-def decode(dec: Decoder, kv, params, token, pos):
+def decode(dec: Decoder, kv, params, token, pos, state=None):
     """One cached decode step: `token` (int32 [batch]) at position `pos`
     (int32 [batch], the row's current length) -> (cache, logits
-    [batch, vocab]).  O(layers * pos) attention reads a token."""
+    [batch, vocab]).  O(layers * pos) attention reads a token.  With
+    `state`, rows that are not `state.live` leave their state untouched."""
     cache, x = _forward(dec, kv, params, token,
-                        kv.seek(pos.astype(jnp.int32)))
+                        kv.seek(pos.astype(jnp.int32)), state,
+                        None if state is None else state.live)
     return cache, dec.unembed(params, x)

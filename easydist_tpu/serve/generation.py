@@ -65,7 +65,8 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from easydist_tpu.kv import PagePool, PageTable, is_host_ref, is_page_ref
+from easydist_tpu.kv import (PagePool, PageTable, StatePool, is_host_ref,
+                             is_page_ref)
 from easydist_tpu.kv.tier import HostTier, TierError
 from easydist_tpu.resilience import faultinject
 from easydist_tpu.runtime import spans
@@ -207,7 +208,8 @@ class _PagedPool:
                  n_rows: int, chunk: int, prefix_bytes: int,
                  n_pages: int, host_tier_bytes: int = 0,
                  export_page: Optional[Callable] = None,
-                 model_itemsize: int = 0):
+                 model_itemsize: int = 0,
+                 init_state: Optional[Callable] = None):
         self.bucket = bucket
         self.n_slots = n_slots
         self.chunk = chunk                       # page_tokens
@@ -219,17 +221,25 @@ class _PagedPool:
         self.n_rows = n_rows
         # {"k": (one leaf per layer), "v": (...)[, "k_scale", "v_scale"]}
         # — kv/arena.py; every leaf is donated to each compiled step
-        self.arena = init_pages(n_pages, chunk)
+        pages = init_pages(n_pages, chunk)
+        self.arena = pages
+        # a model with state layers: its recurrent state lives in the same
+        # donated pytree, a slot per sequence (kv/state.py) — the slot a
+        # request is admitted into is its table row AND its state row
+        self.state: Optional[StatePool] = None
+        if init_state is not None:
+            self.state = StatePool(n_slots)
+            self.arena = {**pages, **init_state(n_slots)}
         # size pages from the arena's STORAGE leaves — quantized arenas
         # charge int8 payload + f32 scales, not the model dtype, which is
         # exactly the density win the kv_quant_bytes_saved gauge reports
         self.page_bytes = sum(int(leaf.nbytes) // n_pages
-                              for leaves in self.arena.values()
+                              for leaves in pages.values()
                               for leaf in leaves)
         # what one page's k/v payload would cost at model precision —
         # the baseline the quant-savings gauge subtracts from
         payload_elems = sum(int(leaf.size) // n_pages
-                            for k in ("k", "v") for leaf in self.arena[k])
+                            for k in ("k", "v") for leaf in pages[k])
         self.model_page_bytes = payload_elems * model_itemsize \
             if model_itemsize else self.page_bytes
         self.pool = PagePool(n_pages, chunk, page_bytes=self.page_bytes)
@@ -341,6 +351,22 @@ class _PagedPool:
                     tokens += self.chunk
         return self.pool.in_use, tokens
 
+    def take_slot(self) -> int:
+        """A free slot: its table row and, for a model with state layers,
+        its state row (zeroed by the first chunk, which starts fresh)."""
+        slot_idx = self.free.pop()
+        if self.state is not None:
+            self.state.take(slot_idx)
+        return slot_idx
+
+    def give_slot(self, slot_idx: int) -> List[int]:
+        """Retirement: the slot, its state row and its pages go back
+        together; returns the page ids the table row held."""
+        self.free.append(slot_idx)
+        if self.state is not None:
+            self.state.release(slot_idx)
+        return self.table.unmap_row(slot_idx)
+
 
 class GenerationSession:
     """Continuous-batching token generation over a cache-carrying model.
@@ -386,6 +412,9 @@ class GenerationSession:
                     f"decode_buckets {bad} exceed the model's maximum "
                     f"sequence length {model.max_positions}; set "
                     f"ServeConfig(decode_buckets=...) within it")
+        self._stateful = bool(model.state_layers)
+        if self._stateful:
+            self._refuse_for_state_layers(self.config)
         self._model = model
         self.params = params
         self.mesh = mesh
@@ -522,10 +551,45 @@ class GenerationSession:
                                    tokens, pos)
             return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+        # a model with state layers: the donated pytree is {arena leaves of
+        # the attention layers, state leaves of the state layers}; `slots`
+        # names each chunk row's state slot, `live` the decode rows that
+        # are sequences.  The round's expert counters ride the token
+        # readback (`out[n_slots:]`), so there is one readback still.
+        def _ids_and_counters(logits, st):
+            import jax.numpy as jnp
+
+            return jnp.concatenate(
+                [jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                 st.counters.astype(jnp.int32)])
+
+        def _prefill_chunk_paged_state(cache, params, table, slots, tokens,
+                                       start, lengths):
+            from easydist_tpu.models.decoder import State
+
+            pages, leaves = State.split(model, cache)
+            n_slots = next(iter(leaves.values()))[0].shape[0]
+            st = State(leaves, slots < n_slots, slots, fresh=start == 0)
+            cache, logits = chunk(model, Paged(pages, table), params,
+                                  tokens, start, lengths, state=st)
+            return cache, _ids_and_counters(logits, st)
+
+        def _decode_paged_state(cache, params, table, live, token, pos):
+            from easydist_tpu.models.decoder import State
+
+            pages, leaves = State.split(model, cache)
+            st = State(leaves, live)
+            cache, logits = decode(model, Paged(pages, table), params,
+                                   token, pos, state=st)
+            return cache, _ids_and_counters(logits, st)
+
         self._paged_defs = {
             "chunk": _prefill_chunk_paged, "decode": _decode_paged,
             "export": _page_export, "import": _page_import,
             "verify": _verify_paged}
+        if self._stateful:
+            self._paged_defs.update(chunk_state=_prefill_chunk_paged_state,
+                                    decode_state=_decode_paged_state)
 
         # pool/staging is arg 0 and output 0 of every mutating compiled
         # callable, so state_io="auto" pairs it and XLA gets the buffer
@@ -559,6 +623,33 @@ class GenerationSession:
         (self._prefill_chunk_c, self._restore_c,
          self._migrate_c, self._decode_c, self._extract_cs,
          self._paged_cs, self._verify_cs) = shared
+
+    @staticmethod
+    def _refuse_for_state_layers(cfg: ServeConfig) -> None:
+        """A state layer caches one state a sequence, not rows a position:
+        what assumes rows is refused here, loudly, until it is built."""
+        why = None
+        if cfg.kv_layout != "paged":
+            why = ("kv_layout='bucketed': the state pool lives beside the "
+                   "page pool; set kv_layout='paged'")
+        elif cfg.kv_host_tier_bytes:
+            why = ("the host tier: it demotes trie pages, and the trie is "
+                   "refused too; set kv_host_tier_bytes=0")
+        elif cfg.enable_prefix_cache and cfg.prefix_cache_bytes:
+            why = ("the prefix trie: a restored prefix needs the state as "
+                   "it was at that chunk's boundary, and no snapshot is "
+                   "kept; set enable_prefix_cache=False")
+        elif cfg.speculate_k:
+            why = ("speculation: a rejected draft cannot be masked out of "
+                   "a recurrent state, and there is no roll-back; set "
+                   "speculate_k=0")
+        elif cfg.kv_quant_dtype not in (None, "none"):
+            why = ("the int8 arena: not measured against a model whose "
+                   "logits also ride a float32 state; set "
+                   "kv_quant_dtype='none'")
+        if why:
+            raise ValueError(f"a model with state layers cannot be served "
+                             f"with {why}")
 
     def _extract_for(self, chunk_len: int) -> Callable:
         """Compiled chunk extractor for one chunk size (the slice size
@@ -671,7 +762,9 @@ class GenerationSession:
                     n_pages=n_pages,
                     host_tier_bytes=cfg.kv_host_tier_bytes,
                     export_page=self._export_arena_page,
-                    model_itemsize=self._model_itemsize())
+                    model_itemsize=self._model_itemsize(),
+                    init_state=self._state_factory
+                    if self._stateful else None)
             else:
                 pool = _BucketPool(
                     bucket, cfg.max_decode_slots, self._cache_factory,
@@ -695,6 +788,11 @@ class GenerationSession:
         return Paged.init(self._model, n_pages, page_tokens,
                           cfg.kv_cache_dtype, cfg.kv_quant_dtype,
                           cfg.kv_quant_block)
+
+    def _state_factory(self, n_slots: int):
+        from easydist_tpu.models.decoder import State
+
+        return State.init(self._model, n_slots)
 
     def _model_itemsize(self) -> int:
         """Bytes per element at model precision (first param leaf) — the
@@ -811,7 +909,7 @@ class GenerationSession:
             if pool.trie is not None:
                 pool.trie.unpin(nodes)
             return True  # cancelled while queued; nothing reserved yet
-        slot_idx = pool.free.pop()
+        slot_idx = pool.take_slot()
         row = pool.free_rows.pop()
         # zero-copy restore: the slot's leading windows point at the
         # trie's pages (shared, read-only by construction — writes only
@@ -941,10 +1039,17 @@ class GenerationSession:
                     start[row] = job.start
                     lengths[row] = len(job.prompt)
                     tbl[row] = pool.table.array[job.slot_idx]
-                args = (pool.arena, self.params, jnp.asarray(tbl),
+                program, extra = "chunk", ()
+                if self._stateful:   # each row's state slot, too
+                    slots = np.full((pool.n_rows,), pool.state.sentinel,
+                                    np.int32)
+                    for row, job in pool.jobs.items():
+                        slots[row] = job.slot_idx
+                    program, extra = "chunk_state", (jnp.asarray(slots),)
+                args = (pool.arena, self.params, jnp.asarray(tbl), *extra,
                         jnp.asarray(tokens), jnp.asarray(start),
                         jnp.asarray(lengths))
-                result = self._paged_c("chunk").get_compiled(*args)
+                result = self._paged_c(program).get_compiled(*args)
                 if pool.bucket not in self._audited_prefill:
                     self._audited_prefill.add(pool.bucket)
                     # SERVE002's jaxpr walk asserts the bucketed staging
@@ -965,6 +1070,8 @@ class GenerationSession:
                 rows=pool.n_rows, chunk=c_len)
             self.metrics.record_prefill_chunk(pool.n_rows, c_len,
                                               sp.seconds)
+            if len(first) > pool.n_rows:   # the call's expert counters
+                self.metrics.record_moe("prefill", *first[pool.n_rows:])
             calls += 1
             self._advance_jobs(pool, first, self._finish_prefill_paged)
         return calls
@@ -1063,14 +1170,15 @@ class GenerationSession:
     # ------------------------------------------------------------- decoding
     def _retire(self, pool, slot_idx: int, reason: str) -> None:
         slot = pool.slots.pop(slot_idx)
-        pool.free.append(slot_idx)
         if self._drafter is not None:
             self._drafter.forget(slot.request_id)
             self._spec_ewma.pop(slot.request_id, None)
             self._spec_idle.pop(slot.request_id, None)
         if self._paged:
-            for pid in pool.table.unmap_row(slot_idx):
+            for pid in pool.give_slot(slot_idx):
                 pool.pool.release(pid)
+        else:
+            pool.free.append(slot_idx)
         if pool.trie is not None and slot.pinned:
             pool.trie.unpin(slot.pinned)
         if self._paged:
@@ -1123,9 +1231,14 @@ class GenerationSession:
                               pool.pool.sentinel, np.int32)
                 for idx in live:
                     tbl[idx] = pool.table.array[idx]
-                args = (pool.arena, self.params, jnp.asarray(tbl),
+                program, extra = "decode", ()
+                if self._stateful:   # which rows are sequences, too
+                    alive = np.zeros((pool.n_slots,), bool)
+                    alive[live] = True
+                    program, extra = "decode_state", (jnp.asarray(alive),)
+                args = (pool.arena, self.params, jnp.asarray(tbl), *extra,
                         jnp.asarray(token), jnp.asarray(pos))
-                compiled = self._paged_c("decode")
+                compiled = self._paged_c(program)
             else:
                 args = (pool.cache, self.params, jnp.asarray(token),
                         jnp.asarray(pos))
@@ -1156,6 +1269,8 @@ class GenerationSession:
                 self._maybe_retire(pool, idx)
             self.metrics.record_decode_step(len(live), pool.n_slots,
                                             sp.seconds)
+            if len(nxt) > pool.n_slots:   # the round's expert counters
+                self.metrics.record_moe("decode", *nxt[pool.n_slots:])
             if self._paged:
                 self._record_kv_pool(pool)
 
@@ -1165,6 +1280,9 @@ class GenerationSession:
             in_use, held, pool.chunk,
             quant_bytes_saved=(pool.model_page_bytes
                                - pool.page_bytes) * in_use)
+        if pool.state is not None:
+            self.metrics.record_state_pool(pool.state.in_use,
+                                           pool.state.n_slots)
 
     # ------------------------------------------------ speculative decoding
     def _spec_round(self, pool) -> bool:
@@ -1471,6 +1589,9 @@ class GenerationSession:
                 check_tier_roundtrip(pool.tier, node=f"kv.tier[{where}]")
         except ImportError:  # analyze is an optional layer at runtime
             pass
+        if pool.state is not None:
+            pool.state.check_invariants(
+                list(pool.slots) + [j.slot_idx for j in pool.jobs.values()])
 
     def _audit_quant_program(self, result, where: str) -> None:
         """KVQ002: the compiled quant step must never feed int8 K/V into
@@ -1744,10 +1865,11 @@ class GenerationSession:
             for row in list(pool.jobs):
                 job = pool.jobs.pop(row)
                 pool.free_rows.append(row)
-                pool.free.append(job.slot_idx)
                 if self._paged:
-                    for pid in pool.table.unmap_row(job.slot_idx):
+                    for pid in pool.give_slot(job.slot_idx):
                         pool.pool.release(pid)
+                else:
+                    pool.free.append(job.slot_idx)
                 if pool.trie is not None:
                     pool.trie.unpin(job.prefix_nodes)
                 job.future.set_result(

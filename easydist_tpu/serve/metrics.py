@@ -82,6 +82,11 @@ class ServeMetrics:
       draft_tokens_proposed / draft_tokens_accepted,
       speculative_rollback_pages_released (paged rollback returns);
       gauge acceptance_rate (lifetime accepted / proposed).
+    Expert-FFN counters (a model whose ffn routes; summed over layers):
+      per decode round moe_rounds, moe_pairs_routed, moe_experts_hit,
+      moe_max_expert_pairs; per chunk call the same under moe_prefill_*
+      (moe_prefill_calls).  Gauges state_slots_in_use / state_slots: the
+      recurrent-state pool beside kv_pages_in_use.
     Gauges: decode_slot_occupancy (active slots / total slots at the last
       decode step), prefill_padding_ratio (executed token slots per real
       prefill token, 1.0 = zero waste), prefix_cache_hit_rate (fraction
@@ -235,6 +240,31 @@ class ServeMetrics:
                 (mapped_tokens / cap) if cap else 1.0
             if quant_bytes_saved is not None:
                 self._gauges["kv_quant_bytes_saved"] = quant_bytes_saved
+
+    def record_state_pool(self, slots_in_use: int, n_slots: int) -> None:
+        """Recurrent-state pool occupancy (a model with state layers): a
+        slot per admitted sequence, prefilling or decoding."""
+        with self._lock:
+            self._gauges["state_slots_in_use"] = slots_in_use
+            self._gauges["state_slots"] = n_slots
+
+    def record_moe(self, step: str, pairs_routed: int, experts_hit: int,
+                   max_expert_pairs: int) -> None:
+        """One program's expert routing, summed over the layers on the
+        device and read back with the program's tokens: (token, expert)
+        pairs routed to held experts, held experts that got at least one,
+        and the busiest held expert's pairs.  `step` is "decode" (a
+        round: `moe_rounds`, `moe_pairs_routed`, ...) or "prefill" (a
+        chunk call: `moe_prefill_calls`, `moe_prefill_pairs_routed`,
+        ...)."""
+        pre = "moe_" if step == "decode" else f"moe_{step}_"
+        calls = "moe_rounds" if step == "decode" else f"moe_{step}_calls"
+        with self._lock:
+            for name, n in ((pre + "pairs_routed", pairs_routed),
+                            (pre + "experts_hit", experts_hit),
+                            (pre + "max_expert_pairs", max_expert_pairs),
+                            (calls, 1)):
+                self._counters[name] = self._counters.get(name, 0) + int(n)
 
     def record_copy_on_restore_saved(self, nbytes: int) -> None:
         """A prefix restore mapped `nbytes` of committed pages into a

@@ -257,3 +257,67 @@ def test_page_wire_format_is_the_stack_of_leaf_pages(model, quant):
                 np.delete(np.asarray(leaf), dst, axis=0),
                 np.delete(before[key][li], dst, axis=0))
     sess.close()
+
+
+def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
+        v5e_chip, monkeypatch):
+    """The Granite 4.0-H cell's decode round (BENCHMARK.json: published
+    widths, 64 state slots, 36 held experts, pages of 256 tokens), one
+    Mamba-2 and one attention layer, with its three Pallas kernels: the
+    arena's two leaves AND the state's two are donated and handed back
+    through writes in place — the SSM leaf (268 MB) by the state-update
+    kernel's own alias — so the temporaries stay far under one SSM leaf."""
+    import functools
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.models import granite_hybrid as gh
+    from easydist_tpu.models.decoder import Paged, State, decode
+    from easydist_tpu.ops import grouped_matmul as gm
+    from easydist_tpu.ops import ssm
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(ssm, "ssm_decode_update", functools.partial(
+        ssm.ssm_decode_update, backend="pallas", interpret=False))
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, backend="pallas", interpret=False))
+    cfg = gh.GraniteHybridConfig(vocab=50176,
+                                 layer_types=("mamba", "attention"),
+                                 experts_held=(0, 36))
+    dec = gh.decoder(cfg)
+    slots, n_pages, pt, max_pages = 64, 768, 256, 16
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: gh.granite_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_chip)
+    table = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
+                                 sharding=v5e_chip)
+
+    def step(cache, params, table, live, token, pos):
+        pages, leaves = State.split(dec, cache)
+        st = State(leaves, live)
+        cache, logits = decode(dec, Paged(pages, table), params, token, pos,
+                               state=st)
+        return cache, jnp.argmax(logits, -1), st.counters
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        cache, params, table, live, rows, rows).compile()
+    page_leaf = n_pages * 8 * pt * 128 * 2
+    ssm_leaf = slots * 128 * 64 * 128 * 4
+    conv_leaf = slots * 3 * 8448 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * page_leaf + ssm_leaf + conv_leaf
+    assert mem.temp_size_in_bytes < ssm_leaf // 2, \
+        "a state or arena leaf is copied round its write"
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6, \
+        "state update, paged decode attention, two grouped products a layer"

@@ -1,0 +1,278 @@
+"""Granite 4.0-H through `models/decoder.py`'s one loop at a tiny size — a
+three-layer period [mamba, attention, mamba], 8 experts top-2 of which 4
+are held — against the plain reference (`chipbench/reference/`, float32,
+sequential scan, dense experts): chunked prefill then decode through the
+pools, the scan's indifference to chunking, padding and dead rows, slots,
+the expert shares, and what a session refuses for a model with state."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import weights_granite
+from chipbench.reference import granite_hybrid as reference
+from easydist_tpu.models import granite_hybrid as gh
+from easydist_tpu.models.decoder import Paged, State, chunk, decode
+from easydist_tpu.serve import GenerationSession, ServeConfig
+
+SIZES = dict(
+    hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+    attention_multiplier=0.125, mamba_n_heads=4, mamba_d_head=16,
+    mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_n_groups=1,
+    mamba_chunk_size=8, router_experts=8, experts_held=[0, 4],
+    num_local_experts=4, num_experts_per_tok=2, intermediate_size=16,
+    shared_intermediate_size=24, layer_types=["mamba", "attention", "mamba"],
+    num_hidden_layers=3, vocab_size=96, embedding_multiplier=12,
+    residual_multiplier=0.22, logits_scaling=16, rms_norm_eps=1e-5)
+CFG = gh.GraniteHybridConfig(
+    vocab=96, dim=32, layer_types=tuple(SIZES["layer_types"]), heads=4,
+    kv_heads=2, head_dim=8, attention_multiplier=0.125, mamba_heads=4,
+    mamba_head_dim=16, d_state=16, mamba_chunk=8, experts=8, top_k=2,
+    experts_held=(0, 4), expert_dim=16, shared_dim=24, dtype="float32")
+N_SLOTS, PT, N_PAGES, MAX_PAGES = 4, 8, 16, 4
+
+
+@pytest.fixture(scope="module")
+def params():
+    return weights_granite.granite_params(SIZES, weights_granite.seed_key(3),
+                                          dtype=jnp.float32)
+
+
+def _pools(dec):
+    return {**Paged.init(dec, N_PAGES, PT), **State.init(dec, N_SLOTS)}
+
+
+def _table(rows):
+    """Slot i owns pages 4i..4i+3; `rows` lists the slot of each row (None
+    = no sequence)."""
+    tbl = np.full((len(rows), MAX_PAGES), N_PAGES, np.int32)
+    for r, slot in enumerate(rows):
+        if slot is not None:
+            tbl[r] = slot * MAX_PAGES + np.arange(MAX_PAGES)
+    return jnp.asarray(tbl)
+
+
+def _prefill(dec, cache, params, prompts, slots, c_len=PT):
+    """Chunked prefill of `prompts` (row r into slot slots[r]); returns
+    (cache, logits at each row's last position)."""
+    n = max(len(p) for p in prompts)
+    last = [None] * len(prompts)
+    for start in range(0, n, c_len):
+        toks = np.zeros((len(prompts), c_len), np.int32)
+        row_slots = []
+        for r, p in enumerate(prompts):
+            seg = p[start:start + c_len]
+            toks[r, :len(seg)] = seg
+            row_slots.append(slots[r] if seg else None)
+        pages, leaves = State.split(dec, cache)
+        sl = jnp.asarray([N_SLOTS if s is None else s for s in row_slots],
+                         jnp.int32)
+        starts = jnp.full((len(prompts),), start, jnp.int32)
+        st = State(leaves, sl < N_SLOTS, sl, fresh=starts == 0)
+        cache, logits = chunk(dec, Paged(pages, _table(row_slots)), params,
+                              jnp.asarray(toks), starts,
+                              jnp.asarray([len(p) for p in prompts]),
+                              state=st)
+        for r, p in enumerate(prompts):
+            if start < len(p) <= start + c_len:
+                last[r] = np.asarray(logits[r])
+    return cache, last
+
+
+def _decode(dec, cache, params, tokens, positions, live):
+    """One decode round over all N_SLOTS rows (`live`: the slots that are
+    sequences)."""
+    pages, leaves = State.split(dec, cache)
+    alive = np.zeros((N_SLOTS,), bool)
+    alive[list(live)] = True
+    st = State(leaves, jnp.asarray(alive))
+    tbl = _table([i if i in live else None for i in range(N_SLOTS)])
+    return decode(dec, Paged(pages, tbl), params, jnp.asarray(tokens),
+                  jnp.asarray(positions), state=st)
+
+
+def test_chunked_prefill_then_decode_equals_the_reference(params):
+    """Logits, not tokens.  Both sides are float32; they differ in the
+    order of sums (SSD blocks against a sequential scan, grouped rows
+    against dense experts, a paged softmax): 2e-5 of the logits' spread,
+    where leaving a term out moves them by the spread itself."""
+    dec = gh.decoder(CFG)
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(1, 96, size=19).tolist()
+    cache, last = _prefill(dec, _pools(dec), params, [prompt], [2])
+    seq, got = list(prompt), [last[0]]
+    for _ in range(5):
+        seq.append(int(np.argmax(got[-1])))
+        toks, pos = np.zeros(N_SLOTS, np.int32), np.zeros(N_SLOTS, np.int32)
+        toks[2], pos[2] = seq[-1], len(seq) - 1
+        cache, logits = _decode(dec, cache, params, toks, pos, {2})
+        got.append(np.asarray(logits[2]))
+    want = np.asarray(reference.logits(params, SIZES,
+                                       np.asarray(seq, np.int32)))
+    want = want[len(prompt) - 1:]
+    spread = want.std()
+    np.testing.assert_allclose(np.stack(got), want, atol=2e-5 * spread
+                               + 1e-9, rtol=2e-4)
+    assert (np.stack(got).argmax(-1) == want.argmax(-1)).all()
+
+
+@pytest.mark.parametrize("c_len", [8, 16, 24])
+def test_the_scan_gives_the_same_state_whatever_the_chunk(params, c_len):
+    """Chunks of 8, 16 and the whole 24-token prompt (pages of that size)
+    leave the same SSM state, conv tail and last logits."""
+    global PT, MAX_PAGES
+    dec = gh.decoder(dataclasses.replace(CFG, mamba_chunk=c_len))
+    prompt = np.random.default_rng(1).integers(1, 96, size=24).tolist()
+    keep = PT, MAX_PAGES
+    try:
+        PT, MAX_PAGES = c_len, 4
+        got, last = _prefill(dec, _pools(dec), params, [prompt], [1], c_len)
+        PT, MAX_PAGES = 8, 4
+        want, last8 = _prefill(gh.decoder(CFG), _pools(dec), params,
+                               [prompt], [1], 8)
+    finally:
+        PT, MAX_PAGES = keep
+    for name in dec.state_shapes:
+        for a, b in zip(got[name], want[name]):
+            np.testing.assert_allclose(a[1], b[1], rtol=1e-4, atol=1e-6)
+            assert not np.asarray(a[0]).any()      # another slot: untouched
+    np.testing.assert_allclose(last[0], last8[0], rtol=1e-4, atol=1e-8)
+
+
+def test_padded_positions_and_dead_rows_leave_state_bit_identical(params):
+    dec = gh.decoder(CFG)
+    rng = np.random.default_rng(2)
+    a, b = rng.integers(1, 96, size=11).tolist(), \
+        rng.integers(1, 96, size=5).tolist()
+    cache, _ = _prefill(dec, _pools(dec), params, [a, b], [0, 3])
+    # the same prompts, the chunks' padding filled with other tokens
+    toks = np.asarray([a[8:] + [7] * 5, [9] * 8], np.int32)
+    first, _ = _prefill(dec, _pools(dec), params, [a[:8], b], [0, 3])
+    pages, leaves = State.split(dec, first)
+    sl = jnp.asarray([0, N_SLOTS], jnp.int32)     # row 1: no sequence now
+    st = State(leaves, sl < N_SLOTS, sl, fresh=jnp.asarray([False, False]))
+    other, _ = chunk(dec, Paged(pages, _table([0, None])), params,
+                     jnp.asarray(toks), jnp.asarray([8, 8]),
+                     jnp.asarray([len(a), len(b)]), state=st)
+    for name in dec.state_shapes:
+        for x, y in zip(cache[name], other[name]):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    # a decode round in which only slot 0 is live: the others' state, the
+    # dead slot 3 (a finished prefill) included, is bit for bit as it was
+    toks, pos = np.full(N_SLOTS, 5, np.int32), np.full(N_SLOTS, 3, np.int32)
+    pos[0] = len(a)
+    after, _ = _decode(dec, cache, params, toks, pos, {0})
+    for name in dec.state_shapes:
+        for x, y in zip(cache[name], after[name]):
+            np.testing.assert_array_equal(np.asarray(x)[1:],
+                                          np.asarray(y)[1:])
+            assert (np.asarray(x)[0] != np.asarray(y)[0]).any()
+
+
+def test_two_sequences_swapped_between_slots_give_swapped_results(params):
+    dec = gh.decoder(CFG)
+    rng = np.random.default_rng(3)
+    a, b = rng.integers(1, 96, size=13).tolist(), \
+        rng.integers(1, 96, size=6).tolist()
+
+    def run(slot_a, slot_b):
+        cache, last = _prefill(dec, _pools(dec), params, [a, b],
+                               [slot_a, slot_b])
+        toks, pos = np.zeros(N_SLOTS, np.int32), np.zeros(N_SLOTS, np.int32)
+        toks[slot_a], pos[slot_a] = int(np.argmax(last[0])), len(a)
+        toks[slot_b], pos[slot_b] = int(np.argmax(last[1])), len(b)
+        _, logits = _decode(dec, cache, params, toks, pos, {slot_a, slot_b})
+        return last, np.asarray(logits)
+
+    last, logits = run(0, 2)
+    last_s, logits_s = run(2, 0)
+    np.testing.assert_array_equal(last[0], last_s[0])
+    np.testing.assert_array_equal(last[1], last_s[1])
+    np.testing.assert_array_equal(logits[0], logits_s[2])
+    np.testing.assert_array_equal(logits[2], logits_s[0])
+    assert not np.array_equal(logits[0], logits[2])
+
+
+def test_the_expert_shares_add_up_to_the_uncut_layer(params):
+    """Experts 0-3 and 4-7, each share routing over all 8, with the shared
+    MLP (which every chip computes alike) counted once, against the
+    reference's layer holding all 8."""
+    full = dict(SIZES, experts_held=[0, 8], num_local_experts=8)
+    blk = weights_granite.granite_params(
+        full, weights_granite.seed_key(5), dtype=jnp.float32)["blocks"][0]
+    u = jax.random.normal(jax.random.PRNGKey(1), (21, 32), jnp.float32)
+    c = dict(reference.constants(full))
+    with jax.default_matmul_precision("highest"):
+        want = reference._moe(u, blk, c, False) + reference._glu(
+            u, blk["shared_w1"], blk["shared_w2"], False)
+    got = gh.shared_mlp(CFG, blk, u)
+    counted = 0
+    for first in (0, 4):
+        share = dict(blk, w1=blk["w1"][first:first + 4],
+                     w2=blk["w2"][first:first + 4])
+        part, counters = gh.expert_ffn(
+            dataclasses.replace(CFG, experts_held=(first, 4)), share, u)
+        got = got + part
+        counted += int(counters[0])
+    assert counted == 21 * 2          # every pair went to exactly one share
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def test_rows_that_are_not_valid_are_routed_nowhere(params):
+    blk = params["blocks"][0]
+    u = jax.random.normal(jax.random.PRNGKey(2), (10, 32), jnp.float32)
+    valid = jnp.arange(10) < 6
+    part, counters = gh.expert_ffn(CFG, blk, u, valid)
+    whole, every = gh.expert_ffn(CFG, blk, u)
+    assert not np.asarray(part[6:]).any()
+    np.testing.assert_array_equal(part[:6], whole[:6])
+    assert 0 < int(counters[0]) < int(every[0]) <= 20
+    assert int(counters[2]) <= int(counters[0])
+
+
+REFUSED = {   # what -> (the config that asks for it, the error names it)
+    "the contiguous layout": (dict(kv_layout="bucketed"), "bucketed"),
+    "the prefix trie": (dict(enable_prefix_cache=True), "prefix trie"),
+    "speculation": (dict(speculate_k=2), "speculation"),
+    "the host tier": (dict(enable_prefix_cache=True,
+                           kv_host_tier_bytes=1 << 20), "host tier"),
+    "the int8 arena": (dict(kv_quant_dtype="int8"), "int8 arena"),
+}
+
+
+@pytest.mark.parametrize("what", list(REFUSED))
+def test_a_model_with_state_layers_refuses(params, what):
+    base = dict(kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+                prefill_chunk=8, enable_prefix_cache=False, speculate_k=0)
+    asked, named = REFUSED[what]
+    with pytest.raises(ValueError, match="state layers.*" + named):
+        GenerationSession(params, model=gh.decoder(CFG),
+                          config=ServeConfig(**{**base, **asked}))
+    GenerationSession(params, model=gh.decoder(CFG),
+                      config=ServeConfig(**base)).close()
+
+
+def test_a_session_serves_it_and_the_ids_are_the_references(params):
+    sess = GenerationSession(params, model=gh.decoder(CFG), config=ServeConfig(
+        kv_layout="paged", decode_buckets=(64,), max_decode_slots=N_SLOTS,
+        prefill_chunk=PT, prefill_batch=2, enable_prefix_cache=False,
+        speculate_k=0))
+    rng = np.random.default_rng(4)
+    reqs = [(rng.integers(1, 96, size=n).tolist(), m)
+            for n, m in ((5, 4), (19, 6), (8, 3), (30, 5), (3, 7), (16, 9))]
+    futs = [sess.submit(p, max_new_tokens=m) for p, m in reqs]
+    sess.run_until_drained()
+    for (prompt, _), fut in zip(reqs, futs):
+        ids = fut.result(timeout=5)["ids"]
+        want = np.asarray(reference.logits(
+            params, SIZES, np.asarray(prompt + ids, np.int32)))
+        rows = want[len(prompt) - 1:len(prompt) - 1 + len(ids)]
+        assert rows.argmax(-1).tolist() == ids
+    counters = sess.metrics.snapshot()["counters"]
+    assert counters["moe_rounds"] > 0 and counters["moe_prefill_calls"] > 0
+    assert 0 < counters["moe_pairs_routed"] <= 2 * 3 * \
+        counters["tokens_generated"]
+    sess.close()
