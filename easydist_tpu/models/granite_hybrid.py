@@ -167,7 +167,15 @@ def expert_ffn(cfg: GraniteHybridConfig, blk, u, valid=None):
     """The held experts' part of the routed FFN: u [rows, dim] (already
     normed), valid bool [rows] or None -> (out [rows, dim], counters int32
     [3]: pairs routed to held experts, held experts hit, the busiest held
-    expert's pairs).  Rows that are not valid are routed nowhere."""
+    expert's pairs).  Rows that are not valid are routed nowhere.
+
+    The routed (token, choice) pairs are laid out SLOT-major: flat pair
+    `j * rows + r` is token r's j-th choice, so a choice's pairs are `rows`
+    consecutive rows of the gathered products and the combine is `top_k`
+    static row slices summed in float32.  Token-major, the combine needs a
+    `[rows, top_k, dim]` view, and `top_k` = 10 in a second-minor dimension
+    pads to the (8, 128) tile's 16: a physical float32 copy of 1.6x the
+    pairs, written and read back, every layer."""
     from easydist_tpu.ops.grouped_matmul import group_rows, grouped_matmul
 
     dtype = jnp.dtype(cfg.dtype)
@@ -175,30 +183,35 @@ def expert_ffn(cfg: GraniteHybridConfig, blk, u, valid=None):
     first, held = cfg.experts_held
     scores = (u @ blk["router"].astype(dtype)).astype(jnp.float32)
     top, idx = jax.lax.top_k(scores, k)
-    gate = jax.nn.softmax(top, axis=-1)                       # [rows, k]
-    local = idx.astype(jnp.int32) - first
+    gate = jax.nn.softmax(top, axis=-1).T                     # [k, rows]
+    local = idx.astype(jnp.int32).T - first
     mine = (local >= 0) & (local < held)
     if valid is not None:
-        mine &= valid[:, None]
-    expert = jnp.where(mine, local, held).reshape(rows * k)
+        mine &= valid[None, :]
+    expert = jnp.where(mine, local, held).reshape(k * rows)
     # a block per ~expert's share of the pairs: 128 rows where experts see
     # that many (prefill), 32 where a round gives each a handful (decode)
     # (float32, the tests' type, tiles in 8s)
     tm = 8 if dtype.itemsize == 4 else 128 if rows * k >= 64 * held else 32
     g = group_rows(expert, held, tm)
-    xb = jnp.take(u, g.source // k, axis=0, mode="clip")
+    # a place that holds no pair reads `k * rows`, so row 0: any real row does
+    xb = jnp.take(u, g.source % rows, axis=0, mode="clip")
     hid = grouped_matmul(xb, blk["w1"].astype(dtype), g.block_expert,
                          g.live_blocks, tm)
     half = hid.shape[-1] // 2
     act = jax.nn.silu(hid[:, :half]) * hid[:, half:]
     out = grouped_matmul(act, blk["w2"].astype(dtype), g.block_expert,
                          g.live_blocks, tm)
-    pairs = jnp.take(out, g.dest, axis=0, mode="clip").reshape(rows, k, -1)
-    pairs = jnp.where(mine[..., None], pairs.astype(jnp.float32)
-                      * gate[..., None], 0.0)
+    pairs = jnp.take(out, g.dest, axis=0, mode="clip")        # [k * rows, dim]
+    # the cast is per slice, inside the sum: on the whole array it is a
+    # pass of its own.  A pair that is not `mine` was gathered from a block
+    # nothing wrote: `where` on the product, so that a NaN there stays out
+    total = sum(jnp.where(mine[j, :, None],
+                          pairs[j * rows:(j + 1) * rows].astype(jnp.float32)
+                          * gate[j, :, None], 0.0) for j in range(k))
     counters = jnp.stack([jnp.sum(g.sizes), jnp.sum(g.sizes > 0),
                           jnp.max(g.sizes)]).astype(jnp.int32)
-    return pairs.sum(axis=1).astype(dtype), counters
+    return total.astype(dtype), counters
 
 
 def mamba_mixer(cfg: GraniteHybridConfig, blk, u, carry, valid):

@@ -14,6 +14,16 @@ no weight is fetched for them, and they compute nothing.  On a TPU this is
 a Pallas kernel whose weight index map reads `block_expert` from
 scalar-prefetch memory; elsewhere, a gather of the blocks' weights and a
 batched einsum.
+
+A row is whatever the caller numbers: `group_rows` sees a flat vector of
+experts and hands back places (`dest`) and the rows at them (`source`) in
+the caller's numbering.  `models/granite_hybrid.py::expert_ffn` numbers its
+(token, choice) pairs SLOT-major, `choice * tokens + token`, so `source %
+tokens` is the token to fetch and the products gathered back by `dest` are
+`top_k` runs of `tokens` rows, summed slice by slice.  Token-major
+(`token * top_k + choice`) the same sum needs a `[tokens, top_k, dim]`
+view, whose second-minor `top_k` pads to the (8, 128) tile: a physical
+copy (PR 32).
 """
 
 from __future__ import annotations
@@ -34,7 +44,8 @@ class RowGroups(NamedTuple):
     """Where `group_rows` put each row.  `dest` int32 [rows]: the row's
     place in the blocked layout, `n_blocks * block_rows` for a row of no
     held expert; `source` int32 [n_blocks * block_rows]: the row held at
-    each place (`rows` where none is); `block_expert` int32 [n_blocks];
+    each place, in the order the caller flattened its rows in (`rows`
+    where none is); `block_expert` int32 [n_blocks];
     `live_blocks` int32 []; `sizes` int32 [experts]: rows per expert."""
     dest: jax.Array
     source: jax.Array

@@ -15,7 +15,10 @@ chip:
   * the decode step compiled for a described TPU v5e at the chat cell's
     widths (one layer): there a row write indexed `[page, :, offset, :]`
     made XLA re-lay the whole leaf out before and after the scatter, which
-    no CPU compile shows.
+    no CPU compile shows;
+  * the Granite 4.0-H cell's expert FFN compiled for that v5e: the routed
+    pairs' products are combined with no float32 copy of them (it lives in
+    this file because one worker alone may load the TPU's compiler).
 
 This is the guard that keeps a later model file from stacking again
 (PR 28: the stacked arena cost 57 % of a decode round on the chip).
@@ -167,6 +170,13 @@ def v5e_chip():
             compilation_cache.reset_cache()
 
 
+def _described(chip, tree):
+    """The tree's shapes on the described chip: there is no device to hold
+    an array."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=chip), tree)
+
+
 def test_decode_step_writes_in_place_on_tpu(v5e_chip, monkeypatch):
     """The chat cell's decode step (BENCHMARK.json: Mistral-7B widths, 32
     slots, 576 pages of 64 tokens), one layer, with the Pallas kernel."""
@@ -181,15 +191,11 @@ def test_decode_step_writes_in_place_on_tpu(v5e_chip, monkeypatch):
                             rope_theta=1e6, dtype="bfloat16")
     slots, max_pages = 32, 32
 
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(
+    params = _described(v5e_chip, jax.eval_shape(
         lambda key: jax.tree.map(lambda x: x.astype(jnp.bfloat16),
                                  llama.llama_init(cfg, key)),
         jax.random.PRNGKey(0)))
-    arena = on_chip(jax.eval_shape(
+    arena = _described(v5e_chip, jax.eval_shape(
         lambda: llama.init_kv_pages(cfg, 576, 64)))
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
     table = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
@@ -289,13 +295,9 @@ def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
     dec = gh.decoder(cfg)
     slots, n_pages, pt, max_pages = 64, 768, 256, 16
 
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(
+    params = _described(v5e_chip, jax.eval_shape(
         lambda key: gh.granite_init(cfg, key), jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(
+    cache = _described(v5e_chip, jax.eval_shape(
         lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
     live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_chip)
@@ -321,3 +323,67 @@ def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6, \
         "state update, paged decode attention, two grouped products a layer"
+
+
+_ENTRY_LINE = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?)\s([a-z][a-z0-9\-]*)\(")
+_ARRAY = re.compile(r"([a-z]+[0-9]*)\[([0-9,]*)\]")
+
+
+def _entry_results(hlo_text):
+    """[(opcode, [(dtype, elements) of each array of its result])] for the
+    instructions of the compiled module's entry computation."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    out = []
+    for line in entry.splitlines()[2:]:
+        m = _ENTRY_LINE.match(line)
+        assert m, line
+        out.append((m.group(2), [
+            (dt, int(np.prod([int(d) for d in dims.split(",") if d])))
+            for dt, dims in _ARRAY.findall(m.group(1))]))
+    return out
+
+
+@pytest.mark.parametrize("rows", [1024, 64], ids=["chunk", "round"])
+def test_expert_combine_makes_no_float32_copy_of_the_pairs_on_tpu(
+        v5e_chip, monkeypatch, rows):
+    """`expert_ffn` at the Granite cell's widths (36 held experts, top-10,
+    bf16) for a chunk call's 4 x 256 rows and a round's 64: the gathered
+    products `[10 * rows, 4096]` are summed over the ten choices without
+    ever existing in float32 and without a pass that only moves them.
+    Token-major they were viewed `[rows, 10, 4096]`, and a second-minor 10
+    pads to the tile's 16: XLA wrote 268 MB of float32 a layer and read it
+    back (PR 32: 10 ms of a 58.6 ms chunk call); a cast written on the
+    whole slot-major view is a pass of 168 MB all the same."""
+    import functools
+
+    from easydist_tpu.models import granite_hybrid as gh
+    from easydist_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, backend="pallas", interpret=False))
+    cfg = gh.GraniteHybridConfig(vocab=50176, layer_types=("mamba",),
+                                 experts_held=(0, 36))
+
+    blk = _described(v5e_chip, jax.eval_shape(
+        lambda key: gh.granite_init(cfg, key),
+        jax.random.PRNGKey(0)))["blocks"][0]
+    u = jax.ShapeDtypeStruct((rows, cfg.dim), jnp.bfloat16,
+                             sharding=v5e_chip)
+    valid = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=v5e_chip)
+    compiled = jax.jit(functools.partial(gh.expert_ffn, cfg)).lower(
+        blk, u, valid).compile()
+
+    pairs = rows * cfg.top_k * cfg.dim
+    results = _entry_results(compiled.as_text())
+    assert sum(op == "custom-call" for op, _ in results) == 2
+    for op, arrays in results:
+        for dt, n in arrays:
+            assert not (dt == "f32" and n >= pairs), \
+                f"`{op}` holds the pairs in float32 ({n} elements)"
+            assert not (n >= pairs and op.split("-")[0] in
+                        ("reshape", "copy", "convert", "transpose")), \
+                f"`{op}` only moves the pairs ({dt}, {n} elements)"
+    if rows == 1024:    # 269 MB token-major: the float32 view
+        assert compiled.memory_analysis().temp_size_in_bytes < 140e6

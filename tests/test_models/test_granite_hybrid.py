@@ -233,6 +233,58 @@ def test_rows_that_are_not_valid_are_routed_nowhere(params):
     assert int(counters[2]) <= int(counters[0])
 
 
+@pytest.mark.parametrize("first", [0, 3], ids=["held-0-3", "held-3-6"])
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "valid"])
+@pytest.mark.parametrize("rows", [1, 5, 16, 64])
+def test_the_expert_ffn_is_the_dense_sum_over_held_choices(params, rows,
+                                                           masked, first):
+    """Against the reference's layer, which runs every held expert densely
+    over every token and knows no order of pairs: 1 and 5 rows (slices of
+    the gathered products that are no whole tile), 16 and 64, some rows
+    not valid, a held window that starts past expert 0."""
+    cfg = dataclasses.replace(CFG, experts_held=(first, 4))
+    blk = params["blocks"][0]
+    u = jax.random.normal(jax.random.PRNGKey(10 + rows), (rows, 32),
+                          jnp.float32)
+    valid = np.arange(rows) % 3 != 1 if masked else np.ones(rows, bool)
+    got, counters = gh.expert_ffn(cfg, blk, u,
+                                  jnp.asarray(valid) if masked else None)
+    c = dict(reference.constants(dict(SIZES, experts_held=[first, 4])))
+    with jax.default_matmul_precision("highest"):
+        want = np.where(valid[:, None], reference._moe(u, blk, c, False), 0)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    if rows >= 16:      # top-2 of 8 with 4 held: some pairs land, some not
+        assert 0 < int(counters[0]) < 2 * rows
+    assert not np.asarray(got)[~valid].any()
+
+
+def test_products_nothing_wrote_cannot_reach_the_output(params, monkeypatch):
+    """The kernel leaves the rows of dead blocks UNWRITTEN, and a pair of
+    no held expert gathers from the last of them: NaN there (put into the
+    fallback's output, where the kernel would leave whatever was in memory)
+    must not leak through a zero weight."""
+    from easydist_tpu.ops import grouped_matmul as gm
+
+    blk = params["blocks"][0]
+    u = jax.random.normal(jax.random.PRNGKey(7), (16, 32), jnp.float32)
+    want, _ = gh.expert_ffn(CFG, blk, u)
+    poisoned = []
+
+    def unwritten(x, w, block_expert, live_blocks, tm):
+        out = gm._gmm_xla(x, w, block_expert, live_blocks, tm)
+        dead = jnp.repeat(jnp.arange(block_expert.shape[0]) >= live_blocks,
+                          tm)
+        poisoned.append(bool(dead[-1]))
+        return jnp.where(dead[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(gm, "grouped_matmul", unwritten)
+    got, counters = gh.expert_ffn(CFG, blk, u)
+    assert poisoned == [True, True]        # the row a clipped gather reads
+    assert int(counters[0]) < 2 * 16       # and some pair does read it
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, want)
+
+
 REFUSED = {   # what -> (the config that asks for it, the error names it)
     "the contiguous layout": (dict(kv_layout="bucketed"), "bucketed"),
     "the prefix trie": (dict(enable_prefix_cache=True), "prefix trie"),
