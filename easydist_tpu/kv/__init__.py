@@ -34,8 +34,10 @@ storage too:
     every leaf, stacked), which is what the trie, the host tier and the
     fleet transport hold, hash and ship.
 
-  * `state.StatePool` — the slots of a model's recurrent-state layers
-    (one per live sequence, beside its pages in the same pool).
+  * `state.StatePool` — the slots of what a model keeps by the SEQUENCE:
+    the recurrent state of its state layers and the K/V rings of its
+    window layers (one slot per live sequence, beside its pages in the
+    same pool).  Pages are of the layers that see every position, only.
 
 Analyze rule KV001 (`analyze/kv_rules.py`) audits the pool/table/trie
 bookkeeping; `check_invariants` here is the raw audit it wraps.

@@ -1,16 +1,20 @@
-"""The recurrent-state pool: a slot per live sequence.
+"""The pool of per-sequence slots: a slot per live sequence.
 
-A state layer (Mamba-2) caches a fixed amount per SEQUENCE — an SSM state
-and the tail of its conv window — whatever the sequence's length, where an
-attention layer caches rows per position.  The device side is
-`models.decoder.State` ({name: (a leaf per state layer)}, each leaf
-[n_slots, ...], donated and written in place like an arena leaf);
-`StatePool` is the host's book of which slots hold a sequence.  It lives
-beside `PagePool` in the session's one pool: a request is admitted when a
-slot AND its pages are there, takes both together, and gives both back
-when it retires.  A slot is never shared and never outlives its sequence,
-so there is no refcount: nothing restores a state from anywhere yet
-(snapshots at chunk boundaries are what the trie and resume would need).
+Some layers cache a fixed amount per SEQUENCE, whatever the sequence's
+length, where a full attention layer caches rows per position: a state
+layer (Mamba-2) an SSM state and the tail of its conv window, a window
+attention layer the K/V of its last `window` positions, in a ring.  The
+device side is `models.decoder.State` ({name: (a leaf per such layer)},
+each leaf [n_slots, ...], donated and written in place like an arena leaf;
+the rings ride in it as `Ring`); `StatePool` is the host's book of which
+slots hold a sequence.  It lives beside `PagePool` in the session's one
+pool: a request is admitted when a slot AND its pages are there, takes
+both together, and gives both back when it retires.  A slot is never
+shared and never outlives its sequence, so there is no refcount: nothing
+restores a state or a ring from anywhere yet (snapshots at chunk
+boundaries are what the trie and resume would need).  A slot's leaves are
+not cleared between sequences: a first chunk starts from zero state, and
+no position of the new sequence reaches a ring row the old one wrote.
 """
 
 from __future__ import annotations

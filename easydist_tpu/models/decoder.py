@@ -17,7 +17,9 @@ functions it supplies, all of which receive the block.  The loop knows two
 KINDS of layer, by what they cache: "attention" (K/V rows per position,
 through `Contiguous` or `Paged`) and "state" (a recurrent state per
 SEQUENCE, through the third adapter, `State`); a model with state layers
-says which is which in `kinds`.
+says which is which in `kinds`.  An attention layer that sees only its
+last `window` positions says so in `windows`, and caches a ring of that
+many rows per SEQUENCE (`Ring`, which `State` carries) instead of pages.
 
 K and V are cached as the model's `qkv` returns them — positions already
 applied (roped keys), at kv_heads granularity; the GQA repeat happens at
@@ -35,8 +37,8 @@ import jax.numpy as jnp
 from easydist_tpu.kv.arena import (init_page_arena, write_chunk, write_row,
                                    write_rows)
 
-__all__ = ["Decoder", "Contiguous", "Paged", "State", "chunk", "verify",
-           "decode", "split_heads"]
+__all__ = ["Decoder", "Contiguous", "Paged", "State", "Ring", "chunk",
+           "verify", "decode", "split_heads"]
 
 
 @dataclass(frozen=True)
@@ -58,23 +60,41 @@ class Decoder:
     final_norm: Callable  # (params, x) -> x
     unembed: Callable     # (params, x) -> float32 logits [..., vocab]
     # A model with state layers fills in the three below; `valid` (bool,
-    # the leading shape of x) marks the rows and positions that are real,
-    # and such a model's `ffn` takes it too and returns (x, int32 counters
-    # [n]), which the loop sums over the layers (`State.counters`).
+    # the leading shape of x) marks the rows and positions that are real.
+    # A model that keeps anything a SEQUENCE (state layers, window layers:
+    # `per_sequence`) is stepped with a `State`, and its `ffn` takes
+    # `valid` too and returns (x, int32 counters [n] or None), which the
+    # loop sums over the layers that gave some (`State.counters`).
     kinds: Optional[Tuple[str, ...]] = None   # "attention" | "state" a block
     state: Optional[Callable] = None  # (block, x, carry, valid) -> x, carry
     state_shapes: Optional[Dict[str, tuple]] = None  # name -> (shape, dtype)
     #                                                  of ONE sequence's carry
+    # one entry per ATTENTION layer: how many positions back, its own
+    # included, the layer sees (key j is visible to query i iff
+    # i - window < j <= i); None = all of them.  Left None: every layer all.
+    windows: Optional[Tuple[Optional[int], ...]] = None
+
+    @property
+    def ring_windows(self) -> Tuple[int, ...]:
+        """The windows of the window layers, in layer order: a ring each."""
+        return tuple(w for w in self.windows or () if w is not None)
 
     @property
     def kv_layers(self) -> int:
-        """The layers that cache K/V: the leaves of an arena."""
-        return self.layers if self.kinds is None \
+        """The layers that cache K/V rows for EVERY position: the leaves of
+        an arena (a window layer keeps a ring instead)."""
+        attention = self.layers if self.kinds is None \
             else self.kinds.count("attention")
+        return attention - len(self.ring_windows)
 
     @property
     def state_layers(self) -> int:
         return 0 if self.kinds is None else self.kinds.count("state")
+
+    @property
+    def per_sequence(self) -> bool:
+        """Whether the model keeps anything by the sequence, in a slot."""
+        return bool(self.state_layers or self.ring_windows)
 
 
 def split_heads(y, n: int):
@@ -122,6 +142,9 @@ class Contiguous:
             raise ValueError(
                 f"max_len {max_len} exceeds the learned position table "
                 f"(cfg.seq={dec.max_positions})")
+        if dec.ring_windows:
+            raise ValueError("a model with window layers has no contiguous "
+                             "cache: its rings live beside a paged arena")
         shape = (dec.kv_layers, batch, dec.kv_heads, max_len, dec.head_dim)
         dt = _storage_dtype(dec, dtype)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -267,12 +290,101 @@ class Paged:
         return {key: tuple(leaves) for key, leaves in self._new.items()}
 
 
+class Ring:
+    """The K/V of a model's window layers, {"ring_k": (a leaf per WINDOW
+    layer), "ring_v": (...)} with each leaf [n_slots, kv_heads, ring,
+    head_dim]: a slot per live sequence, holding its last `ring` positions,
+    position p in row p % ring — so the bytes a sequence holds in a window
+    layer do not grow with it.  `ring` is the layer's window rounded up to
+    the 8 rows of a tile.  A leaf has an arena leaf's layout with the slot
+    as its page, and is written in place by the same scatters.
+
+    Nothing is ever re-ordered: a step knows the absolute position every
+    row of a ring holds from the step's own positions, and masks by it
+    (`ops.window_attention`), which also keeps out what an earlier tenant
+    of the slot left behind.  `State` builds one for a model with window
+    layers; the step tells it (`seek`) its positions and which are real,
+    and only real positions are written."""
+
+    KEYS = ("ring_k", "ring_v")
+
+    @staticmethod
+    def rows(window: int) -> int:
+        return -(-window // 8) * 8
+
+    @staticmethod
+    def init(dec: Decoder, n_slots: int, dtype=None):
+        dt = _storage_dtype(dec, dtype)
+        return {key: tuple(jnp.zeros((n_slots, dec.kv_heads, Ring.rows(w),
+                                      dec.head_dim), dt)
+                           for w in dec.ring_windows)
+                for key in Ring.KEYS}
+
+    def __init__(self, rings, slots=None):
+        self._old, self._slots = rings, slots
+        self._new = {key: [] for key in Ring.KEYS}
+
+    def seek(self, pos, valid):
+        """`pos` int32 [b] (a decode round: the rows are the slots) or
+        [b, s] (a chunk: row r is slot `slots[r]`), `valid` bool alike, a
+        prefix of each chunk row."""
+        self._pos, self._valid = pos, valid
+
+    def write(self, k, v):
+        li = len(self._new["ring_k"])
+        old_k, old_v = self._old["ring_k"][li], self._old["ring_v"][li]
+        n_slots, _, ring, _ = old_k.shape
+        pos, valid = self._pos, self._valid
+        if k.ndim == 3:
+            page = jnp.where(valid, jnp.arange(n_slots, dtype=jnp.int32),
+                             n_slots)
+            put = lambda leaf, new: write_row(leaf, new, page, pos % ring)
+        else:
+            # what a chunk attends beside itself: the ring as it was
+            self._before = tuple(
+                jnp.take(leaf, self._slots, axis=0, mode="clip")
+                for leaf in (old_k, old_v))
+            self._own = (k, v)
+            # the last `ring` real positions: any earlier one would share
+            # a row with one of them
+            end = pos[:, :1] + jnp.sum(valid, axis=1, keepdims=True)
+            page = jnp.where(valid & (pos >= end - ring),
+                             self._slots[:, None], n_slots)
+            put = lambda leaf, new: write_rows(leaf, new, page, pos % ring)
+        self._new["ring_k"].append(put(old_k, k))
+        self._new["ring_v"].append(put(old_v, v))
+
+    def attend(self, dec: Decoder, q, pos):
+        from easydist_tpu.ops import window_attention
+
+        li = len(self._new["ring_k"]) - 1
+        ring, window = self._old["ring_k"][li].shape[2], dec.ring_windows[li]
+        row = jnp.arange(ring, dtype=jnp.int32)[None, :]
+        if q.ndim == 3:
+            # after the write, row j holds the last position <= pos that
+            # lands on it (a negative one: nothing of this sequence)
+            k, v = self._new["ring_k"][li], self._new["ring_v"][li]
+            k_pos = pos[:, None] - (pos[:, None] - row) % ring
+            return window_attention(
+                q[:, :, None], k.astype(dec.dtype), v.astype(dec.dtype),
+                pos[:, None], k_pos, window)[:, :, 0]
+        last = pos[:, :1] - 1      # before the write: the last one < start
+        k_pos = jnp.concatenate([last - (last - row) % ring, pos], axis=1)
+        k, v = (jnp.concatenate([before.astype(dec.dtype), own], axis=2)
+                for before, own in zip(self._before, self._own))
+        return window_attention(q, k, v, pos, k_pos, window)
+
+    def cache(self):
+        return {key: tuple(leaves) for key, leaves in self._new.items()}
+
+
 class State:
-    """The recurrent state of a model's state layers, {name: (a leaf per
-    STATE layer)} with each leaf [n_slots, *shape of one sequence's carry]
-    (`Decoder.state_shapes`): a slot per live sequence, whatever its
-    length.  Like an arena leaf, each is a buffer of its own, donated and
-    written in place.
+    """What a model keeps a SEQUENCE: the recurrent state of its state
+    layers, {name: (a leaf per STATE layer)} with each leaf [n_slots, *shape
+    of one sequence's carry] (`Decoder.state_shapes`), and the rings of its
+    window layers (`Ring`, whose two keys ride in the same dict): a slot per
+    live sequence, whatever its length.  Like an arena leaf, each is a
+    buffer of its own, donated and written in place.
 
     `slots` (int32 [rows]) says which slot each row of the call is, and
     `n_slots` marks a row that is none (reads clip, writes drop); `None`
@@ -283,23 +395,31 @@ class State:
     the rows that start from zero state (a prompt's first chunk)."""
 
     @staticmethod
-    def init(dec: Decoder, n_slots: int):
-        return {name: tuple(jnp.zeros((n_slots,) + tuple(shape), dt)
-                            for _ in range(dec.state_layers))
-                for name, (shape, dt) in dec.state_shapes.items()}
+    def init(dec: Decoder, n_slots: int, ring_dtype=None):
+        state = {name: tuple(jnp.zeros((n_slots,) + tuple(shape), dt)
+                             for _ in range(dec.state_layers))
+                 for name, (shape, dt) in (dec.state_shapes or {}).items()}
+        if dec.ring_windows:
+            state.update(Ring.init(dec, n_slots, ring_dtype))
+        return state
 
     @staticmethod
     def split(dec: Decoder, cache):
-        """A stateful model's one donated pytree -> (the arena's keys, the
-        state's keys)."""
-        return ({k: v for k, v in cache.items()
-                 if k not in dec.state_shapes},
-                {k: cache[k] for k in dec.state_shapes})
+        """The one donated pytree of a model that keeps slots -> (the
+        arena's keys, the slots' keys)."""
+        mine = tuple(dec.state_shapes or ()) \
+            + (Ring.KEYS if dec.ring_windows else ())
+        return ({k: v for k, v in cache.items() if k not in mine},
+                {k: cache[k] for k in mine})
 
     def __init__(self, state, live, slots=None, fresh=None):
-        self._old, self.live = state, live
+        self.live = live
         self._slots, self._fresh = slots, fresh
-        self._new = {name: [] for name in state}
+        self._old = {name: leaves for name, leaves in state.items()
+                     if name not in Ring.KEYS}
+        self._new = {name: [] for name in self._old}
+        self.ring = Ring({key: state[key] for key in Ring.KEYS}, slots) \
+            if Ring.KEYS[0] in state else None
         self.counters = None      # the ffns' counters, summed over layers
 
     def read(self):
@@ -325,7 +445,8 @@ class State:
                     carry[name], mode="drop"))
 
     def cache(self):
-        return {name: tuple(leaves) for name, leaves in self._new.items()}
+        out = {name: tuple(leaves) for name, leaves in self._new.items()}
+        return out if self.ring is None else {**out, **self.ring.cache()}
 
 
 # ------------------------------------------------------------------- steps
@@ -336,6 +457,10 @@ def _forward(dec: Decoder, kv, params, tokens, pos, st=None, valid=None):
     model with state layers), final norm: (cache, x)."""
     x = dec.embed(params, tokens, pos)
     counters = []
+    ring = None if st is None else st.ring
+    if ring is not None:
+        ring.seek(pos, valid)
+    windows = iter(dec.windows or ())
     for kind, blk in zip(dec.kinds or ("attention",) * dec.layers,
                          dec.blocks(params)):
         if kind == "state":
@@ -343,13 +468,15 @@ def _forward(dec: Decoder, kv, params, tokens, pos, st=None, valid=None):
             st.write(carry)
         else:
             q, k, v = dec.qkv(blk, x, pos)
-            kv.write(k, v)
-            x = dec.attn_out(blk, x, _merge_heads(kv.attend(dec, q, pos)))
+            at = kv if next(windows, None) is None else ring
+            at.write(k, v)
+            x = dec.attn_out(blk, x, _merge_heads(at.attend(dec, q, pos)))
         if st is None:
             x = dec.ffn(blk, x)
         else:
             x, c = dec.ffn(blk, x, valid)
-            counters.append(c)
+            if c is not None:     # a layer without experts counts nothing
+                counters.append(c)
     if st is None:
         return kv.cache(), dec.final_norm(params, x)
     st.counters = sum(counters[1:], counters[0])
@@ -365,7 +492,7 @@ def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths, state=None):
     each row's last real position (`lengths - 1`): valid for rows whose
     chunk contains it, garbage nobody reads otherwise.  Against `Paged` a
     chunk fills exactly one page.  With `state` (a `State`), positions at
-    or past a row's length leave its state untouched."""
+    or past a row's length leave its state and its rings untouched."""
     c_len = tokens.shape[1]
     start = start_pos.astype(jnp.int32)
     pos = kv.seek(start, c_len, aligned=True)
@@ -385,8 +512,9 @@ def verify(dec: Decoder, kv, params, tokens, pos):
     first i tokens; rows written past the accepted prefix are the stale
     rows the position mask keeps out of every later step.  Callers
     guarantee pos + s fits the cache (every touched page mapped).  Not for
-    a model with state layers: a state has no position mask to hide a
-    rejected draft behind."""
+    a model that keeps slots: a state has no position mask to hide a
+    rejected draft behind, and a rejected draft has overwritten the ring
+    rows of positions still inside the window."""
     cache, x = _forward(dec, kv, params, tokens,
                         kv.seek(pos.astype(jnp.int32), tokens.shape[1]))
     return cache, dec.unembed(params, x)
@@ -396,7 +524,8 @@ def decode(dec: Decoder, kv, params, token, pos, state=None):
     """One cached decode step: `token` (int32 [batch]) at position `pos`
     (int32 [batch], the row's current length) -> (cache, logits
     [batch, vocab]).  O(layers * pos) attention reads a token.  With
-    `state`, rows that are not `state.live` leave their state untouched."""
+    `state`, rows that are not `state.live` leave their state and their
+    rings untouched."""
     cache, x = _forward(dec, kv, params, token,
                         kv.seek(pos.astype(jnp.int32)), state,
                         None if state is None else state.live)

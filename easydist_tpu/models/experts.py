@@ -1,0 +1,87 @@
+"""The routed expert FFN of a chip that holds SOME of a layer's experts,
+dropless: one body for every expert model.
+
+A model says how its router's scores become choices — `idx` int32 [rows, k]
+(the k experts each token chose, numbered over ALL the layer's experts) and
+`gate` float32 [rows, k] (the weight of each choice) — and `expert_ffn`
+does the rest: which pairs are for the experts held here (`experts_held`:
+first, how many), the rows of each held expert laid out in whole blocks
+(`ops/grouped_matmul.py::group_rows`), the two grouped products of a
+SwiGLU, the weighted sum back to tokens, and three counters.  What the
+absent experts would add is left out: on one chip that is the whole
+layer's work here — no exchange, and nothing stands in for the other
+chips.  A shared expert is a dense SwiGLU (`glu`), added by the model.
+
+    granite_hybrid   top-10 of the logits, softmax over the chosen
+    exaone_moe       sigmoid scores, top-8 of score + bias, the chosen
+                     scores normalised and scaled
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["expert_ffn", "glu"]
+
+
+def glu(x, w1, w2, dtype):
+    """One expert as a dense SwiGLU — a shared expert, a dense layer's FFN:
+    (silu(a) * b) @ w2 with [a | b] = x @ w1."""
+    ab = x @ w1.astype(dtype)
+    half = ab.shape[-1] // 2
+    return (jax.nn.silu(ab[..., :half]) * ab[..., half:]) @ w2.astype(dtype)
+
+
+def expert_ffn(u, idx, gate, w1, w2, experts_held: Tuple[int, int], dtype,
+               valid=None):
+    """The held experts' part of the routed FFN: u [rows, dim] (already
+    normed), idx / gate [rows, k] as above, w1 [held, dim, 2 * expert_dim],
+    w2 [held, expert_dim, dim], valid bool [rows] or None -> (out [rows,
+    dim], counters int32 [3]: pairs routed to held experts, held experts
+    hit, the busiest held expert's pairs).  Rows that are not valid are
+    routed nowhere.
+
+    The routed (token, choice) pairs are laid out SLOT-major: flat pair
+    `j * rows + r` is token r's j-th choice, so a choice's pairs are `rows`
+    consecutive rows of the gathered products and the combine is `k`
+    static row slices summed in float32.  Token-major, the combine needs a
+    `[rows, k, dim]` view, and a `k` of 10 in a second-minor dimension
+    pads to the (8, 128) tile's 16: a physical float32 copy of 1.6x the
+    pairs, written and read back, every layer."""
+    from easydist_tpu.ops.grouped_matmul import group_rows, grouped_matmul
+
+    dtype = jnp.dtype(dtype)
+    rows, k = idx.shape
+    first, held = experts_held
+    gate = gate.T                                             # [k, rows]
+    local = idx.astype(jnp.int32).T - first
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine &= valid[None, :]
+    expert = jnp.where(mine, local, held).reshape(k * rows)
+    # a block per ~expert's share of the pairs: 128 rows where experts see
+    # that many (prefill), 32 where a round gives each a handful (decode)
+    # (float32, the tests' type, tiles in 8s)
+    tm = 8 if dtype.itemsize == 4 else 128 if rows * k >= 64 * held else 32
+    g = group_rows(expert, held, tm)
+    # a place that holds no pair reads `k * rows`, so row 0: any real row does
+    xb = jnp.take(u, g.source % rows, axis=0, mode="clip")
+    hid = grouped_matmul(xb, w1.astype(dtype), g.block_expert,
+                         g.live_blocks, tm)
+    half = hid.shape[-1] // 2
+    act = jax.nn.silu(hid[:, :half]) * hid[:, half:]
+    out = grouped_matmul(act, w2.astype(dtype), g.block_expert,
+                         g.live_blocks, tm)
+    pairs = jnp.take(out, g.dest, axis=0, mode="clip")        # [k * rows, dim]
+    # the cast is per slice, inside the sum: on the whole array it is a
+    # pass of its own.  A pair that is not `mine` was gathered from a block
+    # nothing wrote: `where` on the product, so that a NaN there stays out
+    total = sum(jnp.where(mine[j, :, None],
+                          pairs[j * rows:(j + 1) * rows].astype(jnp.float32)
+                          * gate[j, :, None], 0.0) for j in range(k))
+    counters = jnp.stack([jnp.sum(g.sizes), jnp.sum(g.sizes > 0),
+                          jnp.max(g.sizes)]).astype(jnp.int32)
+    return total.astype(dtype), counters

@@ -11,9 +11,8 @@ multipliers.  Serving only: `decoder(cfg)` is the model as
 
 The expert FFN is told which experts it holds (`experts_held`: first, how
 many): it routes over ALL `experts` (softmax over the chosen `top_k`) and
-computes its own experts' part; what the absent experts would add is left
-out.  On one chip that is the whole layer's work here — no exchange, and
-nothing stands in for the other chips.
+computes its own experts' part (`models/experts.py`, the body every expert
+model shares); what the absent experts would add is left out.
 
 Parameters (`granite_init`, `chipbench/weights_granite.py`): {"wte",
 "blocks": [...], "norm_f"}; a block has "norm_in", "norm_post", "router"
@@ -152,66 +151,26 @@ def _softplus(x):
     return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
 
 
-def _glu(x, w1, w2, dtype):
-    """(silu(a) * b) @ w2 with [a | b] = x @ w1."""
-    ab = x @ w1.astype(dtype)
-    half = ab.shape[-1] // 2
-    return (jax.nn.silu(ab[..., :half]) * ab[..., half:]) @ w2.astype(dtype)
-
-
 def shared_mlp(cfg: GraniteHybridConfig, blk, u):
-    return _glu(u, blk["shared_w1"], blk["shared_w2"], jnp.dtype(cfg.dtype))
+    from .experts import glu
+
+    return glu(u, blk["shared_w1"], blk["shared_w2"], jnp.dtype(cfg.dtype))
 
 
 def expert_ffn(cfg: GraniteHybridConfig, blk, u, valid=None):
-    """The held experts' part of the routed FFN: u [rows, dim] (already
-    normed), valid bool [rows] or None -> (out [rows, dim], counters int32
-    [3]: pairs routed to held experts, held experts hit, the busiest held
-    expert's pairs).  Rows that are not valid are routed nowhere.
-
-    The routed (token, choice) pairs are laid out SLOT-major: flat pair
-    `j * rows + r` is token r's j-th choice, so a choice's pairs are `rows`
-    consecutive rows of the gathered products and the combine is `top_k`
-    static row slices summed in float32.  Token-major, the combine needs a
-    `[rows, top_k, dim]` view, and `top_k` = 10 in a second-minor dimension
-    pads to the (8, 128) tile's 16: a physical float32 copy of 1.6x the
-    pairs, written and read back, every layer."""
-    from easydist_tpu.ops.grouped_matmul import group_rows, grouped_matmul
+    """This model's router in front of `models/experts.py::expert_ffn` (the
+    held experts' part of the routed FFN, shared by every expert model):
+    the top `top_k` of the router's logits over ALL `experts`, softmax over
+    the chosen.  u [rows, dim] (already normed), valid bool [rows] or None
+    -> (out [rows, dim], counters int32 [3])."""
+    from . import experts
 
     dtype = jnp.dtype(cfg.dtype)
-    rows, k = u.shape[0], cfg.top_k
-    first, held = cfg.experts_held
     scores = (u @ blk["router"].astype(dtype)).astype(jnp.float32)
-    top, idx = jax.lax.top_k(scores, k)
-    gate = jax.nn.softmax(top, axis=-1).T                     # [k, rows]
-    local = idx.astype(jnp.int32).T - first
-    mine = (local >= 0) & (local < held)
-    if valid is not None:
-        mine &= valid[None, :]
-    expert = jnp.where(mine, local, held).reshape(k * rows)
-    # a block per ~expert's share of the pairs: 128 rows where experts see
-    # that many (prefill), 32 where a round gives each a handful (decode)
-    # (float32, the tests' type, tiles in 8s)
-    tm = 8 if dtype.itemsize == 4 else 128 if rows * k >= 64 * held else 32
-    g = group_rows(expert, held, tm)
-    # a place that holds no pair reads `k * rows`, so row 0: any real row does
-    xb = jnp.take(u, g.source % rows, axis=0, mode="clip")
-    hid = grouped_matmul(xb, blk["w1"].astype(dtype), g.block_expert,
-                         g.live_blocks, tm)
-    half = hid.shape[-1] // 2
-    act = jax.nn.silu(hid[:, :half]) * hid[:, half:]
-    out = grouped_matmul(act, blk["w2"].astype(dtype), g.block_expert,
-                         g.live_blocks, tm)
-    pairs = jnp.take(out, g.dest, axis=0, mode="clip")        # [k * rows, dim]
-    # the cast is per slice, inside the sum: on the whole array it is a
-    # pass of its own.  A pair that is not `mine` was gathered from a block
-    # nothing wrote: `where` on the product, so that a NaN there stays out
-    total = sum(jnp.where(mine[j, :, None],
-                          pairs[j * rows:(j + 1) * rows].astype(jnp.float32)
-                          * gate[j, :, None], 0.0) for j in range(k))
-    counters = jnp.stack([jnp.sum(g.sizes), jnp.sum(g.sizes > 0),
-                          jnp.max(g.sizes)]).astype(jnp.int32)
-    return total.astype(dtype), counters
+    top, idx = jax.lax.top_k(scores, cfg.top_k)
+    return experts.expert_ffn(u, idx, jax.nn.softmax(top, axis=-1),
+                              blk["w1"], blk["w2"], cfg.experts_held, dtype,
+                              valid)
 
 
 def mamba_mixer(cfg: GraniteHybridConfig, blk, u, carry, valid):

@@ -5,4 +5,4 @@ from .flash_attention import (chunk_attention, decode_attention,  # noqa: F401
                               flash_paged_decode_attention,
                               flash_paged_decode_quant_attention,
                               gather_pages, kv_dequantize, kv_quantize,
-                              paged_decode_attention)
+                              paged_decode_attention, window_attention)

@@ -1044,3 +1044,30 @@ def chunk_attention(q, k, v, q_pos, scale: Optional[float] = None,
         return _chunk_attention_xla(q, k, v, q_pos, scale)
     raise ValueError(f"unknown prefill attention backend {backend!r}; "
                      f"expected auto|xla")
+
+
+def window_attention(q, k, v, q_pos, k_pos, window: int,
+                     scale: Optional[float] = None):
+    """Attention of a layer that sees its last `window` positions, over keys
+    that come with their absolute positions: q [b, h, c, hd] at `q_pos`
+    (int32 [b, c]), k / v [b, kv_heads, n, hd] at `k_pos` (int32 [b, n]; a
+    negative one marks a row that holds nothing).  Key j is visible to
+    query i iff q_pos[i] - window < k_pos[j] <= q_pos[i] — by position, not
+    by place, so the keys may be a ring in any rotation, with a chunk's own
+    keys after it (`models/decoder.py::Ring`).  Every query head of a GQA
+    group attends its KV head as it is: nothing is repeated.  A masked
+    dot_general, as `_chunk_attention_xla`: n is a window and a chunk, not
+    a bucket."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    b, h, c, d = q.shape
+    kvh = k.shape[1]
+    s = jnp.einsum("bgrqd,bgkd->bgrqk",
+                   q.reshape(b, kvh, h // kvh, c, d).astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    qp = q_pos.astype(jnp.int32)[:, None, None, :, None]
+    kp = k_pos.astype(jnp.int32)[:, None, None, None, :]
+    s = jnp.where((kp >= 0) & (kp <= qp) & (kp > qp - window), s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bgrqk,bgkd->bgrqd", p, v.astype(jnp.float32))
+    return out.reshape(b, h, c, d).astype(q.dtype)

@@ -195,6 +195,13 @@ class _BucketPool:
         return len(self.slots)
 
 
+def _ring_bytes(arena) -> int:
+    """The bytes of the window layers' rings in a pool's pytree
+    (`models/decoder.py::Ring`; 0 for a model without window layers)."""
+    return sum(int(leaf.nbytes) for key in ("ring_k", "ring_v")
+               for leaf in arena.get(key, ()))
+
+
 class _PagedPool:
     """The paged layout's single pool: one preallocated page arena, a
     refcounted page allocator, and a fixed [n_slots, max_pages] page
@@ -223,13 +230,18 @@ class _PagedPool:
         # — kv/arena.py; every leaf is donated to each compiled step
         pages = init_pages(n_pages, chunk)
         self.arena = pages
-        # a model with state layers: its recurrent state lives in the same
-        # donated pytree, a slot per sequence (kv/state.py) — the slot a
-        # request is admitted into is its table row AND its state row
+        # a model that keeps something a SEQUENCE (recurrent state, the
+        # rings of window layers): it lives in the same donated pytree, a
+        # slot per sequence (kv/state.py) — the slot a request is admitted
+        # into is its table row AND its row of every such leaf.  Pages,
+        # below, are of the layers that cache every position, and of those
+        # alone.
         self.state: Optional[StatePool] = None
+        self.ring_bytes = 0
         if init_state is not None:
             self.state = StatePool(n_slots)
             self.arena = {**pages, **init_state(n_slots)}
+            self.ring_bytes = _ring_bytes(self.arena)
         # size pages from the arena's STORAGE leaves — quantized arenas
         # charge int8 payload + f32 scales, not the model dtype, which is
         # exactly the density win the kv_quant_bytes_saved gauge reports
@@ -352,8 +364,9 @@ class _PagedPool:
         return self.pool.in_use, tokens
 
     def take_slot(self) -> int:
-        """A free slot: its table row and, for a model with state layers,
-        its state row (zeroed by the first chunk, which starts fresh)."""
+        """A free slot: its table row and, for a model that keeps slots,
+        its state row (zeroed by the first chunk, which starts fresh) and
+        its rings (whose rows of an earlier tenant no position reaches)."""
         slot_idx = self.free.pop()
         if self.state is not None:
             self.state.take(slot_idx)
@@ -412,9 +425,8 @@ class GenerationSession:
                     f"decode_buckets {bad} exceed the model's maximum "
                     f"sequence length {model.max_positions}; set "
                     f"ServeConfig(decode_buckets=...) within it")
-        self._stateful = bool(model.state_layers)
-        if self._stateful:
-            self._refuse_for_state_layers(self.config)
+        self._per_sequence = model.per_sequence
+        self._refuse_for_slots(model, self.config)
         self._model = model
         self.params = params
         self.mesh = mesh
@@ -587,7 +599,7 @@ class GenerationSession:
             "chunk": _prefill_chunk_paged, "decode": _decode_paged,
             "export": _page_export, "import": _page_import,
             "verify": _verify_paged}
-        if self._stateful:
+        if self._per_sequence:
             self._paged_defs.update(chunk_state=_prefill_chunk_paged_state,
                                     decode_state=_decode_paged_state)
 
@@ -625,31 +637,46 @@ class GenerationSession:
          self._paged_cs, self._verify_cs) = shared
 
     @staticmethod
-    def _refuse_for_state_layers(cfg: ServeConfig) -> None:
-        """A state layer caches one state a sequence, not rows a position:
-        what assumes rows is refused here, loudly, until it is built."""
-        why = None
-        if cfg.kv_layout != "paged":
-            why = ("kv_layout='bucketed': the state pool lives beside the "
-                   "page pool; set kv_layout='paged'")
-        elif cfg.kv_host_tier_bytes:
-            why = ("the host tier: it demotes trie pages, and the trie is "
-                   "refused too; set kv_host_tier_bytes=0")
-        elif cfg.enable_prefix_cache and cfg.prefix_cache_bytes:
-            why = ("the prefix trie: a restored prefix needs the state as "
-                   "it was at that chunk's boundary, and no snapshot is "
-                   "kept; set enable_prefix_cache=False")
-        elif cfg.speculate_k:
-            why = ("speculation: a rejected draft cannot be masked out of "
-                   "a recurrent state, and there is no roll-back; set "
-                   "speculate_k=0")
-        elif cfg.kv_quant_dtype not in (None, "none"):
-            why = ("the int8 arena: not measured against a model whose "
-                   "logits also ride a float32 state; set "
-                   "kv_quant_dtype='none'")
-        if why:
-            raise ValueError(f"a model with state layers cannot be served "
-                             f"with {why}")
+    def _refuse_for_slots(model, cfg: ServeConfig) -> None:
+        """A state layer caches one state a sequence and a window layer a
+        ring of its last positions, not rows for every position: what
+        assumes those rows is refused here, loudly, until it is built.
+        One row a feature: (asked for, its name, the setting that drops it,
+        why it cannot be with state layers, why not with window rings)."""
+        refusals = (
+            (cfg.kv_layout != "paged", "kv_layout='bucketed'",
+             "kv_layout='paged'",
+             "the state pool lives beside the page pool",
+             "the rings live beside the page pool"),
+            (cfg.kv_host_tier_bytes, "the host tier",
+             "kv_host_tier_bytes=0",
+             "it demotes trie pages, and the trie is refused too",
+             "it demotes trie pages, and the trie is refused too"),
+            (cfg.enable_prefix_cache and cfg.prefix_cache_bytes,
+             "the prefix trie", "enable_prefix_cache=False",
+             "a restored prefix needs the state as it was at that chunk's "
+             "boundary, and no snapshot is kept",
+             "a restored or resumed prefix needs the ring as it was at "
+             "that chunk's boundary, and no snapshot is kept"),
+            (cfg.speculate_k, "speculation", "speculate_k=0",
+             "a rejected draft cannot be masked out of a recurrent state, "
+             "and there is no roll-back",
+             "a rejected draft has overwritten ring rows of positions "
+             "still inside the window, and there is no roll-back"),
+            (cfg.kv_quant_dtype not in (None, "none"), "the int8 arena",
+             "kv_quant_dtype='none'",
+             "not measured against a model whose logits also ride a "
+             "float32 state",
+             "the rings are kept exact, and a model whose layers read "
+             "int8 and exact keys side by side is not measured"),
+        )
+        for prop, has, why in (("state layers", model.state_layers, 3),
+                               ("window rings", model.ring_windows, 4)):
+            for row in refusals if has else ():
+                if row[0]:
+                    raise ValueError(
+                        f"a model with {prop} cannot be served with "
+                        f"{row[1]}: {row[why]}; set {row[2]}")
 
     def _extract_for(self, chunk_len: int) -> Callable:
         """Compiled chunk extractor for one chunk size (the slice size
@@ -764,7 +791,7 @@ class GenerationSession:
                     export_page=self._export_arena_page,
                     model_itemsize=self._model_itemsize(),
                     init_state=self._state_factory
-                    if self._stateful else None)
+                    if self._per_sequence else None)
             else:
                 pool = _BucketPool(
                     bucket, cfg.max_decode_slots, self._cache_factory,
@@ -792,7 +819,7 @@ class GenerationSession:
     def _state_factory(self, n_slots: int):
         from easydist_tpu.models.decoder import State
 
-        return State.init(self._model, n_slots)
+        return State.init(self._model, n_slots, self.config.kv_cache_dtype)
 
     def _model_itemsize(self) -> int:
         """Bytes per element at model precision (first param leaf) — the
@@ -1040,7 +1067,7 @@ class GenerationSession:
                     lengths[row] = len(job.prompt)
                     tbl[row] = pool.table.array[job.slot_idx]
                 program, extra = "chunk", ()
-                if self._stateful:   # each row's state slot, too
+                if self._per_sequence:   # each row's state slot, too
                     slots = np.full((pool.n_rows,), pool.state.sentinel,
                                     np.int32)
                     for row, job in pool.jobs.items():
@@ -1232,7 +1259,7 @@ class GenerationSession:
                 for idx in live:
                     tbl[idx] = pool.table.array[idx]
                 program, extra = "decode", ()
-                if self._stateful:   # which rows are sequences, too
+                if self._per_sequence:   # which rows are sequences, too
                     alive = np.zeros((pool.n_slots,), bool)
                     alive[live] = True
                     program, extra = "decode_state", (jnp.asarray(alive),)
@@ -1269,6 +1296,9 @@ class GenerationSession:
                 self._maybe_retire(pool, idx)
             self.metrics.record_decode_step(len(live), pool.n_slots,
                                             sp.seconds)
+            # the positions this round attended, its own included
+            self.metrics.set_gauge("kv_tokens_live",
+                                   int(pos[live].sum()) + len(live))
             if len(nxt) > pool.n_slots:   # the round's expert counters
                 self.metrics.record_moe("decode", *nxt[pool.n_slots:])
             if self._paged:
@@ -1283,6 +1313,11 @@ class GenerationSession:
         if pool.state is not None:
             self.metrics.record_state_pool(pool.state.in_use,
                                            pool.state.n_slots)
+        if pool.ring_bytes:
+            # read off the leaves the last program handed back: a window
+            # layer's bytes do not grow with its sequences
+            self.metrics.record_window_rings(pool.state.in_use,
+                                             _ring_bytes(pool.arena))
 
     # ------------------------------------------------ speculative decoding
     def _spec_round(self, pool) -> bool:

@@ -248,6 +248,14 @@ class ServeMetrics:
             self._gauges["state_slots_in_use"] = slots_in_use
             self._gauges["state_slots"] = n_slots
 
+    def record_window_rings(self, slots_in_use: int, ring_bytes: int) -> None:
+        """The rings of a model's window layers: a slot per admitted
+        sequence, and the bytes of ALL the rings as the last program handed
+        them back — a constant, whatever the sequences' lengths."""
+        with self._lock:
+            self._gauges["window_ring_slots_in_use"] = slots_in_use
+            self._gauges["window_ring_bytes"] = ring_bytes
+
     def record_moe(self, step: str, pairs_routed: int, experts_hit: int,
                    max_expert_pairs: int) -> None:
         """One program's expert routing, summed over the layers on the

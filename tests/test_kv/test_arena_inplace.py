@@ -325,6 +325,67 @@ def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
         "state update, paged decode attention, two grouped products a layer"
 
 
+def test_window_decode_step_writes_rings_and_arena_in_place_on_tpu(
+        v5e_chip, monkeypatch):
+    """The K-EXAONE cell's decode round (BENCHMARK.json: published widths,
+    64 slots, 16 held experts, pages of 256 tokens, a window of 128), a
+    sliding, a full and a sliding layer: the arena's two leaves and the
+    rings' four are donated and handed back through writes in place — the
+    program's temporaries stay under ONE ring leaf (16 MiB) — and the
+    kernels are the full layer's paged decode call and two grouped products
+    an expert layer: the rings are read by a fused dot, not by a kernel."""
+    import functools
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.models import exaone_moe as em
+    from easydist_tpu.models.decoder import Paged, State, decode
+    from easydist_tpu.ops import grouped_matmul as gm
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, backend="pallas", interpret=False))
+    cfg = em.ExaoneMoeConfig(
+        vocab=19200, layer_types=("sliding_attention", "full_attention",
+                                  "sliding_attention"),
+        mlp_layer_types=("dense", "sparse", "sparse"), experts_held=(0, 16))
+    dec = em.decoder(cfg)
+    slots, n_pages, pt, max_pages = 64, 2048, 256, 32
+
+    params = _described(v5e_chip, jax.eval_shape(
+        lambda key: em.exaone_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = _described(v5e_chip, jax.eval_shape(
+        lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
+    assert sorted(cache) == ["k", "ring_k", "ring_v", "v"]
+    assert len(cache["k"]) == 1 and len(cache["ring_k"]) == 2
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_chip)
+    table = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
+                                 sharding=v5e_chip)
+
+    def step(cache, params, table, live, token, pos):
+        pages, leaves = State.split(dec, cache)
+        st = State(leaves, live)
+        cache, logits = decode(dec, Paged(pages, table), params, token, pos,
+                               state=st)
+        return cache, jnp.argmax(logits, -1), st.counters
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        cache, params, table, live, rows, rows).compile()
+    page_leaf = n_pages * 8 * pt * 128 * 2
+    ring_leaf = slots * 8 * 128 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * page_leaf + 4 * ring_leaf
+    assert mem.temp_size_in_bytes < ring_leaf, \
+        "a ring or arena leaf is copied round its write"
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5, \
+        "paged decode attention on the full layer, two grouped products " \
+        "an expert layer"
+
+
 _ENTRY_LINE = re.compile(
     r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?)\s([a-z][a-z0-9\-]*)\(")
 _ARRAY = re.compile(r"([a-z]+[0-9]*)\[([0-9,]*)\]")
