@@ -8,7 +8,7 @@ against a reference computed beside it.  Phases, in order:
 
   clock    a chained bf16 matmul timed with plain `jax.block_until_ready` must
            land under the chip's datasheet peak (so the wait is real)
-  kernels  the five Pallas kernels of ops/flash_attention.py, compiled
+  kernels  the six Pallas kernels of ops/flash_attention.py, compiled
            (`interpret=False`), against the float32 XLA paths in that file;
            the paged ones at GPT-2 small's heads and at a GQA shape
   trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
@@ -223,6 +223,17 @@ def phase_kernels(batch=TRAIN_BATCH, heads=12, d=64, seq=1024, slots=8,
             lambda q, k, v, t, l: fa._paged_decode_attention_xla(
                 q, k, v, t, l, scale))(*f32(qd, kp, vp), table, lengths)
         errs[f"paged_decode{tag}"] = _close(f"paged decode{tag}", got, want_p)
+        # a chunk of queries at each row's last positions reads the same
+        # pages through the table (the chunk-prefill and verify programs)
+        start = jnp.maximum(lengths - page_tokens, 0)
+        pos = start[:, None] + jnp.arange(page_tokens, dtype=jnp.int32)
+        qc = jax.random.normal(jax.random.fold_in(keys[4], hd),
+                               (slots, qd.shape[1], page_tokens, hd), bf)
+        got = jax.jit(lambda *a: fa.flash_paged_chunk_attention(
+            *a, interpret=interpret))(qc, kp, vp, table, start + page_tokens)
+        want_c = reference(lambda *a: fa.paged_chunk_attention(
+            *a, backend="xla"))(*f32(qc, kp, vp), table, pos)
+        errs[f"paged_chunk{tag}"] = _close(f"paged chunk{tag}", got, want_c)
         for nb in (1, 2):
             kq, ks = fa.kv_quantize(kp.astype(jnp.float32), nb)
             vq, vs = fa.kv_quantize(vp.astype(jnp.float32), nb)

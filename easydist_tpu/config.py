@@ -271,10 +271,15 @@ decode_attention_backend = os.environ.get("EASYDIST_DECODE_ATTENTION",
 # program is O(block), independent of cache length).  TRACE-AFFECTING:
 # changes the pallas_call grid, so it salts the strategy cache too.
 decode_block_k = _env_int("EASYDIST_DECODE_BLOCK_K", 256)
-# attention backend for the chunked-prefill pass (`*_prefill_chunk`):
-# "auto" | "xla" — both resolve to the masked dot_general path today; the
-# knob reserves the dispatch point for a blocked Pallas prefill kernel.
-# TRACE-AFFECTING: part of the strategy-cache salt like the decode backend.
+# attention backend for a chunk of queries (chunked prefill, speculation
+# verify): "auto" | "paged" | "flash" | "xla", the decode knob's values.
+# "paged"/"flash" pick the Pallas kernel that reads a row's pages through
+# the table up to the row's extent (`paged_chunk_attention`; a paged arena
+# without scale leaves), "xla" the gather + masked dot_general path over
+# the whole bucket; "auto" is the kernel on TPU and "xla" elsewhere.  An
+# int8 arena and the contiguous layout take the "xla" path whatever it
+# says.  TRACE-AFFECTING: part of the strategy-cache salt like the decode
+# backend.
 prefill_attention_backend = os.environ.get("EASYDIST_PREFILL_ATTENTION",
                                            "auto")
 # speculative decoding defaults (`ServeConfig.speculate_k` /

@@ -259,7 +259,8 @@ class Paged:
 
     def attend(self, dec: Decoder, q, pos):
         from easydist_tpu.ops import (chunk_attention, gather_pages,
-                                      kv_dequantize, paged_decode_attention)
+                                      kv_dequantize, paged_chunk_attention,
+                                      paged_decode_attention)
 
         tbl, quant = self._tbl, self._quant_nb
         last = {key: leaves[-1] for key, leaves in self._new.items()}
@@ -273,16 +274,21 @@ class Paged:
             return paged_decode_attention(
                 q, last["k"].astype(dec.dtype), last["v"].astype(dec.dtype),
                 tbl, pos + 1)
+        if not quant:
+            # a chunk of queries (prefill, verify) reads its row's pages
+            # through the table too, as far as the row's extent
+            return paged_chunk_attention(
+                q, last["k"].astype(dec.dtype), last["v"].astype(dec.dtype),
+                tbl, pos)
 
         def virtual(key):
-            # the contiguous cache the table describes, GQA-repeated AFTER
-            # the gather (payload and scales alike, so dequant commutes)
-            got = gather_pages(last[key], tbl, n_heads=dec.heads)
-            if quant:
-                return kv_dequantize(
-                    got, gather_pages(last[key + "_scale"], tbl,
-                                      n_heads=dec.heads), dec.dtype)
-            return got.astype(dec.dtype)
+            # int8 pages: the contiguous cache the table describes,
+            # GQA-repeated AFTER the gather (payload and scales alike, so
+            # dequant commutes)
+            return kv_dequantize(
+                gather_pages(last[key], tbl, n_heads=dec.heads),
+                gather_pages(last[key + "_scale"], tbl, n_heads=dec.heads),
+                dec.dtype)
 
         return chunk_attention(q, virtual("k"), virtual("v"), pos)
 

@@ -441,11 +441,14 @@ def flash_attention(q, k, v, causal: bool = True,
 def _decode_block_update(q, k_blk, v_blk, k0, length, scale, o_scr, m_scr,
                          l_scr):
     """Online-softmax update of `r` query rows per KV head against one K/V
-    block, shared by the three decode kernels: q [g, r, d], k_blk/v_blk
-    [g, t, d] (g KV heads, r = the GQA group; 1, 1 for the contiguous
-    kernel), each still in the dtype it was stored in.  `k0` is the block's
-    first cache position and positions >= `length` are masked (unwritten
-    slots, not future tokens).  q . K^T goes to the MXU in the operands' own
+    block, shared by the decode kernels and the paged chunk kernel: q
+    [g, r, d], k_blk/v_blk [g, t, d] (g KV heads, r = the GQA group; 1, 1
+    for the contiguous kernel; group x chunk for a chunk of queries), each
+    still in the dtype it was stored in.  `k0` is the block's first cache
+    position and positions >= `length` are masked: a scalar for one query a
+    row (unwritten slots, not future tokens), int32 [1, r, 1] where every
+    query row has its own (the chunk kernel: position + 1, which is the
+    causal mask too).  q . K^T goes to the MXU in the operands' own
     dtype when they share one (bf16 products are exact in the f32
     accumulator) and in f32 otherwise; scores, statistics, p and the
     accumulator are f32, and p . V runs in f32."""
@@ -724,32 +727,44 @@ def _largest_divisor(n: int, fits) -> int:
     return next((m for m in range(n, 1, -1) if n % m == 0 and fits(m)), 1)
 
 
-def _paged_step_bytes(pages, g: int, n: int) -> int:
+def _paged_step_bytes(pages, g: int, n: int, rows: int = 0) -> int:
     """VMEM one grid step of the paged kernels takes with g KV heads of n
     pages: every `pages` operand's block double-buffered, and the f32
-    working copies of one page's K and V."""
+    working copies of one page's K and V.  `rows` query rows a KV head
+    count where they are many (a chunk of queries; a decode round's GQA
+    group is left out): q and the output double-buffered, the accumulator
+    and the two statistics, and a page's f32 scores and probabilities."""
     _, _, pt, d = pages[0].shape
-    return (2 * n * sum(_vmem_block_bytes((g, pt, a.shape[-1]), a.dtype)
+    step = (2 * n * sum(_vmem_block_bytes((g, pt, a.shape[-1]), a.dtype)
                         for a in pages)
             + 4 * _vmem_block_bytes((g, pt, d), jnp.float32))
+    if rows:
+        step += (4 * _vmem_block_bytes((g, rows, d), pages[0].dtype)
+                 + _vmem_block_bytes((g, rows, d), jnp.float32)
+                 + 2 * _vmem_block_bytes((g, rows, 1), jnp.float32)
+                 + 2 * _vmem_block_bytes((g, rows, pt), jnp.float32))
+    return step
 
 
-def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None):
+def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None,
+                      rows: int = 0):
     """(KV heads, pages) one grid step of the paged kernels holds, from the
     shapes and dtypes of the `pages` operands ([n_pages, kv_heads,
-    page_tokens, *] each; K first) alone.  Heads: all of them — a page is
-    then one contiguous block — unless one such page is over
-    `_PAGED_VMEM_BUDGET` (then the largest divisor of kv_heads that fits).
-    Pages: the largest divisor of `max_pages` that is at most `want`
-    (default: `_PAGED_STEP_TOKENS` worth) and fits the budget."""
+    page_tokens, *] each; K first) and the query `rows` a KV head
+    (`_paged_step_bytes`) alone.  Heads: all of them — a page is then one
+    contiguous block — unless one such page is over `_PAGED_VMEM_BUDGET`
+    (then the largest divisor of kv_heads that fits).  Pages: the largest
+    divisor of `max_pages` that is at most `want` (default:
+    `_PAGED_STEP_TOKENS` worth) and fits the budget."""
     _, kvh, pt, _ = pages[0].shape
     g = _largest_divisor(
-        kvh, lambda g: _paged_step_bytes(pages, g, 1) <= _PAGED_VMEM_BUDGET)
+        kvh, lambda g: _paged_step_bytes(pages, g, 1, rows)
+        <= _PAGED_VMEM_BUDGET)
     if want is None:
         want = max(_PAGED_STEP_TOKENS // pt, 1)
     n = _largest_divisor(
         max_pages, lambda n: n <= want
-        and _paged_step_bytes(pages, g, n) <= _PAGED_VMEM_BUDGET)
+        and _paged_step_bytes(pages, g, n, rows) <= _PAGED_VMEM_BUDGET)
     return g, n
 
 
@@ -777,69 +792,113 @@ def _q_map(bi, gi, pi, tbl_ref, len_ref):
     return (bi, gi, 0, 0)
 
 
-def _paged_decode_call(kernel, name: str, q, pages, table, lengths,
-                       pages_per_step: Optional[int], interpret: bool):
-    """The pallas_call shared by the exact and the int8 paged kernels
-    (`name`: `paged_decode` or `paged_decode_int8`, as the trace shows it).
+@functools.lru_cache(maxsize=128)
+def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
+                pages_per_step: Optional[int], interpret: bool,
+                chunk: int = 0):
+    """The pallas_call the paged kernels share (`name`: `paged_decode`,
+    `paged_decode_int8` or `paged_chunk`, as the trace shows it), built
+    ONCE a signature: `q_view` and each of `pages` are (shape, dtype name).
+    A model's layers call a kernel at one signature, and the function this
+    returns is a `jax.jit`, so the second layer's call finds the first's
+    trace: a program's equations then carry ONE kernel jaxpr and ONE grid
+    mapping, the body is traced once a process and lowered to a Mosaic
+    module once a lowering (jax keys an equation's lowering by its
+    parameters) — not once a layer, which on a 16-layer model was seconds
+    of every start (PERF.md section 6, PR 35).
+
     Grid (batch, kv_heads / g, max_pages / n) with g and n from
-    `_paged_step_shape`: one step serves EVERY query head of g KV heads (as
-    a rule all heads of the row) over n consecutive windows of the row's
-    page table, so a live K/V row leaves HBM once, whatever the GQA group.
-    Pages are not contiguous in the arena, so every `pages` operand
-    ([n_pages, kv_heads, page_tokens, *]) is passed n times, the j-th copy
-    with the index map of window pi * n + j: BlockSpec pipelining issues
-    the n page copies of the next step while this one computes.  Table and
-    lengths are scalar-prefetched.  q and the output ride a
-    [batch, kv_heads, group, head_dim] view, whose blocks' trailing dims
-    equal the array's."""
-    b, h, d = q.shape
-    n_pages, kvh, pt, _ = pages[0].shape
-    mp = table.shape[1]
-    if h % kvh:
-        raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
-    rep = h // kvh
-    g, n_step = _paged_step_shape(mp, pages, pages_per_step)
-    qo_spec = pl.BlockSpec((1, g, rep, d), _q_map)
+    `_paged_step_shape`: one step serves EVERY query row of g KV heads (in
+    a decode round as a rule all heads of the row) over n consecutive
+    windows of the row's page table, so a live K/V row leaves HBM once,
+    whatever the GQA group.  Pages are not contiguous in the arena, so
+    every `pages` operand ([n_pages, kv_heads, page_tokens, *]) is passed
+    n times, the j-th copy with the index map of window pi * n + j:
+    BlockSpec pipelining issues the n page copies of the next step while
+    this one computes.  Table and lengths are scalar-prefetched.  q and
+    the output ride a [batch, kv_heads, rows, head_dim] view (rows: the
+    GQA group, times `chunk` queries for the chunk kernel), whose blocks'
+    trailing dims equal the array's.  Returns (n, the call)."""
+    (b, kvh, rows, d), q_dtype = q_view
+    avals = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in pages]
+    n_pages, _, pt, _ = avals[0].shape
+    g, n_step = _paged_step_shape(max_pages, avals, pages_per_step,
+                                  rows if chunk else 0)
+    qo_spec = pl.BlockSpec((1, g, rows, d), _q_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh // g, mp // n_step),
+        grid=(b, kvh // g, max_pages // n_step),
         in_specs=[qo_spec] + [
             pl.BlockSpec((1, g, pt, a.shape[-1]),
                          _paged_kv_index_map(j, n_step, pt, n_pages))
-            for a in pages for j in range(n_step)],
+            for a in avals for j in range(n_step)],
         out_specs=qo_spec,
-        scratch_shapes=_decode_scratch(g, rep, d),
+        scratch_shapes=_decode_scratch(g, rows, d),
     )
+    return n_step, pl.pallas_call(
+        functools.partial(body, scale=scale, page_tokens=pt, n_step=n_step,
+                          chunk=chunk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )
+
+
+def _paged_attend(body, name: str, scale: float, q_view, pages, table,
+                  lengths, pages_per_step: Optional[int], interpret: bool,
+                  chunk: int = 0):
+    """`_paged_call` at the operands' signature, applied: `q_view` is q as
+    [batch, kv_heads, rows, head_dim], and so is the result."""
+    n_step, call = _paged_call(
+        body, name, float(scale), (q_view.shape, q_view.dtype.name),
+        tuple((a.shape, a.dtype.name) for a in pages), table.shape[1],
+        pages_per_step, bool(interpret), chunk)
     with jax.named_scope(name):
-        out = pl.pallas_call(
-            functools.partial(kernel, page_tokens=pt, n_step=n_step),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-            name=name,
-        )(jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-          q.reshape(b, kvh, rep, d),
-          *[a for a in pages for _ in range(n_step)])
+        return call(jnp.asarray(table, jnp.int32),
+                    jnp.asarray(lengths, jnp.int32), q_view,
+                    *[a for a in pages for _ in range(n_step)])
+
+
+def _paged_decode_call(body, name: str, scale: float, q, pages, table,
+                       lengths, pages_per_step: Optional[int],
+                       interpret: bool):
+    """One query a row: q [batch, heads, head_dim] rides the view as
+    [batch, kv_heads, group, head_dim]."""
+    b, h, d = q.shape
+    kvh = pages[0].shape[1]
+    if h % kvh:
+        raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
+    out = _paged_attend(body, name, scale, q.reshape(b, kvh, h // kvh, d),
+                        pages, table, lengths, pages_per_step, interpret)
     return out.reshape(b, h, d)
 
 
 def _paged_decode_steps(len_ref, q_ref, o_ref, o_scr, m_scr, l_scr, load_page,
-                        *, scale: float, page_tokens: int, n_step: int):
-    """The body both paged kernels share.  Grid step (bi, gi, pi) holds the
+                        *, scale: float, page_tokens: int, n_step: int,
+                        chunk: int = 0):
+    """The body the paged kernels share.  Grid step (bi, gi, pi) holds the
     pages of windows [pi * n_step, (pi + 1) * n_step) of row bi (the
     BlockSpec index maps, not this body, chased the table); `load_page(j)`
     gives the j-th one's K and V as [g, page_tokens, d].  A step that starts
     past the row's length is skipped whole, a dead page inside a live step
-    one by one."""
+    one by one.  With `chunk`, the block's rows are the GQA group x a chunk
+    of queries (the chunk padded to a whole tile) at the row's LAST `chunk`
+    positions, and each sees the keys up to its own."""
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
         _decode_init(o_scr, m_scr, l_scr)
 
-    length = len_ref[pl.program_id(0)]
+    length = limit = len_ref[pl.program_id(0)]
+    if chunk:
+        rows = q_ref.shape[2]
+        limit = length - chunk + 1 + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1),
+            _chunk_rows(chunk))
 
     @pl.when(pi * n_step * page_tokens < length)
     def _live_step():
@@ -850,7 +909,7 @@ def _paged_decode_steps(len_ref, q_ref, o_ref, o_scr, m_scr, l_scr, load_page,
             @pl.when(k0 < length)
             def _page():
                 k_blk, v_blk = load_page(j)
-                _decode_block_update(q, k_blk, v_blk, k0, length, scale,
+                _decode_block_update(q, k_blk, v_blk, k0, limit, scale,
                                      o_scr, m_scr, l_scr)
 
     @pl.when(pi == pl.num_programs(2) - 1)
@@ -862,7 +921,8 @@ def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, n_step: int,
                                **kw):
     """The single-query decode kernel with the K/V stream indirected
     through the page table: `refs` are n_step K pages, n_step V pages, the
-    output and the scratch."""
+    output and the scratch.  With `chunk` in `kw` it is the chunk kernel
+    (`flash_paged_chunk_attention`)."""
     k_refs, v_refs = refs[:n_step], refs[n_step:2 * n_step]
     _paged_decode_steps(
         len_ref, q_ref, *refs[2 * n_step:],
@@ -892,9 +952,57 @@ def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = _default_interpret()
-    kernel = functools.partial(_flash_paged_decode_kernel, scale=scale)
-    return _paged_decode_call(kernel, "paged_decode", q, (k_pages, v_pages),
-                              table, lengths, pages_per_step, interpret)
+    return _paged_decode_call(_flash_paged_decode_kernel, "paged_decode",
+                              scale, q, (k_pages, v_pages), table, lengths,
+                              pages_per_step, interpret)
+
+
+def _chunk_rows(chunk: int) -> int:
+    """The rows a chunk of queries takes in the kernel's block: the chunk
+    padded to a whole bf16 tile (16 rows), so that a verify step's few
+    queries are a block Mosaic lays out as any other."""
+    return -(-chunk // 16) * 16
+
+
+def flash_paged_chunk_attention(q, k_pages, v_pages, table, extents,
+                                scale: Optional[float] = None,
+                                pages_per_step: Optional[int] = None,
+                                interpret: Optional[bool] = None):
+    """A chunk of queries a row through a page table (chunked prefill, and
+    the speculation verify step): the paged decode kernel with group x
+    chunk query rows a KV head and each query's own limit.
+
+    q: [rows, heads, chunk, head_dim], row r's queries at the LAST `chunk`
+    positions of its extent, `extents[r] - chunk + [0..chunk)` (int32
+    [rows]; 0 marks a row that holds no sequence, which reads nothing and
+    gives zeros); k_pages/v_pages: [n_pages, kv_heads, page_tokens,
+    head_dim] arena layers AFTER the chunk's own write; table: int32
+    [rows, max_pages].  A key at position kp is visible to a query at qp
+    iff kp <= qp — `_chunk_attention_xla`'s one rule: the causal mask
+    inside the chunk and the validity mask over what an earlier tenant
+    left in a page.  Only the pages under a row's extent leave HBM, each
+    once a GQA group: the group is folded into the rows of the matmuls
+    ([group x chunk, head_dim] x [head_dim, page_tokens]), nothing is
+    gathered, repeated or copied to float32 outside VMEM.  Grid, index
+    maps and online softmax are the decode kernel's (`_paged_call`), the
+    KV heads a step holds bounded by what the query rows take in VMEM.
+    Returns [rows, heads, chunk, head_dim]."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if interpret is None:
+        interpret = _default_interpret()
+    b, h, c, d = q.shape
+    kvh = k_pages.shape[1]
+    if h % kvh:
+        raise ValueError(f"heads {h} not a multiple of kv_heads {kvh}")
+    pad = _chunk_rows(c) - c
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    out = _paged_attend(
+        _flash_paged_decode_kernel, "paged_chunk", scale,
+        q.reshape(b, kvh, (h // kvh) * (c + pad), d), (k_pages, v_pages),
+        table, extents, pages_per_step, interpret, chunk=c)
+    return out.reshape(b, h, c + pad, d)[:, :, :c]
 
 
 def _dequant_block(blk_ref, s_ref):
@@ -953,8 +1061,8 @@ def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = _default_interpret()
-    kernel = functools.partial(_flash_paged_decode_quant_kernel, scale=scale)
-    return _paged_decode_call(kernel, "paged_decode_int8", q,
+    return _paged_decode_call(_flash_paged_decode_quant_kernel,
+                              "paged_decode_int8", scale, q,
                               (k_pages, v_pages, k_scale, v_scale), table,
                               lengths, pages_per_step, interpret)
 
@@ -1027,23 +1135,62 @@ def _chunk_attention_xla(q, k, v, q_pos, scale: float):
 
 def chunk_attention(q, k, v, q_pos, scale: Optional[float] = None,
                     backend: Optional[str] = None):
-    """Backend-dispatching chunked-prefill attention (the models'
-    `*_prefill_chunk` call this): q is a fixed-size token chunk at
-    absolute positions `q_pos`, k/v are the full bucket-length cache.
-    `EASYDIST_PREFILL_ATTENTION` forces the backend; today both "auto"
-    and "xla" resolve to the masked dot_general path (a blocked Pallas
-    variant can slot in behind the same knob), and the choice is part of
-    the strategy-cache salt like the decode backend."""
+    """Chunked-prefill attention over a CONTIGUOUS cache (the bucketed
+    layout's `*_prefill_chunk` and verify steps call this): q is a
+    fixed-size token chunk at absolute positions `q_pos`, k/v are the full
+    bucket-length cache.  Always the masked dot_general path, whatever
+    `EASYDIST_PREFILL_ATTENTION` says short of an unknown value: the
+    kernel chases a page table (`paged_chunk_attention`), and a contiguous
+    cache has none."""
     from easydist_tpu import config as edconfig
 
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if backend is None:
         backend = edconfig.prefill_attention_backend
-    if backend in ("auto", "xla"):
+    if backend in ("auto", "paged", "flash", "xla"):
         return _chunk_attention_xla(q, k, v, q_pos, scale)
     raise ValueError(f"unknown prefill attention backend {backend!r}; "
-                     f"expected auto|xla")
+                     f"expected auto|paged|flash|xla")
+
+
+def paged_chunk_attention(q, k_pages, v_pages, table, q_pos,
+                          scale: Optional[float] = None,
+                          backend: Optional[str] = None):
+    """Backend-dispatching chunk attention through a page table (the paged
+    layout's chunk-prefill and verify steps call this, for an arena
+    without scale leaves): q [rows, heads, chunk, head_dim] at CONSECUTIVE
+    absolute positions `q_pos` (int32 [rows, chunk]), the arena layer as
+    it is after the chunk's own write.  `EASYDIST_PREFILL_ATTENTION`
+    forces the backend as `EASYDIST_DECODE_ATTENTION` does the decode
+    round's: "paged"/"flash" pick `flash_paged_chunk_attention`, which
+    reads only the pages under each row's extent; "xla" gathers the whole
+    virtual cache, repeats it to the query heads and runs
+    `_chunk_attention_xla`; "auto" is the kernel on a TPU and "xla"
+    elsewhere.  The value is part of the strategy-cache salt.  A row whose
+    first window is unmapped holds no sequence: the kernel gives it zeros,
+    the gather path whatever the clipped page holds — nobody reads
+    either."""
+    from easydist_tpu import config as edconfig
+
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if backend is None:
+        backend = edconfig.prefill_attention_backend
+    if backend == "auto":
+        backend = "paged" if jax.default_backend() == "tpu" else "xla"
+    if backend in ("paged", "flash"):
+        live = table[:, 0].astype(jnp.int32) < k_pages.shape[0]
+        extents = jnp.where(live, q_pos[:, -1].astype(jnp.int32) + 1, 0)
+        return flash_paged_chunk_attention(q, k_pages, v_pages, table,
+                                           extents, scale=scale)
+    if backend == "xla":
+        h = q.shape[1]
+        return _chunk_attention_xla(
+            q, gather_pages(k_pages, table, n_heads=h),
+            gather_pages(v_pages, table, n_heads=h), q_pos, scale)
+    raise ValueError(f"unknown prefill attention backend {backend!r}; "
+                     f"expected auto|paged|flash|xla")
 
 
 def window_attention(q, k, v, q_pos, k_pos, window: int,

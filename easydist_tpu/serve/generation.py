@@ -1095,8 +1095,12 @@ class GenerationSession:
             pool.arena, first, sp = self._run(
                 "easydist.serve.prefill.call", result, args,
                 rows=pool.n_rows, chunk=c_len)
-            self.metrics.record_prefill_chunk(pool.n_rows, c_len,
-                                              sp.seconds)
+            # a chunk fills one page: a live row's extent ends with it
+            self.metrics.record_prefill_chunk(
+                pool.n_rows, c_len, sp.seconds,
+                pages_walked=sum(job.start // c_len + 1
+                                 for job in pool.jobs.values()),
+                pages_bucket=len(pool.jobs) * pool.max_pages)
             if len(first) > pool.n_rows:   # the call's expert counters
                 self.metrics.record_moe("prefill", *first[pool.n_rows:])
             calls += 1

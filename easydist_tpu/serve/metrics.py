@@ -73,7 +73,10 @@ class ServeMetrics:
       oom_degradations, transient_retries, exec_timeouts (watchdog),
       tokens_generated (decode steps x active slots).
     Chunked-prefill counters: prefills (admissions), prefill_chunks
-      (batched chunk calls), prefill_tokens_real (prompt tokens actually
+      (batched chunk calls), prefill_pages_walked / prefill_pages_bucket
+      (paged: the K/V pages under the live rows' extents, of those their
+      buckets hold — the share of a bucket the chunk kernel reads),
+      prefill_tokens_real (prompt tokens actually
       needing prefill, prefix reuse already deducted),
       prefill_tokens_padded (executed token slots = rows x chunk per
       call), prefix_tokens_reused / prefix_tokens_total,
@@ -205,13 +208,24 @@ class ServeMetrics:
                 self._counters["prefix_tokens_reused"] / total
 
     def record_prefill_chunk(self, n_rows: int, chunk: int,
-                             chunk_s: float) -> None:
+                             chunk_s: float, pages_walked: int = 0,
+                             pages_bucket: int = 0) -> None:
         """One batched chunk call: `n_rows` staging rows executed `chunk`
         token slots each (idle rows and padded tails included — that IS
-        the waste the padding-ratio gauge measures)."""
+        the waste the padding-ratio gauge measures).  A paged call also
+        says how many K/V pages its live rows' extents cover
+        (`pages_walked`: what the chunk kernel reads a layer) of how many
+        their buckets hold (`pages_bucket`: what the gather path reads)."""
         with self._lock:
             self._counters["prefill_chunks"] = \
                 self._counters.get("prefill_chunks", 0) + 1
+            if pages_bucket:
+                self._counters["prefill_pages_walked"] = \
+                    self._counters.get("prefill_pages_walked", 0) \
+                    + pages_walked
+                self._counters["prefill_pages_bucket"] = \
+                    self._counters.get("prefill_pages_bucket", 0) \
+                    + pages_bucket
             padded = self._counters["prefill_tokens_padded"] = \
                 self._counters.get("prefill_tokens_padded", 0) \
                 + n_rows * chunk

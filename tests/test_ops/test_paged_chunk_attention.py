@@ -1,0 +1,265 @@
+"""`flash_paged_chunk_attention` under the Pallas interpreter against the path
+it replaces on a TPU, `_chunk_attention_xla` over `gather_pages`: a chunk of
+queries reads its row's pages through the table and stops at the row's
+extent.  Then what a model's layers cost the host (one kernel trace a
+program, whatever the depth), and three tiny sessions whose greedy tokens
+must not know which backend served their prefill."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from easydist_tpu import config as edconfig
+from easydist_tpu.ops import (flash_paged_chunk_attention, gather_pages,
+                              paged_chunk_attention)
+
+fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+
+HEAD_DIM, KV_HEADS = 32, 2
+
+
+def _arena(rng, n_pages, page_tokens, dtype):
+    return tuple(jnp.asarray(rng.standard_normal(
+        (n_pages, KV_HEADS, page_tokens, HEAD_DIM)), dtype) for _ in "kv")
+
+
+def _case(group, chunk, page_tokens, starts, *, max_pages=6, dtype="bfloat16",
+          unmapped=(), seed=0):
+    """Row r's chunk starts at `starts[r]` (None: a row that holds no
+    sequence: an all-sentinel table row at start 0, as the session builds
+    it).  Every window a live row's extent touches is mapped to a page of
+    its own, the others hold the sentinel; `unmapped` lists (row, window)
+    pairs that hold it INSIDE the extent too."""
+    rng = np.random.default_rng(seed)
+    rows, n_pages = len(starts), len(starts) * max_pages + 3
+    k, v = _arena(rng, n_pages, page_tokens, dtype)
+    q = jnp.asarray(rng.standard_normal(
+        (rows, group * KV_HEADS, chunk, HEAD_DIM)), dtype)
+    table = np.full((rows, max_pages), n_pages, np.int32)
+    perm = rng.permutation(n_pages)
+    for r, start in enumerate(starts):
+        if start is not None:
+            used = -(-(start + chunk) // page_tokens)
+            table[r, :used] = perm[r * max_pages:r * max_pages + used]
+    for r, w in unmapped:
+        table[r, w] = n_pages
+    pos = np.asarray([[0 if s is None else s] for s in starts], np.int32) \
+        + np.arange(chunk, dtype=np.int32)[None, :]
+    live = np.asarray([s is not None for s in starts])
+    return q, k, v, jnp.asarray(table), jnp.asarray(pos), live
+
+
+CASES = {
+    # chunk == page, as chunked prefill runs it: first chunk, a middle one,
+    # the chunk that fills the bucket, and a row that holds no sequence
+    "prefill-group1": dict(group=1, chunk=16, page_tokens=16,
+                           starts=[0, 32, 80, None]),
+    "prefill-group4": dict(group=4, chunk=16, page_tokens=16,
+                           starts=[0, 32, 80, None]),
+    "prefill-group8": dict(group=8, chunk=16, page_tokens=16,
+                           starts=[80, None, 0, 48]),
+    "prefill-float32": dict(group=4, chunk=16, page_tokens=16,
+                            starts=[0, 64], dtype="float32"),
+    # several pages a grid step (the Mistral cell's pages of 64 under
+    # `_PAGED_STEP_TOKENS`): extents on both sides of a step's boundary
+    "prefill-pages-of-8": dict(group=4, chunk=8, page_tokens=8, max_pages=12,
+                               starts=[0, 24, 32, 88]),
+    # chunk < page, as a verify step runs it: inside the first page,
+    # straddling a boundary, ending at the bucket's last position
+    "verify-group1": dict(group=1, chunk=5, page_tokens=16,
+                          starts=[0, 3, 14, 91]),
+    "verify-group4": dict(group=4, chunk=5, page_tokens=16,
+                          starts=[7, 13, 30, None]),
+    "verify-group8": dict(group=8, chunk=3, page_tokens=16,
+                          starts=[15, 0, 93, 47]),
+    # an unmapped window INSIDE the extent clips to a real page, as
+    # `gather_pages` clips it
+    "sentinel-inside": dict(group=4, chunk=16, page_tokens=16,
+                            starts=[48, 80], unmapped=[(0, 1), (1, 4)]),
+    "all-rows-dead": dict(group=4, chunk=16, page_tokens=16,
+                          starts=[None, None]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_the_kernel_is_the_gather_path_on_real_rows(name):
+    q, k, v, table, pos, live = _case(**CASES[name])
+    want = fa._chunk_attention_xla(
+        q, gather_pages(k, table, n_heads=q.shape[1]),
+        gather_pages(v, table, n_heads=q.shape[1]), pos,
+        1.0 / np.sqrt(HEAD_DIM))
+    got = paged_chunk_attention(q, k, v, table, pos, backend="paged")
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # both accumulate in float32 and round once: a bf16 result may differ
+    # by the rounding of its last place
+    tol = 2e-2 if q.dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
+    # a row that holds no sequence reads nothing and gives zeros
+    assert not got[~live].any()
+    # the dispatcher's other arm is the gather path itself
+    same = paged_chunk_attention(q, k, v, table, pos, backend="xla")
+    np.testing.assert_array_equal(np.asarray(same, np.float32), want)
+
+
+@pytest.mark.parametrize("chunk,starts", [(16, [16, 48]), (5, [3, 30])],
+                         ids=["prefill", "verify"])
+def test_a_recycled_page_leaks_nothing_of_its_earlier_tenant(chunk, starts):
+    """What an earlier tenant left beyond a row's extent — in the rest of its
+    last page, and in the pages its table still names — never reaches the
+    result: the property SERVE002 audits for the gather path."""
+    q, k, v, table, pos, _ = _case(group=4, chunk=chunk, page_tokens=16,
+                                   starts=starts)
+    table = np.array(table)
+    extents = np.asarray(pos)[:, -1] + 1
+    stale_k, stale_v = np.array(k, np.float32), np.array(v, np.float32)
+    (spare, *_) = set(range(k.shape[0])) - set(table.ravel().tolist())
+    stale_k[spare], stale_v[spare] = 3e4, -3e4
+    for r, extent in enumerate(extents):
+        table[r, -(-extent // 16):] = spare     # mapped, not yet written
+        last = table[r, (extent - 1) // 16]
+        stale_k[last, :, extent % 16 or 16:] = 3e4
+        stale_v[last, :, extent % 16 or 16:] = -3e4
+    clean = flash_paged_chunk_attention(q, k, v, jnp.asarray(table),
+                                        jnp.asarray(extents))
+    stale = flash_paged_chunk_attention(
+        q, jnp.asarray(stale_k, k.dtype), jnp.asarray(stale_v, v.dtype),
+        jnp.asarray(table), jnp.asarray(extents))
+    np.testing.assert_array_equal(np.asarray(stale, np.float32),
+                                  np.asarray(clean, np.float32))
+
+
+def test_the_knob_takes_the_decode_knobs_values(monkeypatch):
+    q, k, v, table, pos, _ = _case(group=1, chunk=16, page_tokens=16,
+                                   starts=[0])
+    monkeypatch.setattr(edconfig, "prefill_attention_backend", "auto")
+    auto = paged_chunk_attention(q, k, v, table, pos)   # off a TPU: "xla"
+    np.testing.assert_array_equal(
+        np.asarray(auto, np.float32), np.asarray(
+            paged_chunk_attention(q, k, v, table, pos, backend="xla"),
+            np.float32))
+    for kernel in ("paged", "flash"):
+        paged_chunk_attention(q, k, v, table, pos, backend=kernel)
+    with pytest.raises(ValueError, match="auto|paged|flash|xla"):
+        paged_chunk_attention(q, k, v, table, pos, backend="blocked")
+
+
+# ------------------------------------------------- what a layer costs the host
+
+# (rows, heads, kv_heads, chunk, page_tokens, max_pages, n_pages): the chunk
+# programs of the three serving cells
+CELLS = {"mistral": (4, 32, 8, 64, 64, 32, 576),
+         "granite": (4, 32, 8, 256, 256, 16, 1024),
+         "kexaone": (2, 64, 8, 256, 256, 32, 2048)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_sixteen_layers_trace_the_kernel_once(cell):
+    """A 16-layer chunk program holds 16 `pallas_call` equations of ONE
+    kernel whose `jaxpr` and grid mapping are ONE object each: the body was
+    traced once, and jax lowers equal equations once a module.  The body
+    stays as small as the first builder's (20 top-level equations)."""
+    rows, h, kvh, c, pt, mp, n_pages = CELLS[cell]
+    bf16, layers = jnp.bfloat16, 16
+    pages = jax.ShapeDtypeStruct((n_pages, kvh, pt, 128), bf16)
+
+    def program(qs, ks, vs, table, extents):
+        return [flash_paged_chunk_attention(q, k, v, table, extents,
+                                            interpret=False)
+                for q, k, v in zip(qs, ks, vs)]
+
+    fa._paged_call.cache_clear()
+    closed = jax.make_jaxpr(program)(
+        [jax.ShapeDtypeStruct((rows, h, c, 128), bf16)] * layers,
+        [pages] * layers, [pages] * layers,
+        jax.ShapeDtypeStruct((rows, mp), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
+    calls = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == layers
+    assert {e.params["name"] for e in calls} == {"paged_chunk"}
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+    assert len({id(e.params["grid_mapping"]) for e in calls}) == 1
+    assert fa._paged_call.cache_info().misses == 1
+    assert len(calls[0].params["jaxpr"].eqns) <= 20
+    # no wider a step than the decode kernel's at the same pages
+    n_step = (len(calls[0].invars) - 3) // 2
+    assert n_step <= fa._paged_step_shape(mp, (pages, pages))[1]
+
+
+def test_the_decode_kernel_is_built_once_a_signature_too():
+    pages = jax.ShapeDtypeStruct((48, 8, 64, 128), jnp.bfloat16)
+
+    def program(qs, table, lengths):
+        return [fa.flash_paged_decode_attention(q, pages_, pages_, table,
+                                                lengths, interpret=False)
+                for q, pages_ in qs]
+
+    closed = jax.make_jaxpr(program)(
+        [(jax.ShapeDtypeStruct((4, 32, 128), jnp.bfloat16), pages)] * 3,
+        jax.ShapeDtypeStruct((4, 16), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.int32))
+    calls = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+
+
+# -------------------------------------------------------------- sessions
+
+
+def _llama():
+    from easydist_tpu.models import llama
+
+    cfg = llama.LlamaConfig(vocab=96, seq=64, dim=32, heads=4, kv_heads=2,
+                            layers=2, ffn_dim=64)
+    return llama.decoder(cfg), llama.llama_init(cfg, jax.random.PRNGKey(1))
+
+
+def _granite_hybrid():
+    from easydist_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig.tiny()
+    return gh.decoder(cfg), gh.granite_init(cfg, jax.random.PRNGKey(2))
+
+
+def _exaone_moe():
+    from easydist_tpu.models import exaone_moe as em
+
+    cfg = em.ExaoneMoeConfig.tiny()
+    return em.decoder(cfg), em.exaone_init(cfg, jax.random.PRNGKey(3))
+
+
+@pytest.mark.parametrize("family", [_llama, _granite_hybrid, _exaone_moe],
+                         ids=["llama", "granite_hybrid", "exaone_moe"])
+def test_a_sessions_greedy_tokens_do_not_know_the_backend(family, monkeypatch):
+    from easydist_tpu.serve import GenerationSession, ServeConfig
+
+    model, params = family()
+    rng = np.random.default_rng(5)
+    reqs = [(rng.integers(1, 96, size=n).tolist(), m)
+            for n, m in ((5, 4), (19, 6), (8, 3), (30, 5), (41, 7))]
+    ids, kernels = {}, {}
+    for backend in ("xla", "paged"):
+        monkeypatch.setattr(edconfig, "prefill_attention_backend", backend)
+        sess = GenerationSession(params, model=model, config=ServeConfig(
+            kv_layout="paged", decode_buckets=(64,), max_decode_slots=4,
+            prefill_chunk=8, prefill_batch=2, enable_prefix_cache=False,
+            speculate_k=0))
+        futs = [sess.submit(p, max_new_tokens=m) for p, m in reqs]
+        sess.run_until_drained()
+        ids[backend] = [fut.result(timeout=5)["ids"] for fut in futs]
+        (chunk_c,) = (c for name, c in sess._paged_cs.items()
+                      if name.startswith("chunk"))
+        (result,) = chunk_c._cache.values()
+        kernels[backend] = sorted(
+            e.params["name"] for e in result.closed_jaxpr.jaxpr.eqns
+            if e.primitive.name == "pallas_call")
+        walked = sess.metrics.counter("prefill_pages_walked")
+        bucket = sess.metrics.counter("prefill_pages_bucket")
+        assert 0 < walked < bucket
+        sess.close()
+    assert "paged_chunk" in kernels["paged"]
+    assert "paged_chunk" not in kernels["xla"]
+    assert ids["paged"] == ids["xla"]

@@ -6,7 +6,8 @@ jaxpr -> Mosaic lowering; Mosaic's compile is chip_smoke.py's job.
 Shapes: the ones chip_smoke.py uses (GPT-2 small: 12 heads of 64, bf16,
 64-token pages) and a GQA shape (32 query heads over 8 kv heads of 128);
 the paged kernels also at the chat cell's own shape (32 slots, 32 windows)
-and at a wide MHA shape whose pages crowd the VMEM budget.
+and at a wide MHA shape whose pages crowd the VMEM budget; the paged chunk
+kernel at the three serving cells' chunk programs and a verify step.
 Also a kernel inside a program emitted for a (2, 2) mesh, whole and with
 its rows sharded, and the int8 paged kernel against its XLA reference under
 the interpreter, which no other test compares."""
@@ -19,8 +20,9 @@ import pytest
 from easydist_tpu.ops.flash_attention import (
     _PAGED_STEP_TOKENS, _PAGED_VMEM_BUDGET, _paged_decode_attention_quant_xla,
     _paged_step_bytes, _paged_step_shape, _vmem_block_bytes, flash_attention,
-    flash_decode_attention, flash_paged_decode_attention,
-    flash_paged_decode_quant_attention, kv_quantize)
+    flash_decode_attention, flash_paged_chunk_attention,
+    flash_paged_decode_attention, flash_paged_decode_quant_attention,
+    kv_quantize)
 
 BF16 = jnp.bfloat16
 SEQ, PAGE_TOKENS, N_PAGES = 1024, 64, 48
@@ -74,6 +76,28 @@ def test_paged_decode_lowers(b, h, kvh, d, max_pages):
             q, k, v, t, n, interpret=False),
         _aval((b, h, d), BF16), pages, pages,
         _aval((b, max_pages), jnp.int32), _aval((b,), jnp.int32))
+
+
+# the chunk kernel: (rows, heads, kv_heads, chunk, page_tokens, max_pages),
+# the three serving cells' chunk programs and a verify step's few queries
+CHUNK_SHAPES = [
+    pytest.param(4, 32, 8, 64, 64, 32, id="mistral-cell"),
+    pytest.param(4, 32, 8, 256, 256, 16, id="granite-cell"),
+    pytest.param(2, 64, 8, 256, 256, 32, id="kexaone-cell"),
+    pytest.param(32, 32, 8, 5, 64, 32, id="verify-k4"),
+    pytest.param(8, 12, 12, 64, 64, 16, id="gpt2-small"),
+]
+
+
+@pytest.mark.parametrize("rows,h,kvh,chunk,pt,max_pages", CHUNK_SHAPES)
+def test_paged_chunk_lowers(rows, h, kvh, chunk, pt, max_pages):
+    d = 64 if h == 12 else 128
+    pages = _aval((N_PAGES, kvh, pt, d), BF16)
+    _lower_for_tpu(
+        lambda q, k, v, t, n: flash_paged_chunk_attention(
+            q, k, v, t, n, interpret=False),
+        _aval((rows, h, chunk, d), BF16), pages, pages,
+        _aval((rows, max_pages), jnp.int32), _aval((rows,), jnp.int32))
 
 
 @pytest.mark.parametrize("n_blocks", [1, 2])
@@ -135,6 +159,27 @@ def test_paged_step_shape_by_hand():
     assert _paged_step_shape(24, _arena_avals(32, 128, 1)) == (16, 2)
     wide = (_aval((N_PAGES, 64, PAGE_TOKENS, 256), jnp.float32),) * 2
     assert _paged_step_shape(8, wide) == (16, 1)
+
+
+@pytest.mark.parametrize("rows,h,kvh,chunk,pt,max_pages", CHUNK_SHAPES)
+def test_paged_step_shape_counts_a_chunks_query_rows(rows, h, kvh, chunk, pt,
+                                                     max_pages):
+    """A chunk's group x chunk query rows (their blocks, accumulator,
+    statistics and a page's scores) count against the same budget, so the
+    KV heads a step holds shrink as the rows grow: the three cells' chunk
+    programs by hand, and never a wider step than the decode kernel's."""
+    pages = (_aval((N_PAGES, kvh, pt, 64 if h == 12 else 128), BF16),) * 2
+    q_rows = (h // kvh) * -(-chunk // 16) * 16
+    g, n = _paged_step_shape(max_pages, pages, rows=q_rows)
+    by_hand = {(64, 64): (4, 4), (256, 256): (1, 1), (5, 64): (8, 4)}
+    if h != 12:
+        assert (g, n) == by_hand[chunk, pt]
+    assert kvh % g == 0 and max_pages % n == 0
+    assert n <= _paged_step_shape(max_pages, pages)[1]
+    assert g == 1 or _paged_step_bytes(pages, g, n, q_rows) \
+        <= _PAGED_VMEM_BUDGET
+    assert _paged_step_bytes(pages, g, n, q_rows) \
+        > _paged_step_bytes(pages, g, n)
 
 
 @pytest.mark.parametrize("n_blocks", [1, 2])
