@@ -27,7 +27,7 @@ reads which):
     easydist.step.compile         attrs: fn
         the interval of a dispatch that made XLA compile the step, or load
         it from the persistent cache; counted as `xla_compiles{fn=...}`
-    easydist.serve.step           attrs: step, live, queued
+    easydist.serve.step           attrs: step, live, queued, empty_ns
     easydist.serve.admit          attrs: admitted, deferred
     easydist.serve.prefill.build | .call | .finish
     easydist.serve.decode.build | .call | .harvest
@@ -35,7 +35,26 @@ reads which):
         `.call` runs from the dispatch of one compiled program to the
         return of its readback, so a step's duration less its `.call`
         descendants is the host's share of the step, with the device idle;
-        `.finish` carries its request's `request_id`
+        `.finish` carries its request's `request_id`.  `empty_ns` is the
+        part of the time since the previous step's end in which the
+        session had nothing live and nothing queued (a session that
+        emptied and was given work again between two steps: no other
+        record shows it), so the first program of a step whose `empty_ns`
+        is above 0 follows emptiness, not the host's work.
+        Every `.call` (`GenerationSession._run`) also carries `ready_ns`:
+        `block_until_ready` returned, before the result is copied out.
+        The rest of a program's cycle is in the records as they are: the
+        `easydist.step.call` inside a `.call` ends when the program is
+        enqueued; from the previous `.call`'s end to there nothing is in
+        flight (the host's gap), from there to the `.call`'s end one
+        program is (launch, execution, readback).  The session runs one
+        program at a time, so the two tile its timeline exactly.
+    easydist.serve.submit         attrs: prompt_len
+    easydist.serve.snapshot_inflight    attrs: n
+        the session's other entry points on the loop's thread, called
+        between steps: what they cover of the time from one step's end to
+        the next one's start is the session's, the rest the caller's own
+        code
 
 Counters: `xla_compiles{fn=<name>}`; `pallas_calls{kernel=<name>,
 row_shards=<k>}`, one per Pallas kernel call of a program emitted for a mesh
@@ -53,9 +72,10 @@ from __future__ import annotations
 
 import collections
 import itertools
+import statistics
 import threading
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 
@@ -159,15 +179,44 @@ def clear() -> None:
         _counters.clear()
 
 
-def self_ns(record: dict, records: List[dict]) -> int:
-    """A span's duration less what its children cover: the nanoseconds the
-    layer spent in its own code."""
+def _self_ns(record: dict, children: List[dict]) -> int:
     t0, t1 = record["t0_ns"], record["t1_ns"]
     covered, end = 0, t0
     for c0, c1 in sorted((max(r["t0_ns"], t0), min(r["t1_ns"], t1))
-                         for r in records
-                         if r["parent_id"] == record["id"]):
+                         for r in children):
         if c1 > end:
             covered += c1 - max(c0, end)
             end = c1
     return (t1 - t0) - covered
+
+
+def self_ns(record: dict, records: List[dict]) -> int:
+    """A span's duration less what its children cover: the nanoseconds the
+    layer spent in its own code."""
+    return _self_ns(record, [r for r in records
+                             if r["parent_id"] == record["id"]])
+
+
+def self_time_by_name(snapshot: dict,
+                      under: Optional[str] = "easydist.serve.step") -> dict:
+    """{name: (count, median self ms, total self s)} over every span called
+    `under` and every span beneath one (`under=None`: over every span) —
+    where a step goes, by phase."""
+    records = snapshot["spans"]
+    children: Dict[int, List[dict]] = {}
+    for r in records:
+        children.setdefault(r["parent_id"], []).append(r)
+    if under is None:
+        picked = records
+    else:
+        picked, todo = [], [r for r in records if r["name"] == under]
+        while todo:
+            r = todo.pop()
+            picked.append(r)
+            todo += children.get(r["id"], [])
+    selves: Dict[str, List[int]] = {}
+    for r in picked:
+        selves.setdefault(r["name"], []).append(
+            _self_ns(r, children.get(r["id"], [])))
+    return {name: (len(ns), statistics.median(ns) / 1e6, sum(ns) / 1e9)
+            for name, ns in selves.items()}

@@ -439,6 +439,11 @@ class GenerationSession:
         self._pools: Dict[int, _BucketPool] = {}
         self._next_request_id = 0
         self._step_index = 0
+        # emptiness, on the recorder's clock (`submit`, `step`): since when
+        # the session has had nothing live and nothing queued (None while
+        # it has work), and how long it was empty since the last step
+        self._empty_since_ns: Optional[int] = time.perf_counter_ns()
+        self._empty_ns = 0
         self._audited: set = set()
         self._audited_prefill: set = set()
         self._audited_verify: set = set()
@@ -733,30 +738,37 @@ class GenerationSession:
                eos_id: Optional[int] = None) -> Future:
         """Queue one prompt; generation interleaves with every other live
         request (continuous batching) as `step()` is driven."""
-        if self._draining or self._closed:
-            raise ReplicaDrainingError(
-                f"session{f' {self.replica_id}' if self.replica_id else ''} "
-                f"is {'closed' if self._closed else 'draining'}: in-flight "
-                f"work retires but nothing new is admitted")
-        prompt = [int(t) for t in prompt_ids]
-        if not prompt:
-            raise ValueError("empty prompt")
-        if max_new_tokens < 1:
-            raise ValueError(f"max_new_tokens must be >= 1, "
-                             f"got {max_new_tokens}")
-        if select_bucket(len(prompt) + 1, self.config.decode_buckets) is None:
-            raise RequestTooLargeError(
-                f"prompt of {len(prompt)} tokens does not fit any decode "
-                f"bucket {self.config.decode_buckets} with room to "
-                f"generate")
-        fut = Future()
-        self._pending.append(
-            (prompt, max_new_tokens,
-             self.eos_id if eos_id is None else eos_id, fut,
-             _new_timing(len(prompt))))
-        self.metrics.inc("requests_submitted")
-        self.metrics.set_gauge("queue_depth", self.queue_depth)
-        return fut
+        with spans.span("easydist.serve.submit") as sp:
+            if self._draining or self._closed:
+                who = f" {self.replica_id}" if self.replica_id else ""
+                raise ReplicaDrainingError(
+                    f"session{who} is "
+                    f"{'closed' if self._closed else 'draining'}: in-flight "
+                    f"work retires but nothing new is admitted")
+            prompt = [int(t) for t in prompt_ids]
+            sp.set(prompt_len=len(prompt))
+            if not prompt:
+                raise ValueError("empty prompt")
+            if max_new_tokens < 1:
+                raise ValueError(f"max_new_tokens must be >= 1, "
+                                 f"got {max_new_tokens}")
+            if select_bucket(len(prompt) + 1,
+                             self.config.decode_buckets) is None:
+                raise RequestTooLargeError(
+                    f"prompt of {len(prompt)} tokens does not fit any "
+                    f"decode bucket {self.config.decode_buckets} with room "
+                    f"to generate")
+            fut = Future()
+            timing = _new_timing(len(prompt))
+            self._pending.append(
+                (prompt, max_new_tokens,
+                 self.eos_id if eos_id is None else eos_id, fut, timing))
+            if self._empty_since_ns is not None:    # the emptiness ends here
+                self._empty_ns += timing["submit_ns"] - self._empty_since_ns
+                self._empty_since_ns = None
+            self.metrics.inc("requests_submitted")
+            self.metrics.set_gauge("queue_depth", self.queue_depth)
+            return fut
 
     @property
     def queue_depth(self) -> int:
@@ -857,12 +869,16 @@ class GenerationSession:
 
     def _run(self, span_name: str, result, args, **attrs):
         """One compiled program dispatched and its int32 readback awaited,
-        inside a `.call` span: (new state, readback, the closed span)."""
+        inside a `.call` span: (new state, readback, the closed span).
+        `ready_ns` splits the wait at `block_until_ready`'s return, before
+        the result is copied out."""
         import jax
 
         with spans.span(span_name, fn=result.name, **attrs) as sp:
             state, out = result.dispatch(args, {})
-            out = np.asarray(jax.block_until_ready(out))
+            out = jax.block_until_ready(out)
+            sp.set(ready_ns=time.perf_counter_ns())
+            out = np.asarray(out)
         return state, out, sp
 
     def _admit_one(self) -> bool:
@@ -1657,7 +1673,11 @@ class GenerationSession:
         self._step_index += 1
         with spans.span("easydist.serve.step", step=self._step_index,
                         live=sum(p.n_active for p in self._pools.values()),
-                        queued=len(self._pending)):
+                        queued=len(self._pending)) as step_span:
+            empty_ns = self._empty_ns
+            if self._empty_since_ns is not None:    # stepped while empty
+                empty_ns += step_span.t0_ns - self._empty_since_ns
+            step_span.set(empty_ns=empty_ns)
             with spans.span("easydist.serve.admit") as sp:
                 queued = len(self._pending)
                 while self._admit_one():
@@ -1678,7 +1698,10 @@ class GenerationSession:
                         continue
                     self._decode_round(pool)
             self.metrics.set_gauge("queue_depth", self.queue_depth)
-            return self.metrics.counter("tokens_generated") - before
+            generated = self.metrics.counter("tokens_generated") - before
+        self._empty_ns = 0
+        self._empty_since_ns = step_span.t1_ns if self.is_drained else None
+        return generated
 
     def run_until_drained(self, max_steps: int = 100000) -> None:
         """Drive `step()` until no request is live or queued."""
@@ -1861,23 +1884,25 @@ class GenerationSession:
         `ResumeDescriptor`s after each step so a crash of THIS session
         can be recovered bitwise by resubmitting prompt+ids elsewhere.
         Read-only: no session state changes."""
-        out: List[Dict[str, object]] = []
-        for prompt, max_new, eos, fut, _t in self._pending:
-            out.append({"future": fut, "prompt": list(prompt), "ids": [],
-                        "max_new": max_new, "eos_id": eos,
-                        "stage": "queued"})
-        for pool in self._pools.values():
-            for job in pool.jobs.values():
-                out.append({"future": job.future,
-                            "prompt": list(job.prompt), "ids": [],
-                            "max_new": job.max_new, "eos_id": job.eos_id,
-                            "stage": "prefill"})
-            for slot in pool.slots.values():
-                out.append({"future": slot.future,
-                            "prompt": list(slot.prompt),
-                            "ids": list(slot.generated),
-                            "max_new": slot.max_new,
-                            "eos_id": slot.eos_id, "stage": "decode"})
+        with spans.span("easydist.serve.snapshot_inflight") as sp:
+            out: List[Dict[str, object]] = []
+            for prompt, max_new, eos, fut, _t in self._pending:
+                out.append({"future": fut, "prompt": list(prompt),
+                            "ids": [], "max_new": max_new, "eos_id": eos,
+                            "stage": "queued"})
+            for pool in self._pools.values():
+                for job in pool.jobs.values():
+                    out.append({"future": job.future,
+                                "prompt": list(job.prompt), "ids": [],
+                                "max_new": job.max_new,
+                                "eos_id": job.eos_id, "stage": "prefill"})
+                for slot in pool.slots.values():
+                    out.append({"future": slot.future,
+                                "prompt": list(slot.prompt),
+                                "ids": list(slot.generated),
+                                "max_new": slot.max_new,
+                                "eos_id": slot.eos_id, "stage": "decode"})
+            sp.set(n=len(out))
         return out
 
     def evacuate(self) -> List[Dict[str, object]]:

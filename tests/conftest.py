@@ -69,20 +69,47 @@ _GRANITE_IS_THE_LAST_CELL = (    # the node id's end, whatever the rootdir
     "test_the_cell_joins_five_lists_and_its_own_readers_are_unlisted")
 
 
+# The second such pin, and the last: `test_program_readers.py` says that
+# PR 24's five entries are the LAST of `per_layer`, and the driver reads an
+# entry put anywhere but the end as a change to what was there, so that
+# assertion cannot hold beside ANY later entry.  PR 36 appended seven (the
+# session's timeline between programs, set-up's compile seconds); the same
+# route is open to the readers that ship unlisted.  A `benchmark` PR turns
+# the pin into membership and deletes this (PERF.md section 7); what else
+# that test says of the five (each lists one cell, its reader's; each is
+# better lower) is held by name meanwhile, in `test_chipbench/
+# test_session_timeline.py::test_the_five_entries_before_them_are_as_they_were`.
+_FIVE_ENTRIES_ARE_THE_LAST = (
+    "test_chipbench/test_program_readers.py::"
+    "test_the_five_entries_are_the_last_of_benchmark_json")
+_THE_FIVE = ["decode_step_device_ms", "prefill_chunk_device_ms",
+             "session_host_ms_per_step", "step_xla_compiles",
+             "step_dispatch_ms"]
+
+
 def pytest_collection_modifyitems(config, items):
     import json
 
     with open(os.path.join(os.path.dirname(__file__), os.pardir,
                            "BENCHMARK.json")) as f:
-        last = json.load(f)["workloads"][-1]["name"]
-    if last == "serve-granite4hs-chat-1chip":
-        return
+        bench = json.load(f)
+    last = bench["workloads"][-1]["name"]
+    tail = [m["name"] for m in bench["per_layer"][-5:]]
+    pins = {}
+    if last != "serve-granite4hs-chat-1chip":
+        pins[_GRANITE_IS_THE_LAST_CELL] = (
+            f"asserts BENCHMARK.json's workloads[-1] is the Granite cell; "
+            f"{last} was appended after it")
+    if tail != _THE_FIVE:
+        pins[_FIVE_ENTRIES_ARE_THE_LAST] = (
+            f"asserts BENCHMARK.json's per_layer[-5:] are PR 24's five "
+            f"entries; entries were appended after them, the last five are "
+            f"now {', '.join(tail)}")
     for item in items:
-        if item.nodeid.endswith(_GRANITE_IS_THE_LAST_CELL):
-            item.add_marker(pytest.mark.xfail(
-                raises=AssertionError, strict=True,
-                reason=f"asserts BENCHMARK.json's workloads[-1] is the "
-                       f"Granite cell; {last} was appended after it"))
+        for node, reason in pins.items():
+            if item.nodeid.endswith(node):
+                item.add_marker(pytest.mark.xfail(
+                    raises=AssertionError, strict=True, reason=reason))
 
 
 _EXIT_STATUS = [None]

@@ -135,6 +135,30 @@ def test_self_ns(children, expect):
     assert spans.self_ns(parent, records) == expect
 
 
+@pytest.mark.parametrize("under, expect", [
+    # two steps: 10 ms of which 6 in a call (2 of those its dispatch), and
+    # 20 ms of which 4 in a build and 12 in a call; a submit between them
+    ("t.step", {"t.step": (2, 4.0, 0.008), "t.build": (1, 4.0, 0.004),
+                "t.call": (2, 8.0, 0.016), "t.dispatch": (1, 2.0, 0.002)}),
+    ("t.call", {"t.call": (2, 8.0, 0.016), "t.dispatch": (1, 2.0, 0.002)}),
+    (None, {"t.step": (2, 4.0, 0.008), "t.build": (1, 4.0, 0.004),
+            "t.call": (2, 8.0, 0.016), "t.dispatch": (1, 2.0, 0.002),
+            "t.submit": (1, 1.0, 0.001)}),
+    ("t.absent", {}),
+])
+def test_self_time_by_name(under, expect):
+    """Where a step goes, by phase: count, median self ms, total self s."""
+    ms = 1_000_000
+    one = spans.record_span("t.step", 0, 10 * ms)
+    call = spans.record_span("t.call", 2 * ms, 8 * ms, parent_id=one)
+    spans.record_span("t.dispatch", 2 * ms, 4 * ms, parent_id=call)
+    spans.record_span("t.submit", 11 * ms, 12 * ms)
+    two = spans.record_span("t.step", 20 * ms, 40 * ms)
+    spans.record_span("t.build", 21 * ms, 25 * ms, parent_id=two)
+    spans.record_span("t.call", 26 * ms, 38 * ms, parent_id=two)
+    assert spans.self_time_by_name(spans.snapshot(), under=under) == expect
+
+
 def test_many_threads_lose_no_count_and_share_no_id():
     """More workers than cores, a short switch interval: every increment
     counted, every span recorded under an id of its own, every thread's

@@ -1,12 +1,16 @@
 """What `GenerationSession` records of itself (runtime/spans.py): the host
-phases of `step()` as spans that tile it, a timeline for every request, the
+phases of `step()` as spans that tile it, the cycle of every program it runs
+(host gap and in-flight intervals that tile the session's timeline), how
+long it was empty between two steps, a timeline for every request, the
 `queue_wait` histogram, and names for its jitted programs."""
 
 import statistics
+import time
 
 import jax
 import pytest
 
+from chipbench import session_timeline
 from easydist_tpu.models import gpt
 from easydist_tpu.runtime import spans
 from easydist_tpu.serve import GenerationSession, ServeConfig
@@ -183,6 +187,131 @@ def test_recorder_calls_per_step_are_bounded(run):
     later = [t for res in results for t in res["timing"]["token_ns"][1:]]
     assert set(later) <= decode_ends
     assert len(set(later)) < len(later)      # shared, not one read each
+
+
+def _programs(snap):
+    """The `.call` records in order, each with `enqueued_ns`: the end of
+    the `easydist.step.call` inside it."""
+    enqueued = {r["parent_id"]: r["t1_ns"] for r in snap["spans"]
+                if r["name"] == "easydist.step.call"}
+    return sorted(({**r, "enqueued_ns": enqueued[r["id"]]}
+                   for r in snap["spans"]
+                   if r["name"] in ("easydist.serve.prefill.call",
+                                    "easydist.serve.decode.call")),
+                  key=lambda r: r["t0_ns"])
+
+
+def test_every_program_carries_its_cycle(run):
+    """Enqueued, then ready, then read back, inside its `.call`, under a
+    step."""
+    _, _, snap, _ = run
+    by_id = {r["id"]: r for r in snap["spans"]}
+    calls = _programs(snap)
+    assert len(calls) >= 8
+    for call in calls:
+        assert call["t0_ns"] <= call["enqueued_ns"] \
+            <= call["attrs"]["ready_ns"] <= call["t1_ns"]
+        assert by_id[call["parent_id"]]["name"] == "easydist.serve.step"
+
+
+def test_host_gaps_and_flights_tile_the_session(run):
+    """One program in flight at a time: from the first readback to the
+    last, every instant is in exactly one host gap (the previous `.call`'s
+    end to the program's being enqueued: nothing in flight) or one flight
+    (from there to its `.call`'s end) — to the nanosecond, as
+    `chipbench/session_timeline.py` derives the two."""
+    _, _, snap, _ = run
+    calls = session_timeline.calls(snap["spans"])
+    assert [(c["t0_ns"], c["dispatched_ns"]) for c in calls] \
+        == [(c["t0_ns"], c["enqueued_ns"]) for c in _programs(snap)]
+    assert calls[0]["host_gap_ns"] is None
+    for prev, call in zip(calls, calls[1:]):
+        # a gap starts where the previous flight ended and ends where its
+        # own flight starts: no instant in neither, none in both
+        assert call["host_gap_ns"] > 0
+        assert call["dispatched_ns"] - call["host_gap_ns"] == prev["t1_ns"]
+    gaps = sum(c["host_gap_ns"] for c in calls[1:])
+    flights = sum(c["t1_ns"] - c["dispatched_ns"] for c in calls[1:])
+    assert gaps + flights == calls[-1]["t1_ns"] - calls[0]["t1_ns"]
+    assert flights > 0
+    # work all the way: only the session's first program follows emptiness
+    assert [c["after_idle"] for c in calls] \
+        == [True] + [False] * (len(calls) - 1)
+
+
+def test_steps_say_how_long_the_session_was_empty(run):
+    _, _, snap, _ = run
+    steps = _steps(snap)
+    for step in steps[1:]:
+        assert step["attrs"]["empty_ns"] == 0     # work all the way
+    assert steps[0]["attrs"]["empty_ns"] > 0      # since the session was made
+
+
+@pytest.fixture(scope="module")
+def two_bursts(model):
+    """A session that drains, sits empty, and is given work again; its
+    other entry points called between the steps."""
+    cfg, params = model
+    sc = ServeConfig(decode_buckets=(cfg.seq,), max_decode_slots=SLOTS,
+                     prefill_chunk=CHUNK, prefill_batch=ROWS,
+                     kv_layout="paged")
+    sess = GenerationSession.for_gpt(params, cfg, config=sc)
+    spans.clear()
+    sess.submit(PROMPTS[1], max_new_tokens=3)
+    sess.run_until_drained()
+    assert sess.snapshot_inflight() == []
+    time.sleep(0.02)
+    sess.step()                           # stepped while empty
+    time.sleep(0.02)
+    sess.submit(PROMPTS[4], max_new_tokens=3)
+    assert len(sess.snapshot_inflight()) == 1
+    sess.run_until_drained()
+    snap = spans.snapshot()
+    spans.clear()
+    return snap
+
+
+def test_a_program_after_an_empty_session_is_told_apart(two_bursts):
+    """The gap before it is the wait for traffic, not the host's cost: the
+    step says how long the session was empty (the sleeps, and the whole of
+    a step on an empty session), and the readers leave out the first
+    program of a step that says so."""
+    steps = _steps(two_bursts)
+    (empty,) = [s for s in steps[1:] if not s["attrs"]["live"]
+                and not s["attrs"]["queued"]]
+    before = steps[steps.index(empty) - 1]
+    assert 20e6 <= empty["attrs"]["empty_ns"] \
+        == empty["t0_ns"] - before["t1_ns"]
+    after = steps[steps.index(empty) + 1]
+    assert 20e6 <= after["attrs"]["empty_ns"] \
+        < after["t0_ns"] - empty["t1_ns"]      # until the submit, not after
+    for step in steps[1:]:
+        if step not in (empty, after):
+            assert step["attrs"]["empty_ns"] == 0
+    calls = session_timeline.calls(two_bursts["spans"])
+    idle = [c for c in calls if c["after_idle"]]
+    assert len(idle) == 2 and idle[0] is calls[0]
+    assert after["t0_ns"] < idle[1]["t0_ns"] < after["t1_ns"]
+    assert idle[1]["host_gap_ns"] >= 40e6       # both sleeps
+    for name in (session_timeline.PREFILL_CALL, session_timeline.DECODE_CALL):
+        assert not any(c["after_idle"] for c in
+                       session_timeline.steady_calls(two_bursts["spans"],
+                                                     name))
+
+
+def test_submit_and_snapshot_inflight_are_spans_between_the_steps(two_bursts):
+    records = two_bursts["spans"]
+    submits = [r for r in records if r["name"] == "easydist.serve.submit"]
+    looks = [r for r in records
+             if r["name"] == "easydist.serve.snapshot_inflight"]
+    assert [r["attrs"] for r in submits] == [
+        {"prompt_len": len(PROMPTS[1])}, {"prompt_len": len(PROMPTS[4])}]
+    assert [r["attrs"] for r in looks] == [{"n": 0}, {"n": 1}]
+    steps = _steps(two_bursts)
+    for r in submits + looks:
+        assert r["parent_id"] == 0
+        assert not any(s["t0_ns"] < r["t1_ns"] and r["t0_ns"] < s["t1_ns"]
+                       for s in steps)
 
 
 def test_timelines(run):
