@@ -130,7 +130,11 @@ def _emit_attention_variant(eqn, strategies, axis_names, mesh, invals):
 
 def _combined_spec(placements: List[Optional[Placement]],
                    axis_names: Sequence[str], ndim: int) -> PartitionSpec:
-    """Merge per-axis placements into one PartitionSpec."""
+    """Merge per-axis placements into one PartitionSpec, spelled as JAX
+    spells the sharding of an array a jit hands back (no trailing `None`:
+    `P('dp')` for `P('dp', None)`), so that a state leaf's `in_shardings`
+    entry EQUALS the sharding it returns in: equivalent alone is another
+    key to the jit's cache."""
     entries: List[object] = [None] * ndim
     for axis_name, p in zip(axis_names, placements):
         if p is None or not p.is_shard() or p.dim >= ndim:
@@ -142,6 +146,8 @@ def _combined_spec(placements: List[Optional[Placement]],
             entries[p.dim] = cur + (axis_name,)
         else:
             entries[p.dim] = (cur, axis_name)
+    while entries and entries[-1] is None:
+        entries.pop()
     return PartitionSpec(*entries)
 
 
@@ -1150,6 +1156,32 @@ def _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
         donate_state = edconfig.enable_donation
     donate = tuple(sorted(set(state_pairs.values()))) if donate_state else ()
 
+    # ---- a paired state leaf has ONE sharding, the solved one of the input
+    # it replaces.  Left to XLA, a leaf comes back in a sharding of XLA's
+    # choosing (the gpt2-xl step's `wte`: solved whole, returned over both
+    # axes, its donated buffer not aliased): another key to the jit's
+    # cache, so the second call traced, lowered and compiled the whole step
+    # again.  Constrained in the trace and not declared as the jit's
+    # `out_shardings`: with those, JAX pairs a donated input with an output
+    # by their PER-DEVICE shapes, and state brought in uncommitted (its
+    # sharding left to XLA) is aliased to another leaf's output and fails
+    # to compile.  The array handed back carries the sharding as JAX spells
+    # it, which is `_combined_spec`'s spelling.  Unpaired outputs (a loss,
+    # a readback) stay XLA's to place; rank-0 leaves as on the way in.
+    out_pins = {out_idx: in_shardings[in_idx]
+                for out_idx, in_idx in state_pairs.items()
+                if out_idx < len(jaxpr.outvars) and in_idx < len(in_shardings)
+                and jaxpr.outvars[out_idx].aval.shape}
+    if out_pins:
+        emitted_fn = sharded_fn
+
+        def sharded_fn(*flat_args):
+            outs = list(emitted_fn(*flat_args))
+            for out_idx, sharding in out_pins.items():
+                outs[out_idx] = jax.lax.with_sharding_constraint(
+                    outs[out_idx], sharding)
+            return outs
+
     sharded_fn.__name__ = sharded_fn.__qualname__ = name + "_flat"
     jitted = jax.jit(sharded_fn, in_shardings=in_shardings,
                      donate_argnums=donate)
@@ -1172,8 +1204,10 @@ def _finish_compile(closed_jaxpr, jaxpr, names, per_axis, graph, axis_specs,
                 for x, (s, d) in zip(flat, expected_avals)):
             raise SignatureMismatch
         # constrain inputs INSIDE the trace rather than pinning jit
-        # in_shardings: donated state comes back with XLA-chosen output
-        # shardings, and pinned in_shardings would reject it on the next call
+        # in_shardings: paired state comes back in its solved sharding
+        # (`out_pins`), so the second call hits this jit's cache, but a
+        # caller may bring state or a batch committed otherwise, and
+        # pinned in_shardings would reject it where this places it
         flat = [jax.lax.with_sharding_constraint(x, s)
                 if hasattr(x, "ndim") and x.ndim > 0 else x
                 for x, s in zip(flat, in_shardings)]

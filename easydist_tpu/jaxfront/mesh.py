@@ -72,3 +72,21 @@ def make_device_mesh(shape: Optional[Sequence[int]] = None,
              for n, s in zip(axis_names, shape)]
     set_device_mesh(mesh, specs)
     return mesh
+
+
+def put_on_mesh(state, mesh=None):
+    """`state`'s leaves committed to `mesh`, each whole on every device of
+    it: what a program compiled for a mesh of ONE device declares for every
+    argument, so a serving pool born here goes into its first program as
+    every program hands it back, and each compiles once.  The device's own
+    buffer is kept (a new sharding on it, no copy) where the leaf already
+    lives there.  `mesh=None` is the mesh `compile_step` resolves for a
+    program compiled without one: the global mesh, made if there is none."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    if mesh is None:
+        mesh = get_device_mesh()
+    if mesh is None:
+        mesh = make_device_mesh()
+    return jax.device_put(state, NamedSharding(mesh, PartitionSpec()))

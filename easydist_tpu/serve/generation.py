@@ -817,21 +817,34 @@ class GenerationSession:
     def _cache_factory(self, batch: int, max_len: int):
         from easydist_tpu.models.decoder import Contiguous
 
-        return Contiguous.init(self._model, batch, max_len,
-                               self.config.kv_cache_dtype)
+        return self._born(Contiguous.init(self._model, batch, max_len,
+                                          self.config.kv_cache_dtype))
 
     def _pages_factory(self, n_pages: int, page_tokens: int):
         from easydist_tpu.models.decoder import Paged
 
         cfg = self.config
-        return Paged.init(self._model, n_pages, page_tokens,
-                          cfg.kv_cache_dtype, cfg.kv_quant_dtype,
-                          cfg.kv_quant_block)
+        return self._born(Paged.init(self._model, n_pages, page_tokens,
+                                     cfg.kv_cache_dtype, cfg.kv_quant_dtype,
+                                     cfg.kv_quant_block))
 
     def _state_factory(self, n_slots: int):
         from easydist_tpu.models.decoder import State
 
-        return State.init(self._model, n_slots, self.config.kv_cache_dtype)
+        return self._born(State.init(self._model, n_slots,
+                                     self.config.kv_cache_dtype))
+
+    def _born(self, state):
+        """A pool's leaves as the factories hand them to the pool: put
+        ONCE, here, in the sharding this session's programs declare for
+        them and hand them back in (`jaxfront/mesh.py::put_on_mesh`), so
+        that a program's first call sees the pool every later call sees
+        and XLA compiles it once.  Left as `jnp.zeros` makes them, on one
+        device and uncommitted, the first call compiles for that and the
+        second again for the pool the first gave back."""
+        from easydist_tpu.jaxfront.mesh import put_on_mesh
+
+        return put_on_mesh(state, self.mesh)
 
     def _model_itemsize(self) -> int:
         """Bytes per element at model precision (first param leaf) — the

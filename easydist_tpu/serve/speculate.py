@@ -190,7 +190,12 @@ class SmallModelDrafter:
         ids = [int(t) for t in ids]
         st = self._states.get(request_id)
         if st is None:
-            st = {"cache": self._init_cache(1, self.max_len), "fed": []}
+            # born where `_step` declares it and hands it back, as a
+            # session's pools are (`GenerationSession._born`)
+            from easydist_tpu.jaxfront.mesh import put_on_mesh
+
+            st = {"cache": put_on_mesh(self._init_cache(1, self.max_len),
+                                       self._mesh), "fed": []}
             self._states[request_id] = st
         fed = st["fed"]
         common = 0

@@ -49,7 +49,24 @@ def test_dp_collectives_match_hand_gspmd(cpu_devices):
     solve_s = time.perf_counter() - t0
     ours = collective_summary(res.executable().as_text())
 
-    # pure DP is unambiguous: identical collective census, to the byte
+    # the plan keeps the Adam moments of some weights sharded over dp, and
+    # a step hands every state leaf back as it took it (PR 38), so it ends
+    # by gathering those weights' updated values: one all-gather a weight,
+    # of the weight's bytes.  (Until then the gathers sat at the top of the
+    # NEXT call's program, compiled for the state the first gave back,
+    # which this census never saw.)  The hand-written step keeps all state
+    # whole and has none.
+    flat = jax.tree_util.tree_flatten_with_path(state)[0]
+    mu_sharded = {jax.tree_util.keystr(path[2:]) for (path, _), sharding
+                  in zip(flat, res.in_shardings)
+                  if jax.tree_util.keystr(path[:2]) == "[1]['mu']"
+                  and not sharding.is_fully_replicated}
+    gathered = [leaf for path, leaf in flat
+                if jax.tree_util.keystr(path[:1]) == "[0]"
+                and jax.tree_util.keystr(path[1:]) in mu_sharded]
+    assert ours.pop("all-gather", (0, 0)) == (
+        len(gathered), sum(leaf.nbytes for leaf in gathered)), ours
+    # pure DP is unambiguous: otherwise the same census, to the byte
     assert ours == hand, (ours, hand)
     # solver + emission must stay fast (this config solved in <1s; the
     # bound leaves 20x headroom before flagging a blowup)

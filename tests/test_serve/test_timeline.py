@@ -8,10 +8,12 @@ import statistics
 import time
 
 import jax
+import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from chipbench import session_timeline
-from easydist_tpu.models import gpt
+from easydist_tpu.models import exaone_moe, gpt, granite_hybrid
 from easydist_tpu.runtime import spans
 from easydist_tpu.serve import GenerationSession, ServeConfig
 
@@ -36,6 +38,15 @@ def model():
     return cfg, gpt.gpt_init(cfg, jax.random.PRNGKey(0))
 
 
+def _one_device():
+    """The mesh every cell's runner serves on.  (On a mesh of several
+    devices each program solves the pool's sharding for itself: the
+    contiguous layout's `_migrate` splits the cache by position where
+    `_decode` keeps it whole, so the cache changes hands between two
+    shardings and each program compiles once for either.)"""
+    return Mesh(np.array(jax.devices()[:1]), ("d",))
+
+
 @pytest.fixture(scope="module", params=sorted(LAYOUTS))
 def run(request, model):
     """One drained session per layout: (layout, results, recorder snapshot,
@@ -45,7 +56,8 @@ def run(request, model):
                      prefill_chunk=CHUNK, prefill_batch=ROWS,
                      prefill_chunks_per_step=CHUNKS_PER_STEP,
                      **LAYOUTS[request.param])
-    sess = GenerationSession.for_gpt(params, cfg, config=sc)
+    sess = GenerationSession.for_gpt(params, cfg, config=sc,
+                                     mesh=_one_device())
     spans.clear()
     futs = [sess.submit(p, max_new_tokens=n) for p, n in zip(PROMPTS, NEW)]
     sess.run_until_drained()
@@ -150,18 +162,65 @@ def test_every_program_call_is_a_call_span_with_its_dispatch_inside(run):
             "paged_speculative": {"_prefill_chunk_paged", "_decode_paged",
                                   "_verify_paged"}}[layout]
     assert {c["attrs"]["fn"] for c in calls} == want
-    # XLA compiled each program in its first calls and never after: once
-    # for the pool as it was made, and (where the donated pool comes back
-    # committed to other shardings) once more for the pool it gave back
-    # (or not at all: sessions over one model share compiled programs)
-    for fn in want:
+    _compiled_in_its_first_call_or_not_at_all(snap, calls, want)
+
+
+def _compiled_in_its_first_call_or_not_at_all(snap, calls, fns):
+    """XLA compiled each program of `fns` in its FIRST call and never after
+    (or not at all: sessions over one model share compiled programs): the
+    pool goes into a program's first call as every call hands it back
+    (`GenerationSession._born`, `_finish_compile`'s `out_pins`)."""
+    by_parent = _children(snap)
+    for fn in fns:
         mine = [c for c in calls if c["attrs"]["fn"] == fn]
+        assert mine, fn
         compiled_in = [i for i, c in enumerate(mine) if any(
             r["name"] == "easydist.step.compile"
             for r in _descendants(c, by_parent))]
-        assert compiled_in in ([], [0], [0, 1]), (fn, compiled_in)
+        assert compiled_in in ([], [0]), (fn, compiled_in)
         assert snap["counters"].get(f"xla_compiles{{fn={fn}}}", 0) \
             == len(compiled_in)
+
+
+STATE_KEEPING = {    # beside the pages: a recurrent state; rings
+    "granite_hybrid": (granite_hybrid, granite_hybrid.GraniteHybridConfig,
+                       granite_hybrid.granite_init),
+    "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig,
+                   exaone_moe.exaone_init),
+}
+
+
+@pytest.mark.parametrize("family", sorted(STATE_KEEPING))
+def test_a_state_keeping_family_compiles_its_two_programs_once(family):
+    """The pool of such a session is the pages AND the leaves kept a
+    sequence, born in one place: `_prefill_chunk_paged_state` and
+    `_decode_paged_state` compile in their first call and never after."""
+    module, config, init = STATE_KEEPING[family]
+    cfg = config.tiny()
+    sess = GenerationSession(init(cfg, jax.random.PRNGKey(3)),
+                             model=module.decoder(cfg), mesh=_one_device(),
+                             config=ServeConfig(
+        kv_layout="paged", decode_buckets=(64,), max_decode_slots=4,
+        prefill_chunk=8, prefill_batch=2, enable_prefix_cache=False,
+        speculate_k=0))
+    rng = np.random.default_rng(4)
+    spans.clear()
+    futs = [sess.submit(rng.integers(1, cfg.vocab, size=n).tolist(),
+                        max_new_tokens=m)
+            for n, m in ((5, 4), (19, 6), (8, 3), (30, 5), (3, 7))]
+    sess.run_until_drained()
+    assert all(len(f.result(timeout=5)["ids"]) > 0 for f in futs)
+    snap = spans.snapshot()
+    spans.clear()
+    sess.close()
+    calls = [r for r in snap["spans"]
+             if r["name"] in ("easydist.serve.prefill.call",
+                              "easydist.serve.decode.call")]
+    want = {"_prefill_chunk_paged_state", "_decode_paged_state"}
+    assert {c["attrs"]["fn"] for c in calls} == want
+    assert min(sum(c["attrs"]["fn"] == fn for c in calls)
+               for fn in want) >= 3
+    _compiled_in_its_first_call_or_not_at_all(snap, calls, want)
 
 
 @pytest.mark.parametrize("run", ["paged"], indirect=True)
