@@ -3,10 +3,12 @@
 A model file fills in a `Decoder` — its sizes and the arithmetic of one
 block — and gets every serving step from here: `chunk` (chunked prefill),
 `verify` (speculative scoring) and `decode` (one token), each against
-either KV layout.  The layouts are the two adapters below, `Contiguous`
-(the stacked [layers, batch, kv_heads, T, head_dim] cache of the bucketed
-pools) and `Paged` (`kv/arena.py`: one leaf per layer, written in place
-through a page table; the int8 arena lives here and nowhere else).
+either KV layout.  The layouts are the adapters below: `Contiguous` (the
+stacked [layers, batch, kv_heads, T, head_dim] cache of the bucketed
+pools), `Paged` (`kv/arena.py`: one leaf per layer, written in place
+through a page table; the int8 arena lives here and nowhere else) and
+`Latent` (a paged arena of ONE row a position for all the heads, for a
+model whose attention is latent).
 
     cache, logits = decode(dec, Paged(pages, table), params, token, pos)
 
@@ -14,12 +16,16 @@ Every step is a pure function returning the updated cache first, so a jit
 with the cache as argument 0 donates it (`serve/generation.py`).  There is
 no table of models: a model whose layers differ branches inside the
 functions it supplies, all of which receive the block.  The loop knows two
-KINDS of layer, by what they cache: "attention" (K/V rows per position,
-through `Contiguous` or `Paged`) and "state" (a recurrent state per
-SEQUENCE, through the third adapter, `State`); a model with state layers
-says which is which in `kinds`.  An attention layer that sees only its
-last `window` positions says so in `windows`, and caches a ring of that
-many rows per SEQUENCE (`Ring`, which `State` carries) instead of pages.
+KINDS of layer, by what they cache: "attention" (rows per position,
+through `Contiguous`, `Paged` or `Latent`) and "state" (a recurrent state
+per SEQUENCE, through `State`); a model with state layers says which is
+which in `kinds`.  An attention layer that sees only its last `window`
+positions says so in `windows`, and caches a ring of that many rows per
+SEQUENCE (`Ring`, which `State` carries) instead of pages.  A model whose
+attention is latent says so in `latent`, and caches one row a position
+where the others cache a K and a V row a KV head.  What a model's `ffn`
+counts (an expert layer's routing) is a matter of its own, `counts`: it
+leaves a step on the adapter that ran it, with or without a `State`.
 
 K and V are cached as the model's `qkv` returns them — positions already
 applied (roped keys), at kv_heads granularity; the GQA repeat happens at
@@ -34,11 +40,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from easydist_tpu.kv.arena import (init_page_arena, write_chunk, write_row,
-                                   write_rows)
+from easydist_tpu.kv.arena import (init_latent_arena, init_page_arena,
+                                   write_chunk, write_row, write_rows)
 
-__all__ = ["Decoder", "Contiguous", "Paged", "State", "Ring", "chunk",
-           "verify", "decode", "split_heads"]
+__all__ = ["Decoder", "Contiguous", "Paged", "Latent", "State", "Ring",
+           "chunk", "verify", "decode", "split_heads"]
 
 
 @dataclass(frozen=True)
@@ -62,9 +68,7 @@ class Decoder:
     # A model with state layers fills in the three below; `valid` (bool,
     # the leading shape of x) marks the rows and positions that are real.
     # A model that keeps anything a SEQUENCE (state layers, window layers:
-    # `per_sequence`) is stepped with a `State`, and its `ffn` takes
-    # `valid` too and returns (x, int32 counters [n] or None), which the
-    # loop sums over the layers that gave some (`State.counters`).
+    # `per_sequence`) is stepped with a `State`.
     kinds: Optional[Tuple[str, ...]] = None   # "attention" | "state" a block
     state: Optional[Callable] = None  # (block, x, carry, valid) -> x, carry
     state_shapes: Optional[Dict[str, tuple]] = None  # name -> (shape, dtype)
@@ -73,6 +77,17 @@ class Decoder:
     # included, the layer sees (key j is visible to query i iff
     # i - window < j <= i); None = all of them.  Left None: every layer all.
     windows: Optional[Tuple[Optional[int], ...]] = None
+    # latent attention: `qkv` returns (q, row, None) — q [b, heads, (s,)
+    # head_dim] with the keys' up-projection absorbed and the scale applied,
+    # row [b, (s,) head_dim] the ONE row the position caches for all heads —
+    # and `attn_out` is handed, a head, the softmax-weighted sum of the
+    # rows' leading `latent` columns (its values, still to be up-projected).
+    # Then kv_heads is 1 and head_dim the row's width.
+    latent: Optional[int] = None
+    # the `ffn` counts: it is (block, x, valid) -> (x, int32 counters [n] or
+    # None), and the loop sums the counters over the layers that gave some
+    # and leaves them on the step's adapter (`.counters`)
+    counts: bool = False
 
     @property
     def ring_windows(self) -> Tuple[int, ...]:
@@ -145,6 +160,10 @@ class Contiguous:
         if dec.ring_windows:
             raise ValueError("a model with window layers has no contiguous "
                              "cache: its rings live beside a paged arena")
+        if dec.latent:
+            raise ValueError("a model with latent attention has no "
+                             "contiguous cache: its rows live in a paged "
+                             "arena (`Latent`)")
         shape = (dec.kv_layers, batch, dec.kv_heads, max_len, dec.head_dim)
         dt = _storage_dtype(dec, dtype)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -218,6 +237,14 @@ class Paged:
         self._pt = pages["k"][0].shape[2]
         self._quant_nb = pages["k_scale"][0].shape[-1] \
             if "k_scale" in pages else 0
+        self.counters = None      # the ffns' counters, summed over layers
+
+    @property
+    def live(self):
+        """bool [batch]: the rows whose first window is mapped — the rows
+        that are sequences (the session hands every other row sentinels)."""
+        n_pages = next(iter(self._old.values()))[0].shape[0]
+        return self._table[:, 0].astype(jnp.int32) < n_pages
 
     def seek(self, start, n: Optional[int] = None, aligned: bool = False):
         """One row at `start` (n None), `n` rows from `start` that may
@@ -294,6 +321,57 @@ class Paged:
 
     def cache(self):
         return {key: tuple(leaves) for key, leaves in self._new.items()}
+
+
+class Latent(Paged):
+    """The arena of a model with latent attention, {"latent": (a leaf per
+    layer)} with each leaf [n_pages, page_tokens, width]: ONE row a position,
+    [the normed latent | the shared rotary key], which every head reads as
+    its key and, in its leading `Decoder.latent` columns, as its value — no
+    heads axis, no second leaf.  `width` is the row (`Decoder.head_dim`)
+    padded with zeros to whole 128-lane tiles: the TPU tiles an array's
+    minor dimension, and a leaf whose rows were not whole tiles would be
+    given another layout than the kernels read, and copied, whole, every
+    call.  Read and written through `table` as `Paged` is, by the same three
+    writes, each in the layer's own donated leaf."""
+
+    @staticmethod
+    def width(dec: Decoder) -> int:
+        return -(-dec.head_dim // 128) * 128
+
+    @staticmethod
+    def init(dec: Decoder, n_pages: int, page_tokens: int, dtype=None):
+        return init_latent_arena(dec.kv_layers, n_pages, page_tokens,
+                                 Latent.width(dec),
+                                 _storage_dtype(dec, dtype))
+
+    def __init__(self, pages, table):
+        self._old, self._table = pages, table
+        self._new = []
+        self._pt = pages["latent"][0].shape[1]
+        self.counters = None
+
+    def _padded(self, x):
+        pad = self._old["latent"][0].shape[-1] - x.shape[-1]
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+    def write(self, row, _=None):
+        leaf = self._old["latent"][len(self._new)]
+        self._new.append(self._put(leaf, self._padded(row)))
+
+    def attend(self, dec: Decoder, q, pos):
+        from easydist_tpu.ops import (latent_chunk_attention,
+                                      latent_decode_attention)
+
+        pages = self._new[-1].astype(dec.dtype)
+        if q.ndim == 3:
+            return latent_decode_attention(self._padded(q), pages, self._tbl,
+                                           pos + 1, dec.latent)
+        return latent_chunk_attention(self._padded(q), pages, self._tbl, pos,
+                                      dec.latent)
+
+    def cache(self):
+        return {"latent": tuple(self._new)}
 
 
 class Ring:
@@ -426,7 +504,6 @@ class State:
         self._new = {name: [] for name in self._old}
         self.ring = Ring({key: state[key] for key in Ring.KEYS}, slots) \
             if Ring.KEYS[0] in state else None
-        self.counters = None      # the ffns' counters, summed over layers
 
     def read(self):
         li = len(next(iter(self._new.values())))
@@ -460,7 +537,9 @@ class State:
 
 def _forward(dec: Decoder, kv, params, tokens, pos, st=None, valid=None):
     """Embed, run every layer against `kv` (and `st`, the `State` of a
-    model with state layers), final norm: (cache, x)."""
+    model that keeps slots), final norm: (cache, x).  `valid` marks the
+    rows and positions that are real, for the state layers, the rings and
+    an `ffn` that counts; the counters are left on `kv`."""
     x = dec.embed(params, tokens, pos)
     counters = []
     ring = None if st is None else st.ring
@@ -477,16 +556,25 @@ def _forward(dec: Decoder, kv, params, tokens, pos, st=None, valid=None):
             at = kv if next(windows, None) is None else ring
             at.write(k, v)
             x = dec.attn_out(blk, x, _merge_heads(at.attend(dec, q, pos)))
-        if st is None:
+        if not dec.counts:
             x = dec.ffn(blk, x)
         else:
             x, c = dec.ffn(blk, x, valid)
             if c is not None:     # a layer without experts counts nothing
                 counters.append(c)
-    if st is None:
-        return kv.cache(), dec.final_norm(params, x)
-    st.counters = sum(counters[1:], counters[0])
-    return {**kv.cache(), **st.cache()}, dec.final_norm(params, x)
+    if dec.counts:
+        kv.counters = sum(counters[1:], counters[0])
+    cache = kv.cache() if st is None else {**kv.cache(), **st.cache()}
+    return cache, dec.final_norm(params, x)
+
+
+def _live(dec: Decoder, kv, state):
+    """Which rows of a step are sequences: what the `State` was told, or
+    for a model that only counts, what the page table shows; None for a
+    model that asks for neither."""
+    if state is not None:
+        return state.live
+    return kv.live if dec.counts else None
 
 
 def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths, state=None):
@@ -502,8 +590,9 @@ def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths, state=None):
     c_len = tokens.shape[1]
     start = start_pos.astype(jnp.int32)
     pos = kv.seek(start, c_len, aligned=True)
-    valid = None if state is None else \
-        state.live[:, None] & (pos < lengths.astype(jnp.int32)[:, None])
+    live = _live(dec, kv, state)
+    valid = None if live is None else \
+        live[:, None] & (pos < lengths.astype(jnp.int32)[:, None])
     cache, x = _forward(dec, kv, params, tokens, pos, state, valid)
     rel_last = jnp.clip(lengths.astype(jnp.int32) - 1 - start, 0, c_len - 1)
     last = jnp.take_along_axis(x, rel_last[:, None, None], axis=1)[:, 0]
@@ -521,8 +610,11 @@ def verify(dec: Decoder, kv, params, tokens, pos):
     a model that keeps slots: a state has no position mask to hide a
     rejected draft behind, and a rejected draft has overwritten the ring
     rows of positions still inside the window."""
-    cache, x = _forward(dec, kv, params, tokens,
-                        kv.seek(pos.astype(jnp.int32), tokens.shape[1]))
+    pos = kv.seek(pos.astype(jnp.int32), tokens.shape[1])
+    live = _live(dec, kv, None)
+    valid = None if live is None else \
+        jnp.broadcast_to(live[:, None], pos.shape)
+    cache, x = _forward(dec, kv, params, tokens, pos, valid=valid)
     return cache, dec.unembed(params, x)
 
 
@@ -534,5 +626,5 @@ def decode(dec: Decoder, kv, params, token, pos, state=None):
     rings untouched."""
     cache, x = _forward(dec, kv, params, token,
                         kv.seek(pos.astype(jnp.int32)), state,
-                        None if state is None else state.live)
+                        _live(dec, kv, state))
     return cache, dec.unembed(params, x)

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from .decoder import Decoder, split_heads
-from .experts import expert_ffn, glu
+from .experts import expert_ffn, glu, sigmoid_route
 
 __all__ = ["ExaoneMoeConfig", "exaone_init", "decoder", "route"]
 
@@ -143,13 +143,8 @@ def route(cfg: ExaoneMoeConfig, blk, u):
     """u [rows, dim] -> (idx int32 [rows, top_k], gate float32 [rows,
     top_k]): sigmoid scores in float32, the top `top_k` of score + bias,
     the chosen scores normalised and scaled.  The bias never gates."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        u, blk["router"].astype(u.dtype),
-        preferred_element_type=jnp.float32))
-    _, idx = jax.lax.top_k(scores + blk["router_bias"], cfg.top_k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx, cfg.routed_scale * chosen / (
-        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return sigmoid_route(u, blk["router"], cfg.top_k, cfg.routed_scale,
+                         blk["router_bias"])
 
 
 def decoder(cfg: ExaoneMoeConfig) -> Decoder:
@@ -197,7 +192,7 @@ def decoder(cfg: ExaoneMoeConfig) -> Decoder:
         blocks=lambda params: [dict(blk, sliding=s) for blk, s in
                                zip(params["blocks"], sliding)],
         embed=lambda params, tokens, pos: params["wte"][tokens].astype(dtype),
-        qkv=qkv, attn_out=attn_out, ffn=ffn,
+        qkv=qkv, attn_out=attn_out, ffn=ffn, counts=True,
         final_norm=lambda params, x: _rmsnorm(x, params["norm_f"], cfg.eps),
         unembed=lambda params, x: x.astype(jnp.float32) @ params["head"].T,
         windows=tuple(cfg.sliding_window if s else None for s in sliding))

@@ -14,7 +14,8 @@ chips.  A shared expert is a dense SwiGLU (`glu`), added by the model.
 
     granite_hybrid   top-10 of the logits, softmax over the chosen
     exaone_moe       sigmoid scores, top-8 of score + bias, the chosen
-                     scores normalised and scaled
+                     scores normalised and scaled (`sigmoid_route`)
+    axk1             the same without the bias
 """
 
 from __future__ import annotations
@@ -24,7 +25,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["expert_ffn", "glu"]
+__all__ = ["expert_ffn", "glu", "sigmoid_route"]
 
 
 def glu(x, w1, w2, dtype):
@@ -33,6 +34,19 @@ def glu(x, w1, w2, dtype):
     ab = x @ w1.astype(dtype)
     half = ab.shape[-1] // 2
     return (jax.nn.silu(ab[..., :half]) * ab[..., half:]) @ w2.astype(dtype)
+
+
+def sigmoid_route(u, router, top_k: int, scale: float, bias=None):
+    """u [rows, dim] -> (idx int32 [rows, top_k], gate float32 [rows,
+    top_k]): sigmoid scores in float32, the top `top_k` of score (+ `bias`,
+    which steers the choice and never gates), the chosen scores normalised
+    and scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u, router.astype(u.dtype), preferred_element_type=jnp.float32))
+    _, idx = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, scale * chosen / (
+        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
 
 def expert_ffn(u, idx, gate, w1, w2, experts_held: Tuple[int, int], dtype,

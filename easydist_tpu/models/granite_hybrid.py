@@ -262,7 +262,7 @@ def decoder(cfg: GraniteHybridConfig) -> Decoder:
             params["wte"][tokens] * cfg.embedding_multiplier).astype(dtype),
         qkv=qkv,
         attn_out=lambda blk, x, att: x + res * (att @ blk["wo"].astype(dtype)),
-        ffn=ffn,
+        ffn=ffn, counts=True,
         final_norm=lambda params, x: _rmsnorm(x, params["norm_f"], cfg.eps),
         unembed=lambda params, x: (x.astype(jnp.float32) @ params["wte"].T)
         / cfg.logits_scaling,

@@ -768,7 +768,8 @@ def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None,
     return g, n
 
 
-def _paged_kv_index_map(j: int, n_step: int, page_tokens: int, n_pages: int):
+def _paged_kv_index_map(j: int, n_step: int, page_tokens: int, n_pages: int,
+                        shared: bool = False):
     """Block index of the j-th K/V (or scale) page operand for grid
     (bi, gi, pi): window pi * n_step + j of row bi, resolved through the
     scalar-prefetched page table; a block is the gi-th group of KV heads of
@@ -778,12 +779,14 @@ def _paged_kv_index_map(j: int, n_step: int, page_tokens: int, n_pages: int):
     kernel's @pl.when skips the compute.  (Clamping each operand to ITS
     last live window saves the copies of a row's first dead step too, and
     measured 6% slower on the v5e: the maps run on the scalar core every
-    step.)  Sentinel entries clip to a real page, as `gather_pages` does."""
+    step.)  Sentinel entries clip to a real page, as `gather_pages` does.
+    `shared`: the pages have ONE head, which every block of query rows
+    attends (latent attention's blocks of heads)."""
     def kv_map(bi, gi, pi, tbl_ref, len_ref):
         last_live = jnp.maximum(
             jax.lax.div(len_ref[bi] + page_tokens - 1, page_tokens) - 1, 0)
         page = tbl_ref[bi, jnp.minimum(pi * n_step + j, last_live)]
-        return (jnp.clip(page, 0, n_pages - 1), gi, 0, 0)
+        return (jnp.clip(page, 0, n_pages - 1), 0 if shared else gi, 0, 0)
 
     return kv_map
 
@@ -795,9 +798,10 @@ def _q_map(bi, gi, pi, tbl_ref, len_ref):
 @functools.lru_cache(maxsize=128)
 def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
                 pages_per_step: Optional[int], interpret: bool,
-                chunk: int = 0):
+                chunk: int = 0, out_dim: Optional[int] = None):
     """The pallas_call the paged kernels share (`name`: `paged_decode`,
-    `paged_decode_int8` or `paged_chunk`, as the trace shows it), built
+    `paged_decode_int8`, `paged_chunk`, `latent_decode` or `latent_chunk`,
+    as the trace shows it), built
     ONCE a signature: `q_view` and each of `pages` are (shape, dtype name).
     A model's layers call a kernel at one signature, and the function this
     returns is a `jax.jit`, so the second layer's call finds the first's
@@ -818,28 +822,34 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
     this one computes.  Table and lengths are scalar-prefetched.  q and
     the output ride a [batch, kv_heads, rows, head_dim] view (rows: the
     GQA group, times `chunk` queries for the chunk kernel), whose blocks'
-    trailing dims equal the array's.  Returns (n, the call)."""
+    trailing dims equal the array's.  The latent kernels differ in two
+    things: the pages have one head, which every block of the q view's
+    second axis (blocks of query heads, there) attends, and the result is
+    `out_dim` wide, the page's leading columns being its values.  Returns
+    (n, the call)."""
     (b, kvh, rows, d), q_dtype = q_view
     avals = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in pages]
-    n_pages, _, pt, _ = avals[0].shape
+    n_pages, page_heads, pt, _ = avals[0].shape
     g, n_step = _paged_step_shape(max_pages, avals, pages_per_step,
                                   rows if chunk else 0)
-    qo_spec = pl.BlockSpec((1, g, rows, d), _q_map)
+    kw = {} if out_dim is None else {"out_dim": out_dim}
+    out_dim = out_dim or d
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh // g, max_pages // n_step),
-        in_specs=[qo_spec] + [
+        in_specs=[pl.BlockSpec((1, g, rows, d), _q_map)] + [
             pl.BlockSpec((1, g, pt, a.shape[-1]),
-                         _paged_kv_index_map(j, n_step, pt, n_pages))
+                         _paged_kv_index_map(j, n_step, pt, n_pages,
+                                             shared=page_heads != kvh))
             for a in avals for j in range(n_step)],
-        out_specs=qo_spec,
-        scratch_shapes=_decode_scratch(g, rows, d),
+        out_specs=pl.BlockSpec((1, g, rows, out_dim), _q_map),
+        scratch_shapes=_decode_scratch(g, rows, out_dim),
     )
     return n_step, pl.pallas_call(
         functools.partial(body, scale=scale, page_tokens=pt, n_step=n_step,
-                          chunk=chunk),
+                          chunk=chunk, **kw),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q_dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, out_dim), q_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -849,13 +859,14 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
 
 def _paged_attend(body, name: str, scale: float, q_view, pages, table,
                   lengths, pages_per_step: Optional[int], interpret: bool,
-                  chunk: int = 0):
+                  chunk: int = 0, out_dim: Optional[int] = None):
     """`_paged_call` at the operands' signature, applied: `q_view` is q as
-    [batch, kv_heads, rows, head_dim], and so is the result."""
+    [batch, kv_heads, rows, head_dim], and so is the result (`out_dim`
+    wide, where that is given)."""
     n_step, call = _paged_call(
         body, name, float(scale), (q_view.shape, q_view.dtype.name),
         tuple((a.shape, a.dtype.name) for a in pages), table.shape[1],
-        pages_per_step, bool(interpret), chunk)
+        pages_per_step, bool(interpret), chunk, out_dim)
     with jax.named_scope(name):
         return call(jnp.asarray(table, jnp.int32),
                     jnp.asarray(lengths, jnp.int32), q_view,
@@ -1191,6 +1202,169 @@ def paged_chunk_attention(q, k_pages, v_pages, table, q_pos,
             gather_pages(v_pages, table, n_heads=h), q_pos, scale)
     raise ValueError(f"unknown prefill attention backend {backend!r}; "
                      f"expected auto|paged|flash|xla")
+
+
+# ------------------------------------------------- latent attention
+#
+# Multi-head latent attention caches ONE row a position for all the heads:
+# [c | k_r], the normed low-rank latent and the shared rotary key.  With the
+# keys' and values' up-projections absorbed into the query and the output
+# (models/axk1.py), a head's key IS that row and its value the row's leading
+# `values` columns — so one page serves every head as K and as V at once,
+# and is read once for all of them.  Queries come already scaled.
+
+# one grid step of the latent decode kernel covers up to this many tokens
+# of a row: a page's row is a quarter of a GQA page's bytes (no heads, no
+# V), so a step holds four times `_PAGED_STEP_TOKENS` for the same copy
+_LATENT_STEP_TOKENS = 1024
+
+
+def _latent_pages(table, pages):
+    """[rows, max_pages * page_tokens, width]: the rows a table describes,
+    gathered (the fallbacks; sentinels clip, as `gather_pages`)."""
+    n_pages, pt, w = pages.shape
+    idx = jnp.clip(table.astype(jnp.int32), 0, n_pages - 1)
+    return jnp.take(pages, idx, axis=0).reshape(table.shape[0], -1, w)
+
+
+def _latent_attention_xla(q, pages, table, q_pos, values: int):
+    """Gather-then-mask fallback of both latent kernels, and the reference
+    they are tested against: q [rows, heads, chunk, width] at `q_pos`
+    (int32 [rows, chunk]) against the gathered rows, key kp visible iff
+    kp <= q_pos (`_chunk_attention_xla`'s rule; a decode round is a chunk
+    of one query at `length - 1`), float32 throughout."""
+    kv = _latent_pages(table, pages).astype(jnp.float32)
+    s = jnp.einsum("bhqw,bkw->bhqk", q.astype(jnp.float32), kv)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
+    s = jnp.where(k_pos <= q_pos.astype(jnp.int32)[:, None, :, None], s,
+                  _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkv->bhqv", p, kv[..., :values]).astype(q.dtype)
+
+
+def _flash_latent_kernel(tbl_ref, len_ref, q_ref, *refs, n_step: int,
+                         out_dim: int, **kw):
+    """The paged kernels' body over latent pages: `refs` are n_step pages
+    (each both K and, in its leading `out_dim` columns, V), the output and
+    the scratch.  The block's rows are query heads (a decode round: all of
+    them) or a block of heads x a chunk of queries."""
+    k_refs = refs[:n_step]
+
+    def load_page(j):
+        blk = k_refs[j][0]
+        return blk, blk[:, :, :out_dim]
+
+    _paged_decode_steps(len_ref, q_ref, *refs[n_step:], load_page,
+                        n_step=n_step, **kw)
+
+
+def flash_latent_decode_attention(q, pages, table, lengths, values: int,
+                                  pages_per_step: Optional[int] = None,
+                                  interpret: Optional[bool] = None):
+    """One query a row against latent pages through a page table: q
+    [batch, heads, width] (absorbed and scaled), pages [n_pages,
+    page_tokens, width] (one layer of a latent arena), table int32 [batch,
+    max_pages], lengths int32 [batch].  The paged decode kernel with ONE
+    KV head whose group is every query head, so a live row leaves HBM once
+    a round; the result is [batch, heads, values], the softmax-weighted sum
+    of the rows' leading `values` columns."""
+    if interpret is None:
+        interpret = _default_interpret()
+    b, h, w = q.shape
+    if pages_per_step is None:
+        pages_per_step = max(_LATENT_STEP_TOKENS // pages.shape[1], 1)
+    out = _paged_attend(_flash_latent_kernel, "latent_decode", 1.0,
+                        q.reshape(b, 1, h, w), (pages[:, None],), table,
+                        lengths, pages_per_step, interpret, out_dim=values)
+    return out.reshape(b, h, values)
+
+
+def _latent_head_block(heads: int, rows: int, width: int, values: int,
+                       page_tokens: int, dtype) -> int:
+    """The query heads one grid step of the latent chunk kernel holds: the
+    largest divisor of `heads` whose q and output blocks (double-buffered),
+    float32 accumulator, a page's scores and probabilities and its values
+    in float32 stay under `_PAGED_VMEM_BUDGET`.  Every block of heads reads the row's pages
+    again, so fewer, larger blocks read less."""
+    def step_bytes(hb):
+        r = hb * rows
+        return (2 * _vmem_block_bytes((r, width), dtype)
+                + 2 * _vmem_block_bytes((r, values), dtype)
+                + _vmem_block_bytes((r, values), jnp.float32)
+                + 2 * _vmem_block_bytes((r, page_tokens), jnp.float32)
+                + _vmem_block_bytes((page_tokens, values), jnp.float32))
+
+    return _largest_divisor(
+        heads, lambda hb: step_bytes(hb) <= _PAGED_VMEM_BUDGET)
+
+
+def flash_latent_chunk_attention(q, pages, table, extents, values: int,
+                                 pages_per_step: Optional[int] = None,
+                                 interpret: Optional[bool] = None):
+    """A chunk of queries a row against latent pages (chunked prefill): q
+    [rows, heads, chunk, width] (absorbed and scaled), row r's queries at
+    the LAST `chunk` positions of its extent (`extents[r]`, int32 [rows]; 0
+    = no sequence: reads nothing, gives zeros), pages AFTER the chunk's own
+    write.  `flash_paged_chunk_attention`'s rule (key kp visible to query
+    qp iff kp <= qp) and grid, with a block of query HEADS where that has a
+    KV head: a block's rows are heads x chunk queries, every block reads
+    the row's pages — one head, shared — as far as its extent, nothing is
+    expanded to keys or values a head.  Returns [rows, heads, chunk,
+    values]."""
+    if interpret is None:
+        interpret = _default_interpret()
+    b, h, c, w = q.shape
+    pad = _chunk_rows(c) - c
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    hb = _latent_head_block(h, c + pad, w, values, pages.shape[1], q.dtype)
+    out = _paged_attend(
+        _flash_latent_kernel, "latent_chunk", 1.0,
+        q.reshape(b, h // hb, hb * (c + pad), w), (pages[:, None],), table,
+        extents, pages_per_step, interpret, chunk=c, out_dim=values)
+    return out.reshape(b, h, c + pad, values)[:, :, :c]
+
+
+def _latent_backend(backend: str, knob: str) -> str:
+    if backend == "auto":
+        backend = "paged" if jax.default_backend() == "tpu" else "xla"
+    if backend not in ("paged", "flash", "xla"):
+        raise ValueError(f"unknown {knob} attention backend {backend!r}; "
+                         f"expected auto|paged|flash|xla")
+    return backend
+
+
+def latent_decode_attention(q, pages, table, lengths, values: int,
+                            backend: Optional[str] = None):
+    """Backend-dispatching latent decode attention (`models/decoder.py::
+    Latent`): the kernel on a TPU, gather + masked einsum elsewhere;
+    `EASYDIST_DECODE_ATTENTION` forces it as it does the paged kernel's."""
+    from easydist_tpu import config as edconfig
+
+    lengths = jnp.asarray(lengths, jnp.int32)
+    if _latent_backend(backend or edconfig.decode_attention_backend,
+                       "decode") == "xla":
+        return _latent_attention_xla(q[:, :, None], pages, table,
+                                     lengths[:, None] - 1, values)[:, :, 0]
+    return flash_latent_decode_attention(q, pages, table, lengths, values)
+
+
+def latent_chunk_attention(q, pages, table, q_pos, values: int,
+                           backend: Optional[str] = None):
+    """Backend-dispatching latent chunk attention: q [rows, heads, chunk,
+    width] at CONSECUTIVE positions `q_pos` (int32 [rows, chunk]);
+    `EASYDIST_PREFILL_ATTENTION` forces the backend, as for
+    `paged_chunk_attention`.  A row whose first window is unmapped holds no
+    sequence: the kernel gives it zeros, the gather path whatever the
+    clipped page holds — nobody reads either."""
+    from easydist_tpu import config as edconfig
+
+    if _latent_backend(backend or edconfig.prefill_attention_backend,
+                       "prefill") == "xla":
+        return _latent_attention_xla(q, pages, table, q_pos, values)
+    live = table[:, 0].astype(jnp.int32) < pages.shape[0]
+    extents = jnp.where(live, q_pos[:, -1].astype(jnp.int32) + 1, 0)
+    return flash_latent_chunk_attention(q, pages, table, extents, values)
 
 
 def window_attention(q, k, v, q_pos, k_pos, window: int,

@@ -5,7 +5,7 @@ whole forward.  For autoregressive generation that is O(T^2) attention
 flops per sequence; the KV cache makes each token O(T).  This module is
 the serving half of the cache-carrying model API (models/decoder.py: one
 `Decoder` record a model, the `chunk`/`verify`/`decode` steps, and the
-`Contiguous` and `Paged` cache adapters):
+`Contiguous`, `Paged` and `Latent` cache adapters):
 
   * **chunked, batched prefill** — each admitted prompt is processed in
     fixed [prefill_batch, prefill_chunk] windows against a multi-row
@@ -108,15 +108,25 @@ _SPEC_PROBE_EVERY = 12
 _SPEC_VERIFY_COST = 2.0
 
 
-def kv_cache_specs(axis: str = "tp"):
-    """PartitionSpec pytree for a KV cache {"k", "v"} of shape
-    [layers, batch/slots, heads, max_len, head_dim]: heads sharded on
-    `axis`, everything else replicated — the placement consistent with a
-    tensor-parallel attention strategy."""
+def kv_cache_specs(axis: str = "tp", cache=None):
+    """PartitionSpec pytree for a KV cache: heads sharded on `axis`,
+    everything else replicated — the placement consistent with a
+    tensor-parallel attention strategy.  Without `cache`, the contiguous
+    {"k", "v"} of shape [layers, batch/slots, heads, max_len, head_dim].
+    With one (any adapter's pytree, arrays or shapes), a spec a leaf, its
+    keys and ranks read off it: a contiguous leaf as above, an arena leaf
+    [n_pages, heads, page_tokens, *] on its heads, and a leaf without a
+    heads axis — a latent arena's [n_pages, page_tokens, width] — whole on
+    every device of `axis`: latent attention has no heads to split, and is
+    run data-parallel."""
+    import jax
     from jax.sharding import PartitionSpec as P
 
-    spec = P(None, None, axis, None, None)
-    return {"k": spec, "v": spec}
+    by_rank = {5: P(None, None, axis, None, None),
+               4: P(None, axis, None, None), 3: P()}
+    if cache is None:
+        return {"k": by_rank[5], "v": by_rank[5]}
+    return jax.tree.map(lambda leaf: by_rank[len(leaf.shape)], cache)
 
 
 @dataclass
@@ -226,8 +236,9 @@ class _PagedPool:
                 f"kv_arena_pages {n_pages} cannot hold even one "
                 f"full-length sequence ({self.max_pages} pages)")
         self.n_rows = n_rows
-        # {"k": (one leaf per layer), "v": (...)[, "k_scale", "v_scale"]}
-        # — kv/arena.py; every leaf is donated to each compiled step
+        # {"k": (one leaf per layer), "v": (...)[, "k_scale", "v_scale"]},
+        # or {"latent": (...)} — kv/arena.py; every leaf is donated to each
+        # compiled step
         pages = init_pages(n_pages, chunk)
         self.arena = pages
         # a model that keeps something a SEQUENCE (recurrent state, the
@@ -248,10 +259,13 @@ class _PagedPool:
         self.page_bytes = sum(int(leaf.nbytes) // n_pages
                               for leaves in pages.values()
                               for leaf in leaves)
-        # what one page's k/v payload would cost at model precision —
-        # the baseline the quant-savings gauge subtracts from
+        # what one page's payload (every key but the scales) would cost at
+        # model precision — the baseline the quant-savings gauge subtracts
+        # from
         payload_elems = sum(int(leaf.size) // n_pages
-                            for k in ("k", "v") for leaf in pages[k])
+                            for key, leaves in pages.items()
+                            if not key.endswith("_scale")
+                            for leaf in leaves)
         self.model_page_bytes = payload_elems * model_itemsize \
             if model_itemsize else self.page_bytes
         self.pool = PagePool(n_pages, chunk, page_bytes=self.page_bytes)
@@ -412,8 +426,8 @@ class GenerationSession:
                  replica_id: Optional[str] = None,
                  compile_key: Optional[object] = None):
         from easydist_tpu.jaxfront import easydist_compile
-        from easydist_tpu.models.decoder import (Contiguous, Paged, chunk,
-                                                 decode, verify)
+        from easydist_tpu.models.decoder import (Contiguous, Latent, Paged,
+                                                 chunk, decode, verify)
 
         self.config = config or ServeConfig()
         self.replica_id = replica_id
@@ -426,8 +440,10 @@ class GenerationSession:
                     f"sequence length {model.max_positions}; set "
                     f"ServeConfig(decode_buckets=...) within it")
         self._per_sequence = model.per_sequence
-        self._refuse_for_slots(model, self.config)
+        self._refuse_unbuilt(model, self.config)
         self._model = model
+        # the paged arena's adapter: K/V rows a KV head, or one latent row
+        paged = self._paged_adapter = Latent if model.latent else Paged
         self.params = params
         self.mesh = mesh
         self.eos_id = eos_id
@@ -529,20 +545,27 @@ class GenerationSession:
         # signature stays closed over arbitrary per-row lengths).
         # Compiled lazily via `_paged_c` so bucketed sessions never pay
         # for them; export/import move single pages for fleet handoff.
+        # The expert counters of a model whose `ffn` counts ride the token
+        # readback (`out[rows:]`), so there is one readback still — with a
+        # `State` or, as here, without one.
+        def _ids(logits, kv):
+            import jax.numpy as jnp
+
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if not model.counts:
+                return ids
+            return jnp.concatenate([ids, kv.counters.astype(jnp.int32)])
+
         def _prefill_chunk_paged(arena, params, table, tokens, start,
                                  lengths):
-            import jax.numpy as jnp
-
-            arena, logits = chunk(model, Paged(arena, table), params,
-                                  tokens, start, lengths)
-            return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            kv = paged(arena, table)
+            arena, logits = chunk(model, kv, params, tokens, start, lengths)
+            return arena, _ids(logits, kv)
 
         def _decode_paged(arena, params, table, token, pos):
-            import jax.numpy as jnp
-
-            arena, logits = decode(model, Paged(arena, table), params,
-                                   token, pos)
-            return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            kv = paged(arena, table)
+            arena, logits = decode(model, kv, params, token, pos)
+            return arena, _ids(logits, kv)
 
         # export/import iterate ALL arena keys: a quantized arena ships
         # its scale leaves alongside the int8 payload, so fleet manifests
@@ -564,22 +587,14 @@ class GenerationSession:
         def _verify_paged(arena, params, table, tokens, pos):
             import jax.numpy as jnp
 
-            arena, logits = verify(model, Paged(arena, table), params,
+            arena, logits = verify(model, paged(arena, table), params,
                                    tokens, pos)
             return arena, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         # a model with state layers: the donated pytree is {arena leaves of
         # the attention layers, state leaves of the state layers}; `slots`
         # names each chunk row's state slot, `live` the decode rows that
-        # are sequences.  The round's expert counters ride the token
-        # readback (`out[n_slots:]`), so there is one readback still.
-        def _ids_and_counters(logits, st):
-            import jax.numpy as jnp
-
-            return jnp.concatenate(
-                [jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                 st.counters.astype(jnp.int32)])
-
+        # are sequences.
         def _prefill_chunk_paged_state(cache, params, table, slots, tokens,
                                        start, lengths):
             from easydist_tpu.models.decoder import State
@@ -587,18 +602,19 @@ class GenerationSession:
             pages, leaves = State.split(model, cache)
             n_slots = next(iter(leaves.values()))[0].shape[0]
             st = State(leaves, slots < n_slots, slots, fresh=start == 0)
-            cache, logits = chunk(model, Paged(pages, table), params,
-                                  tokens, start, lengths, state=st)
-            return cache, _ids_and_counters(logits, st)
+            kv = Paged(pages, table)
+            cache, logits = chunk(model, kv, params, tokens, start, lengths,
+                                  state=st)
+            return cache, _ids(logits, kv)
 
         def _decode_paged_state(cache, params, table, live, token, pos):
             from easydist_tpu.models.decoder import State
 
             pages, leaves = State.split(model, cache)
-            st = State(leaves, live)
-            cache, logits = decode(model, Paged(pages, table), params,
-                                   token, pos, state=st)
-            return cache, _ids_and_counters(logits, st)
+            kv = Paged(pages, table)
+            cache, logits = decode(model, kv, params, token, pos,
+                                   state=State(leaves, live))
+            return cache, _ids(logits, kv)
 
         self._paged_defs = {
             "chunk": _prefill_chunk_paged, "decode": _decode_paged,
@@ -642,43 +658,49 @@ class GenerationSession:
          self._paged_cs, self._verify_cs) = shared
 
     @staticmethod
-    def _refuse_for_slots(model, cfg: ServeConfig) -> None:
-        """A state layer caches one state a sequence and a window layer a
-        ring of its last positions, not rows for every position: what
-        assumes those rows is refused here, loudly, until it is built.
-        One row a feature: (asked for, its name, the setting that drops it,
-        why it cannot be with state layers, why not with window rings)."""
+    def _refuse_unbuilt(model, cfg: ServeConfig) -> None:
+        """A state layer caches one state a sequence, a window layer a ring
+        of its last positions, a latent layer one row a position without
+        heads: what assumes K/V rows a head for every position is refused
+        here, loudly, until it is built.  One row a feature: (asked for, its
+        name, the setting that drops it, why it cannot be with state layers,
+        with window rings, with latent attention — None where it can)."""
         refusals = (
             (cfg.kv_layout != "paged", "kv_layout='bucketed'",
              "kv_layout='paged'",
              "the state pool lives beside the page pool",
-             "the rings live beside the page pool"),
+             "the rings live beside the page pool",
+             "a row without heads has no contiguous cache: it lives in "
+             "pages, and the contiguous layout is not built for it"),
             (cfg.kv_host_tier_bytes, "the host tier",
              "kv_host_tier_bytes=0",
              "it demotes trie pages, and the trie is refused too",
-             "it demotes trie pages, and the trie is refused too"),
+             "it demotes trie pages, and the trie is refused too", None),
             (cfg.enable_prefix_cache and cfg.prefix_cache_bytes,
              "the prefix trie", "enable_prefix_cache=False",
              "a restored prefix needs the state as it was at that chunk's "
              "boundary, and no snapshot is kept",
              "a restored or resumed prefix needs the ring as it was at "
-             "that chunk's boundary, and no snapshot is kept"),
+             "that chunk's boundary, and no snapshot is kept", None),
             (cfg.speculate_k, "speculation", "speculate_k=0",
              "a rejected draft cannot be masked out of a recurrent state, "
              "and there is no roll-back",
              "a rejected draft has overwritten ring rows of positions "
-             "still inside the window, and there is no roll-back"),
+             "still inside the window, and there is no roll-back", None),
             (cfg.kv_quant_dtype not in (None, "none"), "the int8 arena",
              "kv_quant_dtype='none'",
              "not measured against a model whose logits also ride a "
              "float32 state",
              "the rings are kept exact, and a model whose layers read "
-             "int8 and exact keys side by side is not measured"),
+             "int8 and exact keys side by side is not measured",
+             "the block scales are laid out a head, a latent row has "
+             "none, and int8 latents are not built"),
         )
         for prop, has, why in (("state layers", model.state_layers, 3),
-                               ("window rings", model.ring_windows, 4)):
+                               ("window rings", model.ring_windows, 4),
+                               ("latent attention", model.latent, 5)):
             for row in refusals if has else ():
-                if row[0]:
+                if row[0] and row[why]:
                     raise ValueError(
                         f"a model with {prop} cannot be served with "
                         f"{row[1]}: {row[why]}; set {row[2]}")
@@ -695,10 +717,10 @@ class GenerationSession:
                 import jax
 
                 out = {}
-                for k in ("k", "v"):
-                    layers, _, heads, _, hd = staging[k].shape
+                for k, leaf in staging.items():
+                    layers, _, heads, _, hd = leaf.shape
                     out[k] = jax.lax.dynamic_slice(
-                        staging[k], (0, row, 0, start, 0),
+                        leaf, (0, row, 0, start, 0),
                         (layers, 1, heads, chunk_len, hd))[:, 0]
                 return out
 
@@ -821,12 +843,11 @@ class GenerationSession:
                                           self.config.kv_cache_dtype))
 
     def _pages_factory(self, n_pages: int, page_tokens: int):
-        from easydist_tpu.models.decoder import Paged
-
         cfg = self.config
-        return self._born(Paged.init(self._model, n_pages, page_tokens,
-                                     cfg.kv_cache_dtype, cfg.kv_quant_dtype,
-                                     cfg.kv_quant_block))
+        quant = () if self._model.latent else (cfg.kv_quant_dtype,
+                                               cfg.kv_quant_block)
+        return self._born(self._paged_adapter.init(
+            self._model, n_pages, page_tokens, cfg.kv_cache_dtype, *quant))
 
     def _state_factory(self, n_slots: int):
         from easydist_tpu.models.decoder import State
@@ -1124,12 +1145,18 @@ class GenerationSession:
             pool.arena, first, sp = self._run(
                 "easydist.serve.prefill.call", result, args,
                 rows=pool.n_rows, chunk=c_len)
-            # a chunk fills one page: a live row's extent ends with it
+            # a chunk fills one page: a live row's extent ends with it; its
+            # n real positions start .. start + n - 1 see start + 1 ..
+            # start + n keys
+            real = [(job.start, min(c_len, len(job.prompt) - job.start))
+                    for job in pool.jobs.values()]
             self.metrics.record_prefill_chunk(
                 pool.n_rows, c_len, sp.seconds,
                 pages_walked=sum(job.start // c_len + 1
                                  for job in pool.jobs.values()),
-                pages_bucket=len(pool.jobs) * pool.max_pages)
+                pages_bucket=len(pool.jobs) * pool.max_pages,
+                attn_pairs=sum(n * start + n * (n + 1) // 2
+                               for start, n in real))
             if len(first) > pool.n_rows:   # the call's expert counters
                 self.metrics.record_moe("prefill", *first[pool.n_rows:])
             calls += 1
@@ -1351,6 +1378,9 @@ class GenerationSession:
             # layer's bytes do not grow with its sequences
             self.metrics.record_window_rings(pool.state.in_use,
                                              _ring_bytes(pool.arena))
+        if "latent" in pool.arena:
+            self.metrics.record_latent_cache(
+                sum(int(leaf.nbytes) for leaf in pool.arena["latent"]))
 
     # ------------------------------------------------ speculative decoding
     def _spec_round(self, pool) -> bool:

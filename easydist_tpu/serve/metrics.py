@@ -209,13 +209,17 @@ class ServeMetrics:
 
     def record_prefill_chunk(self, n_rows: int, chunk: int,
                              chunk_s: float, pages_walked: int = 0,
-                             pages_bucket: int = 0) -> None:
+                             pages_bucket: int = 0,
+                             attn_pairs: int = 0) -> None:
         """One batched chunk call: `n_rows` staging rows executed `chunk`
         token slots each (idle rows and padded tails included — that IS
         the waste the padding-ratio gauge measures).  A paged call also
         says how many K/V pages its live rows' extents cover
         (`pages_walked`: what the chunk kernel reads a layer) of how many
-        their buckets hold (`pages_bucket`: what the gather path reads)."""
+        their buckets hold (`pages_bucket`: what the gather path reads),
+        and how many (query, visible key) pairs its REAL positions make
+        (`attn_pairs`: the attention the model asks of it, a head a
+        layer)."""
         with self._lock:
             self._counters["prefill_chunks"] = \
                 self._counters.get("prefill_chunks", 0) + 1
@@ -226,6 +230,8 @@ class ServeMetrics:
                 self._counters["prefill_pages_bucket"] = \
                     self._counters.get("prefill_pages_bucket", 0) \
                     + pages_bucket
+                self._counters["prefill_attn_pairs"] = \
+                    self._counters.get("prefill_attn_pairs", 0) + attn_pairs
             padded = self._counters["prefill_tokens_padded"] = \
                 self._counters.get("prefill_tokens_padded", 0) \
                 + n_rows * chunk
@@ -269,6 +275,13 @@ class ServeMetrics:
         with self._lock:
             self._gauges["window_ring_slots_in_use"] = slots_in_use
             self._gauges["window_ring_bytes"] = ring_bytes
+
+    def record_latent_cache(self, nbytes: int) -> None:
+        """The bytes of a latent arena's leaves as the last program handed
+        them back: one row a position a layer, whatever the heads — a
+        constant, like the arena itself."""
+        with self._lock:
+            self._gauges["latent_cache_bytes"] = nbytes
 
     def record_moe(self, step: str, pairs_routed: int, experts_hit: int,
                    max_expert_pairs: int) -> None:

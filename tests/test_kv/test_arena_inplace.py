@@ -16,6 +16,9 @@ chip:
     widths (one layer): there a row write indexed `[page, :, offset, :]`
     made XLA re-lay the whole leaf out before and after the scatter, which
     no CPU compile shows;
+  * the A.X-K1 cell's two programs compiled for that v5e: a latent leaf
+    whose rows were not whole lane tiles was given another layout than the
+    kernels read, and copied whole;
   * the Granite 4.0-H cell's expert FFN compiled for that v5e: the routed
     pairs' products are combined with no float32 copy of them (it lives in
     this file because one worker alone may load the TPU's compiler).
@@ -306,10 +309,10 @@ def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
 
     def step(cache, params, table, live, token, pos):
         pages, leaves = State.split(dec, cache)
-        st = State(leaves, live)
-        cache, logits = decode(dec, Paged(pages, table), params, token, pos,
-                               state=st)
-        return cache, jnp.argmax(logits, -1), st.counters
+        kv = Paged(pages, table)
+        cache, logits = decode(dec, kv, params, token, pos,
+                               state=State(leaves, live))
+        return cache, jnp.argmax(logits, -1), kv.counters
 
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         cache, params, table, live, rows, rows).compile()
@@ -367,10 +370,10 @@ def test_window_decode_step_writes_rings_and_arena_in_place_on_tpu(
 
     def step(cache, params, table, live, token, pos):
         pages, leaves = State.split(dec, cache)
-        st = State(leaves, live)
-        cache, logits = decode(dec, Paged(pages, table), params, token, pos,
-                               state=st)
-        return cache, jnp.argmax(logits, -1), st.counters
+        kv = Paged(pages, table)
+        cache, logits = decode(dec, kv, params, token, pos,
+                               state=State(leaves, live))
+        return cache, jnp.argmax(logits, -1), kv.counters
 
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         cache, params, table, live, rows, rows).compile()
@@ -384,6 +387,72 @@ def test_window_decode_step_writes_rings_and_arena_in_place_on_tpu(
     assert text.count('custom_call_target="tpu_custom_call"') == 5, \
         "paged decode attention on the full layer, two grouped products " \
         "an expert layer"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_latent_steps_write_the_arena_in_place_on_tpu(v5e_chip, monkeypatch,
+                                                      program):
+    """The A.X-K1 cell's two programs (BENCHMARK.json: published widths, 32
+    slots, 12 held experts, 2,048 pages of 256 tokens, a bucket of 16,384),
+    the dense layer and one expert layer: the arena's ONE leaf a layer
+    ([2048, 256, 640]: a row of 576 values in five whole lane tiles) is
+    donated and handed back through a write in place — with rows of 576 the
+    TPU gave the leaf another layout than the kernel reads and copied it,
+    whole, every call — and the temporaries stay far under one leaf, so
+    nothing the size of a bucket's expanded keys and values (2 x 16,384 x
+    64 x 256 x 2 B = 1.07 GB a row) is ever formed."""
+    import functools
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.models import axk1
+    from easydist_tpu.models.decoder import Latent, chunk, decode
+    from easydist_tpu.ops import grouped_matmul as gm
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(edconfig, "prefill_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, backend="pallas", interpret=False))
+    cfg = axk1.AxK1Config(vocab=20480, layers=2, experts_held=(0, 12))
+    dec = axk1.decoder(cfg)
+    slots, n_pages, pt, max_pages = 32, 2048, 256, 64
+    rows = slots if program == "decode" else 1     # ONE prefill row
+
+    params = _described(v5e_chip, jax.eval_shape(
+        lambda key: axk1.axk1_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = _described(v5e_chip, jax.eval_shape(
+        lambda: Latent.init(dec, n_pages, pt)))
+    assert sorted(cache) == ["latent"] and len(cache["latent"]) == 2
+    assert cache["latent"][0].shape == (n_pages, pt, 640)
+    ints = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
+    table = jax.ShapeDtypeStruct((rows, max_pages), jnp.int32,
+                                 sharding=v5e_chip)
+
+    if program == "decode":
+        def step(cache, params, table, token, pos):
+            kv = Latent(cache, table)
+            cache, logits = decode(dec, kv, params, token, pos)
+            return cache, jnp.argmax(logits, -1), kv.counters
+        args = (cache, params, table, ints, ints)
+    else:
+        def step(cache, params, table, tokens, start, lengths):
+            kv = Latent(cache, table)
+            cache, logits = chunk(dec, kv, params, tokens, start, lengths)
+            return cache, jnp.argmax(logits, -1), kv.counters
+        args = (cache, params, table, jax.ShapeDtypeStruct(
+            (rows, pt), jnp.int32, sharding=v5e_chip), ints, ints)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*args).compile()
+    leaf = n_pages * pt * 640 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * leaf
+    assert mem.temp_size_in_bytes < leaf // 4, \
+        "a latent leaf is copied round its write, or a bucket is expanded"
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4, \
+        "a latent kernel a layer, two grouped products on the expert layer"
 
 
 _ENTRY_LINE = re.compile(
