@@ -423,13 +423,13 @@ def _decode_temporaries(sess):
     leaf written in place (kv/arena.py), so the program's temporaries must
     stay under ONE leaf: a layer sliced out of a stacked arena, a stack, or
     a write that changes a leaf's layout each cost at least that."""
-    import jax.numpy as jnp
+    import numpy as np
 
     pool = next(iter(sess._pools.values()))
-    rows = jnp.zeros((pool.n_slots,), jnp.int32)
-    table = jnp.zeros((pool.n_slots, pool.max_pages), jnp.int32)
+    # the program's one operand: a slot's table row, token and position
+    rows = np.zeros((pool.n_slots, pool.max_pages + 2), np.int32)
     exe = sess._paged_c("decode").executable_for(
-        pool.arena, sess.params, table, rows, rows)
+        pool.arena, sess.params, rows)
     leaf = min(int(x.nbytes) for x in pool.arena["k"])
     return int(exe.memory_analysis().temp_size_in_bytes), leaf
 

@@ -231,10 +231,15 @@ class Paged:
                                _storage_dtype(dec, dtype), quant_dtype,
                                quant_block)
 
+    @staticmethod
+    def page_tokens(pages) -> int:
+        """The positions a page holds, off the arena's leaves."""
+        return pages["k"][0].shape[2]
+
     def __init__(self, pages, table):
         self._old, self._table = pages, table
         self._new = {key: [] for key in pages}
-        self._pt = pages["k"][0].shape[2]
+        self._pt = self.page_tokens(pages)
         self._quant_nb = pages["k_scale"][0].shape[-1] \
             if "k_scale" in pages else 0
         self.counters = None      # the ffns' counters, summed over layers
@@ -345,10 +350,14 @@ class Latent(Paged):
                                  Latent.width(dec),
                                  _storage_dtype(dec, dtype))
 
+    @staticmethod
+    def page_tokens(pages) -> int:
+        return pages["latent"][0].shape[1]
+
     def __init__(self, pages, table):
         self._old, self._table = pages, table
         self._new = []
-        self._pt = pages["latent"][0].shape[1]
+        self._pt = self.page_tokens(pages)
         self.counters = None
 
     def _padded(self, x):

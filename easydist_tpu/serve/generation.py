@@ -286,6 +286,10 @@ class _PagedPool:
             if host_tier_bytes and self.trie is not None else None
         self._export_page = export_page
         self._tier_seq = 0
+        # {program name: the CompileResult its last launch ran}: the pool's
+        # shapes never change, so a step program resolves once and is
+        # dispatched directly after (`GenerationSession._held_or_resolved`)
+        self.held: Dict[str, object] = {}
 
     def _release_evicted(self, node) -> None:
         # trie eviction drops the trie's hold on the node's arena page;
@@ -393,6 +397,61 @@ class _PagedPool:
         if self.state is not None:
             self.state.release(slot_idx)
         return self.table.unmap_row(slot_idx)
+
+
+# The small operands of a paged step program cross to the chip as ONE int32
+# array, a row a slot (decode) or a prefill row (chunk), built fresh for
+# every launch and handed to the dispatch as numpy: one transfer a launch,
+# and no `device_put` of the session's own.  The builders and the cuts the
+# programs make (`_columns`) agree on the columns:
+#   decode   table row | token | pos (| 1 where the row is a sequence)
+#   chunk    table row | start | length (| state slot) | the chunk's tokens
+# with the bracketed column for a model that keeps state a sequence.
+def _decode_operand(pool: _PagedPool, live: List[int]) -> np.ndarray:
+    """Only the rows of `live` expose their table row: a reserved slot that
+    is still prefilling holds pages (possibly SHARED prefix pages) that
+    must not take the dead-row write a decode step lands at pos 0 —
+    sentinel rows drop it instead."""
+    k = pool.max_pages
+    rows = np.zeros((pool.n_slots, k + 2 + (pool.state is not None)),
+                    np.int32)
+    rows[:, :k] = pool.pool.sentinel
+    rows[live, :k] = pool.table.array[live]
+    rows[live, k] = [pool.slots[idx].token for idx in live]
+    rows[live, k + 1] = [pool.slots[idx].pos for idx in live]
+    if pool.state is not None:
+        rows[live, k + 2] = 1
+    return rows
+
+
+def _chunk_operand(pool: _PagedPool, pad_value: int) -> np.ndarray:
+    """Idle rows keep an all-sentinel table row (and state slot), so their
+    writes drop and their logits are garbage nobody reads."""
+    k, c, state = pool.max_pages, pool.chunk, pool.state
+    at = k + 2 + (state is not None)       # where the tokens start
+    rows = np.full((pool.n_rows, at + c), pad_value, np.int32)
+    rows[:, :k] = pool.pool.sentinel
+    rows[:, k], rows[:, k + 1] = 0, 1
+    if state is not None:
+        rows[:, k + 2] = state.sentinel
+    for row, job in pool.jobs.items():
+        seg = job.prompt[job.start:job.start + c]
+        rows[row, :k] = pool.table.array[job.slot_idx]
+        rows[row, k], rows[row, k + 1] = job.start, len(job.prompt)
+        if state is not None:
+            rows[row, k + 2] = job.slot_idx
+        rows[row, at:at + len(seg)] = seg
+    return rows
+
+
+def _columns(rows, fixed: int, tokens: int = 0):
+    """What a program cuts its operand into: the table rows, the `fixed`
+    single columns after them and, of a chunk's, the last `tokens` columns
+    (a chunk fills one page, so the arena says how many)."""
+    at = rows.shape[1] - tokens
+    k = at - fixed
+    cut = (rows[:, :k], *(rows[:, j] for j in range(k, at)))
+    return (*cut, rows[:, at:]) if tokens else cut
 
 
 class GenerationSession:
@@ -542,7 +601,9 @@ class GenerationSession:
 
         # paged-layout programs: arena first for donation pairing, the
         # int32 page table crosses as data every call (fixed shape — the
-        # signature stays closed over arbitrary per-row lengths).
+        # signature stays closed over arbitrary per-row lengths), in the
+        # step programs as columns of their one operand (`_decode_operand`,
+        # `_chunk_operand`).
         # Compiled lazily via `_paged_c` so bucketed sessions never pay
         # for them; export/import move single pages for fleet handoff.
         # The expert counters of a model whose `ffn` counts ride the token
@@ -556,13 +617,15 @@ class GenerationSession:
                 return ids
             return jnp.concatenate([ids, kv.counters.astype(jnp.int32)])
 
-        def _prefill_chunk_paged(arena, params, table, tokens, start,
-                                 lengths):
+        def _prefill_chunk_paged(arena, params, rows):
+            table, start, lengths, tokens = _columns(
+                rows, 2, paged.page_tokens(arena))
             kv = paged(arena, table)
             arena, logits = chunk(model, kv, params, tokens, start, lengths)
             return arena, _ids(logits, kv)
 
-        def _decode_paged(arena, params, table, token, pos):
+        def _decode_paged(arena, params, rows):
+            table, token, pos = _columns(rows, 2)
             kv = paged(arena, table)
             arena, logits = decode(model, kv, params, token, pos)
             return arena, _ids(logits, kv)
@@ -595,11 +658,12 @@ class GenerationSession:
         # the attention layers, state leaves of the state layers}; `slots`
         # names each chunk row's state slot, `live` the decode rows that
         # are sequences.
-        def _prefill_chunk_paged_state(cache, params, table, slots, tokens,
-                                       start, lengths):
+        def _prefill_chunk_paged_state(cache, params, rows):
             from easydist_tpu.models.decoder import State
 
             pages, leaves = State.split(model, cache)
+            table, start, lengths, slots, tokens = _columns(
+                rows, 3, Paged.page_tokens(pages))
             n_slots = next(iter(leaves.values()))[0].shape[0]
             st = State(leaves, slots < n_slots, slots, fresh=start == 0)
             kv = Paged(pages, table)
@@ -607,13 +671,14 @@ class GenerationSession:
                                   state=st)
             return cache, _ids(logits, kv)
 
-        def _decode_paged_state(cache, params, table, live, token, pos):
+        def _decode_paged_state(cache, params, rows):
             from easydist_tpu.models.decoder import State
 
             pages, leaves = State.split(model, cache)
+            table, token, pos, live = _columns(rows, 3)
             kv = Paged(pages, table)
             cache, logits = decode(model, kv, params, token, pos,
-                                   state=State(leaves, live))
+                                   state=State(leaves, live != 0))
             return cache, _ids(logits, kv)
 
         self._paged_defs = {
@@ -901,18 +966,56 @@ class GenerationSession:
         timing["token_ns"].append(now)
         self.metrics.observe("ttft", (now - timing["submit_ns"]) / 1e9)
 
-    def _run(self, span_name: str, result, args, **attrs):
+    def _resolve(self, pool: _PagedPool, program: str, args):
+        """A paged step program's `CompileResult` for `args`, looked up by
+        signature (compiled where there is none yet) and from now on held by
+        the pool: its first launch, or one whose held result refused the
+        operands' shapes."""
+        result = pool.held[program] = \
+            self._paged_c(program).get_compiled(*args)
+        spans.count("serve_launches", fn=result.name, path="resolved")
+        return result
+
+    def _held_or_resolved(self, pool: _PagedPool, program: str, args):
+        """(the result to launch, `_run`'s `held`): what the pool holds, with
+        no flatten of the parameters and no signature, else `_resolve`'s."""
+        result = pool.held.get(program)
+        if result is not None:
+            return result, (pool, program)
+        return self._resolve(pool, program, args), None
+
+    def _run(self, span_name: str, result, args, held=None, **attrs):
         """One compiled program dispatched and its int32 readback awaited,
         inside a `.call` span: (new state, readback, the closed span).
-        `ready_ns` splits the wait at `block_until_ready`'s return, before
-        the result is copied out."""
+        The readback's copy to the host is asked for as soon as the program
+        is enqueued, so it follows the program and is not a round trip after
+        it; `ready_ns` splits the wait at `block_until_ready`'s return,
+        before the result is copied out.  `h2d` counts the host arrays the
+        dispatch itself carries to the device.  `held` is the (pool,
+        program) whose held `result` this is: no flatten of the parameters
+        and no signature found it, and should it meet other shapes it raises
+        `SignatureMismatch` while it traces, before anything runs, and is
+        resolved again."""
         import jax
 
-        with spans.span(span_name, fn=result.name, **attrs) as sp:
-            state, out = result.dispatch(args, {})
+        from easydist_tpu.jaxfront.api import SignatureMismatch
+
+        with spans.span(span_name, fn=result.name,
+                        h2d=sum(isinstance(a, np.ndarray) for a in args),
+                        **attrs) as sp:
+            try:
+                state, out = result.dispatch(args, {})
+            except SignatureMismatch:
+                if held is None:
+                    raise
+                result, held = self._resolve(*held, args), None
+                state, out = result.dispatch(args, {})
+            out.copy_to_host_async()
             out = jax.block_until_ready(out)
             sp.set(ready_ns=time.perf_counter_ns())
             out = np.asarray(out)
+        if held is not None:
+            spans.count("serve_launches", fn=result.name, path="held")
         return state, out, sp
 
     def _admit_one(self) -> bool:
@@ -1098,35 +1201,14 @@ class GenerationSession:
         all-sentinel table row so their writes drop and their logits are
         garbage nobody reads — one compiled signature regardless of
         which rows are live."""
-        import jax.numpy as jnp
-
         calls = 0
         c_len = pool.chunk
         while pool.jobs and calls < max_chunks:
             with spans.span("easydist.serve.prefill.build"):
-                tokens = np.full((pool.n_rows, c_len),
-                                 int(self.config.pad_value), np.int32)
-                start = np.zeros((pool.n_rows,), np.int32)
-                lengths = np.ones((pool.n_rows,), np.int32)
-                tbl = np.full((pool.n_rows, pool.max_pages),
-                              pool.pool.sentinel, np.int32)
-                for row, job in pool.jobs.items():
-                    seg = job.prompt[job.start:job.start + c_len]
-                    tokens[row, :len(seg)] = seg
-                    start[row] = job.start
-                    lengths[row] = len(job.prompt)
-                    tbl[row] = pool.table.array[job.slot_idx]
-                program, extra = "chunk", ()
-                if self._per_sequence:   # each row's state slot, too
-                    slots = np.full((pool.n_rows,), pool.state.sentinel,
-                                    np.int32)
-                    for row, job in pool.jobs.items():
-                        slots[row] = job.slot_idx
-                    program, extra = "chunk_state", (jnp.asarray(slots),)
-                args = (pool.arena, self.params, jnp.asarray(tbl), *extra,
-                        jnp.asarray(tokens), jnp.asarray(start),
-                        jnp.asarray(lengths))
-                result = self._paged_c(program).get_compiled(*args)
+                program = "chunk_state" if self._per_sequence else "chunk"
+                args = (pool.arena, self.params,
+                        _chunk_operand(pool, int(self.config.pad_value)))
+                result, held = self._held_or_resolved(pool, program, args)
                 if pool.bucket not in self._audited_prefill:
                     self._audited_prefill.add(pool.bucket)
                     # SERVE002's jaxpr walk asserts the bucketed staging
@@ -1143,7 +1225,7 @@ class GenerationSession:
                     except ImportError:
                         pass
             pool.arena, first, sp = self._run(
-                "easydist.serve.prefill.call", result, args,
+                "easydist.serve.prefill.call", result, args, held,
                 rows=pool.n_rows, chunk=c_len)
             # a chunk fills one page: a live row's extent ends with it; its
             # n real positions start .. start + n - 1 see start + 1 ..
@@ -1304,33 +1386,21 @@ class GenerationSession:
 
         with spans.span("easydist.serve.decode.build"):
             live = [i for i in pool.slots if only is None or i in only]
-            token = np.zeros((pool.n_slots,), np.int32)
-            pos = np.zeros((pool.n_slots,), np.int32)
-            for idx in live:
-                token[idx] = pool.slots[idx].token
-                pos[idx] = pool.slots[idx].pos
+            # the positions this round attends, its own included
+            attended = sum(pool.slots[idx].pos + 1 for idx in live)
             if self._paged:
-                # only actively-decoding rows expose their table row: a
-                # reserved-but-still-prefilling slot's pages (possibly
-                # SHARED prefix pages) must not take the dead-row write
-                # this step lands at pos 0 — sentinel rows drop it instead
-                tbl = np.full((pool.n_slots, pool.max_pages),
-                              pool.pool.sentinel, np.int32)
-                for idx in live:
-                    tbl[idx] = pool.table.array[idx]
-                program, extra = "decode", ()
-                if self._per_sequence:   # which rows are sequences, too
-                    alive = np.zeros((pool.n_slots,), bool)
-                    alive[live] = True
-                    program, extra = "decode_state", (jnp.asarray(alive),)
-                args = (pool.arena, self.params, jnp.asarray(tbl), *extra,
-                        jnp.asarray(token), jnp.asarray(pos))
-                compiled = self._paged_c(program)
+                program = "decode_state" if self._per_sequence else "decode"
+                args = (pool.arena, self.params, _decode_operand(pool, live))
+                result, held = self._held_or_resolved(pool, program, args)
             else:
+                token = np.zeros((pool.n_slots,), np.int32)
+                pos = np.zeros((pool.n_slots,), np.int32)
+                for idx in live:
+                    token[idx] = pool.slots[idx].token
+                    pos[idx] = pool.slots[idx].pos
                 args = (pool.cache, self.params, jnp.asarray(token),
                         jnp.asarray(pos))
-                compiled = self._decode_c
-            result = compiled.get_compiled(*args)
+                result, held = self._decode_c.get_compiled(*args), None
             if pool.bucket not in self._audited:
                 self._audited.add(pool.bucket)
                 self._audit_donation(result, pool.bucket)
@@ -1340,7 +1410,7 @@ class GenerationSession:
                     if "k_scale" in pool.arena:
                         self._audit_quant_program(result, "first_decode")
         state, nxt, sp = self._run("easydist.serve.decode.call", result,
-                                   args, rows=len(live))
+                                   args, held, rows=len(live))
         if self._paged:
             pool.arena = state
         else:
@@ -1356,9 +1426,7 @@ class GenerationSession:
                 self._maybe_retire(pool, idx)
             self.metrics.record_decode_step(len(live), pool.n_slots,
                                             sp.seconds)
-            # the positions this round attended, its own included
-            self.metrics.set_gauge("kv_tokens_live",
-                                   int(pos[live].sum()) + len(live))
+            self.metrics.set_gauge("kv_tokens_live", attended)
             if len(nxt) > pool.n_slots:   # the round's expert counters
                 self.metrics.record_moe("decode", *nxt[pool.n_slots:])
             if self._paged:
