@@ -1,0 +1,30 @@
+"""The delta-rule decode update's share of its roofline in the traced part:
+what its calls need — the [key, value] float32 matrix of every head of
+every LIVE row read once and written once in every delta-rule layer (the
+session's `delta_rows_updated` over the traced rounds: live rows x layers),
+q, k, v, the decay, beta and o beside it, seven operations a state element
+(`kernel_costs_delta`: 0.9 FLOP a byte, so bytes at the HBM peak bind) —
+over the kernel's time INSIDE the decode program's executions
+(`delta_trace`).  The bytes are the logical ones: a padded layout would
+read lower.  A dead slot costs the kernel a grid step and no state
+traffic."""
+
+from chipbench import delta_trace, kernel_costs, kernel_costs_delta
+
+META = {"layer": "kernels", "unit": "%", "moves": "token_gap_p95_ms",
+        "source": "device_trace"}
+
+
+def read(run):
+    if not run.get("trace") or not run["trace"].get("counted"):
+        return None
+    rows = run["trace"]["counted"].get("delta_rows_updated")
+    secs = delta_trace.kernel_seconds(run)
+    if not rows or secs is None:
+        return None
+    sizes = run["sizes"]
+    least = kernel_costs.roofline_seconds(
+        kernel_costs_delta.update_flops(rows, sizes),
+        kernel_costs_delta.update_bytes(rows, sizes),
+        kernel_costs.peaks(run["device_kind"]))[0]
+    return 100.0 * least / secs

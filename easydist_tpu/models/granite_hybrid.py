@@ -180,11 +180,12 @@ def mamba_mixer(cfg: GraniteHybridConfig, blk, u, carry, valid):
     head_dim, d_state]}, both float32 -> (out like u, carry after the
     positions that are `valid` (bool [b, s] / [b]); the others leave the
     carry as it was)."""
-    from easydist_tpu.ops.ssm import ssd_chunk_scan, ssm_decode_update
+    from easydist_tpu.ops.ssm import (causal_conv_tail, ssd_chunk_scan,
+                                      ssm_decode_update)
 
     dtype = jnp.dtype(cfg.dtype)
     h, p, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.d_state
-    d_in, tail = cfg.d_inner, cfg.d_conv - 1
+    d_in = cfg.d_inner
     window = u.ndim == 3
     if not window:
         u, valid = u[:, None, :], valid[:, None]
@@ -194,15 +195,8 @@ def mamba_mixer(cfg: GraniteHybridConfig, blk, u, carry, valid):
     xbc = zxbcdt[..., d_in:d_in + cfg.conv_dim].astype(jnp.float32)
     dt = zxbcdt[..., d_in + cfg.conv_dim:].astype(jnp.float32)
 
-    # causal depthwise conv over [carried tail | this window]
-    full = jnp.concatenate([carry["conv"], xbc], axis=1)      # [b, s+3, c]
-    w = blk["conv_w"].astype(jnp.float32)
-    conv = sum(full[:, j:j + s] * w[j] for j in range(cfg.d_conv)) \
-        + blk["conv_b"].astype(jnp.float32)
-    xbc = jax.nn.silu(conv)
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)        # a prefix
-    new_conv = jnp.take_along_axis(
-        full, (n_valid[:, None] + jnp.arange(tail))[:, :, None], axis=1)
+    xbc, new_conv = causal_conv_tail(carry["conv"], xbc, blk["conv_w"],
+                                     blk["conv_b"], valid)
 
     x = xbc[..., :d_in].reshape(b, s, h, p)
     b_mat, c_mat = xbc[..., d_in:d_in + n], xbc[..., d_in + n:]

@@ -10,7 +10,9 @@ TPU that reads and writes each state once, in place.  `A` is a scalar a
 head and B, C are shared by every head (one group).  A position whose `dt`
 is 0 leaves the state as it was, bit for bit — exp(0) * S + 0 — which is
 how the callers keep padded positions and dead rows out of it.  The state
-is float32 throughout.
+is float32 throughout.  `causal_conv_tail` is the short conv with a carried
+tail that sits in front of such a recurrence, shared with the delta rule's
+mixer (`ops/delta_rule.py`).
 """
 
 from __future__ import annotations
@@ -22,7 +24,30 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _default_interpret
 
-__all__ = ["ssd_chunk_scan", "ssm_decode_update", "ssm_decode_update_xla"]
+__all__ = ["causal_conv_tail", "ssd_chunk_scan", "ssm_decode_update",
+           "ssm_decode_update_xla"]
+
+
+def causal_conv_tail(tail, x, w, bias, valid):
+    """The short causal depthwise conv in front of a state layer's
+    recurrence (Mamba-2's, the gated delta rule's), over [carried tail |
+    this window]: tail [b, taps - 1, c] (the last pre-activation inputs of
+    the sequence so far, float32), x [b, s, c] (float32), w [taps, c] (row j
+    multiplies the input taps - 1 - j positions back), bias [c] or None,
+    valid bool [b, s] (a PREFIX of each row counts) -> (silu(conv) [b, s,
+    c], the tail after the positions that count: with none of them, the
+    tail as it was, bit for bit)."""
+    s, taps = x.shape[1], w.shape[0]
+    full = jnp.concatenate([tail, x], axis=1)                # [b, s+taps-1, c]
+    w = w.astype(jnp.float32)
+    conv = sum(full[:, j:j + s] * w[j] for j in range(taps))
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    out = jax.nn.silu(conv)
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    new_tail = jnp.take_along_axis(
+        full, (n_valid[:, None] + jnp.arange(taps - 1))[:, :, None], axis=1)
+    return out, new_tail
 
 
 def _ssd_block(x, dt, a, b_mat, c_mat, state):
@@ -108,6 +133,18 @@ def _ssm_decode_kernel(row_ref, live_ref, s_ref, x_ref, dt_ref, a_ref, b_ref,
             s_out[...] = s_ref[...]
 
 
+def standing_rows(live):
+    """live bool [b] -> int32 [b]: the row whose state blocks a decode
+    kernel's grid step stands on — a live row its own, a dead row the
+    nearest live row before it (the first live row, for those before any; 0
+    where none is live): block indices never go back, so a dead row's step
+    fetches nothing and writes nothing back."""
+    idx = jnp.arange(live.shape[0], dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, idx, -1))
+    first = jnp.argmax(live).astype(jnp.int32)      # 0 where none is live
+    return jnp.where(before < 0, first, before)
+
+
 def _heads_per_step(h: int, p: int, n: int) -> int:
     """Heads a grid step holds: the state block, read and written and each
     double-buffered, within ~4 MiB of fast memory."""
@@ -139,12 +176,7 @@ def ssm_decode_update(state, x, dt, a, b_vec, c_vec, d_skip, live=None,
         interpret = _default_interpret()
     hb = _heads_per_step(h, p, n)
     f32 = jnp.float32
-    # a dead row stands on the nearest live row before it (the first live
-    # row, for those before any): block indices never go back
-    idx = jnp.arange(b, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(live, idx, -1))
-    first = jnp.argmax(live).astype(jnp.int32)      # 0 where none is live
-    rows = jnp.where(before < 0, first, before)
+    rows = standing_rows(live)
 
     def row(hi, bi, rows, live):
         return (rows[bi], hi, 0)

@@ -1238,7 +1238,9 @@ class GenerationSession:
                                  for job in pool.jobs.values()),
                 pages_bucket=len(pool.jobs) * pool.max_pages,
                 attn_pairs=sum(n * start + n * (n + 1) // 2
-                               for start, n in real))
+                               for start, n in real),
+                delta_positions=sum(n for _, n in real)
+                * len(pool.arena.get("delta", ())))
             if len(first) > pool.n_rows:   # the call's expert counters
                 self.metrics.record_moe("prefill", *first[pool.n_rows:])
             calls += 1
@@ -1430,9 +1432,9 @@ class GenerationSession:
             if len(nxt) > pool.n_slots:   # the round's expert counters
                 self.metrics.record_moe("decode", *nxt[pool.n_slots:])
             if self._paged:
-                self._record_kv_pool(pool)
+                self._record_kv_pool(pool, len(live))
 
-    def _record_kv_pool(self, pool: _PagedPool) -> None:
+    def _record_kv_pool(self, pool: _PagedPool, live_rows: int = 0) -> None:
         in_use, held = pool.occupancy()
         self.metrics.record_kv_pool(
             in_use, held, pool.chunk,
@@ -1449,6 +1451,13 @@ class GenerationSession:
         if "latent" in pool.arena:
             self.metrics.record_latent_cache(
                 sum(int(leaf.nbytes) for leaf in pool.arena["latent"]))
+        if "delta" in pool.arena:
+            # a delta-rule layer's states: a leaf a layer, a matrix a head
+            # a slot, each live row's updated in place by the round
+            leaves = pool.arena["delta"]
+            self.metrics.record_delta_state(
+                sum(int(leaf.nbytes) for leaf in leaves),
+                rows_updated=live_rows * len(leaves))
 
     # ------------------------------------------------ speculative decoding
     def _spec_round(self, pool) -> bool:

@@ -210,7 +210,8 @@ class ServeMetrics:
     def record_prefill_chunk(self, n_rows: int, chunk: int,
                              chunk_s: float, pages_walked: int = 0,
                              pages_bucket: int = 0,
-                             attn_pairs: int = 0) -> None:
+                             attn_pairs: int = 0,
+                             delta_positions: int = 0) -> None:
         """One batched chunk call: `n_rows` staging rows executed `chunk`
         token slots each (idle rows and padded tails included — that IS
         the waste the padding-ratio gauge measures).  A paged call also
@@ -219,7 +220,9 @@ class ServeMetrics:
         their buckets hold (`pages_bucket`: what the gather path reads),
         and how many (query, visible key) pairs its REAL positions make
         (`attn_pairs`: the attention the model asks of it, a head a
-        layer)."""
+        layer), and a model with delta-rule layers how many REAL positions
+        its chunked scans took, summed over those layers
+        (`delta_positions`)."""
         with self._lock:
             self._counters["prefill_chunks"] = \
                 self._counters.get("prefill_chunks", 0) + 1
@@ -232,6 +235,10 @@ class ServeMetrics:
                     + pages_bucket
                 self._counters["prefill_attn_pairs"] = \
                     self._counters.get("prefill_attn_pairs", 0) + attn_pairs
+            if delta_positions:
+                self._counters["delta_chunk_positions"] = \
+                    self._counters.get("delta_chunk_positions", 0) \
+                    + delta_positions
             padded = self._counters["prefill_tokens_padded"] = \
                 self._counters.get("prefill_tokens_padded", 0) \
                 + n_rows * chunk
@@ -282,6 +289,17 @@ class ServeMetrics:
         constant, like the arena itself."""
         with self._lock:
             self._gauges["latent_cache_bytes"] = nbytes
+
+    def record_delta_state(self, nbytes: int, rows_updated: int) -> None:
+        """The delta-rule layers' states (a model with linear-attention
+        layers): the bytes of ALL the `delta` leaves as the last program
+        handed them back — a constant, whatever the sequences' lengths —
+        and the states this decode round updated in place (live rows x
+        delta-rule layers)."""
+        with self._lock:
+            self._gauges["delta_state_bytes"] = nbytes
+            self._counters["delta_rows_updated"] = \
+                self._counters.get("delta_rows_updated", 0) + rows_updated
 
     def record_moe(self, step: str, pairs_routed: int, experts_hit: int,
                    max_expert_pairs: int) -> None:

@@ -517,3 +517,61 @@ def test_expert_combine_makes_no_float32_copy_of_the_pairs_on_tpu(
                 f"`{op}` only moves the pairs ({dt}, {n} elements)"
     if rows == 1024:    # 269 MB token-major: the float32 view
         assert compiled.memory_analysis().temp_size_in_bytes < 140e6
+
+
+def test_delta_rule_decode_step_writes_arena_and_state_in_place_on_tpu(
+        v5e_chip, monkeypatch):
+    """The Olmo Hybrid cell's decode round (BENCHMARK.json: published
+    widths, 40 slots, 288 pages of 256 tokens), one delta-rule and one full
+    layer, with its two Pallas kernels: the arena's two leaves (30 KV heads
+    of 128, ONE query row a KV head) and the state's two are donated and
+    handed back through writes in place — the delta leaf (88 MB: two heads'
+    [96, 192] matrices to a row of 384 lanes, stored as large as it is) by
+    the update kernel's own alias — so the temporaries stay far under one
+    delta leaf."""
+    import functools
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.models import olmo_hybrid as oh
+    from easydist_tpu.models.decoder import Paged, State, decode
+    from easydist_tpu.ops import delta_rule
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(delta_rule, "delta_decode_update", functools.partial(
+        delta_rule.delta_decode_update, backend="pallas", interpret=False))
+    cfg = oh.OlmoHybridConfig(vocab=50176, layer_types=(
+        "linear_attention", "full_attention"))
+    dec = oh.decoder(cfg)
+    slots, n_pages, pt, max_pages = 40, 288, 256, 16
+
+    params = _described(v5e_chip, jax.eval_shape(
+        lambda key: oh.olmo_hybrid_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = _described(v5e_chip, jax.eval_shape(
+        lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_chip)
+    table = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32,
+                                 sharding=v5e_chip)
+
+    def step(cache, params, table, live, token, pos):
+        pages, leaves = State.split(dec, cache)
+        cache, logits = decode(dec, Paged(pages, table), params, token, pos,
+                               state=State(leaves, live))
+        return cache, jnp.argmax(logits, -1)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        cache, params, table, live, rows, rows).compile()
+    page_leaf = n_pages * 30 * pt * 128 * 2
+    delta_leaf = slots * 15 * 96 * 384 * 4
+    conv_leaf = slots * 3 * 11520 * 4
+    assert delta_leaf == slots * 30 * 96 * 192 * 4       # nothing padded
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * page_leaf + delta_leaf + conv_leaf
+    assert mem.temp_size_in_bytes < delta_leaf // 2, \
+        "a state or arena leaf is copied round its write"
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2, \
+        "the state update and the paged decode attention"

@@ -328,3 +328,35 @@ def test_a_session_serves_it_and_the_ids_are_the_references(params):
     assert 0 < counters["moe_pairs_routed"] <= 2 * 3 * \
         counters["tokens_generated"]
     sess.close()
+
+
+# taken at the commit before the conv moved to `ops/ssm.py::causal_conv_tail`
+# (which the delta rule's mixer calls too), on the CPU, by jax 0.9.0
+MIXER_AT_THE_PARENT = {"window": "e5bf456767e73e43",
+                       "decode": "28ac03f4c7f95629"}
+
+
+@pytest.mark.parametrize("form", sorted(MIXER_AT_THE_PARENT))
+def test_the_mixer_lowers_to_what_it_did_before_the_conv_was_lifted(form):
+    """A migration proof, like `test_lowering_unchanged.py` (which holds the
+    two whole programs): the mixer alone, over a window and over one
+    position, lowers to the text it lowered to with the conv inline."""
+    import hashlib
+
+    import jax
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("digests recorded with jax 0.9.0")
+    cfg = gh.GraniteHybridConfig.tiny()
+    blk = gh.granite_init(cfg, jax.random.PRNGKey(0))["blocks"][0]
+    carry = {"conv": jnp.zeros((2, cfg.d_conv - 1, cfg.conv_dim)),
+             "ssm": jnp.zeros((2, cfg.mamba_heads, cfg.mamba_head_dim,
+                               cfg.d_state))}
+    u, valid = (jnp.zeros((2, 8, cfg.dim)), jnp.ones((2, 8), bool)) \
+        if form == "window" else (jnp.zeros((2, cfg.dim)),
+                                  jnp.ones((2,), bool))
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        (blk, u, carry, valid))
+    text = jax.jit(lambda *a: gh.mamba_mixer(cfg, *a)).lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == MIXER_AT_THE_PARENT[form]
