@@ -8,9 +8,11 @@ against a reference computed beside it.  Phases, in order:
 
   clock    a chained bf16 matmul timed with plain `jax.block_until_ready` must
            land under the chip's datasheet peak (so the wait is real)
-  kernels  the six Pallas kernels of ops/flash_attention.py, compiled
+  kernels  the Pallas kernels of ops/flash_attention.py, compiled
            (`interpret=False`), against the float32 XLA paths in that file;
            the paged ones at GPT-2 small's heads and at a GQA shape
+  probe    the five paged kernels timed ALONE, each at its benchmark cell's
+           shapes and live share (microseconds a call: notes, not claims)
   trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
            state threaded and donated; loss trajectory against a plain
            `jax.jit` of the einsum-attention step
@@ -31,6 +33,7 @@ rule store under `<checkout>/.easydist_cache`, and `chiprun_out/chip_smoke/`.
 """
 
 import collections
+import functools
 import importlib
 import json
 import os
@@ -274,6 +277,89 @@ def phase_kernels(batch=TRAIN_BATCH, heads=12, d=64, seq=1024, slots=8,
     log("PASS kernels (interpret=%s): max error / max |reference| — %s"
         % (interpret, ", ".join(f"{k} {v:.1e}" for k, v in errs.items())))
     return {k: float(f"{v:.2e}") for k, v in errs.items()}
+
+
+# ---------------------------------------------------------------- probe
+
+# The five paged kernels at their cells' shapes (BENCHMARK.json's serving
+# cells) and live shares (PERF.md section 5): slots or prefill rows, query
+# heads, KV heads (0: latent pages, which have none), the pages' minor dim,
+# page_tokens, pages a bucket, arena pages, chunk (0: a decode round), and
+# the rows' lengths — the rest of the rows hold no sequence (an all-sentinel
+# table row; length 1 in a decode round, as `_decode_operand` gives it).
+PROBE_CASES = {
+    "mistral.paged_decode": (32, 32, 8, 128, 64, 32, 576, 0, [380] * 5),
+    "mistral.paged_decode.all_dead": (32, 32, 8, 128, 64, 32, 576, 0, []),
+    "mistral.paged_decode.one_dead_row": (1, 32, 8, 128, 64, 32, 576, 0, []),
+    "mistral.paged_decode.all_live": (32, 32, 8, 128, 64, 32, 576, 0,
+                                      [1100] * 32),
+    "mistral.paged_chunk": (4, 32, 8, 128, 64, 32, 576, 64, [256, 448]),
+    "olmo.paged_decode": (40, 30, 30, 128, 256, 16, 288, 0, [1300] * 25),
+    "olmo.paged_chunk": (1, 30, 30, 128, 256, 16, 288, 256, [1280]),
+    "kexaone.paged_decode": (64, 64, 8, 128, 256, 32, 2048, 0, [900] * 28),
+    "kexaone.paged_chunk": (2, 64, 8, 128, 256, 32, 2048, 256, [1024, 3584]),
+    "granite.paged_decode": (64, 32, 8, 128, 256, 16, 1024, 0, [700] * 29),
+    "axk1.latent_decode": (32, 64, 0, 640, 256, 64, 2048, 0, [6000] * 6),
+    "axk1.latent_chunk": (2, 64, 0, 640, 256, 64, 2048, 256, [3072, 6144]),
+}
+
+
+def phase_paged_probe(cases=None, iters=200, interpret=False):
+    """Microseconds a call of each paged kernel ALONE: `iters` calls in one
+    `fori_loop` of one program (each call's lengths hang on the call before
+    it, so none is hoisted or merged), timed once after a warm-up run.
+    What the loop itself costs reads in the `all_dead` case, where the call
+    walks no page."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    bf = jnp.bfloat16
+    out = {}
+    for name in cases or PROBE_CASES:
+        rows, heads, kvh, width, pt, max_pages, n_pages, chunk, live = \
+            PROBE_CASES[name]
+        rs = np.random.RandomState(len(name))
+        perm = iter(rs.permutation(n_pages))
+        table = np.full((rows, max_pages), n_pages, np.int32)
+        lengths = np.full((rows,), 0 if chunk else 1, np.int32)
+        for row, n in zip(rs.permutation(rows)[:len(live)], live):
+            lengths[row] = n
+            table[row, :-(-n // pt)] = [next(perm) for _ in range(-(-n // pt))]
+        key = jax.random.PRNGKey(len(name))
+        q = jax.random.normal(
+            key, (rows, heads) + ((chunk,) if chunk else ()) + (width,), bf)
+        shape = (n_pages, kvh, pt, width) if kvh else (n_pages, pt, width)
+        pages = [jax.jit(lambda k: jax.random.normal(k, shape, bf))(
+            jax.random.fold_in(key, i)) for i in range(2 if kvh else 1)]
+        if kvh:
+            call = fa.flash_paged_chunk_attention if chunk \
+                else fa.flash_paged_decode_attention
+        else:
+            call = functools.partial(
+                fa.flash_latent_chunk_attention if chunk
+                else fa.flash_latent_decode_attention, values=512)
+
+        @jax.jit
+        def many(q, pages, table, lengths):
+            def one(_, carry):
+                n, acc = carry
+                o = call(q, *pages, table, n, interpret=interpret)
+                first = o.reshape(-1)[0].astype(jnp.float32)
+                return n + jnp.isnan(first).astype(jnp.int32), acc + first
+            return jax.lax.fori_loop(0, iters, one,
+                                     (lengths, jnp.float32(0)))
+
+        args = (q, pages, jnp.asarray(table), jnp.asarray(lengths))
+        jax.block_until_ready(many(*args))
+        t0 = time.perf_counter()
+        jax.block_until_ready(many(*args))
+        out[name] = round((time.perf_counter() - t0) / iters * 1e6, 2)
+        del pages, args
+    log("paged kernels alone, us a call: "
+        + ", ".join(f"{k} {v}" for k, v in out.items()))
+    return out
 
 
 # -------------------------------------------------------------- trainer
@@ -688,6 +774,7 @@ def main() -> int:
 
     notes = {"clock": phase_clock(peak)}
     notes["kernels"] = phase_kernels()
+    notes["paged_probe_us"] = phase_paged_probe()
     notes["trainer"] = phase_trainer()
     params = gpt_init(GPTConfig(**GPT2_SMALL), jax.random.PRNGKey(0))
     for layout in ("bucketed", "paged", "paged_int8"):

@@ -14,6 +14,7 @@ op-execution time, bounding discovery memory to one op's working set.
 
 from __future__ import annotations
 
+import functools
 import logging
 import time
 import zlib
@@ -29,6 +30,8 @@ from easydist_tpu.metashard import MetaOp, ShardSpace, view_rule
 from easydist_tpu.metashard.metaop import probe_calls
 
 logger = logging.getLogger(__name__)
+
+_JAXPRS = (jex_core.Jaxpr, jex_core.ClosedJaxpr)
 
 # primitives whose sharding rule is computed analytically, not by execution
 _VIEW_PRIMS = {"reshape"}
@@ -111,6 +114,25 @@ def hash_array_bytes(arr) -> str:
     return hashlib.sha256(arr.tobytes()).hexdigest()[:16]
 
 
+@functools.lru_cache(maxsize=256)
+def _jaxpr_text(jaxpr) -> str:
+    """`repr` of a jaxpr held as an equation's parameter, printed once an
+    OBJECT: a model's layers call a Pallas kernel at one signature and
+    share its jaxpr (`ops/flash_attention.py::_paged_call`), and printing
+    the paged kernels' body costs ~45 ms — a second a program and more, in
+    every start, when each of 16 layers' equations is signed three times
+    (PERF.md section 6, PR 42)."""
+    return repr(jaxpr)
+
+
+def _params_text(params) -> str:
+    """`str(sorted(params.items()))`, letter for letter."""
+    return "[" + ", ".join(
+        f"({k!r}, "
+        f"{_jaxpr_text(v) if isinstance(v, _JAXPRS) else repr(v)})"
+        for k, v in sorted(params.items())) + "]"
+
+
 def eqn_signature(eqn, names: VarNames) -> str:
     """Cache key for an equation: primitive + params + input shapes/dtypes."""
     import numpy as np
@@ -128,7 +150,7 @@ def eqn_signature(eqn, names: VarNames) -> str:
         else:
             parts.append(f"{v.aval.dtype.name}{list(v.aval.shape)}")
     try:
-        params = str(sorted(eqn.params.items()))
+        params = _params_text(eqn.params)
     except Exception:
         params = str(eqn.params)
     return f"{prim}|{';'.join(parts)}|{params}"

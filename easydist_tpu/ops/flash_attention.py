@@ -704,11 +704,14 @@ def _paged_decode_attention_quant_xla(q, k_pages, v_pages, k_scale,
     return _decode_attention_xla(q, kf, vf, lengths, scale)
 
 
-# One grid step of the paged kernels covers up to this many tokens of a
-# row (swept on a v5e at 32 slots, 8 KV heads of 128, 64-token pages:
-# PERF.md section 6, PR 25) ...
+# One window of a paged kernel's walk — one turn of its loop: the pages it
+# copies together, and behind which it starts the next window's — covers up
+# to this many tokens of a row (swept on a v5e at 32 slots, 8 KV heads of
+# 128, 64-token pages: a call alone is within 4 us of its best from 2 to 16
+# pages a window, at 5 live rows and at 32, and 5-30 % slower at ONE, whose
+# copies nothing hides; PERF.md section 6, PR 42) ...
 _PAGED_STEP_TOKENS = 256
-# ... as long as what the step holds in VMEM stays under this: half of the
+# ... as long as what the window holds in VMEM stays under this: half of the
 # 16 MiB a v5e kernel may scope
 _PAGED_VMEM_BUDGET = 8 * 2 ** 20
 
@@ -728,9 +731,9 @@ def _largest_divisor(n: int, fits) -> int:
 
 
 def _paged_step_bytes(pages, g: int, n: int, rows: int = 0) -> int:
-    """VMEM one grid step of the paged kernels takes with g KV heads of n
-    pages: every `pages` operand's block double-buffered, and the f32
-    working copies of one page's K and V.  `rows` query rows a KV head
+    """VMEM one grid step of the paged kernels takes with g KV heads and
+    windows of n pages: every `pages` operand's window in its two slots,
+    and the f32 working copies of one page's K and V.  `rows` query rows a KV head
     count where they are many (a chunk of queries; a decode round's GQA
     group is left out): q and the output double-buffered, the accumulator
     and the two statistics, and a page's f32 scores and probabilities."""
@@ -748,9 +751,9 @@ def _paged_step_bytes(pages, g: int, n: int, rows: int = 0) -> int:
 
 def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None,
                       rows: int = 0):
-    """(KV heads, pages) one grid step of the paged kernels holds, from the
-    shapes and dtypes of the `pages` operands ([n_pages, kv_heads,
-    page_tokens, *] each; K first) and the query `rows` a KV head
+    """(KV heads a grid step of the paged kernels holds, pages a window of
+    its walk), from the shapes and dtypes of the `pages` operands ([n_pages,
+    kv_heads, page_tokens, *] each; K first) and the query `rows` a KV head
     (`_paged_step_bytes`) alone.  Heads: all of them — a page is then one
     contiguous block — unless one such page is over `_PAGED_VMEM_BUDGET`
     (then the largest divisor of kv_heads that fits).  Pages: the largest
@@ -768,30 +771,51 @@ def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None,
     return g, n
 
 
-def _paged_kv_index_map(j: int, n_step: int, page_tokens: int, n_pages: int,
-                        shared: bool = False):
-    """Block index of the j-th K/V (or scale) page operand for grid
-    (bi, gi, pi): window pi * n_step + j of row bi, resolved through the
-    scalar-prefetched page table; a block is the gi-th group of KV heads of
-    one arena page (as a rule all of them: the whole page).  Dead windows
-    (past the row's live pages) clamp to the last live one — from the
-    second dead step on a repeated index, so Pallas skips the DMA, and the
-    kernel's @pl.when skips the compute.  (Clamping each operand to ITS
-    last live window saves the copies of a row's first dead step too, and
-    measured 6% slower on the v5e: the maps run on the scalar core every
-    step.)  Sentinel entries clip to a real page, as `gather_pages` does.
-    `shared`: the pages have ONE head, which every block of query rows
-    attends (latent attention's blocks of heads)."""
-    def kv_map(bi, gi, pi, tbl_ref, len_ref):
-        last_live = jnp.maximum(
-            jax.lax.div(len_ref[bi] + page_tokens - 1, page_tokens) - 1, 0)
-        page = tbl_ref[bi, jnp.minimum(pi * n_step + j, last_live)]
-        return (jnp.clip(page, 0, n_pages - 1), 0 if shared else gi, 0, 0)
-
-    return kv_map
+def _row_parts(pages) -> int:
+    """The positions of a page that share a 128-lane row in the form a paged
+    kernel takes its `pages` operands (`_whole_lanes`): 128 / head_dim where
+    every operand has that one narrow minor dim and a page's positions
+    divide so (heads of 64: 2), else 1."""
+    _, _, pt, w = pages[0].shape
+    parts = 128 // w if w < 128 and 128 % w == 0 else 1
+    same = all(a.shape[-1] == w for a in pages)
+    return parts if same and pt % parts == 0 else 1
 
 
-def _q_map(bi, gi, pi, tbl_ref, len_ref):
+def _whole_lanes(a, parts: int):
+    """`a` [n_pages, heads, page_tokens, w] as a paged kernel takes it, in
+    whole 128-lane rows: as it is where w is whole tiles (heads of 128 or
+    256, a 640-wide latent row); `parts` consecutive positions to a row
+    where they fill one (`_row_parts`; [.., page_tokens / parts, 128], row
+    r the positions r * parts + i); else the minor dim zero-padded (a
+    width that divides no 128, as 96).  The kernels copy pages by hand,
+    and Mosaic slices an HBM ref along whole tiles only ("Slice shape
+    along dimension 3 must be aligned to tiling (128)", v5e, libtpu
+    0.0.34).  A narrow operand costs a copy of the leaf a call, as it did
+    the BlockSpec form before the walk was the kernel's own: a v5e keeps
+    such a leaf with its PAGES on the lanes ({0,3,2,1}) and a Mosaic call
+    takes it row-major, which XLA made of it with every row padded to 128
+    lanes (PERF.md section 6, PR 42)."""
+    *lead, pt, w = a.shape
+    if parts > 1:
+        return a.reshape(*lead, pt // parts, parts * w)
+    pad = -w % 128
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),)) if pad else a
+
+
+def _use_kernel(backend: str, knob: str) -> bool:
+    """The paged dispatchers' one rule: "paged" / "flash" pick the kernel,
+    "xla" the gather path, "auto" the kernel on a TPU and the gather path
+    elsewhere."""
+    if backend == "auto":
+        return jax.default_backend() == "tpu"
+    if backend not in ("paged", "flash", "xla"):
+        raise ValueError(f"unknown {knob} attention backend {backend!r}; "
+                         f"expected auto|paged|flash|xla")
+    return backend != "xla"
+
+
+def _q_map(bi, gi, tbl_ref, len_ref):
     return (bi, gi, 0, 0)
 
 
@@ -811,47 +835,54 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
     parameters) — not once a layer, which on a 16-layer model was seconds
     of every start (PERF.md section 6, PR 35).
 
-    Grid (batch, kv_heads / g, max_pages / n) with g and n from
-    `_paged_step_shape`: one step serves EVERY query row of g KV heads (in
-    a decode round as a rule all heads of the row) over n consecutive
-    windows of the row's page table, so a live K/V row leaves HBM once,
-    whatever the GQA group.  Pages are not contiguous in the arena, so
+    Grid (batch, kv_heads / g) with g from `_paged_step_shape`: one step
+    serves EVERY query row of g KV heads (in a decode round as a rule all
+    heads of the row) over the row's whole extent, so a live K/V row
+    leaves HBM once, whatever the GQA group.  The grid has no page axis:
     every `pages` operand ([n_pages, kv_heads, page_tokens, *]) is passed
-    n times, the j-th copy with the index map of window pi * n + j:
-    BlockSpec pipelining issues the n page copies of the next step while
-    this one computes.  Table and lengths are scalar-prefetched.  q and
-    the output ride a [batch, kv_heads, rows, head_dim] view (rows: the
+    once (in whole lanes: `_whole_lanes`) and stays where it is (`pl.ANY`),
+    and the body (`_paged_decode_steps`) walks the row's LIVE windows of n
+    pages (n from `_paged_step_shape` too) in a loop of its own, copying
+    each page into one of two VMEM slots of [n, g, page_tokens, *] an
+    operand — the scratch after the accumulator and the statistics, with
+    a DMA semaphore a slot's page and the one SMEM word that hands the slot
+    of a step's first window to it.  Both axes are `arbitrary`: the slots and that
+    word outlive a grid step.  Table and lengths are scalar-prefetched.  q
+    and the output ride a [batch, kv_heads, rows, head_dim] view (rows: the
     GQA group, times `chunk` queries for the chunk kernel), whose blocks'
     trailing dims equal the array's.  The latent kernels differ in two
     things: the pages have one head, which every block of the q view's
     second axis (blocks of query heads, there) attends, and the result is
-    `out_dim` wide, the page's leading columns being its values.  Returns
-    (n, the call)."""
+    `out_dim` wide, the page's leading columns being its values."""
     (b, kvh, rows, d), q_dtype = q_view
     avals = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in pages]
-    n_pages, page_heads, pt, _ = avals[0].shape
+    _, page_heads, pt, _ = avals[0].shape
     g, n_step = _paged_step_shape(max_pages, avals, pages_per_step,
                                   rows if chunk else 0)
+    parts = _row_parts(avals)
     kw = {} if out_dim is None else {"out_dim": out_dim}
     out_dim = out_dim or d
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh // g, max_pages // n_step),
-        in_specs=[pl.BlockSpec((1, g, rows, d), _q_map)] + [
-            pl.BlockSpec((1, g, pt, a.shape[-1]),
-                         _paged_kv_index_map(j, n_step, pt, n_pages,
-                                             shared=page_heads != kvh))
-            for a in avals for j in range(n_step)],
+        grid=(b, kvh // g),
+        in_specs=[pl.BlockSpec((1, g, rows, d), _q_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(avals),
         out_specs=pl.BlockSpec((1, g, rows, out_dim), _q_map),
-        scratch_shapes=_decode_scratch(g, rows, out_dim),
+        scratch_shapes=_decode_scratch(g, rows, out_dim) + [
+            pltpu.VMEM((2, n_step, min(g, page_heads), pt // parts,
+                        -(-parts * a.shape[-1] // 128) * 128), a.dtype)
+            for a in avals]
+        + [pltpu.SemaphoreType.DMA((2, n_step)),
+           pltpu.SMEM((1,), jnp.int32)],
     )
-    return n_step, pl.pallas_call(
-        functools.partial(body, scale=scale, page_tokens=pt, n_step=n_step,
-                          chunk=chunk, **kw),
+    return pl.pallas_call(
+        functools.partial(body, scale=scale, chunk=chunk,
+                          widths=tuple(a.shape[-1] for a in avals),
+                          parts=parts, **kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rows, out_dim), q_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
     )
@@ -863,14 +894,15 @@ def _paged_attend(body, name: str, scale: float, q_view, pages, table,
     """`_paged_call` at the operands' signature, applied: `q_view` is q as
     [batch, kv_heads, rows, head_dim], and so is the result (`out_dim`
     wide, where that is given)."""
-    n_step, call = _paged_call(
+    call = _paged_call(
         body, name, float(scale), (q_view.shape, q_view.dtype.name),
         tuple((a.shape, a.dtype.name) for a in pages), table.shape[1],
         pages_per_step, bool(interpret), chunk, out_dim)
+    parts = _row_parts(pages)
     with jax.named_scope(name):
         return call(jnp.asarray(table, jnp.int32),
                     jnp.asarray(lengths, jnp.int32), q_view,
-                    *[a for a in pages for _ in range(n_step)])
+                    *(_whole_lanes(a, parts) for a in pages))
 
 
 def _paged_decode_call(body, name: str, scale: float, q, pages, table,
@@ -887,57 +919,141 @@ def _paged_decode_call(body, name: str, scale: float, q, pages, table,
     return out.reshape(b, h, d)
 
 
-def _paged_decode_steps(len_ref, q_ref, o_ref, o_scr, m_scr, l_scr, load_page,
-                        *, scale: float, page_tokens: int, n_step: int,
+def _paged_decode_steps(tbl_ref, len_ref, q_ref, pages, o_ref, o_scr, m_scr,
+                        l_scr, slots, sem, first_ref, load_page, *,
+                        scale: float, widths, parts: int = 1,
                         chunk: int = 0):
-    """The body the paged kernels share.  Grid step (bi, gi, pi) holds the
-    pages of windows [pi * n_step, (pi + 1) * n_step) of row bi (the
-    BlockSpec index maps, not this body, chased the table); `load_page(j)`
-    gives the j-th one's K and V as [g, page_tokens, d].  A step that starts
-    past the row's length is skipped whole, a dead page inside a live step
-    one by one.  With `chunk`, the block's rows are the GQA group x a chunk
-    of queries (the chunk padded to a whole tile) at the row's LAST `chunk`
-    positions, and each sees the keys up to its own."""
-    pi = pl.program_id(2)
+    """The body the paged kernels share.  Grid step (bi, gi) is row bi and
+    its gi-th group of g KV heads; `pages` are the arena operands where
+    they lie (HBM, in whole lanes: `_whole_lanes`), `slots` their VMEM
+    scratch [2, n_step, g, page_tokens / parts, *], `sem` a DMA semaphore a
+    page of a slot (its operands share it), `widths` each operand's own
+    minor dim — what is read of a padded slot's lanes — and
+    `load_page(*blocks)` makes the K and V [g, page_tokens, d] of a page
+    from its operands' blocks.  With `parts` positions to a row, a page is
+    attended in `parts` turns: turn i the lanes of the positions i, i +
+    parts, ... of the page.
 
-    @pl.when(pi == 0)
-    def _init():
-        _decode_init(o_scr, m_scr, l_scr)
+    A row walks ceil(length / (n_step * page_tokens)) windows of its page
+    table — none if its first entry names no page of the arena (a slot
+    that holds no sequence) — in a `fori_loop`: window w + 1's pages are
+    asked for (one copy a page an operand, from `pages.at[table[bi, p],
+    heads]`, the entry clipped as `gather_pages` clips it) before window w
+    is waited for and computed, into the other slot.  A page past the
+    row's length, inside its last window, is neither copied nor computed.
+    The LAST window of a step asks for the first window of the next step
+    that walks any (the row's next group of heads, or the first of the
+    next row that has windows: `live_row`), so that no step opens on a
+    copy nobody started; step (0, 0) asks for the first of all.
+    `first_ref[0]` is the slot that window lies in.  (A step that starts
+    its own first window waits ~1.0-1.3 us for it: a decode call alone
+    was 12 % slower at Olmo's shapes, 16 % at K-EXAONE's and 20 % at
+    Granite's — PERF.md section 6, PR 42.)  Whatever is started is waited
+    for by the step it was started for, so nothing is in flight when the
+    call ends.  With
+    `chunk`, the block's rows are the GQA group x a chunk of queries (the
+    chunk padded to a whole tile) at the row's LAST `chunk` positions, and
+    each sees the keys up to its own."""
+    bi, gi = pl.program_id(0), pl.program_id(1)
+    n_rows, n_groups = pl.num_programs(0), pl.num_programs(1)
+    n_pages, page_heads = pages[0].shape[:2]
+    _, n_step, g, page_rows, _ = slots[0].shape
+    page_tokens = page_rows * parts
+    max_windows = tbl_ref.shape[1] // n_step
 
-    length = limit = len_ref[pl.program_id(0)]
+    def windows(row):
+        first = tbl_ref[row, 0]
+        return jnp.where(
+            (first >= 0) & (first < n_pages),
+            jnp.minimum(pl.cdiv(len_ref[row], n_step * page_tokens),
+                        max_windows), 0)
+
+    def live_row(row):
+        """The first row from `row` on that walks any window, or n_rows."""
+        return jax.lax.while_loop(
+            lambda r: (r < n_rows)
+            & (windows(jnp.minimum(r, n_rows - 1)) == 0),
+            lambda r: r + 1, row)
+
+    def start(row, group, w, slot):
+        """Ask for the pages of window w of (row, group), into `slot`;
+        row n_rows: there is none to ask for."""
+        length = jnp.where(row < n_rows,
+                           len_ref[jnp.minimum(row, n_rows - 1)], 0)
+        for j in range(n_step):
+            p = w * n_step + j
+
+            @pl.when(p * page_tokens < length)
+            def _copy():
+                page = jnp.clip(tbl_ref[row, p], 0, n_pages - 1)
+                heads = pl.ds(group * g if page_heads > g else 0, g)
+                for src, dst in zip(pages, slots):
+                    pltpu.make_async_copy(src.at[page, heads],
+                                          dst.at[slot, j],
+                                          sem.at[slot, j]).start()
+
+    @pl.when((bi == 0) & (gi == 0))
+    def _open():
+        first_ref[0] = 0
+        start(live_row(0), 0, 0, 0)
+
+    _decode_init(o_scr, m_scr, l_scr)
+    n_windows = windows(bi)
+    first = first_ref[0]
+    length = limit = len_ref[bi]
     if chunk:
         rows = q_ref.shape[2]
         limit = length - chunk + 1 + jax.lax.rem(
             jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1),
             _chunk_rows(chunk))
 
-    @pl.when(pi * n_step * page_tokens < length)
-    def _live_step():
+    def window(w, carry):
+        slot = jax.lax.rem(first + w, 2)
+
+        # the window after this one: the row's next, or the first of the
+        # next step that walks any (`live_row(bi)` is bi: it is walking)
+        last = w + 1 == n_windows
+        same_row = gi + 1 < n_groups
+        start(live_row(jnp.where(last & ~same_row, bi + 1, bi)),
+              jnp.where(last, jnp.where(same_row, gi + 1, 0), gi),
+              jnp.where(last, 0, w + 1), 1 - slot)
         q = q_ref[0]
         for j in range(n_step):
-            k0 = (pi * n_step + j) * page_tokens
+            k0 = (w * n_step + j) * page_tokens
 
             @pl.when(k0 < length)
             def _page():
-                k_blk, v_blk = load_page(j)
-                _decode_block_update(q, k_blk, v_blk, k0, limit, scale,
-                                     o_scr, m_scr, l_scr)
+                for src, dst in zip(pages, slots):
+                    pltpu.make_async_copy(src.at[0, pl.ds(0, g)],
+                                          dst.at[slot, j],
+                                          sem.at[slot, j]).wait()
+                for i in range(parts):
+                    k_blk, v_blk = load_page(*(
+                        dst[slot, j, :, :, i * w:(i + 1) * w]
+                        for dst, w in zip(slots, widths)))
+                    # turn i of a row of `parts` positions: lane block i of
+                    # row r is position k0 + r * parts + i, so the rows
+                    # under the limit are ceil((limit - k0 - i) / parts)
+                    first, under = (k0, limit) if parts == 1 else (
+                        0, -((k0 + i - limit) // parts))
+                    _decode_block_update(q, k_blk, v_blk, first, under,
+                                         scale, o_scr, m_scr, l_scr)
+        return carry
 
-    @pl.when(pi == pl.num_programs(2) - 1)
-    def _write():
-        o_ref[0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_windows, window, 0)
+    first_ref[0] = jax.lax.rem(first + n_windows, 2)
+    o_ref[0] = _decode_result(o_scr, l_scr).astype(o_ref.dtype)
 
 
-def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, n_step: int,
-                               **kw):
+def _flash_paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                               o_scr, m_scr, l_scr, k_slots, v_slots, sem,
+                               first_ref, **kw):
     """The single-query decode kernel with the K/V stream indirected
-    through the page table: `refs` are n_step K pages, n_step V pages, the
-    output and the scratch.  With `chunk` in `kw` it is the chunk kernel
+    through the page table.  With `chunk` in `kw` it is the chunk kernel
     (`flash_paged_chunk_attention`)."""
-    k_refs, v_refs = refs[:n_step], refs[n_step:2 * n_step]
     _paged_decode_steps(
-        len_ref, q_ref, *refs[2 * n_step:],
-        lambda j: (k_refs[j][0], v_refs[j][0]), n_step=n_step, **kw)
+        tbl_ref, len_ref, q_ref, (k_hbm, v_hbm), o_ref, o_scr, m_scr, l_scr,
+        (k_slots, v_slots), sem, first_ref, lambda k, v: (k, v), **kw)
 
 
 def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
@@ -950,14 +1066,15 @@ def flash_paged_decode_attention(q, k_pages, v_pages, table, lengths,
     page_tokens, head_dim] arena layers; table: int32 [batch, max_pages];
     lengths: int32 [batch].  The table and lengths ride
     `PrefetchScalarGridSpec` scalar prefetch: they land in SMEM before the
-    grid runs, so the K/V BlockSpec index maps can chase the indirection
-    and clamp dead windows (>= the row's live page count) to the last
-    live page — a repeated index that Pallas serves without re-DMA, the
-    paged extension of the contiguous kernel's dead-block skip.  One grid step holds whole pages
-    (all KV heads: a page is one contiguous block of the arena) of several
-    consecutive windows, and every query head of a GQA group attends the
-    page of its KV head while it is in VMEM.  `pages_per_step` is for tests
-    and sweeps; left None, `_paged_step_shape` derives it from the shapes.
+    grid runs, and the kernel's own loop (`_paged_decode_steps`) reads them
+    to copy the pages under a row's length — and no other: a window past
+    it costs nothing, a row whose table names no page (a slot that holds
+    no sequence) walks nothing and gives zeros.  One grid step is a row
+    (all its KV heads, as a rule: a page is then one contiguous block of
+    the arena); a window of its walk holds several consecutive pages, and
+    every query head of a GQA group attends the page of its KV head while
+    it is in VMEM.  `pages_per_step` (a window's pages) is for tests and
+    sweeps; left None, `_paged_step_shape` derives it from the shapes.
     Returns [batch, heads, head_dim]."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -994,9 +1111,9 @@ def flash_paged_chunk_attention(q, k_pages, v_pages, table, extents,
     left in a page.  Only the pages under a row's extent leave HBM, each
     once a GQA group: the group is folded into the rows of the matmuls
     ([group x chunk, head_dim] x [head_dim, page_tokens]), nothing is
-    gathered, repeated or copied to float32 outside VMEM.  Grid, index
-    maps and online softmax are the decode kernel's (`_paged_call`), the
-    KV heads a step holds bounded by what the query rows take in VMEM.
+    gathered, repeated or copied to float32 outside VMEM.  Grid, walk and
+    online softmax are the decode kernel's (`_paged_call`), the KV heads a
+    step holds bounded by what the query rows take in VMEM.
     Returns [rows, heads, chunk, head_dim]."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -1016,43 +1133,34 @@ def flash_paged_chunk_attention(q, k_pages, v_pages, table, extents,
     return out.reshape(b, h, c + pad, d)[:, :, :c]
 
 
-def _dequant_block(blk_ref, s_ref):
-    """One int8 page [g, pt, d] times its per-row block scales [g, pt, nb],
-    in VMEM.  Each scale column is pulled out by a masked lane reduction
-    and selected onto its d // nb payload columns, so the minor dims keep
-    their shape: Mosaic refuses the lane-splitting reshape(pt, nb, d // nb)
-    ("infer-vector-layout: unsupported shape cast", v5e, libtpu 0.0.34)."""
-    blk = blk_ref[0].astype(jnp.float32)                # [g, pt, d]
-    sc = s_ref[0]                                       # [g, pt, nb]
-    nb = sc.shape[-1]
-    if nb == 1:
-        return blk * sc
-    col = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 2)
-    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
-    scale = None
-    for j in range(nb):
-        sc_j = jnp.sum(jnp.where(lane == j, sc, 0.0), axis=-1,
-                       keepdims=True)                   # [g, pt, 1]
-        scale = sc_j if scale is None else jnp.where(
-            col >= j * (blk.shape[-1] // nb), sc_j, scale)
-    return blk * scale
+def _scale_columns(scales, d: int):
+    """Block scales [..., n_blocks] a COLUMN, [..., d]: each block's over
+    its d // n_blocks columns.  Written as selects over broadcasts of one
+    block's scales and not as a `jnp.repeat`: that a v5e made in the
+    leaf's own pages-minor layout and then copied whole, two passes over
+    the d-wide form where this is one (PERF.md section 6, PR 42)."""
+    nb = scales.shape[-1]
+    col = jax.lax.broadcasted_iota(jnp.int32, scales.shape[:-1] + (d,),
+                                   scales.ndim - 1)
+    out = jnp.broadcast_to(scales[..., :1], col.shape)
+    for j in range(1, nb):
+        out = jnp.where(col >= j * (d // nb), scales[..., j:j + 1], out)
+    return out
 
 
-def _flash_paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, *refs,
-                                     n_step: int, **kw):
+def _flash_paged_decode_quant_kernel(tbl_ref, len_ref, q_ref, *refs, **kw):
     """`_flash_paged_decode_kernel` over block-scaled int8 pages: the K/V
-    pages arrive int8 with their per-block f32 scale pages riding the SAME
-    page-table index maps (`refs`: n_step each of K, V, K scales, V scales,
-    then the output and the scratch), and dequantization happens in VMEM
-    inside the online-softmax loop — the arena stream stays int8 all the
-    way from HBM, which is the whole 2-4x bytes/seq win."""
-    k_refs, v_refs, ks_refs, vs_refs = (
-        refs[i * n_step:(i + 1) * n_step] for i in range(4))
+    pages arrive int8 with their f32 scale pages, a scale a column, copied
+    from the SAME table entries (`refs`: K, V, K scales and V scales in
+    HBM, the output and the accumulator's scratch, then the four operands'
+    slots, the semaphores and the slot word), and dequantization happens in
+    VMEM inside the online-softmax loop: the payload stays int8 all the
+    way from HBM."""
     _paged_decode_steps(
-        len_ref, q_ref, *refs[4 * n_step:],
-        lambda j: (_dequant_block(k_refs[j], ks_refs[j]),
-                   _dequant_block(v_refs[j], vs_refs[j])),
-        n_step=n_step, **kw)
+        tbl_ref, len_ref, q_ref, refs[:4], *refs[4:8], refs[8:12],
+        *refs[12:],
+        lambda k, v, ks, vs: (k.astype(jnp.float32) * ks,
+                              v.astype(jnp.float32) * vs), **kw)
 
 
 def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
@@ -1064,18 +1172,25 @@ def flash_paged_decode_quant_attention(q, k_pages, v_pages, k_scale,
 
     k_pages/v_pages: int8 [n_pages, kv_heads, page_tokens, head_dim];
     k_scale/v_scale: f32 [n_pages, kv_heads, page_tokens, n_blocks].  The
-    scale pages ride the same scalar-prefetched table index maps as the
-    payload (one indirection, four streams), and the kernel dequantizes
-    on-chip inside the online-softmax loop.  Returns [batch, heads,
+    scale pages are copied from the same table entries as the payload (one
+    indirection, four streams), and the kernel dequantizes on-chip inside
+    the online-softmax loop.  It takes the scales a COLUMN, each block's
+    repeated over its head_dim / n_blocks columns: a page of them is then
+    whole lanes, as the hand copy needs (`_whole_lanes`), and dequantizing
+    is one product.  That is a pass over the scale leaves a call, and a
+    scale page four times its payload page on the way to VMEM — what a
+    v5e made of the [.., n_blocks] leaf too, whose tile is 128 lanes
+    whatever it holds (PERF.md section 6, PR 42).  Returns [batch, heads,
     head_dim] in q.dtype."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = _default_interpret()
-    return _paged_decode_call(_flash_paged_decode_quant_kernel,
-                              "paged_decode_int8", scale, q,
-                              (k_pages, v_pages, k_scale, v_scale), table,
-                              lengths, pages_per_step, interpret)
+    return _paged_decode_call(
+        _flash_paged_decode_quant_kernel, "paged_decode_int8", scale, q,
+        (k_pages, v_pages, _scale_columns(k_scale, q.shape[-1]),
+         _scale_columns(v_scale, q.shape[-1])), table, lengths,
+        pages_per_step, interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, table, lengths,
@@ -1083,8 +1198,8 @@ def paged_decode_attention(q, k_pages, v_pages, table, lengths,
                            backend: Optional[str] = None,
                            k_scale=None, v_scale=None):
     """Backend-dispatching paged decode attention (the models' paged
-    decode steps call this): the Pallas page-gathering kernel on TPU, the
-    gather + masked dot_general path elsewhere.
+    decode steps call this): the Pallas page-walking kernel on a TPU, the
+    gather + masked dot_general path elsewhere (`_use_kernel`).
     `EASYDIST_DECODE_ATTENTION` forces it — "paged"/"flash" pick the
     kernel, "xla" the gather fallback — and the value rides the same
     strategy-cache salt entry as the contiguous knob.  When
@@ -1100,30 +1215,20 @@ def paged_decode_attention(q, k_pages, v_pages, table, lengths,
         lengths = jnp.broadcast_to(lengths, (q.shape[0],))
     if backend is None:
         backend = edconfig.decode_attention_backend
-    if backend == "auto":
-        backend = "paged" if jax.default_backend() == "tpu" else "xla"
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     if k_scale is not None:
-        if backend in ("paged", "flash"):
+        if _use_kernel(backend, "paged decode"):
             return flash_paged_decode_quant_attention(
                 q, k_pages, v_pages, k_scale, v_scale, table, lengths,
                 scale=scale)
-        if backend == "xla":
-            return _paged_decode_attention_quant_xla(
-                q, k_pages, v_pages, k_scale, v_scale, table, lengths,
-                scale)
-        raise ValueError(
-            f"unknown paged decode attention backend {backend!r}; "
-            f"expected auto|paged|flash|xla")
-    if backend in ("paged", "flash"):
+        return _paged_decode_attention_quant_xla(
+            q, k_pages, v_pages, k_scale, v_scale, table, lengths, scale)
+    if _use_kernel(backend, "paged decode"):
         return flash_paged_decode_attention(q, k_pages, v_pages, table,
                                             lengths, scale=scale)
-    if backend == "xla":
-        return _paged_decode_attention_xla(q, k_pages, v_pages, table,
-                                           lengths, scale)
-    raise ValueError(f"unknown paged decode attention backend {backend!r}; "
-                     f"expected auto|paged|flash|xla")
+    return _paged_decode_attention_xla(q, k_pages, v_pages, table, lengths,
+                                       scale)
 
 
 def _chunk_attention_xla(q, k, v, q_pos, scale: float):
@@ -1188,20 +1293,15 @@ def paged_chunk_attention(q, k_pages, v_pages, table, q_pos,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if backend is None:
         backend = edconfig.prefill_attention_backend
-    if backend == "auto":
-        backend = "paged" if jax.default_backend() == "tpu" else "xla"
-    if backend in ("paged", "flash"):
+    if _use_kernel(backend, "prefill"):
         live = table[:, 0].astype(jnp.int32) < k_pages.shape[0]
         extents = jnp.where(live, q_pos[:, -1].astype(jnp.int32) + 1, 0)
         return flash_paged_chunk_attention(q, k_pages, v_pages, table,
                                            extents, scale=scale)
-    if backend == "xla":
-        h = q.shape[1]
-        return _chunk_attention_xla(
-            q, gather_pages(k_pages, table, n_heads=h),
-            gather_pages(v_pages, table, n_heads=h), q_pos, scale)
-    raise ValueError(f"unknown prefill attention backend {backend!r}; "
-                     f"expected auto|paged|flash|xla")
+    h = q.shape[1]
+    return _chunk_attention_xla(
+        q, gather_pages(k_pages, table, n_heads=h),
+        gather_pages(v_pages, table, n_heads=h), q_pos, scale)
 
 
 # ------------------------------------------------- latent attention
@@ -1213,9 +1313,11 @@ def paged_chunk_attention(q, k_pages, v_pages, table, q_pos,
 # `values` columns — so one page serves every head as K and as V at once,
 # and is read once for all of them.  Queries come already scaled.
 
-# one grid step of the latent decode kernel covers up to this many tokens
+# one window of the latent decode kernel's walk covers up to this many tokens
 # of a row: a page's row is a quarter of a GQA page's bytes (no heads, no
-# V), so a step holds four times `_PAGED_STEP_TOKENS` for the same copy
+# V), so a window holds four times `_PAGED_STEP_TOKENS` for the same copy
+# (a call alone within 3 % from 512 to 4,096 tokens, 13 % slower at 256:
+# PERF.md section 6, PR 42)
 _LATENT_STEP_TOKENS = 1024
 
 
@@ -1242,20 +1344,14 @@ def _latent_attention_xla(q, pages, table, q_pos, values: int):
     return jnp.einsum("bhqk,bkv->bhqv", p, kv[..., :values]).astype(q.dtype)
 
 
-def _flash_latent_kernel(tbl_ref, len_ref, q_ref, *refs, n_step: int,
-                         out_dim: int, **kw):
-    """The paged kernels' body over latent pages: `refs` are n_step pages
-    (each both K and, in its leading `out_dim` columns, V), the output and
-    the scratch.  The block's rows are query heads (a decode round: all of
-    them) or a block of heads x a chunk of queries."""
-    k_refs = refs[:n_step]
-
-    def load_page(j):
-        blk = k_refs[j][0]
-        return blk, blk[:, :, :out_dim]
-
-    _paged_decode_steps(len_ref, q_ref, *refs[n_step:], load_page,
-                        n_step=n_step, **kw)
+def _flash_latent_kernel(tbl_ref, len_ref, q_ref, hbm, o_ref, o_scr, m_scr,
+                         l_scr, slots, sem, first_ref, *, out_dim: int, **kw):
+    """The paged kernels' body over latent pages, each both K and, in its
+    leading `out_dim` columns, V.  The block's rows are query heads (a
+    decode round: all of them) or a block of heads x a chunk of queries."""
+    _paged_decode_steps(tbl_ref, len_ref, q_ref, (hbm,), o_ref, o_scr, m_scr,
+                        l_scr, (slots,), sem, first_ref,
+                        lambda blk: (blk, blk[:, :, :out_dim]), **kw)
 
 
 def flash_latent_decode_attention(q, pages, table, lengths, values: int,
@@ -1306,8 +1402,8 @@ def flash_latent_chunk_attention(q, pages, table, extents, values: int,
     the LAST `chunk` positions of its extent (`extents[r]`, int32 [rows]; 0
     = no sequence: reads nothing, gives zeros), pages AFTER the chunk's own
     write.  `flash_paged_chunk_attention`'s rule (key kp visible to query
-    qp iff kp <= qp) and grid, with a block of query HEADS where that has a
-    KV head: a block's rows are heads x chunk queries, every block reads
+    qp iff kp <= qp), grid and walk, with a block of query HEADS where that
+    has a KV head: a block's rows are heads x chunk queries, every block reads
     the row's pages — one head, shared — as far as its extent, nothing is
     expanded to keys or values a head.  Returns [rows, heads, chunk,
     values]."""
@@ -1325,15 +1421,6 @@ def flash_latent_chunk_attention(q, pages, table, extents, values: int,
     return out.reshape(b, h, c + pad, values)[:, :, :c]
 
 
-def _latent_backend(backend: str, knob: str) -> str:
-    if backend == "auto":
-        backend = "paged" if jax.default_backend() == "tpu" else "xla"
-    if backend not in ("paged", "flash", "xla"):
-        raise ValueError(f"unknown {knob} attention backend {backend!r}; "
-                         f"expected auto|paged|flash|xla")
-    return backend
-
-
 def latent_decode_attention(q, pages, table, lengths, values: int,
                             backend: Optional[str] = None):
     """Backend-dispatching latent decode attention (`models/decoder.py::
@@ -1342,11 +1429,10 @@ def latent_decode_attention(q, pages, table, lengths, values: int,
     from easydist_tpu import config as edconfig
 
     lengths = jnp.asarray(lengths, jnp.int32)
-    if _latent_backend(backend or edconfig.decode_attention_backend,
-                       "decode") == "xla":
-        return _latent_attention_xla(q[:, :, None], pages, table,
-                                     lengths[:, None] - 1, values)[:, :, 0]
-    return flash_latent_decode_attention(q, pages, table, lengths, values)
+    if _use_kernel(backend or edconfig.decode_attention_backend, "decode"):
+        return flash_latent_decode_attention(q, pages, table, lengths, values)
+    return _latent_attention_xla(q[:, :, None], pages, table,
+                                 lengths[:, None] - 1, values)[:, :, 0]
 
 
 def latent_chunk_attention(q, pages, table, q_pos, values: int,
@@ -1359,12 +1445,12 @@ def latent_chunk_attention(q, pages, table, q_pos, values: int,
     clipped page holds — nobody reads either."""
     from easydist_tpu import config as edconfig
 
-    if _latent_backend(backend or edconfig.prefill_attention_backend,
-                       "prefill") == "xla":
-        return _latent_attention_xla(q, pages, table, q_pos, values)
-    live = table[:, 0].astype(jnp.int32) < pages.shape[0]
-    extents = jnp.where(live, q_pos[:, -1].astype(jnp.int32) + 1, 0)
-    return flash_latent_chunk_attention(q, pages, table, extents, values)
+    if _use_kernel(backend or edconfig.prefill_attention_backend,
+                   "prefill"):
+        live = table[:, 0].astype(jnp.int32) < pages.shape[0]
+        extents = jnp.where(live, q_pos[:, -1].astype(jnp.int32) + 1, 0)
+        return flash_latent_chunk_attention(q, pages, table, extents, values)
+    return _latent_attention_xla(q, pages, table, q_pos, values)
 
 
 def window_attention(q, k, v, q_pos, k_pos, window: int,

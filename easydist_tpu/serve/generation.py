@@ -1390,7 +1390,15 @@ class GenerationSession:
             live = [i for i in pool.slots if only is None or i in only]
             # the positions this round attends, its own included
             attended = sum(pool.slots[idx].pos + 1 for idx in live)
+            pages = {}
             if self._paged:
+                # ... and the K/V pages under them, which the paged kernel
+                # walks in EACH full-attention layer, of the pages the
+                # pool's rows could hold
+                pages = dict(
+                    pages_walked=sum(pool.slots[idx].pos // pool.chunk + 1
+                                     for idx in live),
+                    pages_bucket=pool.n_slots * pool.max_pages)
                 program = "decode_state" if self._per_sequence else "decode"
                 args = (pool.arena, self.params, _decode_operand(pool, live))
                 result, held = self._held_or_resolved(pool, program, args)
@@ -1427,7 +1435,7 @@ class GenerationSession:
                 slot.timing["token_ns"].append(sp.t1_ns)
                 self._maybe_retire(pool, idx)
             self.metrics.record_decode_step(len(live), pool.n_slots,
-                                            sp.seconds)
+                                            sp.seconds, **pages)
             self.metrics.set_gauge("kv_tokens_live", attended)
             if len(nxt) > pool.n_slots:   # the round's expert counters
                 self.metrics.record_moe("decode", *nxt[pool.n_slots:])

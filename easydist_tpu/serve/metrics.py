@@ -137,14 +137,30 @@ class ServeMetrics:
             self.execute.observe(execute_s)
 
     def record_decode_step(self, n_active: int, n_slots: int,
-                           step_s: float) -> None:
+                           step_s: float, pages_walked: int = 0,
+                           pages_bucket: int = 0) -> None:
         """One token step across the whole slot pool: `n_active` slots
-        produced a real token, `n_slots` rows executed either way."""
+        produced a real token, `n_slots` rows executed either way.  A paged
+        round also says how many K/V pages lie under its live rows'
+        positions (`pages_walked`) of how many the pool's rows could hold
+        (`pages_bucket`: every slot's bucket) — both PER FULL-ATTENTION
+        LAYER: the paged decode kernel copies that many pages in each such
+        layer, however many the model has (Olmo Hybrid's 4 of 16,
+        K-EXAONE's one; a sliding or a state layer walks none).  Their
+        ratio is the share of a bucket's windows the kernel's loop
+        visits."""
         with self._lock:
             self._counters["tokens_generated"] = \
                 self._counters.get("tokens_generated", 0) + n_active
             self._counters["decode_steps"] = \
                 self._counters.get("decode_steps", 0) + 1
+            if pages_bucket:
+                self._counters["decode_pages_walked"] = \
+                    self._counters.get("decode_pages_walked", 0) \
+                    + pages_walked
+                self._counters["decode_pages_bucket"] = \
+                    self._counters.get("decode_pages_bucket", 0) \
+                    + pages_bucket
             self._gauges["decode_slot_occupancy"] = \
                 (n_active / n_slots) if n_slots else 0.0
             self.per_token.observe(step_s)

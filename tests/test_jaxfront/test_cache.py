@@ -57,6 +57,40 @@ def test_identical_programs_share_key():
     assert _key(f, x, x) == _key(f, x, x)
 
 
+def test_a_jaxpr_held_by_many_equations_is_printed_once():
+    """A model's layers call a Pallas kernel at one signature and their
+    equations share its jaxpr: an equation's signature prints that jaxpr
+    once an object (`_jaxpr_text`), and reads letter for letter what
+    `str(sorted(params.items()))` gives — the keys of the rule store and of
+    the strategy cache do not move."""
+    from easydist_tpu.jaxfront import interpreter
+    from easydist_tpu.jaxfront.inline import inline_calls
+    from easydist_tpu.ops.flash_attention import flash_paged_decode_attention
+
+    pages = jax.ShapeDtypeStruct((8, 2, 8, 128), jnp.float32)
+
+    def layers(q, table, lengths, *leaves):
+        for k, v in zip(leaves[::2], leaves[1::2]):
+            q = flash_paged_decode_attention(q, k, v, table, lengths,
+                                             interpret=True)
+        return jax.lax.cond(lengths[0] > 0, jnp.sin, jnp.cos, q)
+
+    closed = jax.make_jaxpr(layers)(
+        jax.ShapeDtypeStruct((2, 4, 128), jnp.float32),
+        jax.ShapeDtypeStruct((2, 4), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), *[pages] * 6)
+    eqns = inline_calls(closed).jaxpr.eqns
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+    interpreter._jaxpr_text.cache_clear()
+    for eqn in eqns:
+        sig = interpreter.eqn_signature(eqn, None)
+        assert sig.endswith("|" + str(sorted(eqn.params.items())))
+    info = interpreter._jaxpr_text.cache_info()
+    assert (info.misses, info.hits) == (1, 2)
+
+
 @pytest.mark.world_8
 def test_strategy_cache_hit_skips_discovery(cpu_devices, tmp_path,
                                             monkeypatch, caplog):

@@ -218,6 +218,50 @@ def test_decode_step_writes_in_place_on_tpu(v5e_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# pages whose minor dim is not whole 128-lane tiles: GPT-2's heads of 64, a
+# decode round and a chunk of 64 queries, and the int8 arena's scale pages
+# of 1 and 2 blocks a row (head_dim, scale blocks, chunk)
+NARROW_PAGES = [pytest.param(64, 0, 0, id="heads-of-64-decode"),
+                pytest.param(64, 0, 64, id="heads-of-64-chunk"),
+                pytest.param(128, 1, 0, id="int8-one-block"),
+                pytest.param(128, 2, 0, id="int8-two-blocks"),
+                pytest.param(64, 1, 0, id="int8-heads-of-64")]
+
+
+@pytest.mark.parametrize("d,blocks,chunk", NARROW_PAGES)
+def test_narrow_pages_compile_on_tpu(v5e_chip, d, blocks, chunk):
+    """The paged kernels copy pages by hand, and Mosaic slices an HBM ref
+    along whole 128-lane tiles only — which the cross-lowering of
+    tests/test_ops/test_tpu_lowering.py cannot see.  Narrow pages reach the
+    kernel in whole lanes (`_whole_lanes`): the v5e's compiler takes the
+    call, and what is copied round it is the leaves once, never padded
+    (heads of 64: the BlockSpec form's operand was the leaf laid out again
+    with every row padded to 128 lanes, twice these bytes)."""
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    n_pages, kvh, pt, rows, max_pages = 48, 12, 64, 8, 16
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    q = aval((rows, kvh) + ((chunk,) if chunk else ()) + (d,), jnp.bfloat16)
+    ints = (aval((rows, max_pages), jnp.int32), aval((rows,), jnp.int32))
+    pages = (aval((n_pages, kvh, pt, d),
+                  jnp.int8 if blocks else jnp.bfloat16),) * 2
+    if blocks:
+        pages += (aval((n_pages, kvh, pt, blocks), jnp.float32),) * 2
+        call = fa.flash_paged_decode_quant_attention
+    else:
+        call = fa.flash_paged_chunk_attention if chunk \
+            else fa.flash_paged_decode_attention
+    compiled = jax.jit(lambda q, *a: call(q, *a, interpret=False)).lower(
+        q, *pages, *ints).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    if not blocks:
+        leaf_bytes = n_pages * kvh * pt * d * 2
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 2 * leaf_bytes + 2 ** 20
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["exact", "int8"])
 @pytest.mark.parametrize("model", ["llama", "gpt"])
 def test_page_wire_format_is_the_stack_of_leaf_pages(model, quant):

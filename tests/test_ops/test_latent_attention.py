@@ -11,6 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from . import _walk
+
 fa = importlib.import_module("easydist_tpu.ops.flash_attention")
 
 N_PAGES, PT, WIDTH, VALUES, HEADS = 12, 8, 128, 48, 4
@@ -78,17 +80,67 @@ def test_the_chunk_kernel_is_its_fallback(dtype, chunk):
                                   np.asarray(got, np.float32))
 
 
+@pytest.mark.parametrize("dead", sorted(_walk.DEAD_ENTRIES))
+@pytest.mark.parametrize("case", sorted(_walk.CASES))
+@pytest.mark.parametrize("chunk", [0, 8], ids=["decode", "chunk"])
+def test_the_walk_reads_live_pages_only(chunk, case, dead):
+    """The paged decode kernel's cases (`_walk.CASES`) over latent pages, a
+    decode round and a chunk of 8 queries, NaN in every page no live entry
+    names: live rows equal the fallback over the clean pages, a row that
+    walks nothing gives zeros."""
+    table, lengths, live, named = _walk.table_for(case, dead,
+                                                  min_length=chunk)
+    rs = np.random.RandomState(3)
+    pages = rs.standard_normal((_walk.N_PAGES, _walk.PT, WIDTH)) \
+        .astype(np.float32)
+    q = jnp.asarray(0.3 * rs.standard_normal(
+        (len(lengths), HEADS) + ((chunk,) if chunk else ()) + (WIDTH,)),
+        jnp.float32)
+    table = jnp.asarray(table)
+    bad = jnp.asarray(_walk.poisoned(pages, named))
+    kw = dict(pages_per_step=_walk.PAGES_PER_STEP, interpret=True)
+    if chunk:
+        pos = lengths[:, None] - chunk + np.arange(chunk, dtype=np.int32)
+        want = fa._latent_attention_xla(q, jnp.asarray(pages), table,
+                                        jnp.asarray(pos), VALUES)
+        got = fa.flash_latent_chunk_attention(
+            q, bad, table, jnp.asarray(lengths), VALUES, **kw)
+    else:
+        want = fa.latent_decode_attention(
+            q, jnp.asarray(pages), table, jnp.asarray(lengths), VALUES,
+            backend="xla")
+        got = fa.flash_latent_decode_attention(
+            q, bad, table, jnp.asarray(lengths), VALUES, **kw)
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got[live], want[live], atol=1e-5, rtol=1e-5)
+    assert not got[~live].any()
+
+
 def test_the_heads_are_attended_in_blocks_that_fit_the_budget():
     """The cell's shapes: 64 heads x 256 queries of 640 are 21 MB of q
     alone, so a grid step holds a block of heads; every block reads the
     same page (one head, shared)."""
     assert fa._latent_head_block(64, 256, 640, 512, 256, jnp.bfloat16) == 2
     assert fa._latent_head_block(4, 16, 128, 48, 8, jnp.float32) == 4
-    shared = fa._paged_kv_index_map(0, 1, 8, 12, shared=True)
-    own = fa._paged_kv_index_map(0, 1, 8, 12)
-    tbl, lens = np.asarray(TABLE), np.asarray([20, 5, 0, 32])
-    assert shared(0, 3, 1, tbl, lens)[1] == 0
-    assert own(0, 3, 1, tbl, lens)[1] == 3
+    # ... so the slots a block's walk copies into ([2, pages a window, heads,
+    # page_tokens, width]) hold ONE head, whatever the blocks of heads,
+    # where a GQA kernel's hold a group of KV heads
+    def slots(call, *avals):
+        return _walk.slot_shapes(jax.make_jaxpr(call)(*avals).jaxpr)
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    ints = (jax.ShapeDtypeStruct((4, 4), jnp.int32),
+            jax.ShapeDtypeStruct((4,), jnp.int32))
+    assert slots(lambda q, p, t, n: fa.flash_latent_chunk_attention(
+        q, p, t, n, VALUES, interpret=True),
+        aval(4, HEADS, 16, WIDTH), aval(N_PAGES, PT, WIDTH), *ints) \
+        == [(2, 4, 1, PT, WIDTH)]
+    assert slots(lambda q, k, v, t, n: fa.flash_paged_chunk_attention(
+        q, k, v, t, n, interpret=True),
+        aval(4, 8, 16, WIDTH), aval(N_PAGES, 4, PT, WIDTH),
+        aval(N_PAGES, 4, PT, WIDTH), *ints) == [(2, 4, 4, PT, WIDTH)] * 2
 
 
 def test_an_unknown_backend_is_refused():

@@ -16,6 +16,8 @@ from easydist_tpu import config as edconfig
 from easydist_tpu.ops import (flash_paged_chunk_attention, gather_pages,
                               paged_chunk_attention)
 
+from . import _walk
+
 fa = importlib.import_module("easydist_tpu.ops.flash_attention")
 
 HEAD_DIM, KV_HEADS = 32, 2
@@ -105,6 +107,36 @@ def test_the_kernel_is_the_gather_path_on_real_rows(name):
     np.testing.assert_array_equal(np.asarray(same, np.float32), want)
 
 
+@pytest.mark.parametrize("dead", sorted(_walk.DEAD_ENTRIES))
+@pytest.mark.parametrize("case", sorted(_walk.CASES))
+def test_the_walk_reads_live_pages_only(case, dead):
+    """The decode kernel's cases (`_walk.CASES`) for a chunk of 8 queries at
+    each row's last positions, NaN in every page no live entry names: live
+    rows equal the gather path over the clean arena, a row that walks
+    nothing gives zeros."""
+    chunk, group = 8, 4
+    table, extents, live, named = _walk.table_for(case, dead,
+                                                  min_length=chunk)
+    rs = np.random.RandomState(2)
+    k, v = (rs.standard_normal((_walk.N_PAGES, KV_HEADS, _walk.PT, HEAD_DIM))
+            .astype(np.float32) for _ in range(2))
+    q = jnp.asarray(rs.standard_normal(
+        (len(extents), group * KV_HEADS, chunk, HEAD_DIM)), jnp.float32)
+    table = jnp.asarray(table)
+    pos = extents[:, None] - chunk + np.arange(chunk, dtype=np.int32)[None]
+    want = fa._chunk_attention_xla(
+        q, gather_pages(jnp.asarray(k), table, n_heads=q.shape[1]),
+        gather_pages(jnp.asarray(v), table, n_heads=q.shape[1]),
+        jnp.asarray(pos), 1.0 / np.sqrt(HEAD_DIM))
+    got = flash_paged_chunk_attention(
+        q, jnp.asarray(_walk.poisoned(k, named)),
+        jnp.asarray(_walk.poisoned(v, named)), table, jnp.asarray(extents),
+        pages_per_step=_walk.PAGES_PER_STEP, interpret=True)
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    assert not got[~live].any()
+
+
 @pytest.mark.parametrize("chunk,starts", [(16, [16, 48]), (5, [3, 30])],
                          ids=["prefill", "verify"])
 def test_a_recycled_page_leaks_nothing_of_its_earlier_tenant(chunk, starts):
@@ -161,7 +193,8 @@ def test_sixteen_layers_trace_the_kernel_once(cell):
     """A 16-layer chunk program holds 16 `pallas_call` equations of ONE
     kernel whose `jaxpr` and grid mapping are ONE object each: the body was
     traced once, and jax lowers equal equations once a module.  The body
-    stays as small as the first builder's (20 top-level equations)."""
+    stays small (the first builder's had 20 top-level equations; the walk
+    over live windows, with its copies, has 39)."""
     rows, h, kvh, c, pt, mp, n_pages = CELLS[cell]
     bf16, layers = jnp.bfloat16, 16
     pages = jax.ShapeDtypeStruct((n_pages, kvh, pt, 128), bf16)
@@ -183,10 +216,14 @@ def test_sixteen_layers_trace_the_kernel_once(cell):
     assert len({id(e.params["jaxpr"]) for e in calls}) == 1
     assert len({id(e.params["grid_mapping"]) for e in calls}) == 1
     assert fa._paged_call.cache_info().misses == 1
-    assert len(calls[0].params["jaxpr"].eqns) <= 20
-    # no wider a step than the decode kernel's at the same pages
-    n_step = (len(calls[0].invars) - 3) // 2
-    assert n_step <= fa._paged_step_shape(mp, (pages, pages))[1]
+    assert len(calls[0].params["jaxpr"].eqns) <= 40
+    # the arena leaves are passed ONCE each, after table, extents and q ...
+    assert len(calls[0].invars) == 5
+    # ... and no wider a window than the decode kernel's at the same pages
+    slots = [v.aval.shape for v in calls[0].params["jaxpr"].invars
+             if len(v.aval.shape) == 5]
+    assert len(slots) == 2 and slots[0] == slots[1] and slots[0][0] == 2
+    assert slots[0][1] <= fa._paged_step_shape(mp, (pages, pages))[1]
 
 
 def test_the_decode_kernel_is_built_once_a_signature_too():

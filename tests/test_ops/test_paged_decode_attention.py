@@ -14,6 +14,8 @@ from easydist_tpu.ops.flash_attention import (
     flash_paged_decode_attention, flash_paged_decode_quant_attention,
     gather_pages, kv_quantize, paged_decode_attention)
 
+from . import _walk
+
 PT = 8          # page_tokens
 MP = 4          # max_pages per row -> virtual cache length 32
 NP = 16         # arena pages
@@ -138,6 +140,34 @@ class TestFlashPagedKernelInterpret:
                                            scale=0.25, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
+
+    # (head_dim, positions to a 128-lane row, the page's form): the kernel
+    # copies whole 128-lane rows, so narrow pages are packed or padded
+    @pytest.mark.parametrize("d,parts,form", [
+        (16, 8, (PT // 8, 128)), (64, 2, (PT // 2, 128)),
+        (24, 1, (PT, 128)), (128, 1, (PT, 128)), (256, 1, (PT, 256))])
+    @pytest.mark.parametrize("kind", ["exact", "int8"])
+    def test_every_width_reaches_the_kernel_in_whole_lanes(self, kind, d,
+                                                           parts, form):
+        import importlib
+        fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+        lengths = [25, 10, 32, 1]
+        q, kp, vp, table, _, _ = _paged_setup(lengths, kvh=2, h=4, d=d)
+        L = jnp.asarray(lengths, jnp.int32)
+        assert fa._row_parts((kp, vp)) == parts
+        assert fa._whole_lanes(kp, parts).shape == kp.shape[:2] + form
+        if kind == "exact":
+            ref = _paged_decode_attention_xla(q, kp, vp, table, L, 0.25)
+            out = flash_paged_decode_attention(q, kp, vp, table, L,
+                                               scale=0.25, interpret=True)
+        else:
+            (kq, ks), (vq, vs) = kv_quantize(kp, 2), kv_quantize(vp, 2)
+            ref = _paged_decode_attention_quant_xla(q, kq, vq, ks, vs,
+                                                    table, L, 0.25)
+            out = flash_paged_decode_quant_attention(
+                q, kq, vq, ks, vs, table, L, scale=0.25, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_heads_not_multiple_of_kv_heads_raises(self):
         q, kp, vp, table, _, _ = _paged_setup([8], kvh=4, h=4)
@@ -269,6 +299,48 @@ class TestBlockedKernelInterpret:
         assert out.dtype == jnp.bfloat16
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32), atol=2e-2)
+
+
+# ---- the walk: the kernel's own loop over a row's live windows
+
+
+@pytest.mark.parametrize("dead", sorted(_walk.DEAD_ENTRIES))
+@pytest.mark.parametrize("case", sorted(_walk.CASES))
+@pytest.mark.parametrize("kind", ["exact", "int8"])
+def test_the_walk_reads_live_pages_only(kind, case, dead):
+    """Rows of length 0 and rows whose table names no page among live ones,
+    every row dead, lengths on a window's boundary and one past it, a row
+    full to the bucket; the dead entries the sentinel or any index past the
+    arena, and NaN in every page no live entry names.  Live rows equal the
+    fallback over the clean arena; a row that walks nothing gives zeros."""
+    table, lengths, live, named = _walk.table_for(case, dead)
+    rs = np.random.RandomState(1)
+    kvh, rep, d = 2, 4, 16
+    kp, vp = (rs.standard_normal((_walk.N_PAGES, kvh, _walk.PT, d))
+              .astype(np.float32) for _ in range(2))
+    q = jnp.asarray(rs.standard_normal((len(lengths), kvh * rep, d)),
+                    jnp.float32)
+    table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+    kw = dict(scale=0.25, pages_per_step=_walk.PAGES_PER_STEP,
+              interpret=True)
+    if kind == "exact":
+        want = _paged_decode_attention_xla(
+            q, jnp.asarray(kp), jnp.asarray(vp), table, lengths, 0.25)
+        got = flash_paged_decode_attention(
+            q, jnp.asarray(_walk.poisoned(kp, named)),
+            jnp.asarray(_walk.poisoned(vp, named)), table, lengths, **kw)
+    else:
+        (kq, ks), (vq, vs) = (kv_quantize(jnp.asarray(x), 2)
+                              for x in (kp, vp))
+        want = _paged_decode_attention_quant_xla(q, kq, vq, ks, vs, table,
+                                                 lengths, 0.25)
+        got = flash_paged_decode_quant_attention(
+            q, *(jnp.asarray(_walk.poisoned(np.asarray(x), named, bad))
+                 for x, bad in ((kq, 127), (vq, -127), (ks, np.nan),
+                                (vs, np.nan))), table, lengths, **kw)
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-5)
+    assert not got[~live].any()
 
 
 class TestDispatch:

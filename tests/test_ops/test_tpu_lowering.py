@@ -7,7 +7,12 @@ Shapes: the ones chip_smoke.py uses (GPT-2 small: 12 heads of 64, bf16,
 64-token pages) and a GQA shape (32 query heads over 8 kv heads of 128);
 the paged kernels also at the chat cell's own shape (32 slots, 32 windows)
 and at a wide MHA shape whose pages crowd the VMEM budget; the paged chunk
-kernel at the three serving cells' chunk programs and a verify step.
+kernel at the serving cells' chunk programs and a verify step; the decode
+round of every serving cell, and A.X-K1's two latent kernels.  The paged
+kernels copy pages by hand, which Mosaic does for whole 128-lane rows only:
+heads of 64 are handed over two positions to a row and the int8 arena's
+scales a column (Mosaic's own compile of both, for a described v5e, is in
+tests/test_kv/test_arena_inplace.py: one file alone may load libtpu).
 Also a kernel inside a program emitted for a (2, 2) mesh, whole and with
 its rows sharded, and the int8 paged kernel against its XLA reference under
 the interpreter, which no other test compares."""
@@ -19,10 +24,11 @@ import pytest
 
 from easydist_tpu.ops.flash_attention import (
     _PAGED_STEP_TOKENS, _PAGED_VMEM_BUDGET, _paged_decode_attention_quant_xla,
-    _paged_step_bytes, _paged_step_shape, _vmem_block_bytes, flash_attention,
-    flash_decode_attention, flash_paged_chunk_attention,
-    flash_paged_decode_attention, flash_paged_decode_quant_attention,
-    kv_quantize)
+    _paged_step_bytes, _paged_step_shape, _vmem_block_bytes,
+    flash_attention, flash_decode_attention,
+    flash_latent_chunk_attention, flash_latent_decode_attention,
+    flash_paged_chunk_attention, flash_paged_decode_attention,
+    flash_paged_decode_quant_attention, kv_quantize)
 
 BF16 = jnp.bfloat16
 SEQ, PAGE_TOKENS, N_PAGES = 1024, 64, 48
@@ -78,6 +84,44 @@ def test_paged_decode_lowers(b, h, kvh, d, max_pages):
         _aval((b, max_pages), jnp.int32), _aval((b,), jnp.int32))
 
 
+# every serving cell's decode round: (slots, heads, kv_heads, page_tokens,
+# max_pages, arena pages), heads of 128
+CELL_DECODE_SHAPES = [
+    pytest.param(32, 32, 8, 64, 32, 576, id="mistral-cell"),
+    pytest.param(64, 32, 8, 256, 16, 1024, id="granite-cell"),
+    pytest.param(64, 64, 8, 256, 32, 2048, id="kexaone-cell"),
+    pytest.param(40, 30, 30, 256, 16, 288, id="olmo-cell"),
+]
+
+
+@pytest.mark.parametrize("b,h,kvh,pt,max_pages,n_pages", CELL_DECODE_SHAPES)
+def test_paged_decode_lowers_at_the_cells(b, h, kvh, pt, max_pages, n_pages):
+    pages = _aval((n_pages, kvh, pt, 128), BF16)
+    text = _lower_for_tpu(
+        lambda q, k, v, t, n: flash_paged_decode_attention(
+            q, k, v, t, n, interpret=False),
+        _aval((b, h, 128), BF16), pages, pages,
+        _aval((b, max_pages), jnp.int32), _aval((b,), jnp.int32)).as_text()
+    # ONE custom call, and each arena leaf handed to it once, whole
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert call.count(f"tensor<{n_pages}x{kvh}x{pt}x128xbf16>") == 2
+
+
+# A.X-K1's cell: 32 slots and 2 prefill rows, 64 heads, a latent row stored
+# 640 wide whose leading 512 columns are its values, 64 pages of 256 a
+# bucket, 2,048 arena pages
+@pytest.mark.parametrize("chunk", [0, 256], ids=["decode", "chunk"])
+def test_latent_kernels_lower_at_the_cell(chunk):
+    pages = _aval((2048, 256, 640), BF16)
+    rows = 2 if chunk else 32
+    q = _aval((rows, 64) + ((chunk,) if chunk else ()) + (640,), BF16)
+    call = flash_latent_chunk_attention if chunk \
+        else flash_latent_decode_attention
+    _lower_for_tpu(
+        lambda q, p, t, n: call(q, p, t, n, 512, interpret=False),
+        q, pages, _aval((rows, 64), jnp.int32), _aval((rows,), jnp.int32))
+
+
 # the chunk kernel: (rows, heads, kv_heads, chunk, page_tokens, max_pages),
 # the three serving cells' chunk programs and a verify step's few queries
 CHUNK_SHAPES = [
@@ -87,9 +131,12 @@ CHUNK_SHAPES = [
     pytest.param(32, 32, 8, 5, 64, 32, id="verify-k4"),
     pytest.param(8, 12, 12, 64, 64, 16, id="gpt2-small"),
 ]
+# Olmo Hybrid's chunk program: ONE row, a query row a KV head (MHA)
+OLMO_CHUNK = pytest.param(1, 30, 30, 256, 256, 16, id="olmo-cell")
 
 
-@pytest.mark.parametrize("rows,h,kvh,chunk,pt,max_pages", CHUNK_SHAPES)
+@pytest.mark.parametrize("rows,h,kvh,chunk,pt,max_pages",
+                         CHUNK_SHAPES + [OLMO_CHUNK])
 def test_paged_chunk_lowers(rows, h, kvh, chunk, pt, max_pages):
     d = 64 if h == 12 else 128
     pages = _aval((N_PAGES, kvh, pt, d), BF16)

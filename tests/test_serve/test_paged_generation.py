@@ -238,6 +238,25 @@ class TestKvMetrics:
         assert st["kv_pool"]["n_pages"] > 0
         assert st["kv_pool"]["allocs"] >= st["kv_pool"]["frees"]
 
+    def test_a_round_counts_the_pages_under_its_live_rows(self, model):
+        # what the paged decode kernel walks a layer, against the pool's
+        # bucket: a request of L prompt tokens decodes its tokens 2..n at
+        # positions L, L + 1, ..., each under pos // 8 + 1 pages of 8, and
+        # every round runs 2 slots of a 4-page bucket
+        cfg, params = model
+        n_new = 5
+        _, sess = _run(params, cfg, "paged", MIXED, n_new=n_new)
+        counters = sess.metrics.snapshot()["counters"]
+        assert counters["decode_pages_walked"] == sum(
+            (len(p) + r) // 8 + 1 for p in MIXED for r in range(n_new - 1))
+        assert counters["decode_pages_bucket"] == \
+            counters["decode_steps"] * 2 * 4
+        assert counters["tokens_generated"] == len(MIXED) * (n_new - 1)
+        # the contiguous layout has no pages to walk
+        _, sess = _run(params, cfg, "bucketed", MIXED, n_new=n_new)
+        assert "decode_pages_bucket" not in \
+            sess.metrics.snapshot()["counters"]
+
     def test_gauge_tracks_pool_occupancy(self, model):
         # 12 prompt + 4 new = 16 tokens: exactly 2 pages reserved at
         # admission (the peak); the final decode round retires the slot,
