@@ -12,7 +12,9 @@ against a reference computed beside it.  Phases, in order:
            (`interpret=False`), against the float32 XLA paths in that file;
            the paged ones at GPT-2 small's heads and at a GQA shape
   probe    the five paged kernels timed ALONE, each at its benchmark cell's
-           shapes and live share (microseconds a call: notes, not claims)
+           shapes and live share (microseconds a call: notes, not claims);
+           the expert FFN's fused second product and sum beside the three
+           ops it replaced, at the three expert cells' shapes
   trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
            state threaded and donated; loss trajectory against a plain
            `jax.jit` of the einsum-attention step
@@ -358,6 +360,101 @@ def phase_paged_probe(cases=None, iters=200, interpret=False):
         out[name] = round((time.perf_counter() - t0) / iters * 1e6, 2)
         del pages, args
     log("paged kernels alone, us a call: "
+        + ", ".join(f"{k} {v}" for k, v in out.items()))
+    return out
+
+
+EXPERT_CASES = {   # (dim, an expert's width, experts, held, top_k, rows)
+    "granite.chunk": (4096, 768, 72, 36, 10, 1024),
+    "granite.round": (4096, 768, 72, 36, 10, 64),
+    "kexaone.chunk": (6144, 2048, 128, 16, 8, 512),
+    "kexaone.round": (6144, 2048, 128, 16, 8, 64),
+    "axk1.chunk": (7168, 2048, 192, 12, 8, 512),
+    "axk1.round": (7168, 2048, 192, 12, 8, 32),
+}
+
+
+def phase_expert_probe(cases=None, iters=200, interpret=False):
+    """Microseconds a call of the expert FFN's way back to tokens ALONE, at
+    each expert cell's chunk and round shape with routing drawn at the
+    cell's held share: `sum` is `grouped_matmul_sum` (the second grouped
+    product with the gate-weighted sum inside it: what `expert_ffn` runs),
+    `gather` the three ops it replaced — the second product written out,
+    `out[dest]` over all k x rows pair slots, the k-slice combine — kept
+    here as the reference the fused call is checked against.  `iters`
+    calls in one `fori_loop` (each call's live blocks hang on the call
+    before it), timed once after a warm-up run."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    gm = importlib.import_module("easydist_tpu.ops.grouped_matmul")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    pallas = dict(backend="pallas", interpret=interpret)
+
+    def gather(act, w2, g, gate, mine):
+        rows = gate.shape[1]
+        out = gm.grouped_matmul(act, w2, g.block_expert, g.live_blocks,
+                                g.block_rows, **pallas)
+        pairs = jnp.take(out, g.dest, axis=0, mode="clip")
+        return sum(jnp.where(mine[j, :, None],
+                             pairs[j * rows:(j + 1) * rows].astype(f32)
+                             * gate[j, :, None], 0.0)
+                   for j in range(gate.shape[0])).astype(bf)
+
+    def fused(act, w2, g, gate, mine):
+        k, rows = gate.shape
+        token_at = jnp.where(g.source < k * rows, g.source % rows, rows)
+        gate_at = jnp.take(gate.reshape(-1), g.source, mode="fill",
+                           fill_value=0.0)
+        return gm.grouped_matmul_sum(act, w2, g, token_at, gate_at, rows,
+                                     **pallas)
+
+    out = {}
+    for name in cases or EXPERT_CASES:
+        dim, width, experts, held, k, rows = EXPERT_CASES[name]
+        rs = np.random.RandomState(len(name))
+        idx = np.argsort(rs.rand(rows, experts), axis=1)[:, :k].T
+        mine = jnp.asarray(idx < held)
+        tm = 128 if rows * k >= 64 * held else 32    # `expert_ffn`'s rule
+        g = gm.group_rows(jnp.asarray(
+            np.where(idx < held, idx, held).reshape(-1), jnp.int32), held, tm)
+        key = jax.random.PRNGKey(len(name))
+        act = jax.random.normal(key, (g.source.shape[0], width), bf)
+        w2 = jax.jit(lambda k_: jax.random.normal(k_, (held, width, dim), bf)
+                     / width ** 0.5)(jax.random.fold_in(key, 1))
+        gate = jnp.asarray(rs.rand(k, rows), f32)
+        results = {}
+        for tag, fn in (("gather", gather), ("sum", fused)):
+            @jax.jit
+            def once(act, w2, g, gate, mine, live):
+                # a jit's argument is traced: the block's rows are a shape
+                return fn(act, w2, g._replace(live_blocks=live,
+                                              block_rows=tm), gate, mine)
+
+            @jax.jit
+            def many(act, w2, g, gate, mine):
+                def one(_, carry):
+                    live, acc = carry
+                    first = once(act, w2, g, gate, mine,
+                                 live)[0, 0].astype(f32)
+                    return (live + jnp.isnan(first).astype(jnp.int32),
+                            acc + first)
+                return jax.lax.fori_loop(0, iters, one,
+                                         (g.live_blocks, f32(0)))
+
+            args = (act, w2, g, gate, mine)
+            jax.block_until_ready(many(*args))
+            t0 = time.perf_counter()
+            jax.block_until_ready(many(*args))
+            out[f"{name}.{tag}"] = round(
+                (time.perf_counter() - t0) / iters * 1e6, 1)
+            results[tag] = once(*args, g.live_blocks)
+        _close(f"expert probe {name}", results["sum"], results["gather"])
+        out[f"{name}.live_pct"] = round(
+            100.0 * int(jnp.sum(g.sizes)) / (rows * k), 1)
+        del act, w2, args, results
+    log("expert FFN's way back to tokens alone, us a call: "
         + ", ".join(f"{k} {v}" for k, v in out.items()))
     return out
 
@@ -775,6 +872,7 @@ def main() -> int:
     notes = {"clock": phase_clock(peak)}
     notes["kernels"] = phase_kernels()
     notes["paged_probe_us"] = phase_paged_probe()
+    notes["expert_probe_us"] = phase_expert_probe()
     notes["trainer"] = phase_trainer()
     params = gpt_init(GPTConfig(**GPT2_SMALL), jax.random.PRNGKey(0))
     for layout in ("bucketed", "paged", "paged_int8"):

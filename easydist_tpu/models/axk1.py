@@ -252,6 +252,7 @@ def decoder(cfg: AxK1Config) -> Decoder:
         blocks=lambda params: params["blocks"],
         embed=lambda params, tokens, pos: params["wte"][tokens].astype(dtype),
         qkv=qkv, attn_out=attn_out, ffn=ffn, counts=True,
+        pair_slots=cfg.top_k * (cfg.layers - cfg.dense_layers),
         final_norm=lambda params, x: _rmsnorm(x, params["norm_f"], cfg.eps),
         unembed=lambda params, x: x.astype(jnp.float32) @ params["head"].T,
         latent=cfg.kv_rank)

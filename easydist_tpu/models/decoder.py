@@ -88,6 +88,9 @@ class Decoder:
     # None), and the loop sums the counters over the layers that gave some
     # and leaves them on the step's adapter (`.counters`)
     counts: bool = False
+    # the (token, choice) slots a token offers such a model's expert layers,
+    # top_k x expert layers: what `moe_pair_slots` counts a row of a program
+    pair_slots: int = 0
 
     @property
     def ring_windows(self) -> Tuple[int, ...]:

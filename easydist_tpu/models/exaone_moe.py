@@ -193,6 +193,7 @@ def decoder(cfg: ExaoneMoeConfig) -> Decoder:
                                zip(params["blocks"], sliding)],
         embed=lambda params, tokens, pos: params["wte"][tokens].astype(dtype),
         qkv=qkv, attn_out=attn_out, ffn=ffn, counts=True,
+        pair_slots=cfg.top_k * cfg.mlp_layer_types.count("sparse"),
         final_norm=lambda params, x: _rmsnorm(x, params["norm_f"], cfg.eps),
         unembed=lambda params, x: x.astype(jnp.float32) @ params["head"].T,
         windows=tuple(cfg.sliding_window if s else None for s in sliding))

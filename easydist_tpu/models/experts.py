@@ -58,19 +58,19 @@ def expert_ffn(u, idx, gate, w1, w2, experts_held: Tuple[int, int], dtype,
     hit, the busiest held expert's pairs).  Rows that are not valid are
     routed nowhere.
 
-    The routed (token, choice) pairs are laid out SLOT-major: flat pair
-    `j * rows + r` is token r's j-th choice, so a choice's pairs are `rows`
-    consecutive rows of the gathered products and the combine is `k`
-    static row slices summed in float32.  Token-major, the combine needs a
-    `[rows, k, dim]` view, and a `k` of 10 in a second-minor dimension
-    pads to the (8, 128) tile's 16: a physical float32 copy of 1.6x the
-    pairs, written and read back, every layer."""
-    from easydist_tpu.ops.grouped_matmul import group_rows, grouped_matmul
+    The routed (token, choice) pairs are numbered SLOT-major: flat pair
+    `j * rows + r` is token r's j-th choice, so `source % rows` is the
+    token of the pair at a place of the blocked layout.  The sum back to
+    tokens follows the PLACES, which hold the pairs of held experts and
+    nothing else — not the k x rows pair slots, most of them for experts
+    held elsewhere — and happens inside the second product
+    (`grouped_matmul_sum`): a pair's row never reaches HBM."""
+    from easydist_tpu.ops.grouped_matmul import (group_rows, grouped_matmul,
+                                                 grouped_matmul_sum)
 
     dtype = jnp.dtype(dtype)
     rows, k = idx.shape
     first, held = experts_held
-    gate = gate.T                                             # [k, rows]
     local = idx.astype(jnp.int32).T - first
     mine = (local >= 0) & (local < held)
     if valid is not None:
@@ -81,21 +81,18 @@ def expert_ffn(u, idx, gate, w1, w2, experts_held: Tuple[int, int], dtype,
     # (float32, the tests' type, tiles in 8s)
     tm = 8 if dtype.itemsize == 4 else 128 if rows * k >= 64 * held else 32
     g = group_rows(expert, held, tm)
-    # a place that holds no pair reads `k * rows`, so row 0: any real row does
+    # a place that holds no pair reads `k * rows`: row 0 (any real row
+    # does), a token that is no row's and a gate of 0
+    token_at = jnp.where(g.source < k * rows, g.source % rows, rows)
+    gate_at = jnp.take(gate.astype(jnp.float32).T.reshape(k * rows),
+                       g.source, mode="fill", fill_value=0.0)
     xb = jnp.take(u, g.source % rows, axis=0, mode="clip")
     hid = grouped_matmul(xb, w1.astype(dtype), g.block_expert,
                          g.live_blocks, tm)
     half = hid.shape[-1] // 2
     act = jax.nn.silu(hid[:, :half]) * hid[:, half:]
-    out = grouped_matmul(act, w2.astype(dtype), g.block_expert,
-                         g.live_blocks, tm)
-    pairs = jnp.take(out, g.dest, axis=0, mode="clip")        # [k * rows, dim]
-    # the cast is per slice, inside the sum: on the whole array it is a
-    # pass of its own.  A pair that is not `mine` was gathered from a block
-    # nothing wrote: `where` on the product, so that a NaN there stays out
-    total = sum(jnp.where(mine[j, :, None],
-                          pairs[j * rows:(j + 1) * rows].astype(jnp.float32)
-                          * gate[j, :, None], 0.0) for j in range(k))
+    total = grouped_matmul_sum(act, w2.astype(dtype), g, token_at, gate_at,
+                               rows)
     counters = jnp.stack([jnp.sum(g.sizes), jnp.sum(g.sizes > 0),
                           jnp.max(g.sizes)]).astype(jnp.int32)
-    return total.astype(dtype), counters
+    return total, counters

@@ -256,7 +256,7 @@ def decoder(cfg: GraniteHybridConfig) -> Decoder:
             params["wte"][tokens] * cfg.embedding_multiplier).astype(dtype),
         qkv=qkv,
         attn_out=lambda blk, x, att: x + res * (att @ blk["wo"].astype(dtype)),
-        ffn=ffn, counts=True,
+        ffn=ffn, counts=True, pair_slots=cfg.top_k * len(cfg.layer_types),
         final_norm=lambda params, x: _rmsnorm(x, params["norm_f"], cfg.eps),
         unembed=lambda params, x: (x.astype(jnp.float32) @ params["wte"].T)
         / cfg.logits_scaling,

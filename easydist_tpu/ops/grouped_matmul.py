@@ -17,18 +17,28 @@ batched einsum.
 
 A row is whatever the caller numbers: `group_rows` sees a flat vector of
 experts and hands back places (`dest`) and the rows at them (`source`) in
-the caller's numbering.  `models/granite_hybrid.py::expert_ffn` numbers its
+the caller's numbering.  `models/experts.py::expert_ffn` numbers its
 (token, choice) pairs SLOT-major, `choice * tokens + token`, so `source %
-tokens` is the token to fetch and the products gathered back by `dest` are
-`top_k` runs of `tokens` rows, summed slice by slice.  Token-major
-(`token * top_k + choice`) the same sum needs a `[tokens, top_k, dim]`
-view, whose second-minor `top_k` pads to the (8, 128) tile: a physical
-copy (PR 32).
+tokens` is the token to fetch for a place — and the token the place's
+product belongs to.
+
+The way back to tokens is `grouped_matmul_sum`, the SECOND product of an
+expert FFN: the same blocks and weights, but what leaves the kernel is
+`[tokens, n]`, each live block's rows scaled by their pairs' gates and
+added to their tokens where the float32 accumulator lies, in VMEM.  The
+products themselves never exist in HBM.  A chip holds a share of a layer's
+experts, so of the `top_k x tokens` pair slots most hold nothing for it
+(half in the Granite cell, 7 of 8 in K-EXAONE's, 15 of 16 in A.X-K1's): a
+gather of the products back by `dest` and a sum over `top_k` slices of it
+(until PR 43) walked all of them, 6.35 + 1.1 ms of Granite's 47 ms chunk
+call; the sum inside the kernel walks the places, which hold the held
+experts' pairs and nothing else.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _default_interpret
 
-__all__ = ["RowGroups", "group_rows", "grouped_matmul"]
+__all__ = ["RowGroups", "group_rows", "grouped_matmul",
+           "grouped_matmul_sum"]
 
 
 class RowGroups(NamedTuple):
@@ -94,7 +105,7 @@ def _gmm_xla(x, w, block_expert, live_blocks, tm: int):
     out = jnp.einsum("btk,bkn->btn", xb, w[block_expert],
                      preferred_element_type=jnp.float32)
     out = jnp.where((jnp.arange(nb) < live_blocks)[:, None, None], out, 0)
-    return out.astype(x.dtype).reshape(nb * tm, w.shape[-1])
+    return out.reshape(nb * tm, w.shape[-1])
 
 
 def _gmm_kernel(be_ref, live_ref, x_ref, w_ref, o_ref, acc):
@@ -114,6 +125,47 @@ def _gmm_kernel(be_ref, live_ref, x_ref, w_ref, o_ref, acc):
             o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
+def _gmm_sum_kernel(be_ref, live_ref, x_ref, w_ref, token_ref, gate_ref,
+                    o_ref, acc, total):
+    """Grid (column tile, block, contraction tile): `total` [rows, tn]
+    float32 is the column tile's share of EVERY token's sum and outlives the
+    blocks; a live block's finished product is scaled by its rows' gates in
+    float32, rounded once, and added to the rows of `total` its tokens
+    name by a one-hot product (a token is in a block at most once: exact)."""
+    i, k = pl.program_id(1), pl.program_id(2)
+    last_k = k == pl.num_programs(2) - 1
+
+    @pl.when((i == 0) & (k == 0))
+    def _zero():
+        total[...] = jnp.zeros_like(total)
+
+    @pl.when(i < live_ref[0])
+    def _live():
+        @pl.when(k == 0)
+        def _init():
+            acc[...] = jnp.zeros_like(acc)
+
+        acc[...] += jnp.dot(x_ref[...], w_ref[0],
+                            preferred_element_type=jnp.float32)
+
+        @pl.when(last_k)
+        def _add():
+            # a place with no pair was multiplied from some real row, which
+            # may hold anything: 0 x NaN would reach every token
+            gate = gate_ref[0]
+            pairs = jnp.where(gate != 0, acc[...] * gate, 0).astype(
+                o_ref.dtype)
+            token = jax.lax.broadcasted_iota(
+                jnp.int32, (total.shape[0], pairs.shape[0]), 0)
+            total[...] += jnp.dot(
+                (token == token_ref[0]).astype(o_ref.dtype), pairs,
+                preferred_element_type=jnp.float32)
+
+    @pl.when((i == pl.num_programs(1) - 1) & last_k)
+    def _write():
+        o_ref[...] = total[...].astype(o_ref.dtype)
+
+
 def _tile(n: int, want: int) -> int:
     """n where it is at most `want`, else the largest multiple of 128 that
     divides n and is at most `want` (n itself where none does)."""
@@ -123,65 +175,133 @@ def _tile(n: int, want: int) -> int:
     return fits[-1] if fits else n
 
 
+@functools.lru_cache(maxsize=64)
+def _gmm_call(m: int, kk: int, n: int, dtype: str, tm: int,
+              rows: Optional[int], interpret: bool):
+    """The pallas_call of a grouped product over x [m, kk] and w [experts,
+    kk, n] of `dtype`, built ONCE a signature and a `jax.jit`, so that a
+    model's expert layers share one kernel jaxpr and one lowering
+    (`ops/flash_attention.py::_paged_call` says what a call a layer cost).
+    `rows` None: `grouped_matmul`, grid (block, column tile, contraction
+    tile), the product [m, n].  `rows` given: `grouped_matmul_sum`, the
+    column tile OUTERMOST, so that the result's block [rows, tn] does not
+    move while the blocks go by, and two more operands a block: its rows'
+    tokens [1, tm] and gates [tm, 1]."""
+    nb = m // tm
+    itemsize = jnp.dtype(dtype).itemsize
+    tn = _tile(n, 2048)
+    # a weight block of at most ~3 MiB (it is double-buffered)
+    tk = _tile(kk, max(128, (3 * 2 ** 20) // (tn * itemsize) // 128 * 128))
+    nj, nk = n // tn, kk // tk
+    fused = rows is not None
+
+    def block(i, live):
+        return jnp.minimum(i, jnp.maximum(live[0] - 1, 0))
+
+    # a dead block re-reads what the last live step read: no copy.  That
+    # step is the last of everything (block outermost) or of its column
+    # tile (column tile outermost)
+    def x_map(i, j, k, be, live):
+        return block(i, live), jnp.where(i >= live[0], nk - 1, k)
+
+    def w_map(i, j, k, be, live):
+        dead = i >= live[0]
+        return (be[i], jnp.where(dead, nk - 1, k),
+                j if fused else jnp.where(dead, nj - 1, j))
+
+    def o_map(i, j, k, be, live):
+        return block(i, live), jnp.where(i >= live[0], nj - 1, j)
+
+    def row_map(i, j, k, be, live):
+        return block(i, live), 0, 0
+
+    def spec(shape, index_map):
+        if fused:   # the grid's ids come (column tile, block, ...)
+            return pl.BlockSpec(
+                shape, lambda j, i, *rest: index_map(i, j, *rest))
+        return pl.BlockSpec(shape, index_map)
+
+    in_specs = [spec((tm, tk), x_map), spec((1, tk, tn), w_map)]
+    scratch = [pltpu.VMEM((tm, tn), jnp.float32)]
+    if fused:
+        in_specs += [spec((1, 1, tm), row_map), spec((1, tm, 1), row_map)]
+        out_spec = spec((rows, tn), lambda i, j, k, be, live: (0, j))
+        scratch += [pltpu.VMEM((rows, tn), jnp.float32)]
+    else:
+        out_spec = spec((tm, tn), o_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nj, nb, nk) if fused else (nb, nj, nk),
+        in_specs=in_specs, out_specs=out_spec, scratch_shapes=scratch)
+    return pl.pallas_call(
+        _gmm_sum_kernel if fused else _gmm_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows if fused else m, n), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 2 ** 20),
+        interpret=interpret,
+        name="grouped_matmul_sum" if fused else "grouped_matmul",
+    )
+
+
+def _backend(backend, interpret):
+    if backend is None:
+        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if interpret is None:
+        interpret = _default_interpret()
+    return backend, bool(interpret)
+
+
+def _scalars(block_expert, live_blocks):
+    return (block_expert.astype(jnp.int32),
+            jnp.reshape(live_blocks, (1,)).astype(jnp.int32))
+
+
 def grouped_matmul(x, w, block_expert, live_blocks, block_rows: int,
                    interpret=None, backend=None):
     """x [n_blocks * block_rows, k] (the blocked layout of `group_rows`), w
     [experts, k, n] -> [n_blocks * block_rows, n] in x's dtype, float32
     accumulation.  Rows of dead blocks come back unwritten (the kernel) or
     zero (the fallback): nothing may read them."""
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-    tm = block_rows
+    backend, interpret = _backend(backend, interpret)
     if backend == "xla":
-        return _gmm_xla(x, w, block_expert, live_blocks, tm)
-    if interpret is None:
-        interpret = _default_interpret()
-    m, kk = x.shape
-    _, _, n = w.shape
-    nb = m // tm
-    itemsize = jnp.dtype(w.dtype).itemsize
-    tn = _tile(n, 2048)
-    # a weight block of at most ~3 MiB (it is double-buffered)
-    tk = _tile(kk, max(128, (3 * 2 ** 20) // (tn * itemsize) // 128 * 128))
-
-    nj, nk = n // tn, kk // tk
-
-    def dead_to_last(i, live):
-        return jnp.minimum(i, jnp.maximum(live[0] - 1, 0))
-
-    def x_map(i, j, k, be, live):
-        # a dead block re-reads what the last live step read: no copy
-        dead = i >= live[0]
-        return (dead_to_last(i, live),
-                jnp.where(dead, nk - 1, k))
-
-    def w_map(i, j, k, be, live):
-        dead = i >= live[0]
-        return (be[i], jnp.where(dead, nk - 1, k),
-                jnp.where(dead, nj - 1, j))
-
-    def o_map(i, j, k, be, live):
-        dead = i >= live[0]
-        return (dead_to_last(i, live),
-                jnp.where(dead, nj - 1, j))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nb, nj, nk),
-        in_specs=[pl.BlockSpec((tm, tk), x_map),
-                  pl.BlockSpec((1, tk, tn), w_map)],
-        out_specs=pl.BlockSpec((tm, tn), o_map),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-    )
+        return _gmm_xla(x, w, block_expert, live_blocks,
+                        block_rows).astype(x.dtype)
+    call = _gmm_call(*x.shape, w.shape[-1], x.dtype.name, block_rows, None,
+                     interpret)
     with jax.named_scope("grouped_matmul"):
-        return pl.pallas_call(
-            _gmm_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=48 * 2 ** 20),
-            interpret=interpret,
-            name="grouped_matmul",
-        )(block_expert.astype(jnp.int32),
-          jnp.reshape(live_blocks, (1,)).astype(jnp.int32), x, w)
+        return call(*_scalars(block_expert, live_blocks), x, w)
+
+
+def grouped_matmul_sum(x, w, groups: RowGroups, token_at, gate_at, rows: int,
+                       interpret=None, backend=None):
+    """The second product of an expert FFN and the weighted sum back to
+    tokens, in one: x, w as `grouped_matmul`'s, `token_at` int32 and
+    `gate_at` float32 [n_blocks * block_rows] — the token (0..rows-1) whose
+    pair sits at each place of the blocked layout and the pair's gate;
+    `rows` and 0 where the place holds no pair -> [rows, n] in x's dtype:
+
+        out[t] = sum over places p with token_at[p] == t of
+                 round(gate_at[p] * (x[p] @ w[block_expert[p // tm]]))
+
+    A pair's product is accumulated in float32, scaled by its gate in
+    float32 and rounded ONCE to x's dtype (what writing it to HBM did); a
+    token's pairs are summed in float32.  A token may sit in a block at
+    most once (a block is one expert's, and a token chooses an expert
+    once).  The products never reach HBM: nothing a dead block left
+    unwritten can be read, and a token with no pair here gets zeros."""
+    backend, interpret = _backend(backend, interpret)
+    tm = groups.block_rows
+    if backend == "xla":
+        pairs = _gmm_xla(x, w, groups.block_expert, groups.live_blocks, tm)
+        pairs = (pairs * gate_at[:, None]).astype(x.dtype)
+        return jnp.zeros((rows, w.shape[-1]), jnp.float32).at[token_at].add(
+            pairs.astype(jnp.float32), mode="drop").astype(x.dtype)
+    call = _gmm_call(*x.shape, w.shape[-1], x.dtype.name, tm, rows,
+                     interpret)
+    nb = x.shape[0] // tm
+    with jax.named_scope("grouped_matmul_sum"):
+        return call(*_scalars(groups.block_expert, groups.live_blocks), x, w,
+                    token_at.astype(jnp.int32).reshape(nb, 1, tm),
+                    gate_at.astype(jnp.float32).reshape(nb, tm, 1))

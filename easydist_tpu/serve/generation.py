@@ -1242,7 +1242,9 @@ class GenerationSession:
                 delta_positions=sum(n for _, n in real)
                 * len(pool.arena.get("delta", ())))
             if len(first) > pool.n_rows:   # the call's expert counters
-                self.metrics.record_moe("prefill", *first[pool.n_rows:])
+                self.metrics.record_moe(
+                    "prefill", *first[pool.n_rows:],
+                    pair_slots=pool.n_rows * c_len * self._model.pair_slots)
             calls += 1
             self._advance_jobs(pool, first, self._finish_prefill_paged)
         return calls
@@ -1438,7 +1440,9 @@ class GenerationSession:
                                             sp.seconds, **pages)
             self.metrics.set_gauge("kv_tokens_live", attended)
             if len(nxt) > pool.n_slots:   # the round's expert counters
-                self.metrics.record_moe("decode", *nxt[pool.n_slots:])
+                self.metrics.record_moe(
+                    "decode", *nxt[pool.n_slots:],
+                    pair_slots=pool.n_slots * self._model.pair_slots)
             if self._paged:
                 self._record_kv_pool(pool, len(live))
 

@@ -87,8 +87,9 @@ class ServeMetrics:
       gauge acceptance_rate (lifetime accepted / proposed).
     Expert-FFN counters (a model whose ffn routes; summed over layers):
       per decode round moe_rounds, moe_pairs_routed, moe_experts_hit,
-      moe_max_expert_pairs; per chunk call the same under moe_prefill_*
-      (moe_prefill_calls).  Gauges state_slots_in_use / state_slots: the
+      moe_max_expert_pairs, moe_pair_slots (the (token, choice) slots
+      offered: rows x top_k x expert layers); per chunk call the same
+      under moe_prefill_* (moe_prefill_calls).  Gauges state_slots_in_use / state_slots: the
       recurrent-state pool beside kv_pages_in_use.
     Gauges: decode_slot_occupancy (active slots / total slots at the last
       decode step), prefill_padding_ratio (executed token slots per real
@@ -318,20 +319,24 @@ class ServeMetrics:
                 self._counters.get("delta_rows_updated", 0) + rows_updated
 
     def record_moe(self, step: str, pairs_routed: int, experts_hit: int,
-                   max_expert_pairs: int) -> None:
+                   max_expert_pairs: int, pair_slots: int = 0) -> None:
         """One program's expert routing, summed over the layers on the
         device and read back with the program's tokens: (token, expert)
         pairs routed to held experts, held experts that got at least one,
-        and the busiest held expert's pairs.  `step` is "decode" (a
-        round: `moe_rounds`, `moe_pairs_routed`, ...) or "prefill" (a
-        chunk call: `moe_prefill_calls`, `moe_prefill_pairs_routed`,
-        ...)."""
+        and the busiest held expert's pairs; and, known on the host, the
+        (token, choice) slots the program offered (`pair_slots`: its rows x
+        top_k x expert layers), so that pairs_routed / pair_slots is the
+        share of them that were for an expert held here.  `step` is
+        "decode" (a round: `moe_rounds`, `moe_pairs_routed`, ...) or
+        "prefill" (a chunk call: `moe_prefill_calls`,
+        `moe_prefill_pairs_routed`, ...)."""
         pre = "moe_" if step == "decode" else f"moe_{step}_"
         calls = "moe_rounds" if step == "decode" else f"moe_{step}_calls"
         with self._lock:
             for name, n in ((pre + "pairs_routed", pairs_routed),
                             (pre + "experts_hit", experts_hit),
                             (pre + "max_expert_pairs", max_expert_pairs),
+                            (pre + "pair_slots", pair_slots),
                             (calls, 1)):
                 self._counters[name] = self._counters.get(name, 0) + int(n)
 

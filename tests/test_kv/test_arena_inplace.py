@@ -21,7 +21,9 @@ chip:
     kernels read, and copied whole;
   * the Granite 4.0-H cell's expert FFN compiled for that v5e: the routed
     pairs' products are combined with no float32 copy of them (it lives in
-    this file because one worker alone may load the TPU's compiler).
+    this file because one worker alone may load the TPU's compiler), and
+    the three expert cells' at their chunk shapes: nothing as large as the
+    k x rows pair slots exists at all.
 
 This is the guard that keeps a later model file from stacking again
 (PR 28: the stacked arena cost 57 % of a decode round on the chip).
@@ -312,6 +314,18 @@ def test_page_wire_format_is_the_stack_of_leaf_pages(model, quant):
     sess.close()
 
 
+def _compile_the_grouped_products(monkeypatch):
+    """The backend here is the CPU: steer both grouped products of an
+    expert layer onto their kernels."""
+    import functools
+
+    from easydist_tpu.ops import grouped_matmul as gm
+
+    for name in ("grouped_matmul", "grouped_matmul_sum"):
+        monkeypatch.setattr(gm, name, functools.partial(
+            getattr(gm, name), backend="pallas", interpret=False))
+
+
 def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
         v5e_chip, monkeypatch):
     """The Granite 4.0-H cell's decode round (BENCHMARK.json: published
@@ -334,8 +348,7 @@ def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
     monkeypatch.setattr(ssm, "ssm_decode_update", functools.partial(
         ssm.ssm_decode_update, backend="pallas", interpret=False))
-    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, backend="pallas", interpret=False))
+    _compile_the_grouped_products(monkeypatch)
     cfg = gh.GraniteHybridConfig(vocab=50176,
                                  layer_types=("mamba", "attention"),
                                  experts_held=(0, 36))
@@ -392,8 +405,7 @@ def test_window_decode_step_writes_rings_and_arena_in_place_on_tpu(
     # the backend here is the CPU: steer the step onto its TPU path
     monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
-    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, backend="pallas", interpret=False))
+    _compile_the_grouped_products(monkeypatch)
     cfg = em.ExaoneMoeConfig(
         vocab=19200, layer_types=("sliding_attention", "full_attention",
                                   "sliding_attention"),
@@ -457,8 +469,7 @@ def test_latent_steps_write_the_arena_in_place_on_tpu(v5e_chip, monkeypatch,
     monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
     monkeypatch.setattr(edconfig, "prefill_attention_backend", "paged")
     monkeypatch.setattr(fa, "_default_interpret", lambda: False)
-    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, backend="pallas", interpret=False))
+    _compile_the_grouped_products(monkeypatch)
     cfg = axk1.AxK1Config(vocab=20480, layers=2, experts_held=(0, 12))
     dec = axk1.decoder(cfg)
     slots, n_pages, pt, max_pages = 32, 2048, 256, 64
@@ -523,20 +534,21 @@ def _entry_results(hlo_text):
 def test_expert_combine_makes_no_float32_copy_of_the_pairs_on_tpu(
         v5e_chip, monkeypatch, rows):
     """`expert_ffn` at the Granite cell's widths (36 held experts, top-10,
-    bf16) for a chunk call's 4 x 256 rows and a round's 64: the gathered
-    products `[10 * rows, 4096]` are summed over the ten choices without
-    ever existing in float32 and without a pass that only moves them.
-    Token-major they were viewed `[rows, 10, 4096]`, and a second-minor 10
-    pads to the tile's 16: XLA wrote 268 MB of float32 a layer and read it
-    back (PR 32: 10 ms of a 58.6 ms chunk call); a cast written on the
-    whole slot-major view is a pass of 168 MB all the same."""
+    bf16) for a chunk call's 4 x 256 rows and a round's 64: nothing the
+    size of the `[10 * rows, 4096]` pair slots exists in float32, and no
+    pass only moves that much.  Token-major the gathered products were
+    viewed `[rows, 10, 4096]`, and a second-minor 10 pads to the tile's 16:
+    XLA wrote 268 MB of float32 a layer and read it back (PR 32: 10 ms of
+    a 58.6 ms chunk call); a cast written on the whole slot-major view was
+    a pass of 168 MB all the same.  Since PR 43 the products are summed
+    inside the second kernel (the witness below): this one guards the way
+    back."""
     import functools
 
     from easydist_tpu.models import granite_hybrid as gh
     from easydist_tpu.ops import grouped_matmul as gm
 
-    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, backend="pallas", interpret=False))
+    _compile_the_grouped_products(monkeypatch)
     cfg = gh.GraniteHybridConfig(vocab=50176, layer_types=("mamba",),
                                  experts_held=(0, 36))
 
@@ -561,6 +573,61 @@ def test_expert_combine_makes_no_float32_copy_of_the_pairs_on_tpu(
                 f"`{op}` only moves the pairs ({dt}, {n} elements)"
     if rows == 1024:    # 269 MB token-major: the float32 view
         assert compiled.memory_analysis().temp_size_in_bytes < 140e6
+
+
+EXPERT_CELLS = {   # cell -> (dim, an expert's width, held, top_k, the rows
+    #                         of a chunk call)
+    "granite": (4096, 768, 36, 10, 1024),
+    "kexaone": (6144, 2048, 16, 8, 512),
+    "axk1": (7168, 2048, 12, 8, 512),
+}
+
+
+@pytest.mark.parametrize("cell", list(EXPERT_CELLS))
+def test_expert_ffn_holds_nothing_as_wide_as_the_pair_slots_on_tpu(
+        v5e_chip, monkeypatch, cell):
+    """`models/experts.py::expert_ffn` at each expert cell's widths and its
+    chunk call's rows, compiled for the v5e: the sum back to tokens follows
+    the places of the blocked layout (the pairs of HELD experts) inside the
+    second product, so NO array of k x rows x dim elements exists in any
+    type — the parent gathered one 8-14 KB row for every (token, choice)
+    slot, half to fifteen sixteenths of them for experts held elsewhere,
+    and read it back as k slices (PR 43: 6.35 + 1.1 ms of granite's 47.3 ms
+    chunk call) — and no gather or scatter is that wide either.  (The
+    blocked layout's own arrays stay: a row a place.)"""
+    from easydist_tpu.models import experts
+
+    _compile_the_grouped_products(monkeypatch)
+    dim, width, held, k, rows = EXPERT_CELLS[cell]
+    bf16 = jnp.bfloat16
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    compiled = jax.jit(lambda u, idx, gate, w1, w2, valid: experts.expert_ffn(
+        u, idx, gate, w1, w2, (0, held), bf16, valid)).lower(
+            aval((rows, dim), bf16), aval((rows, k), jnp.int32),
+            aval((rows, k), jnp.float32), aval((held, dim, 2 * width), bf16),
+            aval((held, width, dim), bf16), aval((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # nowhere, a fusion's body included, the pair slots by the model's width
+    for shape in ((k * rows, dim), (k, rows, dim), (rows, k, dim)):
+        assert "[" + ",".join(map(str, shape)) + "]" not in text, shape
+    # and of the program's own instructions ONE result is that tall and
+    # `dim` wide: the dispatch gather's, a row a PLACE of the blocked
+    # layout (whole blocks: `ceil(k * rows / tm) + held` of 128), which the
+    # first product reads; nothing that wide is gathered or scattered after
+    entry = text[text.index("\nENTRY "):]
+    tall = [(m.group(2), dims) for m in map(
+        _ENTRY_LINE.match, entry[:entry.index("\n}")].splitlines()[2:])
+        for _, dims in _ARRAY.findall(m.group(1))
+        if dims.endswith(f",{dim}")
+        and int(dims.split(",")[0]) >= k * rows]
+    places = (-(-k * rows // 128) + held) * 128
+    assert tall == [("fusion", f"{places},{dim}")], tall
+    assert not [op for op, _ in _entry_results(text)
+                if op in ("gather", "scatter")]
 
 
 def test_delta_rule_decode_step_writes_arena_and_state_in_place_on_tpu(

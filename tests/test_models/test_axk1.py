@@ -310,6 +310,19 @@ def test_the_expert_counters_are_recorded_without_a_state(served):
     assert snap["gauges"]["latent_cache_bytes"] == 40 * 8 * 128 * 4 * 3
 
 
+def test_the_pair_slots_a_program_offered_are_counted_on_the_host(served):
+    """`moe_pair_slots` / `moe_prefill_pair_slots`: the rows of a program x
+    top_k x its expert layers — the axis the parent's combine walked (PR
+    43); `moe_pairs_routed` over it is the share for experts held HERE."""
+    c = served[1]["counters"]
+    assert DEC.pair_slots == 2 * 2        # top-2, two expert layers
+    assert c["moe_pair_slots"] == c["moe_rounds"] * 4 * DEC.pair_slots  # slots
+    assert c["moe_prefill_pair_slots"] \
+        == c["prefill_tokens_padded"] * DEC.pair_slots
+    assert 0 < c["moe_pairs_routed"] < c["moe_pair_slots"]
+    assert 0 < c["moe_prefill_pairs_routed"] < c["moe_prefill_pair_slots"]
+
+
 def test_the_trie_remaps_a_shared_prefix_and_changes_no_token(params, served):
     ids, _, _, _ = served
     sess = _session(params, enable_prefix_cache=True,

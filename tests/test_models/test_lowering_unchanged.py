@@ -1,8 +1,8 @@
 """The serving programs of the models that were there lower to the
 StableHLO they lowered to before `models/decoder.py` learned of window
-layers and the expert FFN moved to `models/experts.py` (PR 33): Granite's
-pair as the session builds it, and llama's and gpt's six each (paged as the
-session builds them, contiguous through the step functions).  The digests
+layers and the expert FFN moved to `models/experts.py` (PR 33): llama's and
+gpt's six each (paged as the session builds them, contiguous through the
+step functions; Granite's pair until PR 43, below).  The digests
 were taken at the parent commit, on the CPU, at the sizes below; they are
 of the text JAX prints, so another JAX version skips.
 
@@ -16,7 +16,12 @@ PR 40 was asked to do otherwise, once: the paged `chunk`, `decode`,
 int32 array, so their six digests were re-recorded at that PR (they say
 what those programs lower to SINCE it, and guard the next migration), while
 the `verify` and contiguous (`.c.`) entries are the parent's still: that
-PR's proof that it moved nothing else."""
+PR's proof that it moved nothing else.
+
+PR 43 meant to change Granite's pair — the expert FFN sums its pairs inside
+the second grouped product (`ops/grouped_matmul.py::grouped_matmul_sum`),
+so both programs lower to other text — and deleted its two entries; llama's
+and gpt's twelve are that PR's proof that it moved nothing else."""
 
 import hashlib
 
@@ -24,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from easydist_tpu.models import gpt, granite_hybrid, llama
+from easydist_tpu.models import gpt, llama
 from easydist_tpu.models.decoder import Contiguous, chunk, decode, verify
 from easydist_tpu.serve import GenerationSession, ServeConfig
 
@@ -33,8 +38,6 @@ AT_THE_PARENT = {
     "gpt.c.chunk": "8587ec7489438dff", "gpt.c.decode": "b3636895b74ab444",
     "gpt.c.verify": "d4ecdaf5c35b25ba", "gpt.chunk": "3920074e8733334c",
     "gpt.decode": "8698e208932bc22d", "gpt.verify": "490a620b37c845bb",
-    "granite.chunk_state": "a894c4eb131432a4",
-    "granite.decode_state": "debeadaba072264d",
     "llama.c.chunk": "f9001372d4c7c521", "llama.c.decode": "cc05385022f9eef9",
     "llama.c.verify": "16aa8c3b1cccb986", "llama.chunk": "f8eae5c937c90c66",
     "llama.decode": "50f5fd1fa0de0700", "llama.verify": "c1a419303653216b",
@@ -52,10 +55,6 @@ def _digest(fn, *args):
 
 def _model(name):
     key = jax.random.PRNGKey(0)
-    if name == "granite":
-        cfg = granite_hybrid.GraniteHybridConfig.tiny()
-        return granite_hybrid.decoder(cfg), \
-            granite_hybrid.granite_init(cfg, key)
     if name == "llama":
         cfg = llama.LlamaConfig(vocab=64, seq=32, dim=32, heads=4,
                                 kv_heads=2, layers=2, ffn_dim=64)
@@ -70,7 +69,7 @@ def lowered():
         pytest.skip(f"digests recorded with jax {RECORDED_WITH}")
     out = {}
     z4, drafts = jnp.zeros((4,), jnp.int32), jnp.zeros((4, 3), jnp.int32)
-    for name in ("granite", "llama", "gpt"):
+    for name in ("llama", "gpt"):
         dec, params = _model(name)
         sess = GenerationSession(params, model=dec, config=ServeConfig(
             kv_layout="paged", decode_buckets=(32,), max_decode_slots=4,
@@ -79,18 +78,13 @@ def lowered():
         pool = sess._pool_for(32)
         tbl4 = jnp.zeros((4, pool.max_pages), jnp.int32)
         d = sess._paged_defs
-        # the four paged step programs take ONE operand (PR 40), a row a
+        # the paged step programs take ONE operand (PR 40), a row a
         # prefill row or a slot, as the session's builders make it: the
-        # table row, two columns (three with state), a chunk's 8 tokens
-        state = name == "granite"
-        width = pool.max_pages + 2 + state
+        # table row, two columns, a chunk's 8 tokens
+        width = pool.max_pages + 2
         for key, shape in (("chunk", (2, width + 8)), ("decode", (4, width))):
-            key += "_state" if state else ""
             out[f"{name}.{key}"] = _digest(d[key], pool.arena, params,
                                            jnp.zeros(shape, jnp.int32))
-        if state:
-            sess.close()
-            continue
         out[name + ".verify"] = _digest(d["verify"], pool.arena, params,
                                         tbl4, drafts, z4)
         sess.close()

@@ -10,8 +10,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from easydist_tpu.ops import grouped_matmul as gm
 from easydist_tpu.ops.grouped_matmul import (_tile, group_rows,
-                                             grouped_matmul)
+                                             grouped_matmul,
+                                             grouped_matmul_sum)
 from easydist_tpu.ops.ssm import (_heads_per_step, ssd_chunk_scan,
                                   ssm_decode_update, ssm_decode_update_xla)
 
@@ -128,6 +130,130 @@ def test_grouped_matmul_multiplies_each_row_by_its_experts_weights(
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
+def _routed_sum(rows, tm, first=0, valid=None, choose=None, seed=3,
+                experts=8, held=4, k=3, kk=16, n=128):
+    """What `models/experts.py::expert_ffn` hands the fused product, from a
+    routing of `rows` tokens over `experts` (their top `k`, the `held` from
+    `first` on here): every PAIR has a row of its own (no token's), so a
+    pair added to another token's sum shows.  -> (the op's arguments —
+    x, w, the `RowGroups`, token_at, gate_at, rows —, the dense sum over held
+    choices)."""
+    rng = np.random.default_rng(seed)
+    idx = np.argsort(rng.random((rows, experts)), axis=1)[:, :k]
+    if choose is not None:
+        idx = choose(idx)
+    gate = rng.random((rows, k)).astype(np.float32)
+    pair_x = rng.normal(size=(k * rows, kk)).astype(np.float32)  # slot-major
+    w = rng.normal(size=(held, kk, n)).astype(np.float32)
+    local = idx.T - first
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine &= valid[None, :]
+    expert = np.where(mine, local, held).reshape(k * rows)
+    g = group_rows(jnp.asarray(expert, jnp.int32), held, tm)
+    source = np.asarray(g.source)
+    token_at = np.where(source < k * rows, source % rows, rows)
+    gate_at = np.append(gate.T.reshape(-1), 0)[source]
+    x = np.append(pair_x, np.ones((1, kk), np.float32), axis=0)[source]
+    want = np.zeros((rows, n), np.float32)
+    for p in np.nonzero(expert < held)[0]:
+        want[p % rows] += gate.T.reshape(-1)[p] * (pair_x[p] @ w[expert[p]])
+    return (jnp.asarray(x), jnp.asarray(w), g, jnp.asarray(token_at),
+            jnp.asarray(gate_at), rows), want
+
+
+BACKENDS = pytest.mark.parametrize("backend", ["xla", "pallas"])
+
+
+@BACKENDS
+@pytest.mark.parametrize("first", [0, 3], ids=["held-0-3", "held-3-6"])
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "valid"])
+@pytest.mark.parametrize("tm", [8, 32, 128])
+@pytest.mark.parametrize("rows", [1, 5, 64, 300])
+def test_the_fused_product_is_the_dense_sum_over_held_choices(
+        rows, tm, masked, first, backend):
+    valid = np.arange(rows) % 3 != 1 if masked else None
+    args, want = _routed_sum(rows, tm, first, valid, seed=rows + tm)
+    got = grouped_matmul_sum(*args, backend=backend, interpret=True)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    if masked:
+        assert not np.asarray(got)[~valid].any()
+
+
+ROUTINGS = {   # name -> (what it does to the tokens' choices, live blocks)
+    "an expert with no pair": (lambda idx: np.where(idx == 1, 7, idx), None),
+    "no pair held at all": (lambda idx: np.full_like(idx, 6), 0),
+    "an expert over several blocks": (
+        lambda idx: np.where(np.arange(idx.shape[1]) == 0, 2, idx + 4), 8),
+}
+
+
+@BACKENDS
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_fused_product_under_uneven_routing(routing, backend):
+    choose, live = ROUTINGS[routing]
+    args, want = _routed_sum(64, 8, choose=choose)
+    g = args[2]
+    if live is not None:
+        assert int(g.live_blocks) == live
+    if routing == "an expert with no pair":
+        assert int(g.sizes[1]) == 0 and int(g.live_blocks) > 3
+    got = grouped_matmul_sum(*args, backend=backend, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@BACKENDS
+def test_rows_no_pair_sits_at_cannot_reach_the_fused_sum(backend):
+    """The first product leaves the rows of dead blocks UNWRITTEN, and a
+    place of a live block that holds no pair was multiplied from whatever
+    row the clipped gather read: NaN at both (where the parent's `out` had
+    it: `test_products_nothing_wrote_cannot_reach_the_output`) must not
+    reach a token through a zero gate or a one-hot zero."""
+    (x, w, g, token_at, gate_at, rows), want = _routed_sum(21, 8)
+    empty = np.asarray(token_at) == rows
+    dead = np.arange(x.shape[0]) >= int(g.live_blocks) * 8
+    assert dead.any() and (empty & ~dead).any() and not (dead & ~empty).any()
+    got = grouped_matmul_sum(jnp.where(empty[:, None], jnp.nan, x), w, g,
+                             token_at, gate_at, rows, backend=backend,
+                             interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_a_models_expert_layers_share_one_kernel():
+    """Ten layers call each product at one signature: the program's
+    equations carry TWO kernel jaxprs, not twenty (`_gmm_call` is built once
+    a signature, as `ops/flash_attention.py::_paged_call`)."""
+    rows, tm, held, layers = 64, 32, 36, 10
+    nb = -(-rows * 10 // tm) + held
+    bf16 = jnp.bfloat16
+
+    def program(xs, w1, w2, be, live, token_at, gate_at):
+        g = gm.RowGroups(None, None, be, live, None, tm)
+        outs = []
+        for x in xs:
+            hid = grouped_matmul(x, w1, be, live, tm, backend="pallas",
+                                 interpret=False)
+            outs.append(grouped_matmul_sum(
+                hid[:, :768], w2, g, token_at, gate_at, rows,
+                backend="pallas", interpret=False))
+        return outs
+
+    gm._gmm_call.cache_clear()
+    closed = jax.make_jaxpr(program)(
+        [_aval((nb * tm, 4096), bf16)] * layers,
+        _aval((held, 4096, 1536), bf16), _aval((held, 768, 4096), bf16),
+        _aval((nb,), jnp.int32), _aval((), jnp.int32),
+        _aval((nb * tm,), jnp.int32), _aval((nb * tm,), jnp.float32))
+    calls = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] \
+        == ["grouped_matmul", "grouped_matmul_sum"] * layers
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 2
+    assert len({id(e.params["grid_mapping"]) for e in calls}) == 2
+    assert gm._gmm_call.cache_info().misses == 2
+
+
 def test_tiles_divide_and_fit():
     assert _tile(1536, 2048) == 1536 and _tile(4096, 2048) == 2048
     assert _tile(4096, 1024) == 1024 and _tile(768, 768) == 768
@@ -195,4 +321,31 @@ def test_the_grouped_matmul_lowers_for_tpu_at_the_cells_widths(rows, tm, k,
         _aval((n_blocks * tm, k), jnp.bfloat16),
         _aval((36, k, n), jnp.bfloat16), _aval((n_blocks,), jnp.int32),
         _aval((), jnp.int32)).as_text()
+    assert "tpu_custom_call" in text
+
+
+CELL_WIDTHS = {   # cell -> (dim, an expert's width, experts held, top_k,
+    #                      rows of a round, rows of a chunk call)
+    "granite": (4096, 768, 36, 10, 64, 1024),
+    "kexaone": (6144, 2048, 16, 8, 64, 512),
+    "axk1": (7168, 2048, 12, 8, 32, 512),
+}
+
+
+@pytest.mark.parametrize("program", ["round", "chunk"])
+@pytest.mark.parametrize("cell", list(CELL_WIDTHS))
+def test_the_fused_product_lowers_for_tpu_at_the_cells_widths(cell, program):
+    dim, width, held, k, round_rows, chunk_rows = CELL_WIDTHS[cell]
+    rows = round_rows if program == "round" else chunk_rows
+    tm = 128 if rows * k >= 64 * held else 32    # `expert_ffn`'s rule
+    n_blocks = -(-rows * k // tm) + held
+    text = _lower_for_tpu(
+        lambda x, w, be, live, token_at, gate_at: grouped_matmul_sum(
+            x, w, gm.RowGroups(None, None, be, live, None, tm), token_at,
+            gate_at, rows, backend="pallas", interpret=False),
+        _aval((n_blocks * tm, width), jnp.bfloat16),
+        _aval((held, width, dim), jnp.bfloat16),
+        _aval((n_blocks,), jnp.int32), _aval((), jnp.int32),
+        _aval((n_blocks * tm,), jnp.int32),
+        _aval((n_blocks * tm,), jnp.float32)).as_text()
     assert "tpu_custom_call" in text
