@@ -1301,12 +1301,12 @@ def decode_main():
         # one compiled executable (seq-512 forward), re-run per token
         fwd = jax.jit(lambda p, t: gpt_apply(p, cfg, t))
 
-        def full_forward_greedy(prompt):
+        def full_forward_greedy(prompt, n_new=max_new):
             buf = np.zeros((1, seq), np.int32)
             buf[0, :len(prompt)] = prompt
             n = len(prompt)
             ids = []
-            for _ in range(max_new):
+            for _ in range(n_new):
                 logits = fwd(params, jnp.asarray(buf))
                 nxt = int(jax.block_until_ready(
                     jnp.argmax(logits[0, n - 1])))
@@ -1359,69 +1359,44 @@ def decode_main():
             f"speedup {speedup:.1f}x, parity={parity}, "
             f"signatures {sigs_warm}->{sigs_after}")
 
-        # ---- mixed-length high-occupancy: paged vs bucketed layouts.
-        # 8 prompts spanning 24..440 tokens through 8 slots at once; the
-        # bucketed layout pads each slot to its bucket and compiles one
-        # decode step per bucket, the paged layout maps just-enough
+        # ---- mixed-length high-occupancy: 8 prompts spanning 24..440
+        # tokens through 8 slots at once; the pool maps just-enough
         # 64-token pages and serves every length from ONE compiled step.
-        # Gates: bitwise greedy parity paged == bucketed, paged decode
-        # signature count == 1, paged tokens/s >= bucketed, paged
-        # bytes/seq strictly below bucketed.
-        from easydist_tpu.serve.batcher import select_bucket
-
+        # Gates: bitwise greedy parity against the full re-forward, decode
+        # signature count == 1, a prefix restored by page mapping alone.
         m_buckets, m_chunk, m_new = (64, 128, 256, 512), 64, 16
         m_lengths = [24, 40, 90, 150, 200, 300, 400, 440]
         m_prompts = [rng.randint(0, cfg.vocab, size=L).tolist()
                      for L in m_lengths]
+        sess_p = GenerationSession.for_gpt(params, cfg, config=ServeConfig(
+            decode_buckets=m_buckets, max_decode_slots=8,
+            prefill_chunk=m_chunk, prefill_batch=4, kv_arena_pages=128))
+        # two warm waves (uncommitted->committed sharding signature,
+        # as above); they also seed the prefix trie, so the timed
+        # wave restores its prefixes by page mapping alone
+        for _ in range(2):
+            warm = [sess_p.submit(p, max_new_tokens=2) for p in m_prompts]
+            sess_p.run_until_drained()
+            [f.result(timeout=5) for f in warm]
+        t0 = time.perf_counter()
+        futs = [sess_p.submit(p, max_new_tokens=m_new) for p in m_prompts]
+        sess_p.run_until_drained()
+        tps_p = len(m_prompts) * m_new / (time.perf_counter() - t0)
+        ids_p = [f.result(timeout=5)["ids"] for f in futs]
 
-        def run_layout(layout):
-            sconf = ServeConfig(
-                decode_buckets=m_buckets, max_decode_slots=8,
-                prefill_chunk=m_chunk, prefill_batch=4,
-                kv_layout=layout,
-                kv_arena_pages=128 if layout == "paged" else 0)
-            s = GenerationSession.for_gpt(params, cfg, config=sconf)
-            # two warm waves (uncommitted->committed sharding signature,
-            # as above); they also seed the prefix trie, so the timed
-            # paged wave restores its prefixes by page mapping alone
-            for _ in range(2):
-                warm = [s.submit(p, max_new_tokens=2) for p in m_prompts]
-                s.run_until_drained()
-                [f.result(timeout=5) for f in warm]
-            t0 = time.perf_counter()
-            futs = [s.submit(p, max_new_tokens=m_new) for p in m_prompts]
-            s.run_until_drained()
-            wall = time.perf_counter() - t0
-            ids = [f.result(timeout=5)["ids"] for f in futs]
-            return s, ids, len(m_prompts) * m_new / wall
-
-        sess_b, ids_b, tps_b = run_layout("bucketed")
-        sess_p, ids_p, tps_p = run_layout("paged")
-
-        # slot bytes/seq, measured from the live pools: bucketed pins
-        # each request to a whole padded slot of its admission bucket;
-        # paged maps exactly the pages admission reserves
-        def bucketed_slot_bytes(bucket):
-            pool = sess_b._pools[bucket]
-            return sum(int(l.nbytes)
-                       for l in jax.tree_util.tree_leaves(pool.cache)) \
-                // pool.n_slots
-
-        bytes_b = sum(
-            bucketed_slot_bytes(select_bucket(len(p) + 1, m_buckets))
-            for p in m_prompts) / len(m_prompts)
+        # slot bytes/seq: exactly the pages admission reserves
         ppool = next(iter(sess_p._pools.values()))
         bytes_p = sum(
             ppool.page_bytes * ppool.pages_needed(len(p), m_new)
             for p in m_prompts) / len(m_prompts)
 
-        paged_parity = ids_p == ids_b
+        paged_parity = ids_p == [full_forward_greedy(p, m_new)
+                                 for p in m_prompts]
         paged_sigs = sess_p.stats()["decode_signatures"]["size"]
         psnap = sess_p.metrics.snapshot()
-        log(f"# decode bench (mixed): paged {tps_p:.1f} tok/s vs "
-            f"bucketed {tps_b:.1f}, bytes/seq {bytes_p:.0f} vs "
-            f"{bytes_b:.0f}, parity={paged_parity}, "
-            f"paged signatures {paged_sigs}")
+        log(f"# decode bench (mixed): {tps_p:.1f} tok/s, bytes/seq "
+            f"{bytes_p:.0f}, parity={paged_parity}, "
+            f"signatures {paged_sigs}")
 
         # MFU vs the calibrate-layer datasheet peak: ~2 FLOPs per param
         # per generated token (decode is matmul-dominated; the per-token
@@ -1449,9 +1424,7 @@ def decode_main():
             paged_parity_greedy=bool(paged_parity),
             paged_signature_constant=bool(paged_sigs == 1),
             paged_tokens_per_s=round(tps_p, 1),
-            bucketed_tokens_per_s=round(tps_b, 1),
             paged_bytes_per_seq=round(bytes_p),
-            bucketed_bytes_per_seq=round(bytes_b),
             kv_pages_in_use=psnap["gauges"].get("kv_pages_in_use"),
             kv_page_utilization=psnap["gauges"].get(
                 "kv_page_utilization"),
@@ -1462,8 +1435,7 @@ def decode_main():
             measured={"per_token_s": round(
                 float(np.percentile(lat_ms, 50)) / 1e3, 9)},
             verdict="ok" if (speedup >= 5.0 and parity and sig_constant
-                             and paged_parity and paged_sigs == 1
-                             and tps_p >= tps_b and bytes_p < bytes_b)
+                             and paged_parity and paged_sigs == 1)
             else "regression")
         sess_p.metrics.export(sub_key="decode_bench_paged")
         sess.metrics.export(sub_key="decode_bench")
@@ -1526,12 +1498,6 @@ def prefill_main():
             w = sess.submit(warm_prompt, max_new_tokens=1)
             s0 = sess.submit(prompts[0], max_new_tokens=1)
             sess.run_until_drained()
-            # second warm: a shared-prefix prompt outside the measured
-            # set, so the prefix-RESTORE program also compiles before the
-            # clock starts (first trie hit otherwise pays it mid-timing)
-            w2 = sess.submit(shared + [1, 2, 3], max_new_tokens=1)
-            sess.run_until_drained()
-            w2.result(timeout=5)
             ids = [w.result(timeout=5), s0.result(timeout=5)["ids"]][1:]
             sum0, tot0 = sess.metrics.ttft.sum, sess.metrics.ttft.total
             t0 = time.perf_counter()
@@ -1549,26 +1515,10 @@ def prefill_main():
             f"off {ttft_off*1e3:.1f}ms "
             f"(wall {wall_on:.1f}s vs {wall_off:.1f}s)")
 
-        # paged-layout pass over the same traffic: the prefix restore is
-        # a host-side page-mapping, so every follower's restored bytes
-        # land in copy_on_restore_bytes_saved instead of a staging copy
-        sconf_p = ServeConfig(decode_buckets=(seq,), max_decode_slots=4,
-                              prefill_chunk=chunk, prefill_batch=4,
-                              kv_layout="paged", kv_arena_pages=64)
-        sess_p = GenerationSession.for_gpt(params, cfg, config=sconf_p)
-        wp = sess_p.submit(warm_prompt, max_new_tokens=1)
-        s0p = sess_p.submit(prompts[0], max_new_tokens=1)
-        sess_p.run_until_drained()
-        futs_p = [sess_p.submit(p, max_new_tokens=1)
-                  for p in prompts[1:]]
-        sess_p.run_until_drained()
-        wp.result(timeout=5)
-        ids_paged = [s0p.result(timeout=5)["ids"]] + \
-            [f.result(timeout=5)["ids"] for f in futs_p]
-        paged_saved = int(sess_p.metrics.snapshot()["counters"].get(
+        # the prefix restore is a host-side page-mapping: every follower's
+        # restored bytes land in copy_on_restore_bytes_saved
+        restore_saved = int(sess_on.metrics.snapshot()["counters"].get(
             "copy_on_restore_bytes_saved", 0))
-        log(f"# prefill bench: paged copy_on_restore saved "
-            f"{paged_saved} bytes, parity={ids_paged == ids_on}")
 
         # full-re-forward reference first token for a prompt sample
         fwd = jax.jit(lambda t: gpt_apply(params, cfg, t))
@@ -1608,16 +1558,15 @@ def prefill_main():
             trie_nodes=int(trie["nodes"]),
             trie_bytes=int(trie["bytes_used"]),
             trie_evictions=int(trie["evictions"]),
-            paged_parity_greedy=bool(ids_paged == ids_on),
-            copy_on_restore_bytes_saved=paged_saved,
+            copy_on_restore_bytes_saved=restore_saved,
             device=kind, mfu=mfu,
             seq=seq, shared_prefix_len=shared_len, n_requests=n_req,
             prefill_chunk=chunk,
             measured={"ttft_s": round(ttft_on, 9),
                       "wall_s": round(wall_on, 9)},
             verdict="ok" if (speedup >= 2.0 and parity and ref_ok
-                             and sig_constant and ids_paged == ids_on
-                             and paged_saved > 0) else "regression")
+                             and sig_constant and restore_saved > 0)
+            else "regression")
         sess_on.metrics.export(sub_key="prefill_bench")
     except Exception as e:  # always land the JSON line
         import traceback
@@ -1933,7 +1882,7 @@ def fleet_chaos_main():
         def mk_q(rid):
             sq = ServeConfig(decode_buckets=(seq,), max_decode_slots=4,
                              prefill_chunk=chunk, prefill_batch=4,
-                             kv_layout="paged", kv_quant_dtype="int8")
+                             kv_quant_dtype="int8")
             return GenerationSession.for_gpt(params, cfg, config=sq,
                                              replica_id=rid)
 
@@ -2241,7 +2190,7 @@ def speculate_main():
         def mk_paged(spec_k):
             sconf = ServeConfig(decode_buckets=(32,), max_decode_slots=2,
                                 prefill_chunk=8, prefill_batch=2,
-                                kv_layout="paged", speculate_k=spec_k)
+                                speculate_k=spec_k)
             return GenerationSession.for_gpt(pg_params, pg_cfg,
                                              config=sconf)
 
@@ -2914,9 +2863,9 @@ def kv_scale_main():
     """KV memory-scaling scenario (`--kv-scale`): the quantized +
     host-tiered paged KV economics, three arms over one tiny GPT:
 
-      * exact arm — paged layout with quantization OFF must stay
-        bitwise against the bucketed session (the pre-quant contract)
-        with a scale-free {"k","v"} arena and no int8 anywhere in the
+      * exact arm — quantization OFF must stay bitwise against the
+        uncached full re-forward (the pre-quant contract) with a
+        scale-free {"k","v"} arena and no int8 anywhere in the
         compiled decode (the jaxpr-identical purity guarantee);
       * int8 arm — block-scaled int8 pages (kv_quant_dtype="int8").
         Headline value: admissible sequences per HBM byte vs the exact
@@ -2945,7 +2894,7 @@ def kv_scale_main():
         jax.config.update("jax_platforms", "cpu")
         import numpy as np
 
-        from easydist_tpu.models.gpt import (GPTConfig, gpt_init,
+        from easydist_tpu.models.gpt import (GPTConfig, gpt_apply, gpt_init,
                                              gpt_verify_step_paged,
                                              init_kv_pages)
         from easydist_tpu.resilience import faultinject
@@ -2965,7 +2914,6 @@ def kv_scale_main():
                    for i in range(n_req)]
 
         def sc(**kw):
-            kw.setdefault("kv_layout", "paged")
             kw.setdefault("max_decode_slots", 4)
             return ServeConfig(decode_buckets=(seq,), prefill_chunk=chunk,
                                prefill_batch=2, **kw)
@@ -2975,9 +2923,19 @@ def kv_scale_main():
             sess.run_until_drained()
             return [f.result(timeout=5)["ids"] for f in futs]
 
-        # bucketed exact reference: the bitwise target for the exact arm
-        want = run(GenerationSession.for_gpt(
-            params, cfg, config=sc(kv_layout="bucketed")), prompts)
+        # the uncached full re-forward on a padded buffer: the bitwise
+        # target for the exact arm
+        fwd = jax.jit(lambda p, t: gpt_apply(p, cfg, t))
+
+        def reforward(prompt):
+            buf = np.zeros((1, seq), np.int32)
+            buf[0, :len(prompt)] = prompt
+            for n in range(len(prompt), len(prompt) + max_new):
+                buf[0, n] = int(jnp.argmax(
+                    fwd(params, jnp.asarray(buf))[0, n - 1]))
+            return buf[0, len(prompt):len(prompt) + max_new].tolist()
+
+        want = [reforward(p) for p in prompts]
 
         # ---- exact arm: bitwise + scale-free purity
         exact = GenerationSession.for_gpt(params, cfg, config=sc())

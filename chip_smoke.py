@@ -2,9 +2,9 @@
 
 One process, no arguments.  Drives the two main paths once through the entry
 points a user calls — `easydist_compile` over the GPT-2 small train step, and
-`GenerationSession.for_gpt` in its three KV layouts — at the model's published
-width and depth, with random weights made from a seed, and checks every result
-against a reference computed beside it.  Phases, in order:
+`GenerationSession.for_gpt` over an exact and an int8 page arena — at the
+model's published width and depth, with random weights made from a seed, and
+checks every result against a reference computed beside it.  Phases, in order:
 
   clock    a chained bf16 matmul timed with plain `jax.block_until_ready` must
            land under the chip's datasheet peak (so the wait is real)
@@ -18,8 +18,8 @@ against a reference computed beside it.  Phases, in order:
   trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
            state threaded and donated; loss trajectory against a plain
            `jax.jit` of the einsum-attention step
-  server   `submit` / `step` / `run_until_drained`: bucketed, paged, paged
-           int8; every token teacher-forced against one full `gpt_apply`
+  server   `submit` / `step` / `run_until_drained`: paged, paged int8;
+           every token teacher-forced against one full `gpt_apply`
   arena    the paged server again through `for_llama` at the chat cell's
            widths, slots and 576-page arena but two layers: same checks, and
            the compiled decode program holds no copy of an arena leaf
@@ -72,7 +72,7 @@ KERNEL_RTOL = 2e-2
 # bf16 noise, a token read from a corrupted cache lands ~2.5 below the top.
 # First run on a v5e: deficit 0.000 with 175/175 exact on the bf16 caches,
 # 0.008 with 174/175 on int8.
-LOGIT_MARGIN = {"bucketed": 0.05, "paged": 0.05, "paged_int8": 0.1}
+LOGIT_MARGIN = {"paged": 0.05, "paged_int8": 0.1}
 MIN_EXACT_MATCH = 0.9
 
 
@@ -619,8 +619,8 @@ def _decode_temporaries(sess):
 
 def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None,
                  family="gpt", in_place=False):
-    """`GenerationSession.for_gpt` (or `.for_llama`) on one chip in one KV
-    layout; every served token teacher-forced against one full float32
+    """`GenerationSession.for_gpt` (or `.for_llama`) on one chip over one
+    kind of arena; every served token teacher-forced against one full float32
     forward of the model.  `in_place` also holds the compiled paged decode
     program to `_decode_temporaries`' bound."""
     import jax
@@ -641,10 +641,8 @@ def phase_server(layout, params, cfg_kw=None, device=None, config_kw=None,
     cfg = Config(**cfg_kw)
     device = device or jax.devices()[0]
     mesh = make_device_mesh((1,), ("d",), devices=[device])
-    layout_kw = {"bucketed": {},
-                 "paged": {"kv_layout": "paged"},
-                 "paged_int8": {"kv_layout": "paged",
-                                "kv_quant_dtype": "int8"}}[layout]
+    layout_kw = {"paged": {},
+                 "paged_int8": {"kv_quant_dtype": "int8"}}[layout]
     config = ServeConfig(decode_buckets=(cfg.seq,), **layout_kw,
                          **(config_kw or {}))
     bucket, chunk = cfg.seq, min(config.prefill_chunk, cfg.seq)
@@ -769,7 +767,7 @@ def phase_hybrid(sizes=None, device=None):
     bucket, chunk = 256, 64
     sess = GenerationSession(
         params, model=granite_hybrid.decoder(model_config(sizes)),
-        config=ServeConfig(kv_layout="paged", decode_buckets=(bucket,),
+        config=ServeConfig(decode_buckets=(bucket,),
                            max_decode_slots=8, prefill_chunk=chunk,
                            prefill_batch=2, enable_prefix_cache=False,
                            speculate_k=0),
@@ -875,7 +873,7 @@ def main() -> int:
     notes["expert_probe_us"] = phase_expert_probe()
     notes["trainer"] = phase_trainer()
     params = gpt_init(GPTConfig(**GPT2_SMALL), jax.random.PRNGKey(0))
-    for layout in ("bucketed", "paged", "paged_int8"):
+    for layout in ("paged", "paged_int8"):
         notes[f"server_{layout}"] = phase_server(layout, params)
     del params
     from easydist_tpu.models.llama import LlamaConfig, llama_init

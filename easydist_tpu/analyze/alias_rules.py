@@ -252,8 +252,8 @@ def audit_host_aliases(donated, holders,
     "snapshot", "trie", "hot_pages") to a pytree the host retains
     across the step boundary.  A holder leaf that IS (object identity)
     a donated leaf fires one aggregated finding per holder — the trie
-    must hold `_extract` copies (bucketed) or page INDICES (paged),
-    never the arena/staging arrays themselves.
+    must hold page INDICES (or copies), never the donated arrays
+    themselves.
     """
     donated_ids: Dict[int, str] = {}
     for label, tree in donated.items():

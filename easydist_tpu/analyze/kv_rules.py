@@ -56,7 +56,7 @@ def audit_page_table(pool, table, trie=None,
             pid = tnode.kv.get("page") if isinstance(tnode.kv, dict) \
                 else None
             if pid is None:
-                continue  # bucketed-style array commit; nothing to audit
+                continue  # an array commit (no page id); nothing to audit
             holders.setdefault(pid, []).append(f"trie@depth{tnode.depth}")
 
     for pid, who in sorted(holders.items()):

@@ -3,9 +3,9 @@
 A model file fills in a `Decoder` — its sizes and the arithmetic of one
 block — and gets every serving step from here: `chunk` (chunked prefill),
 `verify` (speculative scoring) and `decode` (one token), each against
-either KV layout.  The layouts are the adapters below: `Contiguous` (the
-stacked [layers, batch, kv_heads, T, head_dim] cache of the bucketed
-pools), `Paged` (`kv/arena.py`: one leaf per layer, written in place
+any cache adapter.  The adapters are below: `Contiguous` (a
+stacked [layers, batch, kv_heads, T, head_dim] cache: the draft model's,
+and the one-sequence step functions'; no session pool), `Paged` (`kv/arena.py`: one leaf per layer, written in place
 through a page table; the int8 arena lives here and nowhere else) and
 `Latent` (a paged arena of ONE row a position for all the heads, for a
 model whose attention is latent).

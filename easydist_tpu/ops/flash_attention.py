@@ -622,7 +622,7 @@ def gather_pages(pages, table, n_heads: Optional[int] = None):
     rows sit at masked positions (>= the row's length) so their softmax
     weight is exactly zero — garbage values are unobservable as long as
     they are finite, which arena zeros/stale KV always are.  `n_heads`
-    repeats kv_heads GQA-style AFTER the gather, matching the bucketed
+    repeats kv_heads GQA-style AFTER the gather, matching the contiguous
     llama path's repeat-then-attend order bitwise."""
     n_pages, kvh, pt, d = pages.shape
     b, mp = table.shape
@@ -638,10 +638,10 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, table, lengths,
                                 scale: float):
     """Gather-then-mask fallback: reconstruct the virtual contiguous cache
     through the page table, then run the exact `_decode_attention_xla`
-    einsum.  When max_pages * page_tokens equals the bucketed cache
+    einsum.  When max_pages * page_tokens equals a contiguous cache's
     length, every downstream shape (and therefore the lowered reduction
-    order) matches the bucketed path — the bitwise-parity spine of the
-    paged serving tests."""
+    order) matches that cache's path — the bitwise-parity spine of the
+    paged kernel tests."""
     h = q.shape[1]
     kf = gather_pages(k_pages, table, n_heads=h)
     vf = gather_pages(v_pages, table, n_heads=h)
@@ -1251,8 +1251,8 @@ def _chunk_attention_xla(q, k, v, q_pos, scale: float):
 
 def chunk_attention(q, k, v, q_pos, scale: Optional[float] = None,
                     backend: Optional[str] = None):
-    """Chunked-prefill attention over a CONTIGUOUS cache (the bucketed
-    layout's `*_prefill_chunk` and verify steps call this): q is a
+    """Chunked-prefill attention over a CONTIGUOUS cache (the
+    one-sequence `*_prefill_chunk` and verify steps call this): q is a
     fixed-size token chunk at absolute positions `q_pos`, k/v are the full
     bucket-length cache.  Always the masked dot_general path, whatever
     `EASYDIST_PREFILL_ATTENTION` says short of an unknown value: the

@@ -88,34 +88,37 @@ class ServeConfig:
     breaker_cooldown_ms: how long the open circuit sheds before probing.
     breaker_p99_threshold_ms / breaker_min_samples: optional brownout trip
         on observed p99 execute latency.
-    decode_buckets: allowed KV-cache max-lengths for token-level decode
-        (serve/generation.py); each bucket owns one slot pool and exactly
-        one compiled decode step.
+    decode_buckets: sequence capacities for token-level decode
+        (serve/generation.py).  The session keeps ONE page-granular pool
+        and one compiled decode step whatever the lengths, so only the
+        maximum means anything to it: the longest prompt + output a
+        request may reach.
     kv_cache_dtype: cache storage dtype ("auto" = the model's dtype);
         shape/dtype-visible in every decode signature.
-    max_decode_slots: slots per decode bucket — the fixed decode batch
+    max_decode_slots: slots of the decode pool — the fixed decode batch
         width (idle slots show up as occupancy, never as a new signature).
     prefill_chunk: token window of one chunked-prefill pass — prompts run
         in fixed [prefill_batch, prefill_chunk] chunk calls, so ONE
-        compiled prefill signature per bucket serves every prompt length;
-        also the prefix-cache chunk granularity (reuse is whole chunks).
-    prefill_batch: staging rows — how many pending prompts pack into a
-        single chunked-prefill call.
+        compiled prefill signature serves every prompt length; also the
+        KV page size and the prefix-cache chunk granularity (reuse is
+        whole chunks, each one page).
+    prefill_batch: rows of the chunk program — how many pending prompts
+        pack into a single chunked-prefill call.
     prefill_chunks_per_step: chunk calls interleaved per `step()` before
         the decode rounds run — bounds decode p99 under prefill pressure.
     enable_prefix_cache: commit/restore prefix KV chunks via the token
         trie (serve/prefix_cache.py); off = every prompt recomputes from
         position 0 (bitwise-identical outputs either way).
-    prefix_cache_bytes: LRU byte budget per decode bucket's trie; 0
-        disables committing.
-    kv_layout: decode KV storage — "bucketed" (one padded slot pool per
-        decode bucket, the PR 8-11 layout) or "paged" (ONE page-granular
-        pool over a preallocated arena: arbitrary lengths in one compiled
-        decode step, no bucket padding, zero-copy prefix restore; needs
-        the paged model callables `for_gpt`/`for_llama` wire).
-    kv_page_tokens: tokens per KV page in the paged layout; 0 = the
-        effective prefill chunk (pages ARE the prefix-trie chunks, which
-        is what makes restore a pure table mapping).
+    prefix_cache_bytes: LRU byte budget of the trie; 0 disables
+        committing.
+    kv_layout: "paged", the one layout there is (ONE page-granular pool
+        over a preallocated arena: arbitrary lengths in one compiled
+        decode step, zero-copy prefix restore).  The field has one legal
+        value and can simply be dropped; it stays only until the
+        benchmark's cell files stop passing it.
+    kv_page_tokens: tokens per KV page; 0 = the effective prefill chunk
+        (pages ARE the prefix-trie chunks, which is what makes restore a
+        pure table mapping).
     kv_arena_pages: arena size in pages; 0 = auto
         (max_decode_slots * pages-per-sequence + one sequence's worth of
         headroom for trie-held pages).
@@ -128,17 +131,17 @@ class ServeConfig:
         lookup) or "draft_model" (a second small model's cached greedy
         decode; the session must be given a drafter or draft_model).
     kv_quant_dtype: "none" (exact storage — the bitwise path) or "int8"
-        (paged arena pages stored block-scaled int8 with a parallel f32
+        (arena pages stored block-scaled int8 with a parallel f32
         scale arena; ~4x sequences per HBM byte, greedy output gated by
-        the bounded-drift A/B harness rather than bitwise).  Paged layout
-        only, and mutually exclusive with a non-auto kv_cache_dtype.
+        the bounded-drift A/B harness rather than bitwise).  Mutually
+        exclusive with a non-auto kv_cache_dtype.
     kv_quant_block: head-dim elements per quantization block (one f32
         scale each); 0 = one block per K/V row (head_dim).  Must divide
         head_dim.
     kv_host_tier_bytes: host-RAM byte budget for demoting cold unpinned
         prefix-trie pages out of the HBM arena (kv/tier.py; chunked
         fetches, sha256 manifests, promote-on-hit); 0 disables the tier.
-        Paged layout with the prefix cache enabled only.
+        With the prefix cache enabled only.
     """
     batch_buckets: Tuple[int, ...] = (1, 2, 4, 8)
     seq_buckets: Optional[Tuple[int, ...]] = None
@@ -163,7 +166,7 @@ class ServeConfig:
     prefill_chunks_per_step: int = 4
     enable_prefix_cache: bool = True
     prefix_cache_bytes: int = 64 * 2**20
-    kv_layout: str = "bucketed"
+    kv_layout: str = "paged"
     kv_page_tokens: int = 0
     kv_arena_pages: int = 0
     speculate_k: int = field(
@@ -213,9 +216,7 @@ class ServeConfig:
                              f"got {self.prefill_chunk}")
         for b in self.decode_buckets:
             # the effective chunk (min(prefill_chunk, bucket)) must tile
-            # the bucket exactly: a chunk write that would spill past the
-            # bucket gets its start CLAMPED by dynamic_update_slice,
-            # silently corrupting earlier cache rows
+            # the bucket exactly
             eff = min(self.prefill_chunk, b)
             if b % eff != 0:
                 raise ValueError(
@@ -232,9 +233,11 @@ class ServeConfig:
         if self.prefix_cache_bytes < 0:
             raise ValueError(f"prefix_cache_bytes must be >= 0 "
                              f"(0 disables), got {self.prefix_cache_bytes}")
-        if self.kv_layout not in ("bucketed", "paged"):
-            raise ValueError(f"kv_layout must be 'bucketed' or 'paged', "
-                             f"got {self.kv_layout!r}")
+        if self.kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={self.kv_layout!r}: the bucketed (contiguous-"
+                f"pool) layout was removed and 'paged' is the one layout "
+                f"left; drop the field")
         if self.kv_page_tokens < 0:
             raise ValueError(f"kv_page_tokens must be >= 0 (0 = the "
                              f"effective prefill chunk), "
@@ -242,55 +245,43 @@ class ServeConfig:
         if self.kv_arena_pages < 0:
             raise ValueError(f"kv_arena_pages must be >= 0 (0 = auto), "
                              f"got {self.kv_arena_pages}")
-        if self.kv_layout == "paged":
-            cap = max(self.decode_buckets)
-            pt = self.kv_page_tokens or min(self.prefill_chunk, cap)
-            if pt != min(self.prefill_chunk, cap):
-                # pages ARE the prefix-trie chunks: a paged prefill chunk
-                # fills exactly one page, and a restored trie node maps
-                # exactly one page — different granularities would force
-                # copy-on-restore back in
-                raise ValueError(
-                    f"kv_page_tokens {pt} must equal the effective "
-                    f"prefill chunk {min(self.prefill_chunk, cap)} in the "
-                    f"paged layout (pages are the trie chunks)")
-            if cap % pt != 0:
-                raise ValueError(
-                    f"max decode bucket {cap} is not a multiple of "
-                    f"kv_page_tokens {pt}; pages must tile the sequence "
-                    f"capacity exactly")
+        cap = max(self.decode_buckets)
+        pt = self.kv_page_tokens or min(self.prefill_chunk, cap)
+        if pt != min(self.prefill_chunk, cap):
+            # pages ARE the prefix-trie chunks: a prefill chunk fills
+            # exactly one page, and a restored trie node maps exactly one
+            # page — different granularities would force copy-on-restore
+            # back in
+            raise ValueError(
+                f"kv_page_tokens {pt} must equal the effective "
+                f"prefill chunk {min(self.prefill_chunk, cap)} "
+                f"(pages are the trie chunks)")
+        if cap % pt != 0:
+            raise ValueError(
+                f"max decode bucket {cap} is not a multiple of "
+                f"kv_page_tokens {pt}; pages must tile the sequence "
+                f"capacity exactly")
         if self.kv_quant_dtype not in ("none", "int8"):
             raise ValueError(f"kv_quant_dtype must be 'none' or 'int8', "
                              f"got {self.kv_quant_dtype!r}")
         if self.kv_quant_block < 0:
             raise ValueError(f"kv_quant_block must be >= 0 (0 = one block "
                              f"per row), got {self.kv_quant_block}")
-        if self.kv_quant_dtype != "none":
-            if self.kv_layout != "paged":
-                raise ValueError(
-                    f"kv_quant_dtype {self.kv_quant_dtype!r} requires the "
-                    f"paged layout (quantize-on-commit lives in the page "
-                    f"arena), got kv_layout={self.kv_layout!r}")
-            if self.kv_cache_dtype != "auto":
-                raise ValueError(
-                    f"kv_quant_dtype {self.kv_quant_dtype!r} is mutually "
-                    f"exclusive with a non-auto kv_cache_dtype "
-                    f"({self.kv_cache_dtype!r}): the quantized arena owns "
-                    f"its storage dtype (int8 payload + f32 scales)")
+        if self.kv_quant_dtype != "none" and self.kv_cache_dtype != "auto":
+            raise ValueError(
+                f"kv_quant_dtype {self.kv_quant_dtype!r} is mutually "
+                f"exclusive with a non-auto kv_cache_dtype "
+                f"({self.kv_cache_dtype!r}): the quantized arena owns "
+                f"its storage dtype (int8 payload + f32 scales)")
         if self.kv_host_tier_bytes < 0:
             raise ValueError(f"kv_host_tier_bytes must be >= 0 "
                              f"(0 disables), got {self.kv_host_tier_bytes}")
-        if self.kv_host_tier_bytes:
-            if self.kv_layout != "paged":
-                raise ValueError(
-                    f"kv_host_tier_bytes requires the paged layout (the "
-                    f"tier demotes arena pages), got "
-                    f"kv_layout={self.kv_layout!r}")
-            if not self.enable_prefix_cache or not self.prefix_cache_bytes:
-                raise ValueError(
-                    "kv_host_tier_bytes requires the prefix cache (the "
-                    "tier holds cold TRIE pages; with no trie there is "
-                    "nothing to demote)")
+        if self.kv_host_tier_bytes and not (self.enable_prefix_cache
+                                            and self.prefix_cache_bytes):
+            raise ValueError(
+                "kv_host_tier_bytes requires the prefix cache (the "
+                "tier holds cold TRIE pages; with no trie there is "
+                "nothing to demote)")
         if self.speculate_k < 0:
             raise ValueError(f"speculate_k must be >= 0 (0 disables "
                              f"speculation), got {self.speculate_k}")

@@ -5,49 +5,42 @@ whole forward.  For autoregressive generation that is O(T^2) attention
 flops per sequence; the KV cache makes each token O(T).  This module is
 the serving half of the cache-carrying model API (models/decoder.py: one
 `Decoder` record a model, the `chunk`/`verify`/`decode` steps, and the
-`Contiguous`, `Paged` and `Latent` cache adapters):
+`Paged`, `Latent` and `State` cache adapters):
 
+  * **one paged KV pool** — a page-granular pool over a preallocated
+    arena (kv/pool.py + kv/table.py on the host; kv/arena.py on the
+    device: a pytree of one leaf per layer, each written in place in its
+    own donated buffer).  Sequences of any length up to
+    `max(ServeConfig.decode_buckets)` share ONE compiled decode step: the
+    int32 page table, fixed [max_slots, max_pages], is the only per-step
+    state that varies.  Admission reserves every page a sequence can ever
+    touch up front, so the table row is static for the slot's life;
+    decode always steps ALL slots, slots recycle through a free list;
+    analyze rule KV001 audits the refcount/table bookkeeping at first
+    decode and every retire;
   * **chunked, batched prefill** — each admitted prompt is processed in
-    fixed [prefill_batch, prefill_chunk] windows against a multi-row
-    staging cache, so ONE compiled prefill signature per bucket serves
-    every prompt length, and up to `prefill_batch` pending prompts share
-    each chunk call;
-  * **prefix-reuse KV cache** — finished prefills commit their aligned
-    KV chunks into a per-bucket token trie (serve/prefix_cache.py);
-    admission restores the longest cached whole-chunk prefix with
-    `dynamic_update_slice` and resumes prefill at `prefix_len` instead
-    of 0.  Restored and recomputed KV are bitwise identical, so the
-    cache is a pure latency optimization (`enable_prefix_cache=False`
-    produces bitwise-identical outputs);
+    fixed [prefill_batch, prefill_chunk] windows written straight into
+    arena pages through its table row, so ONE compiled prefill signature
+    serves every prompt length, and up to `prefill_batch` pending prompts
+    share each chunk call;
+  * **prefix-reuse KV cache** — finished prefills commit their whole-chunk
+    pages into a token trie (serve/prefix_cache.py) as page REFERENCES;
+    admission maps the longest cached whole-chunk prefix into the new
+    slot's table row (zero copies) and resumes prefill at `prefix_len`
+    instead of 0.  Restored and recomputed KV are bitwise identical, so
+    the cache is a pure latency optimization
+    (`enable_prefix_cache=False` produces bitwise-identical outputs);
   * **bounded prefill pressure** — `step()` interleaves at most
-    `prefill_chunks_per_step` chunk calls before the decode rounds run,
+    `prefill_chunks_per_step` chunk calls before the decode round runs,
     so a long prompt cannot stall in-flight decodes for its whole
     prefill (decode p99 stays bounded);
-  * **bucketed KV pool + one compiled decode step** — one slot pool per
-    `ServeConfig.decode_buckets` entry, decode always steps ALL slots,
-    slots recycle through a free list;
-  * **paged KV pool** (`ServeConfig.kv_layout="paged"`) — ALL buckets
-    collapse into ONE page-granular pool over a preallocated arena
-    (kv/pool.py + kv/table.py on the host; kv/arena.py on the device: a
-    pytree of one leaf per layer, each written in place in its own
-    donated buffer): sequences of any length share one
-    compiled decode step (the int32 page table, fixed
-    [max_slots, max_pages], is the only per-step state that varies), a
-    restored prefix is table entries pointing at trie-committed pages
-    (zero copies — the bucketed path `dynamic_update_slice`-copies every
-    restored chunk), and prefill writes arena pages directly through the
-    table (no staging cache, no migrate).  Admission reserves every page
-    a sequence can ever touch up front, so the table row is static for
-    the slot's life; analyze rule KV001 audits the refcount/table
-    bookkeeping at first decode and every retire;
-  * **donated caches** — pool, staging and the paged arena (leaf by
-    leaf) are positional arg 0 and output 0 of their compiled callables,
-    so `infer_state_io` pairs and donates them; XLA updates in place
-    instead of copying.  `analyze`
-    rules SERVE001 (decode) and SERVE002 (chunked prefill: donation +
-    length-masked attention + trie accounting) audit exactly this.
+  * **donated cache** — the arena (leaf by leaf, with a model's state
+    leaves beside it) is positional arg 0 and output 0 of every compiled
+    step, so `infer_state_io` pairs and donates it; XLA updates in place
+    instead of copying.  `analyze` rule SERVE001 audits exactly this for
+    the decode and the chunk program.
 
-Sharding rides the existing solver: the cache's heads axis (dim 2) is the
+Sharding rides the existing solver: the cache's heads axis is the
 tensor-parallel shard dim, matching the attention strategy the solver
 picks for the model itself, so tp serving works unchanged —
 `kv_cache_specs` names the placement for callers that want to lay the
@@ -87,7 +80,7 @@ logger = logging.getLogger(__name__)
 # the traced-and-XLA-compiled programs instead of each replica re-paying
 # the compile.  This is the fleet case: N in-process replicas differ only
 # in the state they carry, never in the program they run.
-_COMPILED_MEMO: Dict[tuple, tuple] = {}
+_COMPILED_MEMO: Dict[tuple, dict] = {}
 
 # adaptive-speculation throttle: a verify row costs ~1.5x a decode row,
 # so drafting pays off only while the stream's recent accepted-tokens-
@@ -146,14 +139,15 @@ class _Slot:
 
 @dataclass
 class _PrefillJob:
-    """One prompt mid-prefill: owns a staging row and a reserved pool
-    slot; `start` advances one chunk per batched chunk call."""
+    """One prompt mid-prefill: owns a row of the chunk program and a
+    reserved pool slot; `start` advances one chunk per batched chunk
+    call."""
     request_id: int
     future: Future
     prompt: List[int]
     max_new: int
     eos_id: Optional[int]
-    row: int                      # staging row
+    row: int                      # chunk-program row
     slot_idx: int                 # reserved pool slot
     start: int                    # next chunk start (multiple of chunk)
     prefix_nodes: List[object]    # trie nodes restored (pinned)
@@ -180,31 +174,6 @@ def _finish_timing(timing: dict, reason: str) -> dict:
     return timing
 
 
-class _BucketPool:
-    """One decode bucket: pooled cache + free-list slot allocator +
-    multi-row staging cache shared by the chunked-prefill scheduler +
-    the bucket's prefix trie."""
-
-    def __init__(self, bucket: int, n_slots: int, init_cache,
-                 n_rows: int, chunk: int, prefix_bytes: int):
-        self.bucket = bucket
-        self.n_slots = n_slots
-        self.cache = init_cache(n_slots, bucket)
-        self.n_rows = n_rows
-        self.staging = init_cache(n_rows, bucket)
-        self.chunk = chunk
-        self.free: List[int] = list(range(n_slots))
-        self.slots: Dict[int, _Slot] = {}          # slot index -> _Slot
-        self.free_rows: List[int] = list(range(n_rows))
-        self.jobs: Dict[int, _PrefillJob] = {}     # staging row -> job
-        self.trie: Optional[PrefixCache] = \
-            PrefixCache(chunk, prefix_bytes) if prefix_bytes else None
-
-    @property
-    def n_active(self) -> int:
-        return len(self.slots)
-
-
 def _ring_bytes(arena) -> int:
     """The bytes of the window layers' rings in a pool's pytree
     (`models/decoder.py::Ring`; 0 for a model without window layers)."""
@@ -213,13 +182,13 @@ def _ring_bytes(arena) -> int:
 
 
 class _PagedPool:
-    """The paged layout's single pool: one preallocated page arena, a
+    """The session's single pool: one preallocated page arena, a
     refcounted page allocator, and a fixed [n_slots, max_pages] page
     table shared by every request regardless of length (`bucket` is the
     capacity cap — max(decode_buckets) — not a padding granularity).
-    Prefill jobs write arena pages directly through the table, so there
-    is no staging cache and no migrate; a restored prefix is table
-    entries pointing at trie-committed pages (zero-copy)."""
+    Prefill jobs write arena pages directly through the table; a
+    restored prefix is table entries pointing at trie-committed pages
+    (zero-copy)."""
 
     def __init__(self, bucket: int, n_slots: int, init_pages,
                  n_rows: int, chunk: int, prefix_bytes: int,
@@ -390,13 +359,14 @@ class _PagedPool:
             self.state.take(slot_idx)
         return slot_idx
 
-    def give_slot(self, slot_idx: int) -> List[int]:
+    def give_slot(self, slot_idx: int) -> None:
         """Retirement: the slot, its state row and its pages go back
-        together; returns the page ids the table row held."""
+        together (a page frees when no other row or trie node holds it)."""
         self.free.append(slot_idx)
         if self.state is not None:
             self.state.release(slot_idx)
-        return self.table.unmap_row(slot_idx)
+        for pid in self.table.unmap_row(slot_idx):
+            self.pool.release(pid)
 
 
 # The small operands of a paged step program cross to the chip as ONE int32
@@ -460,7 +430,7 @@ class GenerationSession:
     `model` is the model's `Decoder` record (models/decoder.py;
     `gpt.decoder(cfg)`, `llama.decoder(cfg)`): the session builds every
     program it runs from it — chunked prefill, decode and verify, against
-    the contiguous bucket cache or the page arena as `kv_layout` says.
+    the page arena.
 
     Greedy decoding (argmax inside the compiled step, so only int32 token
     ids cross the host boundary per token).  `submit` returns a Future
@@ -485,8 +455,8 @@ class GenerationSession:
                  replica_id: Optional[str] = None,
                  compile_key: Optional[object] = None):
         from easydist_tpu.jaxfront import easydist_compile
-        from easydist_tpu.models.decoder import (Contiguous, Latent, Paged,
-                                                 chunk, decode, verify)
+        from easydist_tpu.models.decoder import (Latent, Paged, chunk,
+                                                 decode, verify)
 
         self.config = config or ServeConfig()
         self.replica_id = replica_id
@@ -501,7 +471,7 @@ class GenerationSession:
         self._per_sequence = model.per_sequence
         self._refuse_unbuilt(model, self.config)
         self._model = model
-        # the paged arena's adapter: K/V rows a KV head, or one latent row
+        # the arena's adapter: K/V rows a KV head, or one latent row
         paged = self._paged_adapter = Latent if model.latent else Paged
         self.params = params
         self.mesh = mesh
@@ -509,9 +479,8 @@ class GenerationSession:
         self.metrics = metrics or ServeMetrics(replica_id=replica_id)
         self._draining = False
         self._closed = False
-        self._paged = self.config.kv_layout == "paged"
         self._pending: collections.deque = collections.deque()
-        self._pools: Dict[int, _BucketPool] = {}
+        self._pools: Dict[int, _PagedPool] = {}
         self._next_request_id = 0
         self._step_index = 0
         # emptiness, on the recorder's clock (`submit`, `step`): since when
@@ -548,64 +517,13 @@ class GenerationSession:
         self._spec_idle: Dict[int, int] = {}
         self._spec_gate_idle = 0
 
-        def _prefill_chunk(staging, params, tokens, start, lengths):
-            import jax.numpy as jnp
-
-            staging, logits = chunk(model, Contiguous(staging), params,
-                                    tokens, start, lengths)
-            return staging, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        def _restore(staging, chunk_kv, row, start):
-            import jax
-
-            return {
-                k: jax.lax.dynamic_update_slice(
-                    staging[k],
-                    chunk_kv[k][:, None].astype(staging[k].dtype),
-                    (0, row, 0, start, 0))
-                for k in ("k", "v")
-            }
-
-        def _migrate(pool, staging, row, slot):
-            import jax
-
-            out = {}
-            for k in ("k", "v"):
-                layers, _, heads, max_len, hd = staging[k].shape
-                src = jax.lax.dynamic_slice(
-                    staging[k], (0, row, 0, 0, 0),
-                    (layers, 1, heads, max_len, hd))
-                out[k] = jax.lax.dynamic_update_slice(
-                    pool[k], src.astype(pool[k].dtype), (0, slot, 0, 0, 0))
-            return out
-
-        def _decode(pool, params, token, pos):
-            import jax.numpy as jnp
-
-            pool, logits = decode(model, Contiguous(pool), params, token,
-                                  pos)
-            return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        # speculative verify: tokens is [slots, k+1] (committed token then
-        # k drafts), the program writes K/V at all k+1 positions and
-        # returns the greedy pick at EVERY position — the commit walk
-        # happens on the host over int32 ids only
-        def _verify(pool, params, tokens, pos):
-            import jax.numpy as jnp
-
-            pool, logits = verify(model, Contiguous(pool), params, tokens,
-                                  pos)
-            return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        self._verify_def = _verify
-
-        # paged-layout programs: arena first for donation pairing, the
-        # int32 page table crosses as data every call (fixed shape — the
-        # signature stays closed over arbitrary per-row lengths), in the
-        # step programs as columns of their one operand (`_decode_operand`,
+        # the programs: arena first for donation pairing, the int32 page
+        # table crosses as data every call (fixed shape — the signature
+        # stays closed over arbitrary per-row lengths), in the step
+        # programs as columns of their one operand (`_decode_operand`,
         # `_chunk_operand`).
-        # Compiled lazily via `_paged_c` so bucketed sessions never pay
-        # for them; export/import move single pages for fleet handoff.
+        # Compiled lazily via `_paged_c`; export/import move single pages
+        # for fleet handoff and the host tier.
         # The expert counters of a model whose `ffn` counts ride the token
         # readback (`out[rows:]`), so there is one readback still — with a
         # `State` or, as here, without one.
@@ -647,6 +565,10 @@ class GenerationSession:
 
             return import_page(arena, chunk_kv, page)
 
+        # speculative verify: tokens is [slots, k+1] (committed token then
+        # k drafts), the program writes K/V at all k+1 positions and
+        # returns the greedy pick at EVERY position — the commit walk
+        # happens on the host over int32 ids only
         def _verify_paged(arena, params, table, tokens, pos):
             import jax.numpy as jnp
 
@@ -688,11 +610,15 @@ class GenerationSession:
         if self._per_sequence:
             self._paged_defs.update(chunk_state=_prefill_chunk_paged_state,
                                     decode_state=_decode_paged_state)
+        # the keys of the two step programs `step()` launches
+        suffix = "_state" if self._per_sequence else ""
+        self._chunk_program = "chunk" + suffix
+        self._decode_program = "decode" + suffix
 
-        # pool/staging is arg 0 and output 0 of every mutating compiled
+        # the arena is arg 0 and output 0 of every mutating compiled
         # callable, so state_io="auto" pairs it and XLA gets the buffer
-        # donated; _extract's output is chunk-shaped (no pairing, no
-        # donation — it must not invalidate the staging it reads)
+        # donated; `_page_export`'s output is page-shaped (no pairing, no
+        # donation — it must not invalidate the arena it reads).
         # `mesh=None` means "the global mesh at first call", which is
         # sticky process state that can change between sessions — resolve
         # it NOW so every program this session runs (and every session
@@ -704,23 +630,18 @@ class GenerationSession:
             from easydist_tpu.jaxfront.mesh import get_device_mesh
 
             mesh = get_device_mesh()
-            self.mesh = mesh  # _extract_for compiles against it too
+            self.mesh = mesh  # `_paged_c` compiles against it
         memo_key = (compile_key, mesh) \
             if compile_key is not None and mesh is not None else None
+        # {program name: its CompiledFunction}, filled by `_paged_c`
         shared = _COMPILED_MEMO.get(memo_key) if memo_key else None
         if shared is None:
-            shared = (easydist_compile(_prefill_chunk, mesh=mesh),
-                      easydist_compile(_restore, mesh=mesh),
-                      easydist_compile(_migrate, mesh=mesh),
-                      easydist_compile(_decode, mesh=mesh),
-                      {}, {}, {})
+            shared = {}
             if memo_key:
                 while len(_COMPILED_MEMO) >= 32:  # live sessions keep refs
                     _COMPILED_MEMO.pop(next(iter(_COMPILED_MEMO)))
                 _COMPILED_MEMO[memo_key] = shared
-        (self._prefill_chunk_c, self._restore_c,
-         self._migrate_c, self._decode_c, self._extract_cs,
-         self._paged_cs, self._verify_cs) = shared
+        self._paged_cs: Dict[str, Callable] = shared
 
     @staticmethod
     def _refuse_unbuilt(model, cfg: ServeConfig) -> None:
@@ -731,12 +652,6 @@ class GenerationSession:
         name, the setting that drops it, why it cannot be with state layers,
         with window rings, with latent attention — None where it can)."""
         refusals = (
-            (cfg.kv_layout != "paged", "kv_layout='bucketed'",
-             "kv_layout='paged'",
-             "the state pool lives beside the page pool",
-             "the rings live beside the page pool",
-             "a row without heads has no contiguous cache: it lives in "
-             "pages, and the contiguous layout is not built for it"),
             (cfg.kv_host_tier_bytes, "the host tier",
              "kv_host_tier_bytes=0",
              "it demotes trie pages, and the trie is refused too",
@@ -770,53 +685,15 @@ class GenerationSession:
                         f"a model with {prop} cannot be served with "
                         f"{row[1]}: {row[why]}; set {row[2]}")
 
-    def _extract_for(self, chunk_len: int) -> Callable:
-        """Compiled chunk extractor for one chunk size (the slice size
-        must be static, so each chunk length is its own closure — one per
-        distinct bucket chunk, compiled once)."""
-        fn = self._extract_cs.get(chunk_len)
-        if fn is None:
-            from easydist_tpu.jaxfront import easydist_compile
-
-            def _extract(staging, row, start):
-                import jax
-
-                out = {}
-                for k, leaf in staging.items():
-                    layers, _, heads, _, hd = leaf.shape
-                    out[k] = jax.lax.dynamic_slice(
-                        leaf, (0, row, 0, start, 0),
-                        (layers, 1, heads, chunk_len, hd))[:, 0]
-                return out
-
-            # one program per chunk length: each under a name of its own
-            _extract.__name__ = f"_extract_{chunk_len}"
-            fn = easydist_compile(_extract, mesh=self.mesh)
-            self._extract_cs[chunk_len] = fn
-        return fn
-
     def _paged_c(self, name: str) -> Callable:
-        """Compiled paged program ("chunk" / "decode" / "export" /
-        "import"), built on first use and shared through the process
-        memo exactly like `_extract_for`."""
+        """Compiled program (a key of `_paged_defs`), built on first use
+        and shared through the process memo."""
         fn = self._paged_cs.get(name)
         if fn is None:
             from easydist_tpu.jaxfront import easydist_compile
 
             fn = easydist_compile(self._paged_defs[name], mesh=self.mesh)
             self._paged_cs[name] = fn
-        return fn
-
-    def _verify_c(self) -> Callable:
-        """Compiled bucketed verify step, built on first use and shared
-        through the process memo exactly like `_paged_c` (the paged
-        layout's verify program lives in `_paged_defs`/`_paged_cs`)."""
-        fn = self._verify_cs.get("verify")
-        if fn is None:
-            from easydist_tpu.jaxfront import easydist_compile
-
-            fn = easydist_compile(self._verify_def, mesh=self.mesh)
-            self._verify_cs["verify"] = fn
         return fn
 
     # ------------------------------------------------------------ admission
@@ -865,47 +742,31 @@ class GenerationSession:
             len(p.jobs) + p.n_active for p in self._pools.values())
 
     # ------------------------------------------------------------- plumbing
-    def _pool_for(self, bucket: int):
+    def _pool_for(self, bucket: int) -> _PagedPool:
+        """The one pool, built on first use.  Whatever bucket a prompt
+        selects, its key is the capacity cap: lengths are a page-table
+        concern, not a compile-signature concern, so there is nothing to
+        bucket by."""
         cfg = self.config
-        if self._paged:
-            # every bucket collapses into the one page-granular pool:
-            # lengths are a page-table concern, not a compile-signature
-            # concern, so there is nothing to bucket by
-            bucket = max(cfg.decode_buckets)
+        bucket = max(cfg.decode_buckets)
         pool = self._pools.get(bucket)
         if pool is None:
-            if self._paged:
-                chunk = cfg.kv_page_tokens or min(cfg.prefill_chunk,
-                                                  bucket)
-                max_pages = bucket // chunk
-                n_pages = cfg.kv_arena_pages or \
-                    (cfg.max_decode_slots + 1) * max_pages
-                pool = _PagedPool(
-                    bucket, cfg.max_decode_slots, self._pages_factory,
-                    n_rows=cfg.prefill_batch, chunk=chunk,
-                    prefix_bytes=(cfg.prefix_cache_bytes
-                                  if cfg.enable_prefix_cache else 0),
-                    n_pages=n_pages,
-                    host_tier_bytes=cfg.kv_host_tier_bytes,
-                    export_page=self._export_arena_page,
-                    model_itemsize=self._model_itemsize(),
-                    init_state=self._state_factory
-                    if self._per_sequence else None)
-            else:
-                pool = _BucketPool(
-                    bucket, cfg.max_decode_slots, self._cache_factory,
-                    n_rows=cfg.prefill_batch,
-                    chunk=min(cfg.prefill_chunk, bucket),
-                    prefix_bytes=(cfg.prefix_cache_bytes
-                                  if cfg.enable_prefix_cache else 0))
-            self._pools[bucket] = pool
+            chunk = cfg.kv_page_tokens or min(cfg.prefill_chunk, bucket)
+            max_pages = bucket // chunk
+            n_pages = cfg.kv_arena_pages or \
+                (cfg.max_decode_slots + 1) * max_pages
+            pool = self._pools[bucket] = _PagedPool(
+                bucket, cfg.max_decode_slots, self._pages_factory,
+                n_rows=cfg.prefill_batch, chunk=chunk,
+                prefix_bytes=(cfg.prefix_cache_bytes
+                              if cfg.enable_prefix_cache else 0),
+                n_pages=n_pages,
+                host_tier_bytes=cfg.kv_host_tier_bytes,
+                export_page=self._export_arena_page,
+                model_itemsize=self._model_itemsize(),
+                init_state=self._state_factory
+                if self._per_sequence else None)
         return pool
-
-    def _cache_factory(self, batch: int, max_len: int):
-        from easydist_tpu.models.decoder import Contiguous
-
-        return self._born(Contiguous.init(self._model, batch, max_len,
-                                          self.config.kv_cache_dtype))
 
     def _pages_factory(self, n_pages: int, page_tokens: int):
         cfg = self.config
@@ -1019,12 +880,14 @@ class GenerationSession:
         return state, out, sp
 
     def _admit_one(self) -> bool:
-        """Pop one pending request toward generation: reserve a pool slot
-        + staging row, restore the longest cached prefix, and enqueue a
-        prefill job (chunks run in `step()`).  Returns False when nothing
-        is admissible."""
-        import jax.numpy as jnp
-
+        """Pop one pending request toward generation: reserve a pool slot,
+        a chunk-program row and EVERY page the sequence can ever touch up
+        front (decode crossing a page boundary must find the page already
+        mapped — a sentinel there silently drops the token's K/V), map the
+        trie's committed prefix pages into the slot's table row, and
+        enqueue a prefill job (chunks run in `step()`).  Returns False
+        when nothing is admissible or the arena cannot make room (the
+        request stays queued)."""
         if not self._pending:
             return False
         prompt, max_new, eos, fut, timing = self._pending[0]
@@ -1032,41 +895,6 @@ class GenerationSession:
         pool = self._pool_for(bucket)
         if not pool.free or not pool.free_rows:
             return False
-        if self._paged:
-            return self._admit_one_paged(pool)
-        self._pending.popleft()
-        if fut.set_running_or_notify_cancel() is False:
-            return True  # cancelled while queued; slot stays free
-        slot_idx = pool.free.pop()
-        row = pool.free_rows.pop()
-        prefix_len, nodes = 0, []
-        if pool.trie is not None:
-            # cap below len(prompt): at least one real token must run
-            # through prefill so the finishing chunk produces logits
-            prefix_len, nodes = pool.trie.match(
-                prompt, max_tokens=len(prompt) - 1)
-            for j, node in enumerate(nodes):
-                pool.staging = self._restore_c(
-                    pool.staging, node.kv,
-                    jnp.asarray(row, jnp.int32),
-                    jnp.asarray(j * pool.chunk, jnp.int32))
-            pool.trie.pin(nodes)
-        self.metrics.record_admission(len(prompt), prefix_len)
-        pool.jobs[row] = _PrefillJob(
-            request_id=self._admitted(timing, prefix_len), future=fut,
-            prompt=prompt, max_new=max_new, eos_id=eos, row=row,
-            slot_idx=slot_idx, start=prefix_len,
-            prefix_nodes=nodes, timing=timing)
-        return True
-
-    def _admit_one_paged(self, pool: _PagedPool) -> bool:
-        """Paged admission: reserve EVERY page the sequence can ever
-        touch up front (decode crossing a page boundary must find the
-        page already mapped — a sentinel there silently drops the
-        token's K/V), mapping the trie's committed prefix pages in place
-        of the bucketed layout's restore copies.  Defers (returns False,
-        request stays queued) when the arena cannot make room."""
-        prompt, max_new, eos, fut, timing = self._pending[0]
         prefix_len, nodes = 0, []
         if pool.trie is not None:
             # cap below len(prompt): at least one real token must run
@@ -1093,8 +921,7 @@ class GenerationSession:
         row = pool.free_rows.pop()
         # zero-copy restore: the slot's leading windows point at the
         # trie's pages (shared, read-only by construction — writes only
-        # land past the prefix); the bucketed path would
-        # dynamic_update_slice-copy these bytes into staging here
+        # land past the prefix)
         for j, node in enumerate(nodes):
             pid = node.kv["page"]
             pool.pool.share(pid)
@@ -1146,43 +973,7 @@ class GenerationSession:
         return nodes, len(nodes) * pool.chunk
 
     # ----------------------------------------------------- chunked prefill
-    def _prefill_round(self, pool, max_chunks: int) -> int:
-        """Run up to `max_chunks` batched chunk calls on `pool`'s staging
-        rows; finished jobs commit to the trie, migrate to their slot, and
-        free their row.  Returns the number of chunk calls executed."""
-        import jax.numpy as jnp
-
-        if self._paged:
-            return self._prefill_round_paged(pool, max_chunks)
-        calls = 0
-        c_len = pool.chunk
-        while pool.jobs and calls < max_chunks:
-            with spans.span("easydist.serve.prefill.build"):
-                tokens = np.full((pool.n_rows, c_len),
-                                 int(self.config.pad_value), np.int32)
-                start = np.zeros((pool.n_rows,), np.int32)
-                lengths = np.ones((pool.n_rows,), np.int32)
-                for row, job in pool.jobs.items():
-                    seg = job.prompt[job.start:job.start + c_len]
-                    tokens[row, :len(seg)] = seg
-                    start[row] = job.start
-                    lengths[row] = len(job.prompt)
-                args = (pool.staging, self.params, jnp.asarray(tokens),
-                        jnp.asarray(start), jnp.asarray(lengths))
-                result = self._prefill_chunk_c.get_compiled(*args)
-                if pool.bucket not in self._audited_prefill:
-                    self._audited_prefill.add(pool.bucket)
-                    self._audit_chunked_prefill(result, pool.bucket)
-            pool.staging, first, sp = self._run(
-                "easydist.serve.prefill.call", result, args,
-                rows=pool.n_rows, chunk=c_len)
-            self.metrics.record_prefill_chunk(pool.n_rows, c_len,
-                                              sp.seconds)
-            calls += 1
-            self._advance_jobs(pool, first, self._finish_prefill)
-        return calls
-
-    def _advance_jobs(self, pool, first, finish: Callable) -> None:
+    def _advance_jobs(self, pool: _PagedPool, first) -> None:
         """After a chunk call: every job moves one chunk on; the jobs whose
         last chunk this was are finished, each in its own span."""
         for row in list(pool.jobs):
@@ -1191,30 +982,29 @@ class GenerationSession:
             if job.start >= len(job.prompt):
                 with spans.span("easydist.serve.prefill.finish",
                                 request_id=job.request_id):
-                    finish(pool, row, int(first[row]))
+                    self._finish_prefill(pool, row, int(first[row]))
 
-    def _prefill_round_paged(self, pool: _PagedPool,
-                             max_chunks: int) -> int:
-        """Paged `_prefill_round`: each chunk writes straight into the
-        arena through the job's table row (no staging, no migrate, and a
-        restored prefix needed no copy to begin with).  Idle rows get an
-        all-sentinel table row so their writes drop and their logits are
-        garbage nobody reads — one compiled signature regardless of
-        which rows are live."""
+    def _prefill_round(self, pool: _PagedPool, max_chunks: int) -> int:
+        """Run up to `max_chunks` batched chunk calls over `pool`'s jobs:
+        each chunk writes straight into the arena through the job's table
+        row; finished jobs commit to the trie and free their row.  Idle
+        rows get an all-sentinel table row so their writes drop and their
+        logits are garbage nobody reads — one compiled signature
+        regardless of which rows are live.  Returns the number of chunk
+        calls executed."""
         calls = 0
         c_len = pool.chunk
         while pool.jobs and calls < max_chunks:
             with spans.span("easydist.serve.prefill.build"):
-                program = "chunk_state" if self._per_sequence else "chunk"
                 args = (pool.arena, self.params,
                         _chunk_operand(pool, int(self.config.pad_value)))
-                result, held = self._held_or_resolved(pool, program, args)
+                result, held = self._held_or_resolved(
+                    pool, self._chunk_program, args)
                 if pool.bucket not in self._audited_prefill:
                     self._audited_prefill.add(pool.bucket)
-                    # SERVE002's jaxpr walk asserts the bucketed staging
-                    # idiom (dynamic_update_slice restore); the paged
-                    # program replaces it with table writes, audited
-                    # host-side by KV001 — only the donation half applies
+                    # the chunk's writes go through the table, audited
+                    # host-side by KV001; of the program the donation
+                    # is audited
                     try:
                         from easydist_tpu.analyze import \
                             check_decode_donation
@@ -1246,12 +1036,12 @@ class GenerationSession:
                     "prefill", *first[pool.n_rows:],
                     pair_slots=pool.n_rows * c_len * self._model.pair_slots)
             calls += 1
-            self._advance_jobs(pool, first, self._finish_prefill_paged)
+            self._advance_jobs(pool, first)
         return calls
 
-    def _finish_prefill_paged(self, pool: _PagedPool, row: int,
-                              first_token: int) -> None:
-        """One paged job's last chunk ran: commit its whole-chunk pages
+    def _finish_prefill(self, pool: _PagedPool, row: int,
+                        first_token: int) -> None:
+        """One job's last chunk ran: commit its whole-chunk pages
         into the trie as page REFERENCES (share + {"page": id} — no
         extraction copy), free the row, open the decode slot."""
         job = pool.jobs.pop(row)
@@ -1299,70 +1089,24 @@ class GenerationSession:
         pool.slots[job.slot_idx] = slot
         self._maybe_retire(pool, job.slot_idx)
 
-    def _finish_prefill(self, pool: _BucketPool, row: int,
-                        first_token: int) -> None:
-        """One job's last chunk ran: commit its aligned chunks into the
-        trie, migrate the staging row into the reserved pool slot, free
-        the row, and open the decode slot."""
-        import jax.numpy as jnp
-
-        job = pool.jobs.pop(row)
-        pinned = list(job.prefix_nodes)
-        if pool.trie is not None:
-            nodes = list(job.prefix_nodes)
-            for j in range(len(nodes), len(job.prompt) // pool.chunk):
-                chunk_toks = job.prompt[j * pool.chunk:(j + 1) * pool.chunk]
-                node = pool.trie.lookup_node(nodes, chunk_toks)
-                if node is None:
-                    kv = self._extract_for(pool.chunk)(
-                        pool.staging, jnp.asarray(row, jnp.int32),
-                        jnp.asarray(j * pool.chunk, jnp.int32))
-                    node = pool.trie.commit(nodes, chunk_toks, kv)
-                if node is None:
-                    break  # byte budget exhausted; partial path is fine
-                nodes.append(node)
-            # hold the full committed path for the slot's lifetime
-            pool.trie.unpin(job.prefix_nodes)
-            pool.trie.pin(nodes)
-            pinned = nodes
-            self._audit_prefix_cache(pool)
-        pool.cache = self._migrate_c(pool.cache, pool.staging,
-                                     jnp.asarray(row, jnp.int32),
-                                     jnp.asarray(job.slot_idx, jnp.int32))
-        pool.free_rows.append(row)
-        self._first_token(job.timing)
-
-        slot = _Slot(request_id=job.request_id, future=job.future,
-                     pos=len(job.prompt), token=first_token,
-                     max_new=job.max_new, eos_id=job.eos_id,
-                     pinned=pinned, prompt=job.prompt, timing=job.timing)
-        slot.generated.append(slot.token)
-        pool.slots[job.slot_idx] = slot
-        self._maybe_retire(pool, job.slot_idx)
-
     # ------------------------------------------------------------- decoding
-    def _retire(self, pool, slot_idx: int, reason: str) -> None:
+    def _retire(self, pool: _PagedPool, slot_idx: int, reason: str) -> None:
         slot = pool.slots.pop(slot_idx)
         if self._drafter is not None:
             self._drafter.forget(slot.request_id)
             self._spec_ewma.pop(slot.request_id, None)
             self._spec_idle.pop(slot.request_id, None)
-        if self._paged:
-            for pid in pool.give_slot(slot_idx):
-                pool.pool.release(pid)
-        else:
-            pool.free.append(slot_idx)
+        pool.give_slot(slot_idx)
         if pool.trie is not None and slot.pinned:
             pool.trie.unpin(slot.pinned)
-        if self._paged:
-            self._audit_kv(pool, f"retire[{reason}]")
+        self._audit_kv(pool, f"retire[{reason}]")
         slot.future.set_result({"ids": list(slot.generated),
                                 "finish_reason": reason,
                                 "timing": _finish_timing(slot.timing,
                                                          reason)})
         self.metrics.inc("requests_completed")
 
-    def _maybe_retire(self, pool: _BucketPool, slot_idx: int) -> bool:
+    def _maybe_retire(self, pool: _PagedPool, slot_idx: int) -> bool:
         slot = pool.slots[slot_idx]
         if slot.eos_id is not None and slot.token == slot.eos_id:
             self._retire(pool, slot_idx, "eos")
@@ -1374,59 +1118,39 @@ class GenerationSession:
             return False
         return True
 
-    def _decode_round(self, pool, only: Optional[set] = None) -> None:
+    def _decode_round(self, pool: _PagedPool,
+                      only: Optional[set] = None) -> None:
         """One compiled decode step over ALL slots of `pool` (fixed
-        shapes: the signature cache stays at one entry per bucket — and
-        at ONE entry total for the paged layout, whose only per-step
-        variation is page-table DATA).
+        shapes: the signature cache stays at ONE entry, whose only
+        per-step variation is page-table DATA).
 
-        `only` restricts the round to the given slot indices — PAGED
-        layout only (excluded rows keep a sentinel table row so their
-        dead-row write drops; the bucketed cache has no sentinel, so an
-        excluded bucketed slot would take a garbage write at row 0).
+        `only` restricts the round to the given slot indices (excluded
+        rows keep a sentinel table row so their dead-row write drops).
         The speculative scheduler uses it to plain-decode the slots a
         verify round could not carry."""
-        import jax.numpy as jnp
-
         with spans.span("easydist.serve.decode.build"):
             live = [i for i in pool.slots if only is None or i in only]
             # the positions this round attends, its own included
             attended = sum(pool.slots[idx].pos + 1 for idx in live)
-            pages = {}
-            if self._paged:
-                # ... and the K/V pages under them, which the paged kernel
-                # walks in EACH full-attention layer, of the pages the
-                # pool's rows could hold
-                pages = dict(
-                    pages_walked=sum(pool.slots[idx].pos // pool.chunk + 1
-                                     for idx in live),
-                    pages_bucket=pool.n_slots * pool.max_pages)
-                program = "decode_state" if self._per_sequence else "decode"
-                args = (pool.arena, self.params, _decode_operand(pool, live))
-                result, held = self._held_or_resolved(pool, program, args)
-            else:
-                token = np.zeros((pool.n_slots,), np.int32)
-                pos = np.zeros((pool.n_slots,), np.int32)
-                for idx in live:
-                    token[idx] = pool.slots[idx].token
-                    pos[idx] = pool.slots[idx].pos
-                args = (pool.cache, self.params, jnp.asarray(token),
-                        jnp.asarray(pos))
-                result, held = self._decode_c.get_compiled(*args), None
+            # ... and the K/V pages under them, which the paged kernel
+            # walks in EACH full-attention layer, of the pages the
+            # pool's rows could hold
+            pages = dict(
+                pages_walked=sum(pool.slots[idx].pos // pool.chunk + 1
+                                 for idx in live),
+                pages_bucket=pool.n_slots * pool.max_pages)
+            args = (pool.arena, self.params, _decode_operand(pool, live))
+            result, held = self._held_or_resolved(
+                pool, self._decode_program, args)
             if pool.bucket not in self._audited:
                 self._audited.add(pool.bucket)
                 self._audit_donation(result, pool.bucket)
                 self._audit_host_aliases(pool)
-                if self._paged:
-                    self._audit_kv(pool, "first_decode")
-                    if "k_scale" in pool.arena:
-                        self._audit_quant_program(result, "first_decode")
-        state, nxt, sp = self._run("easydist.serve.decode.call", result,
-                                   args, held, rows=len(live))
-        if self._paged:
-            pool.arena = state
-        else:
-            pool.cache = state
+                self._audit_kv(pool, "first_decode")
+                if "k_scale" in pool.arena:
+                    self._audit_quant_program(result, "first_decode")
+        pool.arena, nxt, sp = self._run("easydist.serve.decode.call", result,
+                                        args, held, rows=len(live))
         with spans.span("easydist.serve.decode.harvest"):
             for idx in live:
                 slot = pool.slots[idx]
@@ -1443,8 +1167,7 @@ class GenerationSession:
                 self.metrics.record_moe(
                     "decode", *nxt[pool.n_slots:],
                     pair_slots=pool.n_slots * self._model.pair_slots)
-            if self._paged:
-                self._record_kv_pool(pool, len(live))
+            self._record_kv_pool(pool, len(live))
 
     def _record_kv_pool(self, pool: _PagedPool, live_rows: int = 0) -> None:
         in_use, held = pool.occupancy()
@@ -1472,25 +1195,17 @@ class GenerationSession:
                 rows_updated=live_rows * len(leaves))
 
     # ------------------------------------------------ speculative decoding
-    def _spec_round(self, pool) -> bool:
+    def _spec_round(self, pool: _PagedPool) -> bool:
         """One speculative draft/verify round over `pool`
         (serve/speculate.py describes the accept rule).  Returns False
         when no slot can ride a verify step this round — the caller
         falls back to a plain decode round, so speculation never stalls
         decode.
 
-        Bucketed pools are all-or-nothing: the verify program writes
-        k+1 cache rows for EVERY row, so every live slot needs headroom
-        (pos + k + 1 <= bucket) — near the wall the pool rides plain
-        decode for its last few tokens.  Slots without a draft ride
-        anyway with pad drafts (position 0 of the verify output is the
-        plain-greedy token, so they commit at least one token, exactly
-        like a decode step).
-
-        Paged pools are per-slot: sentinel table rows drop excluded
-        rows' writes, so eligible slots (draft + headroom + speculative
-        spill windows mappable) verify while the rest take a plain
-        decode call (`_decode_round(only=...)`)."""
+        The choice is per slot: sentinel table rows drop excluded rows'
+        writes, so eligible slots (draft + headroom + speculative spill
+        windows mappable) verify while the rest take a plain decode call
+        (`_decode_round(only=...)`)."""
         k = self._spec_k
         if self._spec_gate_idle > 0:
             # pacing after a round that closed below the full-batch
@@ -1516,44 +1231,9 @@ class GenerationSession:
                 drafts[idx] = (list(int(t) for t in d) + [0] * k)[:k]
         if not drafts:
             return False
-        if self._paged:
-            return self._verify_round_paged(pool, drafts)
-        if any(s.pos + k + 1 > pool.bucket for s in pool.slots.values()):
-            return False
-        return self._verify_round_bucketed(pool, drafts)
+        return self._verify_round(pool, drafts)
 
-    def _verify_round_bucketed(self, pool: _BucketPool, drafts) -> bool:
-        import jax.numpy as jnp
-
-        k = self._spec_k
-        with spans.span("easydist.serve.decode.build"):
-            tokens = np.zeros((pool.n_slots, k + 1), np.int32)
-            pos = np.zeros((pool.n_slots,), np.int32)
-            for idx, slot in pool.slots.items():
-                tokens[idx, 0] = slot.token
-                tokens[idx, 1:] = drafts.get(idx, [0] * k)
-                pos[idx] = slot.pos
-            args = (pool.cache, self.params, jnp.asarray(tokens),
-                    jnp.asarray(pos))
-            result = self._verify_c().get_compiled(*args)
-            if ("bucketed", pool.bucket) not in self._audited_verify:
-                self._audited_verify.add(("bucketed", pool.bucket))
-                self._audit_verify(result, f"verify[bucket={pool.bucket}]")
-        pool.cache, nxt, sp = self._run("easydist.serve.decode.call",
-                                        result, args, rows=len(pool.slots))
-        # rejected rows need no explicit cleanup in the bucketed layout:
-        # the pos cursor simply does not advance past the accepted
-        # prefix, the next write at pos overwrites the stale row, and
-        # the length mask hides everything past the query position
-        with spans.span("easydist.serve.decode.harvest"):
-            proposed, accepted, committed = self._commit_verify(
-                pool, drafts, tokens, nxt, list(pool.slots), sp.t1_ns)
-            self.metrics.record_speculation(
-                proposed, accepted, committed, len(drafts), pool.n_slots,
-                sp.seconds)
-        return True
-
-    def _verify_round_paged(self, pool: _PagedPool, drafts) -> bool:
+    def _verify_round(self, pool: _PagedPool, drafts) -> bool:
         import jax.numpy as jnp
 
         k = self._spec_k
@@ -1591,8 +1271,8 @@ class GenerationSession:
             args = (pool.arena, self.params, jnp.asarray(tbl),
                     jnp.asarray(tokens), jnp.asarray(pos))
             result = self._paged_c("verify").get_compiled(*args)
-            if ("paged", pool.bucket) not in self._audited_verify:
-                self._audited_verify.add(("paged", pool.bucket))
+            if pool.bucket not in self._audited_verify:
+                self._audited_verify.add(pool.bucket)
                 self._audit_verify(result,
                                    f"verify[paged cap={pool.bucket}]")
         pool.arena, nxt, sp = self._run("easydist.serve.decode.call",
@@ -1720,15 +1400,6 @@ class GenerationSession:
         except ImportError:  # analyze is an optional layer at runtime
             pass
 
-    def _audit_chunked_prefill(self, result, bucket: int) -> None:
-        try:
-            from easydist_tpu.analyze import check_chunked_prefill
-
-            check_chunked_prefill(result,
-                                  node=f"prefill_chunk[bucket={bucket}]")
-        except ImportError:
-            pass
-
     def _audit_prefix_cache(self, pool) -> None:
         try:
             from easydist_tpu.analyze import check_prefix_cache
@@ -1739,23 +1410,18 @@ class GenerationSession:
             pass
 
     def _audit_host_aliases(self, pool) -> None:
-        """ALIAS004: the buffers the next dispatch donates (cache +
-        staging, or the paged arena) must not be reachable from
-        host-held references that outlive the step — trie nodes must
-        hold `_extract` COPIES (bucketed) or page references (paged,
-        `kv.is_page_ref`), never the donated arrays themselves."""
+        """ALIAS004: the buffers the next dispatch donates (the arena)
+        must not be reachable from host-held references that outlive the
+        step — trie nodes must hold page references
+        (`kv.is_page_ref`), never the donated arrays themselves."""
         try:
             from easydist_tpu.analyze import check_host_aliases
         except ImportError:  # analyze is an optional layer at runtime
             return
-        if self._paged:
-            donated = {"arena": pool.arena}
-        else:
-            donated = {"cache": pool.cache, "staging": pool.staging}
         holders = {}
         if pool.trie is not None:
             holders["trie"] = [node.kv for node in pool.trie._walk()]
-        check_host_aliases(donated, holders,
+        check_host_aliases({"arena": pool.arena}, holders,
                            node=f"session[bucket={pool.bucket}]")
 
     def _audit_kv(self, pool: _PagedPool, where: str) -> None:
@@ -1795,7 +1461,7 @@ class GenerationSession:
     def step(self) -> int:
         """One serving round: admit pending prompts into free slots/rows,
         run at most `prefill_chunks_per_step` prefill chunk calls, then
-        one decode step per bucket with live slots, harvesting
+        one decode (or verify) round over the live slots, harvesting
         retirements.  Returns the number of tokens generated this round
         (decode tokens; prefill first-tokens count via `prefills`)."""
         # the replica-death fault point sits at the step boundary: tokens
@@ -1874,24 +1540,25 @@ class GenerationSession:
         hottest-first (prefix_cache.hot_paths) — what a router re-imports
         into surviving replicas on drain so shared-prefix traffic does
         not re-pay prefill after a scale-down."""
-        return {b: ([self._materialize_path(p, path)
-                     for path in p.trie.hot_paths()]
-                    if self._paged else p.trie.hot_paths())
+        return {b: [self._materialize_path(p, path)
+                    for path in p.trie.hot_paths()]
                 for b, p in self._pools.items() if p.trie is not None}
 
     # ------------------------------------------------- fleet trie access
-    def _trie_bucket(self, bucket: Optional[int]) -> Optional[int]:
-        """The pool key `bucket` maps to: itself, or the single paged
-        pool's capacity cap."""
-        if bucket is None:
+    def _trie_pool(self, prompt: Sequence[int]) -> Optional[_PagedPool]:
+        """The pool whose trie `prompt` would match in: None when the
+        prompt fits no bucket, no pool is built yet or it keeps no trie."""
+        if select_bucket(len(prompt) + 1,
+                         self.config.decode_buckets) is None:
             return None
-        return max(self.config.decode_buckets) if self._paged else bucket
+        pool = self._pools.get(max(self.config.decode_buckets))
+        return pool if pool is not None and pool.trie is not None else None
 
     def _materialize_path(self, pool, path: List[tuple]) -> List[tuple]:
-        """Fleet transport of paged trie entries: replace {"page": id}
-        references with the page's actual K/V (the same
-        [layers, heads, chunk, head_dim] arrays a bucketed trie commits),
-        so exported paths are layout-agnostic on the wire."""
+        """Fleet transport of trie entries: replace {"page": id}
+        references with the page's actual K/V ({key: [layers, heads,
+        chunk, *]} arrays, `kv/arena.py::export_page`), so exported paths
+        carry no page id of this session's arena on the wire."""
         import jax.numpy as jnp
 
         out = []
@@ -1913,8 +1580,8 @@ class GenerationSession:
             out.append((key, kv))
         return out
 
-    def _import_path_paged(self, pool, path: Sequence[tuple]) -> int:
-        """Commit a transported (materialized) chunk path into the paged
+    def _import_path(self, pool, path: Sequence[tuple]) -> int:
+        """Commit a transported (materialized) chunk path into the
         trie: each chunk lands in a freshly allocated arena page, written
         by the compiled import program and committed as a page
         reference.  First-commit-wins like `PrefixCache.import_path`;
@@ -1944,68 +1611,60 @@ class GenerationSession:
         return len(nodes)
 
     def bucket_chunk(self, prompt: Sequence[int]) -> Optional[int]:
-        """Trie page size (tokens) for the bucket `prompt` decodes in, or
-        None when the prompt fits no bucket / prefix reuse is off."""
+        """Trie page size (tokens) `prompt` is cached at, or None when
+        the prompt fits no bucket / prefix reuse is off."""
         bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
         if bucket is None or not self.config.enable_prefix_cache \
                 or not self.config.prefix_cache_bytes:
             return None
-        return min(self.config.prefill_chunk, self._trie_bucket(bucket))
+        return min(self.config.prefill_chunk,
+                   max(self.config.decode_buckets))
 
     def prefix_affinity(self, prompt: Sequence[int]) -> int:
         """Tokens of `prompt` already committed in this session's trie —
         non-mutating (PrefixCache.peek), so a router can probe every
         replica without disturbing LRU state."""
-        bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
-        pool = self._pools.get(self._trie_bucket(bucket)) \
-            if bucket is not None else None
-        if pool is None or pool.trie is None:
+        pool = self._trie_pool(prompt)
+        if pool is None:
             return 0
         return pool.trie.peek(prompt, max_tokens=len(prompt) - 1)
 
     def export_prefix_path(self, prompt: Sequence[int],
                            max_tokens: Optional[int] = None) -> List[tuple]:
         """Committed chunk path for `prompt`'s longest cached prefix, as
-        [(chunk_tokens, kv)] for transport to another replica (paged
-        sessions materialize their page references into real arrays)."""
-        bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
-        pool = self._pools.get(self._trie_bucket(bucket)) \
-            if bucket is not None else None
-        if pool is None or pool.trie is None:
+        [(chunk_tokens, kv)] for transport to another replica (page
+        references materialized into real arrays)."""
+        pool = self._trie_pool(prompt)
+        if pool is None:
             return []
-        path = pool.trie.export_path(prompt, max_tokens=max_tokens)
-        return self._materialize_path(pool, path) if self._paged else path
+        return self._materialize_path(
+            pool, pool.trie.export_path(prompt, max_tokens=max_tokens))
 
     def import_prefix_path(self, prompt: Sequence[int],
                            path: Sequence[tuple]) -> int:
-        """Commit a transported chunk path into the trie of the bucket
-        `prompt` will decode in (creating the pool if needed).  Returns
-        chunks present along the path afterwards."""
+        """Commit a transported chunk path into the trie `prompt` will
+        match in (creating the pool if needed).  Returns chunks present
+        along the path afterwards."""
         bucket = select_bucket(len(prompt) + 1, self.config.decode_buckets)
         if bucket is None:
             return 0
         pool = self._pool_for(bucket)
         if pool.trie is None:
             return 0
-        if self._paged:
-            return self._import_path_paged(pool, path)
-        return pool.trie.import_path(path)
+        return self._import_path(pool, path)
 
     def import_hot_pages(self, pages: Dict[int, List[List[tuple]]]) -> int:
         """Re-admit another replica's exported hot pages (drain
-        migration): each bucket's paths import into this session's same
-        bucket when configured here, falling back to the largest
-        configured bucket.  Returns total chunks committed."""
+        migration): every path, whatever capacity its exporter keyed it
+        by, imports into this session's one trie.  Returns total chunks
+        committed."""
         total = 0
         for bucket, paths in pages.items():
-            b = bucket if bucket in self.config.decode_buckets \
-                else max(self.config.decode_buckets)
-            pool = self._pool_for(b)
+            pool = self._pool_for(bucket)
             if pool.trie is None:
                 continue
             for path in paths:
-                total += (self._import_path_paged(pool, path)
-                          if self._paged else pool.trie.import_path(path))
+                total += self._import_path(pool, path)
         return total
 
     def snapshot_inflight(self) -> List[Dict[str, object]]:
@@ -2061,11 +1720,7 @@ class GenerationSession:
             for row in list(pool.jobs):
                 job = pool.jobs.pop(row)
                 pool.free_rows.append(row)
-                if self._paged:
-                    for pid in pool.give_slot(job.slot_idx):
-                        pool.pool.release(pid)
-                else:
-                    pool.free.append(job.slot_idx)
+                pool.give_slot(job.slot_idx)
                 if pool.trie is not None:
                     pool.trie.unpin(job.prefix_nodes)
                 job.future.set_result(
@@ -2103,25 +1758,16 @@ class GenerationSession:
                     "prefilling": len(p.jobs),
                     "free_rows": len(p.free_rows),
                     "prefix_cache": (p.trie.stats() if p.trie else None),
-                    **({"kv_pool": p.pool.stats(),
-                        "kv_table_mapped": int(
-                            (p.table.array != p.table.sentinel).sum())}
-                       if self._paged else {})}
+                    "kv_pool": p.pool.stats(),
+                    "kv_table_mapped": int(
+                        (p.table.array != p.table.sentinel).sum())}
                 for b, p in self._pools.items()},
-            "decode_signatures": (
-                self._paged_cs["decode"].cache_stats()
-                if self._paged and "decode" in self._paged_cs
-                else self._decode_c.cache_stats()),
-            "prefill_signatures": (
-                self._paged_cs["chunk"].cache_stats()
-                if self._paged and "chunk" in self._paged_cs
-                else self._prefill_chunk_c.cache_stats()),
-            "verify_signatures": (
-                self._paged_cs["verify"].cache_stats()
-                if self._paged and "verify" in self._paged_cs
-                else (self._verify_cs["verify"].cache_stats()
-                      if "verify" in self._verify_cs else None)),
-            "migrate_signatures": self._migrate_c.cache_stats(),
+            "decode_signatures":
+                self._paged_c(self._decode_program).cache_stats(),
+            "prefill_signatures":
+                self._paged_c(self._chunk_program).cache_stats(),
+            "verify_signatures": (self._paged_cs["verify"].cache_stats()
+                                  if "verify" in self._paged_cs else None),
             "metrics": self.metrics.snapshot(),
         }
 

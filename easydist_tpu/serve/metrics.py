@@ -229,9 +229,9 @@ class ServeMetrics:
                              pages_bucket: int = 0,
                              attn_pairs: int = 0,
                              delta_positions: int = 0) -> None:
-        """One batched chunk call: `n_rows` staging rows executed `chunk`
+        """One batched chunk call: `n_rows` rows executed `chunk`
         token slots each (idle rows and padded tails included — that IS
-        the waste the padding-ratio gauge measures).  A paged call also
+        the waste the padding-ratio gauge measures).  The call also
         says how many K/V pages its live rows' extents cover
         (`pages_walked`: what the chunk kernel reads a layer) of how many
         their buckets hold (`pages_bucket`: what the gather path reads),
@@ -271,8 +271,7 @@ class ServeMetrics:
         (slot-mapped or trie-held) holding `mapped_tokens` real tokens of
         `pages_in_use * page_tokens` capacity.  `kv_page_utilization` is
         the intra-page fill fraction — 1.0 means zero fragmentation, and
-        (1 - it) is the only padding waste the paged layout CAN have
-        (the bucketed pool pads every row to the bucket instead).
+        (1 - it) is the only padding waste the pool CAN have.
         `quant_bytes_saved` is HBM the live pages did NOT spend versus
         model-precision storage (block-scaled int8 payload + scales vs
         model dtype) — the quantized arena's density win, exported to
@@ -342,8 +341,8 @@ class ServeMetrics:
 
     def record_copy_on_restore_saved(self, nbytes: int) -> None:
         """A prefix restore mapped `nbytes` of committed pages into a
-        sequence's page table instead of `dynamic_update_slice`-copying
-        them — the zero-copy-restore contract, measured."""
+        sequence's page table: the bytes a restore by copy would have
+        moved — the zero-copy-restore contract, measured."""
         with self._lock:
             self._counters["copy_on_restore_bytes_saved"] = \
                 self._counters.get("copy_on_restore_bytes_saved", 0) + nbytes

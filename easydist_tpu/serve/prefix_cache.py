@@ -6,8 +6,11 @@ preambles), and PR 9's prefill recomputed every admitted prompt's KV from
 position 0.  This module indexes **committed KV chunks** — the K/V a
 finished prefill produced for one aligned `prefill_chunk`-token window —
 by their token ids, so the next prompt sharing a prefix restores the
-longest cached run of whole chunks with `dynamic_update_slice` and resumes
-prefill at `prefix_len` instead of 0.
+longest cached run of whole chunks and resumes prefill at `prefix_len`
+instead of 0.  What a node holds is opaque here: the session commits
+`{"page": id}` references to arena pages and restores one by mapping the
+page into the new sequence's table row; arrays are the fleet's wire
+format (`GenerationSession.export_prefix_path` / `import_prefix_path`).
 
 Design points:
 
@@ -26,8 +29,8 @@ Design points:
     sum of committed chunk bytes; eviction walks leaf-first (a node's
     children always depend on it) among unpinned nodes, oldest
     `last_used` first.
-  * **bitwise contract** — restore copies the exact arrays a previous
-    prefill committed, and the chunked prefill attends the full bucket
+  * **bitwise contract** — a restored sequence reads the exact K/V a
+    previous prefill committed, and the chunked prefill attends the same
     window either way, so prefix-cache-on and -off produce bitwise
     identical logits.  `check_invariants` audits the refcount/byte
     bookkeeping; analyze rule SERVE002 wraps it into findings.

@@ -2,9 +2,8 @@
 once on a seeded known-bad fixture (jaxpr use-after-donate, double
 donation, unhonorable state pair, host-held donated buffer), the AST
 host lint flags a retained reference and accepts the rebind idiom, and
-the real artifacts — an auto-solved preset compile, the bucketed and
-paged serving sessions, the repo's own host code — produce zero
-false positives."""
+the real artifacts — an auto-solved preset compile, the serving
+session, the repo's own host code — produce zero false positives."""
 
 import os
 import types
@@ -260,8 +259,7 @@ class TestRealArtifactsClean:
                  if f.rule_id.startswith("ALIAS")]
         assert alias == []
 
-    @pytest.mark.parametrize("layout", ["bucketed", "paged"])
-    def test_session_host_aliases_clean(self, layout):
+    def test_session_host_aliases_clean(self):
         from easydist_tpu.models import gpt
         from easydist_tpu.serve import (GenerationSession, ServeConfig)
 
@@ -270,8 +268,7 @@ class TestRealArtifactsClean:
         # max_decode_slots/buckets match the other serve tests' sessions
         # so the process memo shares ONE set of compiled programs
         sc = ServeConfig(decode_buckets=(32,), max_decode_slots=2,
-                         prefill_chunk=8, prefill_batch=2,
-                         kv_layout=layout)
+                         prefill_chunk=8, prefill_batch=2)
         sess = GenerationSession.for_gpt(params, cfg, config=sc)
         for p in ([1, 2, 3], list(range(1, 12))):
             sess.submit(p, max_new_tokens=4)
@@ -282,7 +279,5 @@ class TestRealArtifactsClean:
         pool = next(iter(sess._pools.values()))
         if pool.trie is not None:
             holders = {"trie": [n.kv for n in pool.trie._walk()]}
-            donated = ({"arena": pool.arena} if layout == "paged"
-                       else {"cache": pool.cache,
-                             "staging": pool.staging})
-            assert audit_host_aliases(donated, holders) == []
+            assert audit_host_aliases({"arena": pool.arena},
+                                      holders) == []

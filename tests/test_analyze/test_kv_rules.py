@@ -59,8 +59,7 @@ class TestCleanFixtures:
         # max_decode_slots matches the other serve tests' sessions so the
         # process memo shares ONE set of compiled paged programs in-suite
         sc = ServeConfig(decode_buckets=(32,), max_decode_slots=2,
-                         prefill_chunk=8, prefill_batch=2,
-                         kv_layout="paged")
+                         prefill_chunk=8, prefill_batch=2)
         sess = GenerationSession.for_gpt(params, cfg, config=sc)
         for p in ([1, 2, 3], list(range(1, 18)), [5] * 9):
             sess.submit(p, max_new_tokens=4)
@@ -152,8 +151,7 @@ class TestHook:
         # max_decode_slots matches the other serve tests' sessions so the
         # process memo shares ONE set of compiled paged programs in-suite
         sc = ServeConfig(decode_buckets=(32,), max_decode_slots=2,
-                         prefill_chunk=8, prefill_batch=2,
-                         kv_layout="paged")
+                         prefill_chunk=8, prefill_batch=2)
         sess = GenerationSession.for_gpt(params, cfg, config=sc)
         sess.submit([1, 2, 3, 4, 5], max_new_tokens=6)
         sess.step()                   # prefill admitted, slot live

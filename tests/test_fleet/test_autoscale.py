@@ -13,7 +13,7 @@ from easydist_tpu.resilience import faultinject
 from easydist_tpu.serve import GenerationSession, ServeConfig
 from easydist_tpu.sim import Autoscaler, AutoscaleConfig
 
-# same shapes as test_router.py so the bucketed programs come out of the
+# same shapes as test_router.py so the programs come out of the
 # process-wide memo
 CHUNK = 8
 

@@ -1,5 +1,5 @@
 """Fleet fault tolerance: kill-and-recover parity (crash mid-decode on
-gpt/llama, bucketed/paged KV, single-device and tp=2 — every recovered
+gpt/llama, single-device and tp=2 — every recovered
 stream bitwise-identical to the uninterrupted run), wedged-replica
 detection via the health probe, probe flaps absorbed vs escalated,
 prefill-replica crash fallback, poison-request quarantine, revive by
@@ -38,16 +38,16 @@ def llama_model():
     return cfg, params
 
 
-def _mk(model, rid, layout="bucketed", factory=None, mesh=None, **kw):
+def _mk(model, rid, factory=None, mesh=None, **kw):
     cfg, params = model
     factory = factory or GenerationSession.for_gpt
     # chunk/batch shapes match test_serve's sessions (and test_router.py)
-    # so both layouts' programs come out of the process-wide memo instead
+    # so the programs come out of the process-wide memo instead
     # of a private signature family compiled just for this file
     kw.setdefault("prefill_chunk", CHUNK)
     kw.setdefault("prefill_batch", 2)
     sc = ServeConfig(decode_buckets=(cfg.seq,), max_decode_slots=2,
-                     breaker_failure_threshold=3, kv_layout=layout, **kw)
+                     breaker_failure_threshold=3, **kw)
     return factory(params, cfg, config=sc, replica_id=rid, mesh=mesh)
 
 
@@ -96,24 +96,16 @@ class TestCrashRecovery:
     """The tentpole contract: kill the replica that holds live decodes
     and the recovered streams are token-for-token identical."""
 
-    # llama-bucketed is the one arm whose compiled programs no other
-    # tier-1 file shares (llama serving is otherwise paged-only), so its
-    # full XLA trace would be paid just for this test — slow tier; the
-    # other three arms reuse process-memo signatures and stay tier-1
-    @pytest.mark.parametrize("kind,layout", [
-        ("gpt", "bucketed"), ("gpt", "paged"),
-        pytest.param("llama", "bucketed", marks=pytest.mark.slow),
-        ("llama", "paged")])
-    def test_mid_decode_crash_bitwise(self, model, llama_model, kind,
-                                      layout):
+    @pytest.mark.parametrize("kind", ["gpt", "llama"])
+    def test_mid_decode_crash_bitwise(self, model, llama_model, kind):
         m = model if kind == "gpt" else llama_model
         factory = (GenerationSession.for_gpt if kind == "gpt"
                    else GenerationSession.for_llama)
         cfg, _ = m
         prompts = _prompts(cfg, seed=11)
-        want = _reference(m, prompts, 6, layout=layout, factory=factory)
-        router = FleetRouter([_mk(m, "d0", layout, factory),
-                              _mk(m, "d1", layout, factory)])
+        want = _reference(m, prompts, 6, factory=factory)
+        router = FleetRouter([_mk(m, "d0", factory),
+                              _mk(m, "d1", factory)])
         futs = [router.submit(p, max_new_tokens=6) for p in prompts]
         # crash the loaded replica on its 4th step — decodes are live
         # with partial ids by then, so recovery is a true mid-stream
@@ -347,7 +339,7 @@ class TestInflightBookkeeping:
 
 class TestPagedHandoffCorruption:
     def test_corrupt_paged_handoff_aborts_before_pool_commit(self, model):
-        """A bit-flipped page in a paged-layout handoff must abort before
+        """A bit-flipped page in a handoff must abort before
         anything touches the destination's PagePool: no page allocated,
         no refcount moved, KV001 bookkeeping still clean — and a clean
         retry afterwards commits normally."""
@@ -357,12 +349,12 @@ class TestPagedHandoffCorruption:
 
         cfg, _ = model
         prompt = list(range(1, 14))
-        src = _mk(model, "src", "paged")
+        src = _mk(model, "src")
         src.submit(prompt, max_new_tokens=2)
         src.run_until_drained()
         path = src.export_prefix_path(prompt)
         assert path, "source trie exported no pages"
-        dst = _mk(model, "dst", "paged")
+        dst = _mk(model, "dst")
         dst.submit([7, 8, 9], max_new_tokens=2)  # materialize the pool
         dst.run_until_drained()
         pool = dst._pools[cfg.seq]

@@ -15,7 +15,7 @@ from easydist_tpu.serve import (CircuitOpenError, GenerationSession,
                                 RequestTooLargeError, ServeConfig)
 
 # chunk/batch shapes match test_serve/test_generation.py's sessions so the
-# bucketed programs come out of the process-wide memo instead of a private
+# programs come out of the process-wide memo instead of a private
 # signature family compiled just for test_fleet
 CHUNK = 8
 

@@ -274,7 +274,7 @@ def test_page_wire_format_is_the_stack_of_leaf_pages(model, quant):
     factory = (GenerationSession.for_gpt if model == "gpt"
                else GenerationSession.for_llama)
     sess = factory(init(cfg, jax.random.PRNGKey(0)), cfg, config=ServeConfig(
-        kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+        decode_buckets=(32,), max_decode_slots=2,
         prefill_chunk=PT, prefill_batch=2,
         kv_quant_dtype="int8" if quant else "none"))
     fut = sess.submit(list(range(1, 2 * PT + 4)), max_new_tokens=3)

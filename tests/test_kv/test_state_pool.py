@@ -75,7 +75,7 @@ def test_state_adapter_reads_clips_zeroes_and_drops():
 def test_admission_takes_a_slot_and_pages_together_and_retirement_frees_both():
     params = gh.granite_init(CFG, jax.random.PRNGKey(0))
     sess = GenerationSession(params, model=gh.decoder(CFG), config=ServeConfig(
-        kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+        decode_buckets=(32,), max_decode_slots=2,
         prefill_chunk=8, prefill_batch=2, kv_arena_pages=8,
         enable_prefix_cache=False, speculate_k=0))
     rng = np.random.default_rng(0)
@@ -118,7 +118,7 @@ def test_admission_takes_a_slot_and_pages_together_and_retirement_frees_both():
 def test_evacuation_gives_state_slots_back():
     params = gh.granite_init(CFG, jax.random.PRNGKey(0))
     sess = GenerationSession(params, model=gh.decoder(CFG), config=ServeConfig(
-        kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+        decode_buckets=(32,), max_decode_slots=2,
         prefill_chunk=8, prefill_batch=2, enable_prefix_cache=False,
         speculate_k=0))
     sess.submit(list(range(1, 20)), max_new_tokens=8)

@@ -114,7 +114,7 @@ def test_a_window_layers_bytes_do_not_grow_and_pages_are_for_full_layers():
 def test_the_pool_hands_rings_out_with_the_slot_and_counts_pages_for_full():
     params = em.exaone_init(CFG, jax.random.PRNGKey(0))
     sess = GenerationSession(params, model=em.decoder(CFG), config=ServeConfig(
-        kv_layout="paged", decode_buckets=(64,), max_decode_slots=3,
+        decode_buckets=(64,), max_decode_slots=3,
         prefill_chunk=8, prefill_batch=2, kv_arena_pages=24,
         enable_prefix_cache=False, speculate_k=0))
     pool = sess._pool_for(64)

@@ -240,7 +240,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
 
 
 def _session(params, **kw):
-    base = dict(kv_layout="paged", decode_buckets=(64,), max_decode_slots=4,
+    base = dict(decode_buckets=(64,), max_decode_slots=4,
                 prefill_chunk=8, prefill_batch=2, kv_arena_pages=40,
                 enable_prefix_cache=False, speculate_k=0)
     base.update(kw)
@@ -370,8 +370,6 @@ def test_a_latent_page_round_trips_through_export_and_import(params, served):
 
 
 REFUSED = {
-    "the contiguous layout": (dict(kv_layout="bucketed"),
-                              "no contiguous cache"),
     "the int8 arena": (dict(kv_quant_dtype="int8"), "a latent row has none"),
 }
 
@@ -404,7 +402,7 @@ def test_the_kernels_serve_it_too(params, served, monkeypatch, devices):
     mesh = make_device_mesh((devices,), ("tp",),
                             devices=jax.devices()[:devices])
     sess = GenerationSession(params, model=DEC, mesh=mesh, config=ServeConfig(
-        kv_layout="paged", decode_buckets=(64,), max_decode_slots=3 + devices,
+        decode_buckets=(64,), max_decode_slots=3 + devices,
         prefill_chunk=8, prefill_batch=2, kv_arena_pages=40,
         enable_prefix_cache=False, speculate_k=0))
     assert _run(sess, _prompts()) == ids

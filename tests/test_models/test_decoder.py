@@ -1,16 +1,20 @@
 """A model costs one `Decoder` record: a third, toy decoder defined here from
 parts of both models (learned positions like gpt; RMSNorm, separate Q/K/V
 and grouped-query attention like llama; a GELU MLP; an untied head) is
-served through `GenerationSession(params, model=...)` in both KV layouts,
-int8 and speculation included, with no step function written for it — and
-its greedy stream equals its own uncached full forward."""
+served through `GenerationSession(params, model=...)`, int8 and speculation
+(the n-gram drafter, and the toy as its own draft model) included, with no
+step function written for it — and its greedy stream equals its own uncached
+full forward.  And the four families that keep more than K/V pages are
+served by a `ServeConfig` that names no layout."""
 
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from easydist_tpu.models import axk1, exaone_moe, granite_hybrid, olmo_hybrid
 from easydist_tpu.models.decoder import (Contiguous, Decoder, Paged, chunk,
                                          split_heads)
 from easydist_tpu.ops import kv_dequantize, kv_quantize
@@ -108,15 +112,20 @@ PROMPTS = [[5, 17, 3, 9, 22, 4, 31, 8, 2, 40, 6], [7, 8, 9, 7, 8, 9, 7, 8],
 N_NEW = 7
 
 
-@pytest.mark.parametrize("spec_k", [0, 2])
-@pytest.mark.parametrize("layout", ["contiguous", "paged", "paged-int8"])
+# "draft-model": the toy drafts for itself through `_wire_draft_model` — the
+# one place a `Contiguous` cache still meets the session
+@pytest.mark.parametrize("layout,spec_k", [
+    ("paged", 0), ("paged", 2), ("paged-int8", 0), ("paged-int8", 2),
+    ("paged-draft-model", 2)])
 def test_toy_decoder_served_equals_its_full_forward(layout, spec_k):
-    sc = ServeConfig(
+    kw = dict(config=ServeConfig(
         decode_buckets=(SEQ,), max_decode_slots=2, prefill_chunk=8,
         prefill_batch=2, speculate_k=spec_k,
-        kv_layout="bucketed" if layout == "contiguous" else "paged",
-        kv_quant_dtype="int8" if layout == "paged-int8" else "none")
-    sess = GenerationSession(PARAMS, model=TOY, config=sc)
+        speculate_drafter="draft_model" if "draft" in layout else "ngram",
+        kv_quant_dtype="int8" if layout == "paged-int8" else "none"))
+    if "draft" in layout:
+        GenerationSession._wire_draft_model(kw, PARAMS, TOY)
+    sess = GenerationSession(PARAMS, model=TOY, **kw)
     futs = [sess.submit(p, max_new_tokens=N_NEW) for p in PROMPTS]
     sess.run_until_drained()
     for prompt, fut in zip(PROMPTS, futs):
@@ -127,6 +136,34 @@ def test_toy_decoder_served_equals_its_full_forward(layout, spec_k):
     if layout == "paged-int8":
         arena = next(iter(sess._pools.values())).arena
         assert sorted(arena) == ["k", "k_scale", "v", "v_scale"]
+
+
+FAMILIES = {   # beside or in place of K/V pages: states, rings, latents
+    "axk1": (axk1, axk1.AxK1Config, axk1.axk1_init),
+    "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig,
+                   exaone_moe.exaone_init),
+    "granite_hybrid": (granite_hybrid, granite_hybrid.GraniteHybridConfig,
+                       granite_hybrid.granite_init),
+    "olmo_hybrid": (olmo_hybrid, olmo_hybrid.OlmoHybridConfig,
+                    olmo_hybrid.olmo_hybrid_init),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_config_that_names_no_layout_serves_every_family(family):
+    module, config, init = FAMILIES[family]
+    cfg = config.tiny()
+    sess = GenerationSession(
+        init(cfg, jax.random.PRNGKey(3)), model=module.decoder(cfg),
+        config=ServeConfig(enable_prefix_cache=False, speculate_k=0,
+                           decode_buckets=(64,), max_decode_slots=2,
+                           prefill_chunk=8))
+    prompt = np.random.default_rng(4).integers(1, cfg.vocab, size=11).tolist()
+    fut = sess.submit(prompt, max_new_tokens=4)
+    sess.run_until_drained()
+    assert len(fut.result(timeout=5)["ids"]) == 4
+    assert sess.stats()["buckets"][64]["kv_pool"]["in_use"] == 0
+    sess.close()
 
 
 def test_position_bound_comes_from_the_record():

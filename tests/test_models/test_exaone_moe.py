@@ -275,7 +275,6 @@ def test_the_dense_layer_counts_nothing_and_the_others_every_pair(params):
 
 
 REFUSED = {   # what -> (the config that asks for it, the error names it)
-    "the contiguous layout": (dict(kv_layout="bucketed"), "bucketed"),
     "the prefix trie and resume": (dict(enable_prefix_cache=True),
                                    "prefix trie.*resumed prefix"),
     "speculation": (dict(speculate_k=2), "speculation.*overwritten ring"),
@@ -287,7 +286,7 @@ REFUSED = {   # what -> (the config that asks for it, the error names it)
 
 @pytest.mark.parametrize("what", list(REFUSED))
 def test_a_model_with_window_rings_refuses(params, what):
-    base = dict(kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+    base = dict(decode_buckets=(32,), max_decode_slots=2,
                 prefill_chunk=8, enable_prefix_cache=False, speculate_k=0)
     asked, named = REFUSED[what]
     with pytest.raises(ValueError, match="window rings cannot be served "
@@ -300,7 +299,7 @@ def test_a_model_with_window_rings_refuses(params, what):
 
 def test_a_session_serves_it_and_the_ids_are_the_references(params):
     sess = GenerationSession(params, model=em.decoder(CFG), config=ServeConfig(
-        kv_layout="paged", decode_buckets=(64,), max_decode_slots=N_SLOTS,
+        decode_buckets=(64,), max_decode_slots=N_SLOTS,
         prefill_chunk=PT, prefill_batch=2, enable_prefix_cache=False,
         speculate_k=0))
     rng = np.random.default_rng(4)

@@ -289,7 +289,6 @@ def test_products_nothing_wrote_cannot_reach_the_output(params, monkeypatch):
 
 
 REFUSED = {   # what -> (the config that asks for it, the error names it)
-    "the contiguous layout": (dict(kv_layout="bucketed"), "bucketed"),
     "the prefix trie": (dict(enable_prefix_cache=True), "prefix trie"),
     "speculation": (dict(speculate_k=2), "speculation"),
     "the host tier": (dict(enable_prefix_cache=True,
@@ -300,7 +299,7 @@ REFUSED = {   # what -> (the config that asks for it, the error names it)
 
 @pytest.mark.parametrize("what", list(REFUSED))
 def test_a_model_with_state_layers_refuses(params, what):
-    base = dict(kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+    base = dict(decode_buckets=(32,), max_decode_slots=2,
                 prefill_chunk=8, enable_prefix_cache=False, speculate_k=0)
     asked, named = REFUSED[what]
     with pytest.raises(ValueError, match="state layers.*" + named):
@@ -312,7 +311,7 @@ def test_a_model_with_state_layers_refuses(params, what):
 
 def test_a_session_serves_it_and_the_ids_are_the_references(params):
     sess = GenerationSession(params, model=gh.decoder(CFG), config=ServeConfig(
-        kv_layout="paged", decode_buckets=(64,), max_decode_slots=N_SLOTS,
+        decode_buckets=(64,), max_decode_slots=N_SLOTS,
         prefill_chunk=PT, prefill_batch=2, enable_prefix_cache=False,
         speculate_k=0))
     rng = np.random.default_rng(4)

@@ -194,7 +194,6 @@ def test_the_decode_kernel_in_the_mixers_place_gives_the_same_round(
 
 
 REFUSED = {   # what -> (the config that asks for it, the error names it)
-    "the contiguous layout": (dict(kv_layout="bucketed"), "bucketed"),
     "the prefix trie": (dict(enable_prefix_cache=True), "prefix trie"),
     "speculation": (dict(speculate_k=2), "speculation"),
     "the host tier": (dict(enable_prefix_cache=True,
@@ -205,7 +204,7 @@ REFUSED = {   # what -> (the config that asks for it, the error names it)
 
 @pytest.mark.parametrize("what", list(REFUSED))
 def test_a_model_with_delta_rule_layers_refuses(params, what):
-    base = dict(kv_layout="paged", decode_buckets=(32,), max_decode_slots=2,
+    base = dict(decode_buckets=(32,), max_decode_slots=2,
                 prefill_chunk=8, enable_prefix_cache=False, speculate_k=0)
     asked, named = REFUSED[what]
     with pytest.raises(ValueError, match="state layers.*" + named):
@@ -217,7 +216,7 @@ def test_a_model_with_delta_rule_layers_refuses(params, what):
 
 def test_a_session_serves_more_requests_than_slots_and_counts_them(params):
     sess = GenerationSession(params, model=oh.decoder(CFG), config=ServeConfig(
-        kv_layout="paged", decode_buckets=(64,), max_decode_slots=2,
+        decode_buckets=(64,), max_decode_slots=2,
         prefill_chunk=PT, prefill_batch=1, enable_prefix_cache=False,
         speculate_k=0))
     rng = np.random.default_rng(5)

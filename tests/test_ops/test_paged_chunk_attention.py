@@ -281,7 +281,7 @@ def test_a_sessions_greedy_tokens_do_not_know_the_backend(family, monkeypatch):
     for backend in ("xla", "paged"):
         monkeypatch.setattr(edconfig, "prefill_attention_backend", backend)
         sess = GenerationSession(params, model=model, config=ServeConfig(
-            kv_layout="paged", decode_buckets=(64,), max_decode_slots=4,
+            decode_buckets=(64,), max_decode_slots=4,
             prefill_chunk=8, prefill_batch=2, enable_prefix_cache=False,
             speculate_k=0))
         futs = [sess.submit(p, max_new_tokens=m) for p, m in reqs]

@@ -1,10 +1,10 @@
 """Quantized + host-tiered paged KV: block-scaled int8 helper round
-trips, quant-off purity (the exact paged arm stays scale-free and
-bitwise vs the bucketed layout), teacher-forced int8 logit drift at the
+trips, quant-off purity (the exact arena stays scale-free and bitwise vs
+the uncached re-forward), teacher-forced int8 logit drift at the
 model level (gpt AND llama), the host-tier session round trip (demote /
-promote / bitwise pass-2), non-auto `kv_cache_dtype` parity on BOTH
-layouts, ServeConfig validation for the three new knobs, and the layer
-13 KVQ001/002/003 analyzer goldens."""
+promote / bitwise pass-2), non-auto `kv_cache_dtype` parity, ServeConfig
+validation for the three knobs, and the layer 13 KVQ001/002/003 analyzer
+goldens."""
 
 import types
 
@@ -35,12 +35,12 @@ def llama_model():
     return cfg, params
 
 
-def _config(layout="paged", **kw):
+def _config(**kw):
     kw.setdefault("decode_buckets", (32,))
     kw.setdefault("max_decode_slots", 2)
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("prefill_batch", 2)
-    return ServeConfig(kv_layout=layout, **kw)
+    return ServeConfig(**kw)
 
 
 def _run(params, cfg, prompts, n_new=4, factory=None, session=None, **kw):
@@ -167,9 +167,13 @@ def test_exact_paged_program_carries_no_int8(model):
 class TestQuantSession:
     def test_quant_off_paged_is_scale_free_and_bitwise(self, model):
         cfg, params = model
-        want, _ = _run(params, cfg, PROMPTS, layout="bucketed")
-        got, sess = _run(params, cfg, PROMPTS, layout="paged")
-        assert got == want
+        got, sess = _run(params, cfg, PROMPTS)
+        for prompt, ids in zip(PROMPTS, got):
+            cur = list(prompt)
+            for tok in ids:     # the uncached re-forward picks the same
+                logits = gpt.gpt_apply(params, cfg, jnp.asarray([cur]))
+                assert tok == int(jnp.argmax(logits[0, -1]))
+                cur.append(tok)
         pool = next(iter(sess._pools.values()))
         assert sorted(pool.arena) == ["k", "v"]
         assert len(pool.arena["k"]) == cfg.layers
@@ -247,49 +251,52 @@ class TestTierSession:
 
 class TestCacheDtypeParity:
     """Satellite: non-auto `kv_cache_dtype` — bf16 arena parity within
-    the documented tolerance on BOTH layouts (bf16 rounding may flip
-    near-tied argmaxes on the tiny fixture, never most of them)."""
+    the documented tolerance (bf16 rounding may flip near-tied argmaxes
+    on the tiny fixture, never most of them)."""
 
-    @pytest.mark.parametrize("layout", ["bucketed", "paged"])
-    def test_bf16_cache_parity(self, layout, model):
+    def test_bf16_cache_parity(self, model):
         cfg, params = model
-        want, _ = _run(params, cfg, PROMPTS, n_new=6, layout=layout)
-        got, sess = _run(params, cfg, PROMPTS, n_new=6, layout=layout,
+        want, _ = _run(params, cfg, PROMPTS, n_new=6)
+        got, sess = _run(params, cfg, PROMPTS, n_new=6,
                          kv_cache_dtype="bfloat16")
         pool = next(iter(sess._pools.values()))
-        k_leaves = pool.arena["k"] if layout == "paged" \
-            else (pool.cache["k"],)
-        assert all(leaf.dtype == jnp.bfloat16 for leaf in k_leaves)
+        assert all(leaf.dtype == jnp.bfloat16 for leaf in pool.arena["k"])
         flat_w = [t for ids in want for t in ids]
         flat_g = [t for ids in got for t in ids]
         match = sum(a == b for a, b in zip(flat_w, flat_g)) / len(flat_w)
-        assert match >= 0.7, (layout, match, want, got)
-        if layout == "paged":
-            # bf16 is exact-path storage, not quantization: scale-free
-            assert sorted(pool.arena) == ["k", "v"]
+        assert match >= 0.7, (match, want, got)
+        # bf16 is exact-path storage, not quantization: scale-free
+        assert sorted(pool.arena) == ["k", "v"]
 
 
 class TestConfigValidation:
     @pytest.mark.parametrize("kw", [
         dict(kv_quant_dtype="fp4"),
-        dict(kv_quant_dtype="int8"),                       # needs paged
-        dict(kv_quant_dtype="int8", kv_layout="paged",
+        dict(kv_quant_dtype="int8",
              kv_cache_dtype="bfloat16"),                   # mutually excl.
         dict(kv_quant_block=-1),
         dict(kv_host_tier_bytes=-1),
-        dict(kv_host_tier_bytes=1 << 20),                  # needs paged
-        dict(kv_host_tier_bytes=1 << 20, kv_layout="paged",
+        dict(kv_host_tier_bytes=1 << 20,
              enable_prefix_cache=False),                   # needs the trie
     ])
     def test_rejected(self, kw):
         with pytest.raises(ValueError):
             ServeConfig(decode_buckets=(32,), **kw)
 
-    def test_accepted(self):
-        sc = ServeConfig(decode_buckets=(32,), kv_layout="paged",
-                         kv_quant_dtype="int8", kv_quant_block=4,
-                         kv_host_tier_bytes=1 << 20)
-        assert sc.kv_quant_dtype == "int8"
+    def test_the_removed_layout_is_refused_by_name(self):
+        with pytest.raises(ValueError, match="bucketed.*was removed.*"
+                                             "drop the field"):
+            ServeConfig(decode_buckets=(32,), kv_layout="bucketed")
+
+    @pytest.mark.parametrize("kw", [
+        dict(),
+        dict(kv_quant_dtype="int8"),
+        dict(kv_host_tier_bytes=1 << 20),
+        dict(kv_quant_dtype="int8", kv_quant_block=4,
+             kv_host_tier_bytes=1 << 20),
+    ])
+    def test_accepted_with_no_layout_named(self, kw):
+        assert ServeConfig(decode_buckets=(32,), **kw).kv_layout == "paged"
 
 
 # ------------------------------------------------------ layer 13 goldens

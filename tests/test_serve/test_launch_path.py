@@ -75,7 +75,7 @@ def _one_device():
 
 
 def _session(decoder, params, mesh=None, compile_key=None, **kw):
-    base = dict(kv_layout="paged", decode_buckets=(BUCKET,),
+    base = dict(decode_buckets=(BUCKET,),
                 max_decode_slots=SLOTS, prefill_chunk=PAGE,
                 prefill_batch=ROWS, kv_arena_pages=40,
                 enable_prefix_cache=False, speculate_k=0)
@@ -319,20 +319,6 @@ def test_a_held_result_of_other_shapes_falls_back_and_resolves_its_own(
     spans.clear()
     wide.close()
     narrow.close()
-
-
-def test_the_bucketed_layout_takes_the_readbacks_part_alone():
-    decoder, params, _ = _llama()
-    sess = _session(decoder, params, kv_layout="bucketed",
-                    kv_arena_pages=0)
-    spans.clear()
-    results = _serve(sess, _requests()[:1])
-    snap = spans.snapshot()
-    spans.clear()
-    sess.close()
-    assert all(len(ids) == m for (_, ids), (_, m) in zip(results, WAVES[0]))
-    assert {c["attrs"]["h2d"] for c in _calls(snap)} == {0}
-    assert not any(k.startswith("serve_launches") for k in snap["counters"])
 
 
 def test_on_a_mesh_of_four_devices_the_operand_is_a_replicated_input(

@@ -1,8 +1,8 @@
-"""Paged-KV GenerationSession: bitwise greedy parity against the
-bucketed layout and the uncached re-forward loop (prefix cache on/off,
-single-device and tp=2), ONE compiled decode/prefill signature across
-mixed lengths, zero-copy prefix restore, slot/page recycling, fleet
-handoff across layouts, KV gauges, and config validation."""
+"""The session's paged KV pool: bitwise greedy parity against the
+uncached re-forward loop (prefix cache on/off, single-device and tp=2),
+ONE compiled decode/prefill signature across mixed lengths, zero-copy
+prefix restore, slot/page recycling, fleet handoff, KV gauges, and config
+validation."""
 
 import jax
 import jax.numpy as jnp
@@ -39,22 +39,21 @@ def _uncached_greedy(params, cfg, prompt, n_new):
     return out
 
 
-def _config(layout, **kw):
+def _config(**kw):
     kw.setdefault("decode_buckets", (32,))
-    # slot count matches test_generation.py's sessions so the bucketed
-    # arms below reuse the signatures that file already compiled into
-    # the process-wide program memo (a private slot count would re-trace
-    # every bucketed program just for this file)
+    # slot count matches test_generation.py's sessions so the sessions
+    # below reuse the signatures that file already compiled into the
+    # process-wide program memo (a private slot count would re-trace
+    # every program just for this file)
     kw.setdefault("max_decode_slots", 2)
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("prefill_batch", 2)
-    return ServeConfig(kv_layout=layout, **kw)
+    return ServeConfig(**kw)
 
 
-def _run(params, cfg, layout, prompts, n_new=5, mesh=None, factory=None,
-         **kw):
+def _run(params, cfg, prompts, n_new=5, mesh=None, factory=None, **kw):
     factory = factory or GenerationSession.for_gpt
-    sess = factory(params, cfg, config=_config(layout, **kw), mesh=mesh)
+    sess = factory(params, cfg, config=_config(**kw), mesh=mesh)
     futs = [sess.submit(p, max_new_tokens=n_new) for p in prompts]
     sess.run_until_drained()
     return [f.result(timeout=5)["ids"] for f in futs], sess
@@ -67,25 +66,17 @@ MIXED = [[3, 14, 15, 9, 2],                     # shorter than one chunk
 
 
 class TestPagedGreedyParity:
-    def test_paged_matches_bucketed_and_uncached(self, model):
+    def test_paged_matches_uncached(self, model):
         cfg, params = model
-        bucketed, _ = _run(params, cfg, "bucketed", MIXED)
-        paged, _ = _run(params, cfg, "paged", MIXED)
-        assert paged == bucketed
-        # the uncached loop re-jits the full forward at every length, so
-        # anchor the re-forward reference on the two boundary prompts
-        # (shortest; page-crossing) — full-coverage uncached parity is
-        # test_generation.py's and the dryrun's job
-        for i in (2, 3):
-            assert paged[i] == _uncached_greedy(params, cfg, MIXED[i], 5)
+        paged, _ = _run(params, cfg, MIXED)
+        for prompt, ids in zip(MIXED, paged):
+            assert ids == _uncached_greedy(params, cfg, prompt, 5)
 
     def test_prefix_cache_off_parity(self, model):
         cfg, params = model
-        bucketed, _ = _run(params, cfg, "bucketed", MIXED,
-                           enable_prefix_cache=False)
-        paged, _ = _run(params, cfg, "paged", MIXED,
-                        enable_prefix_cache=False)
-        assert paged == bucketed
+        on, _ = _run(params, cfg, MIXED)
+        off, _ = _run(params, cfg, MIXED, enable_prefix_cache=False)
+        assert off == on
 
     def test_shared_prefix_restore_parity(self, model):
         # followers ride the leader's trie pages (zero-copy restore);
@@ -96,13 +87,13 @@ class TestPagedGreedyParity:
         shared = list(range(1, 17))
         prompts = [shared + [20], shared + [21], shared + [22]]
         sess = GenerationSession.for_gpt(params, cfg,
-                                         config=_config("paged"))
+                                         config=_config())
         lead = sess.submit(prompts[0], max_new_tokens=4)
         sess.run_until_drained()
         follow = [sess.submit(p, max_new_tokens=4) for p in prompts[1:]]
         sess.run_until_drained()
         got = [f.result(timeout=5)["ids"] for f in [lead] + follow]
-        control, _ = _run(params, cfg, "paged", prompts, n_new=4,
+        control, _ = _run(params, cfg, prompts, n_new=4,
                           enable_prefix_cache=False)
         assert got == control
         assert sess.metrics.counter("copy_on_restore_bytes_saved") > 0
@@ -110,19 +101,16 @@ class TestPagedGreedyParity:
     def test_tp2_parity(self, model, cpu_devices):
         cfg, params = model
         mesh = make_device_mesh((2,), ("tp",), devices=cpu_devices[:2])
-        single, _ = _run(params, cfg, "paged", MIXED)
-        tp2, _ = _run(params, cfg, "paged", MIXED, mesh=mesh)
+        single, _ = _run(params, cfg, MIXED)
+        tp2, _ = _run(params, cfg, MIXED, mesh=mesh)
         assert tp2 == single
 
     def test_llama_gqa_parity(self, llama_model):
         # GQA paged gather (kv_heads < heads) against the eager
         # re-forward reference on the page-crossing prompt — the one
-        # whose decode round walks more than one page per kv head.  A
-        # second (bucketed) llama session would compile five more
-        # programs for a layout the gpt tests already pin cross-layout;
-        # the reference loop is the stronger oracle
+        # whose decode round walks more than one page per kv head
         cfg, params = llama_model
-        paged, _ = _run(params, cfg, "paged", MIXED,
+        paged, _ = _run(params, cfg, MIXED,
                         factory=GenerationSession.for_llama)
         cur, want = list(MIXED[3]), []
         for _ in range(5):
@@ -137,7 +125,7 @@ class TestSignatureConstancy:
     def test_one_decode_one_prefill_signature(self, model, monkeypatch):
         # arbitrary lengths collapse onto ONE page-granular pool: one
         # compiled decode step and one compiled prefill chunk serve
-        # every mix (vs one pair per bucket in the bucketed layout).
+        # every mix.
         # The signature caches are shared process-wide through the
         # session memo (keyed on model config + mesh), so other tests
         # over the same tiny model would leak their signatures into the
@@ -146,7 +134,7 @@ class TestSignatureConstancy:
 
         monkeypatch.setattr(_gen, "_COMPILED_MEMO", {})
         cfg, params = model
-        _, sess = _run(params, cfg, "paged", MIXED, n_new=6)
+        _, sess = _run(params, cfg, MIXED, n_new=6)
         assert sess.stats()["decode_signatures"]["size"] == 1
         assert sess.stats()["prefill_signatures"]["size"] == 1
         # and they keep serving a second wave of new lengths
@@ -160,14 +148,16 @@ class TestSignatureConstancy:
 
 
 class TestZeroCopyRestore:
-    def test_restore_is_host_side_only(self, model):
-        # the paged restore is a table-mapping operation: the bucketed
-        # restore program (the dynamic_update_slice staging copy) must
-        # never be traced, and no paged program named "restore" exists
+    def test_restore_is_host_side_only(self, model, monkeypatch):
+        # the restore is a table-mapping operation: a session that
+        # restored a prefix has built its chunk and its decode program
+        # and no other (a fresh memo: the programs are shared by name)
+        from easydist_tpu.serve import generation as _gen
+
+        monkeypatch.setattr(_gen, "_COMPILED_MEMO", {})
         cfg, params = model
         sess = GenerationSession.for_gpt(params, cfg,
-                                         config=_config("paged"))
-        before = sess._restore_c.cache_stats()
+                                         config=_config())
         shared = list(range(1, 17))
         a = sess.submit(shared + [20], max_new_tokens=3)
         sess.run_until_drained()
@@ -175,15 +165,14 @@ class TestZeroCopyRestore:
         sess.run_until_drained()
         assert a.result(timeout=5)["finish_reason"] == "length"
         assert b.result(timeout=5)["finish_reason"] == "length"
-        assert sess._restore_c.cache_stats() == before
-        assert "restore" not in sess._paged_cs
-        assert sess._paged_defs is None or \
-            "restore" not in sess._paged_defs
+        assert sess.metrics.counter("copy_on_restore_bytes_saved") > 0
+        assert set(sess._paged_cs) == {"chunk", "decode"}
+        assert "restore" not in sess._paged_defs
 
     def test_saved_bytes_match_restored_pages(self, model):
         cfg, params = model
         sess = GenerationSession.for_gpt(params, cfg,
-                                         config=_config("paged"))
+                                         config=_config())
         shared = list(range(1, 17))           # 2 whole pages of 8
         sess.submit(shared + [20], max_new_tokens=3)
         sess.run_until_drained()
@@ -201,9 +190,9 @@ class TestRecycling:
         rng = np.random.RandomState(0)
         prompts = [rng.randint(0, cfg.vocab, size=3 + i % 7).tolist()
                    for i in range(8)]
-        ids, sess = _run(params, cfg, "paged", prompts, n_new=4)
-        bucketed, _ = _run(params, cfg, "bucketed", prompts, n_new=4)
-        assert ids == bucketed
+        ids, sess = _run(params, cfg, prompts, n_new=4)
+        for prompt, got in zip(prompts, ids):
+            assert got == _uncached_greedy(params, cfg, prompt, 4)
         st = sess.stats()["buckets"][32]
         assert st["active"] == 0 and st["kv_table_mapped"] == 0
         # drained: only trie-held pages remain in use
@@ -215,7 +204,7 @@ class TestRecycling:
     def test_evacuate_releases_pages(self, model):
         cfg, params = model
         sess = GenerationSession.for_gpt(params, cfg,
-                                         config=_config("paged"))
+                                         config=_config())
         futs = [sess.submit(p, max_new_tokens=10) for p in MIXED]
         sess.step()                          # mid-flight
         sess.evacuate()
@@ -227,10 +216,34 @@ class TestRecycling:
             assert st["kv_table_mapped"] == 0
 
 
+class TestCapacityWall:
+    @pytest.mark.parametrize("spec_k", [0, 2])
+    def test_a_sequence_that_reaches_the_cap_retires_bucket_full(
+            self, model, spec_k):
+        # 28 prompt tokens in a cap of 32: the first token and four decoded
+        # ones fill it, whatever `max_new_tokens` asked for — also under
+        # speculation, whose verify window (pos + k + 1) no longer fits
+        # the row near the wall, so it rides the plain round beside the
+        # short row's verify rounds
+        cfg, params = model
+        at_wall, short = ([7, 8, 9] * 10)[:28], [7, 8, 9, 7, 8, 9, 7]
+        sess = GenerationSession.for_gpt(
+            params, cfg, config=_config(speculate_k=spec_k))
+        futs = [sess.submit(p, max_new_tokens=10) for p in (at_wall, short)]
+        sess.run_until_drained()
+        full, fits = (f.result(timeout=5) for f in futs)
+        assert full["finish_reason"] == "bucket_full"
+        assert full["ids"] == _uncached_greedy(params, cfg, at_wall, 5)
+        assert fits["finish_reason"] == "length"
+        assert fits["ids"] == _uncached_greedy(params, cfg, short, 10)
+        assert sess.stats()["buckets"][32]["kv_table_mapped"] == 0
+        assert (sess.metrics.counter("verify_steps") > 0) == bool(spec_k)
+
+
 class TestKvMetrics:
     def test_gauges_surface(self, model):
         cfg, params = model
-        _, sess = _run(params, cfg, "paged", MIXED)
+        _, sess = _run(params, cfg, MIXED)
         snap = sess.metrics.snapshot()
         assert snap["gauges"]["kv_pages_in_use"] >= 0
         assert 0.0 < snap["gauges"]["kv_page_utilization"] <= 1.0
@@ -245,17 +258,13 @@ class TestKvMetrics:
         # every round runs 2 slots of a 4-page bucket
         cfg, params = model
         n_new = 5
-        _, sess = _run(params, cfg, "paged", MIXED, n_new=n_new)
+        _, sess = _run(params, cfg, MIXED, n_new=n_new)
         counters = sess.metrics.snapshot()["counters"]
         assert counters["decode_pages_walked"] == sum(
             (len(p) + r) // 8 + 1 for p in MIXED for r in range(n_new - 1))
         assert counters["decode_pages_bucket"] == \
             counters["decode_steps"] * 2 * 4
         assert counters["tokens_generated"] == len(MIXED) * (n_new - 1)
-        # the contiguous layout has no pages to walk
-        _, sess = _run(params, cfg, "bucketed", MIXED, n_new=n_new)
-        assert "decode_pages_bucket" not in \
-            sess.metrics.snapshot()["counters"]
 
     def test_gauge_tracks_pool_occupancy(self, model):
         # 12 prompt + 4 new = 16 tokens: exactly 2 pages reserved at
@@ -266,7 +275,7 @@ class TestKvMetrics:
         # signature instead of sharing the file's memoized programs
         cfg, params = model
         sess = GenerationSession.for_gpt(
-            params, cfg, config=_config("paged"))
+            params, cfg, config=_config())
         sess.submit(list(range(1, 13)), max_new_tokens=4)
         sess.run_until_drained()
         pool = next(iter(sess._pools.values()))
@@ -275,29 +284,27 @@ class TestKvMetrics:
         assert sess.metrics.snapshot()["gauges"]["kv_pages_in_use"] == 1
 
 
-class TestFleetHandoffAcrossLayouts:
+class TestFleetHandoff:
     SHARED = list(range(1, 17))
 
-    def _leader(self, params, cfg, layout):
+    def _leader(self, params, cfg):
         sess = GenerationSession.for_gpt(params, cfg,
-                                         config=_config(layout),
+                                         config=_config(),
                                          replica_id="lead")
         sess.submit(self.SHARED + [20], max_new_tokens=3)
         sess.run_until_drained()
         return sess
 
-    @pytest.mark.parametrize("src,dst", [("paged", "paged"),
-                                         ("paged", "bucketed"),
-                                         ("bucketed", "paged")])
-    def test_export_import_parity(self, model, src, dst):
-        # paged exports materialize {"page": id} refs into real chunk
-        # arrays, so any layout can import any layout's prefix path
+    def test_export_import_parity(self, model):
+        # exports materialize {"page": id} refs into real chunk arrays,
+        # so a path means the same to an arena that numbers its pages
+        # otherwise
         cfg, params = model
-        lead = self._leader(params, cfg, src)
+        lead = self._leader(params, cfg)
         path = lead.export_prefix_path(self.SHARED + [21])
         assert path and all(set(kv) == {"k", "v"} for _, kv in path)
         dst_sess = GenerationSession.for_gpt(params, cfg,
-                                             config=_config(dst),
+                                             config=_config(),
                                              replica_id="dst")
         assert dst_sess.import_prefix_path(self.SHARED + [21], path) == \
             len(path)
@@ -308,10 +315,10 @@ class TestFleetHandoffAcrossLayouts:
 
     def test_hot_pages_roundtrip(self, model):
         cfg, params = model
-        lead = self._leader(params, cfg, "paged")
+        lead = self._leader(params, cfg)
         hot = lead.export_hot_pages()
         dst = GenerationSession.for_gpt(params, cfg,
-                                        config=_config("paged"),
+                                        config=_config(),
                                         replica_id="dst")
         assert dst.import_hot_pages(hot) > 0
         fut = dst.submit(self.SHARED + [22], max_new_tokens=3)
@@ -329,9 +336,8 @@ class TestConfigValidation:
     def test_page_tokens_must_match_trie_chunk(self):
         with pytest.raises(ValueError, match="kv_page_tokens"):
             ServeConfig(decode_buckets=(32,), prefill_chunk=8,
-                        kv_layout="paged", kv_page_tokens=4)
+                        kv_page_tokens=4)
 
     def test_negative_arena_rejected(self):
         with pytest.raises(ValueError, match="kv_arena_pages"):
-            ServeConfig(decode_buckets=(32,), kv_layout="paged",
-                        kv_arena_pages=-1)
+            ServeConfig(decode_buckets=(32,), kv_arena_pages=-1)

@@ -1,9 +1,8 @@
 """Speculative decoding: the draft/verify/accept path must be a pure
 speed knob — bitwise greedy parity vs plain decode for gpt and llama,
-bucketed and paged layouts, 1-device and tp=2, across mid-stream
-accept/reject boundaries; plus drafter units, verify write locality,
-paged rollback page release, signature closure, knob validation, and
-the speculation metrics."""
+1-device and tp=2, across mid-stream accept/reject boundaries; plus
+drafter units, verify write locality, rollback page release, signature
+closure, knob validation, and the speculation metrics."""
 
 import jax
 import jax.numpy as jnp
@@ -38,10 +37,9 @@ def llama_model():
     return cfg, params
 
 
-def _config(layout="bucketed", spec_k=0, **kw):
+def _config(spec_k=0, **kw):
     base = dict(decode_buckets=(32,), max_decode_slots=2,
-                prefill_chunk=8, prefill_batch=2, kv_layout=layout,
-                speculate_k=spec_k)
+                prefill_chunk=8, prefill_batch=2, speculate_k=spec_k)
     base.update(kw)
     return ServeConfig(**base)
 
@@ -151,8 +149,8 @@ class TestSmallModelDrafter:
 class TestVerifyWriteLocality:
     def test_verify_writes_only_at_pos_window(self, gpt_model):
         """The verify step must leave committed rows (< pos) bitwise
-        untouched — that is what makes the bucketed 'rollback' (cursor
-        not advancing) correct — and only write [pos, pos+k+1)."""
+        untouched — that is what makes a 'rollback' by the cursor's
+        not advancing correct — and only write [pos, pos+k+1)."""
         cfg, params = gpt_model
         k = 3
         rng = np.random.RandomState(0)
@@ -174,7 +172,7 @@ class TestVerifyWriteLocality:
 
 # ------------------------------------------------------- greedy parity
 class TestGreedyParityGPT:
-    def test_bucketed_matches_uncached_and_plain(self, gpt_model):
+    def test_matches_uncached_and_plain(self, gpt_model):
         cfg, params = gpt_model
         plain = _drain(GenerationSession.for_gpt(
             params, cfg, config=_config()), REPETITIVE, 12)
@@ -201,7 +199,7 @@ class TestGreedyParityGPT:
         prompts = [[5, 6] * 6, [9, 3] * 6]
         config = dict(enable_prefix_cache=False)  # no trie-held pages
         plain = _drain(GenerationSession.for_gpt(
-            params, cfg, config=_config("paged", **config)), prompts, 4)
+            params, cfg, config=_config(**config)), prompts, 4)
         wrong = next(t for t in range(cfg.vocab)
                      if all(t not in ids for ids in plain))
 
@@ -213,7 +211,7 @@ class TestGreedyParityGPT:
                 pass
 
         sess = GenerationSession.for_gpt(
-            params, cfg, config=_config("paged", spec_k=4, **config),
+            params, cfg, config=_config(spec_k=4, **config),
             drafter=NeverRight())
         spec = _drain(sess, prompts, 4)
         assert spec == plain
@@ -254,29 +252,15 @@ class TestGreedyParityGPT:
             REPETITIVE[:2], 8)
         assert got == ref
 
-    @pytest.mark.slow
-    def test_paged_tp2_spec_parity(self, gpt_model):
-        cfg, params = gpt_model
-        ref = _drain(GenerationSession.for_gpt(
-            params, cfg, config=_config("paged", spec_k=3)),
-            REPETITIVE[:2], 8)
-        mesh = make_device_mesh((2,), ("tp",), devices=jax.devices()[:2])
-        got = _drain(GenerationSession.for_gpt(
-            params, cfg, config=_config("paged", spec_k=3), mesh=mesh),
-            REPETITIVE[:2], 8)
-        assert got == ref
-
 
 class TestGreedyParityLlama:
-    def test_bucketed_and_paged_match_plain(self, llama_model):
+    def test_matches_plain(self, llama_model):
         cfg, params = llama_model
-        for layout in ("bucketed", "paged"):
-            plain = _drain(GenerationSession.for_llama(
-                params, cfg, config=_config(layout)), REPETITIVE, 10)
-            spec = _drain(GenerationSession.for_llama(
-                params, cfg, config=_config(layout, spec_k=3)),
-                REPETITIVE, 10)
-            assert spec == plain, layout
+        plain = _drain(GenerationSession.for_llama(
+            params, cfg, config=_config()), REPETITIVE, 10)
+        spec = _drain(GenerationSession.for_llama(
+            params, cfg, config=_config(spec_k=3)), REPETITIVE, 10)
+        assert spec == plain
 
     def test_draft_model_drafter_parity(self, llama_model):
         """A second tiny llama as drafter: different weights, different
@@ -326,8 +310,7 @@ class TestSignatureClosure:
     def test_paged_one_verify_signature_total(self, gpt_model):
         cfg, params = gpt_model
         sess = GenerationSession.for_gpt(params, cfg,
-                                         config=_config("paged",
-                                                        spec_k=4))
+                                         config=_config(spec_k=4))
         base = (sess.stats()["verify_signatures"] or {}).get("size", 0)
         _drain(sess, REPETITIVE, 9)
         st = sess.stats()["verify_signatures"]

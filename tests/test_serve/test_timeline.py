@@ -21,13 +21,13 @@ CHUNK, ROWS, CHUNKS_PER_STEP, SLOTS = 4, 2, 2, 3
 PROMPTS = [[3, 14, 15, 9, 2, 6], [5, 3, 5], [8, 9, 7, 9, 3, 2, 3, 8, 4, 6],
            [2, 7, 1, 8, 2, 8, 1, 8, 2, 8, 4], [1, 4, 1, 4, 2]]
 NEW = [5, 3, 7, 4, 6]
-LAYOUTS = {
-    "bucketed": dict(),
-    "paged": dict(kv_layout="paged"),
-    "paged_speculative": dict(kv_layout="paged", speculate_k=2),
+CONFIGS = {
+    "paged": dict(),
+    "paged_int8": dict(kv_quant_dtype="int8"),
+    "paged_speculative": dict(speculate_k=2),
 }
 # per step: the step and its admit; per chunk call a build, a call with the
-# dispatch inside it, and at most one finish per staging row; a decode
+# dispatch inside it, and at most one finish per prefill row; a decode
 # round's build, call with its dispatch, and harvest
 SPANS_PER_STEP_BOUND = 2 + CHUNKS_PER_STEP * (3 + ROWS) + 4
 
@@ -39,23 +39,19 @@ def model():
 
 
 def _one_device():
-    """The mesh every cell's runner serves on.  (On a mesh of several
-    devices each program solves the pool's sharding for itself: the
-    contiguous layout's `_migrate` splits the cache by position where
-    `_decode` keeps it whole, so the cache changes hands between two
-    shardings and each program compiles once for either.)"""
+    """The mesh every cell's runner serves on."""
     return Mesh(np.array(jax.devices()[:1]), ("d",))
 
 
-@pytest.fixture(scope="module", params=sorted(LAYOUTS))
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
 def run(request, model):
-    """One drained session per layout: (layout, results, recorder snapshot,
+    """One drained session per config: (its key, results, recorder snapshot,
     session).  More requests than slots, so some wait in the queue."""
     cfg, params = model
     sc = ServeConfig(decode_buckets=(cfg.seq,), max_decode_slots=SLOTS,
                      prefill_chunk=CHUNK, prefill_batch=ROWS,
                      prefill_chunks_per_step=CHUNKS_PER_STEP,
-                     **LAYOUTS[request.param])
+                     **CONFIGS[request.param])
     sess = GenerationSession.for_gpt(params, cfg, config=sc,
                                      mesh=_one_device())
     spans.clear()
@@ -157,10 +153,9 @@ def test_every_program_call_is_a_call_span_with_its_dispatch_inside(run):
         assert dispatch["name"] == "easydist.step.call"
         assert dispatch["attrs"]["fn"] == call["attrs"]["fn"]
         assert call["attrs"]["rows"] >= 1
-    want = {"bucketed": {"_prefill_chunk", "_decode"},
-            "paged": {"_prefill_chunk_paged", "_decode_paged"},
-            "paged_speculative": {"_prefill_chunk_paged", "_decode_paged",
-                                  "_verify_paged"}}[layout]
+    want = {"_prefill_chunk_paged", "_decode_paged"}
+    if "speculative" in layout:
+        want.add("_verify_paged")
     assert {c["attrs"]["fn"] for c in calls} == want
     _compiled_in_its_first_call_or_not_at_all(snap, calls, want)
 
@@ -200,7 +195,7 @@ def test_a_state_keeping_family_compiles_its_two_programs_once(family):
     sess = GenerationSession(init(cfg, jax.random.PRNGKey(3)),
                              model=module.decoder(cfg), mesh=_one_device(),
                              config=ServeConfig(
-        kv_layout="paged", decode_buckets=(64,), max_decode_slots=4,
+        decode_buckets=(64,), max_decode_slots=4,
         prefill_chunk=8, prefill_batch=2, enable_prefix_cache=False,
         speculate_k=0))
     rng = np.random.default_rng(4)
@@ -226,9 +221,8 @@ def test_a_state_keeping_family_compiles_its_two_programs_once(family):
 @pytest.mark.parametrize("run", ["paged"], indirect=True)
 def test_recorder_calls_per_step_are_bounded(run):
     """A count, not a timing: a step opens at most a constant number of
-    spans, and stamps each live slot once per decode round.  (Stated for
-    the paged layout: the bucketed one adds a dispatch per trie chunk it
-    extracts, speculation a second round per step.)"""
+    spans, and stamps each live slot once per decode round.  (Stated
+    without speculation, which adds a second round per step.)"""
     _, results, snap, _ = run
     by_parent = _children(snap)
     steady = 0
@@ -312,8 +306,7 @@ def two_bursts(model):
     other entry points called between the steps."""
     cfg, params = model
     sc = ServeConfig(decode_buckets=(cfg.seq,), max_decode_slots=SLOTS,
-                     prefill_chunk=CHUNK, prefill_batch=ROWS,
-                     kv_layout="paged")
+                     prefill_chunk=CHUNK, prefill_batch=ROWS)
     sess = GenerationSession.for_gpt(params, cfg, config=sc)
     spans.clear()
     sess.submit(PROMPTS[1], max_new_tokens=3)
@@ -428,19 +421,11 @@ def test_session_programs_have_distinct_stable_names(model):
     jit: every program a session builds gets its function's name."""
     cfg, params = model
     sc = ServeConfig(decode_buckets=(cfg.seq,), max_decode_slots=2,
-                     prefill_chunk=CHUNK, kv_layout="paged", speculate_k=2)
+                     prefill_chunk=CHUNK, speculate_k=2)
     sess = GenerationSession.for_gpt(params, cfg, config=sc)
     names = [sess._paged_defs[k].__name__ for k in sorted(sess._paged_defs)]
     assert names == ["_prefill_chunk_paged", "_decode_paged",
                      "_page_export", "_page_import", "_verify_paged"]
-    flat = GenerationSession.for_gpt(
-        params, cfg, config=ServeConfig(decode_buckets=(cfg.seq,),
-                                        max_decode_slots=2,
-                                        prefill_chunk=CHUNK))
-    names += [c.func.__name__ for c in (
-        flat._prefill_chunk_c, flat._restore_c,
-        flat._migrate_c, flat._decode_c, flat._extract_for(4),
-        flat._extract_for(8))] + [flat._verify_def.__name__]
     assert len(set(names)) == len(names), names
     # and the name reaches the jit (api.py names the module after it:
     # tests/test_runtime/test_spans.py)
