@@ -711,9 +711,14 @@ def _paged_decode_attention_quant_xla(q, k_pages, v_pages, k_scale,
 # pages a window, at 5 live rows and at 32, and 5-30 % slower at ONE, whose
 # copies nothing hides; PERF.md section 6, PR 42) ...
 _PAGED_STEP_TOKENS = 256
-# ... as long as what the window holds in VMEM stays under this: half of the
-# 16 MiB a v5e kernel may scope
+# ... as long as what the window holds in VMEM stays under this: half of
+# what a v5e kernel may scope.  A step's shape is CHOSEN under it; a step
+# that has no smaller shape to take (one KV head, one page) may reckon over
+# it and still run (K-EXAONE's chunk kernel: 2,048 query rows a KV head,
+# 9.75 MiB) ...
 _PAGED_VMEM_BUDGET = 8 * 2 ** 20
+# ... up to the 16 MiB a v5e kernel may scope, over which Mosaic refuses it
+_PAGED_VMEM_LIMIT = 16 * 2 ** 20
 
 
 def _vmem_block_bytes(shape, dtype) -> int:
@@ -758,17 +763,44 @@ def _paged_step_shape(max_pages: int, pages, want: Optional[int] = None,
     contiguous block — unless one such page is over `_PAGED_VMEM_BUDGET`
     (then the largest divisor of kv_heads that fits).  Pages: the largest
     divisor of `max_pages` that is at most `want` (default:
-    `_PAGED_STEP_TOKENS` worth) and fits the budget."""
+    `_PAGED_STEP_TOKENS` worth) and fits the budget.  (1, 1) is what is
+    left where nothing fits the budget; it is refused where it reckons
+    over `_PAGED_VMEM_LIMIT` — the chunk kernel then takes blocks of the
+    group's query heads first (`_query_head_block`)."""
     _, kvh, pt, _ = pages[0].shape
     g = _largest_divisor(
         kvh, lambda g: _paged_step_bytes(pages, g, 1, rows)
         <= _PAGED_VMEM_BUDGET)
+    least = _paged_step_bytes(pages, g, 1, rows)
+    if least > _PAGED_VMEM_LIMIT:
+        raise ValueError(
+            f"one KV head's step of the paged kernels ({rows} query rows, "
+            f"pages of {pt}) reckons to {least} bytes of VMEM, over the "
+            f"{_PAGED_VMEM_LIMIT} a kernel may scope")
     if want is None:
         want = max(_PAGED_STEP_TOKENS // pt, 1)
     n = _largest_divisor(
         max_pages, lambda n: n <= want
         and _paged_step_bytes(pages, g, n, rows) <= _PAGED_VMEM_BUDGET)
     return g, n
+
+
+def _query_head_block(pages, group: int, rows: int) -> int:
+    """The query heads of a KV head's GQA group that one grid step of the
+    chunk kernel holds, `rows` query rows each: the whole group wherever
+    one KV head's step can run with it (`_PAGED_VMEM_LIMIT`: every shape
+    served before a group of 20 came) — a row's pages then leave HBM once a
+    KV head — else the largest divisor of `group` whose step fits
+    `_PAGED_VMEM_BUDGET`, every block walking the row's pages again
+    (`_latent_head_block`'s rule, at the K/V kernel's own bytes: 20 query
+    heads of 256 rows on ONE KV head reckon to 23.25 MiB whole, 6.4 MiB in
+    blocks of 5)."""
+    def step(hb):
+        return _paged_step_bytes(pages, 1, 1, hb * rows)
+
+    if step(group) <= _PAGED_VMEM_LIMIT:
+        return group
+    return _largest_divisor(group, lambda hb: step(hb) <= _PAGED_VMEM_BUDGET)
 
 
 def _row_parts(pages) -> int:
@@ -822,7 +854,8 @@ def _q_map(bi, gi, tbl_ref, len_ref):
 @functools.lru_cache(maxsize=128)
 def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
                 pages_per_step: Optional[int], interpret: bool,
-                chunk: int = 0, out_dim: Optional[int] = None):
+                chunk: int = 0, out_dim: Optional[int] = None,
+                q_blocks: int = 1):
     """The pallas_call the paged kernels share (`name`: `paged_decode`,
     `paged_decode_int8`, `paged_chunk`, `latent_decode` or `latent_chunk`,
     as the trace shows it), built
@@ -853,12 +886,18 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
     trailing dims equal the array's.  The latent kernels differ in two
     things: the pages have one head, which every block of the q view's
     second axis (blocks of query heads, there) attends, and the result is
-    `out_dim` wide, the page's leading columns being its values."""
+    `out_dim` wide, the page's leading columns being its values.  With
+    `q_blocks` > 1 (the chunk kernel, where a group's query rows do not
+    fit a step: `_query_head_block`) the q view's second axis is kv_heads x
+    `q_blocks` blocks of a group's query heads, a step holds ONE KV head,
+    and entry i of that axis attends KV head i // q_blocks."""
     (b, kvh, rows, d), q_dtype = q_view
     avals = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in pages]
     _, page_heads, pt, _ = avals[0].shape
-    g, n_step = _paged_step_shape(max_pages, avals, pages_per_step,
-                                  rows if chunk else 0)
+    g, n_step = _paged_step_shape(
+        max_pages, avals if q_blocks == 1 else [
+            a.update(shape=(a.shape[0], 1) + a.shape[2:]) for a in avals],
+        pages_per_step, rows if chunk else 0)
     parts = _row_parts(avals)
     kw = {} if out_dim is None else {"out_dim": out_dim}
     out_dim = out_dim or d
@@ -875,6 +914,8 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
         + [pltpu.SemaphoreType.DMA((2, n_step)),
            pltpu.SMEM((1,), jnp.int32)],
     )
+    if q_blocks > 1:
+        kw["q_blocks"] = q_blocks
     return pl.pallas_call(
         functools.partial(body, scale=scale, chunk=chunk,
                           widths=tuple(a.shape[-1] for a in avals),
@@ -890,14 +931,15 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
 
 def _paged_attend(body, name: str, scale: float, q_view, pages, table,
                   lengths, pages_per_step: Optional[int], interpret: bool,
-                  chunk: int = 0, out_dim: Optional[int] = None):
+                  chunk: int = 0, out_dim: Optional[int] = None,
+                  q_blocks: int = 1):
     """`_paged_call` at the operands' signature, applied: `q_view` is q as
-    [batch, kv_heads, rows, head_dim], and so is the result (`out_dim`
-    wide, where that is given)."""
+    [batch, kv_heads (x `q_blocks`), rows, head_dim], and so is the result
+    (`out_dim` wide, where that is given)."""
     call = _paged_call(
         body, name, float(scale), (q_view.shape, q_view.dtype.name),
         tuple((a.shape, a.dtype.name) for a in pages), table.shape[1],
-        pages_per_step, bool(interpret), chunk, out_dim)
+        pages_per_step, bool(interpret), chunk, out_dim, q_blocks)
     parts = _row_parts(pages)
     with jax.named_scope(name):
         return call(jnp.asarray(table, jnp.int32),
@@ -922,9 +964,11 @@ def _paged_decode_call(body, name: str, scale: float, q, pages, table,
 def _paged_decode_steps(tbl_ref, len_ref, q_ref, pages, o_ref, o_scr, m_scr,
                         l_scr, slots, sem, first_ref, load_page, *,
                         scale: float, widths, parts: int = 1,
-                        chunk: int = 0):
+                        chunk: int = 0, q_blocks: int = 1):
     """The body the paged kernels share.  Grid step (bi, gi) is row bi and
-    its gi-th group of g KV heads; `pages` are the arena operands where
+    its gi-th group of g KV heads (with `q_blocks` > 1: block gi %
+    q_blocks of the query heads of KV head gi // q_blocks); `pages` are
+    the arena operands where
     they lie (HBM, in whole lanes: `_whole_lanes`), `slots` their VMEM
     scratch [2, n_step, g, page_tokens / parts, *], `sem` a DMA semaphore a
     page of a slot (its operands share it), `widths` each operand's own
@@ -986,7 +1030,9 @@ def _paged_decode_steps(tbl_ref, len_ref, q_ref, pages, o_ref, o_scr, m_scr,
             @pl.when(p * page_tokens < length)
             def _copy():
                 page = jnp.clip(tbl_ref[row, p], 0, n_pages - 1)
-                heads = pl.ds(group * g if page_heads > g else 0, g)
+                first_head = group * g if q_blocks == 1 \
+                    else group // q_blocks
+                heads = pl.ds(first_head if page_heads > g else 0, g)
                 for src, dst in zip(pages, slots):
                     pltpu.make_async_copy(src.at[page, heads],
                                           dst.at[slot, j],
@@ -1113,7 +1159,10 @@ def flash_paged_chunk_attention(q, k_pages, v_pages, table, extents,
     ([group x chunk, head_dim] x [head_dim, page_tokens]), nothing is
     gathered, repeated or copied to float32 outside VMEM.  Grid, walk and
     online softmax are the decode kernel's (`_paged_call`), the KV heads a
-    step holds bounded by what the query rows take in VMEM.
+    step holds bounded by what the query rows take in VMEM; where ONE KV
+    head's group x chunk rows are over what a kernel may scope (20 query
+    heads on one KV head), a step takes a block of the group's query heads
+    (`_query_head_block`) and each block walks the row's pages.
     Returns [rows, heads, chunk, head_dim]."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -1126,10 +1175,12 @@ def flash_paged_chunk_attention(q, k_pages, v_pages, table, extents,
     pad = _chunk_rows(c) - c
     if pad:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    head_block = _query_head_block((k_pages, v_pages), h // kvh, c + pad)
     out = _paged_attend(
         _flash_paged_decode_kernel, "paged_chunk", scale,
-        q.reshape(b, kvh, (h // kvh) * (c + pad), d), (k_pages, v_pages),
-        table, extents, pages_per_step, interpret, chunk=c)
+        q.reshape(b, h // head_block, head_block * (c + pad), d),
+        (k_pages, v_pages), table, extents, pages_per_step, interpret,
+        chunk=c, q_blocks=h // kvh // head_block)
     return out.reshape(b, h, c + pad, d)[:, :, :c]
 
 

@@ -1,4 +1,8 @@
-"""The Mamba-2 (SSD) recurrence of a state layer, in its two serving forms.
+"""The two diagonal state-space recurrences of a state layer, each in its
+two serving forms, and the short conv in front of them.
+
+Mamba-2 (SSD): `A` is a SCALAR a head and B, C are shared by every head
+(one group), so a block of positions is a masked [q, q] product:
 
     S_t = exp(dt_t * A) * S_{t-1} + dt_t * x_t (outer) B_t      S [h, p, n]
     y_t = S_t . C_t + D * x_t
@@ -6,26 +10,43 @@
 `ssd_chunk_scan` runs a window of positions from a state carried in and
 gives the state carried out (chunked prefill); `ssm_decode_update` is the
 same recurrence for one position a sequence (decode), a Pallas kernel on a
-TPU that reads and writes each state once, in place.  `A` is a scalar a
-head and B, C are shared by every head (one group).  A position whose `dt`
-is 0 leaves the state as it was, bit for bit — exp(0) * S + 0 — which is
-how the callers keep padded positions and dead rows out of it.  The state
-is float32 throughout.  `causal_conv_tail` is the short conv with a carried
-tail that sits in front of such a recurrence, shared with the delta rule's
-mixer (`ops/delta_rule.py`).
+TPU that reads and writes each state once, in place.
+
+Mamba-1 (selective): the decay is a different number for every channel d
+AND every state index n, so no matmul form exists and the window is walked
+a position at a time:
+
+    h_t[n, d] = exp(dt_t[d] * A[n, d]) * h_{t-1}[n, d] + dt_t[d] x_t[d] B_t[n]
+    y_t[d]    = sum_n h_t[n, d] C_t[n] + D[d] x_t[d]              h [n, e]
+
+`selective_chunk_scan` is a Pallas kernel on a TPU that keeps a block of
+channels' [n, channels] state in fast memory across all the window's
+positions and writes it once; `selective_decode_update` the one-position
+kernel, in place.  The state is stored with the state index on the
+sublanes and the channels on the lanes ([16, 5120]: nothing padded).
+
+In both, a position whose `dt` is 0 leaves the state as it was, bit for
+bit — exp(0) * S + 0 — which is how the callers keep padded positions and
+dead rows out of it.  The state is float32 throughout.  `causal_conv_tail`
+is the short conv with a carried tail that sits in front of such a
+recurrence, shared with the delta rule's mixer (`ops/delta_rule.py`).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _default_interpret
+from .flash_attention import _default_interpret, _largest_divisor
 
 __all__ = ["causal_conv_tail", "ssd_chunk_scan", "ssm_decode_update",
-           "ssm_decode_update_xla"]
+           "ssm_decode_update_xla", "selective_chunk_scan",
+           "selective_chunk_scan_xla", "selective_decode_update",
+           "selective_decode_update_xla"]
 
 
 def causal_conv_tail(tail, x, w, bias, valid):
@@ -222,3 +243,254 @@ def ssm_decode_update(state, x, dt, a, b_vec, c_vec, d_skip, live=None,
           b_vec.astype(f32)[:, None, :], c_vec.astype(f32)[:, None, :],
           d_skip.astype(f32)[:, None])
     return new, y
+
+
+# ------------------------------------------------ the selective recurrence
+
+
+def selective_chunk_scan_xla(x, dt, a, b_mat, c_mat, d_skip, state):
+    """A window of `s` positions from `state`, a position at a time: x, dt
+    [b, s, e] (float32; dt after softplus, 0 at positions that do not
+    count), a [n, e] (negative), b_mat / c_mat [b, s, n], d_skip [e], state
+    [b, n, e] -> (y [b, s, e], state after the window).  The positions are
+    unrolled, not a `lax.scan`: the solver's discovery EXECUTES a `scan`
+    equation once a candidate sharding, which took a tiny chunk program 340
+    s on the CPU; the unrolled positions are elementwise equations it knows
+    by rule.  This form serves tests and rehearsals, at windows of tens."""
+    ys = []
+    for t in range(x.shape[1]):
+        state, y = selective_decode_update_xla(
+            state, x[:, t], dt[:, t], a, b_mat[:, t], c_mat[:, t], d_skip)
+        ys.append(y)
+    return jnp.stack(ys, axis=1), state
+
+
+# positions a turn of the scan kernel's inner loop takes: one sublane tile
+# of x, dt and y
+_SCAN_POSITIONS = 8
+# positions whose B and C columns a turn of its outer loop loads: one lane
+# tile of the transposed [n, s] operands
+_SCAN_LANES = 128
+
+
+def _channel_block(e: int, most: int = 512) -> int:
+    """Channels a grid step of the selective kernels holds: the largest
+    multiple of 128 lanes that divides `e` and is at most `most` ([16, 512]
+    float32 is 8 vector registers of state, beside as many of `A`); all of
+    them where `e` is no multiple of 128."""
+    if e % 128:
+        return e
+    return 128 * _largest_divisor(e // 128, lambda m: 128 * m <= most)
+
+
+def _selective_scan_kernel(x_ref, dt_ref, a_ref, bt_ref, ct_ref, d_ref, s_ref,
+                           y_ref, s_out, *, lanes: int):
+    """x / dt / y [1, s, eb]; A [n, eb]; B / C transposed [1, n, s]; D [1,
+    eb]; state [1, n, eb].  Grid (rows, channel blocks).  The block's state
+    is the loop's carry — vector registers, for a block of 512 channels —
+    from the first position to the last, and is stored once.  An outer turn
+    loads `lanes` positions' B and C columns (a whole lane tile, or the
+    whole window), an inner one `_SCAN_POSITIONS` rows of x and dt, and
+    stores as many rows of y."""
+    s = x_ref.shape[1]
+    a, d = a_ref[...], d_ref[...]
+
+    def turn(i, h):
+        t0 = pl.multiple_of(i * lanes, lanes)
+        bt = bt_ref[0, :, pl.ds(t0, lanes)]
+        ct = ct_ref[0, :, pl.ds(t0, lanes)]
+        for j0 in range(0, lanes, _SCAN_POSITIONS):
+            at = pl.ds(t0 + j0, _SCAN_POSITIONS)
+            xs, dts = x_ref[0, at, :], dt_ref[0, at, :]
+            ys = []
+            for j in range(_SCAN_POSITIONS):
+                x, dt = xs[j:j + 1], dts[j:j + 1]               # [1, eb]
+                col = slice(j0 + j, j0 + j + 1)                 # [n, 1]
+                h = jnp.exp(dt * a) * h + (dt * x) * bt[:, col]
+                ys.append(jnp.sum(h * ct[:, col], axis=0, keepdims=True)
+                          + d * x)
+            y_ref[0, at, :] = jnp.concatenate(ys, axis=0)
+        return h
+
+    s_out[0] = jax.lax.fori_loop(0, s // lanes, turn, s_ref[0])
+
+
+@functools.lru_cache(maxsize=32)
+def _selective_scan_call(b: int, sp: int, e: int, n: int, interpret: bool):
+    """The scan's `pallas_call`, built ONCE a signature (`ops/
+    flash_attention.py::_paged_call`'s reason: the function it returns is a
+    `jax.jit`, so a model's second layer finds the first's trace — the
+    body unrolls 128 positions, and traced a layer it was 15 s of a
+    26-layer chunk program's start)."""
+    eb = _channel_block(e)
+
+    def row(bi, ei):
+        return (bi, 0, ei)
+
+    def cols(bi, ei):
+        return (bi, 0, 0)
+
+    def chan(bi, ei):
+        return (0, ei)
+
+    return pl.pallas_call(
+        functools.partial(_selective_scan_kernel,
+                          lanes=min(sp, _SCAN_LANES)),
+        grid=(b, e // eb),
+        in_specs=[pl.BlockSpec((1, sp, eb), row),
+                  pl.BlockSpec((1, sp, eb), row),
+                  pl.BlockSpec((n, eb), chan),
+                  pl.BlockSpec((1, n, sp), cols),
+                  pl.BlockSpec((1, n, sp), cols),
+                  pl.BlockSpec((1, eb), chan),
+                  pl.BlockSpec((1, n, eb), row)],
+        out_specs=[pl.BlockSpec((1, sp, eb), row),
+                   pl.BlockSpec((1, n, eb), row)],
+        out_shape=[jax.ShapeDtypeStruct((b, sp, e), jnp.float32),
+                   jax.ShapeDtypeStruct((b, n, e), jnp.float32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="selective_chunk_scan",
+    )
+
+
+def selective_chunk_scan(x, dt, a, b_mat, c_mat, d_skip, state,
+                         interpret=None, backend=None):
+    """`selective_chunk_scan_xla` as one kernel: grid (rows, blocks of
+    channels), a block's [n, channels] state held in fast memory across
+    ALL the window's positions — read once, written once, to the buffer it
+    came from (`input_output_aliases`) — where a `lax.scan` takes it
+    through HBM at every position.  The window is padded to whole tiles of
+    positions with `dt = 0`, which leave the state as it was.  The Pallas
+    kernel on a TPU (or with `backend="pallas"`), the jnp form elsewhere."""
+    if backend is None:
+        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    x, dt, a, b_mat, c_mat, d_skip, state = (
+        v.astype(jnp.float32) for v in (x, dt, a, b_mat, c_mat, d_skip,
+                                        state))
+    if backend == "xla":
+        return selective_chunk_scan_xla(x, dt, a, b_mat, c_mat, d_skip,
+                                        state)
+    if interpret is None:
+        interpret = _default_interpret()
+    b, s, e = x.shape
+    pad = -s % (_SCAN_POSITIONS if s <= _SCAN_LANES else _SCAN_LANES)
+    if pad:
+        x, dt, b_mat, c_mat = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                               for v in (x, dt, b_mat, c_mat))
+    with jax.named_scope("selective_chunk_scan"):
+        y, new = _selective_scan_call(b, s + pad, e, a.shape[0],
+                                      bool(interpret))(
+            x, dt, a, b_mat.swapaxes(1, 2), c_mat.swapaxes(1, 2),
+            d_skip[None], state)
+    return (y[:, :s] if pad else y), new
+
+
+def selective_decode_update_xla(state, x, dt, a, b_vec, c_vec, d_skip):
+    """The recurrence for one position: state [b, n, e], x / dt [b, e], a
+    [n, e], b_vec / c_vec [b, n], d_skip [e] -> (state, y [b, e])."""
+    state = jnp.exp(dt[:, None, :] * a) * state \
+        + (dt * x)[:, None, :] * b_vec[:, :, None]
+    return state, jnp.sum(state * c_vec[:, :, None], axis=1) + d_skip * x
+
+
+def _selective_decode_kernel(row_ref, live_ref, s_ref, x_ref, dt_ref, a_ref,
+                             b_ref, c_ref, d_ref, s_out, y_out):
+    """state [1, n, eb]; x / dt / y [1, 1, eb]; A [n, eb]; B / C [1, n, 1];
+    D [1, eb].  Grid (channel blocks, rows), rows innermost, a dead row
+    standing on a live neighbour's blocks: `_ssm_decode_kernel`'s rule."""
+    i = pl.program_id(1)
+
+    @pl.when(live_ref[i] == 1)
+    def _update():
+        x, dt = x_ref[0], dt_ref[0]                              # [1, eb]
+        new = jnp.exp(dt * a_ref[...]) * s_ref[0] + (dt * x) * b_ref[0]
+        s_out[0] = new
+        y_out[0] = jnp.sum(new * c_ref[0], axis=0, keepdims=True) \
+            + d_ref[...] * x
+
+    @pl.when(live_ref[i] == 0)
+    def _dead():
+        y_out[...] = jnp.zeros_like(y_out)
+
+        @pl.when(i == 0)
+        def _through():
+            s_out[...] = s_ref[...]
+
+
+@functools.lru_cache(maxsize=32)
+def _selective_decode_call(b: int, n: int, e: int, interpret: bool):
+    """The decode update's `pallas_call`, built once a signature, as
+    `_selective_scan_call`."""
+    # a row's whole [n, e] state a step while that is under 1 MiB (Jamba's
+    # [16, 5120] is 320 KiB): fewer, longer copies
+    eb = _channel_block(e, most=max(128, 2 ** 20 // (4 * n)))
+
+    def row(ei, bi, rows, live):
+        return (rows[bi], 0, ei)
+
+    def chan(ei, bi, rows, live):
+        return (0, ei)
+
+    def vec(ei, bi, rows, live):
+        return (rows[bi], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(e // eb, b),
+        in_specs=[pl.BlockSpec((1, n, eb), row),
+                  pl.BlockSpec((1, 1, eb), row),
+                  pl.BlockSpec((1, 1, eb), row),
+                  pl.BlockSpec((n, eb), chan),
+                  pl.BlockSpec((1, n, 1), vec),
+                  pl.BlockSpec((1, n, 1), vec),
+                  pl.BlockSpec((1, eb), chan)],
+        out_specs=[pl.BlockSpec((1, n, eb), row),
+                   pl.BlockSpec((1, 1, eb),
+                                lambda ei, bi, rows, live: (bi, 0, ei))],
+    )
+    return pl.pallas_call(
+        _selective_decode_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, n, e), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, e), jnp.float32)],
+        # operand 2 (after the two prefetched scalars) is the state
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="selective_decode_update",
+    )
+
+
+def selective_decode_update(state, x, dt, a, b_vec, c_vec, d_skip, live=None,
+                            interpret=None, backend=None):
+    """`selective_decode_update_xla` as one pass over the state: each row's
+    [n, channels] block is read once, updated, reduced against C and
+    written back to the buffer it came from (`input_output_aliases`), so a
+    donated state leaf is updated in place.  `live` (bool [b]; None = every
+    row) marks the rows that are sequences: a dead row's state is neither
+    read nor written (`standing_rows`; its `dt` must be 0 all the same: the
+    jnp form relies on it) and its y is 0.  The Pallas kernel on a TPU (or
+    with `backend="pallas"`), the jnp form elsewhere."""
+    if backend is None:
+        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    b, n, e = state.shape
+    if live is None:
+        live = jnp.ones((b,), bool)
+    state, x, dt, a, b_vec, c_vec, d_skip = (
+        v.astype(jnp.float32) for v in (state, x, dt, a, b_vec, c_vec,
+                                        d_skip))
+    if backend == "xla":
+        new, y = selective_decode_update_xla(state, x, dt, a, b_vec, c_vec,
+                                             d_skip)
+        return new, jnp.where(live[:, None], y, 0.0)
+    if interpret is None:
+        interpret = _default_interpret()
+    with jax.named_scope("selective_decode_update"):
+        new, y = _selective_decode_call(b, n, e, bool(interpret))(
+            standing_rows(live), live.astype(jnp.int32), state, x[:, None],
+            dt[:, None], a, b_vec[..., None], c_vec[..., None], d_skip[None])
+    return new, y[:, 0]

@@ -60,12 +60,15 @@ Counters: `xla_compiles{fn=<name>}`; `pallas_calls{kernel=<name>,
 row_shards=<k>}`, one per Pallas kernel call of a program emitted for a mesh
 (jaxfront/api.py::_pallas_row_axes): `k` is the number of shards its rows
 were split into, 1 for a call every device runs whole (the kernels of a
-model with state layers among them: `ssm_decode_update`, `delta_decode_update`;
+model with state layers among them: `ssm_decode_update`, `delta_decode_update`,
+`selective_chunk_scan`, `selective_decode_update`;
 an expert layer's two: `grouped_matmul`, `grouped_matmul_sum`; the latent
 kernels: `latent_decode`, `latent_chunk`).
 Serving counts stay in `ServeMetrics`: the state pool's gauges, a latent
 arena's `latent_cache_bytes`, the delta-rule layers' `delta_state_bytes`,
-`delta_rows_updated` and `delta_chunk_positions`, and the expert FFN's
+`delta_rows_updated` and `delta_chunk_positions`, the selective-state
+layers' `selective_state_bytes`, `selective_rows_updated` and
+`selective_scan_positions`, and the expert FFN's
 `moe_*` counters too,
 which a program sums over its layers on the device and hands back with
 its tokens, under the same `.call` span — of a model stepped with a

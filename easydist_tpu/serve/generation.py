@@ -67,7 +67,7 @@ from easydist_tpu.runtime import spans
 from .admission import ReplicaDrainingError, RequestTooLargeError
 from .batcher import select_bucket
 from .engine import ServeConfig
-from .metrics import ServeMetrics
+from .metrics import RECURRENT_KINDS, ServeMetrics
 from .prefix_cache import PrefixCache
 from .speculate import NGramDrafter, accept_length
 
@@ -1029,8 +1029,9 @@ class GenerationSession:
                 pages_bucket=len(pool.jobs) * pool.max_pages,
                 attn_pairs=sum(n * start + n * (n + 1) // 2
                                for start, n in real),
-                delta_positions=sum(n for _, n in real)
-                * len(pool.arena.get("delta", ())))
+                scan_positions={
+                    kind: sum(n for _, n in real) * len(pool.arena[kind])
+                    for kind in RECURRENT_KINDS if kind in pool.arena})
             if len(first) > pool.n_rows:   # the call's expert counters
                 self.metrics.record_moe(
                     "prefill", *first[pool.n_rows:],
@@ -1186,13 +1187,15 @@ class GenerationSession:
         if "latent" in pool.arena:
             self.metrics.record_latent_cache(
                 sum(int(leaf.nbytes) for leaf in pool.arena["latent"]))
-        if "delta" in pool.arena:
-            # a delta-rule layer's states: a leaf a layer, a matrix a head
-            # a slot, each live row's updated in place by the round
-            leaves = pool.arena["delta"]
-            self.metrics.record_delta_state(
-                sum(int(leaf.nbytes) for leaf in leaves),
-                rows_updated=live_rows * len(leaves))
+        # a recurrent layer's states of either kind: a leaf a layer, a
+        # matrix a slot (a head), each live row's updated in place by the
+        # round
+        for kind in RECURRENT_KINDS:
+            leaves = pool.arena.get(kind, ())
+            if leaves:
+                self.metrics.record_layer_states(
+                    kind, sum(int(leaf.nbytes) for leaf in leaves),
+                    rows_updated=live_rows * len(leaves))
 
     # ------------------------------------------------ speculative decoding
     def _spec_round(self, pool: _PagedPool) -> bool:

@@ -15,6 +15,13 @@ from typing import Dict, Optional
 # log-spaced bucket upper bounds, 0.1ms .. ~107s (x2 per bucket)
 _DEFAULT_BOUNDS = tuple(1e-4 * (2 ** i) for i in range(21))
 
+# A model's recurrent layers by the key of their leaves in a pool, and the
+# counter their chunked scans' real positions are summed under.  Each kind
+# also has a gauge <kind>_state_bytes and a counter <kind>_rows_updated
+# (`record_layer_states`): the next recurrence is one more entry.
+RECURRENT_KINDS = {"delta": "delta_chunk_positions",
+                   "selective": "selective_scan_positions"}
+
 
 class LatencyHistogram:
     """Fixed log-spaced histogram over seconds.  Percentiles resolve to the
@@ -91,6 +98,11 @@ class ServeMetrics:
       offered: rows x top_k x expert layers); per chunk call the same
       under moe_prefill_* (moe_prefill_calls).  Gauges state_slots_in_use / state_slots: the
       recurrent-state pool beside kv_pages_in_use.
+    Selective-state (Mamba-1) layers: gauge selective_state_bytes (the
+      `selective` leaves' bytes: a constant), counters
+      selective_rows_updated (live rows x selective layers, every decode
+      round) and selective_scan_positions (real positions x selective
+      layers, every chunk call).
     Gauges: decode_slot_occupancy (active slots / total slots at the last
       decode step), prefill_padding_ratio (executed token slots per real
       prefill token, 1.0 = zero waste), prefix_cache_hit_rate (fraction
@@ -228,7 +240,8 @@ class ServeMetrics:
                              chunk_s: float, pages_walked: int = 0,
                              pages_bucket: int = 0,
                              attn_pairs: int = 0,
-                             delta_positions: int = 0) -> None:
+                             scan_positions: Optional[Dict[str, int]] = None
+                             ) -> None:
         """One batched chunk call: `n_rows` rows executed `chunk`
         token slots each (idle rows and padded tails included — that IS
         the waste the padding-ratio gauge measures).  The call also
@@ -237,9 +250,10 @@ class ServeMetrics:
         their buckets hold (`pages_bucket`: what the gather path reads),
         and how many (query, visible key) pairs its REAL positions make
         (`attn_pairs`: the attention the model asks of it, a head a
-        layer), and a model with delta-rule layers how many REAL positions
-        its chunked scans took, summed over those layers
-        (`delta_positions`)."""
+        layer), and a model with recurrent layers how many REAL positions
+        its chunked scans took, summed over the layers of each kind
+        (`scan_positions`: kind -> positions, counted under
+        `RECURRENT_KINDS[kind]`)."""
         with self._lock:
             self._counters["prefill_chunks"] = \
                 self._counters.get("prefill_chunks", 0) + 1
@@ -252,10 +266,10 @@ class ServeMetrics:
                     + pages_bucket
                 self._counters["prefill_attn_pairs"] = \
                     self._counters.get("prefill_attn_pairs", 0) + attn_pairs
-            if delta_positions:
-                self._counters["delta_chunk_positions"] = \
-                    self._counters.get("delta_chunk_positions", 0) \
-                    + delta_positions
+            for kind, positions in (scan_positions or {}).items():
+                name = RECURRENT_KINDS[kind]
+                self._counters[name] = \
+                    self._counters.get(name, 0) + positions
             padded = self._counters["prefill_tokens_padded"] = \
                 self._counters.get("prefill_tokens_padded", 0) \
                 + n_rows * chunk
@@ -306,16 +320,19 @@ class ServeMetrics:
         with self._lock:
             self._gauges["latent_cache_bytes"] = nbytes
 
-    def record_delta_state(self, nbytes: int, rows_updated: int) -> None:
-        """The delta-rule layers' states (a model with linear-attention
-        layers): the bytes of ALL the `delta` leaves as the last program
-        handed them back — a constant, whatever the sequences' lengths —
-        and the states this decode round updated in place (live rows x
-        delta-rule layers)."""
+    def record_layer_states(self, kind: str, nbytes: int,
+                            rows_updated: int) -> None:
+        """The states of a model's recurrent layers of one `kind` — "delta"
+        (the delta rule's linear-attention layers) or "selective" (Mamba-1
+        layers), the key of their leaves in the pool: the bytes of ALL
+        those leaves as the last program handed them back (gauge
+        <kind>_state_bytes: a constant, whatever the sequences' lengths)
+        and the states this decode round updated in place (counter
+        <kind>_rows_updated: live rows x layers of that kind)."""
         with self._lock:
-            self._gauges["delta_state_bytes"] = nbytes
-            self._counters["delta_rows_updated"] = \
-                self._counters.get("delta_rows_updated", 0) + rows_updated
+            self._gauges[f"{kind}_state_bytes"] = nbytes
+            self._counters[f"{kind}_rows_updated"] = \
+                self._counters.get(f"{kind}_rows_updated", 0) + rows_updated
 
     def record_moe(self, step: str, pairs_routed: int, experts_hit: int,
                    max_expert_pairs: int, pair_slots: int = 0) -> None:

@@ -686,3 +686,79 @@ def test_delta_rule_decode_step_writes_arena_and_state_in_place_on_tpu(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2, \
         "the state update and the paged decode attention"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_selective_step_writes_arena_and_state_in_place_on_tpu(
+        v5e_chip, monkeypatch, program):
+    """The Jamba2 cell's two programs (BENCHMARK.json: published widths,
+    128 slots, 2,048 pages of 256 tokens, two prefill rows), one selective
+    and one attention layer, with their Pallas kernels compiled by Mosaic
+    for a v5e: the arena's two leaves (ONE KV head of 128 under 20 query
+    heads — the chunk call in blocks of 5 heads' 256 rows, which whole are
+    over what a kernel may scope) and the state's two are donated and
+    handed back through writes in place — the selective leaf (42 MB: [16,
+    5120] float32 a slot, stored as large as it is) by the kernels' own
+    alias — so the temporaries stay under one selective leaf."""
+    import functools
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.models import jamba
+    from easydist_tpu.models.decoder import Paged, State, chunk, decode
+    from easydist_tpu.ops import ssm
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(edconfig, "prefill_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    for name in ("selective_chunk_scan", "selective_decode_update"):
+        monkeypatch.setattr(ssm, name, functools.partial(
+            getattr(ssm, name), backend="pallas", interpret=False))
+    cfg = jamba.JambaConfig(vocab=8192, layers=2, attn_period=2,
+                            attn_offset=1)
+    dec = jamba.decoder(cfg)
+    assert dec.kinds == ("state", "attention")
+    slots, n_pages, pt, max_pages, c_rows = 128, 2048, 256, 16, 2
+
+    def aval(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = _described(v5e_chip, jax.eval_shape(
+        lambda key: jamba.jamba_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = _described(v5e_chip, jax.eval_shape(
+        lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
+
+    def decode_step(cache, params, table, live, token, pos):
+        pages, leaves = State.split(dec, cache)
+        cache, logits = decode(dec, Paged(pages, table), params, token, pos,
+                               state=State(leaves, live))
+        return cache, jnp.argmax(logits, -1)
+
+    def chunk_step(cache, params, table, at, tokens, start, lengths):
+        pages, leaves = State.split(dec, cache)
+        st = State(leaves, at < slots, at, fresh=start == 0)
+        cache, logits = chunk(dec, Paged(pages, table), params, tokens,
+                              start, lengths, state=st)
+        return cache, jnp.argmax(logits, -1)
+
+    if program == "decode":
+        compiled = jax.jit(decode_step, donate_argnums=(0,)).lower(
+            cache, params, aval((slots, max_pages)),
+            aval((slots,), jnp.bool_), aval((slots,)),
+            aval((slots,))).compile()
+    else:
+        compiled = jax.jit(chunk_step, donate_argnums=(0,)).lower(
+            cache, params, aval((c_rows, max_pages)), aval((c_rows,)),
+            aval((c_rows, pt)), aval((c_rows,)), aval((c_rows,))).compile()
+    page_leaf = n_pages * 1 * pt * 128 * 2
+    selective_leaf = slots * 16 * 5120 * 4
+    conv_leaf = slots * 3 * 5120 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes \
+        == 2 * page_leaf + selective_leaf + conv_leaf
+    assert mem.temp_size_in_bytes < selective_leaf, \
+        "a state or arena leaf is copied round its write"
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2, \
+        "the selective kernel and the paged attention kernel"

@@ -300,3 +300,48 @@ def test_a_sessions_greedy_tokens_do_not_know_the_backend(family, monkeypatch):
     assert "paged_chunk" in kernels["paged"]
     assert "paged_chunk" not in kernels["xla"]
     assert ids["paged"] == ids["xla"]
+
+
+# ------------------------------ the step shapes the serving cells are held to
+
+# cell -> ((heads, kv_heads, chunk, page_tokens, max_pages), what the
+# kernels took BEFORE a group's query heads could be taken in blocks (PR 44's
+# tree, computed there): the decode round's (KV heads a step, pages a
+# window), the chunk call's, the query heads of a group a chunk step holds,
+# and what one KV head's chunk step reckons to in MiB)
+PINNED = {
+    "mistral": ((32, 8, 64, 64, 32), (8, 4), (4, 4), 4, 1.0625),
+    "granite": ((32, 8, 256, 256, 16), (8, 1), (1, 1), 4, 5.25),
+    # over the 8 MiB budget, under the 16 MiB a kernel may scope: it has no
+    # smaller shape to take and runs as it is
+    "kexaone": ((64, 8, 256, 256, 32), (8, 1), (1, 1), 8, 9.75),
+    "olmo": ((30, 30, 256, 256, 16), (10, 1), (3, 1), 1, 1.875),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED))
+def test_the_cells_step_shapes_and_query_blocks_are_the_parents(cell):
+    """Blocks of a group's query heads came for 20 heads on ONE KV head
+    (`_query_head_block`); every shape served before keeps the step shape
+    and the WHOLE group it had."""
+    (h, kvh, c, pt, max_pages), decode, chunk, head_block, mib = PINNED[cell]
+    pages = (jax.ShapeDtypeStruct((64, kvh, pt, 128), jnp.bfloat16),) * 2
+    rows = fa._chunk_rows(c)
+    assert fa._paged_step_shape(max_pages, pages) == decode
+    assert fa._query_head_block(pages, h // kvh, rows) == head_block \
+        == h // kvh
+    assert fa._paged_step_shape(max_pages, pages, None,
+                                head_block * rows) == chunk
+    assert fa._paged_step_bytes(pages, 1, 1, (h // kvh) * rows) \
+        == mib * 2 ** 20
+
+
+def test_the_latent_cells_blocks_are_the_parents():
+    """A.X-K1's chunk kernel: blocks of 2 of its 64 query heads against the
+    one shared head (`_latent_head_block`, a rule of its own: another
+    kernel's bytes), windows of 4 pages a decode round."""
+    pages = (jax.ShapeDtypeStruct((2048, 1, 256, 640), jnp.bfloat16),)
+    assert fa._latent_head_block(64, 256, 640, 512, 256, jnp.bfloat16) == 2
+    assert fa._paged_step_shape(64, pages, fa._LATENT_STEP_TOKENS // 256) \
+        == (1, 4)
+    assert fa._paged_step_shape(64, pages, None, 2 * 256) == (1, 1)
