@@ -175,9 +175,11 @@ def expert_ffn(cfg: GraniteHybridConfig, blk, u, valid=None):
 
 def mamba_mixer(cfg: GraniteHybridConfig, blk, u, carry, valid):
     """The Mamba-2 mixer over normed activations u ([b, s, dim] a window,
-    [b, dim] one position) from `carry` = {"conv": [b, d_conv - 1,
-    conv_dim] (the last pre-activation conv inputs), "ssm": [b, heads,
-    head_dim, d_state]}, both float32 -> (out like u, carry after the
+    [b, dim] one position) from `carry` = {"conv": [b, (d_conv - 1) *
+    conv_dim] (the last pre-activation conv inputs, flat: input j is the
+    lanes [j * conv_dim, (j + 1) * conv_dim),
+    `ops/ssm.py::causal_conv_tail`), "ssm": [b, heads, head_dim,
+    d_state]}, both float32 -> (out like u, carry after the
     positions that are `valid` (bool [b, s] / [b]); the others leave the
     carry as it was)."""
     from easydist_tpu.ops.ssm import (causal_conv_tail, ssd_chunk_scan,
@@ -263,6 +265,7 @@ def decoder(cfg: GraniteHybridConfig) -> Decoder:
         kinds=tuple("state" if t == "mamba" else "attention"
                     for t in cfg.layer_types),
         state=state,
-        state_shapes={"conv": ((cfg.d_conv - 1, cfg.conv_dim), jnp.float32),
+        state_shapes={"conv": (((cfg.d_conv - 1) * cfg.conv_dim,),
+                               jnp.float32),
                       "ssm": ((cfg.mamba_heads, cfg.mamba_head_dim,
                                cfg.d_state), jnp.float32)})

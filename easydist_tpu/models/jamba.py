@@ -150,13 +150,14 @@ def _softplus(x):
 
 def selective_mixer(cfg: JambaConfig, blk, u, carry, valid):
     """The Mamba-1 mixer over normed activations u ([b, s, dim] a window,
-    [b, dim] one position) from `carry` = {"conv": [b, d_conv - 1, d_inner]
-    (the last pre-activation conv inputs), "selective": [b, d_state,
-    d_inner]}, both float32 -> (out like u, carry after the positions that
-    are `valid` (bool [b, s] / [b]); the others leave the carry as it
-    was).  The matrix products take `cfg.dtype` operands and sum in
-    float32; the conv, the norms, dt, the decay and the recurrence are
-    float32."""
+    [b, dim] one position) from `carry` = {"conv": [b, (d_conv - 1) *
+    d_inner] (the last pre-activation conv inputs, flat: input j is the
+    lanes [j * d_inner, (j + 1) * d_inner), `ops/ssm.py::causal_conv_tail`),
+    "selective": [b, d_state, d_inner]}, both float32 -> (out like u, carry
+    after the positions that are `valid` (bool [b, s] / [b]); the others
+    leave the carry as it was).  The matrix products take `cfg.dtype`
+    operands and sum in float32; the conv, the norms, dt, the decay and the
+    recurrence are float32."""
     from easydist_tpu.ops.ssm import (causal_conv_tail, selective_chunk_scan,
                                       selective_decode_update)
 
@@ -229,5 +230,5 @@ def decoder(cfg: JambaConfig) -> Decoder:
                     for t in cfg.layer_types),
         state=state,
         state_shapes={
-            "conv": ((cfg.d_conv - 1, cfg.d_inner), jnp.float32),
+            "conv": (((cfg.d_conv - 1) * cfg.d_inner,), jnp.float32),
             "selective": ((cfg.d_state, cfg.d_inner), jnp.float32)})

@@ -158,8 +158,10 @@ def _softplus(x):
 
 def gated_delta_mixer(cfg: OlmoHybridConfig, blk, x, carry, valid):
     """The gated delta-rule mixer over activations x ([b, s, dim] a window,
-    [b, dim] one position) from `carry` = {"conv": [b, conv_kernel - 1,
-    conv_dim] (the last pre-activation conv inputs), "delta": the heads'
+    [b, dim] one position) from `carry` = {"conv": [b, (conv_kernel - 1) *
+    conv_dim] (the last pre-activation conv inputs, flat: input j is the
+    lanes [j * conv_dim, (j + 1) * conv_dim),
+    `ops/ssm.py::causal_conv_tail`), "delta": the heads'
     states as `ops/delta_rule.py` stores them, [b, heads / pack, key_dim,
     pack * value_dim]}, both float32 -> (out like x, carry after the
     positions that are `valid` (bool [b, s] / [b]); the others leave the
@@ -236,6 +238,6 @@ def decoder(cfg: OlmoHybridConfig) -> Decoder:
                     for t in cfg.layer_types),
         state=state,
         state_shapes={
-            "conv": ((cfg.conv_kernel - 1, cfg.conv_dim), jnp.float32),
+            "conv": (((cfg.conv_kernel - 1) * cfg.conv_dim,), jnp.float32),
             "delta": ((cfg.linear_heads // pack, cfg.key_dim,
                        pack * cfg.value_dim), jnp.float32)})

@@ -29,7 +29,9 @@ In both, a position whose `dt` is 0 leaves the state as it was, bit for
 bit — exp(0) * S + 0 — which is how the callers keep padded positions and
 dead rows out of it.  The state is float32 throughout.  `causal_conv_tail`
 is the short conv with a carried tail that sits in front of such a
-recurrence, shared with the delta rule's mixer (`ops/delta_rule.py`).
+recurrence, shared with the delta rule's mixer (`ops/delta_rule.py`); the
+tail is carried FLAT, [b, (taps - 1) * channels], the slots on the sublanes
+and the taps side by side on the lanes.
 """
 
 from __future__ import annotations
@@ -51,24 +53,48 @@ __all__ = ["causal_conv_tail", "ssd_chunk_scan", "ssm_decode_update",
 
 def causal_conv_tail(tail, x, w, bias, valid):
     """The short causal depthwise conv in front of a state layer's
-    recurrence (Mamba-2's, the gated delta rule's), over [carried tail |
-    this window]: tail [b, taps - 1, c] (the last pre-activation inputs of
-    the sequence so far, float32), x [b, s, c] (float32), w [taps, c] (row j
-    multiplies the input taps - 1 - j positions back), bias [c] or None,
-    valid bool [b, s] (a PREFIX of each row counts) -> (silu(conv) [b, s,
-    c], the tail after the positions that count: with none of them, the
-    tail as it was, bit for bit)."""
-    s, taps = x.shape[1], w.shape[0]
-    full = jnp.concatenate([tail, x], axis=1)                # [b, s+taps-1, c]
+    recurrence (Mamba-1's, Mamba-2's, the gated delta rule's), over
+    [carried tail | this window]: tail [b, (taps - 1) * c] (the last
+    pre-activation inputs of the sequence so far, float32, FLAT: input j
+    of the taps - 1 is the lanes [j * c, (j + 1) * c), oldest first), x
+    [b, s, c] (float32), w [taps, c] (row j multiplies the input taps - 1 -
+    j positions back), bias [c] or None, valid bool [b, s] (a PREFIX of
+    each row counts) -> (silu(conv) [b, s, c], the tail after the positions
+    that count: with none of them, the tail as it was, bit for bit).
+
+    Flat because that is how a v5e tiles it with nothing padded — slots on
+    the sublanes, eight to a tile, and where c is whole lane tiles every
+    input a slice of whole tiles: as [b, taps - 1, c] the three rows sat on
+    the sublanes (3 of a tile's 4), and every shift along them cost a
+    relayout of the WHOLE leaf and one back.  One position a row (a decode
+    round, where the tail IS the leaf) is lane slices and a select, no
+    gather; a window takes each new tail row from x or from the old tail,
+    two gathers of taps - 1 rows, and never forms [tail | x] to gather
+    from (the conv's slices of it fuse into the conv)."""
+    b, s, c = x.shape
+    taps = w.shape[0]
     w = w.astype(jnp.float32)
-    conv = sum(full[:, j:j + s] * w[j] for j in range(taps))
+    if s == 1:
+        full = jnp.concatenate([tail, x[:, 0]], axis=1)      # [b, taps * c]
+        conv = sum(full[:, j * c:(j + 1) * c] * w[j] for j in range(taps))
+        new_tail = jnp.where(valid, full[:, c:], tail)
+        conv = conv[:, None]
+    else:
+        rows = tail.reshape(b, taps - 1, c)
+        full = jnp.concatenate([rows, x], axis=1)            # [b, s+taps-1, c]
+        conv = sum(full[:, j:j + s] * w[j] for j in range(taps))
+        # the new tail is full[n_valid : n_valid + taps - 1]
+        n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+        at = (n_valid[:, None] + jnp.arange(taps - 1))[:, :, None]
+        from_x = jnp.take_along_axis(
+            x, jnp.clip(at - (taps - 1), 0, s - 1), axis=1)
+        from_tail = jnp.take_along_axis(
+            rows, jnp.clip(at, 0, taps - 2), axis=1)
+        new_tail = jnp.where(at >= taps - 1, from_x, from_tail).reshape(
+            b, (taps - 1) * c)
     if bias is not None:
         conv = conv + bias.astype(jnp.float32)
-    out = jax.nn.silu(conv)
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
-    new_tail = jnp.take_along_axis(
-        full, (n_valid[:, None] + jnp.arange(taps - 1))[:, :, None], axis=1)
-    return out, new_tail
+    return jax.nn.silu(conv), new_tail
 
 
 def _ssd_block(x, dt, a, b_mat, c_mat, state):

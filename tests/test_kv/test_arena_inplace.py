@@ -762,3 +762,113 @@ def test_selective_step_writes_arena_and_state_in_place_on_tpu(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2, \
         "the selective kernel and the paged attention kernel"
+
+
+# the three cells whose state layers carry a conv tail: (state slots, the
+# conv's channels); four taps in all three
+CONV_TAIL_CELLS = {"jamba2": (128, 5120), "olmo": (40, 11520),
+                   "granite": (64, 8448)}
+_MOVES = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = \(?([a-z]+[0-9]*)\[([0-9,]*)\](\{[^ ]*\})?"
+    r".*? (copy|copy-start|transpose)\(", re.M)
+
+
+def _leaf_sized_moves(hlo_text, elements):
+    """[(opcode, layout)] of every copy or transpose, anywhere in the
+    module, whose (first) result holds `elements` values; the layout
+    without its memory space (`S(1)` is the chip's fast memory)."""
+    return [(m.group(4), re.sub(r"S\(\d+\)", "", m.group(3) or ""))
+            for m in _MOVES.finditer(hlo_text)
+            if np.prod([int(d) for d in m.group(2).split(",") if d])
+            == elements]
+
+
+def _compile_conv_tail(chip, form, conv, leaf_shape, c):
+    """A state layer's conv through `State`, alone, compiled for `chip`
+    with the leaf donated: `round` on the whole leaf (the rows ARE the
+    slots), `chunk` as `State.read` -> conv -> `State.write` for two rows
+    of 256.  fn(leaf, x, w, bias, *ints) -> (leaf, conv out)."""
+    from easydist_tpu.models.decoder import State
+
+    def round_(leaf, x, w, bias, live):
+        st = State({"conv": (leaf,)}, live != 0)
+        out, new = conv(st.read()["conv"], x, w, bias, st.live[:, None])
+        st.write({"conv": new})
+        return st.cache()["conv"][0], out
+
+    def chunk_(leaf, x, w, bias, slots, start, lengths):
+        st = State({"conv": (leaf,)}, slots < leaf.shape[0], slots,
+                   fresh=start == 0)
+        valid = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
+        out, new = conv(st.read()["conv"], x, w, bias, valid)
+        st.write({"conv": new})
+        return st.cache()["conv"][0], out
+
+    def aval(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn, rows, s, n_ints = (round_, leaf_shape[0], 1, 1) if form == "round" \
+        else (chunk_, 2, 256, 3)
+    return jax.jit(fn, donate_argnums=(0,)).lower(
+        aval(leaf_shape, jnp.float32), aval((rows, s, c), jnp.float32),
+        aval((4, c), jnp.float32), aval((c,), jnp.float32),
+        *(aval((rows,)),) * n_ints).compile()
+
+
+@pytest.mark.parametrize("form", ["round", "chunk"])
+@pytest.mark.parametrize("cell", list(CONV_TAIL_CELLS))
+def test_conv_tail_is_shifted_where_it_lies_on_tpu(v5e_chip, cell, form):
+    """A state layer's conv tail at the three cells' sizes, compiled for
+    the v5e: the leaf `[slots, 3 x channels]` float32 — the slots on the
+    sublanes, each of the three carried inputs a run of whole lane tiles —
+    is donated, handed back aliased, and NOTHING lays it out again: no
+    transpose, and no copy of the leaf's size in another layout than the
+    one it came in (`{1,0:T(8,128)}`).  As `[slots, 3, channels]` the three
+    rows tiled `T(4,128)` and every shift or row write along them cost a
+    copy of the whole leaf to `{2,0,1:T(8,128)}` and one back, twice a
+    layer in both serving programs (the test below holds that reading).
+
+    What MAY stay is a copy in the leaf's own layout to or from the fast
+    memory (`S(1)`), in the round alone: a shift along the lanes cannot be
+    written into the buffer it is read from, so XLA reads the leaf from a
+    copy — in a program this small one it keeps in fast memory (no
+    temporary in HBM: `temp_size_in_bytes` stays under a leaf), in the
+    whole decode program one same-layout copy a layer in HBM (PERF.md
+    section 7)."""
+    from easydist_tpu.ops.ssm import causal_conv_tail
+
+    slots, c = CONV_TAIL_CELLS[cell]
+    compiled = _compile_conv_tail(v5e_chip, form, causal_conv_tail,
+                                  (slots, 3 * c), c)
+    text = compiled.as_text()
+    header = text.split("\n", 1)[0]
+    assert re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}, "
+                     r"(?:may|must)-alias\)", header), header
+    leaf_bytes = slots * 3 * c * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes, \
+        "the conv tail's leaf is copied round its write"
+    moves = _leaf_sized_moves(text, slots * 3 * c)
+    assert not [m for m in moves if m[0] == "transpose"], moves
+    assert {layout for _, layout in moves} <= {"{1,0:T(8,128)}"}, \
+        f"the leaf is laid out again: {moves}"
+    if form == "chunk":      # two rows gathered, two rows scattered
+        assert not moves, moves
+    else:                    # at most the one read-from copy
+        assert len(moves) <= 1, moves
+
+
+def test_conv_tail_in_rows_was_laid_out_again_on_tpu(v5e_chip):
+    """The reading the test above guards against, kept so that it is known
+    to SEE it: the body `causal_conv_tail` had with the tail as `[slots, 3,
+    channels]` (frozen in `tests/test_ops/test_conv_tail.py`), the Jamba2
+    cell's round: the v5e's compiler copies the whole leaf into another
+    layout."""
+    from tests.test_ops.test_conv_tail import _frozen
+
+    slots, c = CONV_TAIL_CELLS["jamba2"]
+    text = _compile_conv_tail(v5e_chip, "round", _frozen, (slots, 3, c),
+                              c).as_text()
+    moves = _leaf_sized_moves(text, slots * 3 * c)
+    assert ("copy", "{2,0,1:T(8,128)}") in moves, moves

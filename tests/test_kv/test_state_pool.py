@@ -43,7 +43,7 @@ def test_state_adapter_reads_clips_zeroes_and_drops():
     assert sorted(state) == ["conv", "ssm"]
     assert len(state["ssm"]) == dec.state_layers == 2
     assert state["ssm"][0].shape == (4, 4, 8, 16)
-    assert state["conv"][0].shape == (4, 3, 32 + 2 * 16)
+    assert state["conv"][0].shape == (4, 3 * (32 + 2 * 16))
     state = jax.tree.map(
         lambda x: jnp.arange(x.size, dtype=x.dtype).reshape(x.shape) + 1,
         state)

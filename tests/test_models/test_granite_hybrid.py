@@ -332,33 +332,66 @@ def test_a_session_serves_it_and_the_ids_are_the_references(params):
     sess.close()
 
 
-# taken at the commit before the conv moved to `ops/ssm.py::causal_conv_tail`
-# (which the delta rule's mixer calls too), on the CPU, by jax 0.9.0
-MIXER_AT_THE_PARENT = {"window": "e5bf456767e73e43",
-                       "decode": "28ac03f4c7f95629"}
+# re-recorded where the conv's tail went flat (PR 46: the carry's "conv" is
+# [b, (d_conv - 1) * conv_dim], so the text names another shape and other
+# ops), on the CPU, by jax 0.9.0.  The digests before it (e5bf456767e73e43,
+# 28ac03f4c7f95629) were taken at the commit before the conv moved to
+# `ops/ssm.py::causal_conv_tail`; what carries over from them is below: the
+# mixer's VALUES, bit for bit, against the tail in rows.
+MIXER_SINCE_THE_TAIL_LAY_FLAT = {"window": "7952c6d50e2d3be3",
+                                 "decode": "c82b289422434a25"}
 
 
-@pytest.mark.parametrize("form", sorted(MIXER_AT_THE_PARENT))
-def test_the_mixer_lowers_to_what_it_did_before_the_conv_was_lifted(form):
-    """A migration proof, like `test_lowering_unchanged.py` (which holds the
-    two whole programs): the mixer alone, over a window and over one
-    position, lowers to the text it lowered to with the conv inline."""
+@pytest.mark.parametrize("form", sorted(MIXER_SINCE_THE_TAIL_LAY_FLAT))
+def test_the_mixer_lowers_to_what_it_did_before_the_conv_was_lifted(
+        form, monkeypatch):
+    """A migration proof, like `test_lowering_unchanged.py`: the mixer
+    alone, over a window and over one position, (a) gives the bits it gave
+    with the conv's tail as [b, d_conv - 1, conv_dim] — the body that was
+    in `causal_conv_tail` then is frozen in `tests/test_ops/
+    test_conv_tail.py` and put in its place here, fed the same tail in rows
+    — and (b) lowers to the text recorded when the tail went flat."""
     import hashlib
 
     import jax
+    import numpy as np
+
+    from easydist_tpu.ops import ssm
+    from tests.test_ops.test_conv_tail import _frozen
+
+    cfg = gh.GraniteHybridConfig.tiny()
+    blk = gh.granite_init(cfg, jax.random.PRNGKey(0))["blocks"][0]
+    rng = np.random.default_rng(46)
+    taps = cfg.d_conv - 1
+    carry = {"conv": jnp.asarray(rng.normal(size=(2, taps * cfg.conv_dim)),
+                                 jnp.float32),
+             "ssm": jnp.asarray(rng.normal(size=(
+                 2, cfg.mamba_heads, cfg.mamba_head_dim, cfg.d_state)),
+                 jnp.float32)}
+    if form == "window":
+        u = jnp.asarray(rng.normal(size=(2, 8, cfg.dim)), jnp.float32)
+        valid = jnp.arange(8)[None, :] < jnp.asarray([[8], [5]])
+    else:
+        u = jnp.asarray(rng.normal(size=(2, cfg.dim)), jnp.float32)
+        valid = jnp.asarray([True, False])
+    out, after = gh.mamba_mixer(cfg, blk, u, carry, valid)
+
+    def in_rows(tail, x, w, bias, valid):
+        conv, new = _frozen(tail.reshape(len(tail), taps, -1), x, w, bias,
+                            valid)
+        return conv, new.reshape(tail.shape)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(ssm, "causal_conv_tail", in_rows)
+        want, want_after = gh.mamba_mixer(cfg, blk, u, carry, valid)
+    np.testing.assert_array_equal(out, want)
+    for name in carry:
+        np.testing.assert_array_equal(after[name], want_after[name])
 
     if jax.__version__ != "0.9.0":
         pytest.skip("digests recorded with jax 0.9.0")
-    cfg = gh.GraniteHybridConfig.tiny()
-    blk = gh.granite_init(cfg, jax.random.PRNGKey(0))["blocks"][0]
-    carry = {"conv": jnp.zeros((2, cfg.d_conv - 1, cfg.conv_dim)),
-             "ssm": jnp.zeros((2, cfg.mamba_heads, cfg.mamba_head_dim,
-                               cfg.d_state))}
-    u, valid = (jnp.zeros((2, 8, cfg.dim)), jnp.ones((2, 8), bool)) \
-        if form == "window" else (jnp.zeros((2, cfg.dim)),
-                                  jnp.ones((2,), bool))
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                         (blk, u, carry, valid))
     text = jax.jit(lambda *a: gh.mamba_mixer(cfg, *a)).lower(*args).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == MIXER_AT_THE_PARENT[form]
+        == MIXER_SINCE_THE_TAIL_LAY_FLAT[form]

@@ -100,11 +100,13 @@ def test_the_state_is_stored_with_the_channels_on_the_lanes():
     dec = jamba.decoder(CFG)
     assert dec.kinds == ("state", "state", "attention", "state")
     assert dec.state_shapes["selective"][0] == (16, 64)
-    assert dec.state_shapes["conv"][0] == (3, 64)      # x alone, no B or C
+    # x alone, no B or C; flat, the three inputs side by side on the lanes
+    assert dec.state_shapes["conv"][0] == (3 * 64,)
     full = jamba.decoder(jamba.JambaConfig())
     # [16, 5120]: two sublane tiles by forty lane tiles, nothing padded
     assert full.state_shapes["selective"] == ((16, 5120), jnp.float32)
-    assert full.state_shapes["conv"] == ((3, 5120), jnp.float32)
+    # [3 x 5120]: a slot a sublane, each input forty lane tiles, no padding
+    assert full.state_shapes["conv"] == ((3 * 5120,), jnp.float32)
     assert (full.heads, full.kv_heads, full.head_dim) == (20, 1, 128)
     assert full.kinds.count("state") == 26 and full.kv_layers == 2
     assert [i for i, k in enumerate(full.kinds) if k == "attention"] \
@@ -197,7 +199,7 @@ def test_padded_positions_and_dead_rows_leave_the_carry_bit_identical(
         _kernels(monkeypatch)
     blk = params["blocks"][0]
     rng = np.random.default_rng(3)
-    carry = {"conv": jnp.asarray(rng.normal(size=(3, 3, CFG.d_inner)),
+    carry = {"conv": jnp.asarray(rng.normal(size=(3, 3 * CFG.d_inner)),
                                  jnp.float32),
              "selective": jnp.asarray(rng.normal(size=(3, 16, CFG.d_inner)),
                                       jnp.float32)}

@@ -97,10 +97,11 @@ def test_the_state_is_stored_two_heads_to_a_row_of_whole_lanes():
     dec = oh.decoder(CFG)
     assert dec.kinds == ("state", "state", "attention", "state")
     assert dec.state_shapes["delta"][0] == (2, 8, 128)
-    assert dec.state_shapes["conv"][0] == (3, 4 * (2 * 8 + 64))
+    # flat: the three inputs side by side on the lanes
+    assert dec.state_shapes["conv"][0] == (3 * 4 * (2 * 8 + 64),)
     full = oh.decoder(oh.OlmoHybridConfig())
     assert full.state_shapes["delta"][0] == (15, 96, 384)
-    assert full.state_shapes["conv"][0] == (3, 11520)
+    assert full.state_shapes["conv"][0] == (3 * 11520,)
     assert (full.heads, full.kv_heads, full.head_dim) == (30, 30, 128)
     assert full.kinds.count("state") == 24 and full.kv_layers == 8
 
@@ -157,7 +158,7 @@ def test_padded_positions_and_dead_rows_leave_the_carry_bit_identical(
         params):
     blk = params["blocks"][0]
     rng = np.random.default_rng(3)
-    carry = {"conv": jnp.asarray(rng.normal(size=(3, 3, CFG.conv_dim)),
+    carry = {"conv": jnp.asarray(rng.normal(size=(3, 3 * CFG.conv_dim)),
                                  jnp.float32),
              "delta": jnp.asarray(rng.normal(size=(3, 2, 8, 128)),
                                   jnp.float32)}

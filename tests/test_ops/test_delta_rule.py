@@ -116,16 +116,18 @@ def test_positions_that_do_not_count_leave_the_state_bit_identical():
     _, want = _recurrence(*(x[:, :10] for x in (q, k, v, g, beta)), state)
     np.testing.assert_allclose(_written(part, 2), want, atol=2e-5)
     # and the conv's tail with it
-    tail = jnp.asarray(np.random.default_rng(3).normal(size=(2, 3, 5)), F32)
+    # (flat: the three inputs side by side, oldest first)
+    tail = jnp.asarray(np.random.default_rng(3).normal(size=(2, 3 * 5)), F32)
     x = jnp.ones((2, 6, 5), F32)
     _, kept = causal_conv_tail(tail, x, jnp.ones((4, 5), F32), None,
                                jnp.zeros((2, 6), bool))
+    assert kept.shape == (2, 3 * 5)
     np.testing.assert_array_equal(kept, tail)
     _, moved = causal_conv_tail(tail, x, jnp.ones((4, 5), F32), None,
                                 jnp.arange(6)[None, :] < jnp.array([[2], [6]]))
     np.testing.assert_array_equal(moved[0], jnp.concatenate(
-        [tail[0, 2:], x[0, :2]]))
-    np.testing.assert_array_equal(moved[1], x[1, 3:])
+        [tail[0, 2 * 5:], x[0, :2].reshape(-1)]))
+    np.testing.assert_array_equal(moved[1], x[1, 3:].reshape(-1))
 
 
 @pytest.mark.parametrize("d_v", [16, 64])
