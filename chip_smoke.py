@@ -14,7 +14,8 @@ checks every result against a reference computed beside it.  Phases, in order:
   probe    the five paged kernels timed ALONE, each at its benchmark cell's
            shapes and live share (microseconds a call: notes, not claims);
            the expert FFN's fused second product and sum beside the three
-           ops it replaced, at the three expert cells' shapes
+           ops it replaced, at the three expert cells' shapes; the three
+           flash training kernels at the train cell's call on one chip
   trainer  `make_gpt_train_step` + `easydist_compile` over all local chips,
            state threaded and donated; loss trajectory against a plain
            `jax.jit` of the einsum-attention step
@@ -39,6 +40,7 @@ import functools
 import importlib
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -360,6 +362,82 @@ def phase_paged_probe(cases=None, iters=200, interpret=False):
         out[name] = round((time.perf_counter() - t0) / iters * 1e6, 2)
         del pages, args
     log("paged kernels alone, us a call: "
+        + ", ".join(f"{k} {v}" for k, v in out.items()))
+    return out
+
+
+# The three flash training kernels at the train cell's own call on one chip
+# (BENCHMARK.json's train-gpt2xl-4chip: 100 rows of batch x heads over four
+# chips), at the whole call every chip ran before PR 30 sharded the rows, and
+# at two shapes beside them: rows, positions, head_dim, operands' dtype,
+# causal.
+FLASH_TRAIN_CASES = {
+    "gpt2xl.cell": (25, 1024, 64, "bfloat16", True),
+    "gpt2xl.cell.f32": (25, 1024, 64, "float32", True),
+    "gpt2xl.whole": (100, 1024, 64, "bfloat16", True),  # before PR 30
+    "d128.t1024": (16, 1024, 128, "bfloat16", True),
+    "d128.t8192.streamed": (4, 8192, 128, "bfloat16", True),
+}
+
+
+def phase_flash_train_probe(cases=None, iters=20, fa=None, blocks=(256, 256)):
+    """Microseconds a call of each flash training kernel ALONE — forward,
+    dq, dk/dv, each a program of its own (the backward's other kernel is
+    dead code in it) — read off the device trace of `iters` calls: the
+    mean duration of the program's Mosaic op.  `fa`: the module that holds
+    the kernels (a builder's way to probe another commit's beside this
+    one's); `blocks`: the (block_q, block_k) asked for."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import trace_reduce
+
+    fa = fa or importlib.import_module("easydist_tpu.ops.flash_attention")
+    trace_dir = os.path.join(_OUT, "flash_probe_trace")
+    pallas = re.compile(trace_reduce.PALLAS_KERNEL)
+    out = {}
+    for name in cases or FLASH_TRAIN_CASES:
+        rows, t, d, dtype, causal = FLASH_TRAIN_CASES[name]
+        scale = 1.0 / float(d) ** 0.5
+        q, k, v, g = (jax.random.normal(
+            jax.random.fold_in(jax.random.PRNGKey(len(name)), i),
+            (1, rows, t, d), jnp.dtype(dtype)) for i in range(4))
+
+        def fwd(q, k, v):
+            return fa._flash_forward(q, k, v, causal, scale, *blocks, False)
+
+        def bwd(q, k, v, o, lse, g):
+            return fa._flash_backward(q, k, v, o, lse, g, causal, scale,
+                                      *blocks, False)
+
+        o, lse = jax.jit(fwd)(q, k, v)
+        programs = {
+            "fwd": (jax.jit(fwd), (q, k, v)),
+            "dq": (jax.jit(lambda *a: bwd(*a)[0]), (q, k, v, o, lse, g)),
+            "dkv": (jax.jit(lambda *a: bwd(*a)[1:]), (q, k, v, o, lse, g)),
+        }
+        for fn, args in programs.values():
+            jax.block_until_ready(fn(*args))
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(trace_dir)
+        for fn, args in programs.values():
+            for _ in range(iters):
+                jax.block_until_ready(fn(*args))
+        jax.profiler.stop_trace()
+        trace = trace_reduce.load_xplane(trace_reduce.find_xplane(trace_dir))
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        calls = sorted((s, dur) for plane in trace_reduce.device_planes(trace)
+                       for n, s, dur in trace_reduce.op_events(plane)
+                       if pallas.search(n))
+        if len(calls) != len(programs) * iters:
+            raise RuntimeError(
+                f"{name}: {len(calls)} Mosaic ops in the trace of "
+                f"{len(programs)} x {iters} calls")
+        for i, which in enumerate(programs):
+            durs = [dur for _, dur in calls[i * iters:(i + 1) * iters]]
+            out[f"{name}.{which}"] = round(sum(durs) / iters / 1e3, 2)
+        del programs, q, k, v, g, o, lse
+    log("flash training kernels alone, us a call: "
         + ", ".join(f"{k} {v}" for k, v in out.items()))
     return out
 
@@ -871,6 +949,7 @@ def main() -> int:
     notes["kernels"] = phase_kernels()
     notes["paged_probe_us"] = phase_paged_probe()
     notes["expert_probe_us"] = phase_expert_probe()
+    notes["flash_train_probe_us"] = phase_flash_train_probe()
     notes["trainer"] = phase_trainer()
     params = gpt_init(GPTConfig(**GPT2_SMALL), jax.random.PRNGKey(0))
     for layout in ("paged", "paged_int8"):

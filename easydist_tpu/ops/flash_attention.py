@@ -1,20 +1,60 @@
 """Flash attention as Pallas TPU kernels (forward AND fused backward).
 
-Blockwise online-softmax attention with **streamed K/V**: the K/V (and in
-the dK/dV pass, Q/dO) blocks ride the innermost grid dimension, so VMEM
-residency per program is O(block) — independent of sequence length — and
-long-context (8k-32k) sequences fit the ~16 MB VMEM budget.  Statistics
-(running max / denominator) and the output accumulator persist in f32 VMEM
-scratch across the innermost grid steps (TPU grids iterate sequentially, so
-scratch carries between iterations; ``@pl.when(ki == 0)`` initialises,
-``@pl.when(ki == last)`` writes out).
+Blockwise online-softmax attention.  A grid step holds blocks of its OWN
+side — Q blocks in the forward and dQ passes, K blocks in the dK/dV pass —
+and walks the other side's blocks in loops of the body's own (`_walk`):
+
+* **resident** — where a row's whole other side (K and V; in dK/dV its Q,
+  dO, lse and delta) fits in VMEM beside the step's own blocks
+  (`_step_shape`: the VMEM layout's bytes, double-buffered, against
+  `_TRAIN_VMEM_BUDGET`) the grid's third axis has ONE step, the other side
+  is fetched once a row, a step holds up to `_OWN_BLOCKS` own blocks, and
+  each walks the blocks that hold something for it: up to the diagonal
+  when causal, so no step is taken for a block that is masked out.  Where
+  one grid step holds the row on both sides (the train cell: 4 x 4 blocks
+  of 256) the walks' bounds are static and a row's ten block steps are
+  unrolled, so the compiler schedules one step's products behind the
+  next one's; where the grid position enters the bounds they are loops.
+* **streamed** — where it does not fit (the 8k-32k contexts, ring
+  attention's long blocks) a step holds one own block and the other side
+  rides the grid's third axis, as many blocks a step as the budget takes
+  (at least one): the walk covers the step's blocks up to the diagonal
+  (none of a step wholly above it, which is not fetched either), and VMEM
+  residency per program is bounded by the budget, not by the sequence
+  length.  Statistics and accumulators persist in f32 VMEM scratch across
+  those grid steps (TPU grids iterate sequentially; ``@pl.when(ki == 0)``
+  initialises, ``@pl.when(ki == last)`` writes out).
+
+The same body serves both; the shapes decide the walks' lengths.  The
+causal mask is built only on the blocks the diagonal crosses; the blocks
+under it take no iota, compare or select.
+
+The MXU gets its operands in the dtype they were stored in and
+accumulates in f32 (`_mxu`: the contraction is named, nothing is
+transposed by hand).  Scores, running max and denominator, lse, delta and
+the accumulators are f32; `p` and `ds` are rounded to the operands' dtype
+for the products that consume them.  With f32 operands that is the
+arithmetic the kernels always had.
+
+All three kernels keep the scores [bk, bq], keys down the sublanes and
+queries along the lanes: a query's max, denominator, lse and delta are a
+lane each (a [1, bq] row: 2 vregs a 256-block, where [bq, 1] took 32 and a
+lane broadcast a use), the reductions over keys run down the sublanes, and
+``P^T`` / ``dS^T`` come off the VPU in the layout the next product wants.
+The forward accumulates o^T [d, bq] and dQ accumulates dq^T, each turned
+once a Q block at the write.  lse and delta travel as [rows, t / bq, 1,
+bq]: dense in HBM, and a legal block whatever bq is.
 
 The backward is the FlashAttention-2 recipe: the forward saves the per-row
 logsumexp, ``delta = rowsum(dO * O)`` is precomputed in XLA, then two
-kernels stream blocks — dQ accumulates over K-blocks, dK/dV accumulate over
-Q-blocks — recomputing ``P = exp(S - lse)`` per block.  No [T, T] residual
+kernels walk blocks — dQ accumulates over K blocks, dK/dV accumulate over
+Q blocks — recomputing ``P = exp(S - lse)`` per block.  No [T, T] residual
 survives the forward.  The ring variant composes this kernel with the
 ppermute loop in parallel/ring_attention.py.
+
+Each `pallas_call` is built once a signature and kept (`_forward_call`,
+`_backward_calls`), as the paged kernels' is: a model's layers share one
+traced body and one Mosaic lowering.
 
 Reference scenario: the reference relies on torch SDPA/cutlass kernels
 (benchmark/torch/model/gpt.py attention); this is the TPU-native analog.
@@ -32,6 +72,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# What the blocks, scratch and block-step temporaries of a training kernel
+# may take in VMEM for a row's other side to be held whole (`_resident`):
+# half of the 16 MiB a v5e kernel may scope
+_TRAIN_VMEM_BUDGET = 8 * 2 ** 20
+# Blocks of its OWN side a grid step holds beside a resident other side,
+# at most: each is a copy of the block step's code in the body
+_OWN_BLOCKS = 4
+# A walk whose bounds are static is unrolled up to this many trips
+_UNROLL_TRIPS = 4
 
 
 def _default_interpret() -> bool:
@@ -47,40 +96,123 @@ def _pick_block(block: int, t: int) -> int:
     return max(b, 1)
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
-    bq, bk = s.shape
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(k_pos <= q_pos, s, _NEG_INF)
+def _mxu(a, b, contract):
+    """The product of 2-D `a` and `b` over axis `contract[0]` of a and
+    `contract[1]` of b, f32: the operands go to the MXU in the dtype they
+    share (bf16 products are exact in the f32 accumulator), in f32 where
+    they differ, and the contraction is named, so neither is transposed by
+    hand."""
+    if a.dtype != b.dtype:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jax.lax.dot_general(a, b, (((contract[0],), (contract[1],)),
+                                      ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
-def _kv_index_map(causal, block_q, block_k):
-    """K/V block index for grid (bh, qi, ki).  Causal: steps strictly above
-    the diagonal clamp to the diagonal block — Pallas skips the DMA when
-    the mapped index repeats, so fully-masked blocks cost no HBM traffic
-    (the kernel's @pl.when already skips their compute)."""
+_dot = functools.partial(_mxu, contract=(1, 0))     # a . b:   [m,n] x [n,d]
+_dot_nt = functools.partial(_mxu, contract=(1, 1))  # a . b^T: [m,d] x [n,d]
+_dot_tn = functools.partial(_mxu, contract=(0, 0))  # a^T . b: [n,d] x [n,m]
+
+
+def _prescale(x, scale: float):
+    """(x for the scores' product, what multiplies the f32 scores).  The
+    operand takes the scale itself where that is exact — f32, as the
+    scores would, or a power of two (a head of 64: 1/8), which moves no
+    mantissa bit of a narrower dtype — once a block; else it goes to the
+    MXU as stored and the scale multiplies the f32 scores."""
+    if x.dtype == jnp.float32 or math.frexp(scale)[0] == 0.5:
+        return x * scale, None
+    return x, scale
+
+
+def _scaled(s, s_scale):
+    return s if s_scale is None else s * s_scale
+
+
+def _causal_mask(s, q0, k0):
+    """Scores [keys k0.., queries q0..] with the keys after each query at
+    -inf."""
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(k_pos - q_pos <= q0 - k0, s, _NEG_INF)
+
+
+def _clip(x, lo: int, hi: int):
+    """x held to [lo, hi]; a Python int stays one (a static loop bound)."""
+    return max(lo, min(hi, x)) if isinstance(x, int) else jnp.clip(x, lo, hi)
+
+
+def _k_walk(causal: bool, qi, first, sub: int, block_q: int, block_k: int):
+    """The K blocks Q block `qi` walks of the `sub` a grid step holds from
+    block `first` on, as `_walk` takes them: those wholly under the
+    diagonal unmasked, then those it crosses masked; none above it."""
     if not causal:
-        return lambda bh, qi, ki: (bh, ki, 0)
-    return lambda bh, qi, ki: (
-        bh, jnp.minimum(ki, ((qi + 1) * block_q - 1) // block_k), 0)
+        return ((0, sub, False),)
+    under = _clip((qi * block_q + 1) // block_k - first, 0, sub)
+    live = _clip(((qi + 1) * block_q + block_k - 1) // block_k - first, 0,
+                 sub)
+    return ((0, under, False), (under, live, True))
 
 
-def _q_index_map(causal, block_q, block_k):
-    """Q-side block index for grid (bh, ki, qb) (dK/dV pass).  Causal: Q
-    blocks strictly above the K block's first row are fully masked — clamp
-    to the first contributing block so leading masked steps re-use one
-    fetch."""
+def _q_walk(causal: bool, ki, first, sub: int, block_q: int, block_k: int):
+    """The Q blocks K block `ki` walks of the `sub` a grid step holds from
+    block `first` on: those the diagonal crosses masked, then those
+    wholly under it unmasked; none above it."""
     if not causal:
-        return lambda bh, ki, qb: (bh, qb, 0)
-    return lambda bh, ki, qb: (
-        bh, jnp.maximum(qb, (ki * block_k) // block_q), 0)
+        return ((0, sub, False),)
+    live = _clip((ki * block_k) // block_q - first, 0, sub)
+    under = _clip(((ki + 1) * block_k - 1 + block_q - 1) // block_q - first,
+                  0, sub)
+    return ((live, under, True), (under, sub, False))
+
+
+def _walk(step, spans):
+    """`step(j, masked)` for every j of each (lo, hi, masked) span in
+    turn.  Bounds the grid position enters make a loop of the body's own;
+    static ones (a row whose blocks one grid step holds on both sides)
+    are unrolled up to `_UNROLL_TRIPS`, so that the compiler schedules
+    one block step's products behind the next one's."""
+    for lo, hi, masked in spans:
+        if isinstance(lo, int) and isinstance(hi, int) \
+                and hi - lo <= _UNROLL_TRIPS:
+            for j in range(lo, hi):
+                step(j, masked)
+        else:
+            jax.lax.fori_loop(
+                lo, hi, lambda j, c, masked=masked: step(j, masked) or c, 0)
+
+
+def _rows_of(j, block: int):
+    if isinstance(j, int):
+        return pl.ds(j * block, block)
+    return pl.ds(pl.multiple_of(j * block, block), block)
+
+
+def _first_block(axis: int, n_steps: int, blocks: int):
+    """The first block of the `blocks` that the step at this position of
+    grid `axis` holds: 0, statically, where the axis has one step."""
+    return 0 if n_steps == 1 else pl.program_id(axis) * blocks
+
+
+def _other_side_map(causal: bool, resident: bool, first_live):
+    """Index map of the side a grid step walks, for grid (row, own step,
+    other side's step).  Resident: the row's one block, whatever the
+    position.  Streamed and causal: steps that hold nothing clamp to the
+    nearest block that does (`first_live(own, other)`) — Pallas skips the
+    DMA when the mapped index repeats, so blocks that are masked out cost
+    no HBM traffic (and the body's loop takes no trip for them)."""
+    if resident:
+        return lambda bh, own, other: (bh, 0, 0)
+    if not causal:
+        return lambda bh, own, other: (bh, other, 0)
+    return lambda bh, own, other: (bh, first_live(own, other), 0)
 
 
 def _flash_cost(arrays, n_q, n_k, bq, bk, causal, matmuls):
     """`pl.CostEstimate` of one training call over `arrays` (its operands
     and results, `[b*h, t, d]` first): what the kernel executes, advisory
     to XLA and read by the auto-solver's cost model (jaxfront/bridge.py).
-    Every (Q block, K block) pair the grid runs — causal: those not
+    Every (Q block, K block) pair the walks visit — causal: those not
     strictly above the diagonal — does `matmuls` products of 2 * bq * bk *
     d FLOPs and one exp per score; every array crosses HBM once."""
     bh, _, d = arrays[0].shape
@@ -93,14 +225,82 @@ def _flash_cost(arrays, n_q, n_k, bq, bk, causal, matmuls):
                            for a in arrays))
 
 
+def _train_vmem_bytes(kernel: str, bq: int, bk: int, held: int, d: int,
+                      dtype, own: int = 1) -> int:
+    """VMEM a grid step of training kernel `kernel` takes with `own`
+    blocks of its own side and `held` positions of the side it walks (one
+    block of it streamed, the row's whole extent resident): its blocks in
+    their VMEM layout, double-buffered as Pallas keeps them, the f32
+    scratch, and the f32 [block, block] temporaries of one block step."""
+    f32 = jnp.float32
+
+    def blk(rows, cols=d, dt=dtype):
+        return _vmem_block_bytes((rows, cols), dt)
+
+    if kernel == "flash_fwd":       # q, o, lse | k, v | acc, m, l | s, p
+        mine = 2 * blk(bq) + blk(1, bq, f32)
+        blocks = 2 * blk(held)
+        scratch = blk(d, bq, f32) + 2 * blk(1, bq, f32)
+        work = 2 * blk(bk, bq, f32)
+    elif kernel == "flash_bwd_dq":  # q, do, dq, lse, delta | k, v | acc
+        mine = 3 * blk(bq) + 2 * blk(1, bq, f32)
+        blocks = 2 * blk(held)
+        scratch = blk(d, bq, f32)
+        work = 4 * blk(bk, bq, f32)
+    else:                           # k, v, dk, dv | q, do, lse, delta | 2 acc
+        mine = 4 * blk(bk)
+        blocks = 2 * blk(held) + 2 * (held // bq) * blk(1, bq, f32)
+        scratch = 2 * blk(bk, d, f32)
+        work = 4 * blk(bk, bq, f32)
+    return 2 * (own * mine + blocks) + own * scratch + work
+
+
+def _step_shape(kernel: str, bq: int, bk: int, t_q: int, t_k: int, d: int,
+                dtype):
+    """(blocks of its own side, positions of the side it walks) that a
+    grid step of `kernel` holds, by what reckons under
+    `_TRAIN_VMEM_BUDGET`.  Resident: the walked side's whole extent beside
+    the largest divisor of the own side's blocks up to `_OWN_BLOCKS`.
+    Else streamed: one own block, and as many blocks of the walked side as
+    fit (a divisor of its blocks, at least one) — a long row takes fewer,
+    longer grid steps, and fewer of them above the diagonal."""
+    (t, block), n_own = ((t_q, bq), t_k // bk) \
+        if kernel == "flash_bwd_dkv" else ((t_k, bk), t_q // bq)
+
+    def fits(own, blocks):
+        return _train_vmem_bytes(kernel, bq, bk, blocks * block, d, dtype,
+                                 own) <= _TRAIN_VMEM_BUDGET
+
+    own = _largest_divisor(n_own, lambda m: m <= _OWN_BLOCKS)
+    if fits(own, t // block):
+        return own, t
+    return 1, block * _largest_divisor(t // block, lambda m: fits(1, m))
+
+
+def _count_call(kernel: str, resident: bool, dtype) -> None:
+    """`flash_train_calls{kernel, kv, operands}`, once per traced call."""
+    from easydist_tpu.runtime import spans
+
+    spans.count("flash_train_calls", kernel=kernel,
+                kv="resident" if resident else "streamed",
+                operands=jnp.dtype(dtype).name)
+
+
 # ---------------------------------------------------------------- forward
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_scr, m_scr, l_scr,
                   *, causal: bool, scale: float, block_q: int, block_k: int,
-                  n_k: int):
-    qi = pl.program_id(1)
+                  n_qs: int, n_kv: int):
+    """The scores lie [bk, bq], keys down the sublanes: a query's running
+    max and denominator are a lane each ([1, bq]: 2 vregs a 256-block
+    where [bq, 1] takes 32), the reductions over keys run down the
+    sublanes, and the accumulator is o^T [d, bq], turned once a Q block."""
     ki = pl.program_id(2)
+    own = q_ref.shape[1] // block_q         # Q blocks this step holds
+    sub = k_ref.shape[1] // block_k         # K blocks this step holds
+    first_q = _first_block(1, n_qs, own)
+    first_k = _first_block(2, n_kv, sub)
 
     @pl.when(ki == 0)
     def _init():
@@ -108,78 +308,124 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_scr, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         o_scr[...] = jnp.zeros_like(o_scr)
 
-    # a K block strictly above the diagonal contributes nothing
-    run = (ki * block_k <= (qi + 1) * block_q - 1) if causal else (ki >= 0)
+    for i in range(own):
+        q, s_scale = _prescale(q_ref[0, pl.ds(i * block_q, block_q), :],
+                               scale)                   # [bq, d]
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale        # [bq, d]
-        k_blk = k_ref[0].astype(jnp.float32)            # [bk, d]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = q @ k_blk.T                                 # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        m_prev = m_scr[...]                             # [bq, 1]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)                 # [bq, 1]
-        m_scr[...] = m_new
-        l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_scr[...] = o_scr[...] * alpha + p @ v_blk
+        def step(j, masked, i=i, q=q, s_scale=s_scale):
+            rows = _rows_of(j, block_k)
+            k_blk = k_ref[0, rows, :]                   # [bk, d]
+            v_blk = v_ref[0, rows, :]
+            s = _scaled(_dot_nt(k_blk, q), s_scale)     # [bk, bq]
+            if masked:
+                s = _causal_mask(s, (first_q + i) * block_q,
+                                 (first_k + j) * block_k)
+            m_prev = m_scr[i]                           # [1, bq]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)             # [1, bq]
+            m_scr[i] = m_new
+            l_scr[i] = l_scr[i] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            o_scr[i] = o_scr[i] * alpha + _dot_tn(v_blk,
+                                                  p.astype(v_blk.dtype))
 
-    @pl.when(ki == n_k - 1)
+        _walk(step, _k_walk(causal, first_q + i, first_k, sub, block_q,
+                            block_k))
+
+    @pl.when(ki == n_kv - 1)
     def _write():
-        l_safe = jnp.maximum(l_scr[...], 1e-30)         # [bq, 1]
-        o_ref[0] = (o_scr[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(l_safe)).astype(jnp.float32)
+        for i in range(own):
+            l_safe = jnp.maximum(l_scr[i], 1e-30)       # [1, bq]
+            o_ref[0, pl.ds(i * block_q, block_q), :] = \
+                (o_scr[i] / l_safe).T.astype(o_ref.dtype)
+            lse_ref[0, i] = m_scr[i] + jnp.log(l_safe)
+
+
+def _kv_map(causal: bool, held: int, t_k: int, bq: int, bk: int):
+    """K/V steps of `held` positions for grid (bh, qi, ks): streamed and
+    causal, steps above the diagonal clamp to the step the diagonal is
+    in."""
+    return _other_side_map(
+        causal, held == t_k,
+        lambda qi, ks: jnp.minimum(ks, ((qi + 1) * bq - 1) // held))
+
+
+def _own_map(bh, own, other):
+    return (bh, own, 0)
+
+
+def _own_stat_map(bh, own, other):
+    return (bh, own, 0, 0)
+
+
+def _aval(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+@functools.lru_cache(maxsize=64)
+def _forward_call(rows: int, t_q: int, t_k: int, d: int, dtypes,
+                  causal: bool, scale: float, bq: int, bk: int,
+                  interpret: bool):
+    """(the forward's `pallas_call` over q, k, v `[rows, t, d]` of
+    `dtypes`, whether K/V are resident), built ONCE a signature
+    (`_paged_call`'s reason: what it returns is a `jax.jit`, so a model's
+    second layer finds the first's trace, and a program's equations carry
+    ONE kernel jaxpr, lowered to a Mosaic module once a lowering — a body
+    that unrolls a row's block steps is dear to trace a layer)."""
+    n_q, n_k = t_q // bq, t_k // bk
+    own, held = _step_shape("flash_fwd", bq, bk, t_q, t_k, d, dtypes[1])
+    kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
+                               block_q=bq, block_k=bk, n_qs=n_q // own,
+                               n_kv=t_k // held)
+    kv_map = _kv_map(causal, held, t_k, bq, bk)
+    operands = [_aval((rows, t_q, d), dtypes[0]),
+                _aval((rows, t_k, d), dtypes[1]),
+                _aval((rows, t_k, d), dtypes[2])]
+    out_shape = [
+        _aval((rows, t_q, d), dtypes[0]),
+        # a Q block's lse a row of lanes: the layout the kernels keep it in
+        _aval((rows, n_q, 1, bq), jnp.float32),
+    ]
+    call = pl.pallas_call(
+        kernel,
+        grid=(rows, n_q // own, t_k // held),
+        in_specs=[
+            pl.BlockSpec((1, own * bq, d), _own_map),
+            pl.BlockSpec((1, held, d), kv_map),
+            pl.BlockSpec((1, held, d), kv_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, own * bq, d), _own_map),
+            pl.BlockSpec((1, own, 1, bq), _own_stat_map),
+        ],
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((own, d, bq), jnp.float32),
+            pltpu.VMEM((own, 1, bq), jnp.float32),
+            pltpu.VMEM((own, 1, bq), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=_flash_cost(operands + out_shape, n_q, n_k, bq, bk,
+                                  causal, matmuls=2),
+        interpret=interpret,
+        name="flash_fwd",
+    )
+    return jax.jit(call), held == t_k
 
 
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    bq = _pick_block(block_q, t_q)
-    bk = _pick_block(block_k, t_k)
-    n_q, n_k = t_q // bq, t_k // bk
-
-    qf = q.reshape(b * h, t_q, d)
-    kf = k.reshape(b * h, t_k, d)
-    vf = v.reshape(b * h, t_k, d)
-
-    kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
-                               block_q=bq, block_k=bk, n_k=n_k)
-    kv_map = _kv_index_map(causal, bq, bk)
-    out_shape = [
-        jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
-    ]
+    call, resident = _forward_call(
+        b * h, t_q, t_k, d, (q.dtype.name, k.dtype.name, v.dtype.name),
+        bool(causal), float(scale), _pick_block(block_q, t_q),
+        _pick_block(block_k, t_k), bool(interpret))
+    _count_call("flash_fwd", resident, k.dtype)
     with jax.named_scope("flash_fwd"):
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=(b * h, n_q, n_k),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bk, d), kv_map),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            ],
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((bq, d), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=_flash_cost([qf, kf, vf] + out_shape, n_q, n_k, bq,
-                                      bk, causal, matmuls=2),
-            interpret=interpret,
-            name="flash_fwd",
-        )(qf, kf, vf)
+        out, lse = call(q.reshape(b * h, t_q, d), k.reshape(b * h, t_k, d),
+                        v.reshape(b * h, t_k, d))
     return out.reshape(b, h, t_q, d), lse.reshape(b * h, t_q)
 
 
@@ -188,73 +434,173 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal: bool, scale: float,
-                         block_q: int, block_k: int, n_k: int):
-    qi = pl.program_id(1)
+                         block_q: int, block_k: int, n_qs: int, n_kv: int):
     ki = pl.program_id(2)
+    own = q_ref.shape[1] // block_q
+    sub = k_ref.shape[1] // block_k
+    first_q = _first_block(1, n_qs, own)
+    first_k = _first_block(2, n_kv, sub)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = (ki * block_k <= (qi + 1) * block_q - 1) if causal else (ki >= 0)
+    for i in range(own):
+        mine = pl.ds(i * block_q, block_q)
+        q, s_scale = _prescale(q_ref[0, mine, :], scale)
+        do = do_ref[0, mine, :]
+        lse = lse_ref[0, i]                             # [1, bq]
+        delta = delta_ref[0, i]
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0].astype(jnp.float32)            # [bq, 1]
-        delta = delta_ref[0].astype(jnp.float32)        # [bq, 1]
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = q @ k_blk.T
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
-        dp = do @ v_blk.T
-        ds = p * (dp - delta)
-        dq_scr[...] = dq_scr[...] + ds @ k_blk
+        def step(j, masked, i=i, q=q, s_scale=s_scale, do=do, lse=lse,
+                 delta=delta):
+            rows = _rows_of(j, block_k)
+            k_blk = k_ref[0, rows, :]
+            v_blk = v_ref[0, rows, :]
+            s = _scaled(_dot_nt(k_blk, q), s_scale)     # [bk, bq]
+            if masked:
+                s = _causal_mask(s, (first_q + i) * block_q,
+                                 (first_k + j) * block_k)
+            p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
+            ds = p * (_dot_nt(v_blk, do) - delta)
+            dq_scr[i] = dq_scr[i] + _dot_tn(k_blk, ds.astype(k_blk.dtype))
 
-    @pl.when(ki == n_k - 1)
+        _walk(step, _k_walk(causal, first_q + i, first_k, sub, block_q,
+                            block_k))
+
+    @pl.when(ki == n_kv - 1)
     def _write():
-        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        for i in range(own):
+            dq_ref[0, pl.ds(i * block_q, block_q), :] = \
+                (dq_scr[i] * scale).T.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                           scale: float, block_q: int, block_k: int,
-                          n_q: int):
-    ki = pl.program_id(1)
-    qb = pl.program_id(2)
+                          n_ks: int, n_qv: int):
+    qs = pl.program_id(2)
+    own = k_ref.shape[1] // block_k         # K blocks this step holds
+    sub = q_ref.shape[1] // block_q         # Q blocks this step holds
+    first_k = _first_block(1, n_ks, own)
+    first_q = _first_block(2, n_qv, sub)
 
-    @pl.when(qb == 0)
+    @pl.when(qs == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # a Q block strictly above this K block's first row is fully masked
-    run = ((qb + 1) * block_q - 1 >= ki * block_k) if causal else (qb >= 0)
+    for i in range(own):
+        mine = pl.ds(i * block_k, block_k)
+        k, s_scale = _prescale(k_ref[0, mine, :], scale)    # [bk, d]
+        v = v_ref[0, mine, :]
 
-    @pl.when(run)
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)                # [bk, d]
-        v = v_ref[0].astype(jnp.float32)
-        q_blk = q_ref[0].astype(jnp.float32)            # [bq, d]
-        do_blk = do_ref[0].astype(jnp.float32)
-        lse_blk = lse_ref[0].astype(jnp.float32)        # [bq, 1]
-        delta_blk = delta_ref[0].astype(jnp.float32)
-        s = (q_blk * scale) @ k.T                       # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qb, ki, block_q, block_k)
-        p = jnp.exp(s - lse_blk)
-        dv_scr[...] = dv_scr[...] + p.T @ do_blk
-        dp = do_blk @ v.T
-        ds = p * (dp - delta_blk)
-        dk_scr[...] = dk_scr[...] + (ds.T @ q_blk) * scale
+        def step(j, masked, i=i, k=k, s_scale=s_scale, v=v):
+            rows = _rows_of(j, block_q)
+            q_blk = q_ref[0, rows, :]                   # [bq, d]
+            do_blk = do_ref[0, rows, :]
+            s = _scaled(_dot_nt(k, q_blk), s_scale)     # [bk, bq]
+            if masked:
+                s = _causal_mask(s, (first_q + j) * block_q,
+                                 (first_k + i) * block_k)
+            p = jnp.exp(s - lse_ref[0, j])              # lse: [1, bq]
+            dv_scr[i] = dv_scr[i] + _dot(p.astype(do_blk.dtype), do_blk)
+            ds = p * (_dot_nt(v, do_blk) - delta_ref[0, j])
+            dk_scr[i] = dk_scr[i] + _dot(ds.astype(q_blk.dtype), q_blk)
 
-    @pl.when(qb == n_q - 1)
+        _walk(step, _q_walk(causal, first_k + i, first_q, sub, block_q,
+                            block_k))
+
+    @pl.when(qs == n_qv - 1)
     def _write():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        for i in range(own):
+            mine = pl.ds(i * block_k, block_k)
+            dk_ref[0, mine, :] = (dk_scr[i] * scale).astype(dk_ref.dtype)
+            dv_ref[0, mine, :] = dv_scr[i].astype(dv_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _backward_calls(rows: int, t_q: int, t_k: int, d: int, dtypes,
+                    causal: bool, scale: float, bq: int, bk: int,
+                    interpret: bool):
+    """(dQ's `pallas_call`, dK/dV's, whether each holds the side it walks
+    whole) over q, k, v, dO `[rows, t, d]` of `dtypes` and lse, delta
+    `[rows, t_q / bq, 1, bq]`, built once a signature as `_forward_call`."""
+    n_q, n_k = t_q // bq, t_k // bk
+    operands = [_aval((rows, t_q, d), dtypes[0]),
+                _aval((rows, t_k, d), dtypes[1]),
+                _aval((rows, t_k, d), dtypes[2]),
+                _aval((rows, t_q, d), dtypes[3]),
+                _aval((rows, n_q, 1, bq), jnp.float32),
+                _aval((rows, n_q, 1, bq), jnp.float32)]
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    own, held = _step_shape("flash_bwd_dq", bq, bk, t_q, t_k, d, dtypes[1])
+    kv_map = _kv_map(causal, held, t_k, bq, bk)
+    dq_call = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
+                          block_q=bq, block_k=bk, n_qs=n_q // own,
+                          n_kv=t_k // held),
+        grid=(rows, n_q // own, t_k // held),
+        in_specs=[
+            pl.BlockSpec((1, own * bq, d), _own_map),
+            pl.BlockSpec((1, held, d), kv_map),
+            pl.BlockSpec((1, held, d), kv_map),
+            pl.BlockSpec((1, own * bq, d), _own_map),
+            pl.BlockSpec((1, own, 1, bq), _own_stat_map),
+            pl.BlockSpec((1, own, 1, bq), _own_stat_map),
+        ],
+        out_specs=pl.BlockSpec((1, own * bq, d), _own_map),
+        out_shape=operands[0],
+        scratch_shapes=[pltpu.VMEM((own, d, bq), jnp.float32)],
+        compiler_params=params,
+        cost_estimate=_flash_cost(operands + operands[:1], n_q, n_k, bq, bk,
+                                  causal, matmuls=3),
+        interpret=interpret,
+        name="flash_bwd_dq",
+    )
+    kv_resident = held == t_k
+
+    own, held = _step_shape("flash_bwd_dkv", bq, bk, t_q, t_k, d, dtypes[0])
+    # streamed and causal: Q steps above the K block's first row clamp to
+    # the first that contributes
+    q_map = _other_side_map(
+        causal, held == t_q,
+        lambda ki, qs: jnp.maximum(qs, (ki * bk) // held))
+
+    def stat_map(bh, ki, qb):
+        return q_map(bh, ki, qb) + (0,)
+
+    dkv_call = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
+                          block_q=bq, block_k=bk, n_ks=n_k // own,
+                          n_qv=t_q // held),
+        grid=(rows, n_k // own, t_q // held),
+        in_specs=[
+            pl.BlockSpec((1, held, d), q_map),
+            pl.BlockSpec((1, own * bk, d), _own_map),
+            pl.BlockSpec((1, own * bk, d), _own_map),
+            pl.BlockSpec((1, held, d), q_map),
+            pl.BlockSpec((1, held // bq, 1, bq), stat_map),
+            pl.BlockSpec((1, held // bq, 1, bq), stat_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, own * bk, d), _own_map),
+            pl.BlockSpec((1, own * bk, d), _own_map),
+        ],
+        out_shape=operands[1:3],
+        scratch_shapes=[
+            pltpu.VMEM((own, bk, d), jnp.float32),
+            pltpu.VMEM((own, bk, d), jnp.float32),
+        ],
+        compiler_params=params,
+        cost_estimate=_flash_cost(operands + operands[1:3], n_q, n_k, bq, bk,
+                                  causal, matmuls=4),
+        interpret=interpret,
+        name="flash_bwd_dkv",
+    )
+    return jax.jit(dq_call), jax.jit(dkv_call), kv_resident, held == t_q
 
 
 def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
@@ -263,12 +609,14 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
     bq = _pick_block(block_q, t_q)
-    bk = _pick_block(block_k, t_k)
-    n_q, n_k = t_q // bq, t_k // bk
+    dq_call, dkv_call, kv_resident, q_resident = _backward_calls(
+        b * h, t_q, t_k, d,
+        (q.dtype.name, k.dtype.name, v.dtype.name, g.dtype.name),
+        bool(causal), float(scale), bq, _pick_block(block_k, t_k),
+        bool(interpret))
+    _count_call("flash_bwd_dq", kv_resident, k.dtype)
+    _count_call("flash_bwd_dkv", q_resident, q.dtype)
 
-    qf = q.reshape(b * h, t_q, d)
-    kf = k.reshape(b * h, t_k, d)
-    vf = v.reshape(b * h, t_k, d)
     dof = g.reshape(b * h, t_q, d)
     of = o.reshape(b * h, t_q, d)
     # delta_i = sum_d dO_i O_i — O(T) rowwise, plain XLA; an lse cotangent
@@ -276,98 +624,37 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, scale: float,
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
     if g_lse is not None:
         delta = delta - g_lse.reshape(b * h, t_q).astype(jnp.float32)
-    # trailing singleton keeps lse/delta sublane-major inside the kernels
-    # (a [bq]-lane -> [bq, 1]-sublane reshape is a transpose Mosaic hates)
-    lse3 = lse.reshape(b * h, t_q, 1)
-    delta3 = delta.reshape(b * h, t_q, 1)
-
-    operands = [qf, kf, vf, dof, lse3, delta3]
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                                  scale=scale, block_q=bq, block_k=bk,
-                                  n_k=n_k)
-    kv_map = _kv_index_map(causal, bq, bk)
+    # positions on the lanes, a Q block a row of the second axis: what
+    # broadcasts down the [bk, bq] scores (and a block whose last two dims
+    # are the array's is legal whatever bq is)
+    operands = [q.reshape(b * h, t_q, d), k.reshape(b * h, t_k, d),
+                v.reshape(b * h, t_k, d), dof,
+                lse.reshape(b * h, t_q // bq, 1, bq),
+                delta.reshape(b * h, t_q // bq, 1, bq)]
     with jax.named_scope("flash_bwd_dq"):
-        dq = pl.pallas_call(
-            dq_kernel,
-            grid=(b * h, n_q, n_k),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=_flash_cost(operands + [qf], n_q, n_k, bq, bk,
-                                      causal, matmuls=3),
-            interpret=interpret,
-            name="flash_bwd_dq",
-        )(*operands)
-
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                                   scale=scale, block_q=bq, block_k=bk,
-                                   n_q=n_q)
-    q_map = _q_index_map(causal, bq, bk)
+        dq = dq_call(*operands)
     with jax.named_scope("flash_bwd_dkv"):
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            grid=(b * h, n_k, n_q),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), q_map),
-                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-                pl.BlockSpec((1, bq, d), q_map),
-                pl.BlockSpec((1, bq, 1), q_map),
-                pl.BlockSpec((1, bq, 1), q_map),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh, ki, qb: (bh, ki, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-                jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=_flash_cost(operands + [kf, vf], n_q, n_k, bq, bk,
-                                      causal, matmuls=4),
-            interpret=interpret,
-            name="flash_bwd_dkv",
-        )(*operands)
-
+        dk, dv = dkv_call(*operands)
     return (dq.reshape(b, h, t_q, d), dk.reshape(b, h, t_k, d),
             dv.reshape(b, h, t_k, d))
 
 
 def estimate_vmem_bytes(t_q: int, t_k: int, d: int, block_q: int = 256,
-                        block_k: int = 256) -> int:
-    """Worst-case per-program VMEM residency across the three kernels
-    (blocks + f32 scratch), double-buffered DMA included.  Sequence-length
-    independent by construction — the long-context guarantee."""
+                        block_k: int = 256, dtype=jnp.float32) -> int:
+    """Worst-case per-program VMEM residency across the three kernels as
+    they are built for these shapes and this operand dtype (blocks in
+    their VMEM layout, double-buffered; f32 scratch; a block step's f32
+    temporaries): a row's other side whole where that stays under
+    `_TRAIN_VMEM_BUDGET`, else one block of it — independent of the
+    sequence length from there on, the long-context guarantee."""
     bq = _pick_block(block_q, t_q)
     bk = _pick_block(block_k, t_k)
-    f32 = 4
 
-    def dbl(*block_bytes):  # pallas double-buffers streamed blocks
-        return 2 * sum(block_bytes)
+    def reckon(kernel):
+        own, held = _step_shape(kernel, bq, bk, t_q, t_k, d, dtype)
+        return _train_vmem_bytes(kernel, bq, bk, held, d, dtype, own)
 
-    fwd = dbl(bq * d * f32, 2 * bk * d * f32, bq * d * f32, bq * f32) \
-        + (bq * d + 2 * bq) * f32
-    dq = dbl(bq * d * f32 * 2, 2 * bk * d * f32, 2 * bq * f32,
-             bq * d * f32) + bq * d * f32
-    dkv = dbl(bq * d * f32 * 2, 2 * bk * d * f32, 2 * bq * f32,
-              2 * bk * d * f32) + 2 * bk * d * f32
-    return max(fwd, dq, dkv)
+    return max(map(reckon, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")))
 
 
 def _reference_attention(q, k, v, causal: bool, scale: float):

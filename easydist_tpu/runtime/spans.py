@@ -63,7 +63,11 @@ were split into, 1 for a call every device runs whole (the kernels of a
 model with state layers among them: `ssm_decode_update`, `delta_decode_update`,
 `selective_chunk_scan`, `selective_decode_update`;
 an expert layer's two: `grouped_matmul`, `grouped_matmul_sum`; the latent
-kernels: `latent_decode`, `latent_chunk`).
+kernels: `latent_decode`, `latent_chunk`);
+`flash_train_calls{kernel=<name>,kv=resident|streamed,operands=<dtype>}`,
+one per traced call of a flash training kernel (ops/flash_attention.py):
+whether a grid step holds the side it walks whole, and the dtype the MXU
+is handed.
 Serving counts stay in `ServeMetrics`: the state pool's gauges, a latent
 arena's `latent_cache_bytes`, the delta-rule layers' `delta_state_bytes`,
 `delta_rows_updated` and `delta_chunk_positions`, the selective-state

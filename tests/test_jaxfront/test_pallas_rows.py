@@ -41,16 +41,29 @@ def _pallas_eqns(fn, *args):
 
 # ------------------------------------------------------------ the predicate
 
+# (batch, heads, positions, head_dim, blocks, steps of the grid's third axis):
+# a small shape; the train cell's own (100 rows of 1,024 x 64, a row's other
+# side held whole and walked by the body); and one too long to hold, streamed
+# through the grid half a row a step
+ROW_SHAPES = [pytest.param(4, 5, 64, 16, 32, 1, id="small"),
+              pytest.param(4, 25, 1024, 64, 256, 1, id="train-cell"),
+              pytest.param(1, 4, 8192, 128, 256, 2, id="streamed")]
+
+
 @pytest.mark.parametrize("kernel", FLASH_KERNELS)
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-def test_predicate_accepts_the_training_kernels(kernel, causal):
-    q = jnp.ones((4, 5, 64, 16), jnp.bfloat16)
+@pytest.mark.parametrize("b,h,t,d,block,steps", ROW_SHAPES)
+def test_predicate_accepts_the_training_kernels(kernel, causal, b, h, t, d,
+                                                block, steps):
+    q = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16)
     eqns = _pallas_eqns(
         lambda q, k, v: jax.grad(lambda *qkv: flash_attention(
-            *qkv, causal, block_q=32, block_k=32).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v), q, q, q)
+            *qkv, causal, block_q=block, block_k=block).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v), q, q, q)
     (eqn,) = eqns[kernel]
-    assert pallas_row_extent(eqn) == 20
+    # held whole, the walked side is ONE step of the grid's third axis
+    assert eqn.params["grid_mapping"].grid[2] == steps
+    assert pallas_row_extent(eqn) == b * h
     rule = preset_rule(eqn, 2)
     assert rule["shard_where_valid"]
     assert all(row[0].group == 1 and not any(d.group for d in row[1:])

@@ -23,7 +23,9 @@ chip:
     pairs' products are combined with no float32 copy of them (it lives in
     this file because one worker alone may load the TPU's compiler), and
     the three expert cells' at their chunk shapes: nothing as large as the
-    k x rows pair slots exists at all.
+    k x rows pair slots exists at all;
+  * the flash training kernels at the train cell's call and a streamed
+    length: Mosaic takes the walked loops and the lane-major statistics.
 
 This is the guard that keeps a later model file from stacking again
 (PR 28: the stacked arena cost 57 % of a decode round on the chip).
@@ -262,6 +264,40 @@ def test_narrow_pages_compile_on_tpu(v5e_chip, d, blocks, chunk):
         leaf_bytes = n_pages * kvh * pt * d * 2
         assert compiled.memory_analysis().temp_size_in_bytes \
             < 2 * leaf_bytes + 2 ** 20
+
+
+# the flash training kernels: (rows, positions, head_dim, dtype) — the train
+# cell's own call on a chip (a row's other side held whole, walked by a loop
+# whose bounds are the grid position's), its float32 and head-128 kin, and a
+# length that streams
+FLASH_TRAIN_SHAPES = [
+    pytest.param(25, 1024, 64, jnp.bfloat16, id="train-cell"),
+    pytest.param(25, 1024, 64, jnp.float32, id="train-cell-f32"),
+    pytest.param(8, 1024, 128, jnp.bfloat16, id="d128"),
+    pytest.param(4, 8192, 128, jnp.bfloat16, id="streamed"),
+]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("rows,t,d,dtype", FLASH_TRAIN_SHAPES)
+def test_flash_training_kernels_compile_on_tpu(v5e_chip, rows, t, d, dtype,
+                                               causal):
+    """Mosaic's own compile of the forward, dQ and dK/dV kernels (it lives
+    in this file because one worker alone may load the TPU's compiler): a
+    loop with dynamic bounds over sublane slices of a held row, products
+    that contract both operands' last axes, lse and delta with positions
+    on the lanes — what the cross-lowering of test_tpu_lowering.py cannot
+    refuse."""
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    x = jax.ShapeDtypeStruct((1, rows, t, d), dtype, sharding=v5e_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal, interpret=False).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["exact", "int8"])

@@ -52,17 +52,34 @@ def _aval(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-@pytest.mark.parametrize("b,h,kvh,d", SHAPES)
-def test_flash_forward_and_backward_lower(b, h, kvh, d):
-    qkv = _aval((b, h, SEQ, d), BF16)
+# the training kernels also at the train cell's own call on a chip (25 of
+# its 100 rows of 1,024 x 64: a row's other side held whole) and at a
+# length that streams (8,192 x 128)
+TRAIN_SHAPES = [pytest.param(8, 12, 64, SEQ, id="gpt2-small"),
+                pytest.param(4, 32, 128, SEQ, id="gqa-32-8-128"),
+                pytest.param(1, 25, 64, 1024, id="train-cell"),
+                pytest.param(1, 4, 128, 8192, id="streamed")]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("b,h,d,seq", TRAIN_SHAPES)
+def test_flash_forward_and_backward_lower(b, h, d, seq, causal):
+    qkv = _aval((b, h, seq, d), BF16)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, True, interpret=False).astype(
+        return flash_attention(q, k, v, causal, interpret=False).astype(
             jnp.float32).sum()
 
     _lower_for_tpu(lambda q, k, v: flash_attention(
-        q, k, v, True, interpret=False), qkv, qkv, qkv)
-    _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+        q, k, v, causal, interpret=False), qkv, qkv, qkv)
+    text = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv,
+                          qkv).as_text()
+    # three kernels, and lse and delta reach each with a Q block's
+    # positions on the lanes: none holds a [rows, t, 1] operand
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert all(f"tensor<{b * h}x{seq // 256}x1x256xf32>" in ln
+               and f"x{seq}x1xf32>" not in ln for ln in calls)
 
 
 @pytest.mark.parametrize("b,h,kvh,d", SHAPES)
@@ -283,16 +300,18 @@ def test_kernel_inside_a_sharded_program_lowers(monkeypatch, cpu_devices):
     result.jitted.trace(*result.in_avals).lower(lowering_platforms=("tpu",))
 
 
-@pytest.mark.parametrize("batch,local_rows", [
-    pytest.param(4, 5, id="rows-over-both-axes"),
-    pytest.param(2, 5, id="rows-over-dp-only"),
-    pytest.param(1, 5, id="rows-whole")])
+@pytest.mark.parametrize("batch,local_rows,heads,seq", [
+    pytest.param(4, 5, 5, 128, id="rows-over-both-axes"),
+    pytest.param(2, 5, 5, 128, id="rows-over-dp-only"),
+    pytest.param(1, 5, 5, 128, id="rows-whole"),
+    pytest.param(4, 25, 25, 1024, id="train-cell")])
 def test_row_sharded_kernels_lower(monkeypatch, cpu_devices, batch,
-                                   local_rows):
+                                   local_rows, heads, seq):
     """The flash kernels re-bound at their shard's row count (5 heads x
     `batch` rows over a (2, 2) mesh: 20 / 4, 10 / 2, and 5 left whole,
-    which neither axis divides) pass Pallas' TPU lowering, and the lowered
-    module holds them at that extent."""
+    which neither axis divides; and the train cell's own 100 rows of 1,024
+    x 64, 25 a chip) pass Pallas' TPU lowering, and the lowered module
+    holds them at that extent."""
     import importlib
     import re
 
@@ -304,8 +323,8 @@ def test_row_sharded_kernels_lower(monkeypatch, cpu_devices, batch,
         importlib.import_module("easydist_tpu.ops.flash_attention"),
         "_default_interpret", lambda: False)
     mesh = make_device_mesh((2, 2), ("dp", "tp"), devices=cpu_devices[:4])
-    cfg = GPTConfig(vocab=512, seq=128, dim=320, heads=5, layers=1,
-                    dtype="bfloat16", attention="flash")
+    cfg = GPTConfig(vocab=512, seq=seq, dim=64 * heads, heads=heads,
+                    layers=1, dtype="bfloat16", attention="flash")
     step, init_state = make_gpt_train_step(cfg)
     state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
     tokens = _aval((batch, cfg.seq), jnp.int32)
@@ -314,6 +333,8 @@ def test_row_sharded_kernels_lower(monkeypatch, cpu_devices, batch,
         lowering_platforms=("tpu",)).as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 3
+    block = min(seq, 256)   # q, k, v, dO, and lse and delta a Q block a row
     for line in calls:
-        shapes = re.findall(r"tensor<(\d+)x128x(?:64|1)x", line)
+        shapes = re.findall(
+            rf"tensor<(\d+)x(?:{seq}x64|{seq // block}x1x{block})x", line)
         assert shapes and set(shapes) == {str(local_rows)}, line
