@@ -305,6 +305,16 @@ PROBE_CASES = {
     "granite.paged_decode": (64, 32, 8, 128, 256, 16, 1024, 0, [700] * 29),
     "axk1.latent_decode": (32, 64, 0, 640, 256, 64, 2048, 0, [6000] * 6),
     "axk1.latent_chunk": (2, 64, 0, 640, 256, 64, 2048, 256, [3072, 6144]),
+    # 8 KV heads of SIXTY-FOUR: the leaf lane-dense, [pages, 8, 128, 128], as
+    # the arena stores it (`kv/arena.py`), and (`.as_is`) plain, [pages, 8,
+    # 256, 64], which the call reshapes — on the chip a copy of both leaves
+    "lfm2.paged_decode": (256, 32, 8, 64, 256, 16, 1536, 0, [900] * 160),
+    "lfm2.paged_decode.as_is": (256, 32, 8, 64, 256, 16, 1536, 0,
+                                [900] * 160),
+    "lfm2.paged_chunk": (4, 32, 8, 64, 256, 16, 1536, 256,
+                         [256, 512, 1024, 2048]),
+    "lfm2.paged_chunk.as_is": (4, 32, 8, 64, 256, 16, 1536, 256,
+                               [256, 512, 1024, 2048]),
 }
 
 
@@ -313,10 +323,13 @@ def phase_paged_probe(cases=None, iters=200, interpret=False):
     `fori_loop` of one program (each call's lengths hang on the call before
     it, so none is hoisted or merged), timed once after a warm-up run.
     What the loop itself costs reads in the `all_dead` case, where the call
-    walks no page."""
+    walks no page.  Narrow heads (the `lfm2.*` cases) are probed on the leaf
+    the arena stores, lane-dense, and on a plain one beside it."""
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from easydist_tpu.kv.arena import lane_parts
 
     fa = importlib.import_module("easydist_tpu.ops.flash_attention")
     bf = jnp.bfloat16
@@ -335,6 +348,9 @@ def phase_paged_probe(cases=None, iters=200, interpret=False):
         q = jax.random.normal(
             key, (rows, heads) + ((chunk,) if chunk else ()) + (width,), bf)
         shape = (n_pages, kvh, pt, width) if kvh else (n_pages, pt, width)
+        if kvh and not name.endswith(".as_is"):
+            parts = lane_parts(width, pt)      # 1 at heads of 128 and wider
+            shape = (n_pages, kvh, pt // parts, parts * width)
         pages = [jax.jit(lambda k: jax.random.normal(k, shape, bf))(
             jax.random.fold_in(key, i)) for i in range(2 if kvh else 1)]
         if kvh:

@@ -20,4 +20,4 @@ from .llama import (init_kv_cache as llama_init_kv_cache,  # noqa: F401
                     llama_prefill, llama_prefill_chunk, llama_decode_step)
 from .vit import ViTConfig, vit_init, vit_apply, make_vit_train_step  # noqa: F401
 from .gat import GATConfig, gat_init, gat_apply, make_gat_train_step  # noqa: F401
-from . import jamba, olmo_hybrid  # noqa: F401  (serving only: `decoder(cfg)`)
+from . import jamba, olmo_hybrid, lfm2_moe  # noqa: F401  (serving only: `decoder(cfg)`)

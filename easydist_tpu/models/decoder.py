@@ -174,7 +174,8 @@ class Contiguous:
     def __init__(self, cache):
         self._old, self._k, self._v = cache, [], []
 
-    def seek(self, start, n: Optional[int] = None, aligned: bool = False):
+    def seek(self, start, n: Optional[int] = None, aligned: bool = False,
+             head_dim: Optional[int] = None):
         self._at = start
         return start if n is None else _window_positions(start, n)
 
@@ -211,7 +212,13 @@ class Contiguous:
 
 class Paged:
     """`kv/arena.py`'s arena, {"k": (leaf per layer), "v": (...)} with each
-    leaf [n_pages, kv_heads, page_tokens, head_dim], read and written
+    leaf [n_pages, kv_heads, page_tokens, head_dim] — an exact leaf of
+    heads of 64 or 32 LANE-DENSE, [n_pages, kv_heads, page_tokens / parts,
+    128] (`kv/arena.py::lane_parts`), which is why a step tells `seek` the
+    model's `head_dim`: a leaf's own shape does not say which of the two it
+    is.  `init` picks the form; the writes and the kernels take either as
+    it lies, and only the gather path (the CPU's) sees a reshape of it.
+    Read and written
     through `table` (int32 [batch, max_pages]: the arena page of each
     `page_tokens` window of a sequence; unmapped entries hold the sentinel
     `n_pages`, through which writes drop and reads clip to a real page
@@ -235,14 +242,15 @@ class Paged:
                                quant_block)
 
     @staticmethod
-    def page_tokens(pages) -> int:
-        """The positions a page holds, off the arena's leaves."""
-        return pages["k"][0].shape[2]
+    def page_tokens(pages, head_dim: Optional[int] = None) -> int:
+        """The positions a page holds, off the arena's leaves (`head_dim`:
+        the model's, without which a leaf is read as a plain one)."""
+        _, _, rows, lanes = pages["k"][0].shape
+        return rows * lanes // (head_dim or lanes)
 
     def __init__(self, pages, table):
         self._old, self._table = pages, table
         self._new = {key: [] for key in pages}
-        self._pt = self.page_tokens(pages)
         self._quant_nb = pages["k_scale"][0].shape[-1] \
             if "k_scale" in pages else 0
         self.counters = None      # the ffns' counters, summed over layers
@@ -254,11 +262,14 @@ class Paged:
         n_pages = next(iter(self._old.values()))[0].shape[0]
         return self._table[:, 0].astype(jnp.int32) < n_pages
 
-    def seek(self, start, n: Optional[int] = None, aligned: bool = False):
+    def seek(self, start, n: Optional[int] = None, aligned: bool = False,
+             head_dim: Optional[int] = None):
         """One row at `start` (n None), `n` rows from `start` that may
         straddle a page boundary, or (`aligned`) a chunk that fills exactly
-        the page of window `start // page_tokens`."""
-        pt = self._pt
+        the page of window `start // page_tokens`.  `head_dim` is the
+        model's (the steps pass it): what tells a lane-dense leaf's
+        `page_tokens` from its shape."""
+        pt = self.page_tokens(self._old, head_dim)
         if aligned and n != pt:
             raise ValueError(f"paged prefill chunk {n} != page_tokens {pt} "
                              f"(chunks must fill exactly one page)")
@@ -317,9 +328,9 @@ class Paged:
                 tbl, pos)
 
         def virtual(key):
-            # int8 pages: the contiguous cache the table describes,
-            # GQA-repeated AFTER the gather (payload and scales alike, so
-            # dequant commutes)
+            # int8 pages (never lane-dense): the contiguous cache the table
+            # describes, GQA-repeated AFTER the gather (payload and scales
+            # alike, so dequant commutes)
             return kv_dequantize(
                 gather_pages(last[key], tbl, n_heads=dec.heads),
                 gather_pages(last[key + "_scale"], tbl, n_heads=dec.heads),
@@ -354,13 +365,12 @@ class Latent(Paged):
                                  _storage_dtype(dec, dtype))
 
     @staticmethod
-    def page_tokens(pages) -> int:
+    def page_tokens(pages, head_dim: Optional[int] = None) -> int:
         return pages["latent"][0].shape[1]
 
     def __init__(self, pages, table):
         self._old, self._table = pages, table
         self._new = []
-        self._pt = self.page_tokens(pages)
         self.counters = None
 
     def _padded(self, x):
@@ -601,7 +611,7 @@ def chunk(dec: Decoder, kv, params, tokens, start_pos, lengths, state=None):
     or past a row's length leave its state and its rings untouched."""
     c_len = tokens.shape[1]
     start = start_pos.astype(jnp.int32)
-    pos = kv.seek(start, c_len, aligned=True)
+    pos = kv.seek(start, c_len, aligned=True, head_dim=dec.head_dim)
     live = _live(dec, kv, state)
     valid = None if live is None else \
         live[:, None] & (pos < lengths.astype(jnp.int32)[:, None])
@@ -622,7 +632,8 @@ def verify(dec: Decoder, kv, params, tokens, pos):
     a model that keeps slots: a state has no position mask to hide a
     rejected draft behind, and a rejected draft has overwritten the ring
     rows of positions still inside the window."""
-    pos = kv.seek(pos.astype(jnp.int32), tokens.shape[1])
+    pos = kv.seek(pos.astype(jnp.int32), tokens.shape[1],
+                  head_dim=dec.head_dim)
     live = _live(dec, kv, None)
     valid = None if live is None else \
         jnp.broadcast_to(live[:, None], pos.shape)
@@ -637,6 +648,7 @@ def decode(dec: Decoder, kv, params, token, pos, state=None):
     `state`, rows that are not `state.live` leave their state and their
     rings untouched."""
     cache, x = _forward(dec, kv, params, token,
-                        kv.seek(pos.astype(jnp.int32)), state,
+                        kv.seek(pos.astype(jnp.int32),
+                                head_dim=dec.head_dim), state,
                         _live(dec, kv, state))
     return cache, dec.unembed(params, x)

@@ -16,6 +16,7 @@ chips.  A shared expert is a dense SwiGLU (`glu`), added by the model.
     exaone_moe       sigmoid scores, top-8 of score + bias, the chosen
                      scores normalised and scaled (`sigmoid_route`)
     axk1             the same without the bias
+    lfm2_moe         the same with it, top-4 of 32, the normaliser 1e-6
 """
 
 from __future__ import annotations
@@ -36,17 +37,18 @@ def glu(x, w1, w2, dtype):
     return (jax.nn.silu(ab[..., :half]) * ab[..., half:]) @ w2.astype(dtype)
 
 
-def sigmoid_route(u, router, top_k: int, scale: float, bias=None):
+def sigmoid_route(u, router, top_k: int, scale: float, bias=None,
+                  eps: float = 1e-20):
     """u [rows, dim] -> (idx int32 [rows, top_k], gate float32 [rows,
     top_k]): sigmoid scores in float32, the top `top_k` of score (+ `bias`,
     which steers the choice and never gates), the chosen scores normalised
-    and scaled."""
+    (their sum + `eps`) and scaled."""
     scores = jax.nn.sigmoid(jnp.dot(
         u, router.astype(u.dtype), preferred_element_type=jnp.float32))
     _, idx = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     return idx, scale * chosen / (
-        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        jnp.sum(chosen, axis=-1, keepdims=True) + eps)
 
 
 def expert_ffn(u, idx, gate, w1, w2, experts_held: Tuple[int, int], dtype,

@@ -71,6 +71,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from easydist_tpu.kv.arena import plain_pages
+
 _NEG_INF = -1e30
 # What the blocks, scratch and block-step temporaries of a training kernel
 # may take in VMEM for a row's other side to be held whole (`_resident`):
@@ -1101,14 +1103,25 @@ def _row_parts(pages) -> int:
     return parts if same and pt % parts == 0 else 1
 
 
+def _page_views(pages, d: int):
+    """Each `pages` operand as [n_pages, heads, page_tokens, w] whichever
+    way it is STORED (shape and dtype alone): a lane-dense leaf
+    (`kv/arena.py`: [.., page_tokens / parts, parts * d], wider than the
+    query's head d) as the plain leaf it holds, any other as it is.  Every
+    shape rule of the paged kernels reckons from these, so a lane-dense
+    leaf is stepped through exactly as the plain one it replaces."""
+    return [jax.eval_shape(lambda a: plain_pages(a, d), a) for a in pages]
+
+
 def _whole_lanes(a, parts: int):
     """`a` [n_pages, heads, page_tokens, w] as a paged kernel takes it, in
     whole 128-lane rows: as it is where w is whole tiles (heads of 128 or
     256, a 640-wide latent row); `parts` consecutive positions to a row
     where they fill one (`_row_parts`; [.., page_tokens / parts, 128], row
     r the positions r * parts + i); else the minor dim zero-padded (a
-    width that divides no 128, as 96).  The kernels copy pages by hand,
-    and Mosaic slices an HBM ref along whole tiles only ("Slice shape
+    width that divides no 128, as 96).  A leaf the arena STORES lane-dense
+    already lies so and never comes here (`_paged_attend`).  The kernels
+    copy pages by hand, and Mosaic slices an HBM ref along whole tiles only ("Slice shape
     along dimension 3 must be aligned to tiling (128)", v5e, libtpu
     0.0.34).  A narrow operand costs a copy of the leaf a call, as it did
     the BlockSpec form before the walk was the kernel's own: a v5e keeps
@@ -1216,6 +1229,20 @@ def _paged_call(body, name: str, scale: float, q_view, pages, max_pages: int,
     )
 
 
+def _count_paged_call(name: str, head_dim: int, parts: int,
+                      dense: bool) -> None:
+    """`paged_attn_calls{kernel, head_dim, row_parts, leaf}`, once per
+    traced call: which paged kernel, at what head, how many positions to a
+    128-lane row, and whether the leaf came stored so (`lane_dense`) or was
+    handed over as it is (`as_is`: heads of 128 and wider, or a narrow leaf
+    that `_whole_lanes` reshapes or pads — a copy of the leaf a call)."""
+    from easydist_tpu.runtime import spans
+
+    spans.count("paged_attn_calls", kernel=name.replace("paged_", ""),
+                head_dim=head_dim, row_parts=parts,
+                leaf="lane_dense" if dense else "as_is")
+
+
 def _paged_attend(body, name: str, scale: float, q_view, pages, table,
                   lengths, pages_per_step: Optional[int], interpret: bool,
                   chunk: int = 0, out_dim: Optional[int] = None,
@@ -1223,15 +1250,19 @@ def _paged_attend(body, name: str, scale: float, q_view, pages, table,
     """`_paged_call` at the operands' signature, applied: `q_view` is q as
     [batch, kv_heads (x `q_blocks`), rows, head_dim], and so is the result
     (`out_dim` wide, where that is given)."""
+    views = _page_views(pages, q_view.shape[-1])
     call = _paged_call(
         body, name, float(scale), (q_view.shape, q_view.dtype.name),
-        tuple((a.shape, a.dtype.name) for a in pages), table.shape[1],
+        tuple((v.shape, v.dtype.name) for v in views), table.shape[1],
         pages_per_step, bool(interpret), chunk, out_dim, q_blocks)
-    parts = _row_parts(pages)
+    parts = _row_parts(views)
+    dense = any(a.shape != v.shape for a, v in zip(pages, views))
+    _count_paged_call(name, q_view.shape[-1], parts, dense)
     with jax.named_scope(name):
         return call(jnp.asarray(table, jnp.int32),
                     jnp.asarray(lengths, jnp.int32), q_view,
-                    *(_whole_lanes(a, parts) for a in pages))
+                    *(a if a.shape != v.shape else _whole_lanes(a, parts)
+                      for a, v in zip(pages, views)))
 
 
 def _paged_decode_call(body, name: str, scale: float, q, pages, table,
@@ -1462,7 +1493,8 @@ def flash_paged_chunk_attention(q, k_pages, v_pages, table, extents,
     pad = _chunk_rows(c) - c
     if pad:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    head_block = _query_head_block((k_pages, v_pages), h // kvh, c + pad)
+    head_block = _query_head_block(_page_views((k_pages, v_pages), d),
+                                   h // kvh, c + pad)
     out = _paged_attend(
         _flash_paged_decode_kernel, "paged_chunk", scale,
         q.reshape(b, h // head_block, head_block * (c + pad), d),
@@ -1565,8 +1597,10 @@ def paged_decode_attention(q, k_pages, v_pages, table, lengths,
     if _use_kernel(backend, "paged decode"):
         return flash_paged_decode_attention(q, k_pages, v_pages, table,
                                             lengths, scale=scale)
-    return _paged_decode_attention_xla(q, k_pages, v_pages, table, lengths,
-                                       scale)
+    d = q.shape[-1]
+    return _paged_decode_attention_xla(q, plain_pages(k_pages, d),
+                                       plain_pages(v_pages, d), table,
+                                       lengths, scale)
 
 
 def _chunk_attention_xla(q, k, v, q_pos, scale: float):
@@ -1636,10 +1670,11 @@ def paged_chunk_attention(q, k_pages, v_pages, table, q_pos,
         extents = jnp.where(live, q_pos[:, -1].astype(jnp.int32) + 1, 0)
         return flash_paged_chunk_attention(q, k_pages, v_pages, table,
                                            extents, scale=scale)
-    h = q.shape[1]
+    h, d = q.shape[1], q.shape[-1]
     return _chunk_attention_xla(
-        q, gather_pages(k_pages, table, n_heads=h),
-        gather_pages(v_pages, table, n_heads=h), q_pos, scale)
+        q, gather_pages(plain_pages(k_pages, d), table, n_heads=h),
+        gather_pages(plain_pages(v_pages, d), table, n_heads=h), q_pos,
+        scale)
 
 
 # ------------------------------------------------- latent attention

@@ -51,16 +51,19 @@ __all__ = ["causal_conv_tail", "ssd_chunk_scan", "ssm_decode_update",
            "selective_decode_update_xla"]
 
 
-def causal_conv_tail(tail, x, w, bias, valid):
-    """The short causal depthwise conv in front of a state layer's
-    recurrence (Mamba-1's, Mamba-2's, the gated delta rule's), over
+def causal_conv_tail(tail, x, w, bias, valid, activation=jax.nn.silu):
+    """The short causal depthwise conv of a state layer (in front of
+    Mamba-1's, Mamba-2's and the gated delta rule's recurrence; the whole
+    of a gated short convolution's carry, `models/lfm2_moe.py`), over
     [carried tail | this window]: tail [b, (taps - 1) * c] (the last
     pre-activation inputs of the sequence so far, float32, FLAT: input j
     of the taps - 1 is the lanes [j * c, (j + 1) * c), oldest first), x
     [b, s, c] (float32), w [taps, c] (row j multiplies the input taps - 1 -
     j positions back), bias [c] or None, valid bool [b, s] (a PREFIX of
-    each row counts) -> (silu(conv) [b, s, c], the tail after the positions
-    that count: with none of them, the tail as it was, bit for bit).
+    each row counts) -> (activation(conv) [b, s, c], the tail after the
+    positions that count: with none of them, the tail as it was, bit for
+    bit).  `activation` is silu unless told otherwise; None gives the conv
+    itself (a model that gates it, and applies nothing).
 
     Flat because that is how a v5e tiles it with nothing padded — slots on
     the sublanes, eight to a tile, and where c is whole lane tiles every
@@ -94,7 +97,7 @@ def causal_conv_tail(tail, x, w, bias, valid):
             b, (taps - 1) * c)
     if bias is not None:
         conv = conv + bias.astype(jnp.float32)
-    return jax.nn.silu(conv), new_tail
+    return (conv if activation is None else activation(conv)), new_tail
 
 
 def _ssd_block(x, dt, a, b_mat, c_mat, state):

@@ -537,7 +537,7 @@ class GenerationSession:
 
         def _prefill_chunk_paged(arena, params, rows):
             table, start, lengths, tokens = _columns(
-                rows, 2, paged.page_tokens(arena))
+                rows, 2, paged.page_tokens(arena, model.head_dim))
             kv = paged(arena, table)
             arena, logits = chunk(model, kv, params, tokens, start, lengths)
             return arena, _ids(logits, kv)
@@ -558,7 +558,7 @@ class GenerationSession:
         def _page_export(arena, page):
             from easydist_tpu.kv.arena import export_page
 
-            return export_page(arena, page)
+            return export_page(arena, page, model.head_dim)
 
         def _page_import(arena, chunk_kv, page):
             from easydist_tpu.kv.arena import import_page
@@ -585,7 +585,7 @@ class GenerationSession:
 
             pages, leaves = State.split(model, cache)
             table, start, lengths, slots, tokens = _columns(
-                rows, 3, Paged.page_tokens(pages))
+                rows, 3, Paged.page_tokens(pages, model.head_dim))
             n_slots = next(iter(leaves.values()))[0].shape[0]
             st = State(leaves, slots < n_slots, slots, fresh=start == 0)
             kv = Paged(pages, table)

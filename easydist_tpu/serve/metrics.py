@@ -20,7 +20,8 @@ _DEFAULT_BOUNDS = tuple(1e-4 * (2 ** i) for i in range(21))
 # also has a gauge <kind>_state_bytes and a counter <kind>_rows_updated
 # (`record_layer_states`): the next recurrence is one more entry.
 RECURRENT_KINDS = {"delta": "delta_chunk_positions",
-                   "selective": "selective_scan_positions"}
+                   "selective": "selective_scan_positions",
+                   "shortconv": "shortconv_chunk_positions"}
 
 
 class LatencyHistogram:

@@ -800,6 +800,95 @@ def test_selective_step_writes_arena_and_state_in_place_on_tpu(
         "the selective kernel and the paged attention kernel"
 
 
+@pytest.mark.parametrize("leaf", ["lane_dense", "as_is"])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_lfm2_steps_move_no_whole_leaf_of_narrow_heads_on_tpu(
+        v5e_chip, monkeypatch, program, leaf):
+    """The LFM2-8B-A1B cell's two programs (BENCHMARK.json: published
+    widths, 256 slots, 1,536 pages of 256 tokens, four prefill rows, 16
+    held experts), two conv and two attention layers with a dense and three
+    expert FFNs, their Pallas kernels compiled by Mosaic for a v5e.  With
+    the arena's leaves LANE-DENSE ([pages, 8, 128, 128]: 8 KV heads of 64,
+    two positions to a row) no op of either program gives a whole K or V
+    leaf — no copy, no transpose, no pad, no `while` that carries one — and
+    the temporaries stay far under a leaf.  With the leaves as they were
+    ([pages, 8, 256, 64], which a v5e keeps pages-minor) the decode round
+    copies each leaf FOUR times and the chunk call twice: what heads of 64
+    cost before (PERF.md section 6, PR 48).  (Held here and not in
+    tests/test_ops/test_tpu_lowering.py, which cross-lowers and cannot
+    compile: one file alone may load libtpu.)"""
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.kv import arena as arena_mod
+    from easydist_tpu.models import lfm2_moe
+    from easydist_tpu.models.decoder import Paged, State, chunk, decode
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    monkeypatch.setattr(edconfig, "decode_attention_backend", "paged")
+    monkeypatch.setattr(edconfig, "prefill_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    _compile_the_grouped_products(monkeypatch)
+    if leaf == "as_is":
+        monkeypatch.setattr(arena_mod, "lane_parts", lambda hd, pt: 1)
+    cfg = lfm2_moe.Lfm2MoeConfig(
+        vocab=8192, dense_layers=1, experts_held=(0, 16),
+        layer_types=("conv", "full_attention", "conv", "full_attention"))
+    dec = lfm2_moe.decoder(cfg)
+    slots, n_pages, pt, max_pages, c_rows = 256, 1536, 256, 16, 4
+
+    def aval(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = _described(v5e_chip, jax.eval_shape(
+        lambda key: lfm2_moe.lfm2_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = _described(v5e_chip, jax.eval_shape(
+        lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
+    assert cache["k"][0].shape == ((n_pages, 8, 128, 128)
+                                   if leaf == "lane_dense"
+                                   else (n_pages, 8, 256, 64))
+
+    def decode_step(cache, params, table, live, token, pos):
+        pages, leaves = State.split(dec, cache)
+        kv = Paged(pages, table)
+        cache, logits = decode(dec, kv, params, token, pos,
+                               state=State(leaves, live))
+        return cache, jnp.argmax(logits, -1), kv.counters
+
+    def chunk_step(cache, params, table, at, tokens, start, lengths):
+        pages, leaves = State.split(dec, cache)
+        st = State(leaves, at < slots, at, fresh=start == 0)
+        kv = Paged(pages, table)
+        cache, logits = chunk(dec, kv, params, tokens, start, lengths,
+                              state=st)
+        return cache, jnp.argmax(logits, -1), kv.counters
+
+    if program == "decode":
+        compiled = jax.jit(decode_step, donate_argnums=(0,)).lower(
+            cache, params, aval((slots, max_pages)),
+            aval((slots,), jnp.bool_), aval((slots,)),
+            aval((slots,))).compile()
+    else:
+        compiled = jax.jit(chunk_step, donate_argnums=(0,)).lower(
+            cache, params, aval((c_rows, max_pages)), aval((c_rows,)),
+            aval((c_rows, pt)), aval((c_rows,)), aval((c_rows,))).compile()
+    page_leaf = n_pages * 8 * pt * 64 * 2
+    tail_leaf = slots * 2 * 2048 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * page_leaf + 2 * tail_leaf
+    text = compiled.as_text()
+    # the paged kernel on two layers, two grouped products on three
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 6
+    moves = _leaf_sized_moves(text, n_pages * 8 * pt * 64)
+    carried = len(re.findall(
+        r"= \([^)]*bf16\[1536,8,\d+,\d+\][^)]*\) while\(", text))
+    if leaf == "lane_dense":
+        assert moves == [] and carried == 0, moves
+        assert mem.temp_size_in_bytes < page_leaf // 8
+    else:
+        per_leaf = 4 if program == "decode" else 2
+        assert len(moves) == 4 * per_leaf, moves
+        assert mem.temp_size_in_bytes > 2 * page_leaf
+
+
 # the three cells whose state layers carry a conv tail: (state slots, the
 # conv's channels); four taps in all three
 CONV_TAIL_CELLS = {"jamba2": (128, 5120), "olmo": (40, 11520),

@@ -79,3 +79,49 @@ def test_one_position_a_row_gathers_nothing():
              jax.make_jaxpr(causal_conv_tail)(flat, x, w, bias, valid).eqns}
     assert not names & {"gather", "scatter", "transpose", "reshape",
                         "dynamic_slice"}, names
+
+
+# ---- `activation`: silu unless told otherwise; None gives the conv itself
+# (a gated short convolution, `models/lfm2_moe.py`, applies nothing to it)
+
+
+def _plain_conv(tail, x, w):
+    """A causal depthwise conv over [tail | x], a position and a tap at a
+    time, in numpy: out[t] = sum_j w[j] * full[t + j]."""
+    full = np.concatenate([np.asarray(tail), np.asarray(x)], axis=1)
+    s, taps = x.shape[1], w.shape[0]
+    out = np.zeros(x.shape, np.float64)
+    for t in range(s):
+        for j in range(taps):
+            out[:, t] += np.asarray(w)[j] * full[:, t + j]
+    return out, full
+
+
+@pytest.mark.parametrize("taps", [3, 4])
+@pytest.mark.parametrize("s", [1, 5, 64], ids=["one", "window-5", "chunk"])
+def test_no_activation_is_a_plain_conv(s, taps):
+    rng = np.random.default_rng([s, taps])
+    c = 128
+    tail = jnp.asarray(rng.normal(size=(ROWS, taps - 1, c)), F32)
+    x = jnp.asarray(rng.normal(size=(ROWS, s, c)), F32)
+    w = jnp.asarray(rng.normal(size=(taps, c)), F32)
+    n = jnp.asarray([s, s // 2, 0])
+    valid = jnp.arange(s)[None, :] < n[:, None]
+    out, new_tail = causal_conv_tail(tail.reshape(ROWS, -1), x, w, None,
+                                     valid, activation=None)
+    want, full = _plain_conv(tail, x, w)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
+    # the tail after each row's valid prefix: the last taps - 1 inputs
+    for r in range(ROWS):
+        k = int(n[r])
+        np.testing.assert_array_equal(
+            np.asarray(new_tail[r]).reshape(taps - 1, c),
+            full[r, k:k + taps - 1])
+    # the default is what it was: silu of that conv, bit for bit the
+    # explicit activation's
+    silu, same_tail = causal_conv_tail(tail.reshape(ROWS, -1), x, w, None,
+                                       valid)
+    np.testing.assert_array_equal(
+        np.asarray(silu), np.asarray(jax.nn.silu(out)))
+    np.testing.assert_array_equal(np.asarray(same_tail),
+                                  np.asarray(new_tail))

@@ -345,3 +345,47 @@ def test_the_latent_cells_blocks_are_the_parents():
     assert fa._paged_step_shape(64, pages, fa._LATENT_STEP_TOKENS // 256) \
         == (1, 4)
     assert fa._paged_step_shape(64, pages, None, 2 * 256) == (1, 1)
+
+
+# ---- the lane-dense leaf (kv/arena.py): heads of 32 four positions to a
+# 128-lane row.  The chunk kernel takes it as it lies, the gather path
+# through a reshape; both are the plain leaf's attention.
+
+
+@pytest.mark.parametrize("name", ["prefill-group4", "prefill-float32",
+                                  "verify-group4", "prefill-pages-of-8"])
+def test_lane_dense_leaves_attend_as_plain_ones(name):
+    from easydist_tpu.kv.arena import lane_parts
+
+    q, k, v, table, pos, live = _case(**CASES[name])
+    pt = k.shape[2]
+    parts = lane_parts(HEAD_DIM, pt)
+    assert parts == 4
+    dense = [a.reshape(a.shape[0], KV_HEADS, pt // parts, 128)
+             for a in (k, v)]
+    got = paged_chunk_attention(q, *dense, table, pos, backend="paged")
+    # the very call a plain leaf makes after `_whole_lanes`
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(paged_chunk_attention(q, k, v, table, pos,
+                                         backend="paged"), np.float32))
+    xla = paged_chunk_attention(q, *dense, table, pos, backend="xla")
+    np.testing.assert_array_equal(
+        np.asarray(xla, np.float32),
+        np.asarray(paged_chunk_attention(q, k, v, table, pos,
+                                         backend="xla"), np.float32))
+    # and plain attention: each live row's real keys, a query at a time
+    qf, kf, vf = (np.asarray(a, np.float64) for a in (q, k, v))
+    rep = q.shape[1] // KV_HEADS
+    tol = 2e-2 if q.dtype == jnp.bfloat16 else 2e-5
+    for r in np.flatnonzero(live):
+        for i, p in enumerate(np.asarray(pos)[r]):
+            pages = np.asarray(table)[r, :p // pt + 1]
+            for head in range(q.shape[1]):
+                keys = kf[pages, head // rep].reshape(-1, HEAD_DIM)[:p + 1]
+                vals = vf[pages, head // rep].reshape(-1, HEAD_DIM)[:p + 1]
+                s = keys @ qf[r, head, i] / np.sqrt(HEAD_DIM)
+                w = np.exp(s - s.max())
+                np.testing.assert_allclose(
+                    np.asarray(got, np.float32)[r, head, i],
+                    (w / w.sum()) @ vals, atol=tol, rtol=tol)

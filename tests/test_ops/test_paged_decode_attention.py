@@ -386,3 +386,60 @@ class TestDispatch:
         ref = paged_decode_attention(q, kp, vp, table, L, backend="xla")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-6)
+
+
+# ---- the lane-dense leaf (kv/arena.py): narrow heads `parts` positions to a
+# 128-lane row.  The kernel takes it as it lies, the gather path through a
+# reshape; both must be the plain leaf's attention.
+
+
+def _plain_attention(q, kp, vp, table, lengths, scale):
+    """Softmax attention a row at a time over the row's own pages, in
+    numpy: no gather helper, no kernel."""
+    q, kp, vp = (np.asarray(a, np.float64) for a in (q, kp, vp))
+    b, h, d = q.shape
+    rep = h // kp.shape[1]
+    out = np.zeros((b, h, d))
+    for row in range(b):
+        n = int(lengths[row])
+        pages = np.asarray(table)[row, :-(-n // kp.shape[2])]
+        for head in range(h):
+            k = kp[pages, head // rep].reshape(-1, d)[:n]
+            v = vp[pages, head // rep].reshape(-1, d)[:n]
+            s = k @ q[row, head] * scale
+            p = np.exp(s - s.max())
+            out[row, head] = (p / p.sum()) @ v
+    return out
+
+
+@pytest.mark.parametrize("d,rep", [(64, 4), (32, 1), (16, 8)],
+                         ids=["heads-of-64", "heads-of-32", "heads-of-16"])
+def test_lane_dense_leaves_attend_as_plain_ones(d, rep):
+    from easydist_tpu.kv.arena import lane_parts
+    from easydist_tpu.ops import paged_decode_attention
+    from easydist_tpu.runtime import spans
+
+    q, kp, vp, table, lengths = _blocked_setup(_boundary_lengths(2), rep,
+                                               d=d)
+    parts = lane_parts(d, PT)
+    assert parts == min(128 // d, PT)
+    dense = [a.reshape(BNP, 2, PT // parts, parts * d) for a in (kp, vp)]
+    want = _plain_attention(q, kp, vp, table, lengths, 0.25)
+    spans.clear()
+    kernel = flash_paged_decode_attention(q, *dense, table, lengths,
+                                          scale=0.25, interpret=True)
+    counted = [k for k in spans.snapshot()["counters"]
+               if k.startswith("paged_attn_calls")]
+    assert counted == [f"paged_attn_calls{{head_dim={d},kernel=decode,"
+                       f"leaf=lane_dense,row_parts={parts}}}"]
+    np.testing.assert_allclose(np.asarray(kernel), want, atol=1e-5)
+    # the very kernel call a plain leaf makes (after `_whole_lanes`)
+    np.testing.assert_array_equal(
+        np.asarray(kernel), np.asarray(flash_paged_decode_attention(
+            q, kp, vp, table, lengths, scale=0.25, interpret=True)))
+    xla = paged_decode_attention(q, *dense, table, lengths, scale=0.25,
+                                 backend="xla")
+    np.testing.assert_array_equal(
+        np.asarray(xla), np.asarray(_paged_decode_attention_xla(
+            q, kp, vp, table, lengths, 0.25)))
+    np.testing.assert_allclose(np.asarray(xla), want, atol=1e-5)

@@ -164,6 +164,34 @@ def test_paged_chunk_lowers(rows, h, kvh, chunk, pt, max_pages):
         _aval((rows, max_pages), jnp.int32), _aval((rows,), jnp.int32))
 
 
+# the LFM2 cell (256 slots, 4 prefill rows, 32 query heads on 8 KV heads of
+# SIXTY-FOUR, 1,536 pages of 256): the leaf lane-dense as the arena stores
+# it, [pages, 8, 128, 128], and plain, [pages, 8, 256, 64]
+@pytest.mark.parametrize("leaf", ["lane_dense", "as_is"])
+@pytest.mark.parametrize("chunk", [0, 256], ids=["decode", "chunk"])
+def test_paged_kernels_lower_at_the_lfm2_cell(chunk, leaf):
+    """Both forms lower to the SAME kernel — the lane-dense leaf is handed
+    to the custom call as it is stored, the plain one through a reshape to
+    that very shape (which on the chip is a copy of the leaf: the compile
+    for a v5e in tests/test_kv/test_arena_inplace.py counts them)."""
+    n_pages, rows = 1536, 4 if chunk else 256
+    stored = (n_pages, 8, 128, 128) if leaf == "lane_dense" \
+        else (n_pages, 8, 256, 64)
+    pages = _aval(stored, BF16)
+    q = _aval((rows, 32) + ((chunk,) if chunk else ()) + (64,), BF16)
+    call = flash_paged_chunk_attention if chunk \
+        else flash_paged_decode_attention
+    text = _lower_for_tpu(
+        lambda q, k, v, t, n: call(q, k, v, t, n, interpret=False),
+        q, pages, pages, _aval((rows, 16), jnp.int32),
+        _aval((rows,), jnp.int32)).as_text()
+    (line,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert line.count(f"tensor<{n_pages}x8x128x128xbf16>") == 2
+    reshapes = [ln for ln in text.splitlines() if "reshape" in ln
+                and f"{n_pages}x8x256x64xbf16" in ln]
+    assert len(reshapes) == (0 if leaf == "lane_dense" else 2)
+
+
 @pytest.mark.parametrize("n_blocks", [1, 2])
 @pytest.mark.parametrize("b,h,kvh,d,max_pages", PAGED_SHAPES)
 def test_int8_paged_decode_lowers(b, h, kvh, d, max_pages, n_blocks):
