@@ -361,18 +361,21 @@ def check_prefix_cache(trie, node: str = "prefix_cache"):
     return findings
 
 
-def check_page_table(pool, table, trie=None, node: str = "kv"):
+def check_page_table(pool, table, trie=None, node: str = "kv",
+                     on_path=None):
     """Runtime self-check hook for the paged KV session (KV001): audit
     the page pool / page table / prefix-trie bookkeeping against each
     other and raise (or log, with the escape hatch) on error findings —
     serving on corrupt page accounting reads or frees another sequence's
     K/V, bitwise-silently.  Returns the findings so callers/tests can
-    assert on them."""
+    assert on them.  `on_path` is `audit_page_table`'s: told "vector"
+    or "listed", before anything raises."""
     from easydist_tpu import config as edconfig
 
     if not edconfig.enable_analyze:
         return []
-    findings = audit_page_table(pool, table, trie=trie, node=node)
+    findings = audit_page_table(pool, table, trie=trie, node=node,
+                                on_path=on_path)
     report = AnalysisReport(findings)
     if report.errors() and edconfig.analyze_raise:
         report.raise_on_errors()

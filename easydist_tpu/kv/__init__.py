@@ -40,7 +40,9 @@ storage too:
     same pool).  Pages are of the layers that see every position, only.
 
 Analyze rule KV001 (`analyze/kv_rules.py`) audits the pool/table/trie
-bookkeeping; `check_invariants` here is the raw audit it wraps.
+bookkeeping; `check_invariants` here is the raw audit it wraps:
+`consistent()` decides a sound pool or table in a fixed number of array
+passes, `list_problems()` walks one that fails to word what is wrong.
 """
 
 from __future__ import annotations

@@ -24,6 +24,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+import numpy as np
+
 __all__ = ["PagePool"]
 
 
@@ -44,7 +46,8 @@ class PagePool:
         # LIFO free list: recently freed pages are reused first, keeping
         # the hot working set of arena rows small
         self._free: List[int] = list(range(n_pages - 1, -1, -1))
-        self._refcount: List[int] = [0] * n_pages
+        # an int array, so that the audit reads it without a copy
+        self._refcount = np.zeros(n_pages, dtype=np.int64)
         self.allocs = 0
         self.frees = 0
         self.shares = 0
@@ -88,7 +91,7 @@ class PagePool:
         self._check_live(page, "share")
         self._refcount[page] += 1
         self.shares += 1
-        return self._refcount[page]
+        return int(self._refcount[page])
 
     def release(self, page: int) -> int:
         """Drop one holder; the page returns to the free list when the
@@ -98,12 +101,18 @@ class PagePool:
         if self._refcount[page] == 0:
             self._free.append(page)
             self.frees += 1
-        return self._refcount[page]
+        return int(self._refcount[page])
 
     def refcount(self, page: int) -> int:
         if not 0 <= page < self.n_pages:
             raise ValueError(f"page {page} out of range [0, {self.n_pages})")
-        return self._refcount[page]
+        return int(self._refcount[page])
+
+    @property
+    def refcounts(self) -> np.ndarray:
+        """Every page's count, by page id: the audit's view (KV001
+        counts the table's and the trie's holders against it)."""
+        return np.asarray(self._refcount)
 
     def ensure_exclusive(self, page: int) -> Optional[int]:
         """Copy-on-write fault point: if `page` is shared (refcount > 1),
@@ -140,7 +149,34 @@ class PagePool:
         free-list entries must be unique in-range pages at refcount 0,
         live pages must hold positive counts, and the arena byte total
         must equal mapped + free page bytes (conservation — no page is
-        both free and mapped, none is lost)."""
+        both free and mapped, none is lost).
+
+        A consistent pool is decided by `consistent()`, a fixed number
+        of array passes whatever the arena's size; `list_problems()`
+        walks the pool page by page only to word a failure."""
+        return [] if self.consistent() else self.list_problems()
+
+    def consistent(self) -> bool:
+        """True exactly where `list_problems()` would return []: the
+        same invariants as a few passes over the refcounts and the free
+        list."""
+        n = self.n_pages
+        refcount = self.refcounts
+        if refcount.shape != (n,):
+            return False
+        free = np.asarray(self._free, dtype=np.int64)
+        if free.size and not 0 <= free.min() <= free.max() < n:
+            return False
+        # once on the free list at refcount 0, off it at a count above 0
+        times_free = np.bincount(free, minlength=n)
+        if (refcount < 0).any() or (times_free != (refcount == 0)).any():
+            return False
+        return n * self.page_bytes \
+            == (self.in_use + self.n_free) * self.page_bytes
+
+    def list_problems(self) -> List[str]:
+        """The listed walk: one line per violated invariant, in the
+        order the free list and then the pages are met."""
         problems: List[str] = []
         seen = set()
         for page in self._free:

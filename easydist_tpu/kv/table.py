@@ -88,7 +88,27 @@ class PageTable:
     # ----------------------------------------------------------- reporting
     def check_invariants(self) -> List[str]:
         """Shape/range audit (KV001 cross-checks entries against the
-        pool's refcounts; this is the table-local half)."""
+        pool's refcounts; this is the table-local half).
+
+        A consistent table is decided by `consistent()`, a fixed number
+        of array passes whatever its size; `list_problems()` walks it
+        row by row only to word a failure."""
+        return [] if self.consistent() else self.list_problems()
+
+    def consistent(self) -> bool:
+        """True exactly where `list_problems()` would return []."""
+        if self.array.shape != (self.max_slots, self.max_pages):
+            return False
+        if ((self.array < 0) | (self.array > self.sentinel)).any():
+            return False
+        # a row's mapped windows are a contiguous prefix exactly where
+        # no mapped window follows an unmapped one
+        live = self.array != self.sentinel
+        return not (live[:, 1:] & ~live[:, :-1]).any()
+
+    def list_problems(self) -> List[str]:
+        """The listed walk: one line per violated invariant, slot by
+        slot."""
         problems: List[str] = []
         if self.array.shape != (self.max_slots, self.max_pages):
             problems.append(

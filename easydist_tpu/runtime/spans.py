@@ -49,6 +49,11 @@ reads which):
         flight (the host's gap), from there to the `.call`'s end one
         program is (launch, execution, readback).  The session runs one
         program at a time, so the two tile its timeline exactly.
+    easydist.serve.retire         attrs: reason, request_id
+        GenerationSession._retire, round all a retirement does (slot and
+        pages given back, the KV001 audit, the future resolved): below a
+        step's `.decode.harvest` or `.prefill.finish`, never the step's
+        direct child; outside any step when `evacuate` retires
     easydist.serve.submit         attrs: prompt_len
     easydist.serve.snapshot_inflight    attrs: n
         the session's other entry points on the loop's thread, called
@@ -68,6 +73,10 @@ kernels: `latent_decode`, `latent_chunk`);
 one per traced call of a flash training kernel (ops/flash_attention.py):
 whether a grid step holds the side it walks whole, and the dtype the MXU
 is handed.
+`kv_audits{where=retire|first_decode,path=vector|listed}`, one per KV001
+audit of a session (serve/generation.py::_audit_kv): `vector` where array
+passes decided the pool consistent, `listed` where they did not and the
+pool was walked to word the finding — a sound run reads `listed` 0.
 Serving counts stay in `ServeMetrics`: the state pool's gauges, a latent
 arena's `latent_cache_bytes`, the delta-rule layers' `delta_state_bytes`,
 `delta_rows_updated` and `delta_chunk_positions`, the selective-state

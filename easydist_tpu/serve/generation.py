@@ -1093,19 +1093,21 @@ class GenerationSession:
     # ------------------------------------------------------------- decoding
     def _retire(self, pool: _PagedPool, slot_idx: int, reason: str) -> None:
         slot = pool.slots.pop(slot_idx)
-        if self._drafter is not None:
-            self._drafter.forget(slot.request_id)
-            self._spec_ewma.pop(slot.request_id, None)
-            self._spec_idle.pop(slot.request_id, None)
-        pool.give_slot(slot_idx)
-        if pool.trie is not None and slot.pinned:
-            pool.trie.unpin(slot.pinned)
-        self._audit_kv(pool, f"retire[{reason}]")
-        slot.future.set_result({"ids": list(slot.generated),
-                                "finish_reason": reason,
-                                "timing": _finish_timing(slot.timing,
-                                                         reason)})
-        self.metrics.inc("requests_completed")
+        with spans.span("easydist.serve.retire", reason=reason,
+                        request_id=slot.request_id):
+            if self._drafter is not None:
+                self._drafter.forget(slot.request_id)
+                self._spec_ewma.pop(slot.request_id, None)
+                self._spec_idle.pop(slot.request_id, None)
+            pool.give_slot(slot_idx)
+            if pool.trie is not None and slot.pinned:
+                pool.trie.unpin(slot.pinned)
+            self._audit_kv(pool, f"retire[{reason}]")
+            slot.future.set_result({"ids": list(slot.generated),
+                                    "finish_reason": reason,
+                                    "timing": _finish_timing(slot.timing,
+                                                             reason)})
+            self.metrics.inc("requests_completed")
 
     def _maybe_retire(self, pool: _PagedPool, slot_idx: int) -> bool:
         slot = pool.slots[slot_idx]
@@ -1431,14 +1433,22 @@ class GenerationSession:
         """KV001: page-table/refcount audit at the state transitions
         where drift would matter (first decode, every retire).  Layer 13
         rides along: KVQ001 (scale/payload desync) when the arena is
-        quantized, KVQ003 (manifest round trip) when a tier is up."""
+        quantized, KVQ003 (manifest round trip) when a tier is up.
+
+        A consistent pool is decided in a fixed number of array passes
+        whatever its slots and pages; the listed walk runs only to word
+        a failure.  `kv_audits{where=retire|first_decode,
+        path=vector|listed}` counts which ran: a sound run reads `listed`
+        0."""
         try:
             from easydist_tpu.analyze import (check_page_table,
                                               check_quant_arena,
                                               check_tier_roundtrip)
 
-            check_page_table(pool.pool, pool.table, trie=pool.trie,
-                             node=f"kv[{where}]")
+            check_page_table(
+                pool.pool, pool.table, trie=pool.trie, node=f"kv[{where}]",
+                on_path=lambda path: spans.count(
+                    "kv_audits", where=where.split("[")[0], path=path))
             if "k_scale" in pool.arena:
                 check_quant_arena(pool.arena, node=f"kv.quant[{where}]")
             if pool.tier is not None:

@@ -28,8 +28,10 @@ CONFIGS = {
 }
 # per step: the step and its admit; per chunk call a build, a call with the
 # dispatch inside it, and at most one finish per prefill row; a decode
-# round's build, call with its dispatch, and harvest
-SPANS_PER_STEP_BOUND = 2 + CHUNKS_PER_STEP * (3 + ROWS) + 4
+# round's build, call with its dispatch, and harvest; and one
+# `easydist.serve.retire` per slot, each of which can retire (at its
+# prefill's finish or in the harvest) at most once in a step
+SPANS_PER_STEP_BOUND = 2 + CHUNKS_PER_STEP * (3 + ROWS) + 4 + SLOTS
 
 
 @pytest.fixture(scope="module")
