@@ -1,0 +1,15 @@
+"""Share of the traced window in which chip 0 sat idle outside every record of
+the program, the session not empty: the loop that drives it (of a program
+without the `easydist.serve.empty` record, its emptiness too).  One of the
+six `idle_*_pct` that add up to `device_idle_pct.chat` of the same run
+(`chipbench/idle_timeline.py`: the recorder's ring joined to the device
+trace)."""
+
+from chipbench import idle_timeline
+
+META = {"layer": "session", "unit": "%", "moves": "token_gap_p95_ms",
+        "source": "program_span"}
+
+
+def read(run):
+    return idle_timeline.share(run, "caller")

@@ -14,6 +14,18 @@ Every time is `time.perf_counter_ns()`.  `snapshot()` gives plain lists and
 dicts; the rings drop their oldest entries when full, and `clear()` empties
 them (tests, or a benchmark between phases).
 
+The ring and a trace are joined through a span seen on both sides.  While
+a `jax.profiler` session captures, a span is an event of its name in the
+trace's host plane, its attrs at entry among the event's stats, and a
+record of the ring: an `easydist.serve.step` event whose `step` is n IS the
+record whose `step` is n, and (event start - record `t0_ns`) is the constant
+between the profiler's clock and `perf_counter_ns`.  With it every record
+of the ring, from before the capture and after it too, lies on the device's
+timeline.  `chipbench/idle_timeline.py` does that (through the wrapper its
+runner opens round each `step()`, the median over all pairs) and puts each
+idle interval of the chip down to the record that owned it: the six
+`idle_*_pct` of `BENCHMARK.json`.
+
 The names, each opened in one place (`PERF.md` section 3 says which metric
 reads which):
 
@@ -28,6 +40,13 @@ reads which):
         the interval of a dispatch that made XLA compile the step, or load
         it from the persistent cache; counted as `xla_compiles{fn=...}`
     easydist.serve.step           attrs: step, live, queued, empty_ns
+    easydist.serve.empty
+        one record (`record_span`, no parent) an interval in which the
+        session had nothing live and nothing queued: written when a
+        `submit()` ends it, and up to the start of a `step()` that finds the
+        session still empty.  The records lie between steps and add up to
+        the steps' `empty_ns`; `idle_empty_pct` is the chip's idle time
+        inside them.
     easydist.serve.admit          attrs: admitted, deferred
     easydist.serve.prefill.build | .call | .finish
     easydist.serve.decode.build | .call | .harvest
@@ -161,7 +180,8 @@ class span:
 def record_span(name: str, t0_ns: int, t1_ns: int, parent_id: int = 0,
                 **attrs) -> int:
     """A span whose interval is only known afterwards (a dispatch that
-    turned out to compile).  Returns its id."""
+    turned out to compile, an emptiness that a `submit()` ended).  Returns
+    its id."""
     span_id = next(_ids)
     _spans.append((name, span_id, parent_id, t0_ns, t1_ns, attrs))
     return span_id

@@ -728,6 +728,8 @@ class GenerationSession:
                 (prompt, max_new_tokens,
                  self.eos_id if eos_id is None else eos_id, fut, timing))
             if self._empty_since_ns is not None:    # the emptiness ends here
+                spans.record_span("easydist.serve.empty",
+                                  self._empty_since_ns, timing["submit_ns"])
                 self._empty_ns += timing["submit_ns"] - self._empty_since_ns
                 self._empty_since_ns = None
             self.metrics.inc("requests_submitted")
@@ -1016,7 +1018,7 @@ class GenerationSession:
                         pass
             pool.arena, first, sp = self._run(
                 "easydist.serve.prefill.call", result, args, held,
-                rows=pool.n_rows, chunk=c_len)
+                rows=pool.n_rows)
             # a chunk fills one page: a live row's extent ends with it; its
             # n real positions start .. start + n - 1 see start + 1 ..
             # start + n keys
@@ -1487,7 +1489,11 @@ class GenerationSession:
                         queued=len(self._pending)) as step_span:
             empty_ns = self._empty_ns
             if self._empty_since_ns is not None:    # stepped while empty
+                spans.record_span("easydist.serve.empty",
+                                  self._empty_since_ns, step_span.t0_ns)
                 empty_ns += step_span.t0_ns - self._empty_since_ns
+                # recorded: a `submit()` during this step finds no emptiness
+                self._empty_since_ns = None
             step_span.set(empty_ns=empty_ns)
             with spans.span("easydist.serve.admit") as sp:
                 queued = len(self._pending)

@@ -209,6 +209,38 @@ def test_span_is_in_the_profilers_host_plane(tmp_path):
     assert any(n.startswith("easydist.test.traced") for n in names)
 
 
+def test_a_span_seen_in_a_trace_and_in_the_ring_gives_the_two_clocks_offset(
+        tmp_path):
+    """The join the docstring promises (the CPU backend's host plane
+    suffices): a captured span's event carries its attrs at entry, so the
+    event whose `step` is n is the record whose `step` is n, and (event start
+    - record start) is one constant for every such pair."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    with spans.span("easydist.test.step", step=1):
+        pass                              # before the capture: ring only
+    with jax.profiler.trace(str(tmp_path)):
+        for n in (2, 3, 4):
+            with spans.span("easydist.test.step", step=n) as sp:
+                time.sleep(0.003 * n)
+                sp.set(later=n)           # not at entry: the ring's alone
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {dict(e.stats)["step"]: e
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name == "easydist.test.step"}
+    records = {r["attrs"]["step"]: r for r in spans.snapshot()["spans"]}
+    assert sorted(events) == [2, 3, 4] and sorted(records) == [1, 2, 3, 4]
+    assert all("later" not in dict(e.stats) for e in events.values())
+    offsets = [events[n].start_ns - records[n]["t0_ns"] for n in (2, 3, 4)]
+    assert max(offsets) - min(offsets) < 100_000
+    for n in (2, 3, 4):
+        assert abs(events[n].duration_ns - (
+            records[n]["t1_ns"] - records[n]["t0_ns"])) < 100_000
+
+
 # ----------------------------------------------------- jaxfront call sites
 
 def _mlp(w, x):

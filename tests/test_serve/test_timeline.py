@@ -353,6 +353,71 @@ def test_a_program_after_an_empty_session_is_told_apart(two_bursts):
                                                      name))
 
 
+def test_empty_intervals_lie_between_steps_and_sum_to_empty_ns(two_bursts):
+    """`easydist.serve.empty`: one record an emptiness that a `submit()`
+    ended or a `step()` found — between steps, never over a step that ran a
+    program, and together exactly the steps' `empty_ns`."""
+    records = two_bursts["spans"]
+    empties = [r for r in records if r["name"] == "easydist.serve.empty"]
+    steps = _steps(two_bursts)
+    # since the session was made; the drained session up to the empty step;
+    # from that step's end to the second submit
+    assert len(empties) == 3
+    assert all(r["parent_id"] == 0 and r["attrs"] == {} for r in empties)
+    assert sum(r["t1_ns"] - r["t0_ns"] for r in empties) \
+        == sum(s["attrs"]["empty_ns"] for s in steps)
+    for r in empties:
+        assert r["t0_ns"] < r["t1_ns"]
+        assert not any(s["t0_ns"] < r["t1_ns"] and r["t0_ns"] < s["t1_ns"]
+                       for s in steps)
+    for a, b in zip(empties, empties[1:]):
+        assert a["t1_ns"] <= b["t0_ns"]
+    # the two that a submit ended end at that request's `submit_ns`
+    submitted = sorted(t["submit_ns"] for t in two_bursts["requests"])
+    assert [empties[0]["t1_ns"], empties[2]["t1_ns"]] == submitted
+    (found,) = [s for s in steps[1:] if not s["attrs"]["live"]
+                and not s["attrs"]["queued"]]
+    assert empties[1]["t1_ns"] == found["t0_ns"]
+    assert empties[2]["t0_ns"] == found["t1_ns"]
+
+
+def test_a_session_with_work_all_the_way_records_one_emptiness(run):
+    _, _, snap, _ = run
+    (only,) = [r for r in snap["spans"] if r["name"] == "easydist.serve.empty"]
+    assert only["t1_ns"] - only["t0_ns"] \
+        == _steps(snap)[0]["attrs"]["empty_ns"]
+
+
+def test_a_submit_during_a_step_on_an_empty_session_records_no_emptiness(
+        model):
+    """Another thread's `submit()` while a `step()` that found the session
+    empty is running: the emptiness up to that step is recorded already, so
+    the submit writes no second record from the same start, over the step."""
+    cfg, params = model
+    sc = ServeConfig(decode_buckets=(cfg.seq,), max_decode_slots=SLOTS,
+                     prefill_chunk=CHUNK, prefill_batch=ROWS)
+    sess = GenerationSession.for_gpt(params, cfg, config=sc)
+    admit_one, late = sess._admit_one, []
+
+    def admit_after_a_late_submit():
+        if not late:
+            late.append(sess.submit(PROMPTS[1], max_new_tokens=2))
+        return admit_one()
+
+    sess._admit_one = admit_after_a_late_submit
+    spans.clear()
+    sess.step()                           # empty when it began
+    sess.run_until_drained()
+    snap = spans.snapshot()
+    spans.clear()
+    assert late[0].done()
+    steps = _steps(snap)
+    (only,) = [r for r in snap["spans"] if r["name"] == "easydist.serve.empty"]
+    assert only["t1_ns"] == steps[0]["t0_ns"]
+    assert only["t1_ns"] - only["t0_ns"] \
+        == sum(s["attrs"]["empty_ns"] for s in steps)
+
+
 def test_submit_and_snapshot_inflight_are_spans_between_the_steps(two_bursts):
     records = two_bursts["spans"]
     submits = [r for r in records if r["name"] == "easydist.serve.submit"]
