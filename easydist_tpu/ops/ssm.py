@@ -8,7 +8,10 @@ Mamba-2 (SSD): `A` is a SCALAR a head and B, C are shared by every head
     y_t = S_t . C_t + D * x_t
 
 `ssd_chunk_scan` runs a window of positions from a state carried in and
-gives the state carried out (chunked prefill); `ssm_decode_update` is the
+gives the state carried out (chunked prefill): on a TPU ONE Pallas kernel,
+a row's block of heads a grid step, whose [t, r] decay masks and products
+never leave fast memory (`ssd_chunk_scan_xla` is the jnp form every other
+backend runs and the tests hold the kernel to); `ssm_decode_update` is the
 same recurrence for one position a sequence (decode), a Pallas kernel on a
 TPU that reads and writes each state once, in place.
 
@@ -37,6 +40,7 @@ and the taps side by side on the lanes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _default_interpret, _largest_divisor
 
-__all__ = ["causal_conv_tail", "ssd_chunk_scan", "ssm_decode_update",
+__all__ = ["causal_conv_tail", "ssd_chunk_scan", "ssd_chunk_scan_xla",
+           "ssm_decode_update",
            "ssm_decode_update_xla", "selective_chunk_scan",
            "selective_chunk_scan_xla", "selective_decode_update",
            "selective_decode_update_xla"]
@@ -123,7 +128,8 @@ def _ssd_block(x, dt, a, b_mat, c_mat, state):
     return y, state
 
 
-def ssd_chunk_scan(x, dt, a, b_mat, c_mat, d_skip, state, block: int = 256):
+def ssd_chunk_scan_xla(x, dt, a, b_mat, c_mat, d_skip, state,
+                       block: int = 256):
     """A window of `s` positions from `state`: x [b, s, h, p] (float32),
     dt [b, s, h] (after softplus; 0 at positions that do not count), a [h]
     (negative), b_mat / c_mat [b, s, n], d_skip [h], state [b, h, p, n]
@@ -140,6 +146,239 @@ def ssd_chunk_scan(x, dt, a, b_mat, c_mat, d_skip, state, block: int = 256):
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=2)
     return (y + d_skip[None, :, None, None] * xh).transpose(0, 2, 1, 3), \
         state
+
+
+# positions a block of the scan kernel takes: the [t, r] mask of a head is
+# one MXU tile, 16 vector registers
+_SSD_POSITIONS = 128
+# fast memory a grid step's x, y and state blocks may take, double-buffered
+_SSD_VMEM = 8 * 2 ** 20
+
+
+def _ssd_tiles(s: int, h: int, p: int, n: int):
+    """How the scan kernel tiles a window: (g, hb, q) — `g` heads side by
+    side fill whole 128-lane tiles of x and y (two heads of 64), a grid
+    step holds `hb` heads (a multiple of `g`: the most whose x and y
+    windows and state blocks, each double-buffered, fit `_SSD_VMEM`), and
+    the window is walked `q` positions at a time.  None for a shape the
+    kernel cannot tile: heads that do not fill whole lane tiles, a window
+    shorter than a sublane tile, one so long that `g` heads of it are over
+    the budget."""
+    g = 128 // math.gcd(p, 128)
+    q = min(_SSD_POSITIONS, s + -s % 8)
+    per_head = 16 * p * (s + -s % q + n)
+    if s < 8 or h % g or g * per_head > _SSD_VMEM:
+        return None
+    return g, g * _largest_divisor(
+        h // g, lambda m: m * g * per_head <= _SSD_VMEM), q
+
+
+def _ssd_scan_kernel(counts_ref, x_ref, dt_ref, csc_ref, csr_ref, end_ref,
+                     b_ref, c_ref, d_ref, s_ref, y_ref, s_out, *, q: int,
+                     g: int, p: int, operands):
+    """counts int32 [b, nk] (prefetched: whether any position of the row's
+    block has a `dt` that is not 0); x / y [1, sp, hb * p] (a head's p
+    channels side by side on the lanes, as the model has them); dt and cs,
+    the cumulative sum of dt * A INSIDE each block of q positions, heads on
+    the lanes [1, 1, nk, q, hb], cs again with the positions on the lanes
+    [1, 1, nk, hb, q], and a block's whole decay exp(cs[q - 1]) all along
+    a head's row of lanes [1, 1, nk, hb, n] (Mosaic broadcasts a [1, 1]
+    along the lanes or down the rows, not both at once); B / C [1, sp, n];
+    D on x's lanes [1, hb * p]; state [1, hb * p, n].  Grid (rows, blocks
+    of heads).
+
+    `_ssd_block` a block of q positions at a time, for `g` heads at once —
+    a unit, whole lane tiles of x: C . B^T once a block; a head's masked
+    decay, its [t, r] mix and the mix's product with dt * x; the carried
+    state's C . S^T scaled by exp(cs); and the state after the block, kept
+    in the output block from one block of positions to the next.  The
+    products take `operands` (bfloat16 on the chip: what the backend's
+    default precision hands the MXU for the jnp form's float32 einsums) and
+    accumulate in float32.  A block none of whose positions counts — the
+    padding after a prompt's last chunk, a row that is no sequence — has no
+    mix and moves no state (every decay is exp(0) and every dt * x is 0):
+    its y is C . S^T + D * x for the whole block of heads in one product,
+    the same numbers for a quarter of the work."""
+    f32 = jnp.float32
+    w = g * p
+    n = s_ref.shape[2]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q, w), 1)
+    srow = jax.lax.broadcasted_iota(jnp.int32, (w, n), 0)
+    tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+
+    def by_head(at, pick, shape):
+        """Head i's `pick(i)` where `at` (lane or row number) is inside
+        head i's p, for the unit's g heads."""
+        out = jnp.broadcast_to(pick(g - 1), shape)
+        for i in range(g - 2, -1, -1):
+            out = jnp.where(at < (i + 1) * p, pick(i), out)
+        return out
+
+    def dot(lhs, rhs, contract):
+        return jax.lax.dot_general(
+            lhs.astype(operands), rhs.astype(operands),
+            ((contract, ((), ()))), preferred_element_type=f32)
+
+    def counted(k, at):
+        bk, ck = b_ref[0, at, :], c_ref[0, at, :]
+        scores = dot(ck, bk, ((1,), (1,)))                       # [t, r]
+        dt, csc, csr = dt_ref[0, 0, k], csc_ref[0, 0, k], csr_ref[0, 0, k]
+        to_end = jnp.exp(csc[q - 1:q] - csc) * dt                # [q, hb]
+        carried = jnp.exp(csc)
+        whole = end_ref[0, 0, k]                                 # [hb, n]
+        for u in range(x_ref.shape[2] // w):
+            h0, lanes = u * g, slice(u * w, (u + 1) * w)
+            x = x_ref[0, at, lanes]                              # [q, w]
+            xdt = x * by_head(lane, lambda i: dt[:, h0 + i:h0 + i + 1],
+                              (q, w))
+            mixed = None
+            for i in range(g):
+                # the difference BEFORE the exponential, under the mask:
+                # above the diagonal it is positive and may overflow
+                seg = csc[:, h0 + i:h0 + i + 1] - csr[h0 + i:h0 + i + 1]
+                mix = scores * jnp.exp(jnp.where(tri, seg, -jnp.inf))
+                y_i = dot(mix, xdt, ((1,), (0,)))
+                mixed = y_i if mixed is None else jnp.where(
+                    lane < i * p, mixed, y_i)
+            state = s_out[0, lanes, :]                           # [w, n]
+            y = mixed + dot(ck, state, ((1,), (1,))) * by_head(
+                lane, lambda i: carried[:, h0 + i:h0 + i + 1], (q, w))
+            y_ref[0, at, lanes] = y + d_ref[:, lanes] * x
+            moved = x * by_head(
+                lane, lambda i: to_end[:, h0 + i:h0 + i + 1], (q, w))
+            s_out[0, lanes, :] = state * by_head(
+                srow, lambda i: whole[h0 + i:h0 + i + 1], (w, n)) \
+                + dot(moved, bk, ((0,), (0,)))
+
+    bi = pl.program_id(0)
+
+    def block(k, _):
+        counts = counts_ref[bi, k]
+        at = pl.ds(pl.multiple_of(k * q, q), q)
+        pl.when(counts != 0)(lambda: counted(k, at))
+
+        @pl.when(counts == 0)
+        def _passed():
+            y_ref[0, at, :] = dot(c_ref[0, at, :], s_out[0], ((1,), (1,))) \
+                + d_ref[...] * x_ref[0, at, :]
+
+    s_out[...] = s_ref[...]
+    jax.lax.fori_loop(0, x_ref.shape[1] // q, block, None)
+
+
+@functools.lru_cache(maxsize=32)
+def _ssd_scan_call(b: int, sp: int, h: int, p: int, n: int, g: int, hb: int,
+                   q: int, operands: str, interpret: bool):
+    """The scan's `pallas_call`, built ONCE a signature, as
+    `_selective_scan_call` below and for its reason: a model's second
+    state layer finds the first's trace and its Mosaic lowering."""
+    nk, nb = sp // q, h // hb
+
+    def lanes(bi, hi, counts):              # x, y: the block's lanes
+        return (bi, 0, hi)
+
+    def heads(bi, hi, counts):              # the state: the block's rows
+        return (bi, hi, 0)
+
+    def small(bi, hi, counts):
+        return (bi, hi, 0, 0, 0)
+
+    def shared(bi, hi, counts):
+        return (bi, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nb),
+        in_specs=[pl.BlockSpec((1, sp, hb * p), lanes),
+                  pl.BlockSpec((1, 1, nk, q, hb), small),
+                  pl.BlockSpec((1, 1, nk, q, hb), small),
+                  pl.BlockSpec((1, 1, nk, hb, q), small),
+                  pl.BlockSpec((1, 1, nk, hb, n), small),
+                  pl.BlockSpec((1, sp, n), shared),
+                  pl.BlockSpec((1, sp, n), shared),
+                  pl.BlockSpec((1, hb * p), lambda bi, hi, counts: (0, hi)),
+                  pl.BlockSpec((1, hb * p, n), heads)],
+        out_specs=[pl.BlockSpec((1, sp, hb * p), lanes),
+                   pl.BlockSpec((1, hb * p, n), heads)])
+    return pl.pallas_call(
+        functools.partial(_ssd_scan_kernel, q=q, g=g, p=p,
+                          operands=operands),
+        grid_spec=grid_spec,
+        # y FIRST: a reader of device traces tells the decode update from
+        # the other kernels by a first result that is 4-D float32
+        out_shape=[jax.ShapeDtypeStruct((b, sp, h * p), jnp.float32),
+                   jax.ShapeDtypeStruct((b, h * p, n), jnp.float32)],
+        # operand 9 (after the prefetched counts) is the state
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="ssd_chunk_scan",
+    )
+
+
+def ssd_chunk_scan(x, dt, a, b_mat, c_mat, d_skip, state, block: int = 256,
+                   interpret=None, backend=None):
+    """`ssd_chunk_scan_xla` as one kernel: grid (rows, blocks of heads), a
+    row's block of heads a step — its x and y lane-dense slices of [b, s,
+    h * p] as the model has them (nothing is transposed to heads-major and
+    back), the row's B and C fetched once while the row stands, the block's
+    state read once and written once to the buffer it came from
+    (`input_output_aliases`).  A head's [t, r] decay mask and mix live in
+    fast memory only.  The window is walked in blocks of positions of the
+    kernel's choosing (`_ssd_tiles`; padded to whole blocks with `dt = 0`,
+    which leave the state as it was): `block` is the jnp form's and means
+    nothing here, any value gives the same function.  The Pallas kernel on
+    a TPU where the shape tiles (or with `backend="pallas"`, whatever the
+    shape: the interpreter takes any), the jnp form elsewhere and for
+    heads that do not fill whole 128-lane tiles (`h` no multiple of 128 /
+    gcd(p, 128)), a window of fewer than 8 positions, or one too long for
+    fast memory."""
+    b, s, h, p = x.shape
+    n = state.shape[-1]
+    tiles = _ssd_tiles(s, h, p, n)
+    if backend is None:
+        backend = "pallas" if jax.default_backend() == "tpu" \
+            and tiles is not None else "xla"
+    f32 = jnp.float32
+    x, dt, a, b_mat, c_mat, d_skip, state = (
+        v.astype(f32) for v in (x, dt, a, b_mat, c_mat, d_skip, state))
+    if backend == "xla":
+        return ssd_chunk_scan_xla(x, dt, a, b_mat, c_mat, d_skip, state,
+                                  block)
+    if interpret is None:
+        interpret = _default_interpret()
+    # every head one unit, one step: only the interpreter takes that
+    g, hb, q = tiles or (h, h, min(_SSD_POSITIONS, s + -s % 8))
+    pad = -s % q
+    x = x.reshape(b, s, h * p)
+    if pad:
+        x, dt, b_mat, c_mat = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                               for v in (x, dt, b_mat, c_mat))
+    nk, nb = (s + pad) // q, h // hb
+
+    def rows(v):                         # [b, h, sp] -> [b, nb, nk, hb, q]
+        return v.reshape(b, nb, hb, nk, q).transpose(0, 1, 3, 2, 4)
+
+    # heads-major, the positions on the lanes: where XLA's cumulative sum
+    # is a microsecond (with the heads on the lanes it was 239 us a layer).
+    # It restarts every block: a block's decays are `_ssd_block`'s on its q
+    # positions
+    dth = dt.transpose(0, 2, 1)
+    cs = rows(jnp.cumsum((dth * a[:, None]).reshape(b, h, nk, q), axis=-1))
+    with jax.named_scope("ssd_chunk_scan"):
+        y, new = _ssd_scan_call(
+            b, s + pad, h, p, n, g, hb, q,
+            "float32" if interpret else "bfloat16", bool(interpret))(
+            (dt.reshape(b, nk, q * h) != 0).any(-1).astype(jnp.int32),
+            x, rows(dth).swapaxes(3, 4), cs.swapaxes(3, 4), cs,
+            jnp.broadcast_to(jnp.exp(cs[..., q - 1, None]),
+                             cs.shape[:4] + (n,)),
+            b_mat, c_mat, jnp.repeat(d_skip, p)[None],
+            state.reshape(b, h * p, n))
+    return (y[:, :s] if pad else y).reshape(b, s, h, p), \
+        new.reshape(b, h, p, n)
 
 
 def ssm_decode_update_xla(state, x, dt, a, b_vec, c_vec, d_skip):

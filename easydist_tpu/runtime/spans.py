@@ -84,8 +84,8 @@ Counters: `xla_compiles{fn=<name>}`; `pallas_calls{kernel=<name>,
 row_shards=<k>}`, one per Pallas kernel call of a program emitted for a mesh
 (jaxfront/api.py::_pallas_row_axes): `k` is the number of shards its rows
 were split into, 1 for a call every device runs whole (the kernels of a
-model with state layers among them: `ssm_decode_update`, `delta_decode_update`,
-`selective_chunk_scan`, `selective_decode_update`;
+model with state layers among them: `ssd_chunk_scan`, `ssm_decode_update`,
+`delta_decode_update`, `selective_chunk_scan`, `selective_decode_update`;
 an expert layer's two: `grouped_matmul`, `grouped_matmul_sum`; the latent
 kernels: `latent_decode`, `latent_chunk`);
 `flash_train_calls{kernel=<name>,kv=resident|streamed,operands=<dtype>}`,

@@ -421,6 +421,80 @@ def test_hybrid_decode_step_writes_arena_and_state_in_place_on_tpu(
         "state update, paged decode attention, two grouped products a layer"
 
 
+def test_hybrid_chunk_step_keeps_the_scan_in_one_kernel_on_tpu(
+        v5e_chip, monkeypatch):
+    """The Granite 4.0-H cell's CHUNK call (BENCHMARK.json: published
+    widths, four rows of 256, 64 state slots, 36 held experts), one Mamba-2
+    and one attention layer: the chunked scan lowers through Mosaic at the
+    cell's head shape (p 64, d_state 128) as ONE call named
+    `ssd_chunk_scan`; no float32 array of rank 4 in the compiled program
+    ends in two extents of the window's positions (the jnp form's `seg`,
+    `decay` and `mix`, [4, 128, 256, 256] = 134 MB each, which XLA kept
+    inside one fusion and a kernel keeps in fast memory); and the arena's
+    two leaves and the state's two are donated and handed back through
+    writes in place — no copy of the SSM leaf (268 MB), whose four rows the
+    kernel updates through its own alias."""
+    import functools
+
+    from easydist_tpu import config as edconfig
+    from easydist_tpu.models import granite_hybrid as gh
+    from easydist_tpu.models.decoder import Paged, State, chunk
+    from easydist_tpu.ops import ssm
+
+    fa = importlib.import_module("easydist_tpu.ops.flash_attention")
+    # the backend here is the CPU: steer the step onto its TPU path
+    monkeypatch.setattr(edconfig, "prefill_attention_backend", "paged")
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(ssm, "ssd_chunk_scan", functools.partial(
+        ssm.ssd_chunk_scan, backend="pallas", interpret=False))
+    _compile_the_grouped_products(monkeypatch)
+    cfg = gh.GraniteHybridConfig(vocab=50176,
+                                 layer_types=("mamba", "attention"),
+                                 experts_held=(0, 36))
+    dec = gh.decoder(cfg)
+    slots, n_pages, pt, max_pages, c_rows = 64, 768, 256, 16, 4
+    assert ssm._ssd_tiles(pt, cfg.mamba_heads, cfg.mamba_head_dim,
+                          cfg.d_state) == (2, 16, 128)
+
+    def aval(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = _described(v5e_chip, jax.eval_shape(
+        lambda key: gh.granite_init(cfg, key), jax.random.PRNGKey(0)))
+    cache = _described(v5e_chip, jax.eval_shape(
+        lambda: {**Paged.init(dec, n_pages, pt), **State.init(dec, slots)}))
+
+    def chunk_step(cache, params, table, at, tokens, start, lengths):
+        pages, leaves = State.split(dec, cache)
+        st = State(leaves, at < slots, at, fresh=start == 0)
+        cache, logits = chunk(dec, Paged(pages, table), params, tokens,
+                              start, lengths, state=st)
+        return cache, jnp.argmax(logits, -1)
+
+    compiled = jax.jit(chunk_step, donate_argnums=(0,)).lower(
+        cache, params, aval((c_rows, max_pages)), aval((c_rows,)),
+        aval((c_rows, pt)), aval((c_rows,)), aval((c_rows,))).compile()
+    page_leaf = n_pages * 8 * pt * 128 * 2
+    ssm_leaf = slots * 128 * 64 * 128 * 4
+    conv_leaf = slots * 3 * 8448 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * page_leaf + ssm_leaf + conv_leaf
+    assert mem.temp_size_in_bytes < ssm_leaf, \
+        "a state or arena leaf is copied round its write"
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 6, \
+        "the scan, paged chunk attention, two grouped products a layer"
+    (scan,) = [ln for ln in calls if "ssd_chunk_scan" in ln.split(" = ")[0]]
+    # y first, [rows, positions, heads x head_dim]; the four rows' state,
+    # aliased to the operand it came in as
+    assert f"(f32[{c_rows},{pt},8192]" in scan
+    assert "output_to_operand_aliasing={{1}: (9, {})}" in scan
+    square = re.findall(rf"f32\[\d+,\d+,{pt},{pt}\]", text)
+    assert not square, sorted(set(square))
+
+
 def test_window_decode_step_writes_rings_and_arena_in_place_on_tpu(
         v5e_chip, monkeypatch):
     """The K-EXAONE cell's decode round (BENCHMARK.json: published widths,

@@ -6,6 +6,7 @@ pools, the scan's indifference to chunking, padding and dead rows, slots,
 the expert shares, and what a session refuses for a model with state."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -94,11 +95,27 @@ def _decode(dec, cache, params, tokens, positions, live):
                   jnp.asarray(positions), state=st)
 
 
-def test_chunked_prefill_then_decode_equals_the_reference(params):
+def _scan_kernel(monkeypatch):
+    """The chunked scan's Pallas kernel under the interpreter, in the
+    mixer's place (on the CPU the mixer takes the jnp form)."""
+    from easydist_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "ssd_chunk_scan", functools.partial(
+        ssm.ssd_chunk_scan, backend="pallas", interpret=True))
+    return ssm
+
+
+@pytest.mark.parametrize("scan", ["jnp", "pallas"])
+def test_chunked_prefill_then_decode_equals_the_reference(params, scan,
+                                                          monkeypatch):
     """Logits, not tokens.  Both sides are float32; they differ in the
     order of sums (SSD blocks against a sequential scan, grouped rows
     against dense experts, a paged softmax): 2e-5 of the logits' spread,
-    where leaving a term out moves them by the spread itself."""
+    where leaving a term out moves them by the spread itself.  With the
+    scan's kernel interpreted too (float32 operands there, as the jnp
+    form's are on the CPU)."""
+    if scan == "pallas":
+        _scan_kernel(monkeypatch)
     dec = gh.decoder(CFG)
     rng = np.random.default_rng(0)
     prompt = rng.integers(1, 96, size=19).tolist()
@@ -142,7 +159,11 @@ def test_the_scan_gives_the_same_state_whatever_the_chunk(params, c_len):
     np.testing.assert_allclose(last[0], last8[0], rtol=1e-4, atol=1e-8)
 
 
-def test_padded_positions_and_dead_rows_leave_state_bit_identical(params):
+@pytest.mark.parametrize("scan", ["jnp", "pallas"])
+def test_padded_positions_and_dead_rows_leave_state_bit_identical(
+        params, scan, monkeypatch):
+    if scan == "pallas":
+        _scan_kernel(monkeypatch)
     dec = gh.decoder(CFG)
     rng = np.random.default_rng(2)
     a, b = rng.integers(1, 96, size=11).tolist(), \
@@ -330,6 +351,45 @@ def test_a_session_serves_it_and_the_ids_are_the_references(params):
     assert 0 < counters["moe_pairs_routed"] <= 2 * 3 * \
         counters["tokens_generated"]
     sess.close()
+
+
+def test_a_session_on_a_mesh_counts_the_scan_kernel_once_a_state_layer(
+        params, monkeypatch):
+    """The chunk program emitted for a mesh of two devices with the scan's
+    kernel in the mixer's place: `pallas_calls{kernel=ssd_chunk_scan,
+    row_shards=1}` reads one a state layer (the kernel's D has no row to
+    split, so every device runs the call whole) and nothing for the decode
+    program, the kernel was built ONCE for both layers (`_ssd_scan_call`),
+    and the ids are still the reference's."""
+    from easydist_tpu.jaxfront import make_device_mesh
+    from easydist_tpu.runtime import spans
+
+    ssm = _scan_kernel(monkeypatch)
+    ssm._ssd_scan_call.cache_clear()
+    spans.clear()
+    mesh = make_device_mesh((2,), ("tp",), devices=jax.devices()[:2])
+    sess = GenerationSession(params, model=gh.decoder(CFG), mesh=mesh,
+                             config=ServeConfig(
+        decode_buckets=(64,), max_decode_slots=N_SLOTS, prefill_chunk=PT,
+        prefill_batch=2, enable_prefix_cache=False, speculate_k=0))
+    rng = np.random.default_rng(5)
+    reqs = [(rng.integers(1, 96, size=n).tolist(), m)
+            for n, m in ((19, 4), (8, 3), (30, 5))]
+    futs = [sess.submit(p, max_new_tokens=m) for p, m in reqs]
+    sess.run_until_drained()
+    for (prompt, _), fut in zip(reqs, futs):
+        ids = fut.result(timeout=5)["ids"]
+        want = np.asarray(reference.logits(
+            params, SIZES, np.asarray(prompt + ids, np.int32)))
+        rows = want[len(prompt) - 1:len(prompt) - 1 + len(ids)]
+        assert rows.argmax(-1).tolist() == ids
+    sess.close()
+    counted = {k: v for k, v in spans.snapshot()["counters"].items()
+               if k.startswith("pallas_calls{kernel=ssd")}
+    assert counted == {
+        "pallas_calls{kernel=ssd_chunk_scan,row_shards=1}":
+        CFG.layer_types.count("mamba")}
+    assert ssm._ssd_scan_call.cache_info().misses == 1
 
 
 # re-recorded where the conv's tail went flat (PR 46: the carry's "conv" is

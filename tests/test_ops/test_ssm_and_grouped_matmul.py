@@ -1,9 +1,12 @@
-"""`ops/ssm.py` and `ops/grouped_matmul.py`: the chunked scan against the
-one-position recurrence, both Pallas kernels under the interpreter against
-their jnp forms, the blocked layout's bookkeeping, and both kernels
+"""`ops/ssm.py` and `ops/grouped_matmul.py`: the chunked scan — its jnp form
+and its Pallas kernel under the interpreter — against the one-position
+recurrence, the other Pallas kernels under the interpreter against
+their jnp forms, the blocked layout's bookkeeping, and the kernels
 cross-lowered for TPU at the Granite 4.0-H cell's widths (Pallas' own jaxpr
 -> Mosaic lowering; Mosaic's compile is `tests/test_kv/test_arena_inplace.py`
 and the chip's job)."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +17,10 @@ from easydist_tpu.ops import grouped_matmul as gm
 from easydist_tpu.ops.grouped_matmul import (_tile, group_rows,
                                              grouped_matmul,
                                              grouped_matmul_sum)
-from easydist_tpu.ops.ssm import (_heads_per_step, ssd_chunk_scan,
-                                  ssm_decode_update, ssm_decode_update_xla)
+from easydist_tpu.ops import ssm
+from easydist_tpu.ops.ssm import (_heads_per_step, _ssd_tiles, ssd_chunk_scan,
+                                  ssd_chunk_scan_xla, ssm_decode_update,
+                                  ssm_decode_update_xla)
 
 
 def _ssm_inputs(b=2, s=24, h=4, p=8, n=16, seed=0):
@@ -39,32 +44,210 @@ def _sequential(i):
     return jnp.stack(ys, axis=1), state
 
 
+def _scan(form, i, **kw):
+    """The chunked scan in one of its two forms: the jnp one, or the kernel
+    under the interpreter."""
+    fn = ssd_chunk_scan_xla if form == "xla" else functools.partial(
+        ssd_chunk_scan, backend="pallas", interpret=True)
+    return fn(i["x"], i["dt"], i["a"], i["b_mat"], i["c_mat"], i["d_skip"],
+              i["state"], **kw)
+
+
 @pytest.mark.parametrize("block", [7, 8, 16, 24, 256])
 def test_the_chunked_scan_is_the_recurrence_whatever_the_block(block):
     i = _ssm_inputs()
     want_y, want_state = _sequential(i)
-    y, state = ssd_chunk_scan(i["x"], i["dt"], i["a"], i["b_mat"],
-                              i["c_mat"], i["d_skip"], i["state"],
-                              block=block)
+    y, state = _scan("xla", i, block=block)
     np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(state, want_state, rtol=2e-5, atol=2e-6)
+    # the kernel walks the window in blocks of its own: `block` is the jnp
+    # form's, and any value gives the same function
+    ky, kstate = _scan("pallas", i, block=block)
+    np.testing.assert_allclose(ky, want_y, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(kstate, want_state, rtol=2e-5, atol=2e-6)
 
 
-def test_positions_whose_dt_is_zero_leave_the_state_bit_identical():
-    i = _ssm_inputs()
-    dt = i["dt"].at[:, 10:].set(0.0)       # 10 real positions, 14 padded
-    _, state = ssd_chunk_scan(i["x"], dt, i["a"], i["b_mat"], i["c_mat"],
-                              i["d_skip"], i["state"], block=8)
-    _, short = ssd_chunk_scan(i["x"][:, :10], dt[:, :10], i["a"],
-                              i["b_mat"][:, :10], i["c_mat"][:, :10],
-                              i["d_skip"], i["state"], block=8)
+# (b, s, h, p, n) -> how the kernel tiles it (`_ssd_tiles`: heads a unit,
+# heads a step, positions a block; None = no whole lane tiles: the
+# interpreter alone takes it, all heads one unit)
+SCAN_SHAPES = {
+    "tiny": ((2, 24, 4, 8, 16), None),
+    "a-window-of-9": ((3, 9, 2, 8, 8), None),
+    "three-blocks-six-heads": ((1, 300, 6, 32, 16), None),
+    "the-cells-heads-not-whole-blocks": ((2, 200, 4, 64, 128), (2, 4, 128)),
+    "the-cells-heads-one-block": ((1, 128, 6, 64, 128), (2, 6, 128)),
+    "four-heads-a-unit": ((2, 144, 8, 32, 64), (4, 8, 128)),
+    "a-head-a-unit": ((1, 40, 2, 128, 32), (1, 2, 40)),
+}
+
+
+@pytest.mark.parametrize("shape", list(SCAN_SHAPES))
+def test_the_scan_kernel_is_the_recurrence_and_the_jnp_form(shape):
+    (b, s, h, p, n), tiles = SCAN_SHAPES[shape]
+    assert _ssd_tiles(s, h, p, n) == tiles
+    i = _ssm_inputs(b, s, h, p, n, seed=s)
+    want_y, want_state = _sequential(i)
+    scale = float(jnp.abs(want_y).max())
+    y, state = _scan("pallas", i)
+    np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-6 * scale)
+    np.testing.assert_allclose(state, want_state, rtol=2e-5, atol=2e-5)
+    xla_y, xla_state = _scan("xla", i, block=128)
+    np.testing.assert_allclose(y, xla_y, rtol=2e-5, atol=4e-6 * scale)
+    np.testing.assert_allclose(state, xla_state, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("hb", [2, 4])
+def test_the_scan_kernel_over_several_blocks_of_heads(hb, monkeypatch):
+    """A budget of fast memory that holds `hb` of the eight heads a grid
+    step: every block of heads reads and writes ITS rows of the state (the
+    first kernel handed every block the first block's, and no case above
+    has a second block)."""
+    (b, s, h, p, n) = (2, 200, 8, 64, 128)
+    monkeypatch.setattr(ssm, "_SSD_VMEM", hb * 16 * p * (256 + n))
+    assert _ssd_tiles(s, h, p, n) == (2, hb, 128)
+    i = _ssm_inputs(b, s, h, p, n, seed=19)
+    want_y, want_state = _sequential(i)
+    y, state = _scan("pallas", i)
+    np.testing.assert_allclose(y, want_y, rtol=2e-5,
+                               atol=2e-6 * float(jnp.abs(want_y).max()))
+    np.testing.assert_allclose(state, want_state, rtol=2e-5, atol=2e-5)
+
+
+def test_the_scan_kernels_tiles_fit_fast_memory():
+    # the cell: two heads of 64 a lane tile, 16 heads (1,024 lanes) a step
+    assert _ssd_tiles(256, 128, 64, 128) == (2, 16, 128)
+    assert _ssd_tiles(1024, 128, 64, 128) == (2, 4, 128)
+    assert _ssd_tiles(64, 8, 64, 128) == (2, 8, 64)      # chip_smoke's
+    # what falls to the jnp form: heads that fill no whole lane tiles, a
+    # window under a sublane tile, one over the budget
+    assert _ssd_tiles(256, 3, 64, 128) is None
+    assert _ssd_tiles(7, 128, 64, 128) is None
+    assert _ssd_tiles(8192, 128, 64, 128) is None
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", ["tiny",
+                                   "the-cells-heads-not-whole-blocks"])
+def test_a_state_carried_through_two_windows_is_one_window_of_both(form,
+                                                                   shape):
+    (b, s, h, p, n), _ = SCAN_SHAPES[shape]
+    i = _ssm_inputs(b, s, h, p, n, seed=7)
+    cut = s // 3
+    windowed = ("x", "dt", "b_mat", "c_mat")
+    y0, mid = _scan(form, {**i, **{k: i[k][:, :cut] for k in windowed}})
+    y1, end = _scan(form, {**i, **{k: i[k][:, cut:] for k in windowed},
+                           "state": mid})
+    y, state = _scan(form, i)
+    scale = float(jnp.abs(y).max())
+    np.testing.assert_allclose(jnp.concatenate([y0, y1], axis=1), y,
+                               rtol=2e-5, atol=4e-6 * scale)
+    np.testing.assert_allclose(end, state, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_positions_whose_dt_is_zero_leave_the_state_bit_identical(form):
+    i = _ssm_inputs(b=3)
+    # 10 real positions and 14 padded; a row of none that count
+    dt = i["dt"].at[:, 10:].set(0.0).at[2].set(0.0)
+    _, state = _scan(form, {**i, "dt": dt}, block=8)
+    _, short = _scan(form, {**i, **{k: v[:, :10] for k, v in (
+        ("x", i["x"]), ("dt", dt), ("b_mat", i["b_mat"]),
+        ("c_mat", i["c_mat"]))}}, block=8)
     np.testing.assert_allclose(state, short, rtol=1e-6, atol=1e-7)
+    np.testing.assert_array_equal(state[2], i["state"][2])
     for backend in ("xla", "pallas"):
         same, _ = ssm_decode_update(
             i["state"], i["x"][:, 0], jnp.zeros_like(i["dt"][:, 0]), i["a"],
             i["b_mat"][:, 0], i["c_mat"][:, 0], i["d_skip"],
             backend=backend, interpret=True)
         np.testing.assert_array_equal(same, i["state"])
+
+
+def test_a_block_of_the_kernels_with_no_position_that_counts_changes_nothing():
+    """The cell's head shape, three blocks of 128: a row whose last two
+    blocks are padding, and a row that is padding whole — its state comes
+    back bit for bit (the kernel is told which blocks count and passes
+    over the others)."""
+    (b, s, h, p, n) = (2, 384, 4, 64, 128)
+    i = _ssm_inputs(b, s, h, p, n, seed=11)
+    dt = i["dt"].at[0, 100:].set(0.0).at[1].set(0.0)
+    y, state = _scan("pallas", {**i, "dt": dt})
+    np.testing.assert_array_equal(state[1], i["state"][1])
+    # such a block skips its mix and its state's products, not its y: C . S
+    # + D * x at every position, as the jnp form gives it
+    want_y, _ = _scan("xla", {**i, "dt": dt}, block=128)
+    np.testing.assert_allclose(y, want_y, rtol=2e-5,
+                               atol=4e-6 * float(jnp.abs(want_y).max()))
+    _, short = _scan("pallas", {
+        k: v[:1, :100] if k in ("x", "dt", "b_mat", "c_mat") else v[:1]
+        if k == "state" else v for k, v in {**i, "dt": dt}.items()})
+    np.testing.assert_array_equal(state[0], short[0])
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_a_strongly_decaying_window_stays_finite(form):
+    """sum(dt * a) about -80 over the window: exp(cs_t) * exp(-cs_r)
+    factored apart would be 0 * inf; the difference is taken first."""
+    (b, s, h, p, n) = (1, 256, 4, 64, 128)
+    i = _ssm_inputs(b, s, h, p, n, seed=13)
+    i["dt"] = jnp.full_like(i["dt"], 0.125)
+    i["a"] = jnp.asarray([-2.5, -2.0, -3.0, -2.5], jnp.float32)
+    assert -100 < float((i["dt"][0, :, 0] * i["a"][0]).sum()) < -75
+    want_y, want_state = _sequential(i)
+    y, state = _scan(form, i)
+    assert np.isfinite(np.asarray(y)).all()
+    assert np.isfinite(np.asarray(state)).all()
+    np.testing.assert_allclose(y, want_y, rtol=2e-5,
+                               atol=2e-6 * float(jnp.abs(want_y).max()))
+    np.testing.assert_allclose(state, want_state, rtol=2e-5, atol=2e-5)
+
+
+def test_the_scan_kernel_with_the_chips_operands_is_near_the_recurrence(
+        monkeypatch):
+    """On the chip the products take bfloat16 operands (what the default
+    precision hands the MXU for the jnp form's float32 einsums) and
+    accumulate in float32; the interpreter can be given the same."""
+    build = ssm._ssd_scan_call
+    monkeypatch.setattr(ssm, "_ssd_scan_call", lambda *a: build(
+        *a[:-2], "bfloat16", a[-1]))
+    (b, s, h, p, n), _ = SCAN_SHAPES["the-cells-heads-not-whole-blocks"]
+    i = _ssm_inputs(b, s, h, p, n, seed=17)
+    want_y, want_state = _sequential(i)
+    y, state = _scan("pallas", i)
+    for got, want in ((y, want_y), (state, want_state)):
+        err = np.abs(np.asarray(got) - np.asarray(want))
+        assert 1e-5 * float(jnp.abs(want).max()) < err.max() \
+            < 2e-2 * float(jnp.abs(want).max())
+        # bfloat16 keeps 8 bits: a product's operands are off by 2 ** -9
+        assert err.mean() < 1e-2 * float(jnp.abs(want).mean())
+
+
+def test_a_models_state_layers_share_one_scan_kernel():
+    """Granite's nine state layers a period call the scan at one signature:
+    the program's equations carry ONE kernel jaxpr (`_ssd_scan_call` is
+    built once a signature), traced once and lowered once a module."""
+    layers, (b, s, h, p, n) = 9, (4, 256, 128, 64, 128)
+    f32 = jnp.float32
+
+    def program(states, x, dt, a, b_mat, c_mat, d):
+        return [ssd_chunk_scan(x, dt, a, b_mat, c_mat, d, st,
+                               interpret=False, backend="pallas")
+                for st in states]
+
+    ssm._ssd_scan_call.cache_clear()
+    closed = jax.make_jaxpr(program)(
+        [_aval((b, h, p, n), f32)] * layers, _aval((b, s, h, p), f32),
+        _aval((b, s, h), f32), _aval((h,), f32), _aval((b, s, n), f32),
+        _aval((b, s, n), f32), _aval((h,), f32))
+    calls = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["ssd_chunk_scan"] * layers
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+    assert len({id(e.params["grid_mapping"]) for e in calls}) == 1
+    assert ssm._ssd_scan_call.cache_info().misses == 1
+    # y comes FIRST and is 3-D: a reader of device traces takes a Mosaic
+    # call whose first result is 4-D float32 for the decode update
+    assert [v.aval.shape for v in calls[0].outvars] \
+        == [(b, s, h * p), (b, h * p, n)]
 
 
 def test_the_decode_kernel_is_the_jnp_update():
@@ -306,6 +489,22 @@ def test_the_state_update_lowers_for_tpu_at_the_cells_widths():
         _aval((h,), f32), _aval((b, n), f32), _aval((b, n), f32),
         _aval((h,), f32)).as_text()
     assert "tpu_custom_call" in text
+
+
+def test_the_chunked_scan_lowers_for_tpu_at_the_cells_widths():
+    f32 = jnp.float32
+    b, s, h, p, n = 4, 256, 128, 64, 128
+    text = _lower_for_tpu(
+        lambda *a: ssd_chunk_scan(*a, backend="pallas", interpret=False),
+        _aval((b, s, h, p), f32), _aval((b, s, h), f32), _aval((h,), f32),
+        _aval((b, s, n), f32), _aval((b, s, n), f32), _aval((h,), f32),
+        _aval((b, h, p, n), f32)).as_text()
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert "ssd_chunk_scan" in text
+    # the state goes in and comes out as one operand: aliased
+    assert call.count(f"tensor<{b}x{h * p}x{n}xf32>") >= 2
+    assert "output_operand_alias<output_tuple_indices = [1], " \
+        "operand_index = 9" in call
 
 
 @pytest.mark.parametrize("rows,tm", [(640, 32), (10240, 128)],
